@@ -17,7 +17,9 @@ class). Two deliberate scope limits, measured on this jax build:
   guards every call after the first per program/static-arg key;
 * on CPU, array transfers are zero-copy aliases and do NOT trip the
   guard (scalar transfers do) — the AST side (jitlint's sync-in-loop)
-  covers the class the runtime guard can't see on CPU.
+  covers the class the runtime guard can't see on CPU. On the TPU they
+  do trip it: ``chip_smoke.py`` runs the engine and the served path
+  under ``disallow`` there (PR 21's run found the warm dispatch clean).
 
 Recompile sentinel
 ------------------
@@ -124,12 +126,8 @@ def reset_compile_tracking() -> None:
 
 def jit_cache_size(jitted) -> int:
     """Entries in a jax.jit wrapper's trace cache (one per distinct
-    (shapes, dtypes, static args) signature); -1 when this jax build
-    doesn't expose it."""
-    try:
-        return int(jitted._cache_size())
-    except AttributeError:
-        return -1
+    (shapes, dtypes, static args) signature)."""
+    return int(jitted._cache_size())
 
 
 class CompileBudget:
@@ -153,20 +151,15 @@ class CompileBudget:
 
     def track(self, name: str, jitted) -> "CompileBudget":
         self._fns[name] = jitted
-        self._baseline[name] = max(jit_cache_size(jitted), 0)
+        self._baseline[name] = jit_cache_size(jitted)
         return self
 
     def misses(self) -> int:
-        total = 0
-        for name, fn in self._fns.items():
-            size = jit_cache_size(fn)
-            if size >= 0:
-                total += max(size - self._baseline[name], 0)
-        return total
+        return sum(max(n, 0) for n in self.report().values())
 
     def report(self) -> Dict[str, int]:
         return {
-            name: max(jit_cache_size(fn), 0) - self._baseline[name]
+            name: jit_cache_size(fn) - self._baseline[name]
             for name, fn in self._fns.items()
         }
 

@@ -936,11 +936,7 @@ class BatchedRawNode:
             # take instead of 14 fancy-indexed gathers.
             words_d, simple_d, cplx_d = pack_outbox(outbox, self._slots_j)
 
-        # Device→host reads go through np.asarray, NOT jax.device_get:
-        # this build's device_get pays a fixed ~4ms per buffer (measured
-        # BENCH_NOTES r05 — 27 buffers made the round ~350ms, 100x the
-        # 1.2ms step program), while np.asarray is a zero-copy view on
-        # CPU and a plain single-buffer fetch elsewhere.
+        # Device→host reads: one np.asarray per buffer after one fence.
         jax.block_until_ready(st.term)
         (term, vote, commit, last, role, lead, snap_i, snap_t, ring,
          rd_seq, rd_idx, rd_ready,

@@ -3,7 +3,7 @@ commit-p50 measurement.
 
 ``bench.py`` (the headline number) and ``tools/frontier_sweep.py``
 (the latency/throughput frontier) must stay directly comparable to
-each other and to the committed BENCH_r05 captures — same R/W/E
+each other and to the BENCH_r05 captures (2026-07-31) — same R/W/E
 config, same election setup, same proposal load, same quiet-point
 commit-latency loop. Both import these helpers so a methodology tweak
 lands in one place and cannot silently desynchronize the two tools'

@@ -47,6 +47,9 @@ def main() -> None:
         # CPU timings are meaningless; this is a smoke run only.
         n = min(n, 1024)
     calls = 2 if interpret else 20
+    mode = ("interpret (smoke only, timings meaningless)" if interpret
+            else "compiled")
+    print(f"[{platform}] pallas mode: {mode}, N={n}", flush=True)
     rng = np.random.RandomState(0)
     match = jnp.asarray(rng.randint(0, 50, size=(n, r)), jnp.int32)
     voter = jnp.asarray(rng.rand(n, r) < 0.9)

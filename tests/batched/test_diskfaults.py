@@ -81,13 +81,16 @@ def make_harness(tmp_path, transport="inproc", wal_pipeline=False,
 
 
 def _led_group(h, mid):
-    """Some group the member currently leads (campaign until one)."""
+    """Some group the member currently leads. If it leads none, the
+    leader of group 0 hands that one over: a campaign cannot displace a
+    healthy pre-vote/check-quorum leader, a transfer can."""
     deadline = time.monotonic() + 30.0
     while time.monotonic() < deadline:
         for g in range(G):
             if h.members[mid].is_leader(g):
                 return g
-        h.members[mid].campaign(range(G))
+        for m in h.members.values():
+            m.transfer_leader(0, mid)  # False unless m leads group 0
         time.sleep(0.1)
     raise TimeoutError(f"member {mid} never led a group")
 

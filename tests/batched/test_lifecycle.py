@@ -100,13 +100,22 @@ class TestRotationAndCadence:
             assert hl["lifecycle"]["enabled"]
             assert hl["lifecycle"]["wal_segments"] >= 1
             assert hl["lifecycle"]["snap_files"] >= 1
-            # Retention: never more than keep files per group dir.
+            # Retention: no more than keep files per group dir — once
+            # the builder's prune has run (it writes the new file and
+            # fsyncs its marker BEFORE pruning, so a listing taken in
+            # between legitimately sees keep + 1).
             snap_root = os.path.join(m2.dir, "snap")
-            for sub in os.listdir(snap_root):
-                files = [n for n in
-                         os.listdir(os.path.join(snap_root, sub))
-                         if n.endswith(".snap")]
-                assert len(files) <= m2.snap_keep, (sub, files)
+
+            def over_keep():
+                return [
+                    (sub, files) for sub in os.listdir(snap_root)
+                    for files in [[
+                        n for n in os.listdir(os.path.join(snap_root, sub))
+                        if n.endswith(".snap")]]
+                    if len(files) > m2.snap_keep]
+
+            _wait(lambda: not over_keep(), timeout=10.0,
+                  what="retention to prune every dir to snap_keep")
 
             h.crash(2)
             h.run_workload(6, prefix=b"mid")

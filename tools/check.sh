@@ -32,7 +32,8 @@ fi
 
 echo "== jitlint (trace safety / dtype discipline / purity) =="
 python tools/jitlint.py \
-    etcd_tpu/batched/ etcd_tpu/analysis/ etcd_tpu/tools/ tools/ bench.py
+    etcd_tpu/batched/ etcd_tpu/analysis/ etcd_tpu/tools/ tools/ bench.py \
+    chip_smoke.py
 
 echo "== sentinel smoke (transfer guard, recompile budget, lock order) =="
 python -m pytest tests/analysis tests/batched/test_sentinels.py -q
