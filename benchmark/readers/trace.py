@@ -1,0 +1,69 @@
+"""Per-layer metrics from the reduced device trace (``reduce/trace.py``).
+A run without a trace, or a trace without the program a reader looks
+for, gives ``None``."""
+
+from __future__ import annotations
+
+from typing import Optional
+
+from ..harness import say
+from ..reduce import roofline
+from ..reduce.trace import scope_share_pct
+
+
+def _module(ctx, module: str):
+    red = ctx.get("trace")
+    if not red:
+        return None
+    hits = [m for name, m in red["modules"].items() if module in name]
+    if not hits:
+        return None
+    return {"count": sum(m["count"] for m in hits),
+            "seconds": sum(m["seconds"] for m in hits)}
+
+
+def module_ms(ctx, module: str, rounds_key: Optional[str] = None
+              ) -> Optional[float]:
+    """Device ms of one execution of the program whose name holds
+    ``module``; with ``rounds_key`` (a key of the traffic file), per
+    round of a program that scans that many."""
+    m = _module(ctx, module)
+    if m is None or m["count"] == 0:
+        return None
+    rounds = int(ctx["traffic"][rounds_key]) if rounds_key else 1
+    return 1e3 * m["seconds"] / (m["count"] * rounds)
+
+
+def scope_pct(ctx, scope: str) -> Optional[float]:
+    red = ctx.get("trace")
+    return scope_share_pct(red, scope) if red else None
+
+
+def route_roofline_pct(ctx, module: str, rounds_key: str
+                       ) -> Optional[float]:
+    red = ctx.get("trace")
+    m = _module(ctx, module)
+    if not red or m is None or "raft_route" not in red["scope_s"]:
+        return None
+    s = ctx["config"]["sizes"]
+    rounds = m["count"] * int(ctx["traffic"][rounds_key])
+    need = rounds * roofline.route_bytes(
+        int(s["num_groups"]), int(s["num_replicas"]),
+        int(s["max_ents_per_msg"]))
+    secs = red["scope_s"]["raft_route"]
+    say("roofline", kernel="route", bound_by="HBM bytes (no arithmetic)",
+        bytes_needed=need, seconds=secs, rounds=rounds,
+        achieved_GBps=need / secs / 1e9,
+        peak_GBps=roofline.peaks(ctx["device"]["kind"])[
+            "hbm_bytes_per_s"] / 1e9)
+    return roofline.roofline_pct(need, secs, ctx["device"]["kind"])
+
+
+def call_gap_ms(ctx, module: str, rounds_key: str) -> Optional[float]:
+    """Host wall of a call less the device time of its rounds."""
+    per_round = module_ms(ctx, module, rounds_key)
+    med = ctx["raw"].get("call_s_median")
+    if per_round is None or med is None:
+        return None
+    return max(0.0, med * 1e3
+               - per_round * int(ctx["traffic"][rounds_key]))
