@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..analysis.sentinels import note_compile_key, warm_guard
+from ..obs import spans
 from .compile_cache import enable_compile_cache
 
 # Never-reused engine identity for transfer-guard warm keys (itertools
@@ -33,7 +34,25 @@ from .step import MsgSlots, NUM_KINDS, empty_msgs, make_step_round, route
 
 
 class MultiRaftEngine:
+    """Host calls are spans of the round-span recorder (obs/spans.py):
+    ``engine.init``, ``engine.step_round`` and ``engine.run_rounds``
+    (one a scan, so one a chunk of ``run_rounds_pipelined``), with
+    member 0, the call's number as ``round`` and the engine's serial
+    and the scan's ``rounds`` as stats. A span ends when the program is
+    enqueued: the host's share of a call, not the device's."""
+
     def __init__(self, cfg: BatchedConfig, start_index: int = 0):
+        self._serial = next(_ENGINE_SERIAL)
+        self._calls = 0  # spans opened: the id the next one takes
+        with self._span("engine.init"):
+            self._init(cfg, start_index)
+
+    def _span(self, name: str, **stats) -> "spans.Span":
+        call = self._calls
+        self._calls = call + 1
+        return spans.span(name, 0, call, engine=self._serial, **stats)
+
+    def _init(self, cfg: BatchedConfig, start_index: int) -> None:
         # deliver_shape="auto" resolves to the platform default here
         # (state.default_deliver_shape), so self.cfg always names the
         # concrete shape the compiled round actually runs.
@@ -135,7 +154,6 @@ class MultiRaftEngine:
         # (NOT id(self): CPython reuses freed addresses, and a stale
         # warm key would put a new engine's compile inside the guard).
         self._wkey_step = f"round_step/{hash((cfg, False, n))}"
-        self._serial = next(_ENGINE_SERIAL)
 
     # -- driving --------------------------------------------------------------
 
@@ -161,7 +179,7 @@ class MultiRaftEngine:
         # Inside the guard the dispatch must be all-device: any implicit
         # transfer (an eager scalar op, a stray host array) is a hard
         # error when ETCD_TPU_TRANSFER_GUARD=disallow (tests, benches).
-        with warm_guard(self._wkey_step):
+        with self._span("engine.step_round"), warm_guard(self._wkey_step):
             out = self._step(
                 self.state, self.inbox, ticks, camp, props, iso,
                 transfer_to, read_req,
@@ -205,7 +223,8 @@ class MultiRaftEngine:
         props = propose_n if propose_n is not None else self._zeros_i
         # `rounds` is a static arg: each new value compiles a new scan
         # program, so warmth (and thus the transfer guard) is per value.
-        with warm_guard(f"closed_loop/{self._serial}/{rounds}"):
+        with self._span("engine.run_rounds", rounds=rounds), \
+                warm_guard(f"closed_loop/{self._serial}/{rounds}"):
             self.state, self.inbox, tel, flt, _ = self._closed_loop(
                 self.state, self.inbox, ticks, props, self._tel(),
                 self._flt(), rounds
@@ -239,7 +258,8 @@ class MultiRaftEngine:
         done = 0
         while done < rounds:
             n = min(chunk, rounds - done)
-            with warm_guard(f"closed_loop/{self._serial}/{n}"):
+            with self._span("engine.run_rounds", rounds=n), \
+                    warm_guard(f"closed_loop/{self._serial}/{n}"):
                 self.state, self.inbox, tel, flt, fence = self._closed_loop(
                     self.state, self.inbox, ticks, props, self._tel(),
                     self._flt(), n

@@ -67,6 +67,7 @@ from ..native.walog import (
     read_all_classified as wal_read_all_classified,
     salvage as wal_salvage,
 )
+from ..obs import spans
 from ..obs.tracer import make_tracer
 from ..pkg.failpoint import FailpointPanic, fp
 from ..raft.confchange import ConfChangeError
@@ -266,6 +267,12 @@ def _env_wal_pipeline() -> bool:
 # timeout (election_timeout ticks x tick_interval) delays vote acks
 # past it and starves elections. Keep max_delay well under a quarter
 # of the timeout.
+# Cumulative counters of member.stats that every member.round span
+# carries as they stand when the round closes, so that a window's delta
+# is the last round span's value less the first's.
+SPAN_COUNTERS = ("ready_q_depth_max", "read_opened", "read_confirmed",
+                 "read_timeouts", "leader_losses")
+
 WAL_GROUP_MAX_DELAY_S = 0.0
 WAL_GROUP_MAX_BYTES = 4 << 20
 
@@ -466,10 +473,14 @@ class MultiRaftMember:
         # exactly the window the async pipeline introduces.
         self._fp_before_release = (
             f"hosting.m{member_id}.raftBeforeFsyncRelease")
-        # Wall-seconds per phase of the member pipeline (ETCD_TPU_PROF
-        # companion at the hosting layer; read via the admin 'prof' op).
+        # Wall-seconds per phase of the member pipeline, each summed
+        # from the phase's span (obs/spans.py: member.round, .wal,
+        # .fsync, .apply, .send), and counters at the same boundaries;
+        # read via the admin 'prof' op. Every member.round span carries
+        # SPAN_COUNTERS as they stand when the round closes.
         self.stats = {"rounds": 0, "round_s": 0.0, "wal_s": 0.0,
-                      "apply_s": 0.0, "send_s": 0.0, "batched": 0}
+                      "apply_s": 0.0, "send_s": 0.0, "batched": 0,
+                      **{k: 0 for k in SPAN_COUNTERS}}
         self.tick_interval = tick_interval
         # ReadIndex bookkeeping for linearizable readers: the latest
         # OPENED batch seq per group (readers bind to a batch opened
@@ -1091,7 +1102,9 @@ class MultiRaftMember:
         try:
             while not self._stopped.is_set():
                 if not self.rn.has_work():
-                    self._work.wait(self.tick_interval)
+                    with spans.span("member.idle_wait", self.id,
+                                    self.stats["rounds"]):
+                        self._work.wait(self.tick_interval)
                     self._work.clear()
                     continue
                 self.run_round()
@@ -1125,16 +1138,25 @@ class MultiRaftMember:
                 if rd is None:
                     return
                 batch = [rd]
-                while True:
+                stop = False
+                while not stop:
                     try:
                         nxt = self._ready_q.get_nowait()
                     except queue_mod.Empty:
                         break
                     if nxt is None:
-                        self._process_readys(batch)
-                        return
-                    batch.append(nxt)
+                        stop = True
+                    else:
+                        batch.append(nxt)
+                # The queue wait between round and drain, one span a
+                # Ready: queued by the round thread, closed here.
+                now = time.monotonic_ns()
+                for b in batch:
+                    spans.record("member.ready_q", b.t_queued, now,
+                                 self.id, b.round)
                 self._process_readys(batch)
+                if stop:
+                    return
         except FailpointPanic:
             # Injected crash (chaos harness): exit WITHOUT the orderly
             # stop() below, which would flush state a real kill would
@@ -1160,13 +1182,18 @@ class MultiRaftMember:
         member runs unpipelined (pipeline=False: synchronous — kept as
         a debugging/fallback mode and covered by the test_hosting
         'sync' cluster parametrization)."""
-        t0 = time.perf_counter()
-        rd = self.rn.advance_round()
-        self.rn.advance()
-        self._joint_sweep()  # time-gated; no-op while nothing is joint
-        self.stats["rounds"] += 1
-        dt = time.perf_counter() - t0
-        self.stats["round_s"] += dt
+        stats = self.stats
+        seq = stats["rounds"]
+        with spans.span("member.round", self.id, seq) as sp:
+            rd = self.rn.advance_round()
+            self.rn.advance()
+            self._joint_sweep()  # time-gated; no-op while nothing is joint
+            stats["rounds"] += 1
+            stats["leader_losses"] += rd.leader_losses
+            sp.stats = {k: stats[k] for k in SPAN_COUNTERS}
+        rd.round = seq
+        dt = sp.seconds
+        stats["round_s"] += dt
         if self._h_phase is not None:
             self._h_phase["round"].observe(dt)
             pl = self.rn.phase_last
@@ -1177,12 +1204,16 @@ class MultiRaftMember:
             # forever on a stopped/dead drain worker (see _drain_loop's
             # fatal-fault guard); the unpersisted Ready is dropped with
             # the member, same as a crash at this point.
+            rd.t_queued = time.monotonic_ns()
             while not self._stopped.is_set():
                 try:
                     self._ready_q.put(rd, timeout=0.2)
                     break
                 except queue_mod.Full:
                     continue
+            depth = self._ready_q.qsize()
+            if depth > stats["ready_q_depth_max"]:
+                stats["ready_q_depth_max"] = depth
         else:
             self._process_readys([rd])
         return rd
@@ -1329,21 +1360,39 @@ class MultiRaftMember:
         and return — the worker's ordered release barrier runs the
         apply/send half only after the covering group-commit fsync."""
         fp(self._fp_before_save)  # crash-before-WAL-save injection site
-        t0 = time.perf_counter()
-        lifts: List[int] = []
+        with spans.span("member.wal", self.id, batch[0].round,
+                        readys=len(batch)) as sp:
+            lifts = self._persist_readys(batch)
+        self.stats["wal_s"] += sp.seconds
+        if self._h_phase is not None:
+            self._h_phase["wal"].observe(sp.seconds)
+        if lifts is None:
+            return
+        self.stats["batched"] += len(batch)
+        self._fence_lift_apply(lifts)
+        fp(self._fp_after_save)  # crash-after-save-before-apply site
+        for rd in batch:
+            self._apply_and_send(rd)
+        # Lifecycle work rides the drain AFTER the batch's covering
+        # fsync and release (pipeline mode runs the same pass at the
+        # end of each commit wave instead).
+        self._lifecycle_pass()
+
+    def _persist_readys(self, batch: List[BatchedReady]
+                        ) -> Optional[List[int]]:
+        """The persist half of _process_readys (the member.wal span).
+        Returns the fence lifts once the batch is durable here, or None
+        where the caller releases nothing: the member died, or the
+        records were queued on the WAL-commit worker."""
         with self._lock:
             if self._crashed:
-                return  # simulated kill: queued Readys are torn away
+                return None  # simulated kill: queued Readys are torn away
             must_sync, wm, records = self._build_persist_records(batch)
             if self._wal_worker is not None:
                 self._wal_submit_locked(records, must_sync,
                                         batch=batch, wm=wm)
                 self.stats["batched"] += len(batch)
-                dt = time.perf_counter() - t0
-                self.stats["wal_s"] += dt
-                if self._h_phase is not None:
-                    self._h_phase["wal"].observe(dt)
-                return
+                return None
             # Inline mode: snapshot the install generations under the
             # SAME lock the records were built under. The WAL write
             # below runs OUTSIDE _lock (handle serialized by _wal_io —
@@ -1354,26 +1403,13 @@ class MultiRaftMember:
             # exactly like the pipeline path does.
             gens = {row: int(self._snap_gen[row]) for row in wm}
         if not self._wal_write_sync(records, must_sync, batch):
-            return  # fail-stopped / crashed / stopped mid-write:
+            return None  # fail-stopped / crashed / stopped mid-write:
             # nothing from the unpersisted window is released
         with self._lock:
             if self._crashed:
-                return
+                return None
             self._apply_wm_locked(wm, must_sync, gens)
-            lifts = self._fence_lift_locked()
-        dt = time.perf_counter() - t0
-        self.stats["wal_s"] += dt
-        if self._h_phase is not None:
-            self._h_phase["wal"].observe(dt)
-        self.stats["batched"] += len(batch)
-        self._fence_lift_apply(lifts)
-        fp(self._fp_after_save)  # crash-after-save-before-apply site
-        for rd in batch:
-            self._apply_and_send(rd)
-        # Lifecycle work rides the drain AFTER the batch's covering
-        # fsync and release (pipeline mode runs the same pass at the
-        # end of each commit wave instead).
-        self._lifecycle_pass()
+            return self._fence_lift_locked()
 
     # -- IO-error contract (ISSUE 15) ------------------------------------------
     #
@@ -1429,28 +1465,30 @@ class MultiRaftMember:
         self._exit_disk_full()
         if not must_sync:
             return True
-        if self.tracer is not None:
-            # fsync_wait is stamped at fsync START (the queue/build
-            # half of the old fsync hop), fsync at COMPLETION — one
-            # instant pair covers every traced key the batch covers.
-            tw = time.monotonic_ns()
-            for rd in batch:
-                self.tracer.stamp_many(rd.traced_entries, "fsync_wait",
-                                       tw)
-        tf = time.perf_counter()
-        try:
-            with self._wal_io:
-                if self._wal_closed:
-                    return False
-                self.wal.flush(sync=True)
-                # Everything serialized above is now durable in the
-                # current tail segment — snapshot-install covers fold
-                # with this seq as their WAL-evidence segment.
-                self._last_sync_seq = int(self.wal.tail_seq())
-        except Exception as e:  # noqa: BLE001 — first failed fsync
-            self._io_fail_stop("fsync", e)
-            return False
-        dt = time.perf_counter() - tf
+        with spans.span("member.fsync", self.id,
+                        batch[0].round if batch else -1) as sp:
+            try:
+                with self._wal_io:
+                    if self._wal_closed:
+                        return False
+                    self.wal.flush(sync=True)
+                    # Everything serialized above is now durable in the
+                    # current tail segment — snapshot-install covers
+                    # fold with this seq as their WAL-evidence segment.
+                    self._last_sync_seq = int(self.wal.tail_seq())
+            except Exception as e:  # noqa: BLE001 — first failed fsync
+                self._io_fail_stop("fsync", e)
+                return False
+        self._fsync_done(sp, (rd.traced_entries for rd in batch))
+        return True
+
+    def _fsync_done(self, sp: "spans.Span", traced) -> None:
+        """Account one completed covering fsync from its member.fsync
+        span. The tracer's fsync_wait stamp is the span's start (the
+        queue/build half of the old fsync hop ends where the fsync
+        begins), fsync its end — one instant pair covers every traced
+        key the fsync covers."""
+        dt = sp.seconds
         self.stats["wal_fsyncs"] = self.stats.get("wal_fsyncs", 0) + 1
         self.stats["fsync_s"] = self.stats.get("fsync_s", 0.0) + dt
         if self._h_fsync is not None:
@@ -1461,10 +1499,9 @@ class MultiRaftMember:
             # the rebalancer evicts leadership on.
             self.fleet.observe_fsync(dt)
         if self.tracer is not None:
-            tns = time.monotonic_ns()
-            for rd in batch:
-                self.tracer.stamp_many(rd.traced_entries, "fsync", tns)
-        return True
+            for keys in traced:
+                self.tracer.stamp_many(keys, "fsync_wait", sp.t0)
+                self.tracer.stamp_many(keys, "fsync", sp.t1)
 
     def _enter_disk_full(self) -> None:
         if self._disk_full:
@@ -2137,29 +2174,27 @@ class MultiRaftMember:
         # nothing released/acked. Outside _wal_io so a crash() action
         # at the site can take _lock -> _wal_io itself.
         fp(self._fp_before_release)
-        tw_ns = time.monotonic_ns()  # fsync start (fsync_wait stamp)
-        tf = time.perf_counter()
         if must_sync:
-            try:
-                with self._wal_io:
-                    if self._wal_closed:
-                        return
-                    self.wal.flush(sync=True)
-                    # Wave durable in the current tail (cuts happen
-                    # only on THIS worker, so every record appended
-                    # above landed in it): snapshot-install covers
-                    # fold with this seq as their evidence segment.
-                    self._last_sync_seq = int(self.wal.tail_seq())
-            except Exception as e:  # noqa: BLE001 — first failed fsync
-                # Fail-stop releasing NOTHING covered by the failed
-                # window: every batch queued behind this group-commit
-                # keeps its acks/sends/applies withheld forever
-                # (ATC'19: a retried fsync can report success over
-                # already-dropped dirty pages).
-                self._io_fail_stop("fsync", e)
-                return
-        dt_sync = time.perf_counter() - tf
-        td_ns = time.monotonic_ns()  # fsync completion (fsync stamp)
+            first = next((rd.round for g in wave for rd in g.readys), -1)
+            with spans.span("member.fsync", self.id, first) as sp_sync:
+                try:
+                    with self._wal_io:
+                        if self._wal_closed:
+                            return
+                        self.wal.flush(sync=True)
+                        # Wave durable in the current tail (cuts happen
+                        # only on THIS worker, so every record appended
+                        # above landed in it): snapshot-install covers
+                        # fold with this seq as their evidence segment.
+                        self._last_sync_seq = int(self.wal.tail_seq())
+                except Exception as e:  # noqa: BLE001 — first failed fsync
+                    # Fail-stop releasing NOTHING covered by the failed
+                    # window: every batch queued behind this
+                    # group-commit keeps its acks/sends/applies withheld
+                    # forever (ATC'19: a retried fsync can report
+                    # success over already-dropped dirty pages).
+                    self._io_fail_stop("fsync", e)
+                    return
         lifts: List[int] = []
         with self._lock:
             if self._crashed:
@@ -2172,15 +2207,12 @@ class MultiRaftMember:
             lifts = self._fence_lift_locked()
         self._fence_lift_apply(lifts)
         if must_sync:
-            self.stats["wal_fsyncs"] = self.stats.get("wal_fsyncs", 0) + 1
-            self.stats["fsync_s"] = (
-                self.stats.get("fsync_s", 0.0) + dt_sync)
-            if self._h_fsync is not None:
-                self._h_fsync.observe(dt_sync)
-            if self.fleet is not None:
-                # Gray-failure feed (see _wal_write_sync): sustained
-                # slow group-commits raise member_limping.
-                self.fleet.observe_fsync(dt_sync)
+            # The covering group-commit's instants stamp every traced
+            # key in the wave (a wave that persists entries syncs) —
+            # the satellite contract that keeps the SLO hop table
+            # telescoping with the pipeline on.
+            self._fsync_done(
+                sp_sync, (keys for g in wave for keys in g.traced))
             # Amortization accounting rides the fsyncs only: an idle
             # no-sync wave covering empty rounds must not inflate the
             # rounds-per-fsync ratio the pipeline is judged by.
@@ -2197,15 +2229,6 @@ class MultiRaftMember:
                 if rounds:
                     self._m_wal_batches.observe(rounds)
                 self._m_wal_bytes.observe(nbytes)
-        if self.tracer is not None:
-            # The covering group-commit's instants, for every traced
-            # key in the wave: fsync_wait at fsync start (queue half),
-            # fsync at completion — the satellite contract that keeps
-            # the SLO hop table telescoping with the pipeline on.
-            for g in wave:
-                for keys in g.traced:
-                    self.tracer.stamp_many(keys, "fsync_wait", tw_ns)
-                    self.tracer.stamp_many(keys, "fsync", td_ns)
         fp(self._fp_after_save)  # fsync'd-but-unreleased kill window
         # Ordered release barrier: acks, sends and applies of a batch
         # leave ONLY here, after its covering fsync — persist-before-
@@ -2222,9 +2245,21 @@ class MultiRaftMember:
         self._lifecycle_pass()
 
     def _apply_and_send(self, rd: BatchedReady) -> None:
+        """Release one persisted Ready: the member.apply span, then
+        member.send; apply_s / send_s and their histograms are summed
+        from the two spans."""
         if self._crashed:
             return  # dead members neither apply nor send
-        t0 = time.perf_counter()
+        with spans.phases("member.", self.id, rd.round) as ph:
+            self._apply_then_send(rd, ph)
+        for p, ns in ph.dur.items():
+            self.stats[p + "_s"] += ns / 1e9
+            if self._h_phase is not None:
+                self._h_phase[p].observe(ns / 1e9)
+
+    def _apply_then_send(self, rd: BatchedReady,
+                         ph: "spans.Phases") -> None:
+        ph.next("apply")
         conf_changed: List[int] = []
         auto_leave_rows: List[int] = []
         io_fail: Optional[Tuple[str, BaseException]] = None
@@ -2338,13 +2373,6 @@ class MultiRaftMember:
             return
         if conf_changed:
             self._post_conf_apply(conf_changed, auto_leave_rows)
-        # Apply instant captured here, stamped at the END of this
-        # function: "apply" retires a span, and a same-round
-        # append+commit (solo group) must take its "send" stamp first.
-        tr_apply_ns = (
-            time.monotonic_ns()
-            if self.tracer is not None and rd.traced_commit else 0
-        )
         # 2b. surface ReadIndex progress to waiting readers (after
         #     apply: applied_index moved under the same round).
         if rd.read_opened or rd.read_states or rd.committed:
@@ -2353,11 +2381,20 @@ class MultiRaftMember:
                     self._read_opened[row] = seq
                 for row, seq, idx in rd.read_states:
                     self._read_results[row] = (seq, idx)
+                # ReadIndex batches opened and confirmed: a batch that
+                # opens and never confirms is the difference.
+                self.stats["read_opened"] += len(rd.read_opened)
+                self.stats["read_confirmed"] += len(rd.read_states)
                 self._read_cv.notify_all()
-        t1 = time.perf_counter()
-        self.stats["apply_s"] += t1 - t0
-        if self._h_phase is not None:
-            self._h_phase["apply"].observe(t1 - t0)
+        # The boundary between the two spans is both of the tracer's
+        # instants. "apply" (the end of member.apply) is stamped at the
+        # END of this function: it retires a span, and a same-round
+        # append+commit (solo group) must take its "send" stamp first.
+        # "send" (the start of member.send) is the instant this round's
+        # outbound batch is handed to the transport: the wire/peer clock
+        # starts before the hand-off, not after local serialization
+        # returned.
+        tr_send_ns = tr_apply_ns = ph.next("send").t0
         if self._m_ap_slots is not None and rd.committed:
             ps = self.rn.plane_stats
             self._m_ap_slots.set(ps["slots_hw"])
@@ -2369,13 +2406,10 @@ class MultiRaftMember:
                 self._ap_we_prev = we
         # 3b. send OUTSIDE the lock: delivery takes the receiver's lock,
         #     and two members sending to each other must not deadlock.
-        # "send" = the instant this round's outbound batch is handed to
-        # the transport — captured BEFORE the hand-off (the wire/peer
-        # clock starts here, not after local serialization returned),
-        # stamped only if something actually left (a round that
-        # persisted a traced entry but transmitted nothing — transport
-        # detached, nothing outbound — must not fabricate a send hop).
-        tr_send_ns = time.monotonic_ns() if self.tracer is not None else 0
+        # The send stamp is taken only if something actually left (a
+        # round that persisted a traced entry but transmitted nothing —
+        # transport detached, nothing outbound — must not fabricate a
+        # send hop).
         sent_any = False
         if out and self._send is not None:
             self._send(self.id, out)
@@ -2403,10 +2437,6 @@ class MultiRaftMember:
                 # apply loop finished above.
                 self.tracer.stamp_many(rd.traced_commit, "apply",
                                        tr_apply_ns)
-        dt = time.perf_counter() - t1
-        self.stats["send_s"] += dt
-        if self._h_phase is not None:
-            self._h_phase["send"].observe(dt)
 
     # -- membership (joint-consensus conf changes, ISSUE 11) -------------------
 
@@ -3130,6 +3160,7 @@ class MultiRaftMember:
                     break
                 rem = deadline - time.monotonic()
                 if rem <= 0:
+                    self.stats["read_timeouts"] += 1
                     raise TimeoutError(
                         f"group {group}: ReadIndex quorum not confirmed")
                 self._read_cv.wait(rem)
@@ -3137,6 +3168,7 @@ class MultiRaftMember:
             while self.applied_index[group] < idx:
                 rem = deadline - time.monotonic()
                 if rem <= 0:
+                    self.stats["read_timeouts"] += 1
                     raise TimeoutError(
                         f"group {group}: apply lagging read index {idx}")
                 self._read_cv.wait(rem)

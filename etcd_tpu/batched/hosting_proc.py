@@ -188,14 +188,14 @@ class AdminServer:
         if op == "prof_reset":
             for k in list(m.stats):
                 m.stats[k] = 0 if isinstance(m.stats[k], int) else 0.0
-            if m.rn.prof:
-                for k in list(m.rn.prof):
-                    m.rn.prof[k] = 0
+            for k in list(m.rn.phase_total):
+                m.rn.phase_total[k] = 0
             return {"ok": True}
         if op == "prof":
+            # The member pipeline's stats plus the rawnode's cumulative
+            # seconds per advance_round span (rn_stage, rn_step, ...).
             st = dict(m.stats)
-            if m.rn.prof:
-                st.update({f"rn_{k}": v for k, v in m.rn.prof.items()})
+            st.update({f"rn_{k}": v for k, v in m.rn.phase_total.items()})
             return {"ok": True, "stats": st}
         if op == "stats":
             # Loss/error observability (ISSUE 2 satellite): member
@@ -241,11 +241,23 @@ class AdminServer:
             if m.tracer is None:
                 return {"err": "tracing disabled (start the member "
                                "with --trace / ETCD_TPU_TRACE=1)"}
+            # "rounds": the member's round-span ring (obs.spans), so
+            # that the merge can place a proposal's hops inside the
+            # rounds that carried them (same clock; a sampled
+            # proposal's stage stamp is its round's rawnode.stage
+            # start).
+            from ..obs import spans
+
             if req.get("dump"):
-                path = m.tracer.dump(reason=req.get("reason", "admin"))
+                reason = req.get("reason", "admin")
+                path = m.tracer.dump(reason=reason)
                 return {"ok": True, "path": path,
-                        "spans": m.tracer.span_count()}
-            return {"ok": True, "payload": m.tracer.to_payload()}
+                        "spans": m.tracer.span_count(),
+                        "rounds_path": spans.DEFAULT.dump(
+                            m.id, reason, m.tracer.dump_dir)}
+            payload = m.tracer.to_payload()
+            payload["rounds"] = spans.DEFAULT.to_payload(m.id)
+            return {"ok": True, "payload": payload}
         if op == "fleet":
             # Fleet observatory (obs/fleet.py): inline rollup of the
             # latest device SummaryFrame — leader balance, top-K

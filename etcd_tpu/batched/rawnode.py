@@ -29,7 +29,6 @@ committed entries, then calls ``advance()``.
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 from collections import deque
@@ -41,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..analysis.sentinels import warm_guard
+from ..obs import spans
 from ..raft.types import (
     Entry,
     EntryType,
@@ -120,6 +120,15 @@ class RowRestore:
 
 _EMPTY_I8 = np.empty(0, np.int64)
 
+# The spans of advance_round in order (names under "rawnode."), and
+# those that make up the phase the code has always called step.
+PHASES = ("stage_lock", "stage", "edits", "h2d", "dispatch", "fence",
+          "d2h", "extract", "collect")
+STEP_PHASES = ("edits", "h2d", "dispatch", "fence", "d2h")
+# The pure-Python phases: wall less thread CPU there is the interpreter
+# lock or a mutex (``round.offcpu_pct``), so their CPU time is read.
+CPU_PHASES = frozenset(("stage", "extract", "collect"))
+
 
 class EntryBatch:
     """SoA batch of entry records to persist: parallel numpy arrays
@@ -189,6 +198,13 @@ class BatchedReady:
     # latest_ring() reflects newer rounds and (with auto_compact) the
     # slot may have wrapped to a different entry's term.
     snap_rings: Dict[int, np.ndarray] = field(default_factory=dict)
+    # Rows whose role, as read back this round, left LEADER.
+    leader_losses: int = 0
+    # Set by the hosting layer: the member round's sequence number (the
+    # id its spans share) and the instant the Ready was queued for the
+    # drain worker (start of its member.ready_q span).
+    round: int = -1
+    t_queued: int = 0
 
     def contains_updates(self) -> bool:
         return bool(
@@ -341,25 +357,21 @@ class BatchedRawNode:
         # In-flight round (between advance_round and advance).
         self._round: Optional[Tuple] = None
 
-        # Per-round phase wall-seconds, always measured (four
-        # perf_counter reads per round — noise next to a device round):
-        # stage (inbox build), step (device round + host reads),
-        # extract (post-round entry/commit extraction), collect
-        # (outbound block assembly). The hosting layer folds these into
-        # its phase histograms so the BENCH_NOTES phase breakdown is
-        # reproducible from metrics.
+        # Where a round's host time goes (obs/spans.py, always on): each
+        # phase of advance_round is one span of the recorder, and the
+        # two fields below are set from the spans' own clock reads.
+        # phase_last: the last round's wall-seconds in the four phases
+        # the hosting layer folds into its histograms — stage (inbox
+        # build under _lock), step (edits + h2d + dispatch + fence +
+        # d2h: the device round and the host reads around it), extract
+        # (post-round entry/commit extraction), collect (outbound block
+        # assembly). phase_total: cumulative seconds by span name
+        # (PHASES, plus "step" and the round count), read by the admin
+        # prof op and tools/hosted_bench.
         self.phase_last: Dict[str, float] = {
             "stage": 0.0, "step": 0.0, "extract": 0.0, "collect": 0.0}
-        # Opt-in cumulative profile (ETCD_TPU_PROF=1): same keys plus a
-        # round counter and the staging-lock acquire wait (stage_lock,
-        # a subset of stage: time spent waiting for _lock against
-        # proposer/transport threads — convoy, not work), read by
-        # benches/BENCH_NOTES captures.
-        self.prof: Optional[Dict[str, float]] = (
-            {"stage": 0.0, "stage_lock": 0.0, "step": 0.0,
-             "extract": 0.0, "collect": 0.0, "rounds": 0}
-            if os.environ.get("ETCD_TPU_PROF") else None
-        )
+        self.phase_total: Dict[str, float] = {
+            **{p: 0.0 for p in PHASES}, "step": 0.0, "rounds": 0}
 
         # Telemetry plane (cfg.telemetry): the round returns an extra
         # frame; advance_round fetches it with the other host reads and
@@ -767,21 +779,36 @@ class BatchedRawNode:
     # -- the round -------------------------------------------------------------
 
     def advance_round(self) -> BatchedReady:
+        """One round: stage, run the device round, read back, extract.
+        Each phase is one span of the round-span recorder (PHASES;
+        under the hosting layer they are children of ``member.round``
+        and take its ``(member, round)``); ``phase_last`` and
+        ``phase_total`` are set from the spans' own clock reads."""
+        with spans.phases("rawnode.", 0, self.phase_total["rounds"],
+                          cpu=CPU_PHASES) as ph:
+            rd = self._advance_round(ph)
+        dur, tot, last = ph.dur, self.phase_total, self.phase_last
+        for p in PHASES:
+            tot[p] += dur[p] / 1e9
+        last["step"] = sum(dur[p] for p in STEP_PHASES) / 1e9
+        tot["step"] += last["step"]
+        tot["rounds"] += 1
+        for p in ("stage", "extract", "collect"):
+            last[p] = dur[p] / 1e9
+        return rd
+
+    def _advance_round(self, ph: "spans.Phases") -> BatchedReady:
         assert self._round is None, "previous round not advanced"
         cfg = self.cfg
         r, e, w = cfg.num_replicas, cfg.max_ents_per_msg, cfg.window
-        prof = self.prof
         tracer = self.tracer
-        # Trace stamps use monotonic_ns (the tracer's clock domain, NOT
-        # perf_counter): stage = staging begins, dispatch = device
-        # round dispatched, extract = device done / host extraction.
-        tr_stage = time.monotonic_ns() if tracer is not None else 0
-        t0 = time.perf_counter()
-
+        # The tracer's stage / dispatch / extract stamps are the starts
+        # of the rawnode.stage / .h2d / .extract spans (one clock, one
+        # reading: a sampled proposal joins its round by the instant).
+        ph.next("stage_lock")
         self._lock.acquire()
-        if prof is not None:
-            prof["stage_lock"] += time.perf_counter() - t0
         try:
+            tr_stage = ph.next("stage").t0
             inbox = self._build_inbox()
             ticks = self._ticks > 0
             self._ticks = np.maximum(self._ticks - 1, 0)
@@ -815,11 +842,7 @@ class BatchedRawNode:
             )
         finally:
             self._lock.release()
-        t1 = time.perf_counter()
-        self.phase_last["stage"] = t1 - t0
-        if prof is not None:
-            prof["stage"] += t1 - t0
-        t0 = t1
+        ph.next("edits")
 
         # Host-staged device-state edits (membership masks, ring-floor
         # compaction, bcastAppend pokes), applied here on the round
@@ -913,7 +936,7 @@ class BatchedRawNode:
                         del self.plane_lessor[k]
                     for kb, exp in im[8]:
                         self.plane_lessor[(int(r2), kb)] = exp
-        tr_dispatch = time.monotonic_ns() if tracer is not None else 0
+        tr_dispatch = ph.next("h2d").t0
         # Host->device staging happens OUTSIDE the transfer guard (it
         # is the intended, bulk transfer of the round); the guarded
         # region below is then pure warm device dispatch, where any
@@ -924,6 +947,7 @@ class BatchedRawNode:
             self._dev(props_n), self._dev(iso),
             self._dev(transfer), self._dev(read_req),
         )
+        ph.next("dispatch")
         with warm_guard(self._wkey_step):
             step_out = self._step(self.state, inbox, *dev_in)
             st, outbox, aux = step_out[:3]
@@ -937,7 +961,9 @@ class BatchedRawNode:
             words_d, simple_d, cplx_d = pack_outbox(outbox, self._slots_j)
 
         # Device→host reads: one np.asarray per buffer after one fence.
+        ph.next("fence")
         jax.block_until_ready(st.term)
+        ph.next("d2h")
         (term, vote, commit, last, role, lead, snap_i, snap_t, ring,
          rd_seq, rd_idx, rd_ready,
          mid_seq, mid_idx, mid_ready, last_tick, lease_tk) = [
@@ -983,13 +1009,11 @@ class BatchedRawNode:
             self.last_fleet = fleet_vec
             if self.fleet_hub is not None:
                 self.fleet_hub.ingest_round(fleet_vec)
-        tr_extract = time.monotonic_ns() if tracer is not None else 0
-        t1 = time.perf_counter()
-        self.phase_last["step"] = t1 - t0
-        if prof is not None:
-            prof["step"] += t1 - t0
-        t0 = t1
+        tr_extract = ph.next("extract").t0
 
+        # Rows that left LEADER: the role read back against the mirror
+        # of the round before.
+        lost = int(((self.m_role == LEADER) & (role != LEADER)).sum())
         term = term.astype(np.int64)
         vote = vote.astype(np.int64)
         commit = commit.astype(np.int64)
@@ -1160,21 +1184,12 @@ class BatchedRawNode:
                         tracer.stamp_many(traced_commit, "commit",
                                           tr_extract)
 
-            t1 = time.perf_counter()
-            self.phase_last["extract"] = t1 - t0
-            if prof is not None:
-                prof["extract"] += t1 - t0
-            t0 = t1
-
             # -- outbound messages (MsgApp payloads come from the arena)
+            ph.next("collect")
             msg_block, messages = self._collect_messages(
                 words, simple, cplx, outbox
             )
-            t1 = time.perf_counter()
-            self.phase_last["collect"] = t1 - t0
-            if prof is not None:
-                prof["collect"] += t1 - t0
-                prof["rounds"] += 1
+            ph.end()
 
         must_sync = bool(
             entries
@@ -1232,6 +1247,7 @@ class BatchedRawNode:
             snap_rings=snap_rings,
             traced_entries=traced_entries,
             traced_commit=traced_commit,
+            leader_losses=lost,
         )
 
     def advance(self) -> None:

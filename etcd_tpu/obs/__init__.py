@@ -13,6 +13,12 @@ Pieces:
 
 * ``tracer.Tracer`` — lock-cheap per-member span collector with a
   bounded ring (drops are counted on pkg.metrics, never silent).
+* ``spans`` — the always-on round-span recorder: every host phase of
+  a member round and of an engine call with wall and thread-CPU time,
+  parent and ``(member, round)``, in a bounded ring per thread and as a
+  ``TraceAnnotation`` of any open profiler session (so the spans lie
+  beside the device ops on one clock); the program's phase timers and
+  the tracer's stage/dispatch/extract stamps are set from them.
 * ``export`` — Chrome-trace / Perfetto JSON exporter + validator.
 * ``tools/trace_merge.py`` — joins per-member dumps into one timeline
   with cross-process clock-offset estimation from send/recv pairs.

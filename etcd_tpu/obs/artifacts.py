@@ -35,6 +35,7 @@ KIND_FLIGHTREC = "flightrec"
 KIND_TRACERING = "tracering"
 KIND_FLEETHEAT = "fleetheat"
 KIND_RWGRID = "rwgrid"  # client-side R/W grid CSVs (tools/rw_heatmaps)
+KIND_ROUNDSPANS = "roundspans"  # round-span rings (obs.spans)
 
 
 def artifact_dir(dump_dir: Optional[str] = None) -> str:

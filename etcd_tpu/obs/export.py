@@ -68,6 +68,35 @@ def span_events(span: Dict, pid, offset_ns: int = 0) -> List[Dict]:
     return events
 
 
+# Round spans (obs.spans) render under their member on lanes of their
+# own, one per writing thread, past any group number.
+ROUND_LANE0 = 1 << 20
+
+
+def round_events(rounds: Dict, pid, offset_ns: int = 0) -> List[Dict]:
+    """Complete events for one member's round-span ring (the payload's
+    ``rounds`` key, ``spans.Recorder.to_payload`` shape): the rounds a
+    proposal's hops fall into, on the tracer's own clock."""
+    fields = rounds.get("fields", ())
+    lanes: Dict[int, int] = {}
+    events: List[Dict] = []
+    for row in rounds.get("spans", ()):
+        sp = dict(zip(fields, row))
+        lane = lanes.setdefault(sp["thread"], ROUND_LANE0 + len(lanes))
+        events.append({
+            "name": sp["name"],
+            "cat": "round",
+            "ph": "X",
+            "ts": (sp["t0"] + offset_ns) / 1e3,
+            "dur": max(sp["t1"] - sp["t0"], 0) / 1e3,
+            "pid": pid,
+            "tid": lane,
+            "args": {"round": sp["round"], "cpu_ns": sp["cpu_ns"],
+                     **(sp["stats"] or {})},
+        })
+    return events
+
+
 def chrome_trace(payloads: Iterable[Dict],
                  offsets_ns: Optional[Dict[str, int]] = None) -> Dict:
     """Build one Chrome-trace object from one or more tracer payloads
@@ -92,6 +121,7 @@ def chrome_trace(payloads: Iterable[Dict],
         })
         for span in payload.get("spans", ()):
             events.extend(span_events(span, pid, off))
+        events.extend(round_events(payload.get("rounds") or {}, pid, off))
     return {
         "traceEvents": events,
         "displayTimeUnit": "ms",

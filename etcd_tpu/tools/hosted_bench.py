@@ -85,7 +85,6 @@ def spawn(mid, raft_ports, admin_ports, data_dir, groups, gen=0,
     ]
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"  # three processes cannot share a chip
-    env["ETCD_TPU_PROF"] = "1"
     if trace:
         # Sample rate shared by all members (the cross-member join
         # requires identical sampling decisions); seed pinned so two
@@ -270,7 +269,7 @@ def main() -> None:
             raise RuntimeError(f"bench failed: {bad}")
         # Per-phase member-round budget (ms/round, averaged over the
         # members): stage/step/extract/collect from the rawnode timers
-        # (ETCD_TPU_PROF is set on the workers), wal/apply/send from
+        # (rn.phase_total, summed from the round spans), wal/apply/send from
         # the member pipeline stats — the BENCH_NOTES phase table,
         # recorded in the artifact instead of ad-hoc profiling.
         phase_ms = {}

@@ -1,0 +1,300 @@
+"""The span layer's side of the benchmark (ISSUE 24): the sharing-out of
+device idle gaps among host spans on hand-made intervals and on the
+recorded chip trace, the parked span metrics against the contract, and
+each new reader on a tiny run."""
+
+import gzip
+import json
+import os
+import shutil
+import time
+
+import pytest
+
+from benchmark import harness
+from benchmark.readers import spans as span_readers
+from benchmark.reduce.gaps import (
+    UNSPANNED,
+    idle_gaps,
+    innermost,
+    read_xplane,
+    reduce_gaps,
+    share_out,
+    table,
+)
+from benchmark.reduce.trace import MIN_GAP_NS, _union, reduce_trace
+
+from .test_contract import NAME, SOURCES, UNIT, WITH_PARKED
+from .util import REPO, _edit, bench, tiny_root
+
+# 0.3 s of a G=8 served cluster under 16 putting clients on the chip
+# (TPU v5 lite): the trace ``tools/round_gaps.py`` kept of
+# ``served1k-r3.put``, seed 24, 3 s, with the cell's files cut to 8
+# groups, 16 clients and a 0.3 s trace (my chip run, PR 24), gzipped.
+SPAN_XPLANE = os.path.join(REPO, "artifacts", "tpu_r24_spans",
+                           "g8_served_put.xplane.pb.gz")
+LIVE = ["engine.dispatch_ms", "engine.late_ms", "setup.engine_init_s",
+        "setup.elect_s", "setup.first_scan_s"]
+
+
+def served_spans() -> dict:
+    with open(os.path.join(REPO, "benchmark", "parked",
+                           "served_spans.json")) as f:
+        return json.load(f)
+
+
+PARKED = served_spans()["per_layer"]
+
+# -- the sharing-out, on hand-made intervals -------------------------------------
+
+ROUND = [(0, 100, "member.round"), (10, 30, "rawnode.stage"),
+         (30, 50, "rawnode.h2d"), (60, 90, "rawnode.extract"),
+         (120, 130, "member.idle_wait")]
+DRAIN = [(0, 40, "member.wal"), (20, 40, "member.fsync")]
+
+CASES = {
+    "nested spans, one thread": (
+        [(20, 70)], {1: ROUND},
+        {"rawnode.stage": 10, "rawnode.h2d": 20, "member.round": 10,
+         "rawnode.extract": 10}),
+    "two threads share each instant": (
+        [(20, 70)], {1: ROUND, 2: DRAIN},
+        {"rawnode.stage": 5, "member.fsync": 10, "rawnode.h2d": 15,
+         "member.round": 10, "rawnode.extract": 10}),
+    "a gap under no span": (
+        [(200, 210)], {1: ROUND, 2: DRAIN}, {UNSPANNED: 10}),
+    "a gap half under a span": (
+        [(95, 125)], {1: ROUND},
+        {"member.round": 5, UNSPANNED: 20, "member.idle_wait": 5}),
+    "no host thread at all": ([(0, 7)], {}, {UNSPANNED: 7}),
+}
+
+
+@pytest.mark.parametrize("case", CASES, ids=list(CASES))
+def test_share_out(case):
+    gaps, threads, want = CASES[case]
+    total, per_gap = share_out(gaps, threads)
+    assert total == pytest.approx(want)
+    assert len(per_gap) == len(gaps)
+    for (a, b), cover in zip(gaps, per_gap):
+        assert sum(cover.values()) == pytest.approx(b - a)
+
+
+def test_share_out_adds_gaps_up():
+    gaps = [(20, 70), (95, 125), (200, 210)]
+    total, per_gap = share_out(gaps, {1: ROUND, 2: DRAIN})
+    assert sum(total.values()) == pytest.approx(90)
+    for name in total:
+        assert total[name] == pytest.approx(
+            sum(c.get(name, 0) for c in per_gap))
+
+
+def test_innermost_flattens_a_call_stack():
+    assert innermost(ROUND) == [
+        (0, 10, "member.round"), (10, 30, "rawnode.stage"),
+        (30, 50, "rawnode.h2d"), (50, 60, "member.round"),
+        (60, 90, "rawnode.extract"), (90, 100, "member.round"),
+        (120, 130, "member.idle_wait")]
+    # A child that closes a tick after its parent (two clock reads)
+    # still gives stretches that do not overlap.
+    flat = innermost([(0, 10, "a"), (5, 11, "b")])
+    assert flat == [(0, 5, "a"), (5, 11, "b")]
+
+
+def test_idle_gaps_are_reduce_traces_gaps():
+    ops = [(0.0, 100.0, "%fusion.1 = f32[] fusion()"),
+           (100.5, 50.0, "%copy.2 = f32[] copy()"),  # under MIN_GAP_NS
+           (5000.0, 10.0, "%copy.3 = f32[] copy()")]
+    assert MIN_GAP_NS > 0.5
+    assert idle_gaps(ops) == [(150.5, 5000.0, "copy")]
+
+
+# -- the extraction, on the recorded chip trace ------------------------------------
+
+
+@pytest.fixture(scope="module")
+def chip(tmp_path_factory):
+    if not os.path.exists(SPAN_XPLANE):
+        pytest.skip("artifacts/tpu_r24_spans is not in this checkout")
+    path = str(tmp_path_factory.mktemp("xplane") / "g8.xplane.pb")
+    with gzip.open(SPAN_XPLANE, "rb") as src, open(path, "wb") as dst:
+        shutil.copyfileobj(src, dst)
+    return reduce_gaps(path, top=5), reduce_trace(path, top=10 ** 6)
+
+
+def test_chip_trace_holds_the_spans_on_the_devices_clock(chip):
+    devices, threads = read_xplane(chip[0]["xplane"])
+    assert len(devices) == 1 and devices[0]
+    names = {n for evs in threads.values() for _s, _e, n in evs}
+    assert {"member.round", "rawnode.h2d", "rawnode.dispatch",
+            "rawnode.fence", "rawnode.d2h", "member.wal",
+            "member.apply"} <= names
+    # The two planes share one clock: the device is busy for 1.08 ms of
+    # these 300, and all of it but the round the trace began in lies
+    # between some member's dispatch and the end of its fence.
+    _busy, merged = _union([(s, s + d) for s, d, _n in devices[0]])
+    fed = []
+    for evs in threads.values():
+        fences = sorted(e for e in evs if e[2] == "rawnode.fence")
+        for d in (e for e in evs if e[2] == "rawnode.dispatch"):
+            after = [f for f in fences if f[0] >= d[1] - 1]
+            if after:
+                fed.append((d[0], after[0][1]))
+    inside = sum(1 for a, b in merged
+                 if any(s <= a and b <= e for s, e in fed))
+    assert len(fed) == 15 and len(merged) == 4644
+    assert inside / len(merged) > 0.95
+
+
+def test_every_gap_second_of_the_chip_trace_is_attributed(chip):
+    gaps, red = chip
+    assert gaps["devices"] == red["devices"] == 1
+    assert (gaps["gaps"], gaps["host_threads"], gaps["host_spans"]) == (
+        31, 6, 210)
+    assert gaps["gap_s"] == pytest.approx(0.244485816)
+    assert gaps["gap_s"] == pytest.approx(
+        sum(v for _n, v in red["idle_gaps"]), rel=1e-9)
+    assert sum(gaps["by_span_s"].values()) == pytest.approx(gaps["gap_s"])
+    assert 0.0 <= gaps["unspanned_pct"] < 5.0
+    for row in gaps["longest"]:
+        assert sum(row["spans_ms"].values()) == pytest.approx(row["ms"])
+    assert "| `" in table(gaps)
+
+
+# -- the parked entries against the contract ---------------------------------------
+
+
+def test_live_entries_are_appended_and_nothing_else_changed():
+    names = [m["name"] for m in bench()["per_layer"]]
+    assert names[-len(LIVE):] == LIVE
+    assert all(m["source"] == "program_span"
+               for m in bench()["per_layer"][-len(LIVE):])
+
+
+@pytest.mark.parametrize("m", PARKED, ids=lambda m: m["name"])
+def test_parked_span_metric_entry(m):
+    assert set(served_spans()) == {"note", "per_layer"}
+    assert set(m) == {"name", "unit", "better", "source", "layer", "moves"}
+    assert NAME.match(m["name"]) and UNIT.match(m["unit"])
+    assert m["better"] in ("lower", "higher") and m["source"] in SOURCES
+    assert m["source"] in ("program_span", "program_counter")
+    assert m["moves"] in {e["name"] for e in WITH_PARKED["end_to_end"]}
+    assert m["name"] not in {x["name"] for x in WITH_PARKED["per_layer"]}
+    known = {x["layer"] for x in WITH_PARKED["per_layer"]} | {"ReadIndex"}
+    assert m["layer"] in known
+    with open(os.path.join(REPO, "benchmark", "layer_metrics",
+                           m["name"] + ".json")) as f:
+        spec = json.load(f)
+    assert (spec["name"], spec["unit"], spec["layer"], spec["moves"]) == (
+        m["name"], m["unit"], m["layer"], m["moves"])
+    mod, _, fn = spec["reader"].partition(".")
+    assert mod == "spans" and callable(getattr(span_readers, fn))
+
+
+# -- each new reader on a tiny run ---------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def root(tmp_path_factory):
+    """``tiny_root`` with the span entries added the way ``add_parked``
+    adds ``served.json``."""
+    dst = tiny_root(str(tmp_path_factory.mktemp("spans")))
+    _edit(os.path.join(dst, "BENCHMARK.json"),
+          lambda b: b["per_layer"].extend(PARKED))
+    return dst
+
+
+def drive(root, workload, seed, seconds=0.8):
+    cell = harness.Cell(root, workload)
+    ctx, checks = harness.measure(cell, seed, seconds, False,
+                                  time.perf_counter(), require_tpu=False)
+    assert harness.verdict(checks), [c for c in checks if not c.ok]
+    layer = harness.per_layer_metrics(cell, ctx)
+    harness.refuse_bad_values(layer)
+    return cell, ctx, layer
+
+
+@pytest.fixture(scope="module")
+def put_run(root):
+    return drive(root, "served1k-r3.put", 24)
+
+
+@pytest.mark.parametrize("m", PARKED, ids=lambda m: m["name"])
+def test_parked_reader_on_a_tiny_put_run(put_run, m):
+    _cell, _ctx, layer = put_run
+    got = layer[m["name"]]
+    assert got["unit"] == m["unit"] and got["value"] >= 0.0
+    if m["unit"] == "%":
+        assert 0.0 <= got["value"] <= 100.0
+
+
+def test_span_metrics_agree_with_the_counters_they_sit_beside(put_run):
+    _cell, ctx, layer = put_run
+    w = span_readers._served(ctx)
+    c = ctx["raw"]["counters"]
+    for m, a, b in zip(w["members"], c["before"]["members"],
+                       c["after"]["members"]):
+        # Exactly the window's rounds, by the counter's own numbers.
+        assert [s.round for s in m["member.round"]] == list(
+            range(a["rounds"], b["rounds"]))
+        secs = sum(s.t1 - s.t0 for s in m["member.round"]) / 1e9
+        assert secs == pytest.approx(b["round_s"] - a["round_s"])
+    step = sum(layer[k]["value"] for k in (
+        "round.h2d_ms", "round.dispatch_ms", "round.fence_ms",
+        "round.d2h_ms"))
+    assert 0.0 < step < layer["member.round_ms"]["value"] * 3
+    assert layer["read.unconfirmed"]["value"] == 0.0
+    assert layer["member.leader_losses"]["value"] == 0.0
+    assert layer["read.timeouts"]["value"] == 0.0
+    assert 1.0 <= layer["member.ready_q_depth_max"]["value"] <= 4.0
+
+
+def test_lread_run_counts_its_read_batches(root):
+    _cell, ctx, layer = drive(root, "served1k-r3.lread", 25)
+    opened = span_readers._counter(ctx, "read_opened")
+    assert sum(b - a for a, b in opened) > 0
+    assert layer["read.unconfirmed"]["value"] >= 0.0
+    assert 0.0 <= layer["member.idle_wait_pct"]["value"] <= 100.0
+
+
+def test_live_readers_on_a_tiny_engine_run(root):
+    _cell, ctx, layer = drive(root, "engine64k-r3.append", 26, 0.4)
+    assert set(LIVE) <= set(layer)
+    e = span_readers._engine(ctx)
+    assert len(e["window"]) == ctx["raw"]["calls"]
+    assert len(e["scans"]) == ctx["raw"]["calls"] + 2  # settle, warm-up
+    assert [s.stats["rounds"] for s in e["scans"]] == (
+        [ctx["raw"]["rounds_per_call"]] * len(e["scans"]))
+    assert len(e["init"]) == 1 and len(e["elect"]) == 1
+    assert layer["engine.dispatch_ms"]["value"] <= (
+        ctx["raw"]["call_s_median"] * 1e3)
+    assert layer["engine.late_ms"]["value"] >= 0.0
+    setup = sum(layer[k]["value"] for k in LIVE[2:])
+    assert 0.0 < setup < ctx["raw"]["setup_s"]
+
+
+def test_a_program_without_the_recorder_gives_no_span_metric(
+        put_run, monkeypatch):
+    """The parent commit has no ``etcd_tpu.obs.spans``: every reader
+    then returns None and the line leaves the metric out."""
+    import builtins
+
+    real = builtins.__import__
+
+    def no_spans(name, globals=None, locals=None, fromlist=(), level=0):
+        if name == "etcd_tpu.obs" and "spans" in (fromlist or ()):
+            raise ImportError("No module named 'etcd_tpu.obs.spans'")
+        return real(name, globals, locals, fromlist, level)
+
+    monkeypatch.setattr(builtins, "__import__", no_spans)
+    cell, ctx, _layer = put_run
+    bare = {k: v for k, v in ctx.items() if not k.startswith("_")}
+    layer = harness.per_layer_metrics(cell, bare)
+    assert "member.round_ms" in layer
+    assert not {m["name"] for m in PARKED} & set(layer)
+    eng = dict(bare, raw=dict(bare["raw"], calls=5, traced_calls=2))
+    for fn in (span_readers.engine_dispatch_ms, span_readers.engine_late_ms,
+               span_readers.setup_engine_init_s,
+               span_readers.setup_elect_s,
+               span_readers.setup_first_scan_s):
+        assert fn(dict(eng)) is None
