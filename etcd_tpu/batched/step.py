@@ -15,9 +15,10 @@ step the protocol for any of these — see SURVEY.md §2.1.
 
 Network model: per round each replica sends at most one message of each
 KIND to each peer, so an inbox is a dense ``[N, R, K]`` slot array and
-routing between instances of the same group is a single transpose over
-the (sender, target) axes — no scatters, no host round-trips. A round
-is one jitted program:
+routing between instances of the same group is an exchange of the
+(sender, target) axes inside the group's R adjacent rows — row shifts
+and selects along N (see route()), no scatters, no host round-trips. A
+round is one jitted program:
 
     deliver (shape-configured: lane scans, merged scans, or the
     scan-free vectorized fold) → tick → control → propose → emit → route
@@ -79,7 +80,8 @@ from .state import (
 # NUM_REQ_KINDS: the deliver shapes' request/response split, the
 # round's response scatter (``out[:, NUM_REQ_KINDS:]`` in
 # _step_round_jit), and route()'s no-op on lane indexes (responses are
-# already placed in their response lane BEFORE the transpose). The
+# already placed in their response lane BEFORE the sender/target
+# exchange, which moves whole [K] lane vectors and never a lane). The
 # msgblock↔step differential test pins the contract
 # (tests/batched/test_msgblock.py), so a drifted call site fails a
 # test instead of silently crossing lanes.
@@ -1686,28 +1688,65 @@ ROUND_PHASE_SCOPES = (
 # -----------------------------------------------------------------------------
 
 
+@functools.lru_cache(maxsize=None)
+def _route_jit(r: int):
+    """The router of an R-replica layout as ONE jitted program (inlined
+    where a caller is itself being traced, e.g. the engine's scan)."""
+
+    def exchange(x):
+        # inbox[n, s] = outbox[n + s - t, t] with t = n % R: the row of
+        # sender slot s in n's own group, and there the column addressed
+        # to t. Per sender column s that is a select, over the R values
+        # of t, of outbox plane [:, t] shifted s - t rows along N. The
+        # R - 1 pad rows at each end are only ever read under a mask
+        # that rejects them (n + s - t stays inside n's group), so no
+        # row leaks across a group, the first and the last included.
+        n = x.shape[0]
+        xp = jnp.pad(x, [(r - 1, r - 1)] + [(0, 0)] * (x.ndim - 1))
+        t = (jnp.arange(n, dtype=I32) % r).reshape(
+            (n,) + (1,) * (x.ndim - 2))
+
+        def shifted(s, tt):
+            lo = r - 1 + s - tt
+            return xp[lo:lo + n, tt]
+
+        planes = []
+        for s in range(r):
+            plane = shifted(s, 0)
+            for tt in range(1, r):
+                plane = jnp.where(t == tt, shifted(s, tt), plane)
+            planes.append(plane)
+        return jnp.stack(planes, axis=1)
+
+    def route(outbox: MsgSlots) -> MsgSlots:
+        with jax.named_scope("raft_route"):
+            return jax.tree.map(exchange, outbox)
+
+    return jax.jit(route, inline=True)
+
+
 def route(cfg: BatchedConfig, outbox: MsgSlots) -> MsgSlots:
     """All-device network: outbox[i, target_slot, k] → inbox[t, sender_slot, k]
-    where i=(g, s) and t=(g, r). With the dense instance layout this is
-    one transpose per field — the ICI-friendly formulation of rafthttp's
-    peer streams (ref: SURVEY.md §5 "Distributed communication backend")."""
-    g, r = cfg.num_groups, cfg.num_replicas
+    where i = g*R + s and t = g*R + r — rafthttp's peer streams (ref:
+    SURVEY.md §5 "Distributed communication backend") as an exchange
+    inside each group's R adjacent rows.
 
-    def tr(x):
-        # [G*R_sender, R_target, K, ...] → [G, R_target, R_sender, K, ...]
-        y = x.reshape((g, r) + x.shape[1:])
-        y = jnp.swapaxes(y, 1, 2)
-        return y.reshape((g * r,) + x.shape[1:])
-
-    with jax.named_scope("raft_route"):
-        inbox = jax.tree.map(tr, outbox)
+    Computed on the layout the round carries, with the instance axis N
+    whole: every inbox plane [:, s] is R row-shifted outbox planes
+    under the mask ``n % R == t`` (R² shift-and-select terms a field,
+    elementwise along N). The group and the replica never get an axis
+    of their own: a ``reshape(G, R, ...) → swapaxes → reshape`` splits
+    N = g*R + s out of the TPU's lane dimension and leaves R alone in
+    the 128 lanes. That spelling is the oracle of
+    tests/batched/test_route.py; this one is bit-identical to it for
+    every R, both ``lanes_minor`` layouts and the narrow dtypes."""
     # Lane indexes pass through untouched: by the inbox lane-order
     # contract (NUM_REQ_KINDS, top of module), emit writes requests
     # into lanes 0..NUM_REQ_KINDS-1 and the round's response scatter
     # has ALREADY placed each response in lane k + NUM_REQ_KINDS of the
     # responder's outbox row for the requester (see _step_round_jit),
-    # so the transpose alone lands everything in its inbox lane.
-    return inbox
+    # so the exchange alone lands everything in its inbox lane.
+    return _route_jit(cfg.num_replicas)(outbox)
 
 
 class TelemetryFrame(NamedTuple):
@@ -1935,7 +1974,7 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
                 sti, out = _emit(cfg, slot, sti)
             # Responses to requests from sender s (request kinds) land
             # in out[s, k + NUM_REQ_KINDS]; they route back by the same
-            # transpose (the inbox lane-order contract, top of module).
+            # exchange (the inbox lane-order contract, top of module).
             out = jax.tree.map(
                 lambda o, rr: o.at[:, NUM_REQ_KINDS:].set(rr),
                 out, req_resps,

@@ -145,11 +145,14 @@ def main() -> int:
             fn, fargs = phase_fns[name]
             seg_s[name] = _time_calls(name, fn, fargs, args.rounds)
             print(f"{name}: {seg_s[name] * 1e3:.3f} ms", flush=True)
-    # route runs on a real outbox (emit's output), like the round does.
+    # route runs on a real outbox (emit's output), like the round does:
+    # the program engine.step_round dispatches (row shifts and selects
+    # along N). Inside a scan it is inlined and fuses with its
+    # neighbours, so this segment is an upper bound there.
     _st2, outbox = phase_fns["emit"][0](slots, st)
-    route_fn = jax.jit(lambda ob: step_mod.route(cfg, ob))
-    seg_s["route"] = _time_calls("route", route_fn, (outbox,),
-                                 args.rounds)
+    seg_s["route"] = _time_calls(
+        "route", lambda ob: step_mod.route(cfg, ob), (outbox,),
+        args.rounds)
     print(f"route: {seg_s['route'] * 1e3:.3f} ms", flush=True)
     # pack_outbox: the hosted collect's on-device half (PR 6).
     seg_s["pack_outbox"] = _time_calls(
