@@ -143,10 +143,18 @@ class Driver:
         return out
 
     def sample(self, load) -> List[int]:
+        """Seeded groups for the reference to follow: first one of each
+        leader slot's class (the comparison holds every group equal to
+        its class, so each class stands compared with the reference),
+        then whichever come next in the seed's order."""
         rng = np.random.default_rng([self.seed, 0xE602])
         n = min(int(self.config.get("shadow_groups", 32)), self.groups)
-        return sorted(int(g) for g in rng.choice(
-            self.groups, size=n, replace=False))
+        order = rng.permutation(self.groups)
+        _slots, first = np.unique(load["leader_slots"][order],
+                                  return_index=True)
+        first.sort()
+        picked = np.concatenate([order[first], np.delete(order, first)])
+        return sorted(int(g) for g in picked[:n])
 
     def check(self, load, raw, control: bool = False) -> List[Check]:
         state = self.read_state()

@@ -9,7 +9,8 @@ none.
 
 Order of a run: device check -> compile cache -> driver set-up (build,
 load, warm-up; all of it ``setup_s``) -> the generator's window ->
-the driver's comparisons, outside the window -> the result line.
+the driver's comparisons, outside the window -> the result line, and
+each number compared beside its limit as the last lines of stderr.
 """
 
 from __future__ import annotations
@@ -238,8 +239,11 @@ def refuse_bad_values(metrics: Dict[str, dict]) -> None:
 
 
 def measure(cell: Cell, seed: int, seconds: float, trace: bool,
-            t_start: float, require_tpu: bool = True):
-    """Set-up, window and comparisons of one run: (context, checks)."""
+            t_start: float, require_tpu: bool = True,
+            workdir: Optional[str] = None):
+    """Set-up, window and comparisons of one run: (context, checks).
+    A caller that gives the ``workdir`` keeps it, and the trace in it,
+    until it has read what it wants (``run_cell``: the readers)."""
     device = check_device(cell.chips, require_tpu)
     import jax
 
@@ -251,7 +255,9 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool,
 
     driver_mod = cell.module("drivers", cell.config["driver"])
     gen = cell.module("generators", cell.traffic["generator"])
-    workdir = tempfile.mkdtemp(prefix="bench_")
+    own_workdir = workdir is None
+    if own_workdir:
+        workdir = tempfile.mkdtemp(prefix="bench_")
     probe = Probe(trace, float(cell.traffic.get("trace_s", 3.0)), workdir)
     driver = driver_mod.Driver(cell.config, cell.traffic, seed, workdir)
     try:
@@ -282,17 +288,20 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool,
                         "cache_misses": c1["misses"],
                         "cache_hits": c1["hits"],
                         "compile_s": c1["compile_s"]},
-            "memory_peak_bytes": peak, "trace": None,
+            "memory_peak_bytes": peak, "trace": None, "gaps": None,
         }
         if trace:
+            from .reduce.gaps import reduce_gaps
             from .reduce.trace import reduce_trace
 
             ctx["trace"] = reduce_trace(probe.dir, window_s=probe.traced_s)
+            ctx["gaps"] = reduce_gaps(ctx["trace"]["xplane"])
         return ctx, checks
     finally:
         probe.stop()
         driver.close()
-        shutil.rmtree(workdir, ignore_errors=True)
+        if own_workdir:
+            shutil.rmtree(workdir, ignore_errors=True)
 
 
 def per_layer_metrics(cell: Cell, ctx: dict) -> Dict[str, dict]:
@@ -325,7 +334,15 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     """Run one cell once; returns the result object (the last line)."""
     t_start = time.perf_counter() if t_start is None else t_start
     cell = Cell(root, workload)
-    ctx, checks = measure(cell, seed, seconds, trace, t_start, require_tpu)
+    with tempfile.TemporaryDirectory(
+            prefix="bench_", ignore_cleanup_errors=True) as workdir:
+        ctx, checks = measure(cell, seed, seconds, trace, t_start,
+                              require_tpu, workdir)
+        return _result(cell, ctx, checks, trace)
+
+
+def _result(cell: Cell, ctx: dict, checks: List[Check],
+            trace: bool) -> dict:
     raw = ctx["raw"]
     device = dict(ctx["device"], memory_peak_bytes=ctx["memory_peak_bytes"])
     result = {"correct": verdict(checks),
@@ -341,14 +358,25 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
             busy_s=red["busy_s"], window_s=red["window_s"],
             idle_share_pct=red["idle_share_pct"],
             scope_s=red["scope_s"], modules=red["modules"])
+        from .reduce.gaps import span_rows
+
+        gaps = ctx["gaps"]
+        say("gaps", gaps=gaps["gaps"], gap_s=gaps["gap_s"],
+            by_span_s=gaps["by_span_s"], host_spans=gaps["host_spans"],
+            clock_lead_ms=gaps["clock_lead_ms"],
+            longest=gaps["longest"][:3])
         metrics = per_layer_metrics(cell, ctx)
+        # Device idle seconds by the host span open meanwhile.
         result["breakdown"] = {"device_ops": red["device_ops"],
-                               "idle_gaps": red["idle_gaps"]}
+                               "idle_gaps": span_rows(gaps)}
     else:
         metrics = end_to_end_metrics(cell, ctx)
     refuse_bad_values(metrics)
     result["metrics"] = metrics
     result["device"] = device
+    # Each number compared beside its limit; last in the line.
+    result["checks"] = {c.name: {"value": c.value, "limit": c.limit}
+                        for c in checks}
     return result
 
 
@@ -368,5 +396,10 @@ def main(argv: List[str], t_start: float, root: str) -> int:
     except BenchmarkError as e:
         print(f"benchmark: {e}", file=sys.stderr, flush=True)
         return 1
+    for name, c in result["checks"].items():
+        print(f"benchmark: check {name} = {c['value']} (limit "
+              f"{c['limit']})", file=sys.stderr)
+    print(f"benchmark: correct = {result['correct']}", file=sys.stderr,
+          flush=True)
     print(json.dumps(result), flush=True)
     return 0

@@ -6,16 +6,28 @@ The program's round-span recorder (``etcd_tpu/obs/spans.py``) opens a
 the program runs holds them as events of the host plane, one line a
 thread, on the clock of the device planes. From one xplane this gives:
 
-* the idle gaps of the device planes, as ``reduce/trace.py`` defines
-  them (between the merged intervals of the ``XLA Ops`` line, at least
-  ``MIN_GAP_NS`` long; its ``_union`` and constants are imported);
+* the idle gaps of the device planes, as ``reduce/trace.py`` lists
+  them (``trace.idle_gaps``: between the merged intervals of the
+  ``XLA Ops`` line, at least ``MIN_GAP_NS`` long);
 * the seconds of device idle per span name: at every instant of a gap
   each host thread that has a span open counts with its *innermost*
   one, the instant is shared equally among those threads, and an
   instant under no span is ``unspanned``;
 * the longest gaps, each with the spans that covered it.
 
-``share_out`` and ``innermost`` are pure functions of interval lists.
+The host plane's clock leads the device planes' by a constant that
+differs from one profiler session to the next (0.37 ms in the kept
+served trace, 1.44 ms in an engine trace: a scan seemed to start on the
+device before the host had opened the span that enqueues it). No
+program starts before the host enqueues it, so the lead is the largest
+(host ``DoEnqueueProgram`` start - device ``XLA Modules`` start) over
+the programs of the trace, paired in order (``host_clock_lead``); the
+spans are moved back by it before the gaps are shared out. Where the
+two counts differ nothing can be paired, and the spans stay as they
+are (``clock_lead_ms`` null).
+
+``share_out``, ``innermost`` and ``host_clock_lead`` are pure functions
+of interval lists.
 
     python3 -m benchmark.reduce.gaps <trace dir or .xplane.pb>
 """
@@ -25,11 +37,12 @@ from __future__ import annotations
 import bisect
 import json
 import sys
-from typing import Dict, Hashable, List, Sequence, Tuple
+from typing import Dict, Hashable, List, Optional, Sequence, Tuple
 
-from .trace import MIN_GAP_NS, OPS_LINE, _union, find_xplane, op_kind
+from .trace import MODULES_LINE, OPS_LINE, find_xplane, idle_gaps
 
 HOST_PLANE = "/host:CPU"
+ENQUEUE_EVENT = "DoEnqueueProgram"
 SPAN_PREFIXES = ("engine.", "member.", "rawnode.")
 UNSPANNED = "unspanned"
 
@@ -98,44 +111,54 @@ def share_out(gaps: Sequence[Interval],
     return total, per_gap
 
 
-def idle_gaps(ops: Sequence[Tuple[float, float, str]]
-              ) -> List[Tuple[float, float, str]]:
-    """(start, end, kind of the op that ended before it) of every idle
-    gap of one device's ops (start, duration, name)."""
-    _busy, merged = _union([(s, s + d) for s, d, _n in ops])
-    ends = sorted((s + d, n) for s, d, n in ops)
-    end_times = [e for e, _n in ends]
-    out = []
-    for (_s0, e0), (s1, _e1) in zip(merged, merged[1:]):
-        if s1 - e0 < MIN_GAP_NS:
-            continue
-        i = bisect.bisect_right(end_times, e0 + 1e-3) - 1
-        out.append((e0, s1, op_kind(ends[max(i, 0)][1])))
-    return out
+def host_clock_lead(module_starts: Sequence[float],
+                    enqueue_starts: Sequence[float]) -> Optional[float]:
+    """By how much the host plane's clock leads the device's: the
+    largest (enqueue - start) over the programs, each device program
+    paired with the host's enqueue of the same rank in time (a program
+    that waited for the one before it reads lower, never higher).
+    ``None`` where the counts differ or there is nothing to pair."""
+    if not module_starts or len(module_starts) != len(enqueue_starts):
+        return None
+    return max(h - d for d, h in zip(sorted(module_starts),
+                                     sorted(enqueue_starts)))
 
 
 def read_xplane(path: str):
     """(per device plane its ops, per host thread its span events
-    (start, end, name)) of one trace file."""
+    (start, end, name) on the device's clock, the host clock's lead in
+    ns or ``None``) of one trace file."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
     devices: List[List[Tuple[float, float, str]]] = []
     threads: Dict[Hashable, List[Named]] = {}
+    modules: List[float] = []
+    enqueues: List[float] = []
     for plane in data.planes:
         if plane.name.startswith("/device:TPU:"):
             for line in plane.lines:
                 if line.name == OPS_LINE:
                     devices.append([(e.start_ns, e.duration_ns, e.name)
                                     for e in line.events])
+                elif line.name == MODULES_LINE:
+                    modules.extend(e.start_ns for e in line.events)
         elif plane.name == HOST_PLANE:
             for i, line in enumerate(plane.lines):
-                evs = [(e.start_ns, e.start_ns + e.duration_ns, e.name)
-                       for e in line.events
-                       if e.name.startswith(SPAN_PREFIXES)]
+                evs = []
+                for e in line.events:
+                    if e.name.startswith(SPAN_PREFIXES):
+                        evs.append((e.start_ns, e.start_ns + e.duration_ns,
+                                    e.name))
+                    elif e.name == ENQUEUE_EVENT:
+                        enqueues.append(e.start_ns)
                 if evs:
                     threads[(i, line.name)] = evs
-    return devices, threads
+    lead = host_clock_lead(modules, enqueues)
+    if lead:
+        threads = {k: [(a - lead, b - lead, n) for a, b, n in v]
+                   for k, v in threads.items()}
+    return devices, threads, lead
 
 
 def reduce_gaps(trace_dir_or_file: str, top: int = 10) -> dict:
@@ -144,7 +167,7 @@ def reduce_gaps(trace_dir_or_file: str, top: int = 10) -> dict:
     (as ``reduce_trace`` averages its own)."""
     path = (trace_dir_or_file if trace_dir_or_file.endswith(".pb")
             else find_xplane(trace_dir_or_file))
-    devices, threads = read_xplane(path)
+    devices, threads, lead = read_xplane(path)
     if not devices:
         raise ValueError(f"{path}: no /device:TPU:* plane with ops")
     k = len(devices)
@@ -165,6 +188,7 @@ def reduce_gaps(trace_dir_or_file: str, top: int = 10) -> dict:
         "devices": k,
         "host_threads": len(threads),
         "host_spans": sum(len(v) for v in threads.values()),
+        "clock_lead_ms": None if lead is None else lead / 1e6,
         "gaps": len(rows),
         "gap_s": gap_s,
         "by_span_s": dict(sorted(by_span.items(), key=lambda r: -r[1])),
@@ -177,6 +201,17 @@ def reduce_gaps(trace_dir_or_file: str, top: int = 10) -> dict:
                  cover.items(), key=lambda r: -r[1])}}
             for length, a, after, cover in rows[:top]],
     }
+
+
+def span_rows(red: dict, top: int = 10) -> List[List]:
+    """The result line's ``idle_gaps``: [host span, device idle
+    seconds], longest first, at most ``top``, ``unspanned`` always
+    among them where any second fell under no span."""
+    rows = [[n, v] for n, v in red["by_span_s"].items()]
+    kept = rows[:top]
+    if UNSPANNED in red["by_span_s"] and UNSPANNED not in dict(kept):
+        kept[-1] = [UNSPANNED, red["by_span_s"][UNSPANNED]]
+    return kept
 
 
 def table(red: dict) -> str:

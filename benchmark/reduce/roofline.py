@@ -1,12 +1,19 @@
 """Bytes from shapes, the table of peaks, and a kernel's roofline share.
 
-``route()`` is one transpose of the round's message block: every field
-of the outbox ``[G*R, R, K]`` (``ent_terms`` with a trailing ``[E]``) is
-read once and written once as the inbox. It does no arithmetic, so HBM
+``route()`` is the round's sender/target exchange: every field of the
+outbox ``[G*R, R, K]`` (``ent_terms`` with a trailing ``[E]``) becomes
+the inbox with sender and target swapped inside each group. Since PR 25
+the program does it as a pad of R-1 rows, R*R row-shifted slices
+selected under ``n % R == t`` and a stack, not as a transpose; what the
+exchange *must* move is the same either way, each slot read once and
+written once, and that is the count here. It does no arithmetic, so HBM
 bytes bound it, and its least time is those bytes over the chip's HBM
-bandwidth. The bytes are the algorithm's, from shapes alone — not the
-padded tiles the compiler's layout happens to touch (ROADMAP's hand
-figure, "11.9 GB/s of accessed bytes", counted those).
+bandwidth. The bytes are the algorithm's, from shapes alone (G, R, E of
+the configuration, so the count holds at R=5 as at R=3: 30.5% and 15.2%
+of the roofline on the chip, PERF.md) — not the padded tiles the
+compiler's layout happens to touch (ROADMAP's hand figure, "11.9 GB/s
+of accessed bytes", counted those), and not the second pass the select
+and the stack make over them.
 """
 
 from __future__ import annotations

@@ -8,7 +8,8 @@ What this gives, for the device planes of one trace:
   encloses, so the parts sum to the whole;
 * the busy union of every op interval, the traced window, the idle share;
 * each executed program (``XLA Modules`` line) with its count and seconds;
-* the longest idle gaps, each named by the device op that ended before it.
+* the idle gaps (``idle_gaps``: one list, which ``reduce/gaps.py`` shares
+  out among the host spans) and their seconds.
 
 Times come from ``jax.profiler.ProfileData`` (the events). ProfileData
 does not expose the per-op *metadata* stats, and the ``named_scope`` of
@@ -23,7 +24,7 @@ import bisect
 import glob
 import os
 import re
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
@@ -189,6 +190,23 @@ def _union(intervals: List[Tuple[float, float]]) -> Tuple[float, List]:
     return sum(e - s for s, e in merged), merged
 
 
+def idle_gaps(ops: Sequence[Tuple[float, float, str]]
+              ) -> List[Tuple[float, float, str]]:
+    """(start, end, kind of the op that ended before it) of every idle
+    gap of one device's ops (start, duration, name): the pauses of at
+    least ``MIN_GAP_NS`` between the merged op intervals."""
+    _busy, merged = _union([(s, s + d) for s, d, _n in ops])
+    ends = sorted((s + d, n) for s, d, n in ops)
+    end_times = [e for e, _n in ends]
+    out = []
+    for (_s0, e0), (s1, _e1) in zip(merged, merged[1:]):
+        if s1 - e0 < MIN_GAP_NS:
+            continue
+        i = bisect.bisect_right(end_times, e0 + 1e-3) - 1
+        out.append((e0, s1, op_kind(ends[max(i, 0)][1])))
+    return out
+
+
 def reduce_trace(trace_dir_or_file: str, window_s: Optional[float] = None,
                  top: int = 10) -> dict:
     """Reduce one trace. ``window_s`` is the traced wall window as the
@@ -209,8 +227,7 @@ def reduce_trace(trace_dir_or_file: str, window_s: Optional[float] = None,
     by_scope: Dict[str, float] = {}
     by_op: Dict[str, float] = {}
     modules: Dict[str, List[float]] = {}
-    gaps: Dict[str, List[float]] = {}
-    busy_ns = span_ns = 0.0
+    busy_ns = span_ns = gap_ns = 0.0
     n_ops = 0
     for plane in planes:
         ops: List[Tuple[float, float, str]] = []
@@ -233,24 +250,13 @@ def reduce_trace(trace_dir_or_file: str, window_s: Optional[float] = None,
         total, merged = _union([(s, s + d) for s, d, _n in ops])
         busy_ns += total
         span_ns += merged[-1][1] - merged[0][0]
-        ends = sorted((s + d, n) for s, d, n in ops)
-        # Name each gap by the op whose end opens it.
-        end_times = [e for e, _n in ends]
-        for (_s0, e0), (s1, _e1) in zip(merged, merged[1:]):
-            if s1 - e0 < MIN_GAP_NS:
-                continue
-            i = bisect.bisect_right(end_times, e0 + 1e-3) - 1
-            name = op_kind(ends[max(i, 0)][1])
-            gaps.setdefault("after_" + name, []).append((s1 - e0) / 1e9)
+        gap_ns += sum(b - a for a, b, _after in idle_gaps(ops))
 
     k = len(planes)
     busy_s = busy_ns / 1e9 / k
     span_s = span_ns / 1e9 / k
     window = window_s if window_s else span_s
     leaf_total = sum(by_scope.values()) / k
-    gap_rows = sorted(
-        ((f"{name}__n_{len(v)}__longest_{max(v) * 1e3:.3f}_ms", sum(v) / k)
-         for name, v in gaps.items()), key=lambda r: -r[1])
     return {
         "xplane": path,
         "devices": k,
@@ -268,7 +274,7 @@ def reduce_trace(trace_dir_or_file: str, window_s: Optional[float] = None,
                     for n, v in modules.items()},
         "device_ops": [[n, v / k] for n, v in sorted(
             by_op.items(), key=lambda r: -r[1])[:top]],
-        "idle_gaps": [[n, v] for n, v in gap_rows[:top]],
+        "gap_s": gap_ns / 1e9 / k,
     }
 
 

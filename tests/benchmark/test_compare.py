@@ -98,11 +98,13 @@ def test_no_checks_is_not_correct():
 
 # -- engine ----------------------------------------------------------------------
 
-G, R, W = 4, 3, 32
-SLOTS = np.array([0, 1, 2, 1], np.int32)
+G, W = 4, 32
+# Groups 1 and 3 draw the same leader slot; 3 is not in the sample.
+SLOTS = {3: np.array([0, 1, 2, 1], np.int32),
+         5: np.array([0, 3, 4, 3], np.int32)}
 
 
-def shadow(g, rounds=24, control=False):
+def shadow(r, g, rounds=24, control=False):
     from benchmark.reference.raft import quorum
     from benchmark.reference.raft.logger import DefaultLogger, set_logger
 
@@ -113,11 +115,11 @@ def shadow(g, rounds=24, control=False):
             lambda self, acked: max((acked(v) or 0 for v in self),
                                     default=0))
     try:
-        sh = ShadowCluster(R, heartbeat_timeout=4, group=g,
+        sh = ShadowCluster(r, heartbeat_timeout=4, group=g,
                            deterministic_timeouts=True,
                            auto_compact_window=W, max_ents=4,
                            deliver_shape="merged")
-        lead = int(SLOTS[g])
+        lead = int(SLOTS[r][g])
         sh.round(campaigns=[lead])
         for _ in range(4):
             sh.round()
@@ -128,18 +130,18 @@ def shadow(g, rounds=24, control=False):
         quorum.MajorityConfig.committed_index = sound
 
 
-def engine_state(shadows):
+def engine_state(r, shadows):
     """The engine's arrays as they would be if it equalled the
     reference: built from the reference itself."""
-    n = G * R
+    n = G * r
     st = {f: np.zeros(n, np.int64)
           for f in ("term", "role", "lead", "commit", "last", "snap_index")}
     st["log_term"] = np.zeros((n, W), np.int64)
     st["randomized_timeout"] = np.arange(n)
     for g, sh in enumerate(shadows):
         rows = sh.snapshot_state()
-        for s in range(R):
-            i = g * R + s
+        for s in range(r):
+            i = g * r + s
             (st["term"][i], st["role"][i], st["lead"][i], st["commit"][i],
              st["last"][i]) = rows[s]
             log = sh.log_terms(s)
@@ -149,37 +151,39 @@ def engine_state(shadows):
     return st
 
 
-@pytest.fixture(scope="module")
-def shadows():
-    return [shadow(g) for g in range(G)]
+@pytest.fixture(scope="module", params=[3, 5], ids=["R3", "R5"])
+def shadows(request):
+    """(replicas of a group, the sound reference of each group)."""
+    r = request.param
+    return r, [shadow(r, g) for g in range(G)]
 
 
 def run_engine_checks(st, shadows):
-    return engine_checks(st, G, R, W, SLOTS, [0, 1, 2],
-                         lambda g: shadows[g].snapshot_state(),
-                         lambda g, s: shadows[g].log_terms(s))
+    r, ref = shadows
+    return engine_checks(st, G, r, W, SLOTS[r], [0, 1, 2],
+                         lambda g: ref[g].snapshot_state(),
+                         lambda g, s: ref[g].log_terms(s))
 
 
 def test_engine_sound_state_is_correct(shadows):
-    assert verdict(run_engine_checks(engine_state(shadows), shadows))
+    assert verdict(run_engine_checks(engine_state(*shadows), shadows))
 
 
-def engine_log_differs(st):
-    i = 1 * R + 0
+def engine_log_differs(st, r):
+    i = 1 * r + 0
     st["log_term"][i, int(st["last"][i]) % W] += 1
 
 
-def engine_commit_behind(st):
-    st["commit"][2 * R + 1] -= 1
+def engine_commit_behind(st, r):
+    st["commit"][2 * r + 1] -= 1
 
 
-def engine_group_committed_nothing(st):
-    st["commit"][3 * R:4 * R] = 0
+def engine_group_committed_nothing(st, r):
+    st["commit"][3 * r:4 * r] = 0
 
 
-def engine_class_unequal(st):
-    # Groups 1 and 3 drew the same leader slot; 3 is not in the sample.
-    st["last"][3 * R + 2] += 1
+def engine_class_unequal(st, r):
+    st["last"][3 * r + 2] += 1
 
 
 ENGINE_FAULTS = [engine_log_differs, engine_commit_behind,
@@ -188,16 +192,17 @@ ENGINE_FAULTS = [engine_log_differs, engine_commit_behind,
 
 @pytest.mark.parametrize("fault", ENGINE_FAULTS, ids=lambda f: f.__name__)
 def test_engine_fault_is_not_correct(shadows, fault):
-    st = engine_state(shadows)
-    fault(st)
+    st = engine_state(*shadows)
+    fault(st, shadows[0])
     assert not verdict(run_engine_checks(st, shadows))
 
 
 def test_engine_control_commit_without_quorum_is_not_correct(shadows):
     """The control: the reference with the quorum rule broken, put in
-    the program's place."""
-    control = [shadow(g, control=True) for g in range(G)]
-    checks = run_engine_checks(engine_state(control), shadows)
+    the program's place; at five replicas as at three."""
+    r = shadows[0]
+    control = [shadow(r, g, control=True) for g in range(G)]
+    checks = run_engine_checks(engine_state(r, control), shadows)
     assert not verdict(checks)
     bad = {c.name for c in checks if not c.ok}
     assert "sampled_replicas_state_differs_from_reference" in bad

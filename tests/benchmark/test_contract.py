@@ -10,7 +10,7 @@ import pytest
 
 from benchmark import harness
 
-from .util import REPO, add_parked, bench, tiny_root
+from .util import REPO, add_parked, bench, parked, tiny_root
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -22,6 +22,7 @@ CELLS = [w["name"] for w in B["workloads"]]
 WITH_PARKED = bench()
 add_parked(WITH_PARKED)
 ALL_CELLS = [w["name"] for w in WITH_PARKED["workloads"]]
+PARKED_CELLS = [w["name"] for w in parked()["workloads"]]
 METRICS = WITH_PARKED["end_to_end"] + WITH_PARKED["per_layer"]
 
 
@@ -37,15 +38,63 @@ def test_top_level_keys_and_limits():
     assert (runs * (B["run_seconds"] + 60) + 24 * 2 * 90 + 1200) <= 43200
 
 
-def test_only_the_cells_the_issue_names():
-    assert CELLS == ["engine64k-r3.append"], "the served cells are parked"
-    assert set(ALL_CELLS) == {"served1k-r3.put", "engine64k-r3.append",
-                              "served1k-r3.lread"}
-    assert {c["name"] for c in B["configs"]} == {
-        w["config"] for w in B["workloads"]}
-    assert all(w["chips"] == 1 for w in WITH_PARKED["workloads"])
-    pairs = [(w["config"], w["traffic"]) for w in B["workloads"]]
+# -- the list of cells, by rule: a later PR adds a cell with files and
+# -- entries alone, and these hold whatever the list is
+
+
+@pytest.mark.parametrize("w", WITH_PARKED["workloads"],
+                         ids=lambda w: w["name"])
+def test_cell_names_a_configuration_of_its_own_list(w):
+    """A live cell's configuration is live; a parked cell's is live or
+    parked with it."""
+    pool = B if w["name"] in CELLS else WITH_PARKED
+    assert w["config"] in {c["name"] for c in pool["configs"]}
+
+
+@pytest.mark.parametrize("cfg", B["configs"], ids=lambda c: c["name"])
+def test_live_configuration_has_a_live_cell(cfg):
+    assert any(w["config"] == cfg["name"] for w in B["workloads"])
+
+
+def test_no_two_cells_share_a_configuration_and_a_traffic_mix():
+    pairs = [(w["config"], w["traffic"]) for w in WITH_PARKED["workloads"]]
     assert len(set(pairs)) == len(pairs)
+
+
+def test_at_most_24_cells_and_half_of_them_on_four_chips():
+    assert 1 <= len(CELLS) <= 24
+    four = [w["name"] for w in B["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(CELLS) // 2), four
+
+
+@pytest.mark.parametrize("w", WITH_PARKED["workloads"],
+                         ids=lambda w: w["name"])
+def test_cell_takes_one_chip_or_four(w):
+    assert w["chips"] in (1, 4)
+
+
+@pytest.mark.parametrize("cell", PARKED_CELLS)
+def test_parked_cell_is_not_live(cell):
+    assert cell not in CELLS
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_live_cell_reports_one_end_to_end_metric_and_setup_s(cell):
+    listing = [m["name"] for m in B["end_to_end"]
+               if cell in m.get("workloads", [])]
+    assert len(listing) == 1 and listing != ["setup_s"], listing
+    unlisted = [m["name"] for m in B["end_to_end"] if "workloads" not in m]
+    assert unlisted == ["setup_s"]
+
+
+@pytest.mark.parametrize("w", WITH_PARKED["workloads"],
+                         ids=lambda w: w["name"])
+def test_perf_md_says_why_the_cell_exists(w):
+    with open(os.path.join(REPO, "PERF.md")) as f:
+        perf = f.read()
+    missing = [n for n in (w["name"], w["config"])
+               if f"`{n}`" not in perf]
+    assert not missing, f"PERF.md does not name {missing}"
 
 
 @pytest.mark.parametrize("cfg", WITH_PARKED["configs"],

@@ -65,3 +65,24 @@ def test_engine_rounds_is_deterministic_in_the_seed():
 def test_p95_is_the_nearest_rank():
     assert kv_closed.p95(list(range(1, 101))) == 96
     assert kv_closed.p95([7.0]) == 7.0
+
+
+@pytest.mark.parametrize("replicas,shadow_groups", [(3, 8), (5, 6), (5, 5)])
+@pytest.mark.parametrize("seed", [7, 2**31 + 13, 2_600_000_011])
+def test_the_reference_follows_a_group_of_every_leader_class(
+        replicas, shadow_groups, seed):
+    """The comparison holds each group equal to its leader slot's class,
+    so the sample the reference follows has to hold one of each class."""
+    from benchmark.drivers.engine import Driver
+
+    sizes = {"num_groups": 4096, "num_replicas": replicas}
+    load = engine_rounds.make(traffic("append"), sizes, seed)
+    driver = Driver({"sizes": sizes, "shadow_groups": shadow_groups},
+                    traffic("append"), seed, "")
+    sample = driver.sample(load)
+    assert sample == driver.sample(load) == sorted(set(sample))
+    assert len(sample) == shadow_groups
+    assert set(load["leader_slots"][sample]) == set(range(replicas))
+    other = Driver({"sizes": sizes, "shadow_groups": shadow_groups},
+                   traffic("append"), seed + 1, "")
+    assert other.sample(load) != sample

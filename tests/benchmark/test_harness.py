@@ -14,6 +14,7 @@ import pytest
 
 from benchmark import harness
 
+from .test_contract import ALL_CELLS
 from .util import REPO, tiny_root
 
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
@@ -73,9 +74,11 @@ def drive(root, workload, seed, seconds=0.6):
     return cell, ctx, checks
 
 
+# Every live cell and every parked one, so that a cell a later PR adds
+# runs here without an edit; seeds past 32 bits and small ones by turns.
 @pytest.mark.parametrize("workload,seed", [
-    ("served1k-r3.put", 2**31 + 7), ("served1k-r3.lread", 3),
-    ("engine64k-r3.append", 5)])
+    (name, 2**31 + 7 + i if i % 2 == 0 else 3 + i)
+    for i, name in enumerate(ALL_CELLS)])
 def test_cell_runs_tiny_and_is_correct(root, workload, seed):
     cell, ctx, checks = drive(root, workload, seed)
     assert harness.verdict(checks), [c for c in checks if not c.ok]
@@ -92,6 +95,29 @@ def test_cell_runs_tiny_and_is_correct(root, workload, seed):
                 "rawnode.host_pct", "client.busy_pct",
                 "fabric.lost"} <= set(layer)
         assert ctx["raw"]["failed"] == 0
+
+
+def test_main_prints_each_number_compared_beside_its_limit(
+        root, monkeypatch, capsys, tmp_path):
+    """The command's own ``main`` past its look for a chip: the result
+    line ends with the numbers compared, stderr ends with them too."""
+    real = harness.check_device
+    monkeypatch.setattr(harness, "check_device",
+                        lambda chips, require_tpu=True: real(chips, False))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    rc = harness.main(["--workload", ALL_CELLS[0], "--seed",
+                       str(2**31 + 99), "--seconds", "0.3"],
+                      time.perf_counter(), root)
+    out, err = capsys.readouterr()
+    assert rc == 0
+    line = json.loads(out.splitlines()[-1])
+    assert RESULT_KEYS <= set(line) and list(line)[-1] == "checks"
+    assert line["correct"] is True and line["checks"]
+    tail = err.splitlines()[-len(line["checks"]) - 1:]
+    assert tail[-1] == "benchmark: correct = True"
+    for text, (name, c) in zip(tail, line["checks"].items()):
+        assert text == (f"benchmark: check {name} = {c['value']} "
+                        f"(limit {c['limit']})")
 
 
 def test_a_new_cell_is_files_and_entries_only(root):

@@ -15,17 +15,19 @@ from benchmark import harness
 from benchmark.readers import spans as span_readers
 from benchmark.reduce.gaps import (
     UNSPANNED,
+    host_clock_lead,
     idle_gaps,
     innermost,
     read_xplane,
     reduce_gaps,
     share_out,
+    span_rows,
     table,
 )
 from benchmark.reduce.trace import MIN_GAP_NS, _union, reduce_trace
 
 from .test_contract import NAME, SOURCES, UNIT, WITH_PARKED
-from .util import REPO, _edit, bench, tiny_root
+from .util import REPO, XPROF, _edit, bench, tiny_root
 
 # 0.3 s of a G=8 served cluster under 16 putting clients on the chip
 # (TPU v5 lite): the trace ``tools/round_gaps.py`` kept of
@@ -109,6 +111,16 @@ def test_idle_gaps_are_reduce_traces_gaps():
     assert idle_gaps(ops) == [(150.5, 5000.0, "copy")]
 
 
+def test_host_clock_lead_is_the_largest_enqueue_minus_start():
+    # Three programs: the second waited 40 for the first to end, so it
+    # reads 30 lower; the lead is what the promptest program shows.
+    starts, enqueues = [100.0, 300.0, 900.0], [170.0, 330.0, 968.0]
+    assert host_clock_lead(starts, enqueues) == 70.0
+    assert host_clock_lead(starts[::-1], enqueues) == 70.0
+    assert host_clock_lead(starts, enqueues[:2]) is None
+    assert host_clock_lead([], []) is None
+
+
 # -- the extraction, on the recorded chip trace ------------------------------------
 
 
@@ -119,11 +131,12 @@ def chip(tmp_path_factory):
     path = str(tmp_path_factory.mktemp("xplane") / "g8.xplane.pb")
     with gzip.open(SPAN_XPLANE, "rb") as src, open(path, "wb") as dst:
         shutil.copyfileobj(src, dst)
-    return reduce_gaps(path, top=5), reduce_trace(path, top=10 ** 6)
+    return reduce_gaps(path, top=5), reduce_trace(path)
 
 
 def test_chip_trace_holds_the_spans_on_the_devices_clock(chip):
-    devices, threads = read_xplane(chip[0]["xplane"])
+    devices, threads, lead = read_xplane(chip[0]["xplane"])
+    assert lead == pytest.approx(372441.0)  # ns: the host's clock ahead
     assert len(devices) == 1 and devices[0]
     names = {n for evs in threads.values() for _s, _e, n in evs}
     assert {"member.round", "rawnode.h2d", "rawnode.dispatch",
@@ -152,13 +165,64 @@ def test_every_gap_second_of_the_chip_trace_is_attributed(chip):
     assert (gaps["gaps"], gaps["host_threads"], gaps["host_spans"]) == (
         31, 6, 210)
     assert gaps["gap_s"] == pytest.approx(0.244485816)
-    assert gaps["gap_s"] == pytest.approx(
-        sum(v for _n, v in red["idle_gaps"]), rel=1e-9)
+    assert gaps["gap_s"] == pytest.approx(red["gap_s"], rel=1e-9)
     assert sum(gaps["by_span_s"].values()) == pytest.approx(gaps["gap_s"])
     assert 0.0 <= gaps["unspanned_pct"] < 5.0
     for row in gaps["longest"]:
         assert sum(row["spans_ms"].values()) == pytest.approx(row["ms"])
     assert "| `" in table(gaps)
+
+
+# -- the result line's ``idle_gaps``: rows named by host span -----------------------
+
+# The G=8 served trace's spans with most device idle seconds, in order.
+SERVED_ROWS = ["rawnode.dispatch", "rawnode.d2h", "rawnode.stage",
+               "member.round", "member.send"]
+
+
+def test_rows_of_the_served_chip_trace_are_named_by_host_span(chip):
+    gaps, _red = chip
+    rows = span_rows(gaps)
+    names = [n for n, _v in rows]
+    assert len(rows) == 10 < len(gaps["by_span_s"])
+    assert names[:5] == SERVED_ROWS
+    assert names[-1] == UNSPANNED, "kept though ten spans read longer"
+    assert all(n == UNSPANNED or n.startswith(("member.", "rawnode."))
+               for n in names)
+    assert [v for _n, v in rows[:-1]] == sorted(
+        (v for _n, v in rows[:-1]), reverse=True)
+    assert all(v == gaps["by_span_s"][n] for n, v in rows)
+
+
+def test_rows_of_a_trace_without_spans_are_all_unspanned():
+    """``artifacts/tpu_r05/xprof`` was taken before the program had the
+    span layer: every idle second falls under no span."""
+    if not os.path.isdir(XPROF):
+        pytest.skip("artifacts/tpu_r05/xprof is not in this checkout")
+    gaps, red = reduce_gaps(XPROF), reduce_trace(XPROF)
+    rows = span_rows(gaps)
+    assert [n for n, _v in rows] == [UNSPANNED]
+    assert rows[0][1] == pytest.approx(red["gap_s"], rel=1e-9)
+
+
+def test_result_line_of_a_traced_run(chip, root):
+    """``run_cell``'s second half on a tiny engine run, with the served
+    chip trace put where a traced run's reduction would be (a CPU trace
+    has no device plane): ``idle_gaps`` by host span, ``device_ops`` as
+    they were, the numbers compared last in the line."""
+    gaps, red = chip
+    cell = harness.Cell(root, "engine64k-r3.append")
+    ctx, checks = harness.measure(cell, 27, 0.3, False,
+                                  time.perf_counter(), require_tpu=False)
+    ctx.update(trace=red, gaps=gaps)
+    line = harness._result(cell, ctx, checks, True)
+    assert line["breakdown"]["idle_gaps"] == span_rows(gaps)
+    assert line["breakdown"]["device_ops"] == red["device_ops"]
+    assert list(line)[-1] == "checks" and line["correct"] is True
+    assert line["checks"] == {c.name: {"value": c.value, "limit": c.limit}
+                              for c in checks}
+    assert line["device"]["busy_s"] == red["busy_s"]
+    assert json.loads(json.dumps(line)) == line
 
 
 # -- the parked entries against the contract ---------------------------------------
