@@ -5,7 +5,9 @@ the full multi-group SoA state on device, exposes the same logical
 contract as ``raft.RawNode`` (tick / campaign / propose / step / ready
 watermarks / advance) but batched over every group at once, and runs
 closed-loop rounds entirely on device (deliver → tick → propose → emit →
-route). Entry payloads never touch the device: the host keeps them in
+route), faults included: a scan takes a per-round schedule of nodes cut
+off the network (``run_rounds(isolate=...)``), so an outage begins and
+heals inside one program. Entry payloads never touch the device: the host keeps them in
 an arena keyed by (group, index), and the commit watermarks streaming
 back from the device drive payload application — mirroring how the
 reference applies committed entries after the Ready loop (ref:
@@ -37,9 +39,10 @@ class MultiRaftEngine:
     """Host calls are spans of the round-span recorder (obs/spans.py):
     ``engine.init``, ``engine.step_round`` and ``engine.run_rounds``
     (one a scan, so one a chunk of ``run_rounds_pipelined``), with
-    member 0, the call's number as ``round`` and the engine's serial
-    and the scan's ``rounds`` as stats. A span ends when the program is
-    enqueued: the host's share of a call, not the device's."""
+    member 0, the call's number as ``round`` and the engine's serial,
+    the scan's ``rounds`` and ``isolated`` (rounds x nodes its fault
+    schedule cut off; 0 with none) as stats. A span ends when the
+    program is enqueued: the host's share of a call, not the device's."""
 
     def __init__(self, cfg: BatchedConfig, start_index: int = 0):
         self._serial = next(_ENGINE_SERIAL)
@@ -112,11 +115,25 @@ class MultiRaftEngine:
             self._fleet_sum_np = self._fleet_layout.sum_mask()
         self.fleet_hub = None
 
-        def closed_loop(st, inbox, ticks, props, tel, flt, rounds):
-            def body(carry, _):
+        def closed_loop(st, inbox, ticks, props, tel, flt, isolate, rounds):
+            # `isolate` is None (no fault: the scan is traced as it
+            # always was) or the bool [rounds, R] node schedule, one
+            # row a round as the scan's xs.
+            # jitlint: waive(tracer-branch) -- None is an empty pytree: the branch is on the argument's structure at trace time, never on a device value
+            if isolate is not None:
+                slots = jnp.arange(n, dtype=I32) % cfg.num_replicas
+
+            def body(carry, cut):
                 st, inbox, tel, flt = carry
+                iso = self._zeros_b
+                # jitlint: waive(tracer-branch) -- as above: a scan without xs hands its body None
+                if cut is not None:
+                    # Row t widened to [N] where it is used: node s is
+                    # slot s of every group.
+                    for s in range(cfg.num_replicas):
+                        iso = iso | ((slots == s) & cut[s])
                 out = self._step(
-                    st, inbox, ticks, self._zeros_b, props, self._zeros_b
+                    st, inbox, ticks, self._zeros_b, props, iso
                 )
                 st, outbox = out[:2]
                 if cfg.telemetry:
@@ -128,7 +145,7 @@ class MultiRaftEngine:
                 return (st, route(cfg, outbox), tel, flt), None
 
             (st, inbox, tel, flt), _ = jax.lax.scan(
-                body, (st, inbox, tel, flt), None, length=rounds
+                body, (st, inbox, tel, flt), isolate, length=rounds
             )
             # The scalar fence is a SEPARATE output buffer: pipelined
             # callers block on it to bound queue depth without holding
@@ -215,26 +232,53 @@ class MultiRaftEngine:
         if self.cfg.fleet_summary:
             self._fleet_vec = flt
 
-    def run_rounds(self, rounds: int, tick: bool = True,
-                   propose_n: Optional[jnp.ndarray] = None) -> None:
-        """Closed-loop simulation of `rounds` rounds without leaving the
-        device (one fused lax.scan program)."""
-        ticks = jnp.ones_like(self._zeros_b) if tick else self._zeros_b
-        props = propose_n if propose_n is not None else self._zeros_i
+    def _schedule(self, isolate, rounds: int):
+        """(device schedule or None, rounds x nodes cut) of a call."""
+        if isolate is None:
+            return None, 0
+        sched = np.asarray(isolate, bool)
+        if sched.shape != (rounds, self.cfg.num_replicas):
+            raise ValueError(
+                f"isolate must be [rounds, R] = "
+                f"{(rounds, self.cfg.num_replicas)}, got {sched.shape}")
+        return jnp.asarray(sched), int(sched.sum())
+
+    def _scan(self, rounds: int, ticks, props, isolate):
+        """One closed-loop scan enqueued; returns its scalar fence."""
+        sched, isolated = self._schedule(isolate, rounds)
         # `rounds` is a static arg: each new value compiles a new scan
-        # program, so warmth (and thus the transfer guard) is per value.
-        with self._span("engine.run_rounds", rounds=rounds), \
-                warm_guard(f"closed_loop/{self._serial}/{rounds}"):
-            self.state, self.inbox, tel, flt, _ = self._closed_loop(
+        # program (and so does the first call with a schedule), so
+        # warmth (and thus the transfer guard) is per value.
+        key = f"closed_loop/{self._serial}/{rounds}" + (
+            "" if sched is None else "/isolate")
+        with self._span("engine.run_rounds", rounds=rounds,
+                        isolated=isolated), warm_guard(key):
+            self.state, self.inbox, tel, flt, fence = self._closed_loop(
                 self.state, self.inbox, ticks, props, self._tel(),
-                self._flt(), rounds
+                self._flt(), sched, rounds
             )
         self._set_tel(tel)
         self._set_flt(flt)
+        return fence
+
+    def run_rounds(self, rounds: int, tick: bool = True,
+                   propose_n: Optional[jnp.ndarray] = None,
+                   isolate=None) -> None:
+        """Closed-loop simulation of `rounds` rounds, faults included,
+        without leaving the device (one fused lax.scan program).
+        `isolate`, bool [rounds, R], cuts node s (slot s of every
+        group) off the network in round t where ``isolate[t, s]``: it
+        neither receives nor sends, and keeps ticking — what
+        ``step_round(isolate=...)`` does to single instances, as the
+        scan's per-round input."""
+        ticks = jnp.ones_like(self._zeros_b) if tick else self._zeros_b
+        props = propose_n if propose_n is not None else self._zeros_i
+        self._scan(rounds, ticks, props, isolate)
 
     def run_rounds_pipelined(self, rounds: int, chunk: int = 16,
                              depth: int = 2, tick: bool = True,
-                             propose_n: Optional[jnp.ndarray] = None) -> None:
+                             propose_n: Optional[jnp.ndarray] = None,
+                             isolate=None) -> None:
         """Double-buffered round pipelining: split `rounds` into scan
         chunks and keep up to `depth` chunks in flight — chunk k+1 is
         enqueued while chunk k's scan executes, and because the state
@@ -245,7 +289,8 @@ class MultiRaftEngine:
         Blocking is on the per-chunk scalar fence (an independent
         output), never on donated state; the final chunk is left in
         flight — callers that need completion block on
-        ``self.state.commit`` as usual."""
+        ``self.state.commit`` as usual. `isolate` is ``run_rounds``'
+        node schedule over all `rounds`; each chunk takes its rows."""
         if rounds <= 0:
             return
         if chunk <= 0:
@@ -258,16 +303,10 @@ class MultiRaftEngine:
         done = 0
         while done < rounds:
             n = min(chunk, rounds - done)
-            with self._span("engine.run_rounds", rounds=n), \
-                    warm_guard(f"closed_loop/{self._serial}/{n}"):
-                self.state, self.inbox, tel, flt, fence = self._closed_loop(
-                    self.state, self.inbox, ticks, props, self._tel(),
-                    self._flt(), n
-                )
-            self._set_tel(tel)
-            self._set_flt(flt)
+            fences.append(self._scan(
+                n, ticks, props,
+                None if isolate is None else isolate[done:done + n]))
             done += n
-            fences.append(fence)
             while len(fences) > depth:
                 # jitlint: waive(sync-in-loop) -- the sync IS the pipelining contract: block on the per-chunk scalar fence to bound queue depth at `depth` without holding a donated buffer
                 jax.block_until_ready(fences.popleft())

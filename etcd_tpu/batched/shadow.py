@@ -114,12 +114,14 @@ class ShadowCluster:
         heartbeat_timeout: int = 1,
         max_inflight: int = 1 << 20,
         pre_vote: bool = False,
+        check_quorum: bool = False,
         learners: Sequence[int] = (),
         group: int = 0,
         deterministic_timeouts: bool = False,
         auto_compact_window: int = 0,
         max_ents: Optional[int] = None,
         deliver_shape: str = "auto",
+        max_props: int = 0,
     ):
         # Mirrors BatchedConfig.deliver_shape: the device's delivery
         # order is kind-major (six lane scans, "lanes"), sender-major
@@ -153,15 +155,22 @@ class ShadowCluster:
                 max_size_per_msg=1 << 62,
                 max_inflight_msgs=max_inflight,
                 pre_vote=pre_vote,
+                check_quorum=check_quorum,
                 rand=(DeviceHashRand(group * num_replicas + slot)
                       if deterministic_timeouts else None),
             )
             self.nodes.append(RawNode(cfg))
         self.auto_compact_window = auto_compact_window
-        # Device per-message entry cap: an append exceeding it cannot
-        # fit the device's one send per round, so it is an envelope
-        # error, never a silent truncation.
+        # Device per-message entry cap E: etcd's MaxSizePerMsg counted
+        # in entries. The sender's own log fetch is capped, so its
+        # progress tracks what was sent (no truncation in the network).
         self.max_ents = max_ents
+        if max_ents is not None:
+            for node in self.nodes:
+                self._one_lane_a_round(node.raft)
+        # Device per-round proposal cap P: with the ring's W it bounds
+        # what a leader admits of an `offer` (see `_admitted`).
+        self.max_props = max_props
         # inbox[target][sender][kind]
         self.inbox: List[List[List[Optional[Message]]]] = self._empty_inbox()
 
@@ -178,13 +187,20 @@ class ShadowCluster:
         isolate: Iterable[int] = (),
         transfers: Optional[Dict[int, int]] = None,
         drop_pairs: Iterable[Tuple[int, int]] = (),
+        offer: int = 0,
     ) -> None:
         """One round with the device's phase order:
         deliver → tick/campaign → control → propose → emit.
         `transfers` maps leader slot → target slot; `drop_pairs` drops
-        (sender, target) directed edges at emit — partial partitions."""
+        (sender, target) directed edges at emit — partial partitions.
+        `offer` hands that many proposals to every replica, as the
+        closed loop does: whoever leads when the propose phase is
+        reached takes them (`_admitted`)."""
         iso = set(isolate)
-        proposals = proposals or {}
+        proposals = dict(proposals or {})
+        if self.max_ents is not None:
+            for node in self.nodes:
+                node.raft.lane_sent.clear()
         transfers = transfers or {}
         drops = set(drop_pairs)
 
@@ -243,6 +259,9 @@ class ShadowCluster:
         # its per-round proposals as one batch with one broadcast.
         from ..raft.types import Entry
 
+        if offer:
+            for slot in range(self.r):
+                proposals[slot] = self._admitted(slot, offer)
         for slot, n in proposals.items():
             if n <= 0:
                 continue
@@ -316,10 +335,13 @@ class ShadowCluster:
                     if _same_message(prev, m):
                         continue
                     merged = _merge_apps(prev, m)
-                    if merged is not None and (
-                        self.max_ents is None
-                        or len(merged.entries) <= self.max_ents
-                    ):
+                    if merged is not None:
+                        # What the sender's Progress counts as sent fits
+                        # the lane (`_one_lane_a_round`); entries past
+                        # it are `_rematerialize`'s, and the lane drops
+                        # them as the device's emit does.
+                        if self.max_ents is not None:
+                            del merged.entries[self.max_ents:]
                         self.inbox[target][slot][kind] = merged
                         continue
                     # A snapshot supersedes an append in the same lane,
@@ -343,6 +365,53 @@ class ShadowCluster:
         for slot, rd in readys:
             self.nodes[slot].advance(rd)
 
+
+    def _one_lane_a_round(self, r) -> None:
+        """The device carries one append of at most E entries to a peer
+        in a round. The oracle's leader is held to that where it sends
+        (its log fetch is capped to the room left in the peer's lane,
+        and a send to a full lane is refused like one to a paused
+        peer), so its Progress tracks what the lane carries and no
+        append is cut in the network."""
+        r.lane_sent = {}  # peer id -> entries sent this round
+        room = [self.max_ents]
+        fetch, send = r.raft_log.entries, r.maybe_send_append
+
+        def entries(i, max_size):
+            return fetch(i, max_size)[:room[0]]
+
+        def maybe_send_append(to, send_if_empty):
+            room[0] = self.max_ents - r.lane_sent.get(to, 0)
+            if room[0] <= 0:
+                return False
+            sent = send(to, send_if_empty)
+            if sent and r.msgs[-1].type == MessageType.MsgApp:
+                r.lane_sent[to] = (r.lane_sent.get(to, 0)
+                                   + len(r.msgs[-1].entries))
+            return sent
+
+        r.raft_log.entries = entries
+        r.maybe_send_append = maybe_send_append
+
+    def _admitted(self, slot: int, n: int) -> int:
+        """How many of `n` offered proposals the device's `_propose`
+        appends on this replica: none unless it leads and is not handing
+        leadership over (a follower's forwarded MsgProp has no lane on
+        the device), and no more than the ring's headroom, W less the
+        entries held less one round's proposals (the engine's admission
+        control; W is known here under auto-compaction only, and P is
+        `max_props`)."""
+        from ..raft.raft import StateType
+
+        r = self.nodes[slot].raft
+        if r.state != StateType.StateLeader or r.lead_transferee:
+            return 0
+        if self.auto_compact_window and self.max_props:
+            held = r.raft_log.last_index() - (
+                r.raft_log.storage.first_index() - 1)
+            n = min(n, self.max_props,
+                    max(self.auto_compact_window - held - self.max_props, 0))
+        return n
 
     def _deliver_vectorized_target(self, target: int, msgs) -> None:
         """One target's inbox in the vectorized shape's order contract
@@ -409,11 +478,22 @@ class ShadowCluster:
         from ..raft.raft import StateType
 
         r = node.raft
-        if (
-            m.type != MessageType.MsgApp
-            or m.term != r.term
-            or r.state != StateType.StateLeader
-        ):
+        if m.term != r.term or r.state != StateType.StateLeader:
+            return m
+        if m.type == MessageType.MsgSnap:
+            # The same for a snapshot queued mid-deliver: the device
+            # compacts at the top of emit and sends the floor it has
+            # then, so the oracle's leader sends, and waits on, the
+            # snapshot of the end of the round.
+            snap = r.raft_log.storage.snapshot()
+            pr = r.prs.progress[m.to]
+            if pr.pending_snapshot == m.snapshot.metadata.index:
+                pr.pending_snapshot = snap.metadata.index
+            return Message(
+                type=MessageType.MsgSnap, to=m.to, from_=m.from_,
+                term=m.term, snapshot=snap,
+            )
+        if m.type != MessageType.MsgApp:
             return m
         # Below the (just-advanced) floor the device sends a snapshot
         # instead (step.py _emit snap_needed after auto-compaction).
@@ -426,11 +506,8 @@ class ShadowCluster:
             )
         last = r.raft_log.last_index()
         want = last - m.index
-        if self.max_ents is not None and want > self.max_ents:
-            raise AssertionError(
-                f"append of {want} entries exceeds the device cap "
-                f"{self.max_ents}; schedule outside the differential "
-                "envelope")
+        if self.max_ents is not None:
+            want = min(want, self.max_ents)
         if want <= len(m.entries) and m.commit == r.raft_log.committed:
             return m
         try:

@@ -34,6 +34,9 @@ I8 = jnp.int8
 # count as one byte; a config exceeding this would silently wrap the
 # count on the wire (E=256 reads back as 0 entries).
 MAX_WIRE_ENTS = 255
+# Up to here (floor(sqrt(2^31)); powers of two apart) the sum of two
+# products of residues stays below 2^32 (rand_timeout).
+MAX_HASHED_TIMEOUT = 46340
 
 # The shippable deliver shapes (BatchedConfig.deliver_shape); "auto"
 # resolves to one of these per platform at build time.
@@ -192,6 +195,14 @@ class BatchedConfig(NamedTuple):
             raise ValueError(
                 f"deliver_shape={self.deliver_shape!r} not in "
                 f"{('auto',) + DELIVER_SHAPES}")
+        et = self.election_timeout
+        if et < 1 or (et > MAX_HASHED_TIMEOUT and et & (et - 1)):
+            raise ValueError(
+                f"election_timeout={et} must be 1..{MAX_HASHED_TIMEOUT} "
+                "or a power of two: the timeout "
+                "hash (rand_timeout) multiplies residues modulo it on "
+                "uint32 lanes, and beyond that a product passes 2^32 by "
+                "other than a multiple of it")
         if self.apply_plane:
             if self.apply_capacity < 1:
                 raise ValueError(
@@ -382,6 +393,21 @@ def instance_slot(cfg: BatchedConfig) -> jnp.ndarray:
     return jnp.asarray(_slot_ids(cfg))
 
 
+def rand_timeout(cfg: BatchedConfig, iid, reset_count):
+    """Deterministic stand-in for lockedRand: [et, 2et-1], reproducible
+    by the host oracle (shadow.DeviceHashRand computes the same formula,
+    ((iid+1)*7919 + reset_count*104729) % et, in Python integers). The
+    operands are reduced first, on unsigned lanes: nothing passes 2^32
+    for et up to MAX_HASHED_TIMEOUT, a power of two divides the wrap,
+    and every `%` by one is a mask (validate() admits no other)."""
+    et = cfg.election_timeout
+    t = jnp.uint32(et)
+    h = ((jnp.asarray(iid + 1).astype(jnp.uint32) % t) * jnp.uint32(7919 % et)
+         + (jnp.asarray(reset_count).astype(jnp.uint32) % t)
+         * jnp.uint32(104729 % et)) % t
+    return et + h.astype(I32)
+
+
 def init_state(cfg: BatchedConfig, start_index: int = 0,
                iids=None) -> BatchedState:
     """All groups bootstrapped as followers at term 0 with R voters, log
@@ -421,8 +447,7 @@ def init_state(cfg: BatchedConfig, start_index: int = 0,
         # Per-instance randomized [et, 2et) from the start (reset_count
         # 0 of the deterministic hash) — a uniform value would make
         # every boot election a guaranteed split vote.
-        randomized_timeout=cfg.election_timeout
-        + ((iids + 1) * 7919 % cfg.election_timeout),
+        randomized_timeout=rand_timeout(cfg, iids, 0),
         reset_count=zeros_n(),
         match=jnp.zeros((n, r), I32),
         next=jnp.ones((n, r), I32) * (start0[:, None] + 1),

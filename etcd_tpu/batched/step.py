@@ -65,6 +65,7 @@ from .state import (
     BatchedState,
     I32,
     narrow_state,
+    rand_timeout as _rand_timeout,
     widen_state,
 )
 
@@ -210,13 +211,6 @@ def _pick_b(vec, at):
 # Per-instance primitive transitions (scalars + [R]/[W] vectors; used
 # under vmap). Each returns a full BatchedState slice.
 # -----------------------------------------------------------------------------
-
-
-def _rand_timeout(cfg: BatchedConfig, iid, reset_count):
-    """Deterministic stand-in for lockedRand: [et, 2et-1], reproducible
-    by the host oracle."""
-    h = ((iid + 1) * 7919 + reset_count * 104729) % cfg.election_timeout
-    return cfg.election_timeout + h
 
 
 def _reset(cfg: BatchedConfig, st: BatchedState, iid, slot, term) -> BatchedState:

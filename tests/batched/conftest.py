@@ -88,7 +88,29 @@ os.environ.setdefault("ETCD_TPU_TRANSFER_GUARD", "disallow")
 # (G=3, n-minor, narrow), because the compile-count test has to see
 # the round and route() compile. route() itself is keyed by R alone
 # and counted nowhere here.
-ROUND_STEP_SHAPE_BUDGET = 45
+#
+# ISSUE 28 AUDIT: 48 used of 48, counted (a whole `pytest tests/ -m 'not
+# slow'` session in ONE process, 1,518 passed in 759 s, the sentinel's
+# own message naming 48 keys), not reckoned: the parent stood at 46 of
+# 45 (PR 26's `engine10k-r5` at the benchmark tests' 8 groups was never
+# audited; the driver's run is split over six xdist workers, each a
+# session of its own, so the sentinel never saw it). test_scan_faults
+# adds two programs: the benchmark's `engine100k-r3` at the CPU tests'
+# 8 groups (etcd's raft defaults: election 10, heartbeat 1, pre_vote,
+# check_quorum; n-minor, telemetry on; "auto" = vectorized here), which
+# tests/benchmark builds too for the cell's tiny run, its controls and
+# its broken-path tests, and that config's `merged` twin, because
+# merged is the shape the chip runs and the repo had no differential
+# of the round under pre_vote + check_quorum in any shape. The scan-
+# against-single-rounds test's other two engines reuse values that are
+# built already (`engine10k-r5` at 8 groups, R=5 n-minor, from
+# tests/benchmark; test_pipelined.make_engine(4), R=3 n-major), and the
+# hash tests build no round at all. The scan's per-round fault schedule
+# is an input of the closed-loop program, not of the round step: no key
+# there either
+# (test_without_a_schedule_the_scan_gains_no_input_and_no_key). Of the
+# raise by three, one unit repairs PR 26's count and two are this PR's.
+ROUND_STEP_SHAPE_BUDGET = 48
 
 
 @pytest.fixture(scope="session", autouse=True)
