@@ -90,9 +90,9 @@ def main() -> None:
     layout_env = os.environ.get("BENCH_LAYOUT", "")
     if layout_env and layout_env not in ("major", "minor"):
         raise SystemExit(f"BENCH_LAYOUT must be major|minor, got {layout_env!r}")
-    # Deliver shape (ISSUE 14 A/B axis): the platform default lives in
-    # state.default_deliver_shape (CPU → vectorized, the r14 same-day
-    # winner; TPU → merged, the only on-device-tuned shape, r05).
+    # Deliver shape (ISSUE 14 A/B axis): the default lives in
+    # state.default_deliver_shape (vectorized: the r14 same-day winner
+    # on CPU and, since ISSUE 29, what the chip runs too).
     # BENCH_DELIVER_SHAPE=lanes|merged|vectorized pins it for A/B rows.
     shape_env = os.environ.get("BENCH_DELIVER_SHAPE", "")
     if os.environ.get("BENCH_MERGED_DELIVER", ""):

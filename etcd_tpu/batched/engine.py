@@ -73,6 +73,9 @@ class MultiRaftEngine:
         n = cfg.num_instances
         self._zeros_b = jnp.zeros((n,), bool)
         self._zeros_i = jnp.zeros((n,), I32)
+        # Scan rounds in which each kind lane held a message for any
+        # instance (lane_rounds()): carried through the closed loop.
+        self._lanes = jnp.zeros((NUM_KINDS,), I32)
         # In-device telemetry accumulator (cfg.telemetry): per-instance
         # counter totals + OR-folded invariant bitmaps, accumulated
         # inside the closed-loop scan with no per-round host sync.
@@ -115,7 +118,8 @@ class MultiRaftEngine:
             self._fleet_sum_np = self._fleet_layout.sum_mask()
         self.fleet_hub = None
 
-        def closed_loop(st, inbox, ticks, props, tel, flt, isolate, rounds):
+        def closed_loop(st, inbox, ticks, props, tel, flt, lanes, isolate,
+                        rounds):
             # `isolate` is None (no fault: the scan is traced as it
             # always was) or the bool [rounds, R] node schedule, one
             # row a round as the scan's xs.
@@ -124,7 +128,11 @@ class MultiRaftEngine:
                 slots = jnp.arange(n, dtype=I32) % cfg.num_replicas
 
             def body(carry, cut):
-                st, inbox, tel, flt = carry
+                st, inbox, tel, flt, lanes = carry
+                # The round's own occupancy vector (step_round's
+                # lane_any, which XLA shares): what the vectorized
+                # deliver skips on, counted whatever the shape.
+                lanes = lanes + jnp.any(inbox.valid, axis=(0, 1))
                 iso = self._zeros_b
                 # jitlint: waive(tracer-branch) -- as above: a scan without xs hands its body None
                 if cut is not None:
@@ -142,15 +150,15 @@ class MultiRaftEngine:
                 if cfg.fleet_summary:
                     fv = out[self._fleet_pos]
                     flt = jnp.where(self._fleet_summask, flt + fv, fv)
-                return (st, route(cfg, outbox), tel, flt), None
+                return (st, route(cfg, outbox), tel, flt, lanes), None
 
-            (st, inbox, tel, flt), _ = jax.lax.scan(
-                body, (st, inbox, tel, flt), isolate, length=rounds
+            (st, inbox, tel, flt, lanes), _ = jax.lax.scan(
+                body, (st, inbox, tel, flt, lanes), isolate, length=rounds
             )
             # The scalar fence is a SEPARATE output buffer: pipelined
             # callers block on it to bound queue depth without holding
             # (and thereby breaking) a donated state buffer.
-            return st, inbox, tel, flt, st.commit[0]
+            return st, inbox, tel, flt, lanes, st.commit[0]
 
         # State and inbox are donated: run_rounds/run_rounds_pipelined
         # reassign both from the return value, so XLA writes round k+1
@@ -253,10 +261,11 @@ class MultiRaftEngine:
             "" if sched is None else "/isolate")
         with self._span("engine.run_rounds", rounds=rounds,
                         isolated=isolated), warm_guard(key):
-            self.state, self.inbox, tel, flt, fence = self._closed_loop(
+            self.state, self.inbox, tel, flt, lanes, fence = self._closed_loop(
                 self.state, self.inbox, ticks, props, self._tel(),
-                self._flt(), sched, rounds
+                self._flt(), self._lanes, sched, rounds
             )
+        self._lanes = lanes
         self._set_tel(tel)
         self._set_flt(flt)
         return fence
@@ -419,6 +428,17 @@ class MultiRaftEngine:
         )
         is_lead = role == LEADER
         return np.where(is_lead.any(axis=1), is_lead.argmax(axis=1), -1)
+
+    def lane_rounds(self) -> np.ndarray:
+        """[NUM_KINDS] closed-loop rounds (``run_rounds``,
+        ``run_rounds_pipelined``; ``step_round`` is not counted) in
+        which each inbox lane — vote, append, heartbeat and their
+        responses, in kind order — held a message for any instance:
+        the rounds in which the vectorized deliver ran that lane's
+        fold for the batch (the lane skip, step._deliver_all), or
+        would have under another shape. Accumulated in the scan's
+        carry; one host gather, no per-round sync."""
+        return np.asarray(self._lanes)
 
     def commits(self) -> np.ndarray:
         """Per-instance commit watermarks [G, R] — the host applies

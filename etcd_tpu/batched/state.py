@@ -44,24 +44,22 @@ DELIVER_SHAPES = ("lanes", "merged", "vectorized")
 
 
 def default_deliver_shape() -> str:
-    """Platform default for deliver_shape="auto".
-
-    CPU takes the vectorized shape (the ISSUE 14 same-day A/B winner —
-    BENCH_NOTES r14); TPU keeps the merged scans, the only shape ever
-    tuned ON DEVICE (+4.4% vs lanes, BENCH_NOTES r5) — the r5 lesson is
-    that CPU predictions invert on TPU, so vectorized must win a chip
-    run before it becomes the TPU default. Two platforms exist; any
-    other backend raises rather than run an untested default."""
+    """What deliver_shape="auto" resolves to: the vectorized shape, on
+    every platform the engine runs on. It won the same-day CPU A/B of
+    ISSUE 14 (BENCH_NOTES r14) and, in ISSUE 29, the chip: no
+    per-sender ``while`` is left in deliver (PERF.md section 6, "PR
+    29", has the v5e's numbers against the merged scans, which were
+    the TPU default until then on a reading against ``lanes`` alone).
+    Any backend but 'tpu' and 'cpu' raises rather than run a default
+    nobody measured there."""
     import jax
 
     platform = jax.default_backend()
-    if platform == "cpu":
-        return "vectorized"
-    if platform == "tpu":
-        return "merged"
-    raise RuntimeError(
-        f"no default deliver shape for JAX backend {platform!r}: "
-        "etcd_tpu runs on 'tpu' and (for tests) 'cpu'")
+    if platform not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"no default deliver shape for JAX backend {platform!r}: "
+            "etcd_tpu runs on 'tpu' and (for tests) 'cpu'")
+    return "vectorized"
 
 
 class BatchedConfig(NamedTuple):
@@ -97,9 +95,10 @@ class BatchedConfig(NamedTuple):
     #                 senders ascending (kind-major). Small bodies.
     # * "merged":     two length-R scans (request/response halves,
     #                 sender-major), 3x bigger fused bodies, a third of
-    #                 the loop-carry round trips. The r5 on-TPU winner
-    #                 (+4.4% vs lanes) — kept as the accelerator
-    #                 fallback and differential baseline.
+    #                 the loop-carry round trips. The TPU default
+    #                 until ISSUE 29 (+4.4% vs lanes on the chip,
+    #                 BENCH_NOTES r5) — kept as the differential
+    #                 baseline until ROADMAP D1 deletes the losers.
     # * "vectorized": NO sender scan. Response lanes fold as masked
     #                 segment reductions over the sender axis (one
     #                 commit recompute per lane); request lanes resolve
@@ -108,10 +107,9 @@ class BatchedConfig(NamedTuple):
     #                 once, losers answered with scattered stale
     #                 nudges. The whole round is then one straight-line
     #                 fused region — no scan barriers between phases.
-    # * "auto":       resolved per platform at engine/rawnode build
-    #                 time (default_deliver_shape): CPU → vectorized,
-    #                 TPU → merged until a chip run re-tunes ON DEVICE
-    #                 (the r5 lesson: CPU predictions inverted on TPU).
+    # * "auto":       resolved at engine/rawnode build time
+    #                 (default_deliver_shape): vectorized, on CPU and
+    #                 on TPU alike since the ISSUE 29 chip runs.
     deliver_shape: str = "auto"
     # Store the bounded hot lanes (role/vote/lead enums, vote tallies,
     # progress states, inflight counts) in int8/int16 between rounds:

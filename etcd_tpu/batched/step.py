@@ -834,12 +834,15 @@ def _deliver_all(cfg: BatchedConfig, iid, slot, st: BatchedState,
     * ``"merged"``: two length-R scans — request half (kinds
       0..NUM_REQ_KINDS-1) then response half — each body chaining the
       three kind handlers for one sender (sender-major order within a
-      half). Same 18 handler applications, 3x bigger fused bodies, a
-      third of the loop-carry round trips; the r5 on-TPU winner.
+      half). Same 6R handler applications, 3x bigger fused bodies, a
+      third of the loop-carry round trips; what "auto" meant on TPU
+      until ISSUE 29.
     * ``"vectorized"``: NO sender scan (see _deliver_vectorized) —
       response lanes fold as masked reductions, request lanes resolve
-      one winner per lane, and the full BatchedState stops round-
-      tripping through a loop carry 6R (or 2R) times per round.
+      one winner per lane (six lane folds a round for 6R handler
+      applications), and the full BatchedState stops round-tripping
+      through a loop carry 6R (or 2R) times per round. What "auto"
+      resolves to on every platform (state.default_deliver_shape).
 
     Every shape collects responses for the request lanes and routes
     them back in lanes ``k + NUM_REQ_KINDS``, and the shadow oracle
@@ -987,13 +990,23 @@ def _vec_lane_request(cfg: BatchedConfig, iid, slot, st: BatchedState,
     the gate), so the winner — highest term, lowest sender — runs the
     full per-message handler once, and every loser is answered with
     the stale-leader nudge it would have received anyway, computed
-    against the post-winner state (ref: raft.go:885-905)."""
+    against the post-winner state (ref: raft.go:885-905).
+
+    Returns the state and the lane's answer in its SMALL form, (the
+    winner's response, the winner's sender, the losers' nudge mask):
+    ``_vec_request_resps`` widens it to the [R] response slots. The
+    two are apart because this half runs under the lane's lax.cond:
+    slots built inside a branch from per-instance scalars alone have
+    no operand to take a layout from, and the TPU compiler then lays
+    them out instance-major (R=3 padded to a 4x128 tile, 512 times
+    the bytes) and copies them back at the branch's edge."""
     r = cfg.num_replicas
     senders = jnp.arange(r, dtype=I32)
     t_max = jnp.max(jnp.where(m.valid, m.term, -1))
-    at_w = senders == _argfirst(m.valid & (m.term == t_max))
+    w = _argfirst(m.valid & (m.term == t_max))
+    at_w = senders == w
     mw = _gather_msg(m, at_w)
-    st2, wresp = handler(cfg, iid, slot, st, mw, _pick(senders, at_w))
+    st2, wresp = handler(cfg, iid, slot, st, mw, w)
 
     nudge = (
         m.valid & ~at_w & (m.term < st2.term)
@@ -1003,11 +1016,21 @@ def _vec_lane_request(cfg: BatchedConfig, iid, slot, st: BatchedState,
         # A losing MsgTimeoutNow never draws a response
         # (ref: raft.go:885-905 applies to leader traffic only).
         nudge = nudge & (m.type != T_TIMEOUT_NOW)
+    return st2, (wresp, w, nudge)
+
+
+def _vec_request_resps(cfg: BatchedConfig, st: BatchedState, answer,
+                       occupied) -> MsgSlots:
+    """[R] response slots of a request lane from ``_vec_lane_request``'s
+    answer and the post-lane state; an unoccupied lane answers nothing."""
+    wresp, w, nudge = answer
+    r = cfg.num_replicas
+    at_w = jnp.arange(r, dtype=I32) == w
     resp = empty_msgs((r,), cfg.max_ents_per_msg)
-    resp = resp._replace(
+    return _sel(occupied, resp._replace(
         valid=jnp.where(at_w, wresp.valid, nudge),
         type=jnp.where(at_w, wresp.type, T_APP_RESP),
-        term=jnp.where(at_w, wresp.term, st2.term),
+        term=jnp.where(at_w, wresp.term, st.term),
         log_term=jnp.where(at_w, wresp.log_term, 0),
         index=jnp.where(at_w, wresp.index, 0),
         commit=jnp.where(at_w, wresp.commit, 0),
@@ -1016,19 +1039,21 @@ def _vec_lane_request(cfg: BatchedConfig, iid, slot, st: BatchedState,
         n_ents=jnp.where(at_w, wresp.n_ents, 0),
         ctx=jnp.where(at_w, wresp.ctx, 0),
         ent_terms=jnp.where(at_w[:, None], wresp.ent_terms[None, :], 0),
-    )
-    return st2, resp
+    ), resp)
 
 
 def _vec_lane_vote(cfg: BatchedConfig, iid, slot, st: BatchedState,
-                   m: MsgSlots):
+                   m: MsgSlots, last_term):
     """Lane KIND_VOTE, vectorized. State effects come only from T_VOTE
     at the highest surviving term: one depose (become_follower) and at
     most one recorded grant — if the vote is already cast only its
     holder can re-grant; if it is free the first up-to-date sender
     takes it (sender-ascending, exactly the sequential setdefault).
     Prevotes never mutate state, so all prevote responses evaluate
-    against the post-vote state in one masked shot."""
+    against the post-vote state in one masked shot. ``last_term`` is
+    the term of the receiver's last log entry; the caller reads it,
+    so ``st.log_term`` is not looked at here (_deliver_vectorized
+    hands in a state without its ring)."""
     r = cfg.num_replicas
     senders = jnp.arange(r, dtype=I32)
     is_vote = m.type == T_VOTE
@@ -1055,9 +1080,6 @@ def _vec_lane_vote(cfg: BatchedConfig, iid, slot, st: BatchedState,
     )
 
     eq = vmask & (m.term == st1.term)
-    last_term = term_at(
-        st1.log_term, st1.snap_index, st1.snap_term, st1.last, st1.last
-    )
     up_to_date = (m.log_term > last_term) | (
         (m.log_term == last_term) & (m.index >= st1.last)
     )
@@ -1328,14 +1350,44 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
             return jnp.any(m.valid)
         return lane_any[k]
 
-    def with_resp(k, fn, stx):
-        m = lane(k)
-        return jax.lax.cond(
-            occupied(k, m),
-            lambda sty, mx: fn(sty, mx),
+    def votes(stx):
+        # No vote touches the log, so the ring goes round this cond
+        # and not through it, and the one ring read the lane needs
+        # (the term of the receiver's last entry) is made before it,
+        # behind a barrier that keeps the compiler from sinking it
+        # into the branch: a ring the taken branch reads enters the
+        # cond ring-minor on TPU, and both branches then relayout all
+        # of it, every round (two 25 MB copies at G=65,536; PERF.md
+        # section 6, PR 29).
+        m = lane(KIND_VOTE)
+        last_term = jax.lax.optimization_barrier(term_at(
+            stx.log_term, stx.snap_index, stx.snap_term, stx.last,
+            stx.last))
+        sty, resp = jax.lax.cond(
+            occupied(KIND_VOTE, m),
+            lambda sty, mx: _vec_lane_vote(
+                cfg, iid, slot, sty, mx, last_term),
             lambda sty, mx: (sty, no_resp),
+            stx._replace(log_term=jnp.zeros((0,), I32)), m,
+        )
+        return sty._replace(log_term=stx.log_term), resp
+
+    def request(k, handler, stx):
+        # The cond holds the winner's handler; the [R] response slots
+        # are widened after it (see _vec_lane_request on why).
+        m = lane(k)
+        occ = occupied(k, m)
+        no_answer = (empty_msgs((), cfg.max_ents_per_msg),
+                     jnp.zeros((), I32),
+                     jnp.zeros((cfg.num_replicas,), bool))
+        stx, answer = jax.lax.cond(
+            occ,
+            lambda sty, mx: _vec_lane_request(
+                cfg, iid, slot, sty, mx, handler, hb_lane=k == KIND_HB),
+            lambda sty, mx: (sty, no_answer),
             stx, m,
         )
+        return stx, _vec_request_resps(cfg, stx, answer, occ)
 
     def state_only(k, fn, stx):
         m = lane(k)
@@ -1346,17 +1398,9 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
             stx, m,
         )
 
-    st, r0 = with_resp(
-        KIND_VOTE, lambda s, m: _vec_lane_vote(cfg, iid, slot, s, m),
-        st)
-    st, r1 = with_resp(
-        KIND_APP,
-        lambda s, m: _vec_lane_request(
-            cfg, iid, slot, s, m, _lane_app, hb_lane=False), st)
-    st, r2 = with_resp(
-        KIND_HB,
-        lambda s, m: _vec_lane_request(
-            cfg, iid, slot, s, m, _lane_hb, hb_lane=True), st)
+    st, r0 = votes(st)
+    st, r1 = request(KIND_APP, _lane_app, st)
+    st, r2 = request(KIND_HB, _lane_hb, st)
     st = state_only(
         KIND_VOTE_RESP,
         lambda s, m: _vec_lane_vote_resp(cfg, iid, slot, s, m), st)

@@ -26,9 +26,9 @@ def make_bench_engine(groups: int, lanes_minor: bool = True,
     plus the steady 2-entries-per-group-per-round proposal vector.
 
     ``deliver_shape`` is the ISSUE 14 A/B axis (lanes | merged |
-    vectorized; "auto" = platform default) — every headline number
-    names the concrete shape it ran (engine.cfg.deliver_shape after
-    resolution).
+    vectorized; "auto" = vectorized, on the chip too since ISSUE 29:
+    state.default_deliver_shape) — every headline number names the
+    concrete shape it ran (engine.cfg.deliver_shape after resolution).
 
     ``telemetry`` compiles the kernel telemetry plane in (ISSUE 4):
     the headline number stays telemetry-off; BENCH_TELEMETRY=1 /

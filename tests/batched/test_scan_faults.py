@@ -170,7 +170,7 @@ def test_without_a_schedule_the_scan_gains_no_input_and_no_key():
     eng = MultiRaftEngine(CELL)
     keys = set(sentinels.compile_keys("closed_loop"))
     args = (eng.state, eng.inbox, eng._zeros_b, eng._zeros_i, eng._tel(),
-            eng._flt())
+            eng._flt(), eng._lanes)
     plain = eng._closed_loop.lower(*args, None, 16)
     faulty = eng._closed_loop.lower(
         *args, jnp.zeros((16, 3), bool), 16)
