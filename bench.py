@@ -49,14 +49,13 @@ def _note(msg: str) -> None:
 
 
 def _make_engine(groups: int, lanes_minor: bool,
-                 deliver_shape: str = "auto",
                  telemetry: bool = False,
                  fleet: bool = False):
     # Canonical config + setup shared with tools/frontier_sweep.py so
     # the two tools' numbers stay methodologically comparable.
     from etcd_tpu.tools.benchlib import make_bench_engine
 
-    return make_bench_engine(groups, lanes_minor, deliver_shape,
+    return make_bench_engine(groups, lanes_minor,
                              telemetry=telemetry, fleet=fleet)
 
 
@@ -90,20 +89,6 @@ def main() -> None:
     layout_env = os.environ.get("BENCH_LAYOUT", "")
     if layout_env and layout_env not in ("major", "minor"):
         raise SystemExit(f"BENCH_LAYOUT must be major|minor, got {layout_env!r}")
-    # Deliver shape (ISSUE 14 A/B axis): the default lives in
-    # state.default_deliver_shape (vectorized: the r14 same-day winner
-    # on CPU and, since ISSUE 29, what the chip runs too).
-    # BENCH_DELIVER_SHAPE=lanes|merged|vectorized pins it for A/B rows.
-    shape_env = os.environ.get("BENCH_DELIVER_SHAPE", "")
-    if os.environ.get("BENCH_MERGED_DELIVER", ""):
-        raise SystemExit(
-            "BENCH_MERGED_DELIVER was replaced by "
-            "BENCH_DELIVER_SHAPE=lanes|merged|vectorized (ISSUE 14)")
-    if shape_env and shape_env not in ("lanes", "merged", "vectorized"):
-        raise SystemExit(
-            "BENCH_DELIVER_SHAPE must be lanes|merged|vectorized, "
-            f"got {shape_env!r}")
-    deliver_shape = shape_env or "auto"
     pipe_env = os.environ.get("BENCH_PIPELINE", "")
     if pipe_env and pipe_env not in ("0", "1"):
         raise SystemExit(f"BENCH_PIPELINE must be 0|1, got {pipe_env!r}")
@@ -127,8 +112,7 @@ def main() -> None:
     # 128-wide vector lanes) unless pinned.
     lanes_minor = layout_env != "major"
     t0 = time.perf_counter()
-    eng, props = _make_engine(GROUPS, lanes_minor, deliver_shape,
-                              telemetry, fleet)
+    eng, props = _make_engine(GROUPS, lanes_minor, telemetry, fleet)
     _note(f"main G={GROUPS} built+compiled in {time.perf_counter()-t0:.1f}s")
     rate = _rate(eng, props, 16, 8, pipelined=pipelined)
     _note(f"main rate: {rate:.0f} group-rounds/s")

@@ -56,9 +56,8 @@ class MultiRaftEngine:
         return spans.span(name, 0, call, engine=self._serial, **stats)
 
     def _init(self, cfg: BatchedConfig, start_index: int) -> None:
-        # deliver_shape="auto" resolves to the platform default here
-        # (state.default_deliver_shape), so self.cfg always names the
-        # concrete shape the compiled round actually runs.
+        # deliver_shape="auto" becomes "vectorized" here, so self.cfg
+        # reads as the compile key does.
         self.cfg = cfg = cfg.validate().resolved()
         # Round programs are expensive to build; cache compilations
         # across processes.
@@ -130,8 +129,8 @@ class MultiRaftEngine:
             def body(carry, cut):
                 st, inbox, tel, flt, lanes = carry
                 # The round's own occupancy vector (step_round's
-                # lane_any, which XLA shares): what the vectorized
-                # deliver skips on, counted whatever the shape.
+                # lane_any, which XLA shares): what deliver's lane
+                # conds skip on.
                 lanes = lanes + jnp.any(inbox.valid, axis=(0, 1))
                 iso = self._zeros_b
                 # jitlint: waive(tracer-branch) -- as above: a scan without xs hands its body None
@@ -434,10 +433,9 @@ class MultiRaftEngine:
         ``run_rounds_pipelined``; ``step_round`` is not counted) in
         which each inbox lane — vote, append, heartbeat and their
         responses, in kind order — held a message for any instance:
-        the rounds in which the vectorized deliver ran that lane's
-        fold for the batch (the lane skip, step._deliver_all), or
-        would have under another shape. Accumulated in the scan's
-        carry; one host gather, no per-round sync."""
+        the rounds in which deliver ran that lane's fold for the
+        batch (the lane skip, step._deliver_vectorized). Accumulated
+        in the scan's carry; one host gather, no per-round sync."""
         return np.asarray(self._lanes)
 
     def commits(self) -> np.ndarray:

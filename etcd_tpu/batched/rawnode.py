@@ -232,9 +232,9 @@ class BatchedRawNode:
         start_index: int = 0,
         mesh: Optional["object"] = None,
     ) -> None:
-        # Resolve deliver_shape="auto" to the platform default so the
-        # hosted path and the closed-loop engine pick the same compiled
-        # round program for one logical config.
+        # Resolve deliver_shape="auto" so the hosted path and the
+        # closed-loop engine pick the same compiled round program for
+        # one logical config.
         self.cfg = cfg = cfg.validate().resolved()
         from .compile_cache import enable_compile_cache
 

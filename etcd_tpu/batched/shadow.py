@@ -123,17 +123,15 @@ class ShadowCluster:
         deliver_shape: str = "auto",
         max_props: int = 0,
     ):
-        # Mirrors BatchedConfig.deliver_shape: the device's delivery
-        # order is kind-major (six lane scans, "lanes"), sender-major
-        # within request/response halves ("merged"), or the vectorized
-        # order contract ("vectorized" — see _deliver_vectorized_target
-        # below). "auto" resolves to the same platform default the
-        # engine resolves, so default-config engine↔shadow pairs always
-        # agree on the order.
+        # The delivery order. The program has one, "vectorized" (step.py
+        # _deliver_vectorized; "auto" names it too, as in BatchedConfig):
+        # see _deliver_vectorized_target below. "lanes" (kind-major,
+        # senders ascending) and "merged" (sender-major within the
+        # request and response halves) are orders no program runs any
+        # more; they stay until ROADMAP D1b because tests/benchmark
+        # steps this oracle against its frozen copy in each.
         if deliver_shape == "auto":
-            from .state import default_deliver_shape
-
-            deliver_shape = default_deliver_shape()
+            deliver_shape = "vectorized"
         self.deliver_shape = deliver_shape
         self.r = num_replicas
         self.nodes: List[RawNode] = []
@@ -204,11 +202,9 @@ class ShadowCluster:
         transfers = transfers or {}
         drops = set(drop_pairs)
 
-        # Phase 1: deliver in the exact order of the device's
-        # configured deliver shape (step.py _deliver_all): kind-major
-        # for the six lane scans ("lanes"), request/response halves
-        # sender-major for the two merged scans ("merged"), or the
-        # vectorized order contract ("vectorized").
+        # Phase 1: deliver in the configured order (see __init__): the
+        # program's ("vectorized", per target below), or one of the two
+        # message-at-a-time orders.
         if self.deliver_shape == "merged":
             order = [
                 (sender, kind)
@@ -216,7 +212,7 @@ class ShadowCluster:
                 for sender in range(self.r)
                 for kind in kinds
             ]
-        else:  # "lanes" (the vectorized path orders per target below)
+        else:  # "lanes"
             order = [
                 (sender, kind)
                 for kind in range(NUM_KINDS)

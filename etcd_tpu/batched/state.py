@@ -38,29 +38,6 @@ MAX_WIRE_ENTS = 255
 # products of residues stays below 2^32 (rand_timeout).
 MAX_HASHED_TIMEOUT = 46340
 
-# The shippable deliver shapes (BatchedConfig.deliver_shape); "auto"
-# resolves to one of these per platform at build time.
-DELIVER_SHAPES = ("lanes", "merged", "vectorized")
-
-
-def default_deliver_shape() -> str:
-    """What deliver_shape="auto" resolves to: the vectorized shape, on
-    every platform the engine runs on. It won the same-day CPU A/B of
-    ISSUE 14 (BENCH_NOTES r14) and, in ISSUE 29, the chip: no
-    per-sender ``while`` is left in deliver (PERF.md section 6, "PR
-    29", has the v5e's numbers against the merged scans, which were
-    the TPU default until then on a reading against ``lanes`` alone).
-    Any backend but 'tpu' and 'cpu' raises rather than run a default
-    nobody measured there."""
-    import jax
-
-    platform = jax.default_backend()
-    if platform not in ("cpu", "tpu"):
-        raise RuntimeError(
-            f"no default deliver shape for JAX backend {platform!r}: "
-            "etcd_tpu runs on 'tpu' and (for tests) 'cpu'")
-    return "vectorized"
-
 
 class BatchedConfig(NamedTuple):
     """Static (compile-time) engine configuration."""
@@ -86,30 +63,13 @@ class BatchedConfig(NamedTuple):
     # stays [N, ...]; the jitted round transposes at entry/exit.
     # bench.py runs this layout on the chip.
     lanes_minor: bool = False
-    # Deliver shape: how one instance's [R, K] inbox is folded into
-    # state (step.py _deliver_all). Semantically equivalent protocols
-    # with DIFFERENT delivery orders (the shadow oracle mirrors
-    # whichever is set; see step.py for each shape's order contract):
-    #
-    # * "lanes":      six length-R lax.scans, one per kind lane,
-    #                 senders ascending (kind-major). Small bodies.
-    # * "merged":     two length-R scans (request/response halves,
-    #                 sender-major), 3x bigger fused bodies, a third of
-    #                 the loop-carry round trips. The TPU default
-    #                 until ISSUE 29 (+4.4% vs lanes on the chip,
-    #                 BENCH_NOTES r5) — kept as the differential
-    #                 baseline until ROADMAP D1 deletes the losers.
-    # * "vectorized": NO sender scan. Response lanes fold as masked
-    #                 segment reductions over the sender axis (one
-    #                 commit recompute per lane); request lanes resolve
-    #                 one effective winner per lane via a (term,
-    #                 sender) tournament and apply the handler body
-    #                 once, losers answered with scattered stale
-    #                 nudges. The whole round is then one straight-line
-    #                 fused region — no scan barriers between phases.
-    # * "auto":       resolved at engine/rawnode build time
-    #                 (default_deliver_shape): vectorized, on CPU and
-    #                 on TPU alike since the ISSUE 29 chip runs.
+    # The name of the one delivery order (step.py _deliver_vectorized;
+    # the shadow oracle mirrors it): "vectorized", or "auto", which
+    # resolved() maps to it. Not a choice any more: the field stays
+    # because the benchmark's drivers and its frozen reference
+    # (benchmark/drivers, benchmark/reference/shadow.py) pass and print
+    # it and tests/obs/test_spans.py builds a config with it; ROADMAP
+    # D1b removes it once a `benchmark` PR has dropped those reads.
     deliver_shape: str = "auto"
     # Store the bounded hot lanes (role/vote/lead enums, vote tallies,
     # progress states, inflight counts) in int8/int16 between rounds:
@@ -189,10 +149,10 @@ class BatchedConfig(NamedTuple):
             raise ValueError(
                 f"max_inflight={self.max_inflight} does not fit the "
                 "int16 inflight lane; lower it or disable narrow_lanes")
-        if self.deliver_shape not in ("auto",) + DELIVER_SHAPES:
+        if self.deliver_shape not in ("auto", "vectorized"):
             raise ValueError(
-                f"deliver_shape={self.deliver_shape!r} not in "
-                f"{('auto',) + DELIVER_SHAPES}")
+                f"deliver_shape={self.deliver_shape!r}: deliver has one "
+                "order, 'vectorized' ('auto' names it too)")
         et = self.election_timeout
         if et < 1 or (et > MAX_HASHED_TIMEOUT and et & (et - 1)):
             raise ValueError(
@@ -237,13 +197,13 @@ class BatchedConfig(NamedTuple):
         )
 
     def resolved(self) -> "BatchedConfig":
-        """Resolve deliver_shape="auto" to the platform default. Every
-        engine/rawnode/step builder resolves BEFORE keying a compile
-        (step._step_round_jit caches per config), so "auto" and its
-        concrete resolution share one program."""
+        """The config with deliver_shape="auto" spelled "vectorized".
+        Every engine/rawnode/step builder resolves BEFORE keying a
+        compile (step._step_round_jit caches per config), so the two
+        spellings share one program."""
         if self.deliver_shape != "auto":
             return self
-        return self._replace(deliver_shape=default_deliver_shape())
+        return self._replace(deliver_shape="vectorized")
 
 
 class BatchedState(NamedTuple):

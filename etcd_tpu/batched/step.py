@@ -20,8 +20,8 @@ routing between instances of the same group is an exchange of the
 and selects along N (see route()), no scatters, no host round-trips. A
 round is one jitted program:
 
-    deliver (shape-configured: lane scans, merged scans, or the
-    scan-free vectorized fold) → tick → control → propose → emit → route
+    deliver (each inbox lane one fold over the sender axis, no scan)
+    → tick → control → propose → emit → route
 
 Determinism: randomized election timeouts use a per-instance hash of
 (instance id, reset count), reproducible by the host oracle for
@@ -78,7 +78,7 @@ from .state import (
 # kind-k request routes back in lane ``k + NUM_REQ_KINDS`` — lane 3
 # carries vote responses, lane 4 append responses, lane 5 heartbeat
 # responses. Everything that splits or scatters lanes derives from
-# NUM_REQ_KINDS: the deliver shapes' request/response split, the
+# NUM_REQ_KINDS: deliver's request/response split, the
 # round's response scatter (``out[:, NUM_REQ_KINDS:]`` in
 # _step_round_jit), and route()'s no-op on lane indexes (responses are
 # already placed in their response lane BEFORE the sender/target
@@ -196,14 +196,9 @@ def _sel(cond, a, b):
     return jax.tree.map(lambda x, y: jnp.where(cond, x, y), a, b)
 
 
-def _pick(vec, at):
-    """vec[s] for a traced s, as compare+reduce (at = peers == s):
-    traced-index gathers serialize on TPU, one-hot reads don't."""
-    return jnp.sum(jnp.where(at, vec, 0), axis=-1)
-
-
 def _pick_b(vec, at):
-    """Bool variant of _pick."""
+    """Bool vec[s] for a traced s, as compare+reduce (at = peers == s):
+    traced-index gathers serialize on TPU, one-hot reads don't."""
     return jnp.any(vec & at, axis=-1)
 
 
@@ -359,16 +354,17 @@ def _paused(cfg: BatchedConfig, st: BatchedState):
 
 
 # -----------------------------------------------------------------------------
-# Per-message delivery (one inbox slot for one instance)
+# Per-message delivery (one request for one instance): what a request
+# lane's winner runs through (_vec_lane_request)
 # -----------------------------------------------------------------------------
 
 
 def _term_gate(cfg: BatchedConfig, iid, slot, st: BatchedState, m: MsgSlots,
                from_slot):
-    """raft.Step's term handling (ref: raft.go:849-920), shared by every
-    lane handler. Returns (st1, dead, lower, stale_resp_needed) where
-    st1 is post-become-follower state, `dead` kills the message
-    entirely, `lower` routes to the stale path."""
+    """raft.Step's term handling (ref: raft.go:849-920), shared by the
+    two request handlers below. Returns (st1, dead, lower) where st1
+    is post-become-follower state, `dead` kills the message entirely,
+    `lower` routes to the stale path."""
     higher = m.term > st.term
     lower = m.term < st.term
 
@@ -395,59 +391,10 @@ def _term_gate(cfg: BatchedConfig, iid, slot, st: BatchedState, m: MsgSlots,
     return st1, dead, lower
 
 
-# -- lane handlers: each processes ONE inbox lane's message for one
-# instance, implementing only the types that can land in that lane
+# -- request handlers: each processes ONE message of its inbox lane for
+# one instance, implementing only the types that can land in that lane
 # (lanes are capacity classes — the specialization is what keeps the
 # per-slot cost low; ref: raft.go:991-1473 step* dispatch).
-
-
-def _lane_vote(cfg: BatchedConfig, iid, slot, st: BatchedState, m: MsgSlots,
-               from_slot):
-    """Lane KIND_VOTE: T_VOTE / T_PREVOTE requests (ref: raft.go:930-978)."""
-    no_resp = empty_msgs((), cfg.max_ents_per_msg)
-    st1, dead, lower = _term_gate(cfg, iid, slot, st, m, from_slot)
-
-    last_term = term_at(
-        st1.log_term, st1.snap_index, st1.snap_term, st1.last, st1.last
-    )
-    can_vote = (
-        (st1.vote == from_slot + 1)
-        | ((st1.vote == 0) & (st1.lead == 0))
-        | ((m.type == T_PREVOTE) & (m.term > st1.term))
-    )
-    up_to_date = (m.log_term > last_term) | (
-        (m.log_term == last_term) & (m.index >= st1.last)
-    )
-    # Durability-fenced instances grant nothing (vote or pre-vote): a
-    # fence means this replica verifiably lost fsync'd-acked state at
-    # its last crash, so neither its log comparison nor its persisted
-    # vote can back the election-safety promises a grant makes
-    # (protocol-aware recovery, FAST'18).
-    grant = can_vote & up_to_date & ~st1.fenced
-    resp_type = jnp.where(m.type == T_VOTE, T_VOTE_RESP, T_PREVOTE_RESP)
-    vote_resp = no_resp._replace(
-        valid=True,
-        type=resp_type,
-        term=jnp.where(grant, m.term, st1.term),
-        reject=~grant,
-    )
-    record_real = grant & (m.type == T_VOTE)
-    st_vote = st1._replace(
-        election_elapsed=jnp.where(record_real, 0, st1.election_elapsed),
-        vote=jnp.where(record_real, from_slot + 1, st1.vote),
-    )
-
-    # Stale pre-vote: reject with our term (deposes the sender).
-    stale_prevote = lower & (m.type == T_PREVOTE)
-    resp_stale = no_resp._replace(
-        valid=stale_prevote,
-        type=jnp.asarray(T_PREVOTE_RESP, I32),
-        term=st.term,
-        reject=True,
-    )
-    st_out = _sel(dead | lower, st, st_vote)
-    resp = _sel(dead, no_resp, _sel(lower, resp_stale, vote_resp))
-    return st_out, resp
 
 
 def _leader_traffic_prelude(cfg, iid, slot, st1, m, from_slot):
@@ -530,44 +477,6 @@ def _lane_hb(cfg: BatchedConfig, iid, slot, st: BatchedState, m: MsgSlots,
     st_out = _sel(dead | lower, st, st_live)
     resp = _sel(dead, no_resp, _sel(lower, resp_stale, resp_live))
     return st_out, resp
-
-
-def _lane_vote_resp(cfg: BatchedConfig, iid, slot, st: BatchedState,
-                    m: MsgSlots, from_slot):
-    """Lane KIND_VOTE_RESP: T_VOTE_RESP / T_PREVOTE_RESP
-    (ref: raft.go:1399-1414)."""
-    st1, dead, lower = _term_gate(cfg, iid, slot, st, m, from_slot)
-    is_cand = (st1.role == CANDIDATE) | (st1.role == PRECANDIDATE)
-    my_resp_type = jnp.where(
-        st1.role == PRECANDIDATE, T_PREVOTE_RESP, T_VOTE_RESP
-    )
-    st_vr = _candidate_vote_resp(cfg, iid, slot, st1, m, from_slot)
-    st_live = _sel(is_cand & (m.type == my_resp_type), st_vr, st1)
-    return _sel(dead | lower, st, st_live)
-
-
-def _lane_app_resp(cfg: BatchedConfig, iid, slot, st: BatchedState,
-                   m: MsgSlots, from_slot):
-    """Lane KIND_APP_RESP: T_APP_RESP (ref: raft.go:1106-1283)."""
-    st1, dead, lower = _term_gate(cfg, iid, slot, st, m, from_slot)
-    is_leader = st1.role == LEADER
-    st_ar = _leader_app_resp(cfg, st1, m, from_slot)
-    st_live = _sel(is_leader & (m.type == T_APP_RESP), st_ar, st1)
-    return _sel(dead | lower, st, st_live)
-
-
-def _lane_hb_resp(cfg: BatchedConfig, iid, slot, st: BatchedState,
-                  m: MsgSlots, from_slot):
-    """Lane KIND_HB_RESP: T_HB_RESP, plus T_APP_RESP stale-leader nudges
-    that route back in this lane (ref: raft.go:1284-1309)."""
-    st1, dead, lower = _term_gate(cfg, iid, slot, st, m, from_slot)
-    is_leader = st1.role == LEADER
-    st_hr = _leader_hb_resp(cfg, st1, m, from_slot)
-    st_ar = _leader_app_resp(cfg, st1, m, from_slot)
-    st_live = st1
-    st_live = _sel(is_leader & (m.type == T_HB_RESP), st_hr, st_live)
-    st_live = _sel(is_leader & (m.type == T_APP_RESP), st_ar, st_live)
-    return _sel(dead | lower, st, st_live)
 
 
 def _handle_append(cfg: BatchedConfig, st: BatchedState, m: MsgSlots):
@@ -654,303 +563,27 @@ def _handle_snapshot(cfg: BatchedConfig, st: BatchedState, m: MsgSlots):
     return st_out, resp
 
 
-def _leader_app_resp(cfg: BatchedConfig, st: BatchedState, m: MsgSlots, s):
-    """Leader MsgAppResp handling (ref: raft.go:1106-1283)."""
-    r = st.match.shape[-1]
-    peers = jnp.arange(r, dtype=I32)
-    at_s = peers == s
-    prog_ok = _pick_b(_repl_targets(st), at_s)  # progress exists for
-    # voters+learners
-
-    st = st._replace(recent_active=jnp.where(at_s, True, st.recent_active))
-
-    # --- rejected: move next back using the hint (ref: raft.go:1130-1236) ---
-    hint = jnp.where(
-        m.log_term > 0,
-        find_conflict_by_term(
-            st.log_term, st.snap_index, st.snap_term, st.last, m.reject_hint,
-            m.log_term,
-        ),
-        m.reject_hint,
-    )
-    match_s, next_s = _pick(st.match, at_s), _pick(st.next, at_s)
-    in_repl = _pick(st.pr_state, at_s) == REPLICATE
-    stale_rej = jnp.where(
-        in_repl, m.index <= match_s, next_s - 1 != m.index
-    )
-    dec_next = jnp.where(
-        in_repl,
-        match_s + 1,
-        jnp.maximum(jnp.minimum(m.index, hint + 1), 1),
-    )
-    # On a genuine rejection a replicating peer drops to probing
-    # (becomeProbe: next=match+1, reset probe bookkeeping).
-    #
-    # Stale-high match repair: a follower that rejects the probe at
-    # next-1 with a hint BELOW our recorded match has verifiably lost
-    # entries it once acked — reachable only when durability was
-    # violated under it (torn WAL tail). The reference keeps match
-    # untouched (its Next >= Match+1 invariant makes this state
-    # unreachable in-model), but keeping it here pins next <= match and
-    # the accept path then drops every re-ack at-or-below match
-    # (`updated` false) — the restarted-member progress wedge: next
-    # frozen, the missing suffix never re-sent. Lowering match is
-    # always safe (commit is monotone and never re-derived), so take
-    # the follower's own evidence and let normal probing re-heal.
-    match_repair = at_s & (dec_next <= match_s)
-    st_rej = st._replace(
-        next=jnp.where(at_s, dec_next, st.next),
-        match=jnp.where(match_repair, dec_next - 1, st.match),
-        probe_sent=jnp.where(at_s, False, st.probe_sent),
-        pr_state=jnp.where(at_s & in_repl, PROBE, st.pr_state),
-        pending_snapshot=jnp.where(at_s & in_repl, 0, st.pending_snapshot),
-        inflight=jnp.where(at_s & in_repl, 0, st.inflight),
-        send_append=st.send_append | (at_s & ~stale_rej),
-    )
-    st_rej = _sel(stale_rej, st, st_rej)
-
-    # --- accepted: MaybeUpdate + state transitions + commit ---
-    old_paused = _pick_b(_paused(cfg, st), at_s)
-    updated = match_s < m.index
-    match = jnp.where(at_s, jnp.maximum(st.match, m.index), st.match)
-    nxt = jnp.where(at_s, jnp.maximum(st.next, m.index + 1), st.next)
-    st_acc = st._replace(
-        match=match,
-        next=nxt,
-        probe_sent=jnp.where(at_s & updated, False, st.probe_sent),
-    )
-
-    pr_state_s = _pick(st.pr_state, at_s)
-    new_match_s = jnp.maximum(match_s, m.index)
-    was_probe = pr_state_s == PROBE
-    was_snap = (pr_state_s == SNAPSHOT) & (
-        new_match_s >= _pick(st.pending_snapshot, at_s)
-    )
-    to_replicate = updated & (was_probe | was_snap)
-    st_acc = st_acc._replace(
-        pr_state=jnp.where(at_s & to_replicate, REPLICATE, st_acc.pr_state),
-        pending_snapshot=jnp.where(
-            at_s & to_replicate, 0, st_acc.pending_snapshot
-        ),
-        inflight=jnp.where(
-            at_s & updated, 0, st_acc.inflight
-        ),  # count+watermark degeneration of FreeLE
-        next=jnp.where(
-            at_s & to_replicate, new_match_s + 1, nxt
-        ),
-    )
-    committed_before = st_acc.commit
-    st_acc = _maybe_commit(st_acc)
-    advanced = st_acc.commit > committed_before
-    # bcastAppend on commit advance; resend to a previously-paused peer;
-    # keep draining while entries remain (ref: raft.go:1259-1276).
-    more = st_acc.last >= _pick(st_acc.next, at_s)
-    st_acc = st_acc._replace(
-        send_append=jnp.where(
-            advanced,
-            st_acc.send_append | _repl_targets(st_acc),
-            st_acc.send_append | (at_s & (old_paused | more)),
-        )
-    )
-    st_acc = _sel(updated, st_acc, st)
-
-    out = _sel(m.reject, st_rej, st_acc)
-    return _sel(prog_ok, out, st)
-
-
-def _leader_hb_resp(cfg: BatchedConfig, st: BatchedState, m: MsgSlots, s):
-    """ref: raft.go:1284-1309, incl. the ReadIndex ack path
-    (read_only.go:68 recvAck + :81 advance, on-device)."""
-    r = st.match.shape[-1]
-    peers = jnp.arange(r, dtype=I32)
-    at_s = peers == s
-    full = st.inflight >= cfg.max_inflight
-    st2 = st._replace(
-        recent_active=jnp.where(at_s, True, st.recent_active),
-        probe_sent=jnp.where(at_s, False, st.probe_sent),
-        inflight=jnp.where(
-            at_s & (st.pr_state == REPLICATE) & full,
-            jnp.maximum(st.inflight - 1, 0),
-            st.inflight,
-        ),
-        send_append=st.send_append | (at_s & (st.match < st.last)),
-    )
-    # ReadIndex ack: a heartbeat response echoing the pending read's
-    # ctx counts toward its quorum; quorum → read_ready.
-    pending = (st2.read_index >= 0) & ~st2.read_ready
-    ack = pending & (m.ctx == st2.read_seq) & (m.ctx > 0)
-    acks = st2.read_acks | (at_s & ack)
-    votes = jnp.where(acks, 1, -1)
-    confirmed = joint_vote_result(
-        votes, st2.voter, st2.voter_out, st2.in_joint
-    ) == VOTE_WON
-    st2 = st2._replace(
-        read_acks=acks,
-        read_ready=st2.read_ready | (pending & confirmed),
-    )
-    return _sel(_pick_b(_repl_targets(st), at_s), st2, st)
-
-
-def _candidate_vote_resp(cfg: BatchedConfig, iid, slot, st: BatchedState,
-                         m: MsgSlots, s):
-    """ref: raft.go:1399-1414."""
-    st2, res = _record_vote_and_tally(st, s, ~m.reject)
-    won, lost = res == VOTE_WON, res == VOTE_LOST
-    if cfg.pre_vote:
-        st_won_pre = _campaign(cfg, st2, iid, slot, False)
-    else:
-        st_won_pre = st2
-    st_won_real = _become_leader(cfg, st2, iid, slot)
-    peers_mask = _repl_targets(st_won_real) & (
-        jnp.arange(st.match.shape[-1], dtype=I32) != slot
-    )
-    st_won_real = st_won_real._replace(
-        send_append=st_won_real.send_append | peers_mask
-    )
-    is_pre = st.role == PRECANDIDATE
-    st_won = _sel(is_pre, st_won_pre, st_won_real)
-    st_lost = _become_follower(cfg, st2, iid, slot, st2.term, 0)
-    return _sel(won, st_won, _sel(lost, st_lost, st2))
-
-
 # -----------------------------------------------------------------------------
-# Phases: deliver (scan) / tick / propose / emit
+# Phases: deliver / tick / control / propose / emit
 # -----------------------------------------------------------------------------
 
 
-_LANE_HANDLERS = (
-    _lane_vote, _lane_app, _lane_hb,
-    _lane_vote_resp, _lane_app_resp, _lane_hb_resp,
-)
-
-
-def _deliver_all(cfg: BatchedConfig, iid, slot, st: BatchedState,
-                 inbox: MsgSlots, lane_any=None):
-    """Deliver this instance's inbox; the shape is configured
-    (cfg.deliver_shape — see state.BatchedConfig for the catalog):
-
-    * ``"lanes"``: six length-R scans, one per kind lane, senders
-      ascending within a lane (kind-major order). Small bodies.
-    * ``"merged"``: two length-R scans — request half (kinds
-      0..NUM_REQ_KINDS-1) then response half — each body chaining the
-      three kind handlers for one sender (sender-major order within a
-      half). Same 6R handler applications, 3x bigger fused bodies, a
-      third of the loop-carry round trips; what "auto" meant on TPU
-      until ISSUE 29.
-    * ``"vectorized"``: NO sender scan (see _deliver_vectorized) —
-      response lanes fold as masked reductions, request lanes resolve
-      one winner per lane (six lane folds a round for 6R handler
-      applications), and the full BatchedState stops round-tripping
-      through a loop carry 6R (or 2R) times per round. What "auto"
-      resolves to on every platform (state.default_deliver_shape).
-
-    Every shape collects responses for the request lanes and routes
-    them back in lanes ``k + NUM_REQ_KINDS``, and the shadow oracle
-    replicates the exact delivery order of the configured shape.
-
-    ``lane_any`` ([K] bool, optional) is the vectorized shape's
-    batch-level lane-occupancy vector: the CALLER computes
-    ``jnp.any(inbox.valid, axis=(0, 1))`` OUTSIDE the instance vmap so
-    each lane's fold sits under a lax.cond with an UNMAPPED predicate
-    — a lane nobody used this round (votes in steady state, heartbeat
-    lanes off-cadence) costs nothing instead of a full masked no-op.
-    An all-invalid lane is an exact identity, so the skip is
-    bit-equivalent; None falls back to per-instance occupancy (the
-    cond degrades to a select under a mapped predicate — correct,
-    just unskipped)."""
-    if cfg.deliver_shape == "vectorized":
-        return _deliver_vectorized(cfg, iid, slot, st, inbox, lane_any)
-    if cfg.deliver_shape == "merged":
-        return _deliver_merged(cfg, iid, slot, st, inbox)
-    if cfg.deliver_shape == "lanes":
-        return _deliver_lanes(cfg, iid, slot, st, inbox)
-    raise ValueError(
-        f"unresolved deliver_shape {cfg.deliver_shape!r}: call "
-        "cfg.resolved() before building a round program")
-
-
-def _deliver_lanes(cfg: BatchedConfig, iid, slot, st: BatchedState,
-                   inbox: MsgSlots):
-    r = cfg.num_replicas
-    senders = jnp.arange(r, dtype=I32)
-
-    req_resps = []
-    for k, handler in enumerate(_LANE_HANDLERS):
-        msgs_k = jax.tree.map(lambda x, _k=k: x[:, _k], inbox)  # [R, ...]
-        if k < NUM_REQ_KINDS:
-            def body(carry, xs, _h=handler):
-                m, s = xs
-                st2, resp = _h(cfg, iid, slot, carry, m, s)
-                return st2, resp
-
-            st, resps_k = jax.lax.scan(body, st, (msgs_k, senders))
-            req_resps.append(resps_k)
-        else:
-            def body(carry, xs, _h=handler):
-                m, s = xs
-                return _h(cfg, iid, slot, carry, m, s), 0
-
-            st, _ = jax.lax.scan(body, st, (msgs_k, senders))
-
-    # [R] per request lane → [R, 3].
-    req = jax.tree.map(
-        lambda a, b, c: jnp.stack((a, b, c), axis=1), *req_resps
-    )
-    return st, req
-
-
-def _deliver_merged(cfg: BatchedConfig, iid, slot, st: BatchedState,
-                    inbox: MsgSlots):
-    r = cfg.num_replicas
-    senders = jnp.arange(r, dtype=I32)
-
-    req_inbox = jax.tree.map(
-        lambda x: x[:, :NUM_REQ_KINDS], inbox)  # [R, 3, ...]
-
-    def req_body(carry, xs):
-        msgs, s = xs  # msgs leaves: [3, ...]
-        resps = []
-        for k, handler in enumerate(_LANE_HANDLERS[:NUM_REQ_KINDS]):
-            m = jax.tree.map(lambda x, _k=k: x[_k], msgs)
-            carry, resp = handler(cfg, iid, slot, carry, m, s)
-            resps.append(resp)
-        return carry, tuple(resps)
-
-    st, (r0, r1, r2) = jax.lax.scan(req_body, st, (req_inbox, senders))
-
-    resp_inbox = jax.tree.map(
-        lambda x: x[:, NUM_REQ_KINDS:], inbox)  # [R, 3, ...]
-
-    def resp_body(carry, xs):
-        msgs, s = xs
-        for k, handler in enumerate(_LANE_HANDLERS[NUM_REQ_KINDS:]):
-            m = jax.tree.map(lambda x, _k=k: x[_k], msgs)
-            carry = handler(cfg, iid, slot, carry, m, s)
-        return carry, 0
-
-    st, _ = jax.lax.scan(resp_body, st, (resp_inbox, senders))
-
-    # [R] per request lane → [R, 3].
-    req = jax.tree.map(
-        lambda a, b, c: jnp.stack((a, b, c), axis=1), r0, r1, r2
-    )
-    return st, req
-
-
 # -----------------------------------------------------------------------------
-# Vectorized deliver (cfg.deliver_shape == "vectorized"): no sender
-# scan. The protocol structure this exploits: per round each sender
-# contributes at most ONE message per lane, response-lane handlers are
+# Deliver (the order named cfg.deliver_shape == "vectorized"): each
+# inbox lane is ONE fold over the sender axis, no per-sender loop. The
+# protocol structure this rests on: per round each sender contributes
+# at most ONE message per lane, the response lanes' effects are
 # order-invariant reductions over distinct progress columns (sender s
 # only ever touches column s; commit/read-quorum are single global
 # recomputes), and request lanes admit at most one effective winner
 # after term gating (one leader per term; votes record at most one
-# grant). Where the sequential scans' sender order DID matter — a
-# higher-term message deposing the receiver mid-lane — the vectorized
-# shape fixes its own order contract, mirrored exactly by the shadow
-# oracle (shadow.ShadowCluster deliver_shape="vectorized"):
+# grant). Where the order of a lane's messages DOES matter — a
+# higher-term message deposing the receiver mid-lane — this is the
+# ORDER CONTRACT, which the shadow oracle steps message by message
+# (shadow.ShadowCluster._deliver_vectorized_target) and every
+# differential test holds the program to:
 #
-#   * lanes still process in kind order 0..5;
+#   * lanes process in kind order 0..5;
 #   * request lanes: the winner (highest term, lowest sender) delivers
 #     first through the full handler; losers then answer against the
 #     post-winner state (stale nudges; equal-term losers cannot exist
@@ -972,7 +605,7 @@ def _argfirst(mask):
 def _gather_msg(msgs: MsgSlots, at) -> MsgSlots:
     """msgs[w] for a traced winner index, as one-hot compare+reduce per
     field (at = senders == w): traced-index gathers serialize on TPU,
-    one-hot reads don't (the _pick discipline, tree-wide)."""
+    one-hot reads don't (the _pick_b discipline, tree-wide)."""
     def pick(x):
         sel = at if x.ndim == 1 else at[:, None]
         if x.dtype == jnp.bool_:
@@ -1048,7 +681,8 @@ def _vec_lane_vote(cfg: BatchedConfig, iid, slot, st: BatchedState,
     at the highest surviving term: one depose (become_follower) and at
     most one recorded grant — if the vote is already cast only its
     holder can re-grant; if it is free the first up-to-date sender
-    takes it (sender-ascending, exactly the sequential setdefault).
+    takes it (sender-ascending, as the oracle stepping the lane's
+    votes one at a time grants it).
     Prevotes never mutate state, so all prevote responses evaluate
     against the post-vote state in one masked shot. ``last_term`` is
     the term of the receiver's last log entry; the caller reads it,
@@ -1118,17 +752,16 @@ def _vec_lane_vote(cfg: BatchedConfig, iid, slot, st: BatchedState,
 
 def _vec_app_resp_effects(cfg: BatchedConfig, st: BatchedState,
                           m: MsgSlots, eq):
-    """Columnwise _leader_app_resp for every same-term MsgAppResp at
-    once — sender s's message only ever touches progress column s, so
-    the R sequential handler applications collapse to masked column
-    updates plus ONE commit recompute and one bcast/resend fold. The
-    PR 4 wedge-repair semantics (stale-high match lowered to the
-    follower's own evidence) ride the same masks bit-for-bit.
-    `eq` gates to valid same-term T_APP_RESP on a leader."""
+    """Leader MsgAppResp handling (ref: raft.go:1106-1283) for every
+    same-term MsgAppResp at once — sender s's message only ever
+    touches progress column s, so stepping the R messages one by one
+    collapses to masked column updates plus ONE commit recompute and
+    one bcast/resend fold. `eq` gates to valid same-term T_APP_RESP
+    on a leader."""
     prog = _repl_targets(st)
     ok = eq & prog
     # recent_active is recorded for every handled message, progress row
-    # or not (the sequential handler sets it before the prog_ok gate).
+    # or not (raft.go sets it before looking the progress up).
     st_in = st._replace(recent_active=st.recent_active | eq)
 
     # --- rejected: move next back using the hint (raft.go:1130-1236) ---
@@ -1147,8 +780,20 @@ def _vec_app_resp_effects(cfg: BatchedConfig, st: BatchedState,
         jnp.maximum(jnp.minimum(m.index, hint + 1), 1),
     )
     rej = ok & m.reject & ~stale_rej
-    # Stale-high match repair (the restarted-member progress wedge —
-    # see _leader_app_resp): lowering match is always safe.
+    # On a genuine rejection a replicating peer drops to probing
+    # (becomeProbe: next=match+1, reset probe bookkeeping).
+    #
+    # Stale-high match repair: a follower that rejects the probe at
+    # next-1 with a hint BELOW our recorded match has verifiably lost
+    # entries it once acked — reachable only when durability was
+    # violated under it (torn WAL tail). The reference keeps match
+    # untouched (its Next >= Match+1 invariant makes this state
+    # unreachable in-model), but keeping it here pins next <= match and
+    # the accept path then drops every re-ack at-or-below match
+    # (`updated` false) — the restarted-member progress wedge: next
+    # frozen, the missing suffix never re-sent. Lowering match is
+    # always safe (commit is monotone and never re-derived), so take
+    # the follower's own evidence and let normal probing re-heal.
     match_repair = rej & (dec_next <= st.match)
 
     # --- accepted: MaybeUpdate + state transitions ---
@@ -1219,8 +864,9 @@ def _vec_depose(cfg: BatchedConfig, iid, slot, st: BatchedState,
 def _vec_lane_vote_resp(cfg: BatchedConfig, iid, slot, st: BatchedState,
                         m: MsgSlots):
     """Lane KIND_VOTE_RESP, vectorized: record every same-term tally
-    vote at once (distinct senders → distinct slots; the sequential
-    early-exit on a decisive prefix equals the full tally, since
+    vote at once (distinct senders → distinct slots; the verdict a
+    decisive prefix of them gives one at a time equals the full
+    tally's, since
     grants can only keep a won verdict and rejections a lost one),
     then resolve won/lost once, then the depose tail."""
     keep = (m.type == T_PREVOTE_RESP) & ~m.reject
@@ -1278,7 +924,7 @@ def _vec_lane_hb_resp(cfg: BatchedConfig, iid, slot, st: BatchedState,
     """Lane KIND_HB_RESP, vectorized: heartbeat acks are a masked OR
     into probe_sent/inflight/recent_active plus ONE ReadIndex quorum
     recompute (acks are monotone; quorum on the full set equals the
-    sequential per-ack checks); T_APP_RESP stale-leader probes that
+    checks after each ack); T_APP_RESP stale-leader probes that
     route back in this lane reuse the column fold; then the depose
     tail."""
     is_leader = st.role == LEADER
@@ -1298,16 +944,17 @@ def _vec_lane_hb_resp(cfg: BatchedConfig, iid, slot, st: BatchedState,
         ),
         send_append=st.send_append | (okh & (st.match < st.last)),
     )
-    # ReadIndex acks (read_only.go recvAck/advance). The sequential
-    # scans stop RECORDING once an ack confirms quorum mid-lane
-    # (pending drops with read_ready), so for bit-parity the fold
-    # records only the sender-ascending prefix up to and including the
-    # quorum-confirming ack: conf_at[s] = "quorum with acks from
-    # senders <= s folded in" is monotone in s, so the first set bit
-    # is where the sequential scan stopped. Bits past it are dead
-    # state either way (cleared at the next batch open) — this keeps
-    # the three shapes comparable field-for-field, not just
-    # protocol-equivalent.
+    # ReadIndex acks (read_only.go recvAck/advance). Stepped one at a
+    # time, senders ascending — the order the oracle takes a lane's
+    # same-term messages in — acks stop being RECORDED once one
+    # confirms the quorum (pending drops with read_ready). The fold
+    # records the same set: the sender-ascending prefix up to and
+    # including the quorum-confirming ack. conf_at[s] = "quorum with
+    # acks from senders <= s folded in" is monotone in s, so its first
+    # set bit is that ack. Bits past it are dead state either way
+    # (cleared at the next batch open), and the rule costs R quorums
+    # where one would confirm the same reads: ROADMAP D13 says what to
+    # find out before collapsing it.
     senders = jnp.arange(st.match.shape[-1], dtype=I32)
     pending = (st_h.read_index >= 0) & ~st_h.read_ready
     inc = okh & pending & (m.ctx == st_h.read_seq) & (m.ctx > 0)
@@ -1332,16 +979,24 @@ def _vec_lane_hb_resp(cfg: BatchedConfig, iid, slot, st: BatchedState,
 
 def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
                         inbox: MsgSlots, lane_any=None):
-    """Scan-free deliver: lanes in kind order, each lane one vectorized
-    fold over the sender axis (see the order contract in the section
-    comment above). With no lax.scan barrier left anywhere in the
-    round, deliver→tick→control→propose→emit trace into ONE
-    straight-line fused region — the full-state loop-carry round trips
-    of the scanned shapes disappear, and the named_scope annotations
-    (ROUND_PHASE_SCOPES) survive purely as attribution labels inside
-    the fused program. Each lane runs under lax.cond on its occupancy
-    (see _deliver_all on ``lane_any``), so idle lanes are skipped for
-    the whole batch."""
+    """Deliver this instance's [R, K] inbox: lanes in kind order, each
+    lane one fold over the sender axis (the order contract is in the
+    section comment above). Returns the state and the [R, 3] responses
+    to the request lanes, which the round routes back in lanes
+    ``k + NUM_REQ_KINDS``. No lax.scan sits anywhere in the round, so
+    deliver→tick→control→propose→emit trace into ONE straight-line
+    fused region, and the named_scope annotations (ROUND_PHASE_SCOPES)
+    are attribution labels inside it.
+
+    ``lane_any`` ([K] bool, optional) is the batch-level lane-occupancy
+    vector: the CALLER computes ``jnp.any(inbox.valid, axis=(0, 1))``
+    OUTSIDE the instance vmap so each lane's fold sits under a lax.cond
+    with an UNMAPPED predicate — a lane nobody used this round (votes
+    in steady state, heartbeat lanes off-cadence) costs nothing instead
+    of a full masked no-op. An all-invalid lane is an exact identity,
+    so the skip is bit-equivalent; None falls back to per-instance
+    occupancy (the cond degrades to a select under a mapped predicate
+    — correct, just unskipped)."""
     lane = lambda k: jax.tree.map(lambda x, _k=k: x[:, _k], inbox)  # noqa: E731
     no_resp = empty_msgs((cfg.num_replicas,), cfg.max_ents_per_msg)
 
@@ -1953,8 +1608,8 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
     runtime arguments, so three hosting processes' nodes reuse one
     compilation per shape).
 
-    ``lane_skip`` enables the vectorized shape's batch-level lane-
-    occupancy conds. It MUST be off for mesh-sharded callers: the
+    ``lane_skip`` enables deliver's batch-level lane-occupancy conds.
+    It MUST be off for mesh-sharded callers: the
     occupancy reduce (any over the sharded instance axis) would be the
     round's first cross-device collective — the sharded layout's whole
     point is that NO collective rides the hot path (row-local quorums,
@@ -1979,9 +1634,9 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
             st = widen_state(st)
             inbox = widen_msgs(inbox)
 
-        # Batch-level lane occupancy for the vectorized shape's
-        # lax.cond lane skips: computed OUTSIDE the vmap and passed
-        # unmapped (in_axes=None), so the conds stay real branches
+        # Batch-level lane occupancy for deliver's lax.cond lane
+        # skips: computed OUTSIDE the vmap and passed unmapped
+        # (in_axes=None), so the conds stay real branches
         # instead of degrading to selects under a mapped predicate.
         # None when lane_skip is off (sharded callers — see docstring).
         lane_any = (
@@ -1998,8 +1653,8 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
             pre = sti  # round-entry state (telemetry deltas)
             inbox_i = inbox_i._replace(valid=inbox_i.valid & ~iso)
             with jax.named_scope("raft_deliver"):
-                sti, req_resps = _deliver_all(cfg, iid, slot, sti, inbox_i,
-                                              lane_any)
+                sti, req_resps = _deliver_vectorized(
+                    cfg, iid, slot, sti, inbox_i, lane_any)
             with jax.named_scope("raft_tick"):
                 sti = _tick(cfg, iid, slot, sti, do_tick, do_camp)
             read_snap = (sti.read_seq, sti.read_index, sti.read_ready)
@@ -2119,11 +1774,11 @@ def make_step_round(cfg: BatchedConfig, iids=None, slots=None,
     slot of each group (iid = group*R + slot keeps the deterministic
     randomized-timeout hash identical across topologies)."""
     # Resolve deliver_shape="auto" BEFORE the per-config jit cache so
-    # "auto" and its concrete platform resolution share one program.
+    # "auto" and "vectorized" share one program.
     # ``lane_skip=False`` is for mesh-sharded callers — see
     # _step_round_jit on why the occupancy reduce must not cross
     # shards.
-    cfg = cfg.resolved()
+    cfg = cfg.validate().resolved()
     # Apply-plane knobs never enter the round-step program (the plane
     # is a separate jitted program, applyplane.py): strip them to
     # defaults before the per-config jit cache so apply_plane on/off

@@ -17,18 +17,12 @@ from typing import Tuple
 
 
 def make_bench_engine(groups: int, lanes_minor: bool = True,
-                      deliver_shape: str = "auto",
                       telemetry: bool = False,
                       fleet: bool = False):
     """Build the canonical bench engine (BENCH_r05 methodology: R=3,
     W=32, E=4, steady state with no timer elections, auto-compacting
     ring), elect every group's slot-0 replica, and return the engine
     plus the steady 2-entries-per-group-per-round proposal vector.
-
-    ``deliver_shape`` is the ISSUE 14 A/B axis (lanes | merged |
-    vectorized; "auto" = vectorized, on the chip too since ISSUE 29:
-    state.default_deliver_shape) — every headline number names the
-    concrete shape it ran (engine.cfg.deliver_shape after resolution).
 
     ``telemetry`` compiles the kernel telemetry plane in (ISSUE 4):
     the headline number stays telemetry-off; BENCH_TELEMETRY=1 /
@@ -49,7 +43,6 @@ def make_bench_engine(groups: int, lanes_minor: bool = True,
         heartbeat_timeout=4,
         auto_compact=True,  # sustained load: ring chases the applied mark
         lanes_minor=lanes_minor,
-        deliver_shape=deliver_shape,
         telemetry=telemetry,
         fleet_summary=fleet,
     )

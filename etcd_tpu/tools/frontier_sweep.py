@@ -57,23 +57,22 @@ def _log(msg: str) -> None:
           file=sys.stderr, flush=True)
 
 
-def _make_engine(groups: int, shape: str, telemetry: bool = False):
+def _make_engine(groups: int, telemetry: bool = False):
     # The bench.py config and setup (BENCH_r05 methodology), from the
     # shared module so the sweep cannot desynchronize from bench.py.
     from .benchlib import make_bench_engine
 
     return make_bench_engine(groups, lanes_minor=True,
-                             deliver_shape=shape,
                              telemetry=telemetry)
 
 
-def _pipeline_gate(shape: str) -> None:
+def _pipeline_gate() -> None:
     """Refuse to measure a pipelined loop that diverges from
     single-round stepping (the shadow-verified path)."""
     import numpy as np
 
-    a, props = _make_engine(64, shape)
-    b, _ = _make_engine(64, shape)
+    a, props = _make_engine(64)
+    b, _ = _make_engine(64)
     a.run_rounds_pipelined(48, chunk=8, tick=True, propose_n=props)
     for _ in range(48):
         b.step_round(tick=True, propose_n=props)
@@ -84,16 +83,16 @@ def _pipeline_gate(shape: str) -> None:
         assert (av == bv).all(), (
             f"pipelined loop diverged from single-round stepping on "
             f"{f}; refusing to record frontier numbers")
-    _log(f"pipeline gate[{shape}]: pipelined == single-round "
-         "stepping over 48 rounds at G=64")
+    _log("pipeline gate: pipelined == single-round stepping over 48 "
+         "rounds at G=64")
 
 
-def _measure_point(groups: int, shape: str, rounds_per_call: int,
+def _measure_point(groups: int, rounds_per_call: int,
                    calls: int, telemetry: bool = False) -> dict:
     from .benchlib import measure_commit_p50, measure_rate
 
     t0 = time.perf_counter()
-    eng, props = _make_engine(groups, shape, telemetry)
+    eng, props = _make_engine(groups, telemetry)
     build_s = time.perf_counter() - t0
     _log(f"G={groups}: built+compiled in {build_s:.1f}s")
 
@@ -112,7 +111,6 @@ def _measure_point(groups: int, shape: str, rounds_per_call: int,
     gc.collect()
     return {
         "groups": groups,
-        "deliver": shape,
         "rate_group_rounds_per_s": round(rate, 1),
         "commit_p50_ms": round(p50_ms, 2),
         "commit_p50_rounds": rounds,
@@ -122,13 +120,13 @@ def _measure_point(groups: int, shape: str, rounds_per_call: int,
 
 def _markdown(result: dict) -> str:
     lines = [
-        "| G | deliver | group-rounds/s | commit p50 (ms) | rounds "
+        "| G | group-rounds/s | commit p50 (ms) | rounds "
         "| build (s) |",
-        "|---|---|---|---|---|---|",
+        "|---|---|---|---|---|",
     ]
     for p in result["points"]:
         lines.append(
-            "| {groups} | {deliver} | {rate_group_rounds_per_s:,.0f} | "
+            "| {groups} | {rate_group_rounds_per_s:,.0f} | "
             "{commit_p50_ms} | {commit_p50_rounds} | {build_s} |"
             .format(**p))
     return "\n".join(lines)
@@ -141,12 +139,6 @@ def main() -> None:
     ap.add_argument("--out", default="artifacts/frontier.json")
     ap.add_argument("--rounds-per-call", type=int, default=16)
     ap.add_argument("--calls", type=int, default=8)
-    ap.add_argument("--deliver-shape", default="",
-                    help="comma-separated deliver shapes to sweep "
-                         "(lanes|merged|vectorized; default: the "
-                         "platform default shape). Each point row "
-                         "records its shape, so one sweep writes the "
-                         "per-shape frontier (ISSUE 14).")
     ap.add_argument("--telemetry", action="store_true",
                     help="compile the kernel telemetry plane into the "
                          "measured round (overhead sweep; ISSUE 4)")
@@ -164,35 +156,20 @@ def main() -> None:
 
     platform = jax.devices()[0].platform
     accelerated = platform == "tpu"
-    from etcd_tpu.batched.state import DELIVER_SHAPES, \
-        default_deliver_shape
-
-    if args.deliver_shape:
-        shapes = [s.strip() for s in args.deliver_shape.split(",")]
-        for s in shapes:
-            if s not in DELIVER_SHAPES:
-                raise SystemExit(
-                    f"unknown deliver shape {s!r} (choose from "
-                    f"{DELIVER_SHAPES})")
-    else:
-        shapes = [default_deliver_shape()]
     if args.groups:
         group_list = [int(g) for g in args.groups.split(",")]
     else:
         group_list = TPU_GROUPS if accelerated else CPU_GROUPS
-    _log(f"platform={platform} sweep G={group_list} "
-         f"deliver={','.join(shapes)}")
+    _log(f"platform={platform} sweep G={group_list}")
 
     if not args.skip_gate:
-        for s in shapes:
-            _pipeline_gate(s)
+        _pipeline_gate()
 
     result: dict = {
         "platform": platform,
         "device": str(jax.devices()[0]),
         "loop": "pipelined (run_rounds_pipelined chunk=%d depth=2)"
                 % args.rounds_per_call,
-        "deliver": shapes,
         "telemetry": bool(args.telemetry),
         "compile_cache": cache_dir,
         "captured_at": time.strftime("%Y-%m-%dT%H:%M:%S"),
@@ -207,16 +184,15 @@ def main() -> None:
             f.write("\n")
 
     for g in group_list:
-        for s in shapes:
-            try:
-                result["points"].append(
-                    _measure_point(g, s, args.rounds_per_call,
-                                   args.calls, args.telemetry))
-            except Exception as e:  # noqa: BLE001 — partial frontier
-                _log(f"G={g} {s} failed: {e!r}; frontier stays partial")
-                result.setdefault("failed", []).append(
-                    {"groups": g, "deliver": s, "error": repr(e)})
-            flush()
+        try:
+            result["points"].append(
+                _measure_point(g, args.rounds_per_call, args.calls,
+                               args.telemetry))
+        except Exception as e:  # noqa: BLE001 — partial frontier
+            _log(f"G={g} failed: {e!r}; frontier stays partial")
+            result.setdefault("failed", []).append(
+                {"groups": g, "error": repr(e)})
+        flush()
 
     table = _markdown(result)
     print(table)

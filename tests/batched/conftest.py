@@ -46,19 +46,10 @@ os.environ.setdefault("ETCD_TPU_TRANSFER_GUARD", "disallow")
 # config you added, and prefer sharing an existing module's config —
 # `sentinels.compile_keys("round_step")` names every key.
 #
-# ISSUE 14 AUDIT: 41 used. deliver_shape now rides every config key
-# (the default "auto" resolves to vectorized on CPU, so the ~39
-# pre-existing keys changed VALUE but not COUNT); net-new programs:
-# +1 test_differential's third lockstep parametrization (the old
-# merged=False/True pair became lanes/merged/vectorized), and
-# +1 test_deliver_shapes' hosted narrow-lanes rawnode (narrow config
-# with aux=True — the staged-inbox dtype contract had no coverage).
-# The equivalence engines in test_deliver_shapes reuse the
-# differential trio's exact config values (zero cost), and the
-# non-default chaos cells are slow-marked (outside tier-1). Budget
-# 41 → 43 keeps the same headroom of 2.
-#
-# ISSUE 17 AUDIT: still 43. test_lifecycle reuses test_chaos.CFG
+# ISSUE 17 AUDIT: 43 (ISSUE 14 had made it so: deliver_shape on every
+# key, a third lockstep program and test_deliver_shapes' hosted
+# narrow-lanes rawnode; ISSUE 30 below took the shapes away again).
+# test_lifecycle reuses test_chaos.CFG
 # VALUES verbatim (every lifecycle knob — snap_cadence, snap_keep,
 # wal_rotate_bytes, wal_pinned_segments — is a host-side member arg,
 # not a BatchedConfig field, so it never enters the compile key), and
@@ -97,11 +88,10 @@ os.environ.setdefault("ETCD_TPU_TRANSFER_GUARD", "disallow")
 # session of its own, so the sentinel never saw it). test_scan_faults
 # adds two programs: the benchmark's `engine100k-r3` at the CPU tests'
 # 8 groups (etcd's raft defaults: election 10, heartbeat 1, pre_vote,
-# check_quorum; n-minor, telemetry on; "auto" = vectorized here), which
+# check_quorum; n-minor, telemetry on), which
 # tests/benchmark builds too for the cell's tiny run, its controls and
-# its broken-path tests, and that config's `merged` twin, because
-# merged was the shape the chip ran (until ISSUE 29) and the repo had no differential
-# of the round under pre_vote + check_quorum in any shape. The scan-
+# its broken-path tests, and that config's `merged` twin (gone with
+# the shape in ISSUE 30). The scan-
 # against-single-rounds test's other two engines reuse values that are
 # built already (`engine10k-r5` at 8 groups, R=5 n-minor, from
 # tests/benchmark; test_pipelined.make_engine(4), R=3 n-major), and the
@@ -111,10 +101,23 @@ os.environ.setdefault("ETCD_TPU_TRANSFER_GUARD", "disallow")
 # (test_without_a_schedule_the_scan_gains_no_input_and_no_key). Of the
 # raise by three, one unit repairs PR 26's count and two are this PR's.
 # ISSUE 29 AUDIT: still 48. test_deliver_default builds engines on
-# test_scan_faults' CELL, R5 and CELL's merged twin (the same values,
-# so the same keys) and lowers without compiling; "auto" already
-# resolved to vectorized on the CPU, so no key changed value either.
-ROUND_STEP_SHAPE_BUDGET = 48
+# test_scan_faults' CELL and R5 (the same values, so the same keys)
+# and lowers without compiling.
+# ISSUE 30 AUDIT: 44 used of 46, counted (a whole `pytest tests/ -m 'not
+# slow'` session in ONE process, 1,531 passed in 905 s, the keys dumped
+# at session end; the parent's tree counted the same way stood at 48 of
+# 48). deliver_shape rides every config key and reads 'vectorized' in
+# all 44: deliver has one shape (ISSUE 14 had made it three, and the
+# differential config a trio of programs). Five keys went: the `lanes`
+# and `merged` members of that trio at G=2 and at G=1
+# (test_differential's partition test and test_features' make_pair(1)
+# ran those; at G=1 the one shape was a key already), and
+# test_scan_faults' CELL in `merged`. One came: the differential config
+# with laneskip=0 (test_deliver_shapes' lane_skip twin: the one fork
+# left in deliver had no direct test). test_deliver_shapes' hosted
+# narrow-lanes rawnode (aux=True, ISSUE 14) stays. Budget 48 -> 46
+# keeps the headroom of 2.
+ROUND_STEP_SHAPE_BUDGET = 46
 
 
 @pytest.fixture(scope="session", autouse=True)

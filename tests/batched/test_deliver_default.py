@@ -1,13 +1,13 @@
-"""What ``deliver_shape="auto"`` resolves to, and what the engine's
-lane counter reads (ISSUE 29).
+"""The one deliver: what ``deliver_shape`` still names, that no loop
+sits in the round, and what the engine's lane counter reads.
 
-No per-sender loop in deliver on any platform: ``auto`` is one shape
-for ``cpu`` and ``tpu``, the lowered round in that shape holds no
-``while`` and the closed loop exactly one (the round scan), so a sender
-loop that comes back fails here and not in a benchmark. The lane
-counter (``MultiRaftEngine.lane_rounds``) says in how many scan rounds
-each inbox lane was occupied: what the vectorized deliver's lane skip
-saves.
+``deliver_shape`` admits "auto" and "vectorized", two names of one
+program, and nothing else. No per-sender loop in deliver: the lowered
+round holds no ``while`` and the closed loop exactly one (the round
+scan), so a sender loop that comes back fails here and not in a
+benchmark. The lane counter (``MultiRaftEngine.lane_rounds``) says in
+how many scan rounds each inbox lane was occupied: what deliver's lane
+skip saves.
 
 Round-step programs: none new. ``CELL`` and ``R5`` are
 ``test_scan_faults``' (the benchmark's ``engine100k-r3`` and
@@ -21,7 +21,6 @@ import pytest
 
 from etcd_tpu.analysis import sentinels
 from etcd_tpu.batched import MultiRaftEngine
-from etcd_tpu.batched import state as state_mod
 from etcd_tpu.batched.step import (KIND_APP, KIND_APP_RESP, KIND_HB,
                                    KIND_HB_RESP, KIND_VOTE, KIND_VOTE_RESP,
                                    NUM_KINDS)
@@ -31,24 +30,26 @@ from .test_scan_faults import CELL, R5
 ROUNDS = 64  # a call of every live cell
 
 
-# -- (a) one shape on every platform -------------------------------------------
+# -- (a) one deliver, whatever it is called ----------------------------------------
 
 
-def test_auto_is_one_shape_on_cpu_and_on_tpu(monkeypatch):
-    shapes = set()
-    for backend in ("cpu", "tpu"):
-        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
-        shapes.add(state_mod.default_deliver_shape())
-        assert R5.resolved().deliver_shape in shapes
-    assert shapes == {"vectorized"}
-
-
-def test_auto_still_refuses_a_backend_nobody_ran(monkeypatch):
-    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
-    with pytest.raises(RuntimeError, match="no default deliver shape"):
-        state_mod.default_deliver_shape()
-    with pytest.raises(RuntimeError, match="gpu"):
-        R5.resolved()
+def test_auto_is_one_shape_on_cpu_and_on_tpu():
+    """"auto" and "vectorized" key one program on any platform (no
+    backend is asked), and ``validate()`` refuses every other name,
+    the two deleted shapes' included."""
+    named = R5._replace(deliver_shape="vectorized")
+    assert R5.deliver_shape == "auto"
+    assert R5.validate().resolved() == named == named.validate().resolved()
+    before = set(sentinels.compile_keys("round_step"))
+    a, b = MultiRaftEngine(R5), MultiRaftEngine(named)
+    assert a.cfg == b.cfg == named
+    new = set(sentinels.compile_keys("round_step")) - before
+    assert len(new) <= 1 and all("'vectorized'" in k for k in new)
+    for other in ("lanes", "merged", "", "Vectorized", "scan"):
+        with pytest.raises(ValueError, match="deliver has one order"):
+            R5._replace(deliver_shape=other).validate()
+        with pytest.raises(ValueError, match="deliver has one order"):
+            MultiRaftEngine(R5._replace(deliver_shape=other))
 
 
 # -- (b) no sender loop in the lowered programs ----------------------------------
@@ -74,22 +75,12 @@ def _lowered_texts(cfg):
 @pytest.mark.parametrize("cfg", [R5, CELL], ids=["r5_append", "r3_elections"])
 def test_the_resolved_round_holds_no_loop_and_the_scan_one(cfg):
     resolved, one, loop = _lowered_texts(cfg)
-    assert resolved.deliver_shape == state_mod.default_deliver_shape()
+    assert resolved.deliver_shape == "vectorized"
     assert one.count("stablehlo.while") == 0, (
         "a loop is back inside the round: deliver folds each lane once "
         "over the sender axis and scans nothing")
     assert one.count("stablehlo.sort") > 0  # the text is the program's
     assert loop.count("stablehlo.while") == 1, "only the round scan loops"
-
-
-def test_the_merged_round_is_what_the_check_would_catch():
-    """The same reading finds the two sender scans of the shape that
-    was the TPU default until ISSUE 29 (``CELL``'s merged twin is a
-    program ``test_scan_faults`` builds)."""
-    _cfg, one, loop = _lowered_texts(
-        CELL._replace(deliver_shape="merged"))
-    assert one.count("stablehlo.while") == 2
-    assert loop.count("stablehlo.while") == 3
 
 
 # -- (c) the lane counter ----------------------------------------------------------
@@ -147,15 +138,27 @@ def test_lane_rounds_counts_every_lane_under_an_outage_with_prevote():
     assert (eng.leaders() != 0).any(), "the outage elected nobody"
 
 
-def test_lane_rounds_counts_occupancy_whatever_the_shape():
-    """Under ``merged`` the counter still reads what the skip would
-    have saved; the two shapes run the same protocol over the same
-    messages, so the same lanes are busy in the same rounds."""
+def test_lane_rounds_pipelined_under_a_schedule_equal_run_rounds():
+    """The counter rides both entry points' one scan: the same rounds
+    with the same node schedule, chunked and pipelined, read the same
+    lanes as one ``run_rounds`` call a chunk."""
+    r = CELL.num_replicas
+    sched = np.zeros((ROUNDS, r), bool)
+    sched[8:40, 0] = True
     counts = []
-    for shape in ("auto", "merged"):
-        eng = MultiRaftEngine(CELL._replace(deliver_shape=shape))
-        eng.campaign(np.arange(eng.cfg.num_groups) * eng.cfg.num_replicas)
-        eng.run_rounds(32, tick=True)
-        counts.append(eng.lane_rounds().tolist())
+    for pipelined in (False, True):
+        eng = MultiRaftEngine(CELL)
+        eng.campaign(np.arange(eng.cfg.num_groups) * r)
+        eng.run_rounds(16, tick=False)
+        props = jnp.full((eng.cfg.num_instances,), 2, jnp.int32)
+        before = eng.lane_rounds()
+        if pipelined:
+            eng.run_rounds_pipelined(ROUNDS, chunk=16, tick=True,
+                                     propose_n=props, isolate=sched)
+        else:
+            for t in range(0, ROUNDS, 16):
+                eng.run_rounds(16, tick=True, propose_n=props,
+                               isolate=sched[t:t + 16])
+        counts.append((eng.lane_rounds() - before).tolist())
     assert counts[0] == counts[1]
-    assert sum(counts[0]) > 0
+    assert all(c > 0 for c in counts[0]), counts[0]

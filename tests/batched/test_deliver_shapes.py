@@ -1,25 +1,23 @@
-"""ISSUE 14: deliver-shape equivalence (lanes | merged | vectorized).
+"""Deliver on adversarial schedules: contested elections, torn-tail
+rejection and repair, ReadIndex confirmation.
 
-The vectorized deliver replaces the sequential sender scans with
-masked reductions and winner tournaments (step.py _deliver_vectorized).
-Its order contract is pinned against the shadow oracle by
-test_differential.py (parametrized over all three shapes); THIS module
-pins the three shapes against EACH OTHER on seeded adversarial
-workloads — contested elections, torn-tail rejection/repair, ReadIndex
-confirmation — where the protocol outcome must be bit-identical
-because every delivery-order difference the shapes are allowed to have
-(deposes commuting with same-term effects) is unreachable without
-pre-vote piggybacking, and these configs run pre_vote=False.
+The round's deliver folds each inbox lane once over the sender axis
+(step.py _deliver_vectorized). Its order contract is what the shadow
+oracle steps message by message, and test_differential.py holds the
+program to it on the common envelope; THIS module drives the schedules
+where the order inside a lane decides the outcome — split votes broken
+by sender order, a reject hint against a divergent tail, acks
+confirming a read — and compares the program with the oracle after
+every round on every ``STATE_FIELDS`` field the oracle exposes. The
+fields it does not expose (the read batch, the send flags) are held
+between the program's two forms: each schedule runs once more with the
+lane ``lax.cond``s as selects (``lane_skip=False``, what a mesh-sharded
+member runs) beside the default, every field equal after every round.
 
-Engine configs intentionally reuse test_differential.py's values
-(G=2/R=3/W=64/E=16/P=4, ET=1<<20, unbounded inflight) so the three
-round-step programs here are the SAME three the lockstep suite
-compiles — zero new entries against ROUND_STEP_SHAPE_BUDGET.
-
-The slow-marked chaos cells at the bottom re-fly a quick-chaos episode
-under the non-default shapes (the CPU default already covers
-vectorized in test_chaos.py), so every SHIPPED deliver shape closes
-the strict checkers with ``invariant_trips() == 0``.
+Engine configs reuse test_differential.py's values (G=2/R=3/W=64/E=16/
+P=4, ET=1<<20, unbounded inflight), so the round-step program here is
+the one the lockstep suite compiles; its ``laneskip=0`` twin is this
+module's own entry against ROUND_STEP_SHAPE_BUDGET.
 """
 
 import jax.numpy as jnp
@@ -27,13 +25,15 @@ import numpy as np
 import pytest
 
 from etcd_tpu.batched import BatchedConfig, MultiRaftEngine
+from etcd_tpu.batched.shadow import ShadowCluster
+from etcd_tpu.batched.state import CANDIDATE, LEADER, PRECANDIDATE
+from etcd_tpu.batched.step import make_step_round
 
 R = 3
 ET = 1 << 20
-SHAPES = ("lanes", "merged", "vectorized")
 
 # Every protocol-visible field of BatchedState (send flags included:
-# the shapes must agree on what the NEXT round will emit, not just on
+# two programs must agree on what the NEXT round will emit, not just on
 # the HardState face).
 STATE_FIELDS = (
     "term", "vote", "role", "lead", "log_term", "snap_index",
@@ -43,9 +43,16 @@ STATE_FIELDS = (
     "read_ready", "read_req_latch", "send_append", "send_heartbeat",
     "send_vote_req", "transferee", "transfer_sent",
 )
+# Of those, what the oracle's raft struct holds per replica, and per
+# peer on the rows where it is live state: progress on a leader, the
+# tally on a candidate.
+ORACLE_SCALARS = ("term", "vote", "role", "lead", "snap_index", "last",
+                  "commit")
+ORACLE_PROGRESS = ("match", "next", "pr_state", "probe_sent",
+                   "recent_active")
 
 
-def make_engine(shape, groups=2):
+def make_engine(groups=2, lane_skip=True):
     cfg = BatchedConfig(
         num_groups=groups,
         num_replicas=R,
@@ -55,58 +62,130 @@ def make_engine(shape, groups=2):
         election_timeout=ET,
         heartbeat_timeout=1,
         max_inflight=1 << 20,
-        deliver_shape=shape,
     )
-    return MultiRaftEngine(cfg)
+    eng = MultiRaftEngine(cfg)
+    if not lane_skip:
+        eng._step = make_step_round(eng.cfg, lane_skip=False)
+    return eng
 
 
 def assert_states_equal(engines, rnd, context):
-    ref_shape, ref = engines[0]
-    for shape, eng in engines[1:]:
+    ref_name, ref = engines[0]
+    for name, eng in engines[1:]:
         for f in STATE_FIELDS:
             a = np.asarray(getattr(ref.state, f))
             b = np.asarray(getattr(eng.state, f))
             assert (a == b).all(), (
-                f"{context} round {rnd}: {shape} diverges from "
-                f"{ref_shape} on {f}:\n{a}\nvs\n{b}")
+                f"{context} round {rnd}: {name} diverges from "
+                f"{ref_name} on {f}:\n{a}\nvs\n{b}")
 
 
-def run_schedule(schedule, context):
-    """Drive identical schedules through one engine per shape and
-    compare EVERY protocol state field after every round."""
-    engines = [(s, make_engine(s)) for s in SHAPES]
-    n = engines[0][1].cfg.num_instances
+def oracle_fields(shadows):
+    """field -> per-instance values as the oracle holds them; None
+    where the oracle's value is not live state (progress off a leader,
+    votes off a candidate)."""
+    out = {f: [] for f in ORACLE_SCALARS + ORACLE_PROGRESS
+           + ("votes", "log_term")}
+    for shadow in shadows:
+        for slot, node in enumerate(shadow.nodes):
+            r = node.raft
+            role = int(r.state)
+            first = r.raft_log.first_index()
+            for f, v in zip(ORACLE_SCALARS, (
+                    r.term, r.vote, role, r.lead, first - 1,
+                    r.raft_log.last_index(), r.raft_log.committed)):
+                out[f].append(v)
+            prs = [r.prs.progress[s + 1] for s in range(R)]
+            for f, vals in zip(ORACLE_PROGRESS, (
+                    [p.match for p in prs], [p.next for p in prs],
+                    [int(p.state) for p in prs],
+                    [p.probe_sent for p in prs],
+                    [p.recent_active for p in prs])):
+                out[f].append(vals if role == LEADER else None)
+            cast = r.prs.votes
+            out["votes"].append(
+                [int(cast[s + 1]) if s + 1 in cast else -1
+                 for s in range(R)]
+                if role in (CANDIDATE, PRECANDIDATE) else None)
+            out["log_term"].append(shadow.log_terms(slot))
+    return out
+
+
+def assert_equals_oracle(eng, shadows, rnd, context):
+    want = oracle_fields(shadows)
+    w = eng.cfg.window
+    for f, rows in want.items():
+        dev = np.asarray(getattr(eng.state, f))
+        for i, v in enumerate(rows):
+            if v is None:
+                continue
+            if f == "log_term":
+                got = [(idx, int(dev[i, idx % w])) for idx, _t in v]
+            else:
+                got = dev[i].tolist()
+            assert got == v, (
+                f"{context} round {rnd} instance {i}: program {f}="
+                f"{got}, oracle {v}")
+
+
+def run_schedule(schedule, context, lane_skip_twin=False):
+    """Drive the schedule through the program and the oracle (or, with
+    ``lane_skip_twin``, through the program's two forms) and compare
+    after EVERY round. Returns the default engine."""
+    eng = make_engine()
+    cfg = eng.cfg
+    twin = make_engine(lane_skip=False) if lane_skip_twin else None
+    shadows = [] if lane_skip_twin else [
+        ShadowCluster(R, election_timeout=ET, heartbeat_timeout=1)
+        for _ in range(cfg.num_groups)]
+    n = cfg.num_instances
     for rnd, step in enumerate(schedule):
         camp = np.zeros(n, bool)
         props = np.zeros(n, np.int32)
         iso = np.zeros(n, bool)
+        read = np.zeros(n, bool)
+        per_group = [dict(campaigns=[], proposals={}, isolate=[])
+                     for _ in range(cfg.num_groups)]
         for g, s in step.get("campaign", []):
             camp[g * R + s] = True
+            per_group[g]["campaigns"].append(s)
         for (g, s), k in step.get("propose", {}).items():
             props[g * R + s] = k
+            per_group[g]["proposals"][s] = k
         for g, s in step.get("isolate", []):
             iso[g * R + s] = True
-        read = np.zeros(n, bool)
+            per_group[g]["isolate"].append(s)
+        # The oracle is handed no read: in these schedules a ReadIndex
+        # batch, which rides the heartbeat lanes, changes none of the
+        # fields it exposes.
         for g, s in step.get("read", []):
             read[g * R + s] = True
-        for _shape, eng in engines:
-            eng.step_round(
-                tick=step.get("tick", False),
-                campaign_mask=jnp.asarray(camp),
-                propose_n=jnp.asarray(props),
-                isolate=jnp.asarray(iso),
-                read_req=jnp.asarray(read),
-            )
-        assert_states_equal(engines, rnd, context)
-    return engines
+        tick = step.get("tick", False)
+        for e in (eng, twin):
+            if e is not None:
+                e.step_round(
+                    tick=tick,
+                    campaign_mask=jnp.asarray(camp),
+                    propose_n=jnp.asarray(props),
+                    isolate=jnp.asarray(iso),
+                    read_req=jnp.asarray(read),
+                )
+        for shadow, kw in zip(shadows, per_group):
+            shadow.round(tick=tick, **kw)
+        if lane_skip_twin:
+            assert_states_equal(
+                [("conds", eng), ("selects", twin)], rnd, context)
+        else:
+            assert_equals_oracle(eng, shadows, rnd, context)
+    return eng
 
 
-def test_contested_elections_agree():
+def contested_elections():
     """All three replicas campaign in the same round (guaranteed split
-    vote), then staggered re-campaigns contest the follow-up term —
-    the vote-lane tournament and the tally reductions must reproduce
-    the scan shapes' grants/rejections exactly."""
-    schedule = (
+    vote), then staggered re-campaigns contest the follow-up term: the
+    vote lane's winner and the tally fold must grant and reject as the
+    oracle does one message at a time."""
+    return (
         [{"campaign": [(0, 0), (0, 1), (0, 2), (1, 0), (1, 1), (1, 2)]}]
         + [{} for _ in range(3)]
         # Two-way contest at the next term; sender-order tie-breaks.
@@ -118,19 +197,15 @@ def test_contested_elections_agree():
         + [{"propose": {(0, 0): 3, (1, 2): 2}}]
         + [{} for _ in range(4)]
     )
-    engines = run_schedule(schedule, "contested elections")
-    # The last campaign round must actually have elected leaders.
-    for _shape, eng in engines:
-        assert (eng.leaders() >= 0).all()
 
 
-def test_torn_tail_rejection_repair_agree():
+def torn_tail_repair():
     """Partitioned leader appends a divergent tail; the new leader's
-    probe is rejected with a hint and the tail truncated on heal — the
-    reject/repair column fold (incl. the PR 4 stale-high match repair
-    masks) must match the scan shapes bit-for-bit."""
+    probe is rejected with a hint and the tail truncated on heal: the
+    reject/repair column fold (incl. the stale-high match repair
+    masks)."""
     iso = [(0, 0)]
-    schedule = (
+    return (
         [{"campaign": [(0, 0)]}]
         + [{} for _ in range(4)]
         + [{"propose": {(0, 0): 2}}]
@@ -144,17 +219,12 @@ def test_torn_tail_rejection_repair_agree():
         + [{"tick": True}]
         + [{} for _ in range(6)]
     )
-    engines = run_schedule(schedule, "torn-tail repair")
-    for _shape, eng in engines:
-        c = eng.commits()
-        assert (c[0] == c[0][0]).all() and c[0][0] >= 4
 
 
-def test_readindex_confirmation_agrees():
-    """ReadIndex batches confirm via ctx-echoing heartbeat acks — the
-    hb-resp lane's single quorum recompute must confirm on exactly the
-    same round as the sequential per-ack checks."""
-    schedule = (
+def readindex_confirmation():
+    """ReadIndex batches confirm via ctx-echoing heartbeat acks: the
+    hb-resp lane's quorum over the acks of one round."""
+    return (
         [{"campaign": [(0, 0), (1, 1)]}]
         + [{} for _ in range(4)]
         + [{"propose": {(0, 0): 2, (1, 1): 1}}]
@@ -165,19 +235,57 @@ def test_readindex_confirmation_agrees():
         + [{"read": [(0, 0)]}]
         + [{} for _ in range(4)]
     )
-    engines = run_schedule(schedule, "readindex")
-    for _shape, eng in engines:
-        seq, idx, ready = eng.read_states()
-        assert ready[0] and idx[0] >= 0
-        assert seq[0] == 2 and seq[R + 1] == 1
+
+
+def elected(eng):
+    # The last campaign round must actually have elected leaders.
+    assert (eng.leaders() >= 0).all()
+
+
+def repaired(eng):
+    c = eng.commits()
+    assert (c[0] == c[0][0]).all() and c[0][0] >= 4
+
+
+def confirmed(eng):
+    seq, idx, ready = eng.read_states()
+    assert ready[0] and idx[0] >= 0
+    assert seq[0] == 2 and seq[R + 1] == 1
+
+
+def test_contested_elections_agree():
+    elected(run_schedule(contested_elections(), "contested elections"))
+
+
+def test_torn_tail_rejection_repair_agree():
+    repaired(run_schedule(torn_tail_repair(), "torn-tail repair"))
+
+
+def test_readindex_confirmation_agrees():
+    confirmed(run_schedule(readindex_confirmation(), "readindex"))
+
+
+@pytest.mark.parametrize("schedule,outcome", [
+    (contested_elections, elected),
+    (torn_tail_repair, repaired),
+    (readindex_confirmation, confirmed),
+], ids=["contested_elections", "torn_tail_repair", "readindex"])
+def test_lane_conds_as_selects_equal_the_conds(schedule, outcome):
+    """The one fork left in deliver: with ``lane_skip`` each lane's fold
+    sits under a ``lax.cond`` on the batch's occupancy; without it (a
+    mesh-sharded member, rawnode.py) the predicate is per instance and
+    the cond is a select. An unoccupied lane is an identity, so the two
+    must agree on every field, the read batch and send flags too."""
+    outcome(run_schedule(schedule(), schedule.__name__,
+                         lane_skip_twin=True))
 
 
 def test_vectorized_pipelined_matches_serial():
-    """The pipelined closed loop (donated buffers, chunked scans) over
-    the vectorized round must equal serial single-round stepping —
-    the frontier-sweep gate, pinned as a test for the new shape."""
-    a = make_engine("vectorized")
-    b = make_engine("vectorized")
+    """The pipelined closed loop (donated buffers, chunked scans) must
+    equal serial single-round stepping — the frontier-sweep gate,
+    pinned as a test."""
+    a = make_engine()
+    b = make_engine()
     n = a.cfg.num_instances
     camp = np.zeros(n, bool)
     camp[[0, R]] = True
@@ -244,37 +352,3 @@ def test_hosted_narrow_message_staging():
     assert (commits >= 2).all(), commits
     # Round-tripped state keeps the narrow storage dtypes.
     assert np.asarray(rns[1].state.role).dtype == np.int8
-
-
-# -- chaos re-fly for the non-default shapes (slow: the CPU-default
-# vectorized shape already runs the whole quick subset in
-# test_chaos.py) --------------------------------------------------------------
-
-@pytest.mark.slow
-@pytest.mark.chaos
-@pytest.mark.parametrize("shape", ["lanes", "merged"])
-def test_chaos_msg_faults_other_shapes(tmp_path, shape):
-    """One message-fault episode per non-default shape, strict
-    3-checker + invariant_trips() == 0 (the quick-chaos bar)."""
-    from etcd_tpu.batched.faults import (
-        ChaosHarness,
-        FaultSpec,
-        LeaderObserver,
-        run_invariant_checks,
-    )
-    from .test_chaos import CFG, MSG_FAULTS, SEEDS
-
-    cfg = CFG._replace(deliver_shape=shape)
-    h = ChaosHarness(str(tmp_path), SEEDS[0], MSG_FAULTS,
-                     num_members=R, num_groups=cfg.num_groups, cfg=cfg)
-    obs = LeaderObserver(h.alive)
-    try:
-        h.wait_leaders()
-        obs.start()
-        acked = h.run_workload(20)
-        assert acked >= 10, f"only {acked}/20 writes acked"
-        h.plan.quiesce()
-        run_invariant_checks(h, obs, expect_members=R)
-    finally:
-        obs.stop()
-        h.stop()

@@ -8,18 +8,27 @@ import random
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from etcd_tpu.batched.kernels import (
+    MAX_I32,
     VOTE_LOST,
     VOTE_PENDING,
     VOTE_WON,
     find_conflict_by_term,
+    joint_committed,
+    joint_vote_result,
     quorum_committed,
     term_at,
     vote_result,
 )
 from etcd_tpu.raft.log import RaftLog
-from etcd_tpu.raft.quorum import MajorityConfig, VoteResult
+from etcd_tpu.raft.quorum import (
+    MAX_UINT64,
+    JointConfig,
+    MajorityConfig,
+    VoteResult,
+)
 from etcd_tpu.raft.storage import MemoryStorage
 from etcd_tpu.raft.types import ConfState, Entry, Snapshot, SnapshotMetadata
 
@@ -45,12 +54,14 @@ def test_quorum_committed_matches_oracle():
             assert got[i] == cfg.committed_index(lambda vid: m[vid]), (m, v)
 
 
+VOTE_OF = {
+    VOTE_WON: VoteResult.VoteWon,
+    VOTE_LOST: VoteResult.VoteLost,
+    VOTE_PENDING: VoteResult.VotePending,
+}
+
+
 def test_vote_result_matches_oracle():
-    mapping = {
-        VOTE_WON: VoteResult.VoteWon,
-        VOTE_LOST: VoteResult.VoteLost,
-        VOTE_PENDING: VoteResult.VotePending,
-    }
     cases = []
     for _ in range(500):
         votes = [rng.choice([-1, 0, 1]) for _ in range(R)]
@@ -62,7 +73,63 @@ def test_vote_result_matches_oracle():
     for i, (vs, v) in enumerate(cases):
         cfg = MajorityConfig(j for j in range(R) if v[j])
         votes_map = {j: bool(vs[j]) for j in range(R) if vs[j] >= 0}
-        assert mapping[got[i]] == cfg.vote_result(votes_map), (vs, v)
+        assert VOTE_OF[got[i]] == cfg.vote_result(votes_map), (vs, v)
+
+
+@pytest.mark.parametrize("r", [1, 3, 5, 7])
+def test_joint_quorum_kernels_match_oracle(r):
+    """``joint_committed`` and ``joint_vote_result`` against
+    ``JointConfig`` on random rows: in-joint rows (both halves decide)
+    and rows whose outgoing half is set but not in force."""
+    rs = np.random.RandomState(42 + r)
+    n = 700
+    match = rs.randint(0, 50, size=(n, r)).astype(np.int32)
+    voter = rs.rand(n, r) < 0.8
+    voter_out = rs.rand(n, r) < 0.4
+    in_joint = rs.rand(n) < 0.5
+    votes = rs.randint(-1, 2, size=(n, r)).astype(np.int32)
+    # Empty-config rows: no voter at all, and an in-joint row with
+    # neither half populated.
+    voter[0] = False
+    in_joint[0] = False
+    voter[1] = False
+    voter_out[1] = False
+    in_joint[1] = True
+
+    args = (jnp.asarray(voter), jnp.asarray(voter_out),
+            jnp.asarray(in_joint))
+    commit = np.asarray(
+        jax.jit(jax.vmap(joint_committed))(jnp.asarray(match), *args))
+    vres = np.asarray(
+        jax.jit(jax.vmap(joint_vote_result))(jnp.asarray(votes), *args))
+    assert in_joint.any() and (~in_joint & voter_out.any(axis=1)).any()
+    for i in range(n):
+        cfg = JointConfig(
+            np.flatnonzero(voter[i]).tolist(),
+            np.flatnonzero(voter_out[i]).tolist() if in_joint[i] else ())
+        want = cfg.committed_index(lambda vid, i=i: int(match[i, vid]))
+        assert commit[i] == (MAX_I32 if want == MAX_UINT64 else want), i
+        cast = {j: bool(votes[i, j]) for j in range(r) if votes[i, j] >= 0}
+        assert VOTE_OF[vres[i]] == cfg.vote_result(cast), i
+
+
+def test_an_empty_joint_config_commits_everything_and_wins_the_vote():
+    """quorum/majority.go's convention for a half with no voter, which
+    makes a half-populated joint quorum behave like a majority one."""
+    r = 3
+    none = jnp.zeros((r,), bool)
+    for in_joint in (False, True):
+        assert int(joint_committed(
+            jnp.zeros((r,), jnp.int32), none, none,
+            jnp.asarray(in_joint))) == MAX_I32
+        assert int(joint_vote_result(
+            jnp.full((r,), -1, jnp.int32), none, none,
+            jnp.asarray(in_joint))) == VOTE_WON
+    # A populated incoming half decides alone beside an empty outgoing.
+    voter = jnp.asarray([True, True, True])
+    assert int(joint_committed(
+        jnp.asarray([5, 3, 1], jnp.int32), voter, none,
+        jnp.asarray(True))) == 3
 
 
 def _random_log():

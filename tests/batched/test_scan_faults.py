@@ -8,7 +8,7 @@ Round-step programs: ``CELL`` holds the values of the benchmark's
 ``engine100k-r3`` at the CPU tests' 8 groups (``tests/benchmark`` builds
 the same program), ``R5`` those of ``engine10k-r5`` at 8 groups and
 ``R3_MAJOR`` those of ``test_pipelined.make_engine(4)``; only ``CELL``
-and its ``merged`` twin are new (``conftest.py``, ISSUE 28 audit).
+is this file's own (``conftest.py``, ISSUE 28 and ISSUE 30 audits).
 """
 
 import jax
@@ -206,20 +206,19 @@ def _cut_node(rnd: int, k0: int, r: int):
     return (k0 + period) % r if 32 <= t < 96 else None
 
 
-@pytest.mark.parametrize("deliver_shape", ["auto", "merged"])
-def test_elections_under_etcd_defaults_match_the_oracle_every_round(
-        deliver_shape):
+@pytest.mark.parametrize("k0", [0, 1, 2], ids=["node0", "node1", "node2"])
+def test_elections_under_etcd_defaults_match_the_oracle_every_round(k0):
     """Timer elections, PreVote, the CheckQuorum step-down of a leader
     cut off, snapshot catch-up of the healed node, proposals offered to
     every replica throughout: three periods of the cell's schedule,
-    state and log of every replica compared after every round."""
-    cfg = CELL._replace(deliver_shape=deliver_shape)
-    eng = MultiRaftEngine(cfg)
+    state and log of every replica compared after every round. ``k0``
+    is the node cut first: node 0 leads four of the eight groups at
+    that point, node 1 two, node 2 two, so each case cuts leaders in
+    some groups and followers in the others, in another order."""
+    eng = MultiRaftEngine(CELL)
     cfg = eng.cfg
     g_n, r, n = cfg.num_groups, cfg.num_replicas, cfg.num_instances
-    rng = np.random.default_rng(2700)
-    slots = rng.integers(0, r, g_n)
-    k0 = int(rng.integers(0, r))
+    slots = np.random.default_rng(2700).integers(0, r, g_n)
     shadows = [
         ShadowCluster(
             r, election_timeout=cfg.election_timeout,
@@ -227,8 +226,7 @@ def test_elections_under_etcd_defaults_match_the_oracle_every_round(
             max_inflight=cfg.max_inflight, pre_vote=True,
             check_quorum=True, group=g, deterministic_timeouts=True,
             auto_compact_window=cfg.window, max_ents=cfg.max_ents_per_msg,
-            max_props=cfg.max_props_per_round,
-            deliver_shape=cfg.deliver_shape)
+            max_props=cfg.max_props_per_round)
         for g in range(g_n)]
     eng.campaign(np.arange(g_n) * r + slots)
     for g, sh in enumerate(shadows):

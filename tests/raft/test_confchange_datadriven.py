@@ -23,6 +23,9 @@ from etcd_tpu.raft.types import ConfChangeSingle, ConfChangeType
 from etcd_tpu.rafttest.datadriven import parse_file
 
 TESTDATA = "/root/reference/raft/confchange/testdata"
+if not os.path.isdir(TESTDATA):
+    pytest.skip(f"reference testdata not available: {TESTDATA}",
+                allow_module_level=True)
 FILES = sorted(f for f in os.listdir(TESTDATA) if f.endswith(".txt"))
 
 TOKEN_TYPES = {

@@ -13,8 +13,7 @@
 # + lifecycle smoke (ISSUE 17 log-lifecycle plane: rotation, cadence
 # snapshots, fleet-min release, restart replay from snapshot files)
 # + applyplane smoke (ISSUE 19 device apply plane: lease-hit read,
-# watch frame, TTL expiry on the plane clock, transfer fallback)
-# + bench-history re-emit. CI
+# watch frame, TTL expiry on the plane clock, transfer fallback). CI
 # runs exactly this script
 # (.github/workflows/lint.yml); run it locally before pushing anything
 # that touches the batched hot path.
@@ -53,7 +52,7 @@ python tools/walpipe_smoke.py
 echo "== diskfault smoke (fsync-error fail-stop + ENOSPC recover, IO-error contract) =="
 python tools/diskfault_smoke.py
 
-echo "== fused-round smoke (all deliver shapes agree, transfer guard disallow) =="
+echo "== fused-round smoke (election, commits, ReadIndex; transfer guard disallow) =="
 python tools/fused_smoke.py
 
 echo "== shmfabric smoke (3-member shm ring cluster, console transport column) =="
@@ -64,8 +63,5 @@ python tools/lifecycle_smoke.py
 
 echo "== applyplane smoke (lease-hit read, watch frame, TTL expiry, transfer fallback) =="
 python tools/applyplane_smoke.py
-
-echo "== bench history (artifacts/bench_history.json + BENCH_HISTORY.md) =="
-python tools/bench_history.py
 
 echo "check.sh: all gates green"

@@ -6,8 +6,7 @@ compiled OFF and ON, **interleaved in one process on one box** (the
 box drifts tens of percent day to day — BENCH_NOTES discipline: never
 compare across runs, always A/B within one), at G=512 and G=1024 on
 the canonical bench config (tools/benchlib), and writes
-``artifacts/fleet_overhead.json`` — the row ``tools/bench_history.py``
-ingests and BENCH_NOTES quotes.
+``artifacts/fleet_overhead.json`` — the row BENCH_NOTES quotes.
 
     JAX_PLATFORMS=cpu python tools/fleet_overhead.py [--reps 3]
 """

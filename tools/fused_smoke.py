@@ -1,14 +1,13 @@
 #!/usr/bin/env python
-"""Fused-round smoke (ISSUE 14): tiny-G cross-check of every shipped
-deliver shape under the transfer guard.
+"""Fused-round smoke: a tiny-G liveness run of the round under the
+transfer guard.
 
-One engine per deliver shape (lanes | merged | vectorized) drives an
-identical schedule — contested election, steady proposals, a
+One engine drives a schedule — contested election, steady proposals, a
 partition round, a ReadIndex batch — with every warm dispatch inside
-``ETCD_TPU_TRANSFER_GUARD=disallow``. The three end states must agree
-on every protocol field, commits must have advanced, and the ReadIndex
-batch must have confirmed. This is the check.sh/CI face of the
-equivalence contract; the full seeded suites live in
+``ETCD_TPU_TRANSFER_GUARD=disallow``. Commits must have advanced, the
+contested election must have seated the leader the delivery order
+picks, and the ReadIndex batch must have confirmed. This is the
+check.sh/CI face; the round is held to the oracle field by field in
 tests/batched/test_deliver_shapes.py and test_differential.py.
 
     python tools/fused_smoke.py
@@ -33,25 +32,19 @@ def main() -> int:
     import numpy as np
 
     from etcd_tpu.batched import BatchedConfig, MultiRaftEngine
-    from etcd_tpu.batched.state import DELIVER_SHAPES
 
-    engines = {}
-    for shape in DELIVER_SHAPES:
-        cfg = BatchedConfig(
-            num_groups=G, num_replicas=R, window=32,
-            max_ents_per_msg=4, max_props_per_round=2,
-            election_timeout=1 << 20, heartbeat_timeout=1,
-            deliver_shape=shape,
-        )
-        engines[shape] = MultiRaftEngine(cfg)
+    eng = MultiRaftEngine(BatchedConfig(
+        num_groups=G, num_replicas=R, window=32,
+        max_ents_per_msg=4, max_props_per_round=2,
+        election_timeout=1 << 20, heartbeat_timeout=1,
+    ))
 
     n = G * R
     camp = np.zeros(n, bool)
     camp[[g * R + g % R for g in range(G)]] = True
     # Contested re-election in group 0: BOTH followers campaign in the
     # same round (split self-votes; the shared voter breaks the tie by
-    # sender order) — the vote-lane tournament and the tally fold must
-    # resolve it exactly like the sequential scans.
+    # sender order).
     camp2 = np.zeros(n, bool)
     camp2[[1, 2]] = True
     props = jnp.zeros((n,), jnp.int32)
@@ -61,59 +54,40 @@ def main() -> int:
     read = np.zeros(n, bool)
     read[[g * R + g % R for g in range(G)]] = True
 
-    def drive(eng):
-        eng.step_round(campaign_mask=jnp.asarray(camp))
-        for _ in range(3):
-            eng.step_round()
-        eng.step_round(propose_n=props)
-        for _ in range(2):
-            eng.step_round()
-        eng.step_round(campaign_mask=jnp.asarray(camp2))
-        for _ in range(3):
-            eng.step_round()
-        eng.step_round(propose_n=props, isolate=jnp.asarray(iso))
-        for _ in range(2):
-            eng.step_round()
-        eng.step_round(read_req=jnp.asarray(read))
-        for _ in range(3):
-            eng.step_round()
+    eng.step_round(campaign_mask=jnp.asarray(camp))
+    for _ in range(3):
+        eng.step_round()
+    eng.step_round(propose_n=props)
+    for _ in range(2):
+        eng.step_round()
+    eng.step_round(campaign_mask=jnp.asarray(camp2))
+    for _ in range(3):
+        eng.step_round()
+    eng.step_round(propose_n=props, isolate=jnp.asarray(iso))
+    for _ in range(2):
+        eng.step_round()
+    eng.step_round(read_req=jnp.asarray(read))
+    for _ in range(3):
+        eng.step_round()
 
-    for shape, eng in engines.items():
-        drive(eng)
-
-    fields = ("term", "vote", "role", "lead", "commit", "last",
-              "match", "next", "read_seq", "read_ready", "snap_index")
-    ref = engines[DELIVER_SHAPES[0]]
-    for shape, eng in engines.items():
-        for f in fields:
-            # jitlint: waive(sync-in-loop) -- end-of-smoke differential gate, not a hot path: one bulk gather per compared field
-            a = np.asarray(getattr(ref.state, f))
-            # jitlint: waive(sync-in-loop) -- same differential gate gather as above
-            b = np.asarray(getattr(eng.state, f))
-            assert (a == b).all(), (
-                f"fused smoke: {shape} diverges from "
-                f"{DELIVER_SHAPES[0]} on {f}:\n{a}\nvs\n{b}")
-        commits = eng.commits()
-        assert commits.min() >= 2, (shape, commits)
-        # Group 0's contested re-election must have produced a new
-        # leader at a higher term (sender-order tie-break: slot 1).
-        # jitlint: waive(sync-in-loop) -- end-of-smoke assertion gather, not a hot path
-        role = np.asarray(eng.state.role)
-        # jitlint: waive(sync-in-loop) -- same end-of-smoke assertion gather
-        assert role[1] == 2 and np.asarray(eng.state.term)[1] >= 2, (
-            shape, role[:3])
-        _seq, idx, ready = eng.read_states()
-        # Groups 1.. kept their seeded leaders (group 0's read lands
-        # on a deposed row and is a no-op — also identical per shape).
-        lead_rows = [g * R + g % R for g in range(1, G)]
-        assert all(ready[i] for i in lead_rows), (shape, ready)
-        assert all(idx[i] >= 0 for i in lead_rows)
+    commits = eng.commits()
+    assert commits.min() >= 2, commits
+    # Group 0's contested re-election must have produced a new leader
+    # at a higher term (sender-order tie-break: slot 1).
+    role = np.asarray(eng.state.role)
+    assert role[1] == 2 and np.asarray(eng.state.term)[1] >= 2, role[:3]
+    _seq, idx, ready = eng.read_states()
+    # Groups 1.. kept their seeded leaders (group 0's read lands on a
+    # deposed row and is a no-op).
+    lead_rows = [g * R + g % R for g in range(1, G)]
+    assert all(ready[i] for i in lead_rows), ready
+    assert all(idx[i] >= 0 for i in lead_rows)
 
     print(json.dumps({
         "fused_smoke": "ok",
-        "shapes": list(DELIVER_SHAPES),
+        "deliver": eng.cfg.deliver_shape,
         "groups": G,
-        "commit_min": int(ref.commits().min()),
+        "commit_min": int(commits.min()),
         "transfer_guard": os.environ["ETCD_TPU_TRANSFER_GUARD"],
     }))
     return 0

@@ -1,12 +1,12 @@
 #!/usr/bin/env python
 """Device round-segment attribution: time each named_scope phase of the
 batched round as its own jitted program and write the per-segment table
-to ``artifacts/`` — the "deliver scan dominates the round" claim as a
-tracked artifact instead of one ad-hoc probe's folklore.
+to ``artifacts/`` — which phase dominates the round as a tracked
+artifact instead of one ad-hoc probe's folklore.
 
 Method: a warmed ``MultiRaftEngine`` supplies a realistic steady-state
 (leaders elected, proposals staged, inbox populated); each phase
-function (``step._deliver_all`` / ``_tick`` / ``_control`` /
+function (``step._deliver_vectorized`` / ``_tick`` / ``_control`` /
 ``_propose`` / ``_emit``, vmapped over instances, plus ``route`` and
 ``pack_outbox``) is jitted in isolation, warmed once, then timed over
 K dispatches with the result fenced — every timed call runs inside the
@@ -67,11 +67,6 @@ def main() -> int:
     ap.add_argument("--groups", type=int, default=512)
     ap.add_argument("--layout", choices=("minor", "major"),
                     default="minor")
-    ap.add_argument("--deliver-shape",
-                    choices=("auto", "lanes", "merged", "vectorized"),
-                    default="auto",
-                    help="deliver shape to probe (auto = the platform "
-                         "default, state.default_deliver_shape)")
     ap.add_argument("--rounds", type=int, default=32)
     ap.add_argument("--out-dir", default="artifacts")
     ap.add_argument("--xprof", default="", metavar="DIR",
@@ -86,8 +81,7 @@ def main() -> int:
         max_props_per_round=2, election_timeout=1 << 20,
         heartbeat_timeout=4, auto_compact=True,
         lanes_minor=args.layout == "minor",
-        deliver_shape=args.deliver_shape,
-    ).resolved()
+    )
     eng = MultiRaftEngine(cfg)
     eng.campaign([i * 3 for i in range(g)])
     eng.run_rounds(4, tick=False)
@@ -109,11 +103,12 @@ def main() -> int:
     phase_fns = {
         # deliver takes the batch-level lane-occupancy vector exactly
         # as the production round does (computed outside the vmap →
-        # the vectorized shape's lane skips stay real branches).
+        # the lane skips stay real branches).
         "deliver": (
             jax.jit(lambda _iids, _slots, _st, _inbox: jax.vmap(
                 lambda iid, slot, sti, inb, la:
-                step_mod._deliver_all(cfg, iid, slot, sti, inb, la),
+                step_mod._deliver_vectorized(
+                    cfg, iid, slot, sti, inb, la),
                 in_axes=(0, 0, 0, 0, None))(
                 _iids, _slots, _st, _inbox,
                 jnp.any(_inbox.valid, axis=(0, 1)))),
@@ -187,7 +182,6 @@ def main() -> int:
     result = {
         "metric": "round_segment_attribution",
         "config": (f"G={g} R=3 W=32 E=4 layout={args.layout} "
-                   f"deliver={cfg.deliver_shape} "
                    f"platform={backend.platform}"),
         "device": str(backend),
         "rounds_per_segment": args.rounds,
