@@ -7,7 +7,13 @@ watermarks / advance) but batched over every group at once, and runs
 closed-loop rounds entirely on device (deliver → tick → propose → emit →
 route), faults included: a scan takes a per-round schedule of nodes cut
 off the network (``run_rounds(isolate=...)``), so an outage begins and
-heals inside one program. Entry payloads never touch the device: the host keeps them in
+heals inside one program. Inside a scan the network moves only what
+was sent: the inbox rides as its six kind lanes and a round exchanges
+the lanes some instance of the batch wrote (``step.route_lanes``, on the
+occupancy vector deliver's lane conds skip on); a lane nobody wrote
+holds what ``empty_msgs`` holds, ``valid`` false and every field zero,
+in the scan and in ``eng.inbox`` after it (``lane_rounds()`` counts the
+rounds each lane was occupied, so exchanged). Entry payloads never touch the device: the host keeps them in
 an arena keyed by (group, index), and the commit watermarks streaming
 back from the device drive payload application — mirroring how the
 reference applies committed entries after the Ready loop (ref:
@@ -32,7 +38,9 @@ from .compile_cache import enable_compile_cache
 # .count is atomic under the GIL).
 _ENGINE_SERIAL = itertools.count()
 from .state import BatchedConfig, BatchedState, init_state, LEADER, I32
-from .step import MsgSlots, NUM_KINDS, empty_msgs, make_step_round, route
+from .step import (MsgSlots, NUM_KINDS, empty_msgs, lane_occupancy,
+                   make_step_round, route, route_lanes, split_lanes,
+                   stack_lanes)
 
 
 class MultiRaftEngine:
@@ -69,6 +77,17 @@ class MultiRaftEngine:
             narrow=cfg.narrow_lanes,
         )
         self._step = make_step_round(cfg)
+
+        def step_round(st, inbox, *masks):
+            # The eager round hands the round program what the scan
+            # hands it, lanes and their occupancy, so the two share
+            # one trace of it (a cold start traces the round once,
+            # not twice). `_step` is read when this is first traced.
+            lanes = split_lanes(inbox)
+            return self._step(st, lanes, *masks,
+                              lane_any=lane_occupancy(lanes))
+
+        self._round = jax.jit(step_round)
         n = cfg.num_instances
         self._zeros_b = jnp.zeros((n,), bool)
         self._zeros_i = jnp.zeros((n,), I32)
@@ -127,11 +146,11 @@ class MultiRaftEngine:
                 slots = jnp.arange(n, dtype=I32) % cfg.num_replicas
 
             def body(carry, cut):
-                st, inbox, tel, flt, lanes = carry
-                # The round's own occupancy vector (step_round's
-                # lane_any, which XLA shares): what deliver's lane
-                # conds skip on.
-                lanes = lanes + jnp.any(inbox.valid, axis=(0, 1))
+                # `occ` is the inbox's lane occupancy, [K] bool: what
+                # deliver's lane conds skip on and route_lanes' are
+                # told was there.
+                st, inbox, occ, tel, flt, lanes = carry
+                lanes = lanes + occ
                 iso = self._zeros_b
                 # jitlint: waive(tracer-branch) -- as above: a scan without xs hands its body None
                 if cut is not None:
@@ -140,7 +159,8 @@ class MultiRaftEngine:
                     for s in range(cfg.num_replicas):
                         iso = iso | ((slots == s) & cut[s])
                 out = self._step(
-                    st, inbox, ticks, self._zeros_b, props, iso
+                    st, inbox, ticks, self._zeros_b, props, iso,
+                    self._zeros_i, self._zeros_b, lane_any=occ,
                 )
                 st, outbox = out[:2]
                 if cfg.telemetry:
@@ -149,11 +169,33 @@ class MultiRaftEngine:
                 if cfg.fleet_summary:
                     fv = out[self._fleet_pos]
                     flt = jnp.where(self._fleet_summask, flt + fv, fv)
-                return (st, route(cfg, outbox), tel, flt, lanes), None
+                # The lanes somebody wrote this round are exchanged,
+                # those that held last round's messages wiped, the rest
+                # left as they are (step.route_lanes). The exchange
+                # permutes slots inside a lane, so the outbox's
+                # occupancy is the next inbox's.
+                sent = jnp.any(outbox.valid, axis=(0, 1))
+                inbox = route_lanes(cfg, outbox, sent, (inbox, occ))
+                return (st, inbox, sent, tel, flt, lanes), None
 
-            (st, inbox, tel, flt, lanes), _ = jax.lax.scan(
-                body, (st, inbox, tel, flt, lanes), isolate, length=rounds
+            # The inbox rides the scan as its K kind lanes, each an
+            # array of its own, and is stacked back once at the exit.
+            # A caller's inbox may hold anything in a lane with no
+            # valid slot (the eager round exchanges emit's unsent
+            # request fields too), so such a lane is wiped here, once
+            # a call: inside the scan an empty lane is all zeros.
+            inbox = split_lanes(inbox)
+            occ = lane_occupancy(inbox)
+            inbox = tuple(
+                jax.tree.map(
+                    lambda x, _k=k: jnp.where(occ[_k], x, jnp.zeros_like(x)),
+                    inbox[k])
+                for k in range(NUM_KINDS))
+            (st, inbox, _, tel, flt, lanes), _ = jax.lax.scan(
+                body, (st, inbox, occ, tel, flt, lanes), isolate,
+                length=rounds
             )
+            inbox = stack_lanes(inbox)
             # The scalar fence is a SEPARATE output buffer: pipelined
             # callers block on it to bound queue depth without holding
             # (and thereby breaking) a donated state buffer.
@@ -200,13 +242,15 @@ class MultiRaftEngine:
         camp = campaign_mask if campaign_mask is not None else self._zeros_b
         props = propose_n if propose_n is not None else self._zeros_i
         iso = isolate if isolate is not None else self._zeros_b
+        transfer = transfer_to if transfer_to is not None else self._zeros_i
+        reads = read_req if read_req is not None else self._zeros_b
         # Inside the guard the dispatch must be all-device: any implicit
         # transfer (an eager scalar op, a stray host array) is a hard
         # error when ETCD_TPU_TRANSFER_GUARD=disallow (tests, benches).
         with self._span("engine.step_round"), warm_guard(self._wkey_step):
-            out = self._step(
+            out = self._round(
                 self.state, self.inbox, ticks, camp, props, iso,
-                transfer_to, read_req,
+                transfer, reads,
             )
             self.state, outbox = out[:2]
             if self.cfg.telemetry:
@@ -434,8 +478,11 @@ class MultiRaftEngine:
         which each inbox lane — vote, append, heartbeat and their
         responses, in kind order — held a message for any instance:
         the rounds in which deliver ran that lane's fold for the
-        batch (the lane skip, step._deliver_vectorized). Accumulated
-        in the scan's carry; one host gather, no per-round sync."""
+        batch (the lane skip, step._deliver_vectorized) and the
+        rounds before them in which route() exchanged it
+        (step.route_lanes; lanes run a round over 6 is the share of
+        the exchange that ran). Accumulated in the scan's carry; one
+        host gather, no per-round sync."""
         return np.asarray(self._lanes)
 
     def commits(self) -> np.ndarray:

@@ -191,6 +191,24 @@ def empty_msgs(shape: Tuple[int, ...], num_ents: int,
     return narrow_msgs(m) if narrow else m
 
 
+def split_lanes(m: MsgSlots) -> Tuple[MsgSlots, ...]:
+    """[N, R, K] slots as K kind lanes of [N, R]."""
+    return tuple(
+        jax.tree.map(lambda x, _k=k: x[:, :, _k], m)
+        for k in range(NUM_KINDS))
+
+
+def stack_lanes(lanes: Tuple[MsgSlots, ...]) -> MsgSlots:
+    """K kind lanes of [N, R] as [N, R, K] slots."""
+    return jax.tree.map(lambda *xs: jnp.stack(xs, axis=2), *lanes)
+
+
+def lane_occupancy(lanes: Tuple[MsgSlots, ...]) -> jnp.ndarray:
+    """[K] bool: which kind lanes hold a message for any instance."""
+    return jnp.stack(
+        [jnp.any(lanes[k].valid) for k in range(NUM_KINDS)])
+
+
 def _sel(cond, a, b):
     """Tree-select: where(cond, a, b) leafwise (cond is scalar here)."""
     return jax.tree.map(lambda x, y: jnp.where(cond, x, y), a, b)
@@ -978,8 +996,9 @@ def _vec_lane_hb_resp(cfg: BatchedConfig, iid, slot, st: BatchedState,
 
 
 def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
-                        inbox: MsgSlots, lane_any=None):
-    """Deliver this instance's [R, K] inbox: lanes in kind order, each
+                        inbox: Tuple[MsgSlots, ...], lane_any=None):
+    """Deliver this instance's inbox, K kind lanes of [R] slots each:
+    lanes in kind order, each
     lane one fold over the sender axis (the order contract is in the
     section comment above). Returns the state and the [R, 3] responses
     to the request lanes, which the round routes back in lanes
@@ -989,7 +1008,7 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
     are attribution labels inside it.
 
     ``lane_any`` ([K] bool, optional) is the batch-level lane-occupancy
-    vector: the CALLER computes ``jnp.any(inbox.valid, axis=(0, 1))``
+    vector: the CALLER reduces each lane's ``valid`` over the batch
     OUTSIDE the instance vmap so each lane's fold sits under a lax.cond
     with an UNMAPPED predicate — a lane nobody used this round (votes
     in steady state, heartbeat lanes off-cadence) costs nothing instead
@@ -997,7 +1016,6 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
     so the skip is bit-equivalent; None falls back to per-instance
     occupancy (the cond degrades to a select under a mapped predicate
     — correct, just unskipped)."""
-    lane = lambda k: jax.tree.map(lambda x, _k=k: x[:, _k], inbox)  # noqa: E731
     no_resp = empty_msgs((cfg.num_replicas,), cfg.max_ents_per_msg)
 
     def occupied(k, m):
@@ -1014,7 +1032,7 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
         # cond ring-minor on TPU, and both branches then relayout all
         # of it, every round (two 25 MB copies at G=65,536; PERF.md
         # section 6, PR 29).
-        m = lane(KIND_VOTE)
+        m = inbox[KIND_VOTE]
         last_term = jax.lax.optimization_barrier(term_at(
             stx.log_term, stx.snap_index, stx.snap_term, stx.last,
             stx.last))
@@ -1030,7 +1048,7 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
     def request(k, handler, stx):
         # The cond holds the winner's handler; the [R] response slots
         # are widened after it (see _vec_lane_request on why).
-        m = lane(k)
+        m = inbox[k]
         occ = occupied(k, m)
         no_answer = (empty_msgs((), cfg.max_ents_per_msg),
                      jnp.zeros((), I32),
@@ -1045,7 +1063,7 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
         return stx, _vec_request_resps(cfg, stx, answer, occ)
 
     def state_only(k, fn, stx):
-        m = lane(k)
+        m = inbox[k]
         return jax.lax.cond(
             occupied(k, m),
             lambda sty, mx: fn(sty, mx),
@@ -1053,6 +1071,16 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
             stx, m,
         )
 
+    # No lane writes send_heartbeat (tick and control set it, emit
+    # clears it), so it goes round the six conds like the ring round
+    # the vote cond: with the lanes arrays of their own the TPU
+    # compiler threads this one pass-through field through the three
+    # response conds a second time, instance-major, and copies it
+    # there and back every round (three relayouts of pred[N, R], 5%
+    # of the R=3 append round by its own cost; PERF.md section 6,
+    # PR 31).
+    send_heartbeat = st.send_heartbeat
+    st = st._replace(send_heartbeat=jnp.zeros((0,), bool))
     st, r0 = votes(st)
     st, r1 = request(KIND_APP, _lane_app, st)
     st, r2 = request(KIND_HB, _lane_hb, st)
@@ -1065,6 +1093,7 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
     st = state_only(
         KIND_HB_RESP,
         lambda s, m: _vec_lane_hb_resp(cfg, iid, slot, s, m), st)
+    st = st._replace(send_heartbeat=send_heartbeat)
     # [R] per request lane → [R, 3].
     req = jax.tree.map(
         lambda a, b, c: jnp.stack((a, b, c), axis=1), r0, r1, r2
@@ -1383,8 +1412,9 @@ ROUND_PHASE_SCOPES = (
 
 @functools.lru_cache(maxsize=None)
 def _route_jit(r: int):
-    """The router of an R-replica layout as ONE jitted program (inlined
-    where a caller is itself being traced, e.g. the engine's scan)."""
+    """The router of an R-replica layout as jitted programs (inlined
+    where a caller is itself being traced, e.g. the engine's scan):
+    the whole exchange, and the exchange by kind lane."""
 
     def exchange(x):
         # inbox[n, s] = outbox[n + s - t, t] with t = n % R: the row of
@@ -1415,10 +1445,35 @@ def _route_jit(r: int):
         with jax.named_scope("raft_route"):
             return jax.tree.map(exchange, outbox)
 
-    return jax.jit(route, inline=True)
+    # The by-lane exchange meets the same few shapes six times over:
+    # a jitted callee is traced and lowered once a shape (XLA inlines
+    # it again), which is a third of a warm start's time in the scan.
+    exchange_lane = jax.jit(exchange)
+
+    def route_lanes(outbox: MsgSlots, lane_any, lanes, stale):
+        # Lane by lane and never re-stacked: K is the physically major
+        # axis of the outbox on TPU, so a lane of it is one contiguous
+        # slab a branch slices for nothing, and what a branch returns
+        # is the carry deliver's lane cond takes as it is. (Per-lane
+        # results concatenated back into [N, R, K] relaid the whole
+        # carried inbox out, K into the sublanes: PERF.md section 6,
+        # PR 31.)
+        with jax.named_scope("raft_route"):
+            return tuple(
+                jax.lax.switch(
+                    jnp.where(lane_any[k], 2, stale[k].astype(I32)),
+                    (lambda lane, ob: lane,
+                     lambda lane, ob: jax.tree.map(jnp.zeros_like, lane),
+                     lambda lane, ob, _k=k: jax.tree.map(
+                         lambda o: exchange_lane(o[:, :, _k]), ob)),
+                    lanes[k], outbox)
+                for k in range(NUM_KINDS))
+
+    return (jax.jit(route, inline=True), jax.jit(route_lanes, inline=True))
 
 
-def route(cfg: BatchedConfig, outbox: MsgSlots) -> MsgSlots:
+def route(cfg: BatchedConfig, outbox: MsgSlots, lane_any=None,
+          prev=None) -> MsgSlots:
     """All-device network: outbox[i, target_slot, k] → inbox[t, sender_slot, k]
     where i = g*R + s and t = g*R + r — rafthttp's peer streams (ref:
     SURVEY.md §5 "Distributed communication backend") as an exchange
@@ -1432,14 +1487,53 @@ def route(cfg: BatchedConfig, outbox: MsgSlots) -> MsgSlots:
     N = g*R + s out of the TPU's lane dimension and leaves R alone in
     the 128 lanes. That spelling is the oracle of
     tests/batched/test_route.py; this one is bit-identical to it for
-    every R, both ``lanes_minor`` layouts and the narrow dtypes."""
+    every R, both ``lanes_minor`` layouts and the narrow dtypes.
+
+    With ``lane_any=None`` every lane is exchanged, as one fused
+    program with no branch in it. Mesh-sharded callers need that: the
+    occupancy reduce would cross shards (see ``_step_round_jit`` on
+    ``lane_skip``). Given ``lane_any``, only the lanes somebody wrote
+    are exchanged: ``stack_lanes`` of ``route_lanes``, which see (its
+    ``prev`` here is ``(inbox [N, R, K], stale)``)."""
     # Lane indexes pass through untouched: by the inbox lane-order
     # contract (NUM_REQ_KINDS, top of module), emit writes requests
     # into lanes 0..NUM_REQ_KINDS-1 and the round's response scatter
     # has ALREADY placed each response in lane k + NUM_REQ_KINDS of the
     # responder's outbox row for the requester (see _step_round_jit),
     # so the exchange alone lands everything in its inbox lane.
-    return _route_jit(cfg.num_replicas)(outbox)
+    if lane_any is None:
+        return _route_jit(cfg.num_replicas)[0](outbox)
+    if prev is not None:
+        prev = (split_lanes(prev[0]), prev[1])
+    return stack_lanes(route_lanes(cfg, outbox, lane_any, prev))
+
+
+def route_lanes(cfg: BatchedConfig, outbox: MsgSlots, lane_any,
+                prev=None) -> Tuple[MsgSlots, ...]:
+    """route() by kind lane: the inbox as K lanes of [N, R] slots, the
+    form the engine's scan carries and ``step_round`` takes as it is.
+
+    ``lane_any`` ([K] bool) is the outbox's batch-level lane
+    occupancy, ``jnp.any(outbox.valid, axis=(0, 1))``, reduced by the
+    caller outside any vmap (the pattern of ``_deliver_vectorized``):
+    a lane somebody wrote is exchanged, under a branch with that
+    unmapped predicate; a lane nobody wrote comes out as ``empty_msgs``
+    has it — ``valid`` false and every payload field zero, not emit's
+    term, type and commit of the requests it did not send, and never
+    the last round's slots (a ``valid`` left behind would deliver a
+    message twice). The exchange permutes slots inside a lane, so
+    ``lane_any`` is also the occupancy of the lanes returned: the
+    vector deliver's lane conds skip on next round.
+
+    ``prev`` is ``(lanes, stale)``: the spent inbox, and per lane
+    whether it may hold anything but zeros. A lane empty then and now
+    is handed back untouched, which costs nothing (the vote lanes
+    under steady appends); one occupied then and empty now is wiped.
+    Without ``prev`` an empty lane is fresh zeros."""
+    if prev is None:
+        prev = (split_lanes(jax.tree.map(jnp.zeros_like, outbox)),
+                jnp.zeros((NUM_KINDS,), bool))
+    return _route_jit(cfg.num_replicas)[1](outbox, lane_any, *prev)
 
 
 class TelemetryFrame(NamedTuple):
@@ -1452,7 +1546,7 @@ class TelemetryFrame(NamedTuple):
 
 
 def _telemetry_frame(cfg: BatchedConfig, slot, pre: BatchedState,
-                     post: BatchedState, inbox_i: MsgSlots,
+                     post: BatchedState, inbox_i: Tuple[MsgSlots, ...],
                      out: MsgSlots, last_tick, n_new) -> TelemetryFrame:
     """Counters for one instance's round — a pure READ of the round's
     inputs/outputs (column order = telemetry.TM_NAMES). Never touches
@@ -1473,7 +1567,7 @@ def _telemetry_frame(cfg: BatchedConfig, slot, pre: BatchedState,
         cnt(v[:, KIND_VOTE_RESP]),
         cnt(v[:, KIND_APP_RESP]),
         cnt(v[:, KIND_HB_RESP]),
-        cnt(inbox_i.valid),
+        sum(cnt(inbox_i[k].valid) for k in range(NUM_KINDS)),
         cnt(ar_v & ~out.reject[:, KIND_APP_RESP]),
         cnt(ar_v & out.reject[:, KIND_APP_RESP]),
         cnt((pre.pr_state == PROBE) & (post.pr_state == REPLICATE)),
@@ -1624,24 +1718,37 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
         "round_step",
         f"{cfg}|aux={int(with_aux)}|laneskip={int(lane_skip)}")
 
-    def step_round(st: BatchedState, inbox: MsgSlots, tick_mask, campaign_mask,
-                   propose_n, isolate, transfer_to, read_req, iids, slots):
+    def step_round(st: BatchedState, inbox, tick_mask, campaign_mask,
+                   propose_n, isolate, transfer_to, read_req, iids, slots,
+                   lane_any=None):
+        # The inbox as [N, R, K] slots (a hosting process's, the eager
+        # engine's) or as the K kind lanes of [N, R] the engine's scan
+        # carries: deliver takes lanes, and a lane that comes as an
+        # array of its own enters its cond with no slice at the edge.
+        # jitlint: waive(tracer-branch) -- the branch is on the argument's pytree structure at trace time, never on a device value
+        if isinstance(inbox, MsgSlots):
+            inbox = split_lanes(inbox)
         if cfg.narrow_lanes:
             # Narrow lanes live int8/int16 BETWEEN rounds (the donated
             # state carry AND the routed inbox); the protocol math runs
             # on i32 exactly as in the wide layout, so parity is by
             # construction.
             st = widen_state(st)
-            inbox = widen_msgs(inbox)
+            inbox = tuple(map(widen_msgs, inbox))
 
         # Batch-level lane occupancy for deliver's lax.cond lane
         # skips: computed OUTSIDE the vmap and passed unmapped
         # (in_axes=None), so the conds stay real branches
         # instead of degrading to selects under a mapped predicate.
         # None when lane_skip is off (sharded callers — see docstring).
-        lane_any = (
-            jnp.any(inbox.valid, axis=(0, 1)) if lane_skip else None
-        )  # [K]
+        # A caller that routed this inbox by lane holds the vector
+        # already (route_lanes) and hands it in; lane_skip off
+        # overrules it.
+        if not lane_skip:
+            lane_any = None
+        # jitlint: waive(tracer-branch) -- None is an empty pytree: the branch is on the argument's structure at trace time
+        elif lane_any is None:
+            lane_any = lane_occupancy(inbox)  # [K]
 
         def per_instance(iid, slot, sti, inbox_i, do_tick, do_camp, n_new,
                          iso, tr_to, rd_req, lane_any):
@@ -1651,7 +1758,9 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
             # profiler traces attribute device time per phase (SURVEY
             # §5 tracing: profiler hooks around the step kernel).
             pre = sti  # round-entry state (telemetry deltas)
-            inbox_i = inbox_i._replace(valid=inbox_i.valid & ~iso)
+            inbox_i = tuple(
+                inbox_i[k]._replace(valid=inbox_i[k].valid & ~iso)
+                for k in range(NUM_KINDS))
             with jax.named_scope("raft_deliver"):
                 sti, req_resps = _deliver_vectorized(
                     cfg, iid, slot, sti, inbox_i, lane_any)
@@ -1772,7 +1881,10 @@ def make_step_round(cfg: BatchedConfig, iids=None, slots=None,
     multi-raft simulation (the dense all-replica layout), or pass
     explicit `iids`/`slots` for a hosting process that owns one replica
     slot of each group (iid = group*R + slot keeps the deterministic
-    randomized-timeout hash identical across topologies)."""
+    randomized-timeout hash identical across topologies). `inbox` is
+    [N, R, K] slots or the K kind lanes route_lanes() returns; a caller
+    that holds the inbox's lane occupancy already (route_lanes' own
+    `lane_any`) may hand it in as `lane_any` and save the reduce."""
     # Resolve deliver_shape="auto" BEFORE the per-config jit cache so
     # "auto" and "vectorized" share one program.
     # ``lane_skip=False`` is for mesh-sharded callers — see
@@ -1799,12 +1911,12 @@ def make_step_round(cfg: BatchedConfig, iids=None, slots=None,
     zero_b = jnp.zeros((n,), bool)
 
     def step(st, inbox, tick_mask, campaign_mask, propose_n, isolate,
-             transfer_to=None, read_req=None):
+             transfer_to=None, read_req=None, lane_any=None):
         return inner(st, inbox, tick_mask, campaign_mask, propose_n,
                      isolate,
                      zero_i if transfer_to is None else transfer_to,
                      zero_b if read_req is None else read_req,
-                     iids, slots)
+                     iids, slots, lane_any)
 
     return step
 

@@ -117,6 +117,13 @@ os.environ.setdefault("ETCD_TPU_TRANSFER_GUARD", "disallow")
 # left in deliver had no direct test). test_deliver_shapes' hosted
 # narrow-lanes rawnode (aux=True, ISSUE 14) stays. Budget 48 -> 46
 # keeps the headroom of 2.
+# ISSUE 31 AUDIT: still 44 of 46. test_route's new cases build engines
+# on values that are keys already: its own cfg_of(4, 3) pair, the
+# n-minor one again for the scan's jaxpr, test_scan_faults' CELL and
+# test_differential_wide.make_pair(2, 10, auto_compact=True) for the
+# election schedule. route() by lane is keyed by R alone, like route();
+# the inbox handed to the round as six lanes is a second trace of the
+# same `jit(step_round)`, inside the scan, and no key.
 ROUND_STEP_SHAPE_BUDGET = 46
 
 

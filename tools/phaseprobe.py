@@ -101,16 +101,17 @@ def main() -> int:
     # lanes_minor transpose belongs to the fused round, measured via
     # the full-round reference below).
     phase_fns = {
-        # deliver takes the batch-level lane-occupancy vector exactly
-        # as the production round does (computed outside the vmap →
-        # the lane skips stay real branches).
+        # deliver takes the inbox by kind lane and the batch-level
+        # lane-occupancy vector exactly as the production round does
+        # (computed outside the vmap → the lane skips stay real
+        # branches).
         "deliver": (
             jax.jit(lambda _iids, _slots, _st, _inbox: jax.vmap(
                 lambda iid, slot, sti, inb, la:
                 step_mod._deliver_vectorized(
                     cfg, iid, slot, sti, inb, la),
                 in_axes=(0, 0, 0, 0, None))(
-                _iids, _slots, _st, _inbox,
+                _iids, _slots, _st, step_mod.split_lanes(_inbox),
                 jnp.any(_inbox.valid, axis=(0, 1)))),
             (iids, slots, st, inbox)),
         "tick": (
