@@ -5,9 +5,15 @@ the full multi-group SoA state on device, exposes the same logical
 contract as ``raft.RawNode`` (tick / campaign / propose / step / ready
 watermarks / advance) but batched over every group at once, and runs
 closed-loop rounds entirely on device (deliver → tick → propose → emit →
-route), faults included: a scan takes a per-round schedule of nodes cut
-off the network (``run_rounds(isolate=...)``), so an outage begins and
-heals inside one program. Inside a scan the network moves only what
+route), faults and the control plane included: a scan takes a per-round
+schedule of nodes cut off the network (``run_rounds(isolate=...)``) and,
+beside it, one of what the control plane asks (``control=...``: which
+node hands its leaderships to which, which configuration change is on
+offer, whether reads are asked), so an outage begins and heals, a
+leadership moves, a ReadIndex batch opens and confirms, and a
+configuration change is appended and applied by each replica at its own
+apply point (``BatchedConfig.conf_entries``) inside one program. Inside
+a scan the network moves only what
 was sent: the inbox rides as its six kind lanes and a round exchanges
 the lanes some instance of the batch wrote (``step.route_lanes``, on the
 occupancy vector deliver's lane conds skip on); a lane nobody wrote
@@ -24,7 +30,7 @@ from __future__ import annotations
 
 import itertools
 from collections import deque
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -43,13 +49,76 @@ from .step import (MsgSlots, NUM_KINDS, empty_msgs, lane_occupancy,
                    stack_lanes)
 
 
+# Columns of a scan's control schedule, int32 [rounds, CTL_COLS], one
+# row a round (``run_rounds(control=...)``). A row asks every leader on
+# node CTL_FROM - 1 (slot s of every group is node s) to hand
+# leadership to slot CTL_TO - 1 (0: no transfer), offers every other
+# instance the configuration change CTL_CONF (a ``state.conf_code``; 0
+# none; the node that is asked to hand over is being drained and is not
+# offered it), asks every instance for a read where CTL_READS is not 0,
+# and, where CTL_STALL is not 0, says that no replica in a joint
+# configuration may advance its commit in this round (``scan_watch``
+# counts those that do).
+CTL_FROM, CTL_TO, CTL_CONF, CTL_READS, CTL_STALL = range(5)
+CTL_COLS = 5
+
+# What a scan with a control schedule counts in its carry
+# (``MultiRaftEngine.scan_watch``), in this order.
+WATCH_NAMES = (
+    "joint_instance_rounds",   # instance-rounds in a joint configuration
+    "read_open_instance_rounds",  # instance-rounds with a read batch open
+    "reads_below_commit",      # batches confirmed with an index below the
+    # highest commit a replica of the group held before the batch opened
+    "joint_commits_in_stall",  # commit advances in a joint configuration
+    # in a round the schedule marks CTL_STALL
+    "conf_marks_lost",         # an unapplied change's mark overwritten
+)
+# A count is two int32 limbs, low 24 bits and the rest: a round adds at
+# most N to one, and N rounds x instances passes 2^31 inside a run.
+_LIMB = 24
+
+
+# The fields a controlled scan folds into each instance's history,
+# after every round, in this order (the membership masks as bits, slot
+# s worth 1 << s): `history_fold` below is the rule, in Python
+# integers, for whoever steps a reference through the same rounds.
+HISTORY_FIELDS = ("term", "role", "lead", "commit", "last", "read_seq",
+                  "read_index", "read_ready", "in_joint", "voter",
+                  "voter_out", "learner")
+_FNV = 16777619
+
+
+def history_fold(h: int, values) -> int:
+    """One round folded into a history: `values` are HISTORY_FIELDS'
+    of the instance after the round (FNV-1a over 32-bit words)."""
+    for v in values:
+        h = ((h ^ (int(v) & 0xFFFFFFFF)) * _FNV) & 0xFFFFFFFF
+    return h
+
+
+class ScanWatch(NamedTuple):
+    counts: jnp.ndarray  # [len(WATCH_NAMES), 2] i32 (high, low limb)
+    # Per instance: the highest commit any replica of its group held at
+    # the end of the round before its open read batch opened.
+    read_floor: jnp.ndarray  # [N] i32
+    # Per instance: a hash of its state after every round of every
+    # controlled scan so far. Two runs agree on it only if they agreed
+    # round by round, which the state after the last round cannot say
+    # (a commit that ran ahead through an outage has been caught up
+    # with by then).
+    history: jnp.ndarray  # [N] u32
+
+
 class MultiRaftEngine:
     """Host calls are spans of the round-span recorder (obs/spans.py):
     ``engine.init``, ``engine.step_round`` and ``engine.run_rounds``
     (one a scan, so one a chunk of ``run_rounds_pipelined``), with
     member 0, the call's number as ``round`` and the engine's serial,
     the scan's ``rounds`` and ``isolated`` (rounds x nodes its fault
-    schedule cut off; 0 with none) as stats. A span ends when the
+    schedule cut off; 0 with none) and, of its control schedule,
+    ``reads`` (rounds x instances asked), ``conf_ops`` and ``transfers``
+    (rows that offer a change, ask for a hand-over; 0 with none) as
+    stats. A span ends when the
     program is enqueued: the host's share of a call, not the device's."""
 
     def __init__(self, cfg: BatchedConfig, start_index: int = 0):
@@ -78,14 +147,17 @@ class MultiRaftEngine:
         )
         self._step = make_step_round(cfg)
 
-        def step_round(st, inbox, *masks):
+        def step_round(st, inbox, *masks, conf_req=None):
             # The eager round hands the round program what the scan
             # hands it, lanes and their occupancy, so the two share
             # one trace of it (a cold start traces the round once,
             # not twice). `_step` is read when this is first traced.
+            # `conf_req` is given for a configuration with
+            # conf_entries alone; None is no input.
             lanes = split_lanes(inbox)
             return self._step(st, lanes, *masks,
-                              lane_any=lane_occupancy(lanes))
+                              lane_any=lane_occupancy(lanes),
+                              conf_req=conf_req)
 
         self._round = jax.jit(step_round)
         n = cfg.num_instances
@@ -94,6 +166,9 @@ class MultiRaftEngine:
         # Scan rounds in which each kind lane held a message for any
         # instance (lane_rounds()): carried through the closed loop.
         self._lanes = jnp.zeros((NUM_KINDS,), I32)
+        # What the scans with a control schedule counted (scan_watch()):
+        # made by the first of them, carried by every one after.
+        self._watch: Optional[ScanWatch] = None
         # In-device telemetry accumulator (cfg.telemetry): per-instance
         # counter totals + OR-folded invariant bitmaps, accumulated
         # inside the closed-loop scan with no per-round host sync.
@@ -137,19 +212,23 @@ class MultiRaftEngine:
         self.fleet_hub = None
 
         def closed_loop(st, inbox, ticks, props, tel, flt, lanes, isolate,
-                        rounds):
+                        rounds, control=None, watch=None):
             # `isolate` is None (no fault: the scan is traced as it
             # always was) or the bool [rounds, R] node schedule, one
-            # row a round as the scan's xs.
+            # row a round as the scan's xs; `control` is None (the
+            # same) or the int32 [rounds, CTL_COLS] control schedule,
+            # beside it, and `watch` the ScanWatch that rides the carry
+            # with it.
             # jitlint: waive(tracer-branch) -- None is an empty pytree: the branch is on the argument's structure at trace time, never on a device value
-            if isolate is not None:
+            if isolate is not None or control is not None:
                 slots = jnp.arange(n, dtype=I32) % cfg.num_replicas
 
-            def body(carry, cut):
+            def body(carry, row):
                 # `occ` is the inbox's lane occupancy, [K] bool: what
                 # deliver's lane conds skip on and route_lanes' are
                 # told was there.
-                st, inbox, occ, tel, flt, lanes = carry
+                st, inbox, occ, tel, flt, lanes, watch = carry
+                cut, ctl = row
                 lanes = lanes + occ
                 iso = self._zeros_b
                 # jitlint: waive(tracer-branch) -- as above: a scan without xs hands its body None
@@ -158,11 +237,25 @@ class MultiRaftEngine:
                     # slot s of every group.
                     for s in range(cfg.num_replicas):
                         iso = iso | ((slots == s) & cut[s])
+                transfer, reads, conf = self._zeros_i, self._zeros_b, None
+                # jitlint: waive(tracer-branch) -- as above
+                if ctl is not None:
+                    # The row's few scalars widened the same way.
+                    drained = slots == ctl[CTL_FROM] - 1
+                    transfer = jnp.where(drained, ctl[CTL_TO], 0)
+                    reads = jnp.broadcast_to(ctl[CTL_READS] != 0, (n,))
+                    if cfg.conf_entries:
+                        conf = jnp.where(drained, 0, ctl[CTL_CONF])
+                    pre = st
                 out = self._step(
                     st, inbox, ticks, self._zeros_b, props, iso,
-                    self._zeros_i, self._zeros_b, lane_any=occ,
+                    transfer, reads, lane_any=occ, conf_req=conf,
                 )
                 st, outbox = out[:2]
+                # jitlint: waive(tracer-branch) -- as above
+                if ctl is not None:
+                    watch = self._watch_round(
+                        watch, pre, st, slots, ctl[CTL_STALL] != 0)
                 if cfg.telemetry:
                     fr = out[self._tel_pos]
                     tel = (tel[0] + fr.counters, tel[1] | fr.invariants)
@@ -176,7 +269,7 @@ class MultiRaftEngine:
                 # occupancy is the next inbox's.
                 sent = jnp.any(outbox.valid, axis=(0, 1))
                 inbox = route_lanes(cfg, outbox, sent, (inbox, occ))
-                return (st, inbox, sent, tel, flt, lanes), None
+                return (st, inbox, sent, tel, flt, lanes, watch), None
 
             # The inbox rides the scan as its K kind lanes, each an
             # array of its own, and is stacked back once at the exit.
@@ -191,15 +284,15 @@ class MultiRaftEngine:
                     lambda x, _k=k: jnp.where(occ[_k], x, jnp.zeros_like(x)),
                     inbox[k])
                 for k in range(NUM_KINDS))
-            (st, inbox, _, tel, flt, lanes), _ = jax.lax.scan(
-                body, (st, inbox, occ, tel, flt, lanes), isolate,
-                length=rounds
+            (st, inbox, _, tel, flt, lanes, watch), _ = jax.lax.scan(
+                body, (st, inbox, occ, tel, flt, lanes, watch),
+                (isolate, control), length=rounds
             )
             inbox = stack_lanes(inbox)
             # The scalar fence is a SEPARATE output buffer: pipelined
             # callers block on it to bound queue depth without holding
             # (and thereby breaking) a donated state buffer.
-            return st, inbox, tel, flt, lanes, st.commit[0]
+            return st, inbox, tel, flt, lanes, st.commit[0], watch
 
         # State and inbox are donated: run_rounds/run_rounds_pipelined
         # reassign both from the return value, so XLA writes round k+1
@@ -221,6 +314,62 @@ class MultiRaftEngine:
         # warm key would put a new engine's compile inside the guard).
         self._wkey_step = f"round_step/{hash((cfg, False, n))}"
 
+    def _watch_round(self, watch: ScanWatch, pre, st, slots,
+                     stall) -> ScanWatch:
+        """One round of a controlled scan counted into its ScanWatch:
+        `pre` and `st` are the state before and after the round, `stall`
+        the row's CTL_STALL. Runs inside the scan's body, outside the
+        instance vmap, on whole [N] fields."""
+        r = self.cfg.num_replicas
+
+        def group_max(x):
+            # The maximum over the R adjacent rows of each row's group,
+            # as row shifts under the slot mask (route()'s idiom: N is
+            # never split).
+            out = x
+            for k in range(1, r):
+                pad = jnp.zeros((k,), x.dtype)
+                up = jnp.concatenate([x[k:], pad])      # row n + k
+                down = jnp.concatenate([pad, x[:-k]])   # row n - k
+                out = jnp.maximum(out, jnp.where(slots + k < r, up, out))
+                out = jnp.maximum(out, jnp.where(slots - k >= 0, down, out))
+            return out
+
+        # A batch open as the round began is confirmed in it if the
+        # round ends with it ready or with the next one open (the
+        # control phase reopens in the round whose deliver confirmed).
+        open_before = (pre.read_index >= 0) & ~pre.read_ready
+        reopened = st.read_seq != pre.read_seq
+        confirmed = open_before & (reopened | st.read_ready)
+        below = confirmed & (pre.read_index < watch.read_floor)
+        floor = jnp.where(reopened, group_max(pre.commit), watch.read_floor)
+        advanced = st.commit > pre.commit
+        # Conservative: a conflict that truncates the mark and an
+        # append that brings another in one round counts too.
+        lost = ((pre.conf.index > pre.applied)
+                & (st.conf.index != pre.conf.index) & (st.conf.index != 0)
+                ) if self.cfg.conf_entries else self._zeros_b
+        events = [
+            st.in_joint,
+            (st.read_index >= 0) & ~st.read_ready,
+            below,
+            stall & advanced & (pre.in_joint | st.in_joint),
+            lost,
+        ]
+        add = jnp.stack([jnp.sum(e.astype(I32)) for e in events])
+        low = watch.counts[:, 1] + add
+        counts = jnp.stack(
+            [watch.counts[:, 0] + (low >> _LIMB), low & ((1 << _LIMB) - 1)],
+            axis=1)
+        bits = (1 << jnp.arange(r, dtype=I32))[None, :]
+        history = watch.history
+        for name in HISTORY_FIELDS:
+            v = getattr(st, name)
+            if v.ndim == 2:
+                v = jnp.sum(jnp.where(v, bits, 0), axis=1)
+            history = (history ^ v.astype(jnp.uint32)) * jnp.uint32(_FNV)
+        return ScanWatch(counts, floor, history)
+
     # -- driving --------------------------------------------------------------
 
     def step_round(
@@ -231,10 +380,13 @@ class MultiRaftEngine:
         isolate: Optional[jnp.ndarray] = None,
         transfer_to: Optional[jnp.ndarray] = None,
         read_req: Optional[jnp.ndarray] = None,
+        conf_req: Optional[jnp.ndarray] = None,
     ) -> None:
         """One round: deliver pending messages, optionally tick every
-        instance, run host control ops (leader transfer, ReadIndex),
-        append proposals on leaders, route the outbox. `isolate` cuts
+        instance, run host control ops (leader transfer, ReadIndex and,
+        for a configuration with ``conf_entries``, the configuration
+        change on offer, `conf_req`: [N] ``state.conf_code``), append
+        proposals on leaders, route the outbox. `isolate` cuts
         instances off the network for this round."""
         ticks = (
             jnp.ones_like(self._zeros_b) if tick else self._zeros_b
@@ -244,13 +396,20 @@ class MultiRaftEngine:
         iso = isolate if isolate is not None else self._zeros_b
         transfer = transfer_to if transfer_to is not None else self._zeros_i
         reads = read_req if read_req is not None else self._zeros_b
+        if self.cfg.conf_entries:
+            conf = conf_req if conf_req is not None else self._zeros_i
+        elif conf_req is not None:
+            raise ValueError(
+                "conf_req needs a configuration with conf_entries")
+        else:
+            conf = None
         # Inside the guard the dispatch must be all-device: any implicit
         # transfer (an eager scalar op, a stray host array) is a hard
         # error when ETCD_TPU_TRANSFER_GUARD=disallow (tests, benches).
         with self._span("engine.step_round"), warm_guard(self._wkey_step):
             out = self._round(
                 self.state, self.inbox, ticks, camp, props, iso,
-                transfer, reads,
+                transfer, reads, conf_req=conf,
             )
             self.state, outbox = out[:2]
             if self.cfg.telemetry:
@@ -294,43 +453,82 @@ class MultiRaftEngine:
                 f"{(rounds, self.cfg.num_replicas)}, got {sched.shape}")
         return jnp.asarray(sched), int(sched.sum())
 
-    def _scan(self, rounds: int, ticks, props, isolate):
+    def _control_schedule(self, control, rounds: int):
+        """(device schedule or None, span stats) of a call's control
+        plane."""
+        if control is None:
+            return None, {"reads": 0, "conf_ops": 0, "transfers": 0}
+        ctl = np.asarray(control)
+        if ctl.shape != (rounds, CTL_COLS) or ctl.dtype.kind not in "iu":
+            raise ValueError(
+                f"control must be integers [rounds, CTL_COLS] = "
+                f"{(rounds, CTL_COLS)}, got {ctl.dtype} {ctl.shape}")
+        if ctl[:, CTL_CONF].any() and not self.cfg.conf_entries:
+            raise ValueError(
+                "the control schedule offers a configuration change: "
+                "that needs a configuration with conf_entries")
+        stats = {
+            "reads": int((ctl[:, CTL_READS] != 0).sum())
+            * self.cfg.num_instances,
+            "conf_ops": int((ctl[:, CTL_CONF] != 0).sum()),
+            "transfers": int(((ctl[:, CTL_FROM] != 0)
+                              & (ctl[:, CTL_TO] != 0)).sum()),
+        }
+        if self._watch is None:
+            self._watch = ScanWatch(
+                jnp.zeros((len(WATCH_NAMES), 2), I32),
+                jnp.zeros((self.cfg.num_instances,), I32),
+                jnp.zeros((self.cfg.num_instances,), jnp.uint32))
+        return jnp.asarray(ctl, I32), stats
+
+    def _scan(self, rounds: int, ticks, props, isolate, control=None):
         """One closed-loop scan enqueued; returns its scalar fence."""
         sched, isolated = self._schedule(isolate, rounds)
+        ctl, asked = self._control_schedule(control, rounds)
         # `rounds` is a static arg: each new value compiles a new scan
-        # program (and so does the first call with a schedule), so
-        # warmth (and thus the transfer guard) is per value.
+        # program (and so does the first call with a schedule of either
+        # kind), so warmth (and thus the transfer guard) is per value.
         key = f"closed_loop/{self._serial}/{rounds}" + (
-            "" if sched is None else "/isolate")
+            "" if sched is None else "/isolate") + (
+            "" if ctl is None else "/control")
         with self._span("engine.run_rounds", rounds=rounds,
-                        isolated=isolated), warm_guard(key):
-            self.state, self.inbox, tel, flt, lanes, fence = self._closed_loop(
+                        isolated=isolated, **asked), warm_guard(key):
+            watch = None if ctl is None else self._watch
+            self.state, self.inbox, tel, flt, lanes, fence, watch = self._closed_loop(
                 self.state, self.inbox, ticks, props, self._tel(),
-                self._flt(), self._lanes, sched, rounds
+                self._flt(), self._lanes, sched, rounds, ctl, watch
             )
         self._lanes = lanes
         self._set_tel(tel)
         self._set_flt(flt)
+        if ctl is not None:
+            self._watch = watch
         return fence
 
     def run_rounds(self, rounds: int, tick: bool = True,
                    propose_n: Optional[jnp.ndarray] = None,
-                   isolate=None) -> None:
-        """Closed-loop simulation of `rounds` rounds, faults included,
-        without leaving the device (one fused lax.scan program).
+                   isolate=None, control=None) -> None:
+        """Closed-loop simulation of `rounds` rounds, faults and the
+        control plane included, without leaving the device (one fused
+        lax.scan program).
         `isolate`, bool [rounds, R], cuts node s (slot s of every
         group) off the network in round t where ``isolate[t, s]``: it
         neither receives nor sends, and keeps ticking — what
         ``step_round(isolate=...)`` does to single instances, as the
-        scan's per-round input."""
+        scan's per-round input. `control`, int [rounds, CTL_COLS], is
+        what the control plane asks in round t (the CTL_* columns at
+        the top of this module): the ``transfer_to``, ``read_req`` and
+        ``conf_req`` of ``step_round``, a few scalars a round widened
+        to the instances on the device as `isolate` is. With neither,
+        the scan takes no per-round input."""
         ticks = jnp.ones_like(self._zeros_b) if tick else self._zeros_b
         props = propose_n if propose_n is not None else self._zeros_i
-        self._scan(rounds, ticks, props, isolate)
+        self._scan(rounds, ticks, props, isolate, control)
 
     def run_rounds_pipelined(self, rounds: int, chunk: int = 16,
                              depth: int = 2, tick: bool = True,
                              propose_n: Optional[jnp.ndarray] = None,
-                             isolate=None) -> None:
+                             isolate=None, control=None) -> None:
         """Double-buffered round pipelining: split `rounds` into scan
         chunks and keep up to `depth` chunks in flight — chunk k+1 is
         enqueued while chunk k's scan executes, and because the state
@@ -342,7 +540,8 @@ class MultiRaftEngine:
         output), never on donated state; the final chunk is left in
         flight — callers that need completion block on
         ``self.state.commit`` as usual. `isolate` is ``run_rounds``'
-        node schedule over all `rounds`; each chunk takes its rows."""
+        node schedule over all `rounds` and `control` its control
+        schedule; each chunk takes its rows."""
         if rounds <= 0:
             return
         if chunk <= 0:
@@ -357,7 +556,8 @@ class MultiRaftEngine:
             n = min(chunk, rounds - done)
             fences.append(self._scan(
                 n, ticks, props,
-                None if isolate is None else isolate[done:done + n]))
+                None if isolate is None else isolate[done:done + n],
+                None if control is None else control[done:done + n]))
             done += n
             while len(fences) > depth:
                 # jitlint: waive(sync-in-loop) -- the sync IS the pipelining contract: block on the per-chunk scalar fence to bound queue depth at `depth` without holding a donated buffer
@@ -392,9 +592,13 @@ class MultiRaftEngine:
     def set_membership(self, group: int, voters, voters_out=(),
                        learners=(), joint: bool = False) -> None:
         """Upload new membership masks for every replica row of `group`
-        — the confchange apply point (ref: confchange/confchange.go
+        — the confchange apply point of a configuration without
+        ``conf_entries`` (ref: confchange/confchange.go
         EnterJoint/LeaveJoint/Simple; the host Changer computes the
-        slot sets, the device only sees masks)."""
+        slot sets, the device only sees masks). With ``conf_entries``
+        a change is an entry and each replica applies it itself
+        (``step_round(conf_req=...)``, ``run_rounds(control=...)``);
+        this upload is then for a test's starting point only."""
         r = self.cfg.num_replicas
         rows = jnp.arange(group * r, (group + 1) * r)
 
@@ -484,6 +688,24 @@ class MultiRaftEngine:
         the exchange that ran). Accumulated in the scan's carry; one
         host gather, no per-round sync."""
         return np.asarray(self._lanes)
+
+    def scan_watch(self) -> dict:
+        """What the scans with a control schedule counted, by
+        WATCH_NAMES, since the engine was built (all zero before the
+        first such scan). One host gather; no per-round sync."""
+        if self._watch is None:
+            return dict.fromkeys(WATCH_NAMES, 0)
+        c = np.asarray(self._watch.counts).astype(np.int64)
+        return {name: int((c[i, 0] << _LIMB) + c[i, 1])
+                for i, name in enumerate(WATCH_NAMES)}
+
+    def scan_history(self) -> np.ndarray:
+        """[N] uint32: each instance's state after every round of every
+        controlled scan, hashed (``history_fold`` over HISTORY_FIELDS,
+        from 0). All zero before the first such scan."""
+        if self._watch is None:
+            return np.zeros((self.cfg.num_instances,), np.uint32)
+        return np.asarray(self._watch.history)
 
     def commits(self) -> np.ndarray:
         """Per-instance commit watermarks [G, R] — the host applies

@@ -18,7 +18,18 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 from ..raft import Config, MemoryStorage, RawNode
 from ..raft.errors import RaftError
-from ..raft.types import ConfState, Message, MessageType
+from ..raft.tracker import ProgressStateType
+from ..raft.types import (
+    ConfChangeSingle,
+    ConfChangeTransition,
+    ConfChangeType,
+    ConfChangeV2,
+    ConfState,
+    EntryType,
+    Message,
+    MessageType,
+)
+from .state import CONF_DEMOTE, CONF_LEAVE, CONF_PROMOTE
 from .step import (
     KIND_APP,
     KIND_APP_RESP,
@@ -57,8 +68,9 @@ def _same_message(a: Message, b: Message) -> bool:
 def _merge_apps(a: Message, b: Message) -> Optional[Message]:
     """Coalesce two same-round MsgApps to one target the way the
     device's single send flag does (one append per peer per round
-    carrying the union): contiguous, same-term appends merge; anything
-    else is a real envelope violation (returns None).
+    carrying the union): contiguous, same-term appends merge, of two
+    with a gap between them the probe stays; anything else is a real
+    envelope violation (returns None).
 
     The oracle legitimately emits two — commit-advance bcastAppend plus
     the proposal bcastAppend in the same Ready (raft.go maybeCommit →
@@ -71,7 +83,15 @@ def _merge_apps(a: Message, b: Message) -> Optional[Message]:
     end1 = first.index + len(first.entries)
     end2 = second.index + len(second.entries)
     if end1 < second.index:
-        return None  # gap — not one logical send
+        # A gap: the later-indexed one was sent ahead in REPLICATE and a
+        # rejection handled after it took `next` back (a commit
+        # broadcast, then the healed peer's reject, in one deliver).
+        # The device holds one send flag a peer and slices at emit from
+        # the `next` it has then, so only the probe leaves.
+        return Message(
+            type=MessageType.MsgApp, to=first.to, from_=first.from_,
+            term=first.term, log_term=first.log_term, index=first.index,
+            entries=list(first.entries), commit=max(a.commit, b.commit))
     if end1 >= end2:
         # first covers second entirely (re-materialized sends overlap)
         return Message(
@@ -104,6 +124,30 @@ class DeviceHashRand:
         out = ((self.iid + 1) * 7919 + self.n * 104729) % et
         self.n += 1
         return out
+
+
+def conf_change(code: int) -> ConfChangeV2:
+    """The ConfChangeV2 a device ``state.conf_code`` stands for."""
+    kind, node = code & 3, (code >> 2) + 1
+    if kind == CONF_LEAVE:
+        return ConfChangeV2()
+    how = {CONF_DEMOTE: ConfChangeType.ConfChangeAddLearnerNode,
+           CONF_PROMOTE: ConfChangeType.ConfChangeAddNode}[kind]
+    return ConfChangeV2(
+        transition=ConfChangeTransition.ConfChangeTransitionJointExplicit,
+        changes=[ConfChangeSingle(type=how, node_id=node)])
+
+
+class _ReadView:
+    """What the device's read lanes hold of one replica (read_seq,
+    read_index, read_ready): one batch at a time, a request that finds
+    one in flight waits for it (step._control)."""
+
+    def __init__(self) -> None:
+        self.seq, self.index, self.ready = 0, -1, False
+
+    def reset(self) -> None:
+        self.index, self.ready = -1, False
 
 
 class ShadowCluster:
@@ -169,8 +213,27 @@ class ShadowCluster:
         # Device per-round proposal cap P: with the ring's W it bounds
         # what a leader admits of an `offer` (see `_admitted`).
         self.max_props = max_props
+        # The control plane's view of each replica (round(reads=...,
+        # conf=...)): its one read batch, which dies with raft.reset()
+        # as the device's does, and how far it has applied the
+        # configuration changes of its log.
+        self.reads = [_ReadView() for _ in self.nodes]
+        self.conf_applied_to = [0] * num_replicas
+        self.conf_applied = [0] * num_replicas  # changes applied, counted
+        for node, view in zip(self.nodes, self.reads):
+            self._reset_kills_the_read(node.raft, view)
         # inbox[target][sender][kind]
         self.inbox: List[List[List[Optional[Message]]]] = self._empty_inbox()
+
+    @staticmethod
+    def _reset_kills_the_read(r, view: "_ReadView") -> None:
+        reset = r.reset
+
+        def wrapped(term):
+            view.reset()
+            reset(term)
+
+        r.reset = wrapped
 
     def _empty_inbox(self):
         return [
@@ -186,6 +249,10 @@ class ShadowCluster:
         transfers: Optional[Dict[int, int]] = None,
         drop_pairs: Iterable[Tuple[int, int]] = (),
         offer: int = 0,
+        reads: bool = False,
+        conf=0,
+        drained: Optional[int] = None,
+        transfer_to: Optional[int] = None,
     ) -> None:
         """One round with the device's phase order:
         deliver → tick/campaign → control → propose → emit.
@@ -193,7 +260,13 @@ class ShadowCluster:
         (sender, target) directed edges at emit — partial partitions.
         `offer` hands that many proposals to every replica, as the
         closed loop does: whoever leads when the propose phase is
-        reached takes them (`_admitted`)."""
+        reached takes them (`_admitted`). `reads`, `conf`, `drained`
+        and `transfer_to` are a row of the engine's control schedule
+        (engine.CTL_*): a read asked of every replica, the
+        configuration change on offer (a ``state.conf_code``) to every
+        replica but those of node `drained`, which is asked to hand
+        its leaderships to slot `transfer_to` instead (`conf` as a
+        dict offers slot -> code, as ``step_round(conf_req=...)`` can)."""
         iso = set(isolate)
         proposals = dict(proposals or {})
         if self.max_ents is not None:
@@ -242,12 +315,23 @@ class ShadowCluster:
             self.nodes[slot].campaign()
 
         # Phase 2b: host control ops, same slot order as the device's
-        # _control phase (after tick, before propose).
+        # _control phase (after tick, before propose): the apply point
+        # of a configuration change, transfers, reads, the change on
+        # offer.
+        for slot in range(self.r):
+            self._apply_conf_changes(slot)
+        if drained is not None and transfer_to is not None:
+            transfers = dict(transfers)
+            transfers[drained] = transfer_to
         for slot, target in transfers.items():
-            try:
+            if self._leads(slot):  # a follower's forward has no lane
                 self.nodes[slot].transfer_leader(target + 1)
-            except RaftError:
-                pass
+        for slot in range(self.r):
+            self._control_read(slot, reads)
+            code = (conf.get(slot, 0) if isinstance(conf, dict)
+                    else 0 if slot == drained else conf)
+            if code:
+                self._offer_conf(slot, code)
 
         # Phase 3: proposals (empty payloads; the batched engine carries
         # payloads in the host arena, so terms are the shared content).
@@ -353,6 +437,13 @@ class ShadowCluster:
                                    key=lambda x: x.snapshot.metadata.index)
                         self.inbox[target][slot][kind] = best
                         continue
+                    # MsgTimeoutNow shares the heartbeat lane and
+                    # supersedes that peer's heartbeat (step._emit).
+                    if kinds == {MessageType.MsgHeartbeat,
+                                 MessageType.MsgTimeoutNow}:
+                        if m.type == MessageType.MsgTimeoutNow:
+                            self.inbox[target][slot][kind] = m
+                        continue
                     raise AssertionError(
                         f"slot collision: {m.type} from {slot} to {target}; "
                         "schedule outside the differential envelope"
@@ -361,6 +452,75 @@ class ShadowCluster:
         for slot, rd in readys:
             self.nodes[slot].advance(rd)
 
+
+    # -- the control phase (device: step._control) -----------------------------
+
+    def _leads(self, slot: int) -> bool:
+        from ..raft.raft import StateType
+
+        return self.nodes[slot].raft.state == StateType.StateLeader
+
+    def _apply_conf_changes(self, slot: int) -> None:
+        """The replica's own apply point (step._conf_apply): every
+        configuration change among the entries its commit has reached
+        since it last looked, through RawNode.apply_conf_change."""
+        node = self.nodes[slot]
+        log = node.raft.raft_log
+        lo, hi = self.conf_applied_to[slot], log.committed
+        if hi <= lo:
+            return
+        # Where this is called for the first time the log begins at
+        # the bootstrap snapshot, which holds no change.
+        lo = max(lo, log.first_index() - 1)
+        for e in log.slice(lo + 1, hi + 1, 1 << 62):
+            if e.type == EntryType.EntryConfChangeV2:
+                node.apply_conf_change(ConfChangeV2.unmarshal(e.data))
+                self.conf_applied[slot] += 1
+        self.conf_applied_to[slot] = hi
+
+    def _control_read(self, slot: int, asked: bool) -> None:
+        """step._control's ReadIndex rule on plain RawNode.read_index:
+        a leader that has committed in its term opens a batch at its
+        commit index when none is in flight; the ReadState raft yields
+        for its context confirms it."""
+        node, view = self.nodes[slot], self.reads[slot]
+        r = node.raft
+        ctx = str(view.seq).encode()
+        if any(rs.request_ctx == ctx for rs in r.read_states):
+            view.ready = True
+        pending = view.index >= 0 and not view.ready
+        if not (asked and self._leads(slot) and not pending
+                and r.committed_entry_in_current_term()):
+            return
+        view.seq += 1
+        view.index, view.ready = r.raft_log.committed, False
+        node.read_index(str(view.seq).encode())
+        if any(rs.request_ctx == str(view.seq).encode()
+               for rs in r.read_states):
+            view.ready = True  # a quorum of one
+
+    def _offer_conf(self, slot: int, code: int) -> None:
+        """step._conf_propose's rule, then RawNode.propose_conf_change."""
+        node = self.nodes[slot]
+        r = node.raft
+        cfg = r.prs.config
+        kind, who = code & 3, (code >> 2) + 1
+        joint = bool(cfg.voters.outgoing)
+        if kind == CONF_LEAVE:
+            fits = joint
+        elif kind == CONF_DEMOTE:
+            fits = not joint and who in cfg.voters.incoming
+        else:
+            fits = not joint and who in cfg.learners
+        held = r.raft_log.last_index() - (
+            r.raft_log.storage.first_index() - 1)
+        room = (self.auto_compact_window - held - self.max_props
+                if self.auto_compact_window else 1)
+        if (self._leads(slot) and not r.lead_transferee
+                and r.id in r.prs.progress and fits
+                and r.pending_conf_index <= r.raft_log.applied
+                and room > 0):
+            node.propose_conf_change(conf_change(code))
 
     def _one_lane_a_round(self, r) -> None:
         """The device carries one append of at most E entries to a peer
@@ -379,6 +539,14 @@ class ShadowCluster:
         def maybe_send_append(to, send_if_empty):
             room[0] = self.max_ents - r.lane_sent.get(to, 0)
             if room[0] <= 0:
+                # The lane's one append has left already. Where it was
+                # a probe and a heartbeat response handled since has
+                # cleared `probe_sent` to send again, the device, whose
+                # one send leaves at emit, ends the round waiting on
+                # that probe.
+                pr = r.prs.progress[to]
+                if pr.state == ProgressStateType.StateProbe:
+                    pr.probe_sent = True
                 return False
             sent = send(to, send_if_empty)
             if sent and r.msgs[-1].type == MessageType.MsgApp:
@@ -489,6 +657,15 @@ class ShadowCluster:
                 type=MessageType.MsgSnap, to=m.to, from_=m.from_,
                 term=m.term, snapshot=snap,
             )
+        if m.type == MessageType.MsgHeartbeat:
+            # The device stamps a heartbeat where it emits it: the
+            # read batch then open (the control phase may have opened
+            # one since the tick queued this) and the commit then held.
+            return Message(
+                type=m.type, to=m.to, from_=m.from_, term=m.term,
+                commit=min(r.prs.progress[m.to].match,
+                           r.raft_log.committed),
+                context=r.read_only.last_pending_request_ctx())
         if m.type != MessageType.MsgApp:
             return m
         # Below the (just-advanced) floor the device sends a snapshot
@@ -534,6 +711,22 @@ class ShadowCluster:
                 )
             )
         return out
+
+    def membership(self) -> List[Tuple]:
+        """(voters, outgoing voters, learners, learners next) per
+        replica, as sorted tuples of slots: each replica's own view."""
+        out = []
+        for node in self.nodes:
+            c = node.raft.prs.config
+            out.append(tuple(
+                tuple(sorted(i - 1 for i in ids))
+                for ids in (c.voters.incoming, c.voters.outgoing,
+                            c.learners, c.learners_next)))
+        return out
+
+    def read_state(self) -> List[Tuple[int, int, bool]]:
+        """(read_seq, read_index, read_ready) per replica."""
+        return [(v.seq, v.index, v.ready) for v in self.reads]
 
     def log_terms(self, slot: int) -> List[Tuple[int, int]]:
         r = self.nodes[slot].raft

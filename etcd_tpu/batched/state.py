@@ -126,6 +126,15 @@ class BatchedConfig(NamedTuple):
     # serve a linearizable read locally (host-side routing threshold;
     # the lease lane itself is part of the round program regardless).
     lease_read_margin: int = 2
+    # Configuration changes as entries of the device's log (see
+    # ConfLanes): a leader appends a change offered to it in the
+    # control phase and every replica flips its own membership masks in
+    # the round in which it applies that entry, with no host upload.
+    # Static, default off, the contract of `telemetry`: off, the state
+    # the state is a BatchedState as ever (on, a ConfBatchedState), no
+    # message carries the marker, and the compiled round and closed
+    # loop are the programs they were.
+    conf_entries: bool = False
 
     @property
     def num_instances(self) -> int:
@@ -206,6 +215,46 @@ class BatchedConfig(NamedTuple):
         return self._replace(deliver_shape="vectorized")
 
 
+# A configuration change as the device holds it: one i32 code, the
+# kind in the low two bits and the replica slot it names above them.
+# Every kind is a ConfChangeV2 upstream: CONF_DEMOTE is
+# {JointExplicit, [AddLearnerNode slot]} (a voter leaves the incoming
+# half and waits in LearnersNext), CONF_PROMOTE is
+# {JointExplicit, [AddNode slot]}, CONF_LEAVE is the empty change that
+# leaves a joint configuration. 0 is no change.
+CONF_NONE, CONF_DEMOTE, CONF_LEAVE, CONF_PROMOTE = 0, 1, 2, 3
+
+
+def conf_code(kind: int, slot: int = 0) -> int:
+    return kind | (slot << 2)
+
+
+class ConfLanes(NamedTuple):
+    """What a replica knows of configuration changes in its log
+    (cfg.conf_entries). Entry types never reach the device, so the one
+    unapplied change an instance's log may hold is marked here: where
+    it is and what it says. A leader sets the mark when it appends the
+    change; a follower learns it with the append that carries the
+    entry (the APP lane's `reject_hint` and `ctx`, which an append
+    leaves unused) and forgets it if a conflict truncates the log
+    below it; each replica applies it, flipping its own masks, when
+    its commit reaches the index (step._control), and clears the mark.
+    One mark an instance: a second change reaches a follower only once
+    its leader has applied the first, a round after the follower heard
+    of that commit (the scan counts a mark overwritten unapplied)."""
+
+    index: jnp.ndarray  # [N] i32: index of the unapplied change; 0 none
+    op: jnp.ndarray  # [N] i32: its conf_code
+    # raft.pendingConfIndex (ref: raft.go:1043-1077, becomeLeader): a
+    # leader takes no change while this lies above `applied`. The
+    # index of the change it last appended, its last index as it won
+    # the term, 0 on every reset.
+    pending: jnp.ndarray  # [N] i32
+    # tracker.Config.LearnersNext: voters of the outgoing half that
+    # become learners when the joint configuration is left.
+    learner_next: jnp.ndarray  # [N, R] bool
+
+
 class BatchedState(NamedTuple):
     """Per-instance consensus state, all leading dim N = G*R."""
 
@@ -244,8 +293,10 @@ class BatchedState(NamedTuple):
 
     # Membership (ref: tracker.Config / quorum/joint.go): incoming
     # voters, outgoing voters (joint), learners. in_joint gates the
-    # second quorum half. Masks are uploaded by the host at the
-    # confchange apply point (SURVEY §2.1 "host-side control plane"):
+    # second quorum half. With cfg.conf_entries each replica flips its
+    # own masks when it applies the change (ConfLanes, step._control);
+    # otherwise masks are uploaded by the host at the confchange apply
+    # point (SURVEY §2.1 "host-side control plane"):
     # on the hosting path that is batched/membership.GroupConfStore —
     # committed EntryConfChangeV2 entries flip these lanes via one
     # bulk staged upload (rawnode.set_membership_many), enter-joint at
@@ -311,6 +362,16 @@ class BatchedState(NamedTuple):
     # every program, keeping on/off bit-identical); write-only w.r.t.
     # every protocol branch.
     lease_ticks: jnp.ndarray  # [N] i32
+
+
+# The state of a configuration with cfg.conf_entries: every field of
+# BatchedState and, last, `conf`, its ConfLanes. A type of its own, so
+# that a configuration that does not ask for the lanes carries, donates
+# and compiles exactly the fields it did, and whoever walks
+# BatchedState._fields meets arrays only.
+ConfBatchedState = NamedTuple(
+    "ConfBatchedState",
+    [*BatchedState.__annotations__.items(), ("conf", ConfLanes)])
 
 
 # Narrow storage dtype per hot lane (cfg.narrow_lanes). Values are
@@ -435,6 +496,10 @@ def init_state(cfg: BatchedConfig, start_index: int = 0,
         send_timeout_now=jnp.zeros((n,), bool),
         lease_ticks=zeros_n(),
     )
+    if cfg.conf_entries:
+        st = ConfBatchedState(*st, conf=ConfLanes(
+            index=zeros_n(), op=zeros_n(), pending=zeros_n(),
+            learner_next=jnp.zeros((n, r), bool)))
     if cfg.narrow_lanes:
         st = narrow_state(st)
     return st

@@ -11,7 +11,11 @@ handling around the heartbeat-response lane below), joint-config
 membership changes (per-instance voter/learner masks with joint
 commit/vote kernels), learners, and leader transfer all execute on
 device. The host uploads mask/config rows (set_membership) but does not
-step the protocol for any of these — see SURVEY.md §2.1.
+step the protocol for any of these — see SURVEY.md §2.1. With
+``BatchedConfig.conf_entries`` the masks are not uploaded either: a
+configuration change is an entry of the device's log, appended by the
+leader in the control phase and applied by each replica, itself, in
+the round its commit reaches it (_conf_propose, _conf_apply).
 
 Network model: per round each replica sends at most one message of each
 KIND to each peer, so an inbox is a dense ``[N, R, K]`` slot array and
@@ -55,6 +59,9 @@ from ..obs.fleet import FLEET_BUCKETS, FleetLayout
 from .telemetry import NUM_COUNTERS
 from .state import (
     CANDIDATE,
+    CONF_DEMOTE,
+    CONF_LEAVE,
+    CONF_PROMOTE,
     FOLLOWER,
     LEADER,
     PRECANDIDATE,
@@ -232,6 +239,11 @@ def _reset(cfg: BatchedConfig, st: BatchedState, iid, slot, term) -> BatchedStat
     changed = st.term != term
     rc = st.reset_count + 1
     peers = jnp.arange(r, dtype=I32)
+    if cfg.conf_entries:
+        # pendingConfIndex dies with the term or the role; what the log
+        # holds of a change (index, op) does not.
+        st = st._replace(conf=st.conf._replace(
+            pending=jnp.zeros_like(st.conf.pending)))
     return st._replace(
         term=term,
         vote=jnp.where(changed, 0, st.vote),
@@ -311,6 +323,10 @@ def _become_leader(cfg, st, iid, slot) -> BatchedState:
         lead=slot + 1,
         pr_state=jnp.where(peers == slot, REPLICATE, st.pr_state),
     )
+    if cfg.conf_entries:
+        # The tail may hold a change nobody has applied: no new one
+        # until all of it is (ref: raft.go becomeLeader).
+        st = st._replace(conf=st.conf._replace(pending=st.last))
     return _append_own(cfg, st, slot, jnp.asarray(1, I32))
 
 
@@ -528,6 +544,19 @@ def _handle_append(cfg: BatchedConfig, st: BatchedState, m: MsgSlots):
     lastnewi = prev + m.n_ents
     commit = jnp.maximum(st.commit, jnp.minimum(m.commit, lastnewi))
     st_ok = st._replace(log_term=log, last=last, commit=commit)
+    if cfg.conf_entries:
+        # The append says which of its entries is a configuration
+        # change (emit: reject_hint its index, ctx its code). A mark at
+        # or past the first entry a conflict rewrote names an entry
+        # that is gone.
+        c = st.conf
+        carried = m.reject_hint > 0
+        gone = any_conflict & (c.index >= prev + 1 + ci) & (
+            prev + 1 + ci <= st.last)
+        st_ok = st_ok._replace(conf=c._replace(
+            index=jnp.where(carried, m.reject_hint,
+                            jnp.where(gone, 0, c.index)),
+            op=jnp.where(carried, m.ctx, jnp.where(gone, 0, c.op))))
     resp_ok = no_resp._replace(
         valid=True, type=jnp.asarray(T_APP_RESP, I32), index=lastnewi,
         term=st.term,
@@ -555,8 +584,8 @@ def _handle_append(cfg: BatchedConfig, st: BatchedState, m: MsgSlots):
 
 def _handle_snapshot(cfg: BatchedConfig, st: BatchedState, m: MsgSlots):
     """Follower snapshot install (ref: raft.go:1518-1614 restore). The
-    conf state rides host-side; on device membership masks are already
-    current. m.index/m.log_term carry the snapshot (index, term)."""
+    conf state rides host-side; on device membership masks are taken
+    to be current. m.index/m.log_term carry the snapshot (index, term)."""
     no_resp = empty_msgs((), cfg.max_ents_per_msg)
     ignore = m.index <= st.commit
     ta = lambda i: term_at(st.log_term, st.snap_index, st.snap_term, st.last, i)
@@ -570,6 +599,13 @@ def _handle_snapshot(cfg: BatchedConfig, st: BatchedState, m: MsgSlots):
         last=m.index,
         commit=m.index,
     )
+    if cfg.conf_entries:
+        # A log replaced by a snapshot holds no entry to mark. (The
+        # ConfState a snapshot carries upstream does not travel yet:
+        # the masks stay as they are. ROADMAP.)
+        st_restore = st_restore._replace(conf=st.conf._replace(
+            index=jnp.zeros_like(st.conf.index),
+            op=jnp.zeros_like(st.conf.op)))
     restored = ~ignore & ~fast_forward
     st_out = _sel(ignore, st, _sel(fast_forward, st_ff, st_restore))
     resp = no_resp._replace(
@@ -1081,6 +1117,11 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
     # PR 31).
     send_heartbeat = st.send_heartbeat
     st = st._replace(send_heartbeat=jnp.zeros((0,), bool))
+    if cfg.conf_entries:
+        # LearnersNext too: only the control phase reads or writes it.
+        learner_next = st.conf.learner_next
+        st = st._replace(conf=st.conf._replace(
+            learner_next=jnp.zeros((0,), bool)))
     st, r0 = votes(st)
     st, r1 = request(KIND_APP, _lane_app, st)
     st, r2 = request(KIND_HB, _lane_hb, st)
@@ -1094,6 +1135,8 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
         KIND_HB_RESP,
         lambda s, m: _vec_lane_hb_resp(cfg, iid, slot, s, m), st)
     st = st._replace(send_heartbeat=send_heartbeat)
+    if cfg.conf_entries:
+        st = st._replace(conf=st.conf._replace(learner_next=learner_next))
     # [R] per request lane → [R, 3].
     req = jax.tree.map(
         lambda a, b, c: jnp.stack((a, b, c), axis=1), r0, r1, r2
@@ -1171,17 +1214,117 @@ def _tick(cfg: BatchedConfig, iid, slot, st: BatchedState, do_tick,
     return _sel(fire, st_camp, st1)
 
 
-def _control(cfg: BatchedConfig, slot, st: BatchedState, transfer_to,
-             read_req):
-    """Host control plane: leader-transfer requests and ReadIndex
-    rounds (ref: raft.go:1339-1372 stepLeader MsgTransferLeader;
-    raft.go:1078-1096 MsgReadIndex + read_only.go addRequest).
-
-    `transfer_to` is slot+1 (0 = none); `read_req` asks the leader to
-    open a read batch at its current commit index. Both are no-ops on
-    non-leaders (the host routes requests to the leader instance)."""
+def _conf_apply(cfg: BatchedConfig, slot, st: BatchedState):
+    """The apply point of a configuration change (cfg.conf_entries):
+    the replica whose commit has reached the change its log holds
+    unapplied flips its own masks, leader and follower alike, each in
+    its own round (ref: raft.go applyConfChange -> confchange.Changer
+    EnterJoint / LeaveJoint, then switchToConfig). Returns the state
+    and whether a change was applied."""
     r = cfg.num_replicas
     peers = jnp.arange(r, dtype=I32)
+    c = st.conf
+    due = (c.index > st.applied) & (st.commit >= c.index)
+    kind, at = c.op & 3, peers == (c.op >> 2)
+    demote, promote, leave = (
+        kind == CONF_DEMOTE, kind == CONF_PROMOTE, kind == CONF_LEAVE)
+    enter = demote | promote
+    # EnterJoint copies the voters to the outgoing half, then makes the
+    # one change: AddLearnerNode takes a voter out of the incoming half
+    # and, as the outgoing half still counts it, parks it in
+    # LearnersNext (a slot that was no voter is a learner at once);
+    # AddNode makes a voter of a learner. LeaveJoint turns LearnersNext
+    # into learners and drops the outgoing half.
+    st_new = st._replace(
+        voter=jnp.where(demote, st.voter & ~at,
+                        jnp.where(promote, st.voter | at, st.voter)),
+        voter_out=jnp.where(enter, st.voter, st.voter_out & ~leave),
+        learner=jnp.where(
+            demote, st.learner | (at & ~st.voter),
+            jnp.where(promote, st.learner & ~at,
+                      jnp.where(leave, st.learner | c.learner_next,
+                                st.learner))),
+        in_joint=(st.in_joint | enter) & ~leave,
+        conf=c._replace(learner_next=jnp.where(
+            demote, c.learner_next | (at & st.voter),
+            c.learner_next & ~(at & promote) & ~leave)),
+    )
+    # switchToConfig on a leader that is still a voter of the new
+    # configuration: the quorum may have shrunk, so commit and tell
+    # everyone, else send what a peer lacks (sendIfEmpty false); a
+    # transfer to a slot that is no voter any more is off. A leader
+    # demoted or removed stays as it is until it steps down.
+    at_self = peers == slot
+    electorate = _vote_targets(st_new)
+    leads_on = (st.role == LEADER) & _pick_b(electorate, at_self)
+    st_lead = _maybe_commit(st_new)
+    behind = _repl_targets(st_lead) & ~at_self & (st_lead.last >= st_lead.next)
+    keep_transfer = _pick_b(electorate, peers == st_lead.transferee - 1)
+    st_lead = st_lead._replace(
+        send_append=st_lead.send_append | jnp.where(
+            st_lead.commit > st.commit,
+            _repl_targets(st_lead) & ~at_self, behind),
+        transferee=jnp.where(keep_transfer, st_lead.transferee, 0),
+        transfer_sent=st_lead.transfer_sent & keep_transfer,
+    )
+    return _sel(due, _sel(leads_on, st_lead, st_new), st), due
+
+
+def _conf_propose(cfg: BatchedConfig, slot, st: BatchedState, conf_req):
+    """A leader takes the configuration change on offer (`conf_req`, a
+    state.conf_code; 0 none) as an entry of its log and marks it (ref:
+    raft.go:1043-1077 stepLeader MsgProp): not while an earlier one may
+    be unapplied, not with a transfer in flight, not into or out of a
+    joint configuration from the wrong side. Upstream turns a refused
+    change into an empty entry; here it is not appended at all, and a
+    change that would change nothing (demoting a slot that is no
+    voter, promoting one that is no learner) is not taken either: an
+    offer stands round after round until a leader has appended it, so
+    it has to be idempotent. The ring's back-pressure holds it as it
+    holds a proposal."""
+    r = cfg.num_replicas
+    peers = jnp.arange(r, dtype=I32)
+    c = st.conf
+    kind, at = conf_req & 3, peers == (conf_req >> 2)
+    fits = jnp.where(
+        kind == CONF_LEAVE, st.in_joint,
+        ~st.in_joint & (
+            ((kind == CONF_DEMOTE) & _pick_b(st.voter, at))
+            | ((kind == CONF_PROMOTE) & _pick_b(st.learner, at))))
+    room = cfg.window - (st.last - st.snap_index) - cfg.max_props_per_round
+    accept = (
+        (st.role == LEADER) & (st.transferee == 0)
+        & _pick_b(_repl_targets(st), peers == slot)
+        & (conf_req > 0) & fits & (c.pending <= st.applied) & (room > 0)
+    )
+    st2 = _append_own(cfg, st, slot, jnp.asarray(1, I32))
+    st2 = st2._replace(
+        conf=c._replace(index=st2.last, op=conf_req, pending=st2.last),
+        send_append=st2.send_append
+        | (_repl_targets(st2) & (peers != slot)),
+    )
+    return _sel(accept, st2, st)
+
+
+def _control(cfg: BatchedConfig, slot, st: BatchedState, transfer_to,
+             read_req, conf_req=None):
+    """Host control plane: leader-transfer requests and ReadIndex
+    rounds (ref: raft.go:1339-1372 stepLeader MsgTransferLeader;
+    raft.go:1078-1096 MsgReadIndex + read_only.go addRequest) and,
+    with cfg.conf_entries, configuration changes: first the apply
+    point of one this replica's commit has reached (_conf_apply), last
+    the one on offer (_conf_propose).
+
+    `transfer_to` is slot+1 (0 = none); `read_req` asks the leader to
+    open a read batch at its current commit index; `conf_req` is the
+    change offered. All are no-ops on non-leaders (the host routes
+    requests to the leader instance). Returns the state and, with
+    cfg.conf_entries, whether a change was applied (else None)."""
+    r = cfg.num_replicas
+    peers = jnp.arange(r, dtype=I32)
+    conf_applied = None
+    if cfg.conf_entries:
+        st, conf_applied = _conf_apply(cfg, slot, st)
     is_leader = st.role == LEADER
 
     # --- leader transfer -----------------------------------------------------
@@ -1236,7 +1379,10 @@ def _control(cfg: BatchedConfig, slot, st: BatchedState, transfer_to,
         | (_repl_targets(st) & (peers != slot)),
     )
     st = _sel(accept, st_rd, st)
-    return st._replace(read_req_latch=want & ~accept)
+    st = st._replace(read_req_latch=want & ~accept)
+    if cfg.conf_entries:
+        st = _conf_propose(cfg, slot, st, conf_req)
+    return st, conf_applied
 
 
 def _propose(cfg: BatchedConfig, slot, st: BatchedState, n_new):
@@ -1265,10 +1411,12 @@ def _propose(cfg: BatchedConfig, slot, st: BatchedState, n_new):
     return _sel(n > 0, st2, st)
 
 
-def _emit(cfg: BatchedConfig, slot, st: BatchedState):
+def _emit(cfg: BatchedConfig, slot, st: BatchedState, conf_applied=None):
     """Materialize pending sends into an outbox [R, K] and clear flags;
     auto-apply committed entries (device applies immediately; the host
-    drains (group, index) ranges for real payload apply)."""
+    drains (group, index) ranges for real payload apply).
+    `conf_applied` (cfg.conf_entries) is _control's word that this
+    round's apply point was taken."""
     e = cfg.max_ents_per_msg
     r = cfg.num_replicas
     peers = jnp.arange(r, dtype=I32)
@@ -1279,7 +1427,14 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState):
     # watermark), and with auto_compact the snapshot floor chases the
     # applied watermark so the ring never fills. Stale ring slots below
     # the floor need no clearing — term_at bounds exclude them.
-    st = st._replace(applied=jnp.maximum(st.applied, st.commit))
+    upto = st.commit
+    if cfg.conf_entries:
+        # `applied` stops short of a configuration change this replica
+        # has yet to apply (one committed in the round it was appended,
+        # under a quorum of one): the control phase takes it next round.
+        waits = (st.conf.index > st.applied) & ~conf_applied
+        upto = jnp.where(waits, jnp.minimum(upto, st.conf.index - 1), upto)
+    st = st._replace(applied=jnp.maximum(st.applied, upto))
     if cfg.auto_compact:
         ta0 = lambda i: term_at(
             st.log_term, st.snap_index, st.snap_term, st.last, i
@@ -1368,6 +1523,18 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState):
             jnp.where(ent_mask & app[:, None], ent_terms, 0)
         ),
     )
+    if cfg.conf_entries:
+        # Entry types do not travel: an append that carries the entry
+        # this leader's log marks as a configuration change says so in
+        # the two fields an append leaves unused, reject_hint (its
+        # index) and ctx (its code). No field more, no byte more.
+        c = st.conf
+        marks = app & (c.index > prev) & (c.index <= prev + n_send)
+        out = out._replace(
+            reject_hint=out.reject_hint.at[:, KIND_APP].set(
+                jnp.where(marks, c.index, 0)),
+            ctx=out.ctx.at[:, KIND_APP].set(jnp.where(marks, c.op, 0)),
+        )
 
     # Progress effects of the sends.
     sent_ents = app & (n_send > 0)
@@ -1547,10 +1714,14 @@ class TelemetryFrame(NamedTuple):
 
 def _telemetry_frame(cfg: BatchedConfig, slot, pre: BatchedState,
                      post: BatchedState, inbox_i: Tuple[MsgSlots, ...],
-                     out: MsgSlots, last_tick, n_new) -> TelemetryFrame:
+                     out: MsgSlots, last_tick, n_new, read_snap=None,
+                     conf_applied=None) -> TelemetryFrame:
     """Counters for one instance's round — a pure READ of the round's
     inputs/outputs (column order = telemetry.TM_NAMES). Never touches
-    protocol state, so telemetry=True stays bit-identical."""
+    protocol state, so telemetry=True stays bit-identical.
+    `read_snap` and `conf_applied` are the round's own (cfg.conf_entries:
+    the read state as deliver left it, and whether the control phase
+    applied a configuration change)."""
     cnt = lambda m: jnp.sum(m.astype(I32))  # noqa: E731
     v, t = out.valid, out.type
     ar_v = v[:, KIND_APP_RESP] & (t[:, KIND_APP_RESP] == T_APP_RESP)
@@ -1558,6 +1729,15 @@ def _telemetry_frame(cfg: BatchedConfig, slot, pre: BatchedState,
     cand = lambda role: (role == CANDIDATE) | (role == PRECANDIDATE)  # noqa: E731
     won = (post.role == LEADER) & (pre.role != LEADER)
     started = (cand(post.role) & ~cand(pre.role)) | (won & ~cand(pre.role))
+    if cfg.conf_entries:
+        # Reads asked for in every round of a scan reopen a batch in
+        # the control phase of the round whose deliver confirmed the
+        # last one, so the state after the round never shows it ready:
+        # count at deliver's snapshot, and a batch the control phase
+        # opened and confirmed at once (a quorum of one) beside it.
+        seq, _, ready = read_snap
+        reads_confirmed = (ready & ~pre.read_ready).astype(I32) + (
+            post.read_ready & (post.read_seq != seq)).astype(I32)
     cols = (
         cnt(v[:, KIND_VOTE]),
         cnt(v[:, KIND_APP] & (t[:, KIND_APP] == T_APP)),
@@ -1576,14 +1756,18 @@ def _telemetry_frame(cfg: BatchedConfig, slot, pre: BatchedState,
         started.astype(I32),
         won.astype(I32),
         post.commit - pre.commit,
-        (post.read_ready & ~pre.read_ready).astype(I32),
+        reads_confirmed if cfg.conf_entries
+        else (post.read_ready & ~pre.read_ready).astype(I32),
         jnp.maximum(jnp.maximum(n_new, 0) - appended, 0),
         post.fenced.astype(I32),
-        # conf_changes_applied: always zero on device — entry types
-        # live in the host arena, so the rawnode adds the count where
-        # the masks are actually staged (advance_round's pending-conf
-        # application), keeping the column's per-round per-group shape.
-        jnp.zeros((), I32),
+        # conf_changes_applied: with cfg.conf_entries, this replica's
+        # apply point taken this round (step._conf_apply). Without,
+        # zero on device — entry types live in the host arena, so the
+        # rawnode adds the count where the masks are actually staged
+        # (advance_round's pending-conf application), keeping the
+        # column's per-round per-group shape.
+        conf_applied.astype(I32) if cfg.conf_entries
+        else jnp.zeros((), I32),
     )
     counters = jnp.stack([jnp.asarray(c, I32) for c in cols])
     assert counters.shape == (NUM_COUNTERS,)
@@ -1720,7 +1904,10 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
 
     def step_round(st: BatchedState, inbox, tick_mask, campaign_mask,
                    propose_n, isolate, transfer_to, read_req, iids, slots,
-                   lane_any=None):
+                   lane_any=None, conf_req=None):
+        # `conf_req` ([N] i32, state.conf_code; cfg.conf_entries only)
+        # is the configuration change offered to each instance; None,
+        # an empty pytree, is no input at all.
         # The inbox as [N, R, K] slots (a hosting process's, the eager
         # engine's) or as the K kind lanes of [N, R] the engine's scan
         # carries: deliver takes lanes, and a lane that comes as an
@@ -1751,7 +1938,7 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
             lane_any = lane_occupancy(inbox)  # [K]
 
         def per_instance(iid, slot, sti, inbox_i, do_tick, do_camp, n_new,
-                         iso, tr_to, rd_req, lane_any):
+                         iso, tr_to, rd_req, cf_req, lane_any):
             # Partitioned instances neither receive nor send this round
             # (fault injection; ref: tests/framework bridge & pkg/proxy).
             # Phases carry jax.named_scope annotations so xprof/JAX
@@ -1768,12 +1955,13 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
                 sti = _tick(cfg, iid, slot, sti, do_tick, do_camp)
             read_snap = (sti.read_seq, sti.read_index, sti.read_ready)
             with jax.named_scope("raft_control"):
-                sti = _control(cfg, slot, sti, tr_to, rd_req)
+                sti, conf_applied = _control(
+                    cfg, slot, sti, tr_to, rd_req, cf_req)
             last_tick = sti.last
             with jax.named_scope("raft_propose"):
                 sti = _propose(cfg, slot, sti, n_new)
             with jax.named_scope("raft_emit"):
-                sti, out = _emit(cfg, slot, sti)
+                sti, out = _emit(cfg, slot, sti, conf_applied)
             # Responses to requests from sender s (request kinds) land
             # in out[s, k + NUM_REQ_KINDS]; they route back by the same
             # exchange (the inbox lane-order contract, top of module).
@@ -1808,7 +1996,7 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
                 with jax.named_scope("raft_telemetry"):
                     ret += (_telemetry_frame(
                         cfg, slot, pre, sti, inbox_i, out, last_tick,
-                        n_new),)
+                        n_new, read_snap, conf_applied),)
             return ret
 
         if cfg.lanes_minor:
@@ -1823,7 +2011,7 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
             args = jax.tree.map(
                 to_minor,
                 (iids, slots, st, inbox, tick_mask, campaign_mask,
-                 propose_n, isolate, transfer_to, read_req),
+                 propose_n, isolate, transfer_to, read_req, conf_req),
             )
             outs = jax.vmap(
                 per_instance,
@@ -1832,10 +2020,11 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
             outs = jax.tree.map(to_major, outs)
         else:
             outs = jax.vmap(
-                per_instance, in_axes=(0,) * 10 + (None,),
+                per_instance, in_axes=(0,) * 11 + (None,),
             )(
                 iids, slots, st, inbox, tick_mask, campaign_mask,
-                propose_n, isolate, transfer_to, read_req, lane_any,
+                propose_n, isolate, transfer_to, read_req, conf_req,
+                lane_any,
             )
         sti, out, aux = outs[:3]
         fleet = None
@@ -1877,6 +2066,10 @@ def make_step_round(cfg: BatchedConfig, iids=None, slots=None,
         state, outbox[, aux] = step_round(state, inbox, tick_mask,
                                           campaign_mask, propose_n, isolate)
 
+    (and, by keyword, ``transfer_to``, ``read_req`` and, for a
+    configuration with ``conf_entries``, ``conf_req``: the control
+    phase's three requests).
+
     All arrays stay on device; chain with route() for a closed-loop
     multi-raft simulation (the dense all-replica layout), or pass
     explicit `iids`/`slots` for a hosting process that owns one replica
@@ -1911,12 +2104,17 @@ def make_step_round(cfg: BatchedConfig, iids=None, slots=None,
     zero_b = jnp.zeros((n,), bool)
 
     def step(st, inbox, tick_mask, campaign_mask, propose_n, isolate,
-             transfer_to=None, read_req=None, lane_any=None):
+             transfer_to=None, read_req=None, lane_any=None, conf_req=None):
+        if cfg.conf_entries:
+            conf_req = zero_i if conf_req is None else conf_req
+        elif conf_req is not None:
+            raise ValueError(
+                "conf_req needs a configuration with conf_entries")
         return inner(st, inbox, tick_mask, campaign_mask, propose_n,
                      isolate,
                      zero_i if transfer_to is None else transfer_to,
                      zero_b if read_req is None else read_req,
-                     iids, slots, lane_any)
+                     iids, slots, lane_any, conf_req)
 
     return step
 

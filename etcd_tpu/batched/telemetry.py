@@ -65,15 +65,21 @@ TM_NAMES = (
     "elections_started",   # campaigns entered (candidate/pre-candidate)
     "elections_won",       # transitions into LEADER
     "commit_delta",        # commit-index advance this round
-    "reads_confirmed",     # ReadIndex batches quorum-confirmed
+    "reads_confirmed",     # ReadIndex batches quorum-confirmed (seen
+    # in the state after the round; with conf_entries, at deliver's
+    # snapshot, so that a batch reopened in the same round counts)
     "proposals_dropped",   # staged proposals the device did not append
     "fenced_rounds",       # rounds spent durability-fenced (PAR rejoin)
-    # Membership-mask applications staged onto the device this round
-    # (entry-driven conf-change applies, snapshot conf restores, manual
-    # uploads). The device column is zero — entry types never reach the
-    # kernel — and the rawnode adds the count at the staging seam
-    # (advance_round's pending-conf application), so the flight
-    # recorder still shows per-group conf flips round by round.
+    # Configuration changes applied this round. With
+    # BatchedConfig.conf_entries a change is an entry of the device's
+    # log and the column counts, per instance, the rounds in which that
+    # replica took its own apply point (step._conf_apply), inside a
+    # scan as anywhere. Without it entry types never reach the kernel,
+    # the device column is zero, and the rawnode adds the count at the
+    # staging seam where the host uploads the masks (entry-driven
+    # applies, snapshot conf restores, manual uploads: advance_round's
+    # pending-conf application), so the flight recorder still shows
+    # per-group conf flips round by round.
     "conf_changes_applied",
 )
 NUM_COUNTERS = len(TM_NAMES)
