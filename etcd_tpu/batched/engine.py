@@ -14,7 +14,8 @@ leadership moves, a ReadIndex batch opens and confirms, and a
 configuration change is appended and applied by each replica at its own
 apply point (``BatchedConfig.conf_entries``) inside one program. Inside
 a scan the network moves only what
-was sent: the inbox rides as its six kind lanes and a round exchanges
+was sent: inbox and outbox ride as six kind lanes (entries in the
+append lane alone, ``step.split_lanes``) and a round exchanges
 the lanes some instance of the batch wrote (``step.route_lanes``, on the
 occupancy vector deliver's lane conds skip on); a lane nobody wrote
 holds what ``empty_msgs`` holds, ``valid`` false and every field zero,
@@ -154,10 +155,13 @@ class MultiRaftEngine:
             # not twice). `_step` is read when this is first traced.
             # `conf_req` is given for a configuration with
             # conf_entries alone; None is no input.
+            # Handed lanes it answers in lanes; route(), a program
+            # of its own here, takes them stacked.
             lanes = split_lanes(inbox)
-            return self._step(st, lanes, *masks,
-                              lane_any=lane_occupancy(lanes),
-                              conf_req=conf_req)
+            out = self._step(st, lanes, *masks,
+                             lane_any=lane_occupancy(lanes),
+                             conf_req=conf_req)
+            return (out[0], stack_lanes(out[1])) + out[2:]
 
         self._round = jax.jit(step_round)
         n = cfg.num_instances
@@ -267,12 +271,13 @@ class MultiRaftEngine:
                 # left as they are (step.route_lanes). The exchange
                 # permutes slots inside a lane, so the outbox's
                 # occupancy is the next inbox's.
-                sent = jnp.any(outbox.valid, axis=(0, 1))
+                sent = lane_occupancy(outbox)
                 inbox = route_lanes(cfg, outbox, sent, (inbox, occ))
                 return (st, inbox, sent, tel, flt, lanes, watch), None
 
-            # The inbox rides the scan as its K kind lanes, each an
-            # array of its own, and is stacked back once at the exit.
+            # Inbox and outbox ride the scan as K kind lanes, each an
+            # array of its own (the round answers lanes with lanes),
+            # and the inbox is stacked back once at the exit.
             # A caller's inbox may hold anything in a lane with no
             # valid slot (the eager round exchanges emit's unsent
             # request fields too), so such a lane is wiped here, once

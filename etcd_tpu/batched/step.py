@@ -86,8 +86,9 @@ from .state import (
 # carries vote responses, lane 4 append responses, lane 5 heartbeat
 # responses. Everything that splits or scatters lanes derives from
 # NUM_REQ_KINDS: deliver's request/response split, the
-# round's response scatter (``out[:, NUM_REQ_KINDS:]`` in
-# _step_round_jit), and route()'s no-op on lane indexes (responses are
+# round's outbox (emit's request lanes, then deliver's response lanes:
+# ``out + req_resps`` in _step_round_jit), and route()'s no-op on lane
+# indexes (responses are
 # already placed in their response lane BEFORE the sender/target
 # exchange, which moves whole [K] lane vectors and never a lane). The
 # msgblock↔step differential test pins the contract
@@ -198,16 +199,33 @@ def empty_msgs(shape: Tuple[int, ...], num_ents: int,
     return narrow_msgs(m) if narrow else m
 
 
-def split_lanes(m: MsgSlots) -> Tuple[MsgSlots, ...]:
-    """[N, R, K] slots as K kind lanes of [N, R]."""
+def _entries_in_app_alone(lanes, ents) -> Tuple[MsgSlots, ...]:
+    """`lanes` with `ents` for the ``ent_terms`` of every lane but
+    KIND_APP's."""
     return tuple(
-        jax.tree.map(lambda x, _k=k: x[:, :, _k], m)
+        lanes[k] if k == KIND_APP else lanes[k]._replace(ent_terms=ents)
         for k in range(NUM_KINDS))
 
 
+def split_lanes(m: MsgSlots) -> Tuple[MsgSlots, ...]:
+    """[N, R, K] slots as K kind lanes of [N, R]. Entries travel in
+    KIND_APP alone (emit writes no other lane's, no other handler reads
+    them), so only that lane keeps its ``ent_terms`` [N, R, E]; the
+    other five carry an ``ent_terms`` of no columns, [N, R, 0]: the
+    same NamedTuple, one tree.map over fields, and nothing to move."""
+    lanes = [jax.tree.map(lambda x, _k=k: x[:, :, _k], m)
+             for k in range(NUM_KINDS)]
+    return _entries_in_app_alone(lanes, lanes[KIND_APP].ent_terms[..., :0])
+
+
 def stack_lanes(lanes: Tuple[MsgSlots, ...]) -> MsgSlots:
-    """K kind lanes of [N, R] as [N, R, K] slots."""
-    return jax.tree.map(lambda *xs: jnp.stack(xs, axis=2), *lanes)
+    """K kind lanes of [N, R] as [N, R, K] slots, the public form: the
+    ``ent_terms`` the five other lanes do not carry come back as the
+    zeros they always were."""
+    return jax.tree.map(
+        lambda *xs: jnp.stack(xs, axis=2),
+        *_entries_in_app_alone(
+            lanes, jnp.zeros_like(lanes[KIND_APP].ent_terms)))
 
 
 def lane_occupancy(lanes: Tuple[MsgSlots, ...]) -> jnp.ndarray:
@@ -448,8 +466,10 @@ def _leader_traffic_prelude(cfg, iid, slot, st1, m, from_slot):
 
 def _lane_app(cfg: BatchedConfig, iid, slot, st: BatchedState, m: MsgSlots,
               from_slot):
-    """Lane KIND_APP: T_APP / T_SNAP (ref: raft.go:1475-1614)."""
-    no_resp = empty_msgs((), cfg.max_ents_per_msg)
+    """Lane KIND_APP: T_APP / T_SNAP (ref: raft.go:1475-1614). A
+    response carries no entries: its ``ent_terms`` has no columns, as
+    its lane's (split_lanes)."""
+    no_resp = empty_msgs((), 0)
     st1, dead, lower = _term_gate(cfg, iid, slot, st, m, from_slot)
 
     fol = _leader_traffic_prelude(cfg, iid, slot, st1, m, from_slot)
@@ -477,7 +497,7 @@ def _lane_hb(cfg: BatchedConfig, iid, slot, st: BatchedState, m: MsgSlots,
              from_slot):
     """Lane KIND_HB: T_HB + T_TIMEOUT_NOW (ref: raft.go:1513;
     :1465-1472 MsgTimeoutNow → immediate transfer campaign)."""
-    no_resp = empty_msgs((), cfg.max_ents_per_msg)
+    no_resp = empty_msgs((), 0)
     st1, dead, lower = _term_gate(cfg, iid, slot, st, m, from_slot)
 
     fol = _leader_traffic_prelude(cfg, iid, slot, st1, m, from_slot)
@@ -517,7 +537,7 @@ def _handle_append(cfg: BatchedConfig, st: BatchedState, m: MsgSlots):
     """Follower append handling (ref: raft.go:1475-1511 +
     log.go maybeAppend/findConflict)."""
     e = cfg.max_ents_per_msg
-    no_resp = empty_msgs((), e)
+    no_resp = empty_msgs((), 0)
     prev = m.index
 
     # Fast path: stale append below commit acks the commit index.
@@ -586,7 +606,7 @@ def _handle_snapshot(cfg: BatchedConfig, st: BatchedState, m: MsgSlots):
     """Follower snapshot install (ref: raft.go:1518-1614 restore). The
     conf state rides host-side; on device membership masks are taken
     to be current. m.index/m.log_term carry the snapshot (index, term)."""
-    no_resp = empty_msgs((), cfg.max_ents_per_msg)
+    no_resp = empty_msgs((), 0)
     ignore = m.index <= st.commit
     ta = lambda i: term_at(st.log_term, st.snap_index, st.snap_term, st.last, i)
     fast_forward = ta(m.index) == m.log_term
@@ -713,7 +733,7 @@ def _vec_request_resps(cfg: BatchedConfig, st: BatchedState, answer,
     wresp, w, nudge = answer
     r = cfg.num_replicas
     at_w = jnp.arange(r, dtype=I32) == w
-    resp = empty_msgs((r,), cfg.max_ents_per_msg)
+    resp = empty_msgs((r,), 0)
     return _sel(occupied, resp._replace(
         valid=jnp.where(at_w, wresp.valid, nudge),
         type=jnp.where(at_w, wresp.type, T_APP_RESP),
@@ -725,7 +745,6 @@ def _vec_request_resps(cfg: BatchedConfig, st: BatchedState, answer,
         reject_hint=jnp.where(at_w, wresp.reject_hint, 0),
         n_ents=jnp.where(at_w, wresp.n_ents, 0),
         ctx=jnp.where(at_w, wresp.ctx, 0),
-        ent_terms=jnp.where(at_w[:, None], wresp.ent_terms[None, :], 0),
     ), resp)
 
 
@@ -793,7 +812,7 @@ def _vec_lane_vote(cfg: BatchedConfig, iid, slot, st: BatchedState,
     ) | (m.term > st2.term)
     grant_p = pv & ~lower_p & can_pre & up_to_date & ~st2.fenced
 
-    resp = empty_msgs((r,), cfg.max_ents_per_msg)
+    resp = empty_msgs((r,), 0)
     resp = resp._replace(
         valid=eq | pv,
         type=jnp.where(is_vote, T_VOTE_RESP, T_PREVOTE_RESP),
@@ -1036,9 +1055,10 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
     """Deliver this instance's inbox, K kind lanes of [R] slots each:
     lanes in kind order, each
     lane one fold over the sender axis (the order contract is in the
-    section comment above). Returns the state and the [R, 3] responses
-    to the request lanes, which the round routes back in lanes
-    ``k + NUM_REQ_KINDS``. No lax.scan sits anywhere in the round, so
+    section comment above). Returns the state and the responses to the
+    three request lanes, each a lane of [R] slots as the round hands it
+    on: the outbox's lanes ``k + NUM_REQ_KINDS``. No lax.scan sits
+    anywhere in the round, so
     deliver→tick→control→propose→emit trace into ONE straight-line
     fused region, and the named_scope annotations (ROUND_PHASE_SCOPES)
     are attribution labels inside it.
@@ -1052,7 +1072,7 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
     so the skip is bit-equivalent; None falls back to per-instance
     occupancy (the cond degrades to a select under a mapped predicate
     — correct, just unskipped)."""
-    no_resp = empty_msgs((cfg.num_replicas,), cfg.max_ents_per_msg)
+    no_resp = empty_msgs((cfg.num_replicas,), 0)
 
     def occupied(k, m):
         if lane_any is None:
@@ -1086,7 +1106,7 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
         # are widened after it (see _vec_lane_request on why).
         m = inbox[k]
         occ = occupied(k, m)
-        no_answer = (empty_msgs((), cfg.max_ents_per_msg),
+        no_answer = (empty_msgs((), 0),
                      jnp.zeros((), I32),
                      jnp.zeros((cfg.num_replicas,), bool))
         stx, answer = jax.lax.cond(
@@ -1137,11 +1157,7 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
     st = st._replace(send_heartbeat=send_heartbeat)
     if cfg.conf_entries:
         st = st._replace(conf=st.conf._replace(learner_next=learner_next))
-    # [R] per request lane → [R, 3].
-    req = jax.tree.map(
-        lambda a, b, c: jnp.stack((a, b, c), axis=1), r0, r1, r2
-    )
-    return st, req
+    return st, (r0, r1, r2)
 
 
 def _tick(cfg: BatchedConfig, iid, slot, st: BatchedState, do_tick,
@@ -1412,15 +1428,21 @@ def _propose(cfg: BatchedConfig, slot, st: BatchedState, n_new):
 
 
 def _emit(cfg: BatchedConfig, slot, st: BatchedState, conf_applied=None):
-    """Materialize pending sends into an outbox [R, K] and clear flags;
-    auto-apply committed entries (device applies immediately; the host
-    drains (group, index) ranges for real payload apply).
+    """Materialize pending sends into the three request lanes of the
+    outbox (KIND_VOTE, KIND_APP, KIND_HB, each [R] slots addressed by
+    target) and clear flags; auto-apply committed entries (device
+    applies immediately; the host drains (group, index) ranges for real
+    payload apply). The lanes leave as they are computed: nothing here
+    builds [R, K], and only KIND_APP's ``ent_terms`` has columns.
     `conf_applied` (cfg.conf_entries) is _control's word that this
     round's apply point was taken."""
     e = cfg.max_ents_per_msg
     r = cfg.num_replicas
     peers = jnp.arange(r, dtype=I32)
-    out = empty_msgs((r, NUM_KINDS), e)
+    # A field of a lane is int32 [R], one slot a target: what a sender
+    # says to all alike is widened here, and a Python-int choice made
+    # int32 (the branches of route_lanes' switches must agree on it).
+    per_target = lambda x: jnp.broadcast_to(jnp.asarray(x, I32), (r,))  # noqa: E731
 
     # Device-side apply + compaction first: committed == applied on
     # device (payload apply is the host's job, driven from the commit
@@ -1453,18 +1475,14 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, conf_applied=None):
     is_leader = st.role == LEADER
 
     # --- vote requests (ref: raft.go:822-834) ---
-    vr = st.send_vote_req & vote_peer
-    vtype = jnp.where(st.vote_req_is_pre, T_PREVOTE, T_VOTE)
-    vterm = jnp.where(st.vote_req_is_pre, st.term + 1, st.term)
-    out = out._replace(
-        valid=out.valid.at[:, KIND_VOTE].set(vr),
-        type=out.type.at[:, KIND_VOTE].set(vtype),
-        term=out.term.at[:, KIND_VOTE].set(vterm),
-        index=out.index.at[:, KIND_VOTE].set(st.last),
-        log_term=out.log_term.at[:, KIND_VOTE].set(ta(st.last)),
-        ctx=out.ctx.at[:, KIND_VOTE].set(
-            jnp.where(st.vote_req_transfer, 1, 0)
-        ),
+    vote = empty_msgs((r,), 0)._replace(
+        valid=st.send_vote_req & vote_peer,
+        type=per_target(jnp.where(st.vote_req_is_pre, T_PREVOTE, T_VOTE)),
+        term=per_target(
+            jnp.where(st.vote_req_is_pre, st.term + 1, st.term)),
+        index=per_target(st.last),
+        log_term=per_target(ta(st.last)),
+        ctx=per_target(jnp.where(st.vote_req_transfer, 1, 0)),
     )
 
     # --- heartbeats + TimeoutNow (ref: raft.go:495-511; :1367-1372) ---
@@ -1482,16 +1500,12 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, conf_applied=None):
         & (st.match >= st.last)  # masked to the transferee's slot below
         & (peers == tr)
     )
-    out = out._replace(
-        valid=out.valid.at[:, KIND_HB].set(hb | ton),
-        type=out.type.at[:, KIND_HB].set(
-            jnp.where(ton, T_TIMEOUT_NOW, T_HB)
-        ),
-        term=out.term.at[:, KIND_HB].set(st.term),
-        commit=out.commit.at[:, KIND_HB].set(
-            jnp.minimum(st.match, st.commit)
-        ),
-        ctx=out.ctx.at[:, KIND_HB].set(jnp.where(ton, 0, hb_ctx)),
+    heartbeat = empty_msgs((r,), 0)._replace(
+        valid=hb | ton,
+        type=per_target(jnp.where(ton, T_TIMEOUT_NOW, T_HB)),
+        term=per_target(st.term),
+        commit=jnp.minimum(st.match, st.commit),
+        ctx=jnp.where(ton, 0, hb_ctx),
     )
     st = st._replace(transfer_sent=st.transfer_sent | jnp.any(ton))
 
@@ -1507,21 +1521,15 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, conf_applied=None):
     app = want & ~snap_needed
     snp = want & snap_needed
 
-    out = out._replace(
-        valid=out.valid.at[:, KIND_APP].set(app | snp),
-        type=out.type.at[:, KIND_APP].set(jnp.where(snp, T_SNAP, T_APP)),
-        term=out.term.at[:, KIND_APP].set(st.term),
-        index=out.index.at[:, KIND_APP].set(
-            jnp.where(snp, st.snap_index, prev)
-        ),
-        log_term=out.log_term.at[:, KIND_APP].set(
-            jnp.where(snp, st.snap_term, ta(prev))
-        ),
-        commit=out.commit.at[:, KIND_APP].set(st.commit),
-        n_ents=out.n_ents.at[:, KIND_APP].set(jnp.where(app, n_send, 0)),
-        ent_terms=out.ent_terms.at[:, KIND_APP].set(
-            jnp.where(ent_mask & app[:, None], ent_terms, 0)
-        ),
+    append = empty_msgs((r,), e)._replace(
+        valid=app | snp,
+        type=per_target(jnp.where(snp, T_SNAP, T_APP)),
+        term=per_target(st.term),
+        index=jnp.where(snp, st.snap_index, prev),
+        log_term=jnp.where(snp, st.snap_term, ta(prev)),
+        commit=per_target(st.commit),
+        n_ents=jnp.where(app, n_send, 0),
+        ent_terms=jnp.where(ent_mask & app[:, None], ent_terms, 0),
     )
     if cfg.conf_entries:
         # Entry types do not travel: an append that carries the entry
@@ -1530,10 +1538,9 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, conf_applied=None):
         # index) and ctx (its code). No field more, no byte more.
         c = st.conf
         marks = app & (c.index > prev) & (c.index <= prev + n_send)
-        out = out._replace(
-            reject_hint=out.reject_hint.at[:, KIND_APP].set(
-                jnp.where(marks, c.index, 0)),
-            ctx=out.ctx.at[:, KIND_APP].set(jnp.where(marks, c.op, 0)),
+        append = append._replace(
+            reject_hint=jnp.where(marks, c.index, 0),
+            ctx=jnp.where(marks, c.op, 0),
         )
 
     # Progress effects of the sends.
@@ -1555,7 +1562,7 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, conf_applied=None):
         send_vote_req=jnp.zeros_like(st.send_vote_req),
         vote_req_transfer=jnp.zeros_like(st.vote_req_transfer),
     )
-    return st, out
+    return st, (vote, append, heartbeat)
 
 
 # Annotation registry for tools/phaseprobe.py and trace tooling: the
@@ -1617,23 +1624,31 @@ def _route_jit(r: int):
     # it again), which is a third of a warm start's time in the scan.
     exchange_lane = jax.jit(exchange)
 
-    def route_lanes(outbox: MsgSlots, lane_any, lanes, stale):
-        # Lane by lane and never re-stacked: K is the physically major
-        # axis of the outbox on TPU, so a lane of it is one contiguous
-        # slab a branch slices for nothing, and what a branch returns
-        # is the carry deliver's lane cond takes as it is. (Per-lane
-        # results concatenated back into [N, R, K] relaid the whole
-        # carried inbox out, K into the sublanes: PERF.md section 6,
-        # PR 31.)
+    def route_lanes(outbox, lane_any, lanes, stale):
+        # Lane by lane and never stacked: the round hands its outbox on
+        # as K lanes of [N, R], each cond takes its own lane of it and
+        # nothing else as operand, and what a branch returns is the
+        # carry deliver's lane cond takes as it is. (A packed [N, R, K]
+        # outbox cost a pad a field in emit, a slice a field and lane
+        # here, and a copy of every field at the edge of all six conds:
+        # PERF.md section 6, PR 33. Per-lane results concatenated back
+        # into [N, R, K] relaid the whole carried inbox out, K into the
+        # sublanes: PR 31.)
+        # The barrier keeps emit out of the branches: a lane only its
+        # exchange reads is otherwise computed inside that branch (the
+        # compiler sinks it there), where nothing gives it a layout,
+        # and on TPU it then comes out instance-major and is copied
+        # back (R=3 padded to a 4x128 tile: PERF.md section 6, PR 33).
+        outbox = jax.lax.optimization_barrier(outbox)
         with jax.named_scope("raft_route"):
             return tuple(
                 jax.lax.switch(
                     jnp.where(lane_any[k], 2, stale[k].astype(I32)),
                     (lambda lane, ob: lane,
                      lambda lane, ob: jax.tree.map(jnp.zeros_like, lane),
-                     lambda lane, ob, _k=k: jax.tree.map(
-                         lambda o: exchange_lane(o[:, :, _k]), ob)),
-                    lanes[k], outbox)
+                     lambda lane, ob: jax.tree.map(
+                         lambda o: exchange_lane(o) if o.size else o, ob)),
+                    lanes[k], outbox[k])
                 for k in range(NUM_KINDS))
 
     return (jax.jit(route, inline=True), jax.jit(route_lanes, inline=True))
@@ -1660,11 +1675,13 @@ def route(cfg: BatchedConfig, outbox: MsgSlots, lane_any=None,
     program with no branch in it. Mesh-sharded callers need that: the
     occupancy reduce would cross shards (see ``_step_round_jit`` on
     ``lane_skip``). Given ``lane_any``, only the lanes somebody wrote
-    are exchanged: ``stack_lanes`` of ``route_lanes``, which see (its
-    ``prev`` here is ``(inbox [N, R, K], stale)``)."""
+    are exchanged: ``stack_lanes`` of ``route_lanes`` of the outbox's
+    ``split_lanes``, which see (its ``prev`` here is ``(inbox [N, R,
+    K], stale)``; like the round itself this carries ``ent_terms`` in
+    KIND_APP alone)."""
     # Lane indexes pass through untouched: by the inbox lane-order
     # contract (NUM_REQ_KINDS, top of module), emit writes requests
-    # into lanes 0..NUM_REQ_KINDS-1 and the round's response scatter
+    # into lanes 0..NUM_REQ_KINDS-1 and the round
     # has ALREADY placed each response in lane k + NUM_REQ_KINDS of the
     # responder's outbox row for the requester (see _step_round_jit),
     # so the exchange alone lands everything in its inbox lane.
@@ -1672,16 +1689,19 @@ def route(cfg: BatchedConfig, outbox: MsgSlots, lane_any=None,
         return _route_jit(cfg.num_replicas)[0](outbox)
     if prev is not None:
         prev = (split_lanes(prev[0]), prev[1])
-    return stack_lanes(route_lanes(cfg, outbox, lane_any, prev))
+    return stack_lanes(
+        route_lanes(cfg, split_lanes(outbox), lane_any, prev))
 
 
-def route_lanes(cfg: BatchedConfig, outbox: MsgSlots, lane_any,
+def route_lanes(cfg: BatchedConfig, outbox: Tuple[MsgSlots, ...], lane_any,
                 prev=None) -> Tuple[MsgSlots, ...]:
-    """route() by kind lane: the inbox as K lanes of [N, R] slots, the
-    form the engine's scan carries and ``step_round`` takes as it is.
+    """route() by kind lane: outbox and inbox as K lanes of [N, R]
+    slots (``split_lanes``' form: entries in KIND_APP alone), what
+    ``step_round`` returns when handed lanes, what the engine's scan
+    carries and what ``step_round`` takes as it is.
 
     ``lane_any`` ([K] bool) is the outbox's batch-level lane
-    occupancy, ``jnp.any(outbox.valid, axis=(0, 1))``, reduced by the
+    occupancy, ``lane_occupancy(outbox)``, reduced by the
     caller outside any vmap (the pattern of ``_deliver_vectorized``):
     a lane somebody wrote is exchanged, under a branch with that
     unmapped predicate; a lane nobody wrote comes out as ``empty_msgs``
@@ -1698,7 +1718,7 @@ def route_lanes(cfg: BatchedConfig, outbox: MsgSlots, lane_any,
     under steady appends); one occupied then and empty now is wiped.
     Without ``prev`` an empty lane is fresh zeros."""
     if prev is None:
-        prev = (split_lanes(jax.tree.map(jnp.zeros_like, outbox)),
+        prev = (jax.tree.map(jnp.zeros_like, outbox),
                 jnp.zeros((NUM_KINDS,), bool))
     return _route_jit(cfg.num_replicas)[1](outbox, lane_any, *prev)
 
@@ -1714,7 +1734,8 @@ class TelemetryFrame(NamedTuple):
 
 def _telemetry_frame(cfg: BatchedConfig, slot, pre: BatchedState,
                      post: BatchedState, inbox_i: Tuple[MsgSlots, ...],
-                     out: MsgSlots, last_tick, n_new, read_snap=None,
+                     out: Tuple[MsgSlots, ...], last_tick, n_new,
+                     read_snap=None,
                      conf_applied=None) -> TelemetryFrame:
     """Counters for one instance's round — a pure READ of the round's
     inputs/outputs (column order = telemetry.TM_NAMES). Never touches
@@ -1723,8 +1744,10 @@ def _telemetry_frame(cfg: BatchedConfig, slot, pre: BatchedState,
     the read state as deliver left it, and whether the control phase
     applied a configuration change)."""
     cnt = lambda m: jnp.sum(m.astype(I32))  # noqa: E731
-    v, t = out.valid, out.type
-    ar_v = v[:, KIND_APP_RESP] & (t[:, KIND_APP_RESP] == T_APP_RESP)
+    v = [out[k].valid for k in range(NUM_KINDS)]
+    t = [out[k].type for k in range(NUM_KINDS)]
+    ar = out[KIND_APP_RESP]
+    ar_v = ar.valid & (ar.type == T_APP_RESP)
     appended = post.last - last_tick
     cand = lambda role: (role == CANDIDATE) | (role == PRECANDIDATE)  # noqa: E731
     won = (post.role == LEADER) & (pre.role != LEADER)
@@ -1739,17 +1762,17 @@ def _telemetry_frame(cfg: BatchedConfig, slot, pre: BatchedState,
         reads_confirmed = (ready & ~pre.read_ready).astype(I32) + (
             post.read_ready & (post.read_seq != seq)).astype(I32)
     cols = (
-        cnt(v[:, KIND_VOTE]),
-        cnt(v[:, KIND_APP] & (t[:, KIND_APP] == T_APP)),
-        cnt(v[:, KIND_APP] & (t[:, KIND_APP] == T_SNAP)),
-        cnt(v[:, KIND_HB] & (t[:, KIND_HB] == T_HB)),
-        cnt(v[:, KIND_HB] & (t[:, KIND_HB] == T_TIMEOUT_NOW)),
-        cnt(v[:, KIND_VOTE_RESP]),
-        cnt(v[:, KIND_APP_RESP]),
-        cnt(v[:, KIND_HB_RESP]),
+        cnt(v[KIND_VOTE]),
+        cnt(v[KIND_APP] & (t[KIND_APP] == T_APP)),
+        cnt(v[KIND_APP] & (t[KIND_APP] == T_SNAP)),
+        cnt(v[KIND_HB] & (t[KIND_HB] == T_HB)),
+        cnt(v[KIND_HB] & (t[KIND_HB] == T_TIMEOUT_NOW)),
+        cnt(v[KIND_VOTE_RESP]),
+        cnt(v[KIND_APP_RESP]),
+        cnt(v[KIND_HB_RESP]),
         sum(cnt(inbox_i[k].valid) for k in range(NUM_KINDS)),
-        cnt(ar_v & ~out.reject[:, KIND_APP_RESP]),
-        cnt(ar_v & out.reject[:, KIND_APP_RESP]),
+        cnt(ar_v & ~ar.reject),
+        cnt(ar_v & ar.reject),
         cnt((pre.pr_state == PROBE) & (post.pr_state == REPLICATE)),
         cnt((pre.pr_state != SNAPSHOT) & (post.pr_state == SNAPSHOT)),
         cnt((pre.pr_state != PROBE) & (post.pr_state == PROBE)),
@@ -1912,8 +1935,12 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
         # engine's) or as the K kind lanes of [N, R] the engine's scan
         # carries: deliver takes lanes, and a lane that comes as an
         # array of its own enters its cond with no slice at the edge.
+        # The outbox leaves in the form the inbox came in: lanes for
+        # lanes (route_lanes takes them as they are), [N, R, K] slots
+        # for slots, stacked once, outside the vmap.
+        packed = isinstance(inbox, MsgSlots)
         # jitlint: waive(tracer-branch) -- the branch is on the argument's pytree structure at trace time, never on a device value
-        if isinstance(inbox, MsgSlots):
+        if packed:
             inbox = split_lanes(inbox)
         if cfg.narrow_lanes:
             # Narrow lanes live int8/int16 BETWEEN rounds (the donated
@@ -1962,14 +1989,13 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
                 sti = _propose(cfg, slot, sti, n_new)
             with jax.named_scope("raft_emit"):
                 sti, out = _emit(cfg, slot, sti, conf_applied)
-            # Responses to requests from sender s (request kinds) land
-            # in out[s, k + NUM_REQ_KINDS]; they route back by the same
+            # The response to sender s's request of kind k is slot s of
+            # lane k + NUM_REQ_KINDS; it routes back by the same
             # exchange (the inbox lane-order contract, top of module).
-            out = jax.tree.map(
-                lambda o, rr: o.at[:, NUM_REQ_KINDS:].set(rr),
-                out, req_resps,
-            )
-            out = out._replace(valid=out.valid & ~iso)
+            out = out + req_resps
+            out = tuple(
+                out[k]._replace(valid=out[k].valid & ~iso)
+                for k in range(NUM_KINDS))
             with jax.named_scope("raft_lease"):
                 # Quorum-evidence lease re-arm (BatchedState.lease_ticks):
                 # commit progress this round means a quorum just acked
@@ -2038,7 +2064,10 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
             sti = narrow_state(sti)
             # Telemetry/fleet frames above read the WIDE outbox; the
             # narrowed one is what rides the route()→inbox carry.
-            out = narrow_msgs(out)
+            out = tuple(map(narrow_msgs, out))
+        # jitlint: waive(tracer-branch) -- on the argument's pytree structure, as above
+        if packed:
+            out = stack_lanes(out)
         # Output order: (state, outbox[, aux][, telemetry][, fleet]) —
         # callers index via the cfg flags (engine/rawnode compute the
         # positions once at build time).

@@ -137,6 +137,15 @@ os.environ.setdefault("ETCD_TPU_TRANSFER_GUARD", "disallow")
 # (test_without_a_schedule_the_scan_is_the_parents), and every other
 # engine of that file is built on CELL, a key since ISSUE 28. Budget
 # 46 -> 48: raised by exactly the two, the headroom of 2 kept.
+# ISSUE 33 AUDIT: 47 used of 48, no raise. test_route's cases for the
+# outbox's two forms build on keys that are there (test_scan_faults'
+# CELL, R5 and R3_MAJOR, test_scan_reconf's RC3 and RC5, this file's
+# own n-minor pair and its narrow G=3, test_deliver_shapes' lane_skip
+# twin and its hosted narrow-lanes rawnode) and add ONE: R=5, n-minor,
+# narrow lanes, laneskip=0 (cfg_of(4, 5, lanes_minor=True,
+# narrow_lanes=True)): R=5 had no narrow and no lane_skip=False
+# program to hold the two forms against each other. The round handed
+# lanes or slots is two traces of one `jit(step_round)`, no key.
 ROUND_STEP_SHAPE_BUDGET = 48
 
 

@@ -10,7 +10,10 @@ own, and the engine's eager path must stay two programs. Handed the
 outbox's lane occupancy (ISSUE 31), route() exchanges only the lanes
 somebody wrote: those equal the oracle bit for bit, the others come
 out as ``empty_msgs``, and the closed loop, which routes that way,
-equals single rounds that exchange every lane.
+equals single rounds that exchange every lane. The round hands its
+outbox on in the form it was handed its inbox (ISSUE 33): six kind
+lanes for lanes, entries in the append lane alone, and the scan's body
+never holds an [N, R, K] array; slots for slots, the same bits.
 """
 
 import re
@@ -22,6 +25,7 @@ import pytest
 
 import chip_smoke  # tests/conftest.py puts the repo root on sys.path
 from etcd_tpu.batched import BatchedConfig, MultiRaftEngine
+from etcd_tpu.batched.engine import CTL_COLS
 from etcd_tpu.batched.step import (
     KIND_APP,
     KIND_APP_RESP,
@@ -30,12 +34,20 @@ from etcd_tpu.batched.step import (
     KIND_VOTE,
     NARROW_MSG_DTYPES,
     NUM_KINDS,
+    T_APP,
+    T_SNAP,
     MsgSlots,
+    make_step_round,
     route,
+    split_lanes,
+    stack_lanes,
 )
 
+from .test_deliver_shapes import make_engine as differential_engine
 from .test_differential_wide import make_pair
-from .test_scan_faults import CELL, _fields_equal, inbox_equal
+from .test_scan_faults import (CELL, R3_MAJOR, R5, _fields_equal,
+                               inbox_equal)
+from .test_scan_reconf import RC3, RC5
 
 E = 4
 REPLICAS = (1, 2, 3, 5, 7)
@@ -96,11 +108,16 @@ OCCUPANCY = {
 
 
 def only_lanes(out: MsgSlots, lanes) -> MsgSlots:
-    """`out` with nothing valid outside `lanes`; the payload fields
-    there stay, as emit leaves term, type and commit in the request
-    slots it does not send."""
+    """`out` as a round can make it, with nothing valid outside
+    `lanes`: the payload fields there stay, as emit leaves term, type
+    and commit in the request slots it does not send, and entries
+    travel in the append lane alone (every other lane's `ent_terms` is
+    zero, which is why the lane form carries none: ISSUE 33)."""
     keep = np.isin(np.arange(NUM_KINDS), lanes)
-    return out._replace(valid=out.valid & jnp.asarray(keep))
+    app = np.arange(NUM_KINDS) == KIND_APP
+    return out._replace(
+        valid=out.valid & jnp.asarray(keep),
+        ent_terms=jnp.where(jnp.asarray(app)[:, None], out.ent_terms, 0))
 
 
 def lane_any_of(out: MsgSlots):
@@ -332,3 +349,205 @@ def test_scan_equals_single_rounds(lanes_minor, schedule):
     else:
         assert occupied[KIND_VOTE] == 0 and occupied[KIND_APP] == rounds
         assert 0 < occupied[KIND_HB] < rounds
+
+
+# -- the outbox leaves the round as kind lanes (ISSUE 33) --------------------------
+
+
+def _trace_scan(cfg, rounds=64):
+    """The closed loop of `cfg` traced (nothing compiles), with a
+    control schedule where the configuration has a control plane of its
+    own to trace."""
+    eng = MultiRaftEngine(cfg)
+    ctl = watch = None
+    if cfg.conf_entries:
+        ctl, _ = eng._control_schedule(
+            np.zeros((rounds, CTL_COLS), np.int32), rounds)
+        watch = eng._watch
+    return eng._closed_loop.trace(
+        eng.state, eng.inbox, eng._zeros_b, eng._zeros_i, eng._tel(),
+        eng._flt(), eng._lanes, None, rounds, ctl, watch)
+
+
+# Values other tests build already (no round-step key of this file's
+# own): R=3 and R=5, both layouts, the counter plane and the
+# configuration lanes on and off.
+SCANNED = {
+    "r3-major": R3_MAJOR,
+    "r3-minor": cfg_of(4, 3, lanes_minor=True),
+    "r3-minor-telemetry": CELL,
+    "r5-minor": R5,
+    "r3-minor-telemetry-conf": RC3,
+    "r5-major-conf": RC5,
+}
+
+
+@pytest.mark.parametrize("name", list(SCANNED))
+def test_the_scans_body_holds_no_packed_outbox(name):
+    """Inside the 64-round scan no value has the shape of a packed
+    field, [N, R, K] or [N, R, K, E], in either order of its axes
+    (under `lanes_minor` the vmap's own arrays run [R, K, N]): emit
+    makes lanes, route_lanes takes them, and the inbox is stacked once,
+    after the scan. Exactly one [N, R, E] array is exchanged a round,
+    the append lane's `ent_terms`; the other five lanes carry none."""
+    cfg = SCANNED[name].validate().resolved()
+    n, r, e = cfg.num_instances, cfg.num_replicas, cfg.max_ents_per_msg
+    closed = _trace_scan(cfg).jaxpr.jaxpr
+    scans = [q for q in closed.eqns if q.primitive.name == "scan"]
+    assert len(scans) == 1 and scans[0].params["length"] == 64
+    body = scans[0].params["jaxpr"].jaxpr
+    packed = {tuple(sorted((n, r, NUM_KINDS))),
+              tuple(sorted((n, r, NUM_KINDS, e)))}
+    exchanged = []
+    seen = 0
+    for q, routed, _branch in _eqns(body):
+        for v in list(q.invars) + list(q.outvars):
+            shape = getattr(getattr(v, "aval", None), "shape", None)
+            if shape is not None:
+                seen += 1
+                assert tuple(sorted(shape)) not in packed, (q.primitive, shape)
+        if routed and q.params.get("name") == "exchange":
+            exchanged.append(q.invars[0].aval.shape)
+    assert seen > 1000
+    fields = len(MsgSlots._fields) - 1
+    assert sorted(exchanged) == sorted(
+        [(n, r)] * (fields * NUM_KINDS) + [(n, r, e)]), exchanged
+    # The stacked inbox is there all the same, outside the scan.
+    outer = [v.aval.shape for q in closed.eqns for v in q.outvars
+             if hasattr(v.aval, "shape")]
+    assert (n, r, NUM_KINDS, e) in outer
+
+
+def _bits_equal(a, b, what):
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b), strict=True):
+        x, y = np.asarray(x), np.asarray(y)
+        assert x.dtype == y.dtype and x.shape == y.shape, what
+        assert (x == y).all(), what
+
+
+# (configuration, lane_skip): R=3 and R=5, the narrow carry on and
+# off, the lane skip on and off. The last is this file's one new
+# round-step key (conftest.py, ISSUE 33 audit); the others are keys
+# already (test_scan_faults' CELL and R5, this file's own narrow
+# configuration, test_deliver_shapes' lane_skip twin).
+BOTH_FORMS = {
+    "r3-wide-skip": (lambda: CELL, True),
+    "r5-wide-skip": (lambda: R5, True),
+    "r3-narrow-skip": (
+        lambda: cfg_of(3, 3, lanes_minor=True, narrow_lanes=True), True),
+    "r3-wide-noskip": (lambda: differential_engine().cfg, False),
+    "r5-narrow-noskip": (
+        lambda: cfg_of(4, 5, lanes_minor=True, narrow_lanes=True), False),
+}
+
+
+@pytest.mark.parametrize("name", list(BOTH_FORMS))
+def test_lanes_in_lanes_out_equals_slots_in_slots_out(name):
+    """The round handed its inbox as six lanes answers with six lanes,
+    and their `stack_lanes` is, field for field and bit for bit, the
+    outbox of the same round handed [N, R, K] slots; the state and the
+    counter frame are the same too. Driven through a campaign (a vote
+    round), steady appends, a node cut off until the ring has passed it
+    by and healed (snapshot rounds, where the ring compacts). And
+    `split_lanes` then `stack_lanes` is the identity on every inbox
+    and outbox on the way."""
+    make_cfg, lane_skip = BOTH_FORMS[name]
+    cfg = make_cfg().validate().resolved()
+    n, r = cfg.num_instances, cfg.num_replicas
+    step = make_step_round(cfg, lane_skip=lane_skip)
+    eng = MultiRaftEngine(cfg)  # for a fresh state and inbox
+    st, inbox = eng.state, eng.inbox
+    slots = np.arange(n) % r
+    lead = np.zeros((n,), bool)
+    lead[np.arange(cfg.num_groups) * r + np.arange(cfg.num_groups) % r] = True
+    rounds = 8 + 3 * cfg.window
+    saw = dict(vote=0, append=0, snapshot=0, entries=0, cut=0)
+    for t in range(rounds):
+        cut = 8 <= t < 8 + 2 * cfg.window
+        iso = jnp.asarray((slots == 0) & cut)
+        args = (jnp.full((n,), t >= 4), jnp.asarray(lead & (t == 0)),
+                jnp.full((n,), cfg.max_props_per_round if t >= 4 else 0,
+                         jnp.int32), iso)
+        slots_out = step(st, inbox, *args)
+        lanes_out = step(st, split_lanes(inbox), *args)
+        assert isinstance(slots_out[1], MsgSlots)
+        assert isinstance(lanes_out[1], tuple) and all(
+            isinstance(m, MsgSlots) for m in lanes_out[1])
+        assert [m.ent_terms.shape for m in lanes_out[1]] == [
+            (n, r, cfg.max_ents_per_msg if k == KIND_APP else 0)
+            for k in range(NUM_KINDS)]
+        _bits_equal(slots_out[0], lanes_out[0], ("state", t))
+        _bits_equal(slots_out[2:], lanes_out[2:], ("frames", t))
+        outbox = stack_lanes(lanes_out[1])
+        _bits_equal(outbox, slots_out[1], ("outbox", t))
+        st = slots_out[0]
+        inbox = route(cfg, outbox)
+        for what in (inbox, outbox):
+            _bits_equal(stack_lanes(split_lanes(what)), what,
+                        ("split then stack", t))
+        v, ty = np.asarray(outbox.valid), np.asarray(outbox.type)
+        saw["vote"] += v[:, :, KIND_VOTE].any()
+        saw["append"] += (v & (ty == T_APP))[:, :, KIND_APP].any()
+        saw["snapshot"] += (v & (ty == T_SNAP))[:, :, KIND_APP].any()
+        saw["entries"] += np.asarray(outbox.ent_terms).any()
+        saw["cut"] += bool(np.asarray(iso).any()) and v.any()
+    assert (np.asarray(st.commit).reshape(-1, r).max(axis=1) > 0).all()
+    assert saw["vote"] and saw["append"] and saw["entries"] and saw["cut"]
+    if cfg.auto_compact:
+        assert saw["snapshot"], saw
+
+
+def test_split_then_stack_is_the_identity_on_a_hosted_inbox():
+    """What `BatchedRawNode` stages for a round (a campaign, appends
+    with entries, their responses, heartbeats, over three members)
+    carries entries in the append lane alone, so the round's
+    `split_lanes` loses nothing of it; and the hosted round, handed
+    slots, hands slots back."""
+    from etcd_tpu.batched.rawnode import BatchedRawNode
+
+    g = 4
+    # test_deliver_shapes' hosted configuration: the same round-step key.
+    cfg = BatchedConfig(
+        num_groups=g, num_replicas=3, window=16, max_ents_per_msg=4,
+        max_props_per_round=2, election_timeout=1 << 20,
+        heartbeat_timeout=1, narrow_lanes=True, deliver_shape="vectorized")
+    rns = {
+        mid: BatchedRawNode(cfg, groups=np.arange(g, dtype=np.int32),
+                            slots=np.full(g, mid - 1, np.int32))
+        for mid in (1, 2, 3)}
+    staged = []
+    for rn in rns.values():
+        def spy(build=rn._build_inbox):
+            staged.append(build())
+            return staged[-1]
+        rn._build_inbox = spy
+
+    def pump(rounds):
+        for _ in range(rounds):
+            for rn in rns.values():
+                rn.tick()  # heartbeat_timeout 1: a leader beats
+                rd = rn.advance_round()
+                blk = rd.msg_block
+                if blk is not None and len(blk):
+                    for to, sub in sorted(blk.split_by_target().items()):
+                        rns[to].step_block(sub)
+                for row, m in rd.messages:
+                    rns[m.to].step(row, m)
+                rn.advance()
+
+    rns[1].campaign(list(range(g)))
+    pump(4)
+    for k in range(3):
+        for row in range(g):
+            rns[1].propose(row, b"entry-%d-%d" % (k, row))
+        pump(3)
+    assert (np.asarray(rns[1].state.commit) >= 4).all()
+    occupied = np.zeros((NUM_KINDS,), bool)
+    with_entries = 0
+    for inbox in staged:
+        assert isinstance(inbox, MsgSlots)
+        _bits_equal(stack_lanes(split_lanes(inbox)), inbox, "staged inbox")
+        occupied |= np.asarray(inbox.valid).any(axis=(0, 1))
+        with_entries += bool(np.asarray(inbox.ent_terms).any())
+    assert occupied.all(), occupied
+    assert with_entries

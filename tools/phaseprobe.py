@@ -141,11 +141,14 @@ def main() -> int:
             fn, fargs = phase_fns[name]
             seg_s[name] = _time_calls(name, fn, fargs, args.rounds)
             print(f"{name}: {seg_s[name] * 1e3:.3f} ms", flush=True)
-    # route runs on a real outbox (emit's output), like the round does:
-    # the program engine.step_round dispatches (row shifts and selects
-    # along N). Inside a scan it is inlined and fuses with its
-    # neighbours, so this segment is an upper bound there.
-    _st2, outbox = phase_fns["emit"][0](slots, st)
+    # route runs on a real outbox (emit's request lanes and deliver's
+    # response lanes, stacked as the eager round stacks them), like the
+    # round does: the program engine.step_round dispatches (row shifts
+    # and selects along N). Inside a scan it is inlined, by lane, and
+    # fuses with its neighbours, so this segment is an upper bound there.
+    _st1, resps = phase_fns["deliver"][0](iids, slots, st, inbox)
+    _st2, reqs = phase_fns["emit"][0](slots, st)
+    outbox = step_mod.stack_lanes(reqs + resps)
     seg_s["route"] = _time_calls(
         "route", lambda ob: step_mod.route(cfg, ob), (outbox,),
         args.rounds)
