@@ -12,7 +12,12 @@ node hands its leaderships to which, which configuration change is on
 offer, whether reads are asked), so an outage begins and heals, a
 leadership moves, a ReadIndex batch opens and confirms, and a
 configuration change is appended and applied by each replica at its own
-apply point (``BatchedConfig.conf_entries``) inside one program. Inside
+apply point (``BatchedConfig.conf_entries``) inside one program; with
+``BatchedConfig.replace_replicas`` a group may keep a slot empty
+(``MultiRaftEngine(cfg, spare=...)``), a fresh replica joins there as a
+learner and is carried by a snapshot that states the configuration, a
+node is retired and its slot reset (``CTL_RETIRE``, ``CTL_WIPE``), all
+inside the scan. Inside
 a scan the network moves only what
 was sent: inbox and outbox ride as six kind lanes (entries in the
 append lane alone, ``step.split_lanes``) and a round exchanges
@@ -44,7 +49,8 @@ from .compile_cache import enable_compile_cache
 # Never-reused engine identity for transfer-guard warm keys (itertools
 # .count is atomic under the GIL).
 _ENGINE_SERIAL = itertools.count()
-from .state import BatchedConfig, BatchedState, init_state, LEADER, I32
+from .state import (CANDIDATE, CONF_SWAP, LEADER, PRECANDIDATE, REPLICATE,
+                    BatchedConfig, BatchedState, I32, conf_decode, init_state)
 from .step import (MsgSlots, NUM_KINDS, empty_msgs, lane_occupancy,
                    make_step_round, route, route_lanes, split_lanes,
                    stack_lanes)
@@ -62,6 +68,22 @@ from .step import (MsgSlots, NUM_KINDS, empty_msgs, lane_occupancy,
 # counts those that do).
 CTL_FROM, CTL_TO, CTL_CONF, CTL_READS, CTL_STALL = range(5)
 CTL_COLS = 5
+# Two columns more for a configuration with ``replace_replicas``
+# (``control_cols``; every other configuration's schedule, and so its
+# compiled scan, keeps the five): node CTL_RETIRE - 1 is switched off
+# in this round, cut off both ways as ``isolate`` cuts a node off
+# (0: none; a retired machine is no network fault, and the span says
+# which was which), and every replica on node CTL_WIPE - 1 is reset to
+# the empty replica in this round's control phase (0: none): the slot
+# of a retired machine handed to a fresh process. CTL_CONF holds the
+# wide ``state.conf_code`` there, second slot included.
+CTL_RETIRE, CTL_WIPE = 5, 6
+
+
+def control_cols(cfg: BatchedConfig) -> int:
+    """The width of `cfg`'s control schedule."""
+    return CTL_COLS + (2 if cfg.replace_replicas else 0)
+
 
 # What a scan with a control schedule counts in its carry
 # (``MultiRaftEngine.scan_watch``), in this order.
@@ -74,6 +96,28 @@ WATCH_NAMES = (
     # in a round the schedule marks CTL_STALL
     "conf_marks_lost",         # an unapplied change's mark overwritten
 )
+# What it counts besides for a configuration with ``replace_replicas``
+# (``watch_names``), after those.
+REPLACE_WATCH_NAMES = (
+    "outsider_votes_or_campaigns",  # a campaign begun or a vote granted
+    # by a replica whose own configuration does not name it as a voter
+    # (before the round and after it)
+    "swaps_before_ready",      # a CONF_SWAP taken by a leader whose row
+    # for the learner was not REPLICATE with a match at the commit the
+    # leader held as the round began
+    "conf_restores",           # snapshots that restored a configuration
+    "replicas_reset",          # instances the control phase wiped
+    "learner_rounds_short_of_replicate",  # instance-rounds a learner
+    # spent in PROBE or SNAPSHOT in its leader's row
+    "swaps_taken",             # CONF_SWAP entries appended
+)
+
+
+def watch_names(cfg: BatchedConfig):
+    return WATCH_NAMES + (
+        REPLACE_WATCH_NAMES if cfg.replace_replicas else ())
+
+
 # A count is two int32 limbs, low 24 bits and the rest: a round adds at
 # most N to one, and N rounds x instances passes 2^31 inside a run.
 _LIMB = 24
@@ -118,29 +162,34 @@ class MultiRaftEngine:
     the scan's ``rounds`` and ``isolated`` (rounds x nodes its fault
     schedule cut off; 0 with none) and, of its control schedule,
     ``reads`` (rounds x instances asked), ``conf_ops`` and ``transfers``
-    (rows that offer a change, ask for a hand-over; 0 with none) as
+    (rows that offer a change, ask for a hand-over; 0 with none) and,
+    for a configuration with ``replace_replicas``, ``retired`` and
+    ``wipes`` (rounds x nodes switched off, reset; 0 elsewhere) as
     stats. A span ends when the
     program is enqueued: the host's share of a call, not the device's."""
 
-    def __init__(self, cfg: BatchedConfig, start_index: int = 0):
+    def __init__(self, cfg: BatchedConfig, start_index: int = 0,
+                 spare=None):
+        """`spare` (``cfg.replace_replicas``): the slot every group
+        leaves empty, or one a group as [G]: ``state.init_state``."""
         self._serial = next(_ENGINE_SERIAL)
         self._calls = 0  # spans opened: the id the next one takes
         with self._span("engine.init"):
-            self._init(cfg, start_index)
+            self._init(cfg, start_index, spare)
 
     def _span(self, name: str, **stats) -> "spans.Span":
         call = self._calls
         self._calls = call + 1
         return spans.span(name, 0, call, engine=self._serial, **stats)
 
-    def _init(self, cfg: BatchedConfig, start_index: int) -> None:
+    def _init(self, cfg: BatchedConfig, start_index: int, spare) -> None:
         # deliver_shape="auto" becomes "vectorized" here, so self.cfg
         # reads as the compile key does.
         self.cfg = cfg = cfg.validate().resolved()
         # Round programs are expensive to build; cache compilations
         # across processes.
         enable_compile_cache()
-        self.state = init_state(cfg, start_index)
+        self.state = init_state(cfg, start_index, spare=spare)
         self.inbox = empty_msgs(
             (cfg.num_instances, cfg.num_replicas, NUM_KINDS),
             cfg.max_ents_per_msg,
@@ -148,19 +197,20 @@ class MultiRaftEngine:
         )
         self._step = make_step_round(cfg)
 
-        def step_round(st, inbox, *masks, conf_req=None):
+        def step_round(st, inbox, *masks, conf_req=None, wipe=None):
             # The eager round hands the round program what the scan
             # hands it, lanes and their occupancy, so the two share
             # one trace of it (a cold start traces the round once,
             # not twice). `_step` is read when this is first traced.
             # `conf_req` is given for a configuration with
-            # conf_entries alone; None is no input.
+            # conf_entries alone, `wipe` for one with
+            # replace_replicas; None is no input.
             # Handed lanes it answers in lanes; route(), a program
             # of its own here, takes them stacked.
             lanes = split_lanes(inbox)
             out = self._step(st, lanes, *masks,
                              lane_any=lane_occupancy(lanes),
-                             conf_req=conf_req)
+                             conf_req=conf_req, wipe=wipe)
             return (out[0], stack_lanes(out[1])) + out[2:]
 
         self._round = jax.jit(step_round)
@@ -242,6 +292,7 @@ class MultiRaftEngine:
                     for s in range(cfg.num_replicas):
                         iso = iso | ((slots == s) & cut[s])
                 transfer, reads, conf = self._zeros_i, self._zeros_b, None
+                wipe = None
                 # jitlint: waive(tracer-branch) -- as above
                 if ctl is not None:
                     # The row's few scalars widened the same way.
@@ -250,16 +301,20 @@ class MultiRaftEngine:
                     reads = jnp.broadcast_to(ctl[CTL_READS] != 0, (n,))
                     if cfg.conf_entries:
                         conf = jnp.where(drained, 0, ctl[CTL_CONF])
+                    if cfg.replace_replicas:
+                        iso = iso | (slots == ctl[CTL_RETIRE] - 1)
+                        wipe = slots == ctl[CTL_WIPE] - 1
                     pre = st
                 out = self._step(
                     st, inbox, ticks, self._zeros_b, props, iso,
                     transfer, reads, lane_any=occ, conf_req=conf,
+                    wipe=wipe,
                 )
                 st, outbox = out[:2]
                 # jitlint: waive(tracer-branch) -- as above
                 if ctl is not None:
                     watch = self._watch_round(
-                        watch, pre, st, slots, ctl[CTL_STALL] != 0)
+                        watch, pre, st, slots, ctl[CTL_STALL] != 0, wipe)
                 if cfg.telemetry:
                     fr = out[self._tel_pos]
                     tel = (tel[0] + fr.counters, tel[1] | fr.invariants)
@@ -320,11 +375,12 @@ class MultiRaftEngine:
         self._wkey_step = f"round_step/{hash((cfg, False, n))}"
 
     def _watch_round(self, watch: ScanWatch, pre, st, slots,
-                     stall) -> ScanWatch:
+                     stall, wiped=None) -> ScanWatch:
         """One round of a controlled scan counted into its ScanWatch:
         `pre` and `st` are the state before and after the round, `stall`
-        the row's CTL_STALL. Runs inside the scan's body, outside the
-        instance vmap, on whole [N] fields."""
+        the row's CTL_STALL, `wiped` (``replace_replicas``) the
+        instances its control phase reset. Runs inside the scan's body,
+        outside the instance vmap, on whole [N] fields."""
         r = self.cfg.num_replicas
 
         def group_max(x):
@@ -362,6 +418,9 @@ class MultiRaftEngine:
             lost,
         ]
         add = jnp.stack([jnp.sum(e.astype(I32)) for e in events])
+        if self.cfg.replace_replicas:
+            add = jnp.concatenate(
+                [add, self._replace_events(pre, st, slots, wiped)])
         low = watch.counts[:, 1] + add
         counts = jnp.stack(
             [watch.counts[:, 0] + (low >> _LIMB), low & ((1 << _LIMB) - 1)],
@@ -375,6 +434,42 @@ class MultiRaftEngine:
             history = (history ^ v.astype(jnp.uint32)) * jnp.uint32(_FNV)
         return ScanWatch(counts, floor, history)
 
+    def _replace_events(self, pre, st, slots, wiped) -> jnp.ndarray:
+        """REPLACE_WATCH_NAMES' events of one round, counted, from the
+        state before and after it: nothing here is carried by the
+        round."""
+        cfg = self.cfg
+        peers = jnp.arange(cfg.num_replicas, dtype=I32)[None, :]
+        own = peers == slots[:, None]
+
+        def votes_here(s):  # its own configuration names it as a voter
+            return jnp.any((s.voter | s.voter_out) & own, axis=1)
+
+        def cand(role):
+            return (role == CANDIDATE) | (role == PRECANDIDATE)
+
+        leads = st.role == LEADER
+        campaigned = (cand(st.role) & ~cand(pre.role)) | (
+            leads & (pre.role != LEADER))
+        voted = ((st.vote != 0) & (st.vote != slots + 1)
+                 & ((st.vote != pre.vote) | (st.term != pre.term)))
+        kind, learner, _ = conf_decode(st.conf.op)
+        taken = (leads & (st.conf.index != pre.conf.index)
+                 & (st.conf.index == st.conf.pending) & (kind == CONF_SWAP))
+        ready = jnp.any(
+            (peers == learner[:, None]) & (st.pr_state == REPLICATE)
+            & (st.match >= pre.commit[:, None]), axis=1)
+        # A restore leaves the log empty at the snapshot's index; no
+        # compaction does (a floor stays half a ring behind `last`).
+        restored = (st.snap_index > pre.snap_index) & (
+            st.snap_index == st.last)
+        short = leads[:, None] & st.learner & (st.pr_state != REPLICATE)
+        events = [
+            ~votes_here(pre) & ~votes_here(st) & (campaigned | voted),
+            taken & ~ready, restored, wiped, short, taken,
+        ]
+        return jnp.stack([jnp.sum(e.astype(I32)) for e in events])
+
     # -- driving --------------------------------------------------------------
 
     def step_round(
@@ -386,13 +481,15 @@ class MultiRaftEngine:
         transfer_to: Optional[jnp.ndarray] = None,
         read_req: Optional[jnp.ndarray] = None,
         conf_req: Optional[jnp.ndarray] = None,
+        wipe: Optional[jnp.ndarray] = None,
     ) -> None:
         """One round: deliver pending messages, optionally tick every
-        instance, run host control ops (leader transfer, ReadIndex and,
-        for a configuration with ``conf_entries``, the configuration
-        change on offer, `conf_req`: [N] ``state.conf_code``), append
-        proposals on leaders, route the outbox. `isolate` cuts
-        instances off the network for this round."""
+        instance, run host control ops (leader transfer, ReadIndex,
+        for a configuration with ``conf_entries`` the configuration
+        change on offer, `conf_req`: [N] ``state.conf_code``, and for
+        one with ``replace_replicas`` the replica reset, `wipe`: [N]
+        bool), append proposals on leaders, route the outbox. `isolate`
+        cuts instances off the network for this round."""
         ticks = (
             jnp.ones_like(self._zeros_b) if tick else self._zeros_b
         )
@@ -408,13 +505,18 @@ class MultiRaftEngine:
                 "conf_req needs a configuration with conf_entries")
         else:
             conf = None
+        if self.cfg.replace_replicas:
+            wipe = wipe if wipe is not None else self._zeros_b
+        elif wipe is not None:
+            raise ValueError(
+                "wipe needs a configuration with replace_replicas")
         # Inside the guard the dispatch must be all-device: any implicit
         # transfer (an eager scalar op, a stray host array) is a hard
         # error when ETCD_TPU_TRANSFER_GUARD=disallow (tests, benches).
         with self._span("engine.step_round"), warm_guard(self._wkey_step):
             out = self._round(
                 self.state, self.inbox, ticks, camp, props, iso,
-                transfer, reads, conf_req=conf,
+                transfer, reads, conf_req=conf, wipe=wipe,
             )
             self.state, outbox = out[:2]
             if self.cfg.telemetry:
@@ -462,12 +564,14 @@ class MultiRaftEngine:
         """(device schedule or None, span stats) of a call's control
         plane."""
         if control is None:
-            return None, {"reads": 0, "conf_ops": 0, "transfers": 0}
+            return None, {"reads": 0, "conf_ops": 0, "transfers": 0,
+                          "wipes": 0, "retired": 0}
         ctl = np.asarray(control)
-        if ctl.shape != (rounds, CTL_COLS) or ctl.dtype.kind not in "iu":
+        cols = control_cols(self.cfg)
+        if ctl.shape != (rounds, cols) or ctl.dtype.kind not in "iu":
             raise ValueError(
                 f"control must be integers [rounds, CTL_COLS] = "
-                f"{(rounds, CTL_COLS)}, got {ctl.dtype} {ctl.shape}")
+                f"{(rounds, cols)}, got {ctl.dtype} {ctl.shape}")
         if ctl[:, CTL_CONF].any() and not self.cfg.conf_entries:
             raise ValueError(
                 "the control schedule offers a configuration change: "
@@ -478,10 +582,13 @@ class MultiRaftEngine:
             "conf_ops": int((ctl[:, CTL_CONF] != 0).sum()),
             "transfers": int(((ctl[:, CTL_FROM] != 0)
                               & (ctl[:, CTL_TO] != 0)).sum()),
+            # rounds x nodes, as `isolated` counts.
+            "wipes": int((ctl[:, CTL_WIPE:] != 0).sum()),
+            "retired": int((ctl[:, CTL_RETIRE:CTL_WIPE] != 0).sum()),
         }
         if self._watch is None:
             self._watch = ScanWatch(
-                jnp.zeros((len(WATCH_NAMES), 2), I32),
+                jnp.zeros((len(watch_names(self.cfg)), 2), I32),
                 jnp.zeros((self.cfg.num_instances,), I32),
                 jnp.zeros((self.cfg.num_instances,), jnp.uint32))
         return jnp.asarray(ctl, I32), stats
@@ -696,13 +803,15 @@ class MultiRaftEngine:
 
     def scan_watch(self) -> dict:
         """What the scans with a control schedule counted, by
-        WATCH_NAMES, since the engine was built (all zero before the
-        first such scan). One host gather; no per-round sync."""
+        ``watch_names(cfg)``, since the engine was built (all zero
+        before the first such scan). One host gather; no per-round
+        sync."""
+        names = watch_names(self.cfg)
         if self._watch is None:
-            return dict.fromkeys(WATCH_NAMES, 0)
+            return dict.fromkeys(names, 0)
         c = np.asarray(self._watch.counts).astype(np.int64)
         return {name: int((c[i, 0] << _LIMB) + c[i, 1])
-                for i, name in enumerate(WATCH_NAMES)}
+                for i, name in enumerate(names)}
 
     def scan_history(self) -> np.ndarray:
         """[N] uint32: each instance's state after every round of every

@@ -29,7 +29,8 @@ from ..raft.types import (
     Message,
     MessageType,
 )
-from .state import CONF_DEMOTE, CONF_LEAVE, CONF_PROMOTE
+from .state import (CONF_ADD_LEARNER, CONF_DEMOTE, CONF_LEAVE, CONF_PROMOTE,
+                    CONF_SWAP, conf_decode)
 from .step import (
     KIND_APP,
     KIND_APP_RESP,
@@ -127,14 +128,26 @@ class DeviceHashRand:
 
 
 def conf_change(code: int) -> ConfChangeV2:
-    """The ConfChangeV2 a device ``state.conf_code`` stands for."""
-    kind, node = code & 3, (code >> 2) + 1
+    """The ConfChangeV2 a device ``state.conf_code`` stands for (the
+    wide kinds too: a narrow code decodes as itself)."""
+    kind, slot, slot2 = conf_decode(code)
+    node, node2 = slot + 1, slot2 + 1
     if kind == CONF_LEAVE:
         return ConfChangeV2()
+    if kind == CONF_ADD_LEARNER:  # a simple change: no joint configuration
+        return ConfChangeV2(changes=[ConfChangeSingle(
+            type=ConfChangeType.ConfChangeAddLearnerNode, node_id=node)])
+    explicit = ConfChangeTransition.ConfChangeTransitionJointExplicit
+    if kind == CONF_SWAP:
+        return ConfChangeV2(transition=explicit, changes=[
+            ConfChangeSingle(type=ConfChangeType.ConfChangeAddNode,
+                             node_id=node),
+            ConfChangeSingle(type=ConfChangeType.ConfChangeRemoveNode,
+                             node_id=node2)])
     how = {CONF_DEMOTE: ConfChangeType.ConfChangeAddLearnerNode,
            CONF_PROMOTE: ConfChangeType.ConfChangeAddNode}[kind]
     return ConfChangeV2(
-        transition=ConfChangeTransition.ConfChangeTransitionJointExplicit,
+        transition=explicit,
         changes=[ConfChangeSingle(type=how, node_id=node)])
 
 
@@ -166,7 +179,14 @@ class ShadowCluster:
         max_ents: Optional[int] = None,
         deliver_shape: str = "auto",
         max_props: int = 0,
+        spare: Optional[int] = None,
+        replace: bool = False,
     ):
+        # `spare` and `replace` are the device's
+        # ``BatchedConfig.replace_replicas`` (with init_state's
+        # `spare`): slot `spare` starts as a RawNode over empty storage
+        # that no configuration names; a snapshot is taken at the
+        # applied index and states the configuration.
         # The delivery order. The program has one, "vectorized" (step.py
         # _deliver_vectorized; "auto" names it too, as in BatchedConfig):
         # see _deliver_vectorized_target below. "lanes" (kind-major,
@@ -178,18 +198,22 @@ class ShadowCluster:
             deliver_shape = "vectorized"
         self.deliver_shape = deliver_shape
         self.r = num_replicas
-        self.nodes: List[RawNode] = []
+        self.replace = replace
         lrn = {s + 1 for s in learners}
-        for slot in range(num_replicas):
+        seated = ConfState(
+            voters=[i for i in range(1, num_replicas + 1)
+                    if i not in lrn and i - 1 != spare],
+            learners=sorted(lrn))
+
+        def fresh_node(slot: int, conf_state=None) -> RawNode:
             storage = MemoryStorage()
             # Bootstrap the full-voter config the way the batched engine
-            # does: membership is initial state, not replayed conf changes.
-            storage._snapshot.metadata.conf_state = ConfState(
-                voters=[i for i in range(1, num_replicas + 1)
-                        if i not in lrn],
-                learners=sorted(lrn),
-            )
-            cfg = Config(
+            # does: membership is initial state, not replayed conf
+            # changes. A fresh replica (the spare slot, a wiped one)
+            # has none: its storage is empty.
+            if conf_state is not None:
+                storage._snapshot.metadata.conf_state = conf_state
+            return RawNode(Config(
                 id=slot + 1,
                 election_tick=election_timeout,
                 heartbeat_tick=heartbeat_timeout,
@@ -200,16 +224,23 @@ class ShadowCluster:
                 check_quorum=check_quorum,
                 rand=(DeviceHashRand(group * num_replicas + slot)
                       if deterministic_timeouts else None),
-            )
-            self.nodes.append(RawNode(cfg))
+            ))
+
+        self._fresh_node = fresh_node
+        self.nodes: List[RawNode] = [
+            fresh_node(slot, None if slot == spare else seated)
+            for slot in range(num_replicas)]
+        # Slot numbers are reused by fresh replicas where upstream
+        # gives a new member a new id: the votes each slot has cast,
+        # term -> for whom, over every replica it has held (`round`
+        # raises if a slot ever votes for two in one term).
+        self.votes_cast: List[Dict[int, int]] = [
+            {} for _ in range(num_replicas)]
         self.auto_compact_window = auto_compact_window
         # Device per-message entry cap E: etcd's MaxSizePerMsg counted
         # in entries. The sender's own log fetch is capped, so its
         # progress tracks what was sent (no truncation in the network).
         self.max_ents = max_ents
-        if max_ents is not None:
-            for node in self.nodes:
-                self._one_lane_a_round(node.raft)
         # Device per-round proposal cap P: with the ring's W it bounds
         # what a leader admits of an `offer` (see `_admitted`).
         self.max_props = max_props
@@ -220,10 +251,27 @@ class ShadowCluster:
         self.reads = [_ReadView() for _ in self.nodes]
         self.conf_applied_to = [0] * num_replicas
         self.conf_applied = [0] * num_replicas  # changes applied, counted
-        for node, view in zip(self.nodes, self.reads):
-            self._reset_kills_the_read(node.raft, view)
+        for slot in range(num_replicas):
+            self._wire(slot)
         # inbox[target][sender][kind]
         self.inbox: List[List[List[Optional[Message]]]] = self._empty_inbox()
+
+    def _wire(self, slot: int) -> None:
+        """What the emulation hangs on a node's raft, for the node the
+        slot holds now."""
+        r = self.nodes[slot].raft
+        if self.max_ents is not None:
+            self._one_lane_a_round(r)
+        self._reset_kills_the_read(r, self.reads[slot])
+
+    def _wipe(self, slot: int) -> None:
+        """The device's replica reset (step._control's `wipe`): the
+        slot's machine is gone and a fresh process over empty storage
+        has its place."""
+        self.nodes[slot] = self._fresh_node(slot)
+        self.reads[slot] = _ReadView()
+        self.conf_applied_to[slot] = 0
+        self._wire(slot)
 
     @staticmethod
     def _reset_kills_the_read(r, view: "_ReadView") -> None:
@@ -253,6 +301,7 @@ class ShadowCluster:
         conf=0,
         drained: Optional[int] = None,
         transfer_to: Optional[int] = None,
+        wipe: Optional[int] = None,
     ) -> None:
         """One round with the device's phase order:
         deliver → tick/campaign → control → propose → emit.
@@ -266,7 +315,10 @@ class ShadowCluster:
         configuration change on offer (a ``state.conf_code``) to every
         replica but those of node `drained`, which is asked to hand
         its leaderships to slot `transfer_to` instead (`conf` as a
-        dict offers slot -> code, as ``step_round(conf_req=...)`` can)."""
+        dict offers slot -> code, as ``step_round(conf_req=...)`` can).
+        `wipe` resets that slot's replica as the control phase begins
+        (engine.CTL_WIPE; a retired node, engine.CTL_RETIRE, is one of
+        `isolate` here)."""
         iso = set(isolate)
         proposals = dict(proposals or {})
         if self.max_ents is not None:
@@ -318,6 +370,8 @@ class ShadowCluster:
         # _control phase (after tick, before propose): the apply point
         # of a configuration change, transfers, reads, the change on
         # offer.
+        if wipe is not None:
+            self._wipe(wipe)
         for slot in range(self.r):
             self._apply_conf_changes(slot)
         if drained is not None and transfer_to is not None:
@@ -379,7 +433,10 @@ class ShadowCluster:
         # the top of _emit with this round's commit and log, and its
         # append-vs-snapshot decision sees the new floor (step.py
         # _emit auto_compact then snap_needed).
-        if self.auto_compact_window:
+        if self.auto_compact_window and self.replace:
+            for slot in range(self.r):
+                self._compact(slot)
+        elif self.auto_compact_window:
             keep = self.auto_compact_window // 2
             for node in self.nodes:
                 r = node.raft
@@ -396,6 +453,13 @@ class ShadowCluster:
             node = self.nodes[slot]
             for m in rd.messages:
                 if slot in iso:
+                    continue
+                if (m.to not in node.raft.prs.progress
+                        and m.term == node.raft.term and self._leads(slot)):
+                    # Queued (a tick's heartbeat, a commit's broadcast)
+                    # before this round's apply point deleted the
+                    # peer's row: the device's emit sends to the rows
+                    # it has when the messages leave.
                     continue
                 m = self._rematerialize(node, m)
                 kind = _TYPE_TO_KIND.get(m.type)
@@ -451,6 +515,40 @@ class ShadowCluster:
                 self.inbox[target][slot][kind] = m
         for slot, rd in readys:
             self.nodes[slot].advance(rd)
+        for slot, node in enumerate(self.nodes):
+            r = node.raft
+            if r.vote and self.votes_cast[slot].setdefault(
+                    r.term, r.vote) != r.vote:
+                raise AssertionError(
+                    f"slot {slot} voted for {r.vote} in term {r.term} where "
+                    f"it had voted for {self.votes_cast[slot][r.term]}: a "
+                    "reused slot voted twice")
+
+    def _applied(self, slot: int) -> int:
+        """The device's `applied` after emit: the commit index, or the
+        entry before a configuration change this replica has yet to
+        apply."""
+        log = self.nodes[slot].raft.raft_log
+        lo = max(self.conf_applied_to[slot], log.first_index() - 1)
+        if log.committed > lo:
+            for e in log.slice(lo + 1, log.committed + 1, 1 << 62):
+                if e.type == EntryType.EntryConfChangeV2:
+                    return e.index - 1
+        return log.committed
+
+    def _compact(self, slot: int) -> None:
+        """step._emit's compaction with `replace`: the snapshot a
+        replica would send is taken at its applied index and states the
+        configuration it holds there (etcd snapshots at the applied
+        index and compacts behind it); the floor is auto_compact's."""
+        r = self.nodes[slot].raft
+        st = r.raft_log.storage
+        applied = self._applied(slot)
+        target = min(applied, st.last_index() - self.auto_compact_window // 2)
+        if applied > st._snapshot.metadata.index:
+            st.create_snapshot(applied, r.prs.conf_state(), b"")
+        if target > st.first_index() - 1:
+            st.compact(target)
 
 
     # -- the control phase (device: step._control) -----------------------------
@@ -504,12 +602,22 @@ class ShadowCluster:
         node = self.nodes[slot]
         r = node.raft
         cfg = r.prs.config
-        kind, who = code & 3, (code >> 2) + 1
+        kind, slot1, slot2 = conf_decode(code)
+        who, whom = slot1 + 1, slot2 + 1
         joint = bool(cfg.voters.outgoing)
         if kind == CONF_LEAVE:
             fits = joint
         elif kind == CONF_DEMOTE:
             fits = not joint and who in cfg.voters.incoming
+        elif kind == CONF_ADD_LEARNER:
+            fits = not joint and who not in r.prs.progress
+        elif kind == CONF_SWAP:
+            # The stand-in for isLearnerReady: the leader's row for the
+            # learner is REPLICATE.
+            fits = (not joint and who in cfg.learners
+                    and whom in cfg.voters.incoming
+                    and r.prs.progress[who].state
+                    == ProgressStateType.StateReplicate)
         else:
             fits = not joint and who in cfg.learners
         held = r.raft_log.last_index() - (
@@ -673,6 +781,10 @@ class ShadowCluster:
         floor = r.raft_log.storage.first_index() - 1
         if m.index < floor:
             snap = r.raft_log.storage.snapshot()
+            if self.replace:
+                # The device's emit leaves the peer's row waiting on
+                # the snapshot it sends.
+                r.prs.progress[m.to].become_snapshot(snap.metadata.index)
             return Message(
                 type=MessageType.MsgSnap, to=m.to, from_=m.from_,
                 term=m.term, snapshot=snap,

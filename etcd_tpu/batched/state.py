@@ -17,6 +17,7 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -37,6 +38,9 @@ MAX_WIRE_ENTS = 255
 # Up to here (floor(sqrt(2^31)); powers of two apart) the sum of two
 # products of residues stays below 2^32 (rand_timeout).
 MAX_HASHED_TIMEOUT = 46340
+# A snapshot that states its configuration (cfg.replace_replicas) packs
+# two masks into one int32, 16 bits each, the sign bit left alone.
+MAX_SNAPSHOT_SLOTS = 15
 
 
 class BatchedConfig(NamedTuple):
@@ -135,6 +139,18 @@ class BatchedConfig(NamedTuple):
     # message carries the marker, and the compiled round and closed
     # loop are the programs they were.
     conf_entries: bool = False
+    # Replicas that are born and retired (needs conf_entries): a group
+    # may start with an empty slot (init_state's `spare`), a change may
+    # add a slot that is in nobody's masks as a learner (initProgress)
+    # or swap a learner for a voter in one joint change of two ops
+    # (conf_code's wide kinds), a snapshot states the configuration of
+    # the index it stands at and the replica that restores it takes
+    # that configuration (step._handle_snapshot), and the control phase
+    # can reset a replica to the empty one (the schedule's wipe).
+    # Static, default off, the contract of `telemetry` and
+    # `conf_entries`: off, the compiled round and closed loop are the
+    # programs they were.
+    replace_replicas: bool = False
 
     @property
     def num_instances(self) -> int:
@@ -162,6 +178,15 @@ class BatchedConfig(NamedTuple):
             raise ValueError(
                 f"deliver_shape={self.deliver_shape!r}: deliver has one "
                 "order, 'vectorized' ('auto' names it too)")
+        if self.replace_replicas and not self.conf_entries:
+            raise ValueError(
+                "replace_replicas needs conf_entries: a replica enters and "
+                "leaves through entries of the device's log")
+        if self.replace_replicas and self.num_replicas > MAX_SNAPSHOT_SLOTS:
+            raise ValueError(
+                f"num_replicas={self.num_replicas} with replace_replicas: a "
+                "snapshot states its four membership masks as two 16-bit "
+                f"halves of two int32 fields (1..{MAX_SNAPSHOT_SLOTS})")
         et = self.election_timeout
         if et < 1 or (et > MAX_HASHED_TIMEOUT and et & (et - 1)):
             raise ValueError(
@@ -223,10 +248,31 @@ class BatchedConfig(NamedTuple):
 # {JointExplicit, [AddNode slot]}, CONF_LEAVE is the empty change that
 # leaves a joint configuration. 0 is no change.
 CONF_NONE, CONF_DEMOTE, CONF_LEAVE, CONF_PROMOTE = 0, 1, 2, 3
+# The wide kinds (cfg.replace_replicas): CONF_ADD_LEARNER is the simple
+# change {[AddLearnerNode slot]} for a slot in nobody's masks (a fresh
+# replica joins as a learner, no joint configuration), CONF_SWAP is
+# {JointExplicit, [AddNode slot, RemoveNode slot2]}: the learner `slot`
+# takes the voter `slot2`'s place in one joint change of two ops
+# (upstream's confchange_v2_replace_leader.txt).
+CONF_ADD_LEARNER, CONF_SWAP = 4, 5
+_CONF_WIDE_BIT, _CONF_SLOT2_SHIFT, _CONF_SLOT_MASK = 9, 10, 127
 
 
-def conf_code(kind: int, slot: int = 0) -> int:
-    return kind | (slot << 2)
+def conf_code(kind: int, slot: int = 0, slot2: int = 0) -> int:
+    """kind's low two bits | slot << 2 | kind's third bit << 9 |
+    slot2 << 10: for the four narrow kinds `kind | slot << 2` as ever
+    (nothing above bit 8 is set, so `code & 3` and `code >> 2` read
+    them), for the wide kinds the same fields under masks
+    (`conf_decode`). A slot is below 127 (validate())."""
+    return ((kind & 3) | (slot << 2) | ((kind >> 2) << _CONF_WIDE_BIT)
+            | (slot2 << _CONF_SLOT2_SHIFT))
+
+
+def conf_decode(code):
+    """(kind, slot, slot2) of a wide `conf_code`, on ints or arrays."""
+    kind = (code & 3) | (((code >> _CONF_WIDE_BIT) & 1) << 2)
+    return (kind, (code >> 2) & _CONF_SLOT_MASK,
+            code >> _CONF_SLOT2_SHIFT)
 
 
 class ConfLanes(NamedTuple):
@@ -244,7 +290,10 @@ class ConfLanes(NamedTuple):
     of that commit (the scan counts a mark overwritten unapplied)."""
 
     index: jnp.ndarray  # [N] i32: index of the unapplied change; 0 none
-    op: jnp.ndarray  # [N] i32: its conf_code
+    # [N] i32: its conf_code; with cfg.replace_replicas the wide code
+    # (conf_decode: two slots, six kinds), which still rides an append's
+    # `ctx` as it is.
+    op: jnp.ndarray
     # raft.pendingConfIndex (ref: raft.go:1043-1077, becomeLeader): a
     # leader takes no change while this lies above `applied`. The
     # index of the change it last appended, its last index as it won
@@ -428,10 +477,16 @@ def rand_timeout(cfg: BatchedConfig, iid, reset_count):
 
 
 def init_state(cfg: BatchedConfig, start_index: int = 0,
-               iids=None) -> BatchedState:
+               iids=None, spare=None) -> BatchedState:
     """All groups bootstrapped as followers at term 0 with R voters, log
     beginning at start_index (mirrors add-nodes bootstrap-from-snapshot,
-    ref: rafttest/interaction_env_handler_add_nodes.go).
+    ref: rafttest/interaction_env_handler_add_nodes.go). With
+    cfg.replace_replicas and `spare` (a slot, or one a group as [G]; -1
+    none) a group has R - 1 voters and one empty slot: the spare is in
+    nobody's masks, its own included, and its replica is what a fresh
+    RawNode over empty storage is (no log whatever `start_index`, term
+    0, no configuration): the rows `empty_replica` gives, which is also
+    what the control phase's wipe leaves behind.
 
     `iids` (optional) gives each row its global instance id
     (group*R + slot): a hosting process that owns one replica slot of
@@ -500,6 +555,43 @@ def init_state(cfg: BatchedConfig, start_index: int = 0,
         st = ConfBatchedState(*st, conf=ConfLanes(
             index=zeros_n(), op=zeros_n(), pending=zeros_n(),
             learner_next=jnp.zeros((n, r), bool)))
+    if spare is not None:
+        if not cfg.replace_replicas:
+            raise ValueError(
+                "a spare slot needs a configuration with replace_replicas")
+        spare = jnp.broadcast_to(
+            jnp.asarray(spare, I32), (cfg.num_groups,))[iids // r]  # [n]
+        empty = empty_replica(cfg, st, iids)
+        is_spare = (iids % r) == spare
+        seated = jnp.arange(r, dtype=I32)[None, :] != spare[:, None]
+        st = st._replace(voter=st.voter & seated)
+        st = jax.tree.map(
+            lambda e, x: jnp.where(
+                is_spare.reshape((n,) + (1,) * (x.ndim - 1)), e, x),
+            empty, st)
     if cfg.narrow_lanes:
         st = narrow_state(st)
+    return st
+
+
+def empty_replica(cfg: BatchedConfig, like: BatchedState, iid):
+    """The state of a replica that holds nothing, in the shapes and
+    dtypes of `like` (a whole state, or one instance's slice of it
+    under the round's vmap) for the instance ids `iid`: a fresh RawNode
+    over empty storage. Term 0, no log, no configuration (so it neither
+    campaigns nor is counted), every lane as init_state makes it but
+    the masks, which are empty, and the timeout, drawn at reset count 0
+    as a new process draws its first."""
+    fresh = {
+        "role": FOLLOWER, "read_index": -1, "votes": -1, "next": 1,
+        "pr_state": PROBE,
+    }
+    st = like._replace(**{
+        f: jnp.full_like(getattr(like, f), fresh.get(f, 0))
+        for f in BatchedState._fields})
+    st = st._replace(
+        randomized_timeout=rand_timeout(cfg, iid, 0).astype(
+            like.randomized_timeout.dtype))
+    if cfg.conf_entries:
+        st = st._replace(conf=jax.tree.map(jnp.zeros_like, like.conf))
     return st

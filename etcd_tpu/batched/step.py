@@ -59,9 +59,11 @@ from ..obs.fleet import FLEET_BUCKETS, FleetLayout
 from .telemetry import NUM_COUNTERS
 from .state import (
     CANDIDATE,
+    CONF_ADD_LEARNER,
     CONF_DEMOTE,
     CONF_LEAVE,
     CONF_PROMOTE,
+    CONF_SWAP,
     FOLLOWER,
     LEADER,
     PRECANDIDATE,
@@ -71,6 +73,8 @@ from .state import (
     BatchedConfig,
     BatchedState,
     I32,
+    conf_decode,
+    empty_replica,
     narrow_state,
     rand_timeout as _rand_timeout,
     widen_state,
@@ -474,7 +478,7 @@ def _lane_app(cfg: BatchedConfig, iid, slot, st: BatchedState, m: MsgSlots,
 
     fol = _leader_traffic_prelude(cfg, iid, slot, st1, m, from_slot)
     st_app, app_resp = _handle_append(cfg, fol, m)
-    st_snap, snap_resp = _handle_snapshot(cfg, fol, m)
+    st_snap, snap_resp = _handle_snapshot(cfg, fol, m, slot)
     is_snap = m.type == T_SNAP
     leader_traffic_ok = st1.role != LEADER
     st_live = _sel(is_snap, st_snap, st_app)
@@ -602,10 +606,33 @@ def _handle_append(cfg: BatchedConfig, st: BatchedState, m: MsgSlots):
     return st_out, resp
 
 
-def _handle_snapshot(cfg: BatchedConfig, st: BatchedState, m: MsgSlots):
-    """Follower snapshot install (ref: raft.go:1518-1614 restore). The
-    conf state rides host-side; on device membership masks are taken
-    to be current. m.index/m.log_term carry the snapshot (index, term)."""
+def _snapshot_conf_words(st: BatchedState):
+    """The configuration a snapshot states (cfg.replace_replicas), in
+    the two int32 fields a T_SNAP leaves unused: `reject_hint` holds the
+    voters (low half, slot s worth 1 << s) and the outgoing voters (high
+    half), `ctx` the learners and LearnersNext. A configuration is joint
+    exactly when it has outgoing voters (upstream's own definition), so
+    `in_joint` does not travel."""
+    bits = 1 << jnp.arange(st.voter.shape[-1], dtype=I32)
+    word = lambda lo, hi: (  # noqa: E731
+        jnp.sum(jnp.where(lo, bits, 0)) | (jnp.sum(jnp.where(hi, bits, 0)) << 16))
+    return (word(st.voter, st.voter_out & st.in_joint),
+            word(st.learner, st.conf.learner_next))
+
+
+def _handle_snapshot(cfg: BatchedConfig, st: BatchedState, m: MsgSlots,
+                     slot=None):
+    """Follower snapshot install (ref: raft.go:1518-1614 restore).
+    m.index/m.log_term carry the snapshot (index, term). Without
+    cfg.replace_replicas the conf state rides host-side (or, with
+    conf_entries alone, no snapshot is ever sent across a change) and
+    the membership masks are taken to be current. With it the snapshot
+    states the configuration as of its index (_snapshot_conf_words; the
+    sender takes it at its applied index, _emit) and the replica that
+    restores it takes the four masks, `in_joint` and LearnersNext from
+    the message, as raft.restore rebuilds the tracker from the
+    ConfState (confchange.Restore, restore.go:155); like upstream it
+    refuses a snapshot whose configuration does not hold it."""
     no_resp = empty_msgs((), 0)
     ignore = m.index <= st.commit
     ta = lambda i: term_at(st.log_term, st.snap_index, st.snap_term, st.last, i)
@@ -620,12 +647,22 @@ def _handle_snapshot(cfg: BatchedConfig, st: BatchedState, m: MsgSlots):
         commit=m.index,
     )
     if cfg.conf_entries:
-        # A log replaced by a snapshot holds no entry to mark. (The
-        # ConfState a snapshot carries upstream does not travel yet:
-        # the masks stay as they are. ROADMAP.)
+        # A log replaced by a snapshot holds no entry to mark. (Without
+        # replace_replicas the masks stay as they are: the ConfState a
+        # snapshot carries upstream does not travel then.)
         st_restore = st_restore._replace(conf=st.conf._replace(
             index=jnp.zeros_like(st.conf.index),
             op=jnp.zeros_like(st.conf.op)))
+    if cfg.replace_replicas:
+        peers = jnp.arange(st.voter.shape[-1], dtype=I32)
+        half = lambda word, sh: ((word >> (peers + sh)) & 1) == 1  # noqa: E731
+        voter, voter_out = half(m.reject_hint, 0), half(m.reject_hint, 16)
+        learner, learner_next = half(m.ctx, 0), half(m.ctx, 16)
+        ignore = ignore | ~_pick_b(voter | voter_out | learner, peers == slot)
+        st_restore = st_restore._replace(
+            voter=voter, voter_out=voter_out, learner=learner,
+            in_joint=jnp.any(voter_out),
+            conf=st_restore.conf._replace(learner_next=learner_next))
     restored = ~ignore & ~fast_forward
     st_out = _sel(ignore, st, _sel(fast_forward, st_ff, st_restore))
     resp = no_resp._replace(
@@ -1138,12 +1175,20 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
     send_heartbeat = st.send_heartbeat
     st = st._replace(send_heartbeat=jnp.zeros((0,), bool))
     if cfg.conf_entries:
-        # LearnersNext too: only the control phase reads or writes it.
+        # LearnersNext too: only the control phase reads or writes it,
+        # and with replace_replicas a snapshot's restore, so there it
+        # goes through the append lane's cond and round the other five.
         learner_next = st.conf.learner_next
-        st = st._replace(conf=st.conf._replace(
+        without = lambda stx: stx._replace(conf=stx.conf._replace(  # noqa: E731
             learner_next=jnp.zeros((0,), bool)))
+        st = without(st)
     st, r0 = votes(st)
+    if cfg.replace_replicas:
+        st = st._replace(conf=st.conf._replace(learner_next=learner_next))
     st, r1 = request(KIND_APP, _lane_app, st)
+    if cfg.replace_replicas:
+        learner_next = st.conf.learner_next
+        st = without(st)
     st, r2 = request(KIND_HB, _lane_hb, st)
     st = state_only(
         KIND_VOTE_RESP,
@@ -1230,41 +1275,100 @@ def _tick(cfg: BatchedConfig, iid, slot, st: BatchedState, do_tick,
     return _sel(fire, st_camp, st1)
 
 
+def _conf_fields(cfg: BatchedConfig, code, peers):
+    """(kind, the slot a state.conf_code names as a mask over `peers`,
+    its second slot as one): the narrow word as it has always been read
+    (kind in two bits, one slot; the second mask is None), and with
+    cfg.replace_replicas the wide one (state.conf_decode)."""
+    if not cfg.replace_replicas:
+        return code & 3, peers == (code >> 2), None
+    kind, s1, s2 = conf_decode(code)
+    return kind, peers == s1, peers == s2
+
+
 def _conf_apply(cfg: BatchedConfig, slot, st: BatchedState):
     """The apply point of a configuration change (cfg.conf_entries):
     the replica whose commit has reached the change its log holds
     unapplied flips its own masks, leader and follower alike, each in
     its own round (ref: raft.go applyConfChange -> confchange.Changer
-    EnterJoint / LeaveJoint, then switchToConfig). Returns the state
-    and whether a change was applied."""
+    EnterJoint / LeaveJoint / Simple, then switchToConfig). Returns the
+    state and whether a change was applied.
+
+    With cfg.replace_replicas the two wide kinds besides, which let a
+    replica enter and leave (the branches on `wide` below; without it
+    nothing of them is traced). CONF_ADD_LEARNER, a simple change,
+    makes a learner of a slot in nobody's masks and gives it upstream's
+    initProgress (match 0, next the applier's last index, PROBE,
+    recently active: CheckQuorum must not count a replica against the
+    leader before it could answer). CONF_SWAP enters a joint
+    configuration whose incoming half has the learner `slot` for the
+    voter `slot2`; `slot2` stays in the outgoing half, and its row
+    stays, until LeaveJoint, which deletes the row of every slot the
+    configuration no longer names."""
+    wide = cfg.replace_replicas
     r = cfg.num_replicas
     peers = jnp.arange(r, dtype=I32)
     c = st.conf
     due = (c.index > st.applied) & (st.commit >= c.index)
-    kind, at = c.op & 3, peers == (c.op >> 2)
+    kind, at, at2 = _conf_fields(cfg, c.op, peers)
     demote, promote, leave = (
         kind == CONF_DEMOTE, kind == CONF_PROMOTE, kind == CONF_LEAVE)
     enter = demote | promote
+    # Who leaves `learner` for the incoming half, and what the masks
+    # are where no joint kind says otherwise.
+    seated, voter_else, learner_else = promote, st.voter, st.learner
+    if wide:
+        swap = kind == CONF_SWAP
+        enter, seated = enter | swap, promote | swap
+        tracked = _repl_targets(st)
+        born = at & (kind == CONF_ADD_LEARNER) & ~tracked
+        voter_else = jnp.where(swap, (st.voter | at) & ~at2, st.voter)
+        learner_else = st.learner | born
     # EnterJoint copies the voters to the outgoing half, then makes the
     # one change: AddLearnerNode takes a voter out of the incoming half
     # and, as the outgoing half still counts it, parks it in
     # LearnersNext (a slot that was no voter is a learner at once);
     # AddNode makes a voter of a learner. LeaveJoint turns LearnersNext
     # into learners and drops the outgoing half.
+    voter = jnp.where(demote, st.voter & ~at,
+                      jnp.where(promote, st.voter | at, voter_else))
+    voter_out = jnp.where(enter, st.voter, st.voter_out & ~leave)
+    learner = jnp.where(
+        demote, st.learner | (at & ~st.voter),
+        jnp.where(seated, st.learner & ~at,
+                  jnp.where(leave, st.learner | c.learner_next,
+                            learner_else)))
+    in_joint = (st.in_joint | enter) & ~leave
+    parked = c.learner_next | (at & st.voter)
+    waiting = c.learner_next & ~(at & seated)
+    if wide:
+        waiting = waiting & ~(at2 & swap)
     st_new = st._replace(
-        voter=jnp.where(demote, st.voter & ~at,
-                        jnp.where(promote, st.voter | at, st.voter)),
-        voter_out=jnp.where(enter, st.voter, st.voter_out & ~leave),
-        learner=jnp.where(
-            demote, st.learner | (at & ~st.voter),
-            jnp.where(promote, st.learner & ~at,
-                      jnp.where(leave, st.learner | c.learner_next,
-                                st.learner))),
-        in_joint=(st.in_joint | enter) & ~leave,
-        conf=c._replace(learner_next=jnp.where(
-            demote, c.learner_next | (at & st.voter),
-            c.learner_next & ~(at & promote) & ~leave)),
+        voter=voter, voter_out=voter_out, learner=learner,
+        in_joint=in_joint,
+        conf=c._replace(
+            learner_next=jnp.where(demote, parked, waiting & ~leave)),
     )
+    if wide:
+        gone = tracked & ~_repl_targets(st_new)
+        fresh = born | gone
+        st_new = st_new._replace(
+            match=jnp.where(fresh, 0, st_new.match),
+            next=jnp.where(born, st.last, jnp.where(gone, 1, st_new.next)),
+            pr_state=jnp.where(fresh, PROBE, st_new.pr_state),
+            probe_sent=st_new.probe_sent & ~fresh,
+            pending_snapshot=jnp.where(fresh, 0, st_new.pending_snapshot),
+            recent_active=(st_new.recent_active | born) & ~gone,
+            inflight=jnp.where(fresh, 0, st_new.inflight),
+        )
+    return _switch_to_config(cfg, slot, peers, st, st_new, due), due
+
+
+def _switch_to_config(cfg: BatchedConfig, slot, peers, st: BatchedState,
+                      st_new: BatchedState, due):
+    """raft.switchToConfig at a replica's apply point: `st` as it
+    stood, `st_new` with the change made, `due` whether there was one
+    to make."""
     # switchToConfig on a leader that is still a voter of the new
     # configuration: the quorum may have shrunk, so commit and tell
     # everyone, else send what a peer lacks (sendIfEmpty false); a
@@ -1283,7 +1387,7 @@ def _conf_apply(cfg: BatchedConfig, slot, st: BatchedState):
         transferee=jnp.where(keep_transfer, st_lead.transferee, 0),
         transfer_sent=st_lead.transfer_sent & keep_transfer,
     )
-    return _sel(due, _sel(leads_on, st_lead, st_new), st), due
+    return _sel(due, _sel(leads_on, st_lead, st_new), st)
 
 
 def _conf_propose(cfg: BatchedConfig, slot, st: BatchedState, conf_req):
@@ -1301,12 +1405,23 @@ def _conf_propose(cfg: BatchedConfig, slot, st: BatchedState, conf_req):
     r = cfg.num_replicas
     peers = jnp.arange(r, dtype=I32)
     c = st.conf
-    kind, at = conf_req & 3, peers == (conf_req >> 2)
-    fits = jnp.where(
-        kind == CONF_LEAVE, st.in_joint,
-        ~st.in_joint & (
-            ((kind == CONF_DEMOTE) & _pick_b(st.voter, at))
-            | ((kind == CONF_PROMOTE) & _pick_b(st.learner, at))))
+    kind, at, at2 = _conf_fields(cfg, conf_req, peers)
+    leave, outside = kind == CONF_LEAVE, ~st.in_joint
+    enters = (((kind == CONF_DEMOTE) & _pick_b(st.voter, at))
+              | ((kind == CONF_PROMOTE) & _pick_b(st.learner, at)))
+    if cfg.replace_replicas:
+        # The wide kinds too: a learner is added where the slot is in
+        # nobody's masks, and the swap is taken only by a leader whose
+        # row for the learner is REPLICATE: the stand-in for etcd's
+        # isLearnerReady (server.go:1446), which a member promote has
+        # to pass.
+        ready = _pick_b(st.pr_state == REPLICATE, at)
+        enters = (enters
+                  | ((kind == CONF_ADD_LEARNER)
+                     & ~_pick_b(_repl_targets(st), at))
+                  | ((kind == CONF_SWAP) & _pick_b(st.learner, at)
+                     & _pick_b(st.voter, at2) & ready))
+    fits = jnp.where(leave, st.in_joint, outside & enters)
     room = cfg.window - (st.last - st.snap_index) - cfg.max_props_per_round
     accept = (
         (st.role == LEADER) & (st.transferee == 0)
@@ -1323,7 +1438,7 @@ def _conf_propose(cfg: BatchedConfig, slot, st: BatchedState, conf_req):
 
 
 def _control(cfg: BatchedConfig, slot, st: BatchedState, transfer_to,
-             read_req, conf_req=None):
+             read_req, conf_req=None, wipe=None, iid=None):
     """Host control plane: leader-transfer requests and ReadIndex
     rounds (ref: raft.go:1339-1372 stepLeader MsgTransferLeader;
     raft.go:1078-1096 MsgReadIndex + read_only.go addRequest) and,
@@ -1334,11 +1449,18 @@ def _control(cfg: BatchedConfig, slot, st: BatchedState, transfer_to,
     `transfer_to` is slot+1 (0 = none); `read_req` asks the leader to
     open a read batch at its current commit index; `conf_req` is the
     change offered. All are no-ops on non-leaders (the host routes
-    requests to the leader instance). Returns the state and, with
-    cfg.conf_entries, whether a change was applied (else None)."""
+    requests to the leader instance). With cfg.replace_replicas the
+    phase begins with the replica reset: where `wipe` says so the
+    instance becomes the empty replica (state.empty_replica: a retired
+    machine's slot handed to a fresh process over empty storage), and
+    the rest of the phase finds nothing to do on it. Returns the state
+    and, with cfg.conf_entries, whether a change was applied (else
+    None)."""
     r = cfg.num_replicas
     peers = jnp.arange(r, dtype=I32)
     conf_applied = None
+    if cfg.replace_replicas:
+        st = _sel(wipe, empty_replica(cfg, st, iid), st)
     if cfg.conf_entries:
         st, conf_applied = _conf_apply(cfg, slot, st)
     is_leader = st.role == LEADER
@@ -1462,9 +1584,8 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, conf_applied=None):
             st.log_term, st.snap_index, st.snap_term, st.last, i
         )
         keep = cfg.window // 2
-        new_snap = jnp.maximum(
-            st.snap_index, jnp.minimum(st.applied, st.last - keep)
-        )
+        floor = jnp.minimum(st.applied, st.last - keep)
+        new_snap = jnp.maximum(st.snap_index, floor)
         st = st._replace(snap_term=ta0(new_snap), snap_index=new_snap)
 
     ta = lambda i: term_at(st.log_term, st.snap_index, st.snap_term, st.last, i)
@@ -1520,13 +1641,21 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, conf_applied=None):
     ent_mask = j[None, :] < n_send[:, None]
     app = want & ~snap_needed
     snp = want & snap_needed
+    # The snapshot sent is the floor's. One that states the
+    # configuration (cfg.replace_replicas) is taken where this replica
+    # knows it, at its applied index, which is where etcd takes its own
+    # (it snapshots at the applied index and compacts CatchUpEntries
+    # behind it): the masks are as of `applied`, not as of the floor.
+    snap_at, snap_t = st.snap_index, st.snap_term
+    if cfg.replace_replicas:
+        snap_at, snap_t = st.applied, ta(st.applied)
 
     append = empty_msgs((r,), e)._replace(
         valid=app | snp,
         type=per_target(jnp.where(snp, T_SNAP, T_APP)),
         term=per_target(st.term),
-        index=jnp.where(snp, st.snap_index, prev),
-        log_term=jnp.where(snp, st.snap_term, ta(prev)),
+        index=jnp.where(snp, snap_at, prev),
+        log_term=jnp.where(snp, snap_t, ta(prev)),
         commit=per_target(st.commit),
         n_ents=jnp.where(app, n_send, 0),
         ent_terms=jnp.where(ent_mask & app[:, None], ent_terms, 0),
@@ -1542,6 +1671,14 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, conf_applied=None):
             reject_hint=jnp.where(marks, c.index, 0),
             ctx=jnp.where(marks, c.op, 0),
         )
+    if cfg.replace_replicas:
+        # And a snapshot states the configuration in the same two
+        # fields, which it leaves unused as well.
+        voters, learners = _snapshot_conf_words(st)
+        append = append._replace(
+            reject_hint=jnp.where(snp, voters, append.reject_hint),
+            ctx=jnp.where(snp, learners, append.ctx),
+        )
 
     # Progress effects of the sends.
     sent_ents = app & (n_send > 0)
@@ -1556,7 +1693,7 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, conf_applied=None):
             st.inflight,
         ),
         pr_state=jnp.where(snp, SNAPSHOT, st.pr_state),
-        pending_snapshot=jnp.where(snp, st.snap_index, st.pending_snapshot),
+        pending_snapshot=jnp.where(snp, snap_at, st.pending_snapshot),
         send_append=jnp.zeros_like(st.send_append),
         send_heartbeat=jnp.zeros_like(st.send_heartbeat),
         send_vote_req=jnp.zeros_like(st.send_vote_req),
@@ -1927,10 +2064,12 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
 
     def step_round(st: BatchedState, inbox, tick_mask, campaign_mask,
                    propose_n, isolate, transfer_to, read_req, iids, slots,
-                   lane_any=None, conf_req=None):
+                   lane_any=None, conf_req=None, wipe=None):
         # `conf_req` ([N] i32, state.conf_code; cfg.conf_entries only)
         # is the configuration change offered to each instance; None,
-        # an empty pytree, is no input at all.
+        # an empty pytree, is no input at all. `wipe` ([N] bool;
+        # cfg.replace_replicas only) likewise: the instances the
+        # control phase resets to the empty replica.
         # The inbox as [N, R, K] slots (a hosting process's, the eager
         # engine's) or as the K kind lanes of [N, R] the engine's scan
         # carries: deliver takes lanes, and a lane that comes as an
@@ -1965,7 +2104,7 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
             lane_any = lane_occupancy(inbox)  # [K]
 
         def per_instance(iid, slot, sti, inbox_i, do_tick, do_camp, n_new,
-                         iso, tr_to, rd_req, cf_req, lane_any):
+                         iso, tr_to, rd_req, cf_req, wp, lane_any):
             # Partitioned instances neither receive nor send this round
             # (fault injection; ref: tests/framework bridge & pkg/proxy).
             # Phases carry jax.named_scope annotations so xprof/JAX
@@ -1983,7 +2122,7 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
             read_snap = (sti.read_seq, sti.read_index, sti.read_ready)
             with jax.named_scope("raft_control"):
                 sti, conf_applied = _control(
-                    cfg, slot, sti, tr_to, rd_req, cf_req)
+                    cfg, slot, sti, tr_to, rd_req, cf_req, wp, iid)
             last_tick = sti.last
             with jax.named_scope("raft_propose"):
                 sti = _propose(cfg, slot, sti, n_new)
@@ -2037,7 +2176,8 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
             args = jax.tree.map(
                 to_minor,
                 (iids, slots, st, inbox, tick_mask, campaign_mask,
-                 propose_n, isolate, transfer_to, read_req, conf_req),
+                 propose_n, isolate, transfer_to, read_req, conf_req,
+                 wipe),
             )
             outs = jax.vmap(
                 per_instance,
@@ -2046,11 +2186,11 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
             outs = jax.tree.map(to_major, outs)
         else:
             outs = jax.vmap(
-                per_instance, in_axes=(0,) * 11 + (None,),
+                per_instance, in_axes=(0,) * 12 + (None,),
             )(
                 iids, slots, st, inbox, tick_mask, campaign_mask,
                 propose_n, isolate, transfer_to, read_req, conf_req,
-                lane_any,
+                wipe, lane_any,
             )
         sti, out, aux = outs[:3]
         fleet = None
@@ -2095,9 +2235,9 @@ def make_step_round(cfg: BatchedConfig, iids=None, slots=None,
         state, outbox[, aux] = step_round(state, inbox, tick_mask,
                                           campaign_mask, propose_n, isolate)
 
-    (and, by keyword, ``transfer_to``, ``read_req`` and, for a
-    configuration with ``conf_entries``, ``conf_req``: the control
-    phase's three requests).
+    (and, by keyword, ``transfer_to``, ``read_req``, for a
+    configuration with ``conf_entries`` ``conf_req`` and for one with
+    ``replace_replicas`` ``wipe``: what the control phase is asked).
 
     All arrays stay on device; chain with route() for a closed-loop
     multi-raft simulation (the dense all-replica layout), or pass
@@ -2133,17 +2273,23 @@ def make_step_round(cfg: BatchedConfig, iids=None, slots=None,
     zero_b = jnp.zeros((n,), bool)
 
     def step(st, inbox, tick_mask, campaign_mask, propose_n, isolate,
-             transfer_to=None, read_req=None, lane_any=None, conf_req=None):
+             transfer_to=None, read_req=None, lane_any=None, conf_req=None,
+             wipe=None):
         if cfg.conf_entries:
             conf_req = zero_i if conf_req is None else conf_req
         elif conf_req is not None:
             raise ValueError(
                 "conf_req needs a configuration with conf_entries")
+        if cfg.replace_replicas:
+            wipe = zero_b if wipe is None else wipe
+        elif wipe is not None:
+            raise ValueError(
+                "wipe needs a configuration with replace_replicas")
         return inner(st, inbox, tick_mask, campaign_mask, propose_n,
                      isolate,
                      zero_i if transfer_to is None else transfer_to,
                      zero_b if read_req is None else read_req,
-                     iids, slots, lane_any, conf_req)
+                     iids, slots, lane_any, conf_req, wipe)
 
     return step
 

@@ -146,7 +146,23 @@ os.environ.setdefault("ETCD_TPU_TRANSFER_GUARD", "disallow")
 # narrow_lanes=True)): R=5 had no narrow and no lane_skip=False
 # program to hold the two forms against each other. The round handed
 # lanes or slots is two traces of one `jit(step_round)`, no key.
-ROUND_STEP_SHAPE_BUDGET = 48
+# ISSUE 34 AUDIT: 49 used of 50. test_scan_replace adds two programs,
+# both with `replace_replicas` (replicas born and retired on the
+# device, a snapshot that states the configuration: new round text, so
+# new programs): RP4, the values of
+# the benchmark's `engine512k-r3of4` at the CPU tests' 8 groups (R=4,
+# n-minor, telemetry on), which tests/benchmark builds too for the
+# cell's tiny runs, its controls and its broken-path tests (one of
+# which clears step._step_round_jit's cache to swap the snapshot
+# handler: the same key string, counted once); and RP4_MAJOR (n-major,
+# telemetry off, 4 groups), so that both layouts and the plane on and
+# off stand against the oracle. The wipe and the two control columns
+# are inputs of the round and of the closed loop, no key; the four
+# text-for-text cases lower the live configurations at 8 groups, which
+# are keys since ISSUE 28 and 32 (CELL, R5, RC3) but for `engine64k-r3`
+# at 8 groups, which tests/benchmark builds. Budget 48 -> 50: raised by
+# exactly the two, the headroom of 1 that ISSUE 33 left kept.
+ROUND_STEP_SHAPE_BUDGET = 50
 
 
 @pytest.fixture(scope="session", autouse=True)
