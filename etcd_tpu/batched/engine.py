@@ -25,7 +25,20 @@ the lanes some instance of the batch wrote (``step.route_lanes``, on the
 occupancy vector deliver's lane conds skip on); a lane nobody wrote
 holds what ``empty_msgs`` holds, ``valid`` false and every field zero,
 in the scan and in ``eng.inbox`` after it (``lane_rounds()`` counts the
-rounds each lane was occupied, so exchanged). Entry payloads never touch the device: the host keeps them in
+rounds each lane was occupied, so exchanged). A call over more rows
+than one tile holds (``scan_tiles``: TILE_ROWS, from the shape alone)
+runs tile by tile: a tile is a block of whole groups, adjacent rows of
+``eng.state`` (row ``g * R + s`` as ever: the row order does not
+change), stepped through all the call's rounds before the next tile
+sees its first, so that the chip works on a block small enough for its
+fast memory; groups share nothing, so the result is the one scan's.
+What crosses tiles is put together once a call: ``lane_rounds()``
+counts a round for a lane that held a message in any tile, the
+``scan_watch()`` counts add up, the fence is the last tile's; the lane
+skip is each tile's own, so a lane nobody of a tile wrote is zeros on
+that tile's rows. The eager round runs in the same tiles (and donates
+state and inbox there), so a configuration traces the round at one
+shape. Entry payloads never touch the device: the host keeps them in
 an arena keyed by (group, index), and the commit watermarks streaming
 back from the device drive payload application — mirroring how the
 reference applies committed entries after the Ready loop (ref:
@@ -141,6 +154,37 @@ def history_fold(h: int, values) -> int:
     return h
 
 
+# The closed loop steps a tile of whole groups through all of a call's
+# rounds, then the next tile (``scan_tiles``; ``MultiRaftEngine._init``'s
+# ``closed_loop``). A tile holds at most TILE_ROWS instance rows, the
+# one size the chip has priced (PERF.md section 6, "PR 35": a round's
+# intermediates at that size stay in the compiler's fast memory, at
+# millions of rows every fusion streams from HBM), and a multiple of
+# TILE_ALIGN of them: the TPU's T(1024) / T(8,128) tiling of an [N] /
+# [N, W] array, so a tile is a contiguous block and never a relayout.
+TILE_ROWS = 196_608
+TILE_ALIGN = 1_024
+
+
+def scan_tiles(cfg: BatchedConfig) -> int:
+    """How many tiles `cfg`'s closed loop runs in: the fewest, so the
+    largest, that are whole groups, equal, TILE_ALIGN-aligned and no
+    larger than TILE_ROWS. 1 — the one scan over all rows — for a shape
+    of less than two tiles' rows (at 307,200 rows two tiles gained 5.9%
+    a round and cost a quarter more warm set-up, the round traced and
+    fetched at a second shape: PERF.md section 6, "PR 35"), one with no
+    such divisor, and a configuration with ``fleet_summary`` (its frame
+    reduces across rows with fields that do not add)."""
+    n = cfg.num_instances
+    if n < 2 * TILE_ROWS or cfg.fleet_summary:
+        return 1
+    for tiles in range(-(-n // TILE_ROWS), n // TILE_ALIGN + 1):
+        rows, rest = divmod(n, tiles)
+        if not (rest or rows % cfg.num_replicas or rows % TILE_ALIGN):
+            return tiles
+    return 1
+
+
 class ScanWatch(NamedTuple):
     counts: jnp.ndarray  # [len(WATCH_NAMES), 2] i32 (high, low limb)
     # Per instance: the highest commit any replica of its group held at
@@ -159,7 +203,8 @@ class MultiRaftEngine:
     ``engine.init``, ``engine.step_round`` and ``engine.run_rounds``
     (one a scan, so one a chunk of ``run_rounds_pipelined``), with
     member 0, the call's number as ``round`` and the engine's serial,
-    the scan's ``rounds`` and ``isolated`` (rounds x nodes its fault
+    the scan's ``rounds``, its ``tiles`` (``scan_tiles``; 1: one scan
+    over all rows) and ``isolated`` (rounds x nodes its fault
     schedule cut off; 0 with none) and, of its control schedule,
     ``reads`` (rounds x instances asked), ``conf_ops`` and ``transfers``
     (rows that offer a change, ask for a hand-over; 0 with none) and,
@@ -197,6 +242,54 @@ class MultiRaftEngine:
         )
         self._step = make_step_round(cfg)
 
+        n = cfg.num_instances
+        # One scan over all rows, or tile by tile (``scan_tiles``); the
+        # eager round likewise, so that a configuration traces the
+        # round at one shape.
+        self._tiles = tiles = scan_tiles(cfg)
+        rows = n // tiles
+
+        def tile_step(lo, slots):
+            """The round for the rows from `lo` on: the timeout hash
+            reads the row's own id."""
+            return make_step_round(
+                cfg, iids=lo + jnp.arange(rows, dtype=I32), slots=slots)
+
+        def like(x):  # a tile's share of a per-row array, as a shape
+            return jax.ShapeDtypeStruct((rows,) + x.shape[1:], x.dtype)
+
+        def tiled_round(st, lanes, per_row):
+            """The eager round tile by tile (`tiled_loop`'s idiom):
+            `per_row` is (masks, conf_req, wipe), every leaf [N]."""
+            slots = jnp.arange(rows, dtype=I32) % cfg.num_replicas
+
+            def one(lo, st, lanes, per_row):
+                masks, conf_req, wipe = per_row
+                return tile_step(lo, slots)(
+                    st, lanes, *masks, lane_any=lane_occupancy(lanes),
+                    conf_req=conf_req, wipe=wipe)
+
+            # The shapes of what a tile answers, for the outbox and the
+            # frames the loop writes into; and the round traced once
+            # outside any loop (see `tiled_loop`).
+            answer = jax.eval_shape(
+                one, 0, *jax.tree.map(like, (st, lanes, per_row)))[1:]
+            whole = jax.tree.map(
+                lambda x: jnp.zeros((n,) + x.shape[1:], x.dtype), answer)
+
+            def tile(i, carry):
+                lo = i * rows
+                out = one(lo, *jax.tree.map(
+                    lambda x: jax.lax.dynamic_slice_in_dim(x, lo, rows),
+                    (carry[0], lanes, per_row)))
+                return jax.tree.map(
+                    lambda x, y: jax.lax.dynamic_update_slice_in_dim(
+                        x, y, lo, 0),
+                    carry, (out[0], out[1:]))
+
+            st, out = jax.lax.fori_loop(0, tiles, tile, (st, whole))
+            return (st, stack_lanes(out[0])) + out[1:]
+
         def step_round(st, inbox, *masks, conf_req=None, wipe=None):
             # The eager round hands the round program what the scan
             # hands it, lanes and their occupancy, so the two share
@@ -208,13 +301,20 @@ class MultiRaftEngine:
             # Handed lanes it answers in lanes; route(), a program
             # of its own here, takes them stacked.
             lanes = split_lanes(inbox)
+            if tiles > 1:
+                return tiled_round(st, lanes, (masks, conf_req, wipe))
             out = self._step(st, lanes, *masks,
                              lane_any=lane_occupancy(lanes),
                              conf_req=conf_req, wipe=wipe)
             return (out[0], stack_lanes(out[1])) + out[2:]
 
-        self._round = jax.jit(step_round)
-        n = cfg.num_instances
+        # In tiles the loop's carry is the state: donated, it is updated
+        # in place as the scan's is (`step_round` below reassigns state
+        # and inbox from what comes back); not donated it would be a
+        # second copy, a gigabyte more than the one round holds. One
+        # tile keeps the parent's program, which donates nothing.
+        self._round = jax.jit(
+            step_round, donate_argnums=(0, 1) if tiles > 1 else ())
         self._zeros_b = jnp.zeros((n,), bool)
         self._zeros_i = jnp.zeros((n,), I32)
         # Scan rounds in which each kind lane held a message for any
@@ -265,17 +365,9 @@ class MultiRaftEngine:
             self._fleet_sum_np = self._fleet_layout.sum_mask()
         self.fleet_hub = None
 
-        def closed_loop(st, inbox, ticks, props, tel, flt, lanes, isolate,
-                        rounds, control=None, watch=None):
-            # `isolate` is None (no fault: the scan is traced as it
-            # always was) or the bool [rounds, R] node schedule, one
-            # row a round as the scan's xs; `control` is None (the
-            # same) or the int32 [rounds, CTL_COLS] control schedule,
-            # beside it, and `watch` the ScanWatch that rides the carry
-            # with it.
-            # jitlint: waive(tracer-branch) -- None is an empty pytree: the branch is on the argument's structure at trace time, never on a device value
-            if isolate is not None or control is not None:
-                slots = jnp.arange(n, dtype=I32) % cfg.num_replicas
+        def round_body(step, zeros_b, zeros_i, slots, ticks, props, tiled):
+            """The scan's body over the rows its arguments are made
+            for: all N, or one tile's (`tiled`)."""
 
             def body(carry, row):
                 # `occ` is the inbox's lane occupancy, [K] bool: what
@@ -283,30 +375,32 @@ class MultiRaftEngine:
                 # told was there.
                 st, inbox, occ, tel, flt, lanes, watch = carry
                 cut, ctl = row
-                lanes = lanes + occ
-                iso = self._zeros_b
+                if not tiled:
+                    lanes = lanes + occ
+                iso = zeros_b
                 # jitlint: waive(tracer-branch) -- as above: a scan without xs hands its body None
                 if cut is not None:
                     # Row t widened to [N] where it is used: node s is
                     # slot s of every group.
                     for s in range(cfg.num_replicas):
                         iso = iso | ((slots == s) & cut[s])
-                transfer, reads, conf = self._zeros_i, self._zeros_b, None
+                transfer, reads, conf = zeros_i, zeros_b, None
                 wipe = None
                 # jitlint: waive(tracer-branch) -- as above
                 if ctl is not None:
                     # The row's few scalars widened the same way.
                     drained = slots == ctl[CTL_FROM] - 1
                     transfer = jnp.where(drained, ctl[CTL_TO], 0)
-                    reads = jnp.broadcast_to(ctl[CTL_READS] != 0, (n,))
+                    reads = jnp.broadcast_to(
+                        ctl[CTL_READS] != 0, zeros_b.shape)
                     if cfg.conf_entries:
                         conf = jnp.where(drained, 0, ctl[CTL_CONF])
                     if cfg.replace_replicas:
                         iso = iso | (slots == ctl[CTL_RETIRE] - 1)
                         wipe = slots == ctl[CTL_WIPE] - 1
                     pre = st
-                out = self._step(
-                    st, inbox, ticks, self._zeros_b, props, iso,
+                out = step(
+                    st, inbox, ticks, zeros_b, props, iso,
                     transfer, reads, lane_any=occ, conf_req=conf,
                     wipe=wipe,
                 )
@@ -328,22 +422,133 @@ class MultiRaftEngine:
                 # occupancy is the next inbox's.
                 sent = lane_occupancy(outbox)
                 inbox = route_lanes(cfg, outbox, sent, (inbox, occ))
-                return (st, inbox, sent, tel, flt, lanes, watch), None
+                # A tile cannot count the rounds a lane was occupied
+                # for ANY instance: it hands each round's own vector
+                # out, for the call to put together over its tiles.
+                return (st, inbox, sent, tel, flt, lanes, watch), (
+                    occ if tiled else None)
 
-            # Inbox and outbox ride the scan as K kind lanes, each an
-            # array of its own (the round answers lanes with lanes),
-            # and the inbox is stacked back once at the exit.
-            # A caller's inbox may hold anything in a lane with no
-            # valid slot (the eager round exchanges emit's unsent
-            # request fields too), so such a lane is wiped here, once
-            # a call: inside the scan an empty lane is all zeros.
-            inbox = split_lanes(inbox)
+            return body
+
+        def enter(inbox):
+            """Inbox lanes as a scan takes them, and their occupancy.
+            A caller's inbox may hold anything in a lane with no
+            valid slot (the eager round exchanges emit's unsent
+            request fields too), so such a lane is wiped here, once
+            a call: inside the scan an empty lane is all zeros."""
             occ = lane_occupancy(inbox)
-            inbox = tuple(
+            return tuple(
                 jax.tree.map(
                     lambda x, _k=k: jnp.where(occ[_k], x, jnp.zeros_like(x)),
                     inbox[k])
-                for k in range(NUM_KINDS))
+                for k in range(NUM_KINDS)), occ
+
+        def tiled_loop(st, inbox, ticks, props, tel, lanes, isolate,
+                       rounds, control, watch):
+            """`closed_loop` tile by tile. Groups share nothing, so a
+            call of `rounds` rounds over all rows is `tiles` calls
+            over a block of whole groups each (`rows` adjacent rows:
+            N is g-major), and the chip then steps a block small
+            enough for its fast memory. What crosses tiles is put
+            together here: a lane counts for a round if any tile held
+            a message in it, the ScanWatch counts run on from tile to
+            tile, everything else is per row and rides the slice. The
+            lane skip is the tile's own, and exact: a lane empty in
+            this tile and occupied in another comes out as zeros here,
+            where one scan over all rows exchanged emit's unsent
+            fields under ``valid`` false
+            (tests/batched/test_scan_tiles.py)."""
+            slots = jnp.arange(rows, dtype=I32) % cfg.num_replicas
+            zeros_b, zeros_i = jnp.zeros((rows,), bool), jnp.zeros((rows,), I32)
+
+            def tile_body(lo, ticks, props):
+                """The scan's body for the rows from `lo` on."""
+                return round_body(tile_step(lo, slots), zeros_b, zeros_i,
+                                  slots, ticks, props, tiled=True)
+
+            def tile_rounds(lo, st, inbox, tel, watch, ticks, props):
+                """The call's rounds on the rows from `lo` on, handed
+                in as the tile's slices (`watch` with the whole
+                counts); and each round's lane occupancy."""
+                inbox, occ = enter(inbox)
+                (st, inbox, _, tel, _, _, watch), occs = jax.lax.scan(
+                    tile_body(lo, ticks, props),
+                    (st, inbox, occ, tel, (), (), watch),
+                    (isolate, control), length=rounds)
+                return st, inbox, tel, watch, occs
+
+            def tile(i, carry):
+                st, inbox, tel, watch, seen = carry
+                lo = i * rows
+                cut = lambda x: jax.lax.dynamic_slice_in_dim(x, lo, rows)  # noqa: E731
+                # The counts run on from tile to tile: a sum, whatever
+                # the order. (None is an empty pytree, as in closed_loop.)
+                t_watch = None if watch is None else watch._replace(
+                    read_floor=cut(watch.read_floor),
+                    history=cut(watch.history))
+                t_st, t_inbox, t_tel, t_watch, occs = tile_rounds(
+                    lo, *jax.tree.map(cut, (st, inbox, tel)), t_watch,
+                    cut(ticks), cut(props))
+                # In place: the carry is the donated state, and no
+                # second copy of it exists.
+                paste = lambda x, y: jax.lax.dynamic_update_slice_in_dim(  # noqa: E731
+                    x, y, lo, 0)
+                st, inbox, tel = jax.tree.map(
+                    paste, (st, inbox, tel), (t_st, t_inbox, t_tel))
+                watch = None if watch is None else ScanWatch(
+                    t_watch.counts,
+                    paste(watch.read_floor, t_watch.read_floor),
+                    paste(watch.history, t_watch.history))
+                return st, inbox, tel, watch, seen | occs
+
+            inbox = split_lanes(inbox)
+            # Tracing only. As the body of the loops the round takes
+            # JAX 12.5 s to trace on the TPU's host, by itself 2.6 s
+            # (PERF.md section 6, "PR 35": every warm start would pay
+            # the difference); one round traced abstractly here first,
+            # the loops' trace finds the round's jaxpr cached. Nothing
+            # of this reaches the program.
+            # jitlint: waive(tracer-branch) -- None is an empty pytree, as in closed_loop
+            t_watch = None if watch is None else ScanWatch(
+                watch.counts, like(watch.read_floor), like(watch.history))
+            jax.eval_shape(
+                lambda ticks, props, carry, row: tile_body(0, ticks, props)(
+                    carry, row),
+                like(ticks), like(props),
+                (*jax.tree.map(like, (st, inbox)),
+                 jax.ShapeDtypeStruct((NUM_KINDS,), bool),
+                 jax.tree.map(like, tel), (), (), t_watch),
+                jax.tree.map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
+                    (isolate, control)))
+            st, inbox, tel, watch, seen = jax.lax.fori_loop(
+                0, tiles, tile,
+                (st, inbox, tel, watch, jnp.zeros((rounds, NUM_KINDS), bool)))
+            return (st, stack_lanes(inbox), tel, (),
+                    lanes + jnp.sum(seen, axis=0, dtype=I32), st.commit[0],
+                    watch)
+
+        def closed_loop(st, inbox, ticks, props, tel, flt, lanes, isolate,
+                        rounds, control=None, watch=None):
+            # `isolate` is None (no fault: the scan is traced as it
+            # always was) or the bool [rounds, R] node schedule, one
+            # row a round as the scan's xs; `control` is None (the
+            # same) or the int32 [rounds, CTL_COLS] control schedule,
+            # beside it, and `watch` the ScanWatch that rides the carry
+            # with it.
+            if tiles > 1:
+                return tiled_loop(st, inbox, ticks, props, tel, lanes,
+                                  isolate, rounds, control, watch)
+            slots = None
+            # jitlint: waive(tracer-branch) -- None is an empty pytree: the branch is on the argument's structure at trace time, never on a device value
+            if isolate is not None or control is not None:
+                slots = jnp.arange(n, dtype=I32) % cfg.num_replicas
+            body = round_body(self._step, self._zeros_b, self._zeros_i,
+                              slots, ticks, props, tiled=False)
+            # Inbox and outbox ride the scan as K kind lanes, each an
+            # array of its own (the round answers lanes with lanes),
+            # and the inbox is stacked back once at the exit.
+            inbox, occ = enter(split_lanes(inbox))
             (st, inbox, _, tel, flt, lanes, watch), _ = jax.lax.scan(
                 body, (st, inbox, occ, tel, flt, lanes, watch),
                 (isolate, control), length=rounds
@@ -409,7 +614,7 @@ class MultiRaftEngine:
         # append that brings another in one round counts too.
         lost = ((pre.conf.index > pre.applied)
                 & (st.conf.index != pre.conf.index) & (st.conf.index != 0)
-                ) if self.cfg.conf_entries else self._zeros_b
+                ) if self.cfg.conf_entries else jnp.zeros_like(advanced)
         events = [
             st.in_joint,
             (st.read_index >= 0) & ~st.read_ready,
@@ -604,7 +809,8 @@ class MultiRaftEngine:
             "" if sched is None else "/isolate") + (
             "" if ctl is None else "/control")
         with self._span("engine.run_rounds", rounds=rounds,
-                        isolated=isolated, **asked), warm_guard(key):
+                        tiles=self._tiles, isolated=isolated,
+                        **asked), warm_guard(key):
             watch = None if ctl is None else self._watch
             self.state, self.inbox, tel, flt, lanes, fence, watch = self._closed_loop(
                 self.state, self.inbox, ticks, props, self._tel(),

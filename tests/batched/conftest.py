@@ -162,6 +162,11 @@ os.environ.setdefault("ETCD_TPU_TRANSFER_GUARD", "disallow")
 # are keys since ISSUE 28 and 32 (CELL, R5, RC3) but for `engine64k-r3`
 # at 8 groups, which tests/benchmark builds. Budget 48 -> 50: raised by
 # exactly the two, the headroom of 1 that ISSUE 33 left kept.
+# ISSUE 35 AUDIT: still 49 of 50. test_scan_tiles builds the five live
+# configurations at 8 groups, values that are keys already (CELL, R5,
+# RC3, RP4 and `engine64k-r3` at 8 groups). A tile of the closed loop
+# calls the configuration's own `jit(step_round)` on fewer rows
+# (make_step_round with the tile's iids): another trace of one key.
 ROUND_STEP_SHAPE_BUDGET = 50
 
 
