@@ -6,6 +6,11 @@ scan length (``rounds_per_call``) serves settle, warm-up and window, so
 a cell compiles one scan program. After the window the whole state is
 read back once; a seeded sample of groups is compared with
 ``reference.shadow.ShadowCluster`` stepped through the same schedule.
+
+The scan's lane counter (``MultiRaftEngine.lane_rounds``) is read where
+set-up ends and as the window closes, never between two calls of the
+window; ``window_counters`` hands both readings to the harness's
+``raw`` (``lanes``), for ``readers/lanes.py``.
 """
 
 from __future__ import annotations
@@ -29,6 +34,7 @@ class Driver:
         self.eng = None
         self.calls = 0
         self.settle_rounds = 0
+        self.lanes: dict = {}  # lane_rounds() before and after the window
 
     def setup(self, load, gen) -> None:
         import jax
@@ -72,6 +78,10 @@ class Driver:
             load["proposals_per_round"])
         self.call()  # warm-up: the window's own program and arguments
         jax.block_until_ready(eng.state.commit)
+        # Nothing runs between here and the window's first call, and
+        # ``generators/engine_rounds.run`` starts its clock before it
+        # calls ``window_opens``: read here, the window pays nothing.
+        self.lanes = {"before": eng.lane_rounds().tolist()}
         say("engine", build_elect_warm_s=time.perf_counter() - t0,
             deliver=cfg.deliver_shape, lanes_minor=cfg.lanes_minor,
             leaders_per_slot=np.bincount(slots, minlength=r).tolist())
@@ -88,7 +98,11 @@ class Driver:
         pass
 
     def window_closes(self) -> None:
-        pass
+        self.lanes["after"] = self.eng.lane_rounds().tolist()
+
+    def window_counters(self) -> dict:
+        """For the harness's ``raw``: what ``readers/lanes.py`` reads."""
+        return {"lanes": self.lanes}
 
     # -- the comparison, outside the window -------------------------------------------
 

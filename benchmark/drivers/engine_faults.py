@@ -45,23 +45,15 @@ one call more)
   of the same program with nothing offered and no node cut, and every
   replica has to stand level with its leader, in REPLICATE.
 
-While the cell's own per-layer entries are parked
-(``parked/engine100k-r3_layers.json``), every run prints the five that
-read the telemetry plane on a ``[bench:election]`` line, each through
-its own ``layer_metrics/election.*.json`` and reader. The two that read
-the device trace (``round.tick_pct``, ``round.telemetry_pct``) are not
-repeated there: a driver never sees the reduced trace, and reducing it
-a second time costs a traced run half a minute; the harness's own
-``[bench:trace]`` line has their seconds (``scope_s``: ``raft_tick``,
-``raft_telemetry``) beside every other scope's.
+The cell's per-layer entries that read the telemetry plane
+(``layer_metrics/election.*.json``) and the lane counter
+(``round.lanes_run``) read what ``window_counters`` hands the
+generator's ``raw``.
 """
 
 from __future__ import annotations
 
-import glob
 import inspect
-import json
-import os
 import time
 from typing import Dict, List, Optional
 
@@ -71,28 +63,12 @@ from ..compare import Check, engine_checks
 from ..fault_checks import (group_checks, quiet_checks, schedule_classes,
                             window_checks)
 from ..harness import say
-from ..readers import telemetry as telemetry_readers
 
 # Controls (``check(control=...)``): each breaks, in the reference, one
 # guarantee the configuration states; the comparison then has to fail.
 CONTROLS = ("commit_without_quorum", "votes_without_log_check")
 # (iid + 1) * 7919 passed 2**31 from this instance id on.
 WRAPPED_FROM_IID = (2**31 - 1) // 7919
-
-
-def election_line(raw: dict) -> Dict[str, Optional[float]]:
-    """Every ``layer_metrics/election.*.json`` read from ``raw`` by the
-    reader and parameters its file names."""
-    base = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    out = {}
-    for path in sorted(glob.glob(
-            os.path.join(base, "layer_metrics", "election.*.json"))):
-        with open(path) as f:
-            spec = json.load(f)
-        fn = spec["reader"].partition(".")[2]
-        out[spec["name"]] = getattr(telemetry_readers, fn)(
-            {"raw": raw}, **spec.get("params", {}))
-    return out
 
 
 class _Derailed:
@@ -207,6 +183,7 @@ class Driver:
         self.marks[name] = {
             "counters": {n: int(v) for n, v in zip(TM_NAMES, totals)},
             "commit": self.eng.commits().max(axis=1),
+            "lanes": self.eng.lane_rounds().tolist(),
         }
 
     def window_opens(self) -> None:
@@ -218,10 +195,11 @@ class Driver:
 
     def window_counters(self) -> dict:
         """For the generator's ``raw``: what ``readers/telemetry.py``
-        reads."""
+        and ``readers/lanes.py`` read."""
         a, b = self.marks["open"], self.marks["close"]
         return {
             "telemetry": {"before": a["counters"], "after": b["counters"]},
+            "lanes": {"before": a["lanes"], "after": b["lanes"]},
             "entries_committed": int((b["commit"] - a["commit"]).sum()),
         }
 
@@ -363,7 +341,6 @@ class Driver:
             control = CONTROLS[0]
         if self.final is None:
             self.final = self.finish()
-            say("election", **election_line(raw))
         state = self.final["state"]
         cfg = self.cfg
         t0 = time.perf_counter()

@@ -53,17 +53,14 @@ whole state once (after ``memory_peak_bytes`` is read), and holds it to
   at a period's end cannot tell a commit that ran ahead through the
   cut from one that stalled, the history can.
 
-While the cell's own per-layer entries are parked
-(``parked/engine1m-r3_layers.json``), every run prints those that read
-counters on a ``[bench:reconf]`` line, each through its own
-``layer_metrics`` file and reader.
+The cell's per-layer entries that read counters (``read.*``,
+``reconf.*``, ``round.lanes_run``) read what ``window_counters`` hands
+the generator's ``raw``.
 """
 
 from __future__ import annotations
 
 import inspect
-import json
-import os
 import time
 from typing import Dict, List, Optional
 
@@ -72,37 +69,13 @@ import numpy as np
 from ..compare import Check, engine_checks
 from ..fault_checks import group_checks, schedule_classes
 from ..harness import say
-from ..readers import reconf as reconf_readers
-from ..readers import telemetry as telemetry_readers
 from ..reconf_checks import (MASKS, membership_checks, run_checks,
                              sample_checks, window_checks)
-
-READERS = {"reconf": reconf_readers, "telemetry": telemetry_readers}
 
 # Controls (``check(control=...)``): each breaks, in the reference, one
 # guarantee the configuration states; the comparison then has to fail.
 CONTROLS = ("commit_on_the_incoming_majority_alone",
             "reads_confirmed_without_the_quorum")
-PARKED = "engine1m-r3_layers.json"
-BASE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def reconf_line(raw: dict) -> Dict[str, Optional[float]]:
-    """Every parked metric that reads counters, read from ``raw`` by
-    the reader and parameters its ``layer_metrics`` file names."""
-    with open(os.path.join(BASE, "parked", PARKED)) as f:
-        parked = json.load(f)["per_layer"]
-    out = {}
-    for m in parked:
-        if m["source"] == "device_trace":
-            continue
-        with open(os.path.join(BASE, "layer_metrics",
-                               m["name"] + ".json")) as f:
-            spec = json.load(f)
-        mod, _, fn = spec["reader"].partition(".")
-        out[spec["name"]] = getattr(READERS[mod], fn)(
-            {"raw": raw}, **spec.get("params", {}))
-    return out
 
 
 class _Derailed:
@@ -264,7 +237,7 @@ class Driver:
             "reads": counters[:, TM_INDEX["reads_confirmed"]].reshape(
                 g_n, r).sum(axis=1, dtype=np.int64),
             "applied": counters[:, TM_INDEX["conf_changes_applied"]].copy(),
-            "lanes": self.eng.lane_rounds().astype(np.int64),
+            "lanes": self.eng.lane_rounds().tolist(),
             "rounds_done": self.rounds_done,
         }
 
@@ -274,20 +247,15 @@ class Driver:
 
     def window_closes(self) -> None:
         self._mark("close")
-        a, b = self.marks["open"], self.marks["close"]
-        lanes = b["lanes"] - a["lanes"]
-        say("lanes", rounds=b["rounds_done"] - a["rounds_done"],
-            occupied=lanes.tolist(),  # VOTE, APP, HB and their responses
-            run_a_round=float(lanes.sum())
-            / max(b["rounds_done"] - a["rounds_done"], 1))
 
     def window_counters(self) -> dict:
-        """For the generator's ``raw``: what ``readers/telemetry.py``
-        and ``readers/reconf.py`` read."""
+        """For the generator's ``raw``: what ``readers/telemetry.py``,
+        ``readers/reconf.py`` and ``readers/lanes.py`` read."""
         a, b = self.marks["open"], self.marks["close"]
         return {
             "telemetry": {"before": a["counters"], "after": b["counters"]},
             "watch": {"before": a["watch"], "after": b["watch"]},
+            "lanes": {"before": a["lanes"], "after": b["lanes"]},
             "entries_committed": int((b["commit"] - a["commit"]).sum()),
         }
 
@@ -417,7 +385,6 @@ class Driver:
             control = CONTROLS[0]
         if self.final is None:
             self.final = self.finish()
-            say("reconf", **reconf_line(raw))
         final = self.final
         state = final["state"]
         cfg = self.cfg

@@ -53,59 +53,31 @@ whole state once (after ``memory_peak_bytes`` is read), and holds it to
   rounds: state and log (``compare.engine_checks``), membership masks,
   read state and history (``reconf_checks.sample_checks``).
 
-While the cell's own per-layer entries are parked
-(``parked/engine512k-r3of4_layers.json``), every run prints them on a
-``[bench:replace]`` line, each through its own ``layer_metrics`` file
-and reader.
+The cell's per-layer entries (``replace.*``, ``round.lanes_run``) read
+what the base class's ``window_counters`` hands the generator's ``raw``.
 """
 
 from __future__ import annotations
 
 import inspect
-import json
-import os
 import time
-from typing import Dict, List, Optional
+from typing import List, Optional
 
 import numpy as np
 
 from ..compare import Check, engine_checks
 from ..fault_checks import group_checks
 from ..harness import say
-from ..readers import reconf as reconf_readers
-from ..readers import replace as replace_readers
-from ..readers import telemetry as telemetry_readers
 from ..reconf_checks import sample_checks
 from ..replace_checks import (empty_slot_checks, live_view,
                               membership_checks, run_checks, window_checks)
 from . import engine_reconf
 from .engine_reconf import _Derailed
 
-READERS = {"replace": replace_readers, "reconf": reconf_readers,
-           "telemetry": telemetry_readers}
-
 # Controls (``check(control=...)``): each breaks, in the reference, one
 # guarantee the configuration states; the comparison then has to fail.
 CONTROLS = ("snapshot_restored_without_its_confstate",
             "commit_on_the_incoming_majority_alone")
-PARKED = "engine512k-r3of4_layers.json"
-BASE = engine_reconf.BASE
-
-
-def replace_line(raw: dict) -> Dict[str, Optional[float]]:
-    """Every parked metric, read from ``raw`` by the reader and
-    parameters its ``layer_metrics`` file names."""
-    with open(os.path.join(BASE, "parked", PARKED)) as f:
-        parked = json.load(f)["per_layer"]
-    out = {}
-    for m in parked:
-        with open(os.path.join(BASE, "layer_metrics",
-                               m["name"] + ".json")) as f:
-            spec = json.load(f)
-        mod, _, fn = spec["reader"].partition(".")
-        out[spec["name"]] = getattr(READERS[mod], fn)(
-            {"raw": raw}, **spec.get("params", {}))
-    return out
 
 
 class Driver(engine_reconf.Driver):
@@ -304,7 +276,6 @@ class Driver(engine_reconf.Driver):
             control = CONTROLS[0]
         if self.final is None:
             self.final = self.finish()
-            say("replace", **replace_line(raw))
         final = self.final
         state = final["state"]
         cfg = self.cfg

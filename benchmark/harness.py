@@ -269,6 +269,9 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool,
 
         raw = gen.run(driver, load, cell.traffic, seconds, probe)
         probe.stop()
+        # What the driver read at the window's two marks, for the
+        # readers; a generator that asks for it itself gets the same.
+        raw.update(getattr(driver, "window_counters", dict)())
         c1 = meter.snapshot()
         peak = memory_peak_bytes()
         say("window", **{k: v for k, v in raw.items()

@@ -1,12 +1,12 @@
 """The fault cell's own pieces on the CPU: the generator's schedule from
 the seed, each new comparison shown to fail on a fault handed to it, the
 classes' derivation, the sample, the reference wrapper against the
-program's oracle, the telemetry readers, the seven parked per-layer
-entries against the contract's rules and each read on a tiny run, and
-the cell driven tiny with its timed path broken and under both
-controls. (That the cell runs tiny and is correct, and the contract's
-rules for its live entries, are ``test_harness.py``'s and
-``test_contract.py``'s, from the data.)"""
+program's oracle, the telemetry readers, the cell's seven per-layer
+entries (live since PR 36) with the cells each lists and each read on a
+tiny run, and the cell driven tiny with its timed path broken and under
+both controls. (That the cell runs tiny and is correct, and the
+contract's rules for its live entries, are ``test_harness.py``'s,
+``test_contract.py``'s and ``test_layers.py``'s, from the data.)"""
 
 import json
 import os
@@ -24,10 +24,8 @@ from benchmark.fault_checks import (LEADER, REPLICATE, group_checks,
                                     window_checks)
 from benchmark.generators import engine_faults_rounds as gen
 from benchmark.readers import telemetry as reader
-from benchmark.readers import trace as trace_reader
 
-from .test_contract import NAME, SOURCES, UNIT
-from .util import REPO, _edit, bench, tiny_root
+from .util import CELLS_AT_36, REPO, bench, listed_cells, tiny_root
 
 CELL = "engine100k-r3.elections"
 SIZES = {"num_groups": 16, "num_replicas": 3}
@@ -442,108 +440,66 @@ def test_telemetry_readers_find_nothing_in_another_drivers_run():
                             ["elections_started"]) is None
 
 
-# -- the parked entries against the contract, and each read on a tiny run ------------
+# -- the cell's per-layer entries, and each read on a tiny run ------------------------
 
-
-def parked_layers() -> dict:
-    with open(os.path.join(REPO, "benchmark", "parked",
-                           "engine100k-r3_layers.json")) as f:
-        return json.load(f)
-
-
-PARKED = parked_layers()["per_layer"]
 SEVEN = ["round.tick_pct", "round.telemetry_pct",
          "election.started_per_kgr", "election.snapshots_per_kgr",
          "election.won_pct", "election.vote_msg_pct",
          "election.committed_pct"]
 
 
-def test_the_seven_are_parked_and_not_live():
-    assert set(parked_layers()) == {"note", "per_layer"}
-    assert [m["name"] for m in PARKED] == SEVEN
-    live = {m["name"] for m in bench()["per_layer"]}
-    assert not live & set(SEVEN)
-
-
-@pytest.mark.parametrize("m", PARKED, ids=lambda m: m["name"])
-def test_parked_layer_entry(m):
-    """``test_contract.py::test_metric_entry``'s rules for a per-layer
-    entry, so that the PR which pastes these pastes entries that
-    pass."""
+def test_the_seven_are_live_with_exactly_these_workloads():
+    """The two shares of the round follow the work among the cells PR 36
+    found (``raft_tick`` runs in every one, ``raft_telemetry`` where the
+    configuration turns the plane on); the five counter metrics keep
+    the cell they were written for. A cell appended since is not
+    theirs to list."""
     b = bench()
-    assert set(m) == {"name", "unit", "better", "source", "layer", "moves",
-                      "workloads"}
-    assert NAME.match(m["name"]) and UNIT.match(m["unit"])
-    assert m["better"] in ("lower", "higher") and m["source"] in SOURCES
-    assert m["workloads"] == [CELL]
-    assert CELL in {w["name"] for w in b["workloads"]}
-    moved = [e for e in b["end_to_end"] if e["name"] == m["moves"]]
-    assert moved and CELL in moved[0]["workloads"]
-    assert "\n" not in m["layer"] and 1 <= len(m["layer"]) <= 200
-    with open(os.path.join(REPO, "benchmark", "layer_metrics",
-                           m["name"] + ".json")) as f:
-        spec = json.load(f)
-    assert (spec["name"], spec["unit"], spec["layer"], spec["moves"]) == (
-        m["name"], m["unit"], m["layer"], m["moves"])
-    assert "workloads" not in spec, "cells are named on the cell's side"
-    mod, _, fn = spec["reader"].partition(".")
-    assert m["source"] == {"trace": "device_trace",
-                           "telemetry": "program_counter"}[mod]
-    assert callable(getattr({"trace": trace_reader,
-                             "telemetry": reader}[mod], fn))
-    with open(os.path.join(REPO, "PERF.md")) as f:
-        assert f"`{m['name']}`" in f.read()
+    files = {c["name"]: c["file"] for c in b["configs"]}
+    plane_on = []
+    for w in b["workloads"]:
+        with open(os.path.join(REPO, files[w["config"]])) as f:
+            if (w["name"] in CELLS_AT_36
+                    and json.load(f)["sizes"].get("telemetry")):
+                plane_on.append(w["name"])
+    assert CELL in plane_on and len(plane_on) == 3
+    assert listed_cells(SEVEN) == {
+        "round.tick_pct": CELLS_AT_36, "round.telemetry_pct": plane_on,
+        **{name: [CELL] for name in SEVEN[2:]}}
+    assert not os.path.exists(os.path.join(
+        REPO, "benchmark", "parked", "engine100k-r3_layers.json"))
 
 
 @pytest.fixture(scope="module")
-def pasted_root(tmp_path_factory):
-    """``tiny_root`` with the seven entries pasted at the end of
-    ``per_layer``, as the ``benchmark`` PR that takes them up will."""
-    dst = tiny_root(str(tmp_path_factory.mktemp("faults_layers")))
-    _edit(os.path.join(dst, "BENCHMARK.json"),
-          lambda b: b["per_layer"].extend(PARKED))
-    return dst
-
-
-@pytest.fixture(scope="module")
-def pasted_run(pasted_root):
-    cell = harness.Cell(pasted_root, CELL)
+def layer_run(root):
+    cell = harness.Cell(root, CELL)
     ctx, checks = harness.measure(cell, 2**31 + 28, 0.3, False,
                                   time.perf_counter(), require_tpu=False)
     assert verdict(checks), [c for c in checks if not c.ok]
     return cell, ctx
 
 
-def test_pasted_entries_reach_this_cell_alone(pasted_root):
-    cell = harness.Cell(pasted_root, CELL)
-    assert set(SEVEN) <= {m["name"] for m in cell.per_layer}
-    other = harness.Cell(pasted_root, "engine64k-r3.append")
-    assert not set(SEVEN) & {m["name"] for m in other.per_layer}
-
-
-def test_each_counter_reader_on_a_tiny_run(pasted_run, capsys):
+def test_each_counter_reader_on_a_tiny_run(layer_run):
     """No trace on the CPU: the five counter metrics are read, the two
     trace shares find nothing and are left out."""
-    cell, ctx = pasted_run
+    cell, ctx = layer_run
     layer = harness.per_layer_metrics(cell, ctx)
     harness.refuse_bad_values(layer)
     assert set(SEVEN[2:]) <= set(layer)
     assert not set(SEVEN[:2]) & set(layer)
-    for m in PARKED[2:]:
-        got = layer[m["name"]]
-        assert got["unit"] == m["unit"] and got["value"] > 0.0
+    units = {m["name"]: m["unit"] for m in bench()["per_layer"]}
+    for name in SEVEN[2:]:
+        assert layer[name]["unit"] == units[name]
+        assert layer[name]["value"] > 0.0
     assert layer["election.won_pct"]["value"] <= 100.0
     assert layer["election.committed_pct"]["value"] < 100.0
-    # The driver's ``[bench:election]`` line holds the same five.
-    assert engine_faults.election_line(ctx["raw"]) == {
-        name: layer[name]["value"] for name in SEVEN[2:]}
 
 
-def test_each_trace_reader_on_a_reduced_trace(pasted_run):
+def test_each_trace_reader_on_a_reduced_trace(layer_run):
     """The two shares of the round from a reduced trace as
     ``reduce/trace.py`` gives it (the chip's scopes; seconds of PR 27's
     builder's traced run, rounded)."""
-    cell, ctx = pasted_run
+    cell, ctx = layer_run
     scope_s = {"raft_deliver": 1.4556, "raft_route": 0.3115,
                "unscoped": 0.1472, "raft_emit": 0.1027,
                "raft_telemetry": 0.0527, "raft_tick": 0.0315,
@@ -561,18 +517,6 @@ def test_each_trace_reader_on_a_reduced_trace(pasted_run):
     layer = harness.per_layer_metrics(cell, dict(ctx, trace=red))
     assert "round.telemetry_pct" not in layer
     assert "round.tick_pct" in layer
-
-
-def test_the_election_line_is_printed_once_a_run(pasted_root, capsys):
-    cell = harness.Cell(pasted_root, CELL)
-    harness.measure(cell, 5, 0.3, False, time.perf_counter(),
-                    require_tpu=False)
-    lines = [ln for ln in capsys.readouterr().out.splitlines()
-             if ln.startswith("[bench:election] ")]
-    assert len(lines) == 1
-    got = json.loads(lines[0].split(" ", 1)[1])
-    assert sorted(got) == sorted(SEVEN[2:])
-    assert all(v is not None and v > 0 for v in got.values())
 
 
 # -- the cell driven tiny: the timed path broken, and the controls ------------------

@@ -2,12 +2,15 @@
 schedule from the seed and its whole-period windows, each new comparison
 shown to fail on a fault handed to it, the classes' derivation, the
 sample, the reference wrapper against the program's oracle (and its
-history against the engine's rule), the readers, the seven parked
-per-layer entries against the contract's rules and each read on a tiny
-run, the ``[bench:reconf]`` line, and the cell driven tiny with its
-timed path broken and under both controls. (That the cell runs tiny and
-is correct, and the contract's rules for its live entries, are
-``test_harness.py``'s and ``test_contract.py``'s, from the data.)"""
+history against the engine's rule), the readers, the cell's seven
+per-layer entries (live since PR 36) with the cells each lists and each
+read on a tiny run, the rule that holds the cell's entries after the
+three that were there and lets a later PR append (shown open and shown
+tight on a temporary copy of ``BENCHMARK.json``), and the cell driven
+tiny with its timed path broken and under both controls. (That the cell
+runs tiny and is correct, and the contract's rules for its live
+entries, are ``test_harness.py``'s, ``test_contract.py``'s and
+``test_layers.py``'s, from the data.)"""
 
 import json
 import os
@@ -24,12 +27,11 @@ from benchmark.fault_checks import schedule_classes
 from benchmark.generators import engine_reconf_rounds as gen
 from benchmark.readers import reconf as reader
 from benchmark.readers import telemetry as telemetry_reader
-from benchmark.readers import trace as trace_reader
 from benchmark.reconf_checks import (LEADER, membership_checks, run_checks,
                                      sample_checks, window_checks)
 
-from .test_contract import NAME, SOURCES, UNIT
-from .util import REPO, _edit, bench, tiny_root
+from .util import (CELLS_AT_36, REPO, bench, edited_copy, listed_cells,
+                   swap, tiny_root)
 
 CELL = "engine1m-r3.joint-readindex"
 SIZES = {"num_groups": 16, "num_replicas": 3}
@@ -528,38 +530,44 @@ def test_readers_find_nothing_in_another_drivers_run():
     assert reader.rounds_to_confirm(ctx) is None
 
 
-# -- the parked entries against the contract, and each read on a tiny run ------------
+# -- the cell's entries: where they stand, which cells they list, each read tiny -----
 
-
-def parked_layers() -> dict:
-    with open(os.path.join(REPO, "benchmark", "parked",
-                           "engine1m-r3_layers.json")) as f:
-        return json.load(f)
-
-
-PARKED = parked_layers()["per_layer"]
 SEVEN = ["round.control_pct", "read.confirmed_per_kgr",
          "read.rounds_to_confirm", "reconf.joint_pct",
          "reconf.applied_per_kgr", "reconf.committed_pct",
          "reconf.transfer_won_pct"]
-READERS = {"trace": trace_reader, "telemetry": telemetry_reader,
-           "reconf": reader}
+WERE = ["engine64k-r3", "engine10k-r5", "engine100k-r3"]
+CELLS_WERE = CELLS_AT_36[:3]
 
 
-def test_the_seven_are_parked_and_not_live():
-    assert set(parked_layers()) == {"note", "per_layer"}
-    assert [m["name"] for m in PARKED] == SEVEN
-    live = {m["name"] for m in bench()["per_layer"]}
-    assert not live & set(SEVEN)
+def test_the_seven_are_live_with_exactly_these_workloads():
+    """``raft_control`` runs in every cell (transfers and ReadIndex
+    batches are the round's, asked or not), so its share lists the five
+    PR 36 found; the six counter metrics keep the cell they were
+    written for. A cell appended since is not theirs to list."""
+    assert listed_cells(SEVEN) == {
+        "round.control_pct": CELLS_AT_36,
+        **{name: [CELL] for name in SEVEN[1:]}}
+    assert not os.path.exists(os.path.join(
+        REPO, "benchmark", "parked", "engine1m-r3_layers.json"))
+
+
+def gained_rule(b: dict) -> None:
+    """PR 32's three additions, each right after the three entries its
+    list had: the configuration, the cell, the cell's name under its
+    end-to-end metric (the order-relative form of ``test_replace.py::
+    test_the_cell_follows_the_cells_that_were_there``). What a later PR
+    appends after them is its own."""
+    assert [c["name"] for c in b["configs"]][:4] == WERE + ["engine1m-r3"]
+    assert [w["name"] for w in b["workloads"]][:4] == CELLS_WERE + [CELL]
+    moved = [e for e in b["end_to_end"] if e["name"] == "group_rounds_per_s"]
+    assert moved[0]["workloads"][:4] == CELLS_WERE + [CELL]
+    assert b["workloads"][3]["chips"] == 1
+    assert b["workloads"][3]["config"] == "engine1m-r3"
 
 
 def test_the_benchmark_gained_one_config_one_cell_and_one_name():
-    b = bench()
-    assert [c["name"] for c in b["configs"]][-1] == "engine1m-r3"
-    assert [w["name"] for w in b["workloads"]][-1] == CELL
-    moved = [e for e in b["end_to_end"] if e["name"] == "group_rounds_per_s"]
-    assert moved[0]["workloads"][-1] == CELL
-    assert b["workloads"][-1]["chips"] == 1
+    gained_rule(bench())
     cfg = config()
     assert cfg["reduced"] == [] and cfg["sizes"]["num_groups"] == 1_048_576
     assert 1 <= len(cfg["source"]) <= 200
@@ -568,77 +576,74 @@ def test_the_benchmark_gained_one_config_one_cell_and_one_name():
                                    "snapshots"}
 
 
-@pytest.mark.parametrize("m", PARKED, ids=lambda m: m["name"])
-def test_parked_layer_entry(m):
-    """``test_contract.py::test_metric_entry``'s rules for a per-layer
-    entry, so that the PR which pastes these pastes entries that
-    pass."""
-    b = bench()
-    assert set(m) == {"name", "unit", "better", "source", "layer", "moves",
-                      "workloads"}
-    assert NAME.match(m["name"]) and UNIT.match(m["unit"])
-    assert m["better"] in ("lower", "higher") and m["source"] in SOURCES
-    assert m["workloads"] == [CELL]
-    assert CELL in {w["name"] for w in b["workloads"]}
-    moved = [e for e in b["end_to_end"] if e["name"] == m["moves"]]
-    assert moved and CELL in moved[0]["workloads"]
-    assert "\n" not in m["layer"] and 1 <= len(m["layer"]) <= 200
-    with open(os.path.join(REPO, "benchmark", "parked",
-                           "engine100k-r3_layers.json")) as f:
-        known = {x["layer"] for x in json.load(f)["per_layer"]}
-    assert m["layer"] in known | {x["layer"] for x in b["per_layer"]}
-    with open(os.path.join(REPO, "benchmark", "layer_metrics",
-                           m["name"] + ".json")) as f:
-        spec = json.load(f)
-    assert (spec["name"], spec["unit"], spec["layer"], spec["moves"]) == (
-        m["name"], m["unit"], m["layer"], m["moves"])
-    assert "workloads" not in spec, "cells are named on the cell's side"
-    mod, _, fn = spec["reader"].partition(".")
-    assert m["source"] == {"trace": "device_trace",
-                           "telemetry": "program_counter",
-                           "reconf": "program_counter"}[mod]
-    assert callable(getattr(READERS[mod], fn))
-    with open(os.path.join(REPO, "PERF.md")) as f:
-        assert f"`{m['name']}`" in f.read()
+def _append_a_cell(b: dict) -> None:
+    """What the next ``model_config`` PR does: a configuration, a cell
+    and its name under ``group_rounds_per_s``, each at its list's end."""
+    b["configs"].append(dict(b["configs"][3], name="engine2m-r3",
+                             file="benchmark/configs/engine2m-r3.json"))
+    b["workloads"].append(dict(b["workloads"][3], name="engine2m-r3.drain",
+                               config="engine2m-r3", traffic="drain"))
+    b["end_to_end"][0]["workloads"].append("engine2m-r3.drain")
+
+
+# Index 3 is ``engine1m-r3``'s entry in each of the three lists.
+OPEN = {
+    "a configuration, a cell and its name appended": _append_a_cell,
+    "the cell after it taken away again": lambda b: (
+        b["configs"].pop(), b["workloads"].pop(),
+        b["end_to_end"][0]["workloads"].pop()),
+}
+TIGHT = {
+    "the configuration removed": lambda b: b["configs"].pop(3),
+    "the cell removed": lambda b: b["workloads"].pop(3),
+    "its name under the metric removed": lambda b: (
+        b["end_to_end"][0]["workloads"].pop(3)),
+    "the configuration renamed": lambda b: b["configs"][3].update(
+        name="engine1m-r3b"),
+    "the cell renamed": lambda b: b["workloads"][3].update(
+        name="engine1m-r3.joint"),
+    "the configurations re-ordered": lambda b: swap(b["configs"], 2, 3),
+    "the cells re-ordered": lambda b: swap(b["workloads"], 3, 4),
+    "the names under the metric re-ordered": lambda b: swap(
+        b["end_to_end"][0]["workloads"], 0, 3),
+    "the cell moved to four chips": lambda b: b["workloads"][3].update(
+        chips=4),
+    "a cell put before it": lambda b: b["workloads"].insert(
+        0, dict(b["workloads"][0], name="engine64k-r3.other")),
+}
+
+
+@pytest.mark.parametrize("edit", OPEN.values(), ids=OPEN.keys())
+def test_the_rule_lets_a_later_pr_append_a_cell(tmp_path, edit):
+    gained_rule(edited_copy(tmp_path, edit))
+
+
+@pytest.mark.parametrize("edit", TIGHT.values(), ids=TIGHT.keys())
+def test_the_rule_holds_the_cells_entries_where_they_are(tmp_path, edit):
+    with pytest.raises(AssertionError):
+        gained_rule(edited_copy(tmp_path, edit))
 
 
 @pytest.fixture(scope="module")
-def pasted_root(tmp_path_factory):
-    """``tiny_root`` with the seven entries pasted at the end of
-    ``per_layer``, as the ``benchmark`` PR that takes them up will."""
-    dst = tiny_root(str(tmp_path_factory.mktemp("reconf_layers")))
-    _edit(os.path.join(dst, "BENCHMARK.json"),
-          lambda b: b["per_layer"].extend(PARKED))
-    return dst
-
-
-@pytest.fixture(scope="module")
-def pasted_run(pasted_root):
-    cell = harness.Cell(pasted_root, CELL)
+def layer_run(root):
+    cell = harness.Cell(root, CELL)
     ctx, checks = harness.measure(cell, 2**31 + 32, 0.3, False,
                                   time.perf_counter(), require_tpu=False)
     assert verdict(checks), [c for c in checks if not c.ok]
     return cell, ctx
 
 
-def test_pasted_entries_reach_this_cell_alone(pasted_root):
-    cell = harness.Cell(pasted_root, CELL)
-    assert set(SEVEN) <= {m["name"] for m in cell.per_layer}
-    for name in ("engine64k-r3.append", "engine100k-r3.elections"):
-        other = harness.Cell(pasted_root, name)
-        assert not set(SEVEN) & {m["name"] for m in other.per_layer}
-
-
-def test_each_counter_reader_on_a_tiny_run(pasted_run):
+def test_each_counter_reader_on_a_tiny_run(layer_run):
     """No trace on the CPU: the six counter metrics are read, the trace
     share finds nothing and is left out."""
-    cell, ctx = pasted_run
+    cell, ctx = layer_run
     layer = harness.per_layer_metrics(cell, ctx)
     harness.refuse_bad_values(layer)
     assert set(SEVEN[1:]) <= set(layer) and SEVEN[0] not in layer
-    for m in PARKED[1:]:
-        got = layer[m["name"]]
-        assert got["unit"] == m["unit"] and got["value"] > 0.0
+    units = {m["name"]: m["unit"] for m in bench()["per_layer"]}
+    for name in SEVEN[1:]:
+        assert layer[name]["unit"] == units[name]
+        assert layer[name]["value"] > 0.0
     # About half the rounds in a joint configuration; a batch takes two
     # rounds and a little (the cut, the elections); commits fall short
     # of what is offered by the transfers and the cut.
@@ -649,16 +654,13 @@ def test_each_counter_reader_on_a_tiny_run(pasted_run):
     # Four changes a replica a period of 128 rounds.
     assert layer["reconf.applied_per_kgr"]["value"] == pytest.approx(
         1e3 * 4 * 3 / 128)
-    # The driver's ``[bench:reconf]`` line holds the same six.
-    assert engine_reconf.reconf_line(ctx["raw"]) == {
-        name: layer[name]["value"] for name in SEVEN[1:]}
 
 
-def test_the_trace_reader_on_a_reduced_trace(pasted_run):
+def test_the_trace_reader_on_a_reduced_trace(layer_run):
     """The control phase's share of the round from a reduced trace as
     ``reduce/trace.py`` gives it (the chip's scopes; seconds of PR 27's
     builder's traced run, rounded)."""
-    cell, ctx = pasted_run
+    cell, ctx = layer_run
     scope_s = {"raft_deliver": 1.4556, "raft_route": 0.3115,
                "unscoped": 0.1472, "raft_emit": 0.1027,
                "raft_telemetry": 0.0527, "raft_tick": 0.0315,
@@ -672,18 +674,6 @@ def test_the_trace_reader_on_a_reduced_trace(pasted_run):
     del scope_s["raft_control"]
     layer = harness.per_layer_metrics(cell, dict(ctx, trace=red))
     assert "round.control_pct" not in layer
-
-
-def test_the_reconf_line_is_printed_once_a_run(pasted_root, capsys):
-    cell = harness.Cell(pasted_root, CELL)
-    harness.measure(cell, 5, 0.3, False, time.perf_counter(),
-                    require_tpu=False)
-    lines = [ln for ln in capsys.readouterr().out.splitlines()
-             if ln.startswith("[bench:reconf] ")]
-    assert len(lines) == 1
-    got = json.loads(lines[0].split(" ", 1)[1])
-    assert sorted(got) == sorted(SEVEN[1:])
-    assert all(v is not None and v > 0 for v in got.values())
 
 
 # -- the cell driven tiny: the timed path broken, and the controls ------------------

@@ -4,10 +4,9 @@ new comparison shown to fail on a fault handed to it, the classes'
 derivation at R=4, the reference wrapper against the program's oracle
 round for round, the readers, the cell's entries in ``BENCHMARK.json``
 (appended after the cells that were there, nothing before them moved),
-the five parked per-layer metrics against the contract's rules on a
-temporary copy of ``BENCHMARK.json`` and each metric read on a tiny
-run, the ``[bench:replace]`` line, and the cell driven tiny with its
-timed path broken and under both controls. (The guide's share test
+the cell's five per-layer entries (live since PR 36) with the cell each
+lists and each metric read on a tiny run, and the cell driven tiny with
+its timed path broken and under both controls. (The guide's share test
 does not apply: nothing here is a share of a layer.)"""
 
 import json
@@ -32,8 +31,8 @@ from benchmark.replace_checks import (FRESH, empty_slot_checks, live_view,
                                       membership_checks, run_checks,
                                       window_checks)
 
-from .test_contract import NAME, SOURCES, UNIT
-from .util import REPO, _edit, bench, tiny_root
+from .test_contract import NAME
+from .util import CELLS_AT_36, REPO, bench, listed_cells, tiny_root
 
 CELL = "engine512k-r3of4.replace-readindex"
 SIZES = {"num_groups": 20, "num_replicas": 4}
@@ -467,28 +466,17 @@ def test_readers_find_nothing_in_another_drivers_run():
             assert fn(ctx) is None
 
 
-# -- the parked entries against the contract, and each read on a tiny run ------------
+# -- the cell's entries: where they stand, which cell they list, each read tiny -------
 
-
-def parked_layers() -> dict:
-    with open(os.path.join(REPO, "benchmark", "parked",
-                           engine_replace.PARKED)) as f:
-        return json.load(f)
-
-
-PARKED = parked_layers()["per_layer"]
 FIVE = ["replace.snapshots_per_swap", "replace.catchup_rounds",
         "replace.joint_pct", "replace.committed_pct",
         "replace.swapped_per_kgr"]
-READERS = {"replace": reader, "reconf": reconf_reader,
-           "telemetry": telemetry_reader}
 
 
-def test_the_five_are_parked_and_not_live():
-    assert set(parked_layers()) == {"note", "per_layer"}
-    assert [m["name"] for m in PARKED] == FIVE
-    live = {m["name"] for m in bench()["per_layer"]}
-    assert not live & set(FIVE)
+def test_the_five_are_live_with_exactly_these_workloads():
+    assert listed_cells(FIVE) == {name: [CELL] for name in FIVE}
+    assert not os.path.exists(os.path.join(
+        REPO, "benchmark", "parked", "engine512k-r3of4_layers.json"))
 
 
 def test_the_cell_follows_the_cells_that_were_there():
@@ -497,13 +485,11 @@ def test_the_cell_follows_the_cells_that_were_there():
     under its end-to-end metric. Held in the order-relative form (this
     cell's entries come right after ``engine1m-r3``'s, which come after
     the three before them), so that the next cell appended after this
-    one does not fail it: ``test_reconf.py``'s ``[-1]`` pins do fail
-    from this PR on, and only a ``benchmark`` PR may edit them
-    (ROADMAP R0b.11)."""
+    one does not fail it (``test_reconf.py`` holds ``engine1m-r3``'s
+    in the same form since PR 36, and shows it open and tight)."""
     b = bench()
     was = ["engine64k-r3", "engine10k-r5", "engine100k-r3", "engine1m-r3"]
-    cells = ["engine64k-r3.append", "engine10k-r5.append",
-             "engine100k-r3.elections", "engine1m-r3.joint-readindex"]
+    cells = CELLS_AT_36[:4]
     assert [c["name"] for c in b["configs"]][:5] == was + [
         "engine512k-r3of4"]
     assert [w["name"] for w in b["workloads"]][:5] == cells + [CELL]
@@ -512,7 +498,7 @@ def test_the_cell_follows_the_cells_that_were_there():
         "group_rounds_per_s", 0.01, cells + [CELL])
     assert setup == {"name": "setup_s", "unit": "s", "better": "lower",
                      "bound": 0.25, "source": "host_clock"}
-    assert not set(FIVE) & {m["name"] for m in b["per_layer"]}
+    assert set(FIVE) <= {m["name"] for m in b["per_layer"]}
     assert b["run_seconds"] == 30
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         assert len(f.read()) < 64 << 10
@@ -592,73 +578,24 @@ def test_the_cells_entries_are_the_issues():
         "replacement_cycle", "randomized_timeout"}
 
 
-@pytest.mark.parametrize("m", PARKED, ids=lambda m: m["name"])
-def test_parked_layer_entry(m):
-    """``test_contract.py::test_metric_entry``'s rules for a per-layer
-    entry, so that the PR which pastes these pastes entries that
-    pass."""
-    b = bench()
-    assert set(m) == {"name", "unit", "better", "source", "layer", "moves",
-                      "workloads"}
-    assert NAME.match(m["name"]) and UNIT.match(m["unit"])
-    assert m["better"] in ("lower", "higher") and m["source"] in SOURCES
-    assert m["workloads"] == [CELL]
-    assert CELL in {w["name"] for w in b["workloads"]}
-    moved = [e for e in b["end_to_end"] if e["name"] == m["moves"]]
-    assert moved and CELL in moved[0]["workloads"]
-    assert "\n" not in m["layer"] and 1 <= len(m["layer"]) <= 200
-    with open(os.path.join(REPO, "benchmark", "parked",
-                           "engine1m-r3_layers.json")) as f:
-        known = {x["layer"] for x in json.load(f)["per_layer"]}
-    assert m["layer"] in known | {x["layer"] for x in b["per_layer"]}
-    with open(os.path.join(REPO, "benchmark", "layer_metrics",
-                           m["name"] + ".json")) as f:
-        spec = json.load(f)
-    assert (spec["name"], spec["unit"], spec["layer"], spec["moves"]) == (
-        m["name"], m["unit"], m["layer"], m["moves"])
-    assert "workloads" not in spec, "cells are named on the cell's side"
-    mod, _, fn = spec["reader"].partition(".")
-    assert m["source"] == "program_counter"
-    assert callable(getattr(READERS[mod], fn))
-    with open(os.path.join(REPO, "PERF.md")) as f:
-        assert f"`{m['name']}`" in f.read()
-
-
 @pytest.fixture(scope="module")
-def pasted_root(tmp_path_factory):
-    """``tiny_root`` with the five entries pasted at the end of
-    ``per_layer``, as the ``benchmark`` PR that takes them up will."""
-    dst = tiny_root(str(tmp_path_factory.mktemp("replace_layers")))
-    _edit(os.path.join(dst, "BENCHMARK.json"),
-          lambda b: b["per_layer"].extend(PARKED))
-    return dst
-
-
-@pytest.fixture(scope="module")
-def pasted_run(pasted_root):
-    cell = harness.Cell(pasted_root, CELL)
+def layer_run(root):
+    cell = harness.Cell(root, CELL)
     ctx, checks = harness.measure(cell, 2**31 + 34, 0.3, False,
                                   time.perf_counter(), require_tpu=False)
     assert verdict(checks), [c for c in checks if not c.ok]
     return cell, ctx
 
 
-def test_pasted_entries_reach_this_cell_alone(pasted_root):
-    cell = harness.Cell(pasted_root, CELL)
-    assert set(FIVE) <= {m["name"] for m in cell.per_layer}
-    for name in ("engine64k-r3.append", "engine1m-r3.joint-readindex"):
-        other = harness.Cell(pasted_root, name)
-        assert not set(FIVE) & {m["name"] for m in other.per_layer}
-
-
-def test_each_reader_on_a_tiny_run(pasted_run):
-    cell, ctx = pasted_run
+def test_each_reader_on_a_tiny_run(layer_run):
+    cell, ctx = layer_run
     layer = harness.per_layer_metrics(cell, ctx)
     harness.refuse_bad_values(layer)
     assert set(FIVE) <= set(layer)
-    for m in PARKED:
-        got = layer[m["name"]]
-        assert got["unit"] == m["unit"] and got["value"] > 0.0
+    units = {m["name"]: m["unit"] for m in bench()["per_layer"]}
+    for name in FIVE:
+        assert layer[name]["unit"] == units[name]
+        assert layer[name]["value"] > 0.0
     # One snapshot carried each new replica: catch-up works.
     assert layer["replace.snapshots_per_swap"]["value"] == 1.0
     assert 4.0 <= layer["replace.catchup_rounds"]["value"] <= 12.0
@@ -668,20 +605,6 @@ def test_each_reader_on_a_tiny_run(pasted_run):
     # or four of four slots.
     assert 25.0 < layer["replace.joint_pct"]["value"] < 50.0
     assert 80.0 < layer["replace.committed_pct"]["value"] < 100.0
-    assert engine_replace.replace_line(ctx["raw"]) == {
-        name: layer[name]["value"] for name in FIVE}
-
-
-def test_the_replace_line_is_printed_once_a_run(pasted_root, capsys):
-    cell = harness.Cell(pasted_root, CELL)
-    harness.measure(cell, 5, 0.3, False, time.perf_counter(),
-                    require_tpu=False)
-    lines = [ln for ln in capsys.readouterr().out.splitlines()
-             if ln.startswith("[bench:replace] ")]
-    assert len(lines) == 1
-    got = json.loads(lines[0].split(" ", 1)[1])
-    assert sorted(got) == sorted(FIVE)
-    assert all(v is not None and v > 0 for v in got.values())
 
 
 # -- the cell driven tiny: the timed path broken, and the controls ------------------

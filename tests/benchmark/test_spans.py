@@ -1,7 +1,9 @@
 """The span layer's side of the benchmark (ISSUE 24): the sharing-out of
 device idle gaps among host spans on hand-made intervals and on the
-recorded chip trace, the parked span metrics against the contract, and
-each new reader on a tiny run."""
+recorded chip trace, the rule that holds PR 24's five live entries where
+they are and lets a later PR append (shown open and shown tight on a
+temporary copy of ``BENCHMARK.json``), the parked span metrics against
+the contract, and each new reader on a tiny run."""
 
 import gzip
 import json
@@ -27,7 +29,8 @@ from benchmark.reduce.gaps import (
 from benchmark.reduce.trace import MIN_GAP_NS, _union, reduce_trace
 
 from .test_contract import NAME, SOURCES, UNIT, WITH_PARKED
-from .util import REPO, XPROF, _edit, bench, tiny_root
+from .util import (REPO, XPROF, _edit, bench, edited_copy, swap,
+                   tiny_root)
 
 # 0.3 s of a G=8 served cluster under 16 putting clients on the chip
 # (TPU v5 lite): the trace ``tools/round_gaps.py`` kept of
@@ -35,8 +38,19 @@ from .util import REPO, XPROF, _edit, bench, tiny_root
 # groups, 16 clients and a 0.3 s trace (my chip run, PR 24), gzipped.
 SPAN_XPLANE = os.path.join(REPO, "artifacts", "tpu_r24_spans",
                            "g8_served_put.xplane.pb.gz")
-LIVE = ["engine.dispatch_ms", "engine.late_ms", "setup.engine_init_s",
-        "setup.elect_s", "setup.first_scan_s"]
+# PR 24's five live entries, as ``BENCHMARK.json`` has to hold them.
+LIVE_ENTRIES = [
+    {"name": name, "unit": unit, "better": "lower",
+     "source": "program_span", "layer": layer, "moves": moves}
+    for name, unit, layer, moves in (
+        ("engine.dispatch_ms", "ms", "closed-loop engine",
+         "group_rounds_per_s"),
+        ("engine.late_ms", "ms", "closed-loop engine",
+         "group_rounds_per_s"),
+        ("setup.engine_init_s", "s", "compile", "setup_s"),
+        ("setup.elect_s", "s", "compile", "setup_s"),
+        ("setup.first_scan_s", "s", "compile", "setup_s"))]
+LIVE = [m["name"] for m in LIVE_ENTRIES]
 
 
 def served_spans() -> dict:
@@ -228,11 +242,70 @@ def test_result_line_of_a_traced_run(chip, root):
 # -- the parked entries against the contract ---------------------------------------
 
 
-def test_live_entries_are_appended_and_nothing_else_changed():
-    names = [m["name"] for m in bench()["per_layer"]]
-    assert names[-len(LIVE):] == LIVE
-    assert all(m["source"] == "program_span"
-               for m in bench()["per_layer"][-len(LIVE):])
+# What ``per_layer`` held before PR 24's five: they stand right after.
+BEFORE_LIVE = ["round.device_ms", "round.route_pct", "round.deliver_pct",
+               "route.roofline_pct", "engine.call_gap_ms",
+               "device.hbm_peak_gb", "compile.in_window",
+               "compile.cache_misses"]
+
+
+def live_entries_rule(b: dict) -> None:
+    """PR 24's five are present, unchanged (``program_span``, no
+    ``workloads``), consecutive and in their order, right after the
+    eight entries that were there before them. What follows them is
+    any later PR's to append: the rule says nothing of it."""
+    rows = b["per_layer"]
+    at = len(BEFORE_LIVE)
+    assert [m["name"] for m in rows[:at]] == BEFORE_LIVE
+    assert rows[at:at + len(LIVE)] == LIVE_ENTRIES
+    assert not set(LIVE) & {m["name"] for m in rows[at + len(LIVE):]}
+
+
+def test_live_entries_are_present_unchanged_and_in_their_order():
+    live_entries_rule(bench())
+
+
+ONE_MORE = {"name": "engine.one_more_ms", "unit": "ms", "better": "lower",
+            "source": "program_span", "layer": "closed-loop engine",
+            "moves": "group_rounds_per_s",
+            "workloads": ["engine64k-r3.append"]}
+
+
+# What the rule is for, and what it still refuses: index 8 is the
+# first of the five, 12 the last.
+OPEN = {
+    "an entry appended": lambda b: b["per_layer"].append(dict(ONE_MORE)),
+    "three entries appended": lambda b: b["per_layer"].extend(
+        dict(ONE_MORE, name=f"engine.one_more_{i}_ms") for i in range(3)),
+    "an appended entry removed again": lambda b: b["per_layer"].pop(),
+}
+TIGHT = {
+    "one of the five removed": lambda b: b["per_layer"].pop(9),
+    "one renamed": lambda b: b["per_layer"][10].update(
+        name="setup.engine_build_s"),
+    "two re-ordered": lambda b: swap(b["per_layer"], 11, 12),
+    "a source changed": lambda b: b["per_layer"][8].update(
+        source="host_clock"),
+    "one given a list of cells": lambda b: b["per_layer"][12].update(
+        workloads=["engine64k-r3.append"]),
+    "one moved to the end": lambda b: b["per_layer"].append(
+        b["per_layer"].pop(8)),
+    "an entry put before them": lambda b: b["per_layer"].insert(
+        3, dict(ONE_MORE)),
+    "one of the five a second time": lambda b: b["per_layer"].append(
+        dict(LIVE_ENTRIES[0])),
+}
+
+
+@pytest.mark.parametrize("edit", OPEN.values(), ids=OPEN.keys())
+def test_the_rule_lets_a_later_pr_append(tmp_path, edit):
+    live_entries_rule(edited_copy(tmp_path, edit))
+
+
+@pytest.mark.parametrize("edit", TIGHT.values(), ids=TIGHT.keys())
+def test_the_rule_holds_the_five_where_they_are(tmp_path, edit):
+    with pytest.raises(AssertionError):
+        live_entries_rule(edited_copy(tmp_path, edit))
 
 
 @pytest.mark.parametrize("m", PARKED, ids=lambda m: m["name"])

@@ -10,6 +10,13 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 XPROF = os.path.join(REPO, "artifacts", "tpu_r05", "xprof")
 
+# The cells live when PR 36 made the per-layer list open, in their
+# order: what an entry of that PR that "lists every cell" lists. A cell
+# a later PR appends is that PR's to list under its own entries.
+CELLS_AT_36 = ["engine64k-r3.append", "engine10k-r5.append",
+               "engine100k-r3.elections", "engine1m-r3.joint-readindex",
+               "engine512k-r3of4.replace-readindex"]
+
 TINY_TRAFFIC = {
     "put": {"clients": 16, "ramp_s": 0.3},
     "lread": {"clients": 4, "preload_keys_per_group": 2, "ramp_s": 0.3},
@@ -61,3 +68,27 @@ def add_parked(b: dict) -> None:
 def bench() -> dict:
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         return json.load(f)
+
+
+def edited_copy(tmp_path, edit) -> dict:
+    """``BENCHMARK.json`` as a temporary copy of it reads after
+    ``edit``: what a rule on the file is shown to admit and to refuse."""
+    path = os.path.join(str(tmp_path), "BENCHMARK.json")
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), path)
+    _edit(path, edit)
+    with open(path) as f:
+        return json.load(f)
+
+
+def swap(rows: list, i: int, j: int) -> None:
+    rows[i], rows[j] = rows[j], rows[i]
+
+
+def listed_cells(names) -> dict:
+    """{name: its ``workloads``} of the live per-layer entries of these
+    names, which have to stand in ``per_layer`` in this order, one
+    after the other."""
+    rows = bench()["per_layer"]
+    at = [m["name"] for m in rows].index(names[0])
+    assert [m["name"] for m in rows[at:at + len(names)]] == list(names)
+    return {m["name"]: m["workloads"] for m in rows[at:at + len(names)]}
