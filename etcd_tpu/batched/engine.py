@@ -227,6 +227,14 @@ class MultiRaftEngine:
         self._calls = call + 1
         return spans.span(name, 0, call, engine=self._serial, **stats)
 
+    def _pretrace(self) -> "spans.Span":
+        """The span over an abstract pre-trace of one tile's round
+        (``jax.eval_shape`` in the two tiled paths): opened while the
+        jit that needs it is being traced, so under the
+        ``engine.step_round`` or ``engine.run_rounds`` that pays for
+        it, whose ``(member, round)`` it takes; it numbers no call."""
+        return spans.span("engine.pretrace", engine=self._serial)
+
     def _init(self, cfg: BatchedConfig, start_index: int, spare) -> None:
         # deliver_shape="auto" becomes "vectorized" here, so self.cfg
         # reads as the compile key does.
@@ -261,34 +269,43 @@ class MultiRaftEngine:
         def tiled_round(st, lanes, per_row):
             """The eager round tile by tile (`tiled_loop`'s idiom):
             `per_row` is (masks, conf_req, wipe), every leaf [N]."""
-            slots = jnp.arange(rows, dtype=I32) % cfg.num_replicas
+            with jax.named_scope("raft_carry"):
+                slots = jnp.arange(rows, dtype=I32) % cfg.num_replicas
 
             def one(lo, st, lanes, per_row):
                 masks, conf_req, wipe = per_row
-                return tile_step(lo, slots)(
-                    st, lanes, *masks, lane_any=lane_occupancy(lanes),
-                    conf_req=conf_req, wipe=wipe)
+                with jax.named_scope("raft_carry"):
+                    return tile_step(lo, slots)(
+                        st, lanes, *masks, lane_any=lane_occupancy(lanes),
+                        conf_req=conf_req, wipe=wipe)
 
             # The shapes of what a tile answers, for the outbox and the
             # frames the loop writes into; and the round traced once
             # outside any loop (see `tiled_loop`).
-            answer = jax.eval_shape(
-                one, 0, *jax.tree.map(like, (st, lanes, per_row)))[1:]
-            whole = jax.tree.map(
-                lambda x: jnp.zeros((n,) + x.shape[1:], x.dtype), answer)
+            with self._pretrace():
+                answer = jax.eval_shape(
+                    one, 0, *jax.tree.map(like, (st, lanes, per_row)))[1:]
+            with jax.named_scope("raft_tiles"):
+                whole = jax.tree.map(
+                    lambda x: jnp.zeros((n,) + x.shape[1:], x.dtype),
+                    answer)
 
             def tile(i, carry):
-                lo = i * rows
-                out = one(lo, *jax.tree.map(
-                    lambda x: jax.lax.dynamic_slice_in_dim(x, lo, rows),
-                    (carry[0], lanes, per_row)))
-                return jax.tree.map(
-                    lambda x, y: jax.lax.dynamic_update_slice_in_dim(
-                        x, y, lo, 0),
-                    carry, (out[0], out[1:]))
+                with jax.named_scope("raft_tiles"):
+                    lo = i * rows
+                    mine = jax.tree.map(
+                        lambda x: jax.lax.dynamic_slice_in_dim(x, lo, rows),
+                        (carry[0], lanes, per_row))
+                out = one(lo, *mine)
+                with jax.named_scope("raft_tiles"):
+                    return jax.tree.map(
+                        lambda x, y: jax.lax.dynamic_update_slice_in_dim(
+                            x, y, lo, 0),
+                        carry, (out[0], out[1:]))
 
             st, out = jax.lax.fori_loop(0, tiles, tile, (st, whole))
-            return (st, stack_lanes(out[0])) + out[1:]
+            with jax.named_scope("raft_carry"):
+                return (st, stack_lanes(out[0])) + out[1:]
 
         def step_round(st, inbox, *masks, conf_req=None, wipe=None):
             # The eager round hands the round program what the scan
@@ -300,13 +317,15 @@ class MultiRaftEngine:
             # replace_replicas; None is no input.
             # Handed lanes it answers in lanes; route(), a program
             # of its own here, takes them stacked.
-            lanes = split_lanes(inbox)
+            with jax.named_scope("raft_carry"):
+                lanes = split_lanes(inbox)
             if tiles > 1:
                 return tiled_round(st, lanes, (masks, conf_req, wipe))
-            out = self._step(st, lanes, *masks,
-                             lane_any=lane_occupancy(lanes),
-                             conf_req=conf_req, wipe=wipe)
-            return (out[0], stack_lanes(out[1])) + out[2:]
+            with jax.named_scope("raft_carry"):
+                out = self._step(st, lanes, *masks,
+                                 lane_any=lane_occupancy(lanes),
+                                 conf_req=conf_req, wipe=wipe)
+                return (out[0], stack_lanes(out[1])) + out[2:]
 
         # In tiles the loop's carry is the state: donated, it is updated
         # in place as the scan's is (`step_round` below reassigns state
@@ -373,54 +392,63 @@ class MultiRaftEngine:
                 # `occ` is the inbox's lane occupancy, [K] bool: what
                 # deliver's lane conds skip on and route_lanes' are
                 # told was there.
+                # Every line here stands under a scope of
+                # step.DEVICE_SCOPES (the round's own are innermost and
+                # win): what a trace then files under no scope, the
+                # compiler made (tests/batched/test_scopes.py).
                 st, inbox, occ, tel, flt, lanes, watch = carry
                 cut, ctl = row
-                if not tiled:
-                    lanes = lanes + occ
-                iso = zeros_b
-                # jitlint: waive(tracer-branch) -- as above: a scan without xs hands its body None
-                if cut is not None:
-                    # Row t widened to [N] where it is used: node s is
-                    # slot s of every group.
-                    for s in range(cfg.num_replicas):
-                        iso = iso | ((slots == s) & cut[s])
-                transfer, reads, conf = zeros_i, zeros_b, None
-                wipe = None
+                with jax.named_scope("raft_carry"):
+                    if not tiled:
+                        lanes = lanes + occ
+                    iso = zeros_b
+                    # jitlint: waive(tracer-branch) -- as above: a scan without xs hands its body None
+                    if cut is not None:
+                        # Row t widened to [N] where it is used: node s
+                        # is slot s of every group.
+                        for s in range(cfg.num_replicas):
+                            iso = iso | ((slots == s) & cut[s])
+                    transfer, reads, conf = zeros_i, zeros_b, None
+                    wipe = None
+                    # jitlint: waive(tracer-branch) -- as above
+                    if ctl is not None:
+                        # The row's few scalars widened the same way.
+                        drained = slots == ctl[CTL_FROM] - 1
+                        transfer = jnp.where(drained, ctl[CTL_TO], 0)
+                        reads = jnp.broadcast_to(
+                            ctl[CTL_READS] != 0, zeros_b.shape)
+                        if cfg.conf_entries:
+                            conf = jnp.where(drained, 0, ctl[CTL_CONF])
+                        if cfg.replace_replicas:
+                            iso = iso | (slots == ctl[CTL_RETIRE] - 1)
+                            wipe = slots == ctl[CTL_WIPE] - 1
+                        pre = st
+                    out = step(
+                        st, inbox, ticks, zeros_b, props, iso,
+                        transfer, reads, lane_any=occ, conf_req=conf,
+                        wipe=wipe,
+                    )
+                    st, outbox = out[:2]
                 # jitlint: waive(tracer-branch) -- as above
                 if ctl is not None:
-                    # The row's few scalars widened the same way.
-                    drained = slots == ctl[CTL_FROM] - 1
-                    transfer = jnp.where(drained, ctl[CTL_TO], 0)
-                    reads = jnp.broadcast_to(
-                        ctl[CTL_READS] != 0, zeros_b.shape)
-                    if cfg.conf_entries:
-                        conf = jnp.where(drained, 0, ctl[CTL_CONF])
-                    if cfg.replace_replicas:
-                        iso = iso | (slots == ctl[CTL_RETIRE] - 1)
-                        wipe = slots == ctl[CTL_WIPE] - 1
-                    pre = st
-                out = step(
-                    st, inbox, ticks, zeros_b, props, iso,
-                    transfer, reads, lane_any=occ, conf_req=conf,
-                    wipe=wipe,
-                )
-                st, outbox = out[:2]
-                # jitlint: waive(tracer-branch) -- as above
-                if ctl is not None:
-                    watch = self._watch_round(
-                        watch, pre, st, slots, ctl[CTL_STALL] != 0, wipe)
-                if cfg.telemetry:
-                    fr = out[self._tel_pos]
-                    tel = (tel[0] + fr.counters, tel[1] | fr.invariants)
-                if cfg.fleet_summary:
-                    fv = out[self._fleet_pos]
-                    flt = jnp.where(self._fleet_summask, flt + fv, fv)
+                    with jax.named_scope("raft_watch"):
+                        watch = self._watch_round(
+                            watch, pre, st, slots, ctl[CTL_STALL] != 0,
+                            wipe)
+                with jax.named_scope("raft_carry"):
+                    if cfg.telemetry:
+                        fr = out[self._tel_pos]
+                        tel = (tel[0] + fr.counters,
+                               tel[1] | fr.invariants)
+                    if cfg.fleet_summary:
+                        fv = out[self._fleet_pos]
+                        flt = jnp.where(self._fleet_summask, flt + fv, fv)
+                    sent = lane_occupancy(outbox)
                 # The lanes somebody wrote this round are exchanged,
                 # those that held last round's messages wiped, the rest
                 # left as they are (step.route_lanes). The exchange
                 # permutes slots inside a lane, so the outbox's
-                # occupancy is the next inbox's.
-                sent = lane_occupancy(outbox)
+                # occupancy (`sent`) is the next inbox's.
                 inbox = route_lanes(cfg, outbox, sent, (inbox, occ))
                 # A tile cannot count the rounds a lane was occupied
                 # for ANY instance: it hands each round's own vector
@@ -458,19 +486,24 @@ class MultiRaftEngine:
             where one scan over all rows exchanged emit's unsent
             fields under ``valid`` false
             (tests/batched/test_scan_tiles.py)."""
-            slots = jnp.arange(rows, dtype=I32) % cfg.num_replicas
-            zeros_b, zeros_i = jnp.zeros((rows,), bool), jnp.zeros((rows,), I32)
+            with jax.named_scope("raft_carry"):
+                slots = jnp.arange(rows, dtype=I32) % cfg.num_replicas
+                zeros_b = jnp.zeros((rows,), bool)
+                zeros_i = jnp.zeros((rows,), I32)
 
             def tile_body(lo, ticks, props):
                 """The scan's body for the rows from `lo` on."""
-                return round_body(tile_step(lo, slots), zeros_b, zeros_i,
-                                  slots, ticks, props, tiled=True)
+                with jax.named_scope("raft_carry"):
+                    step = tile_step(lo, slots)
+                return round_body(step, zeros_b, zeros_i, slots, ticks,
+                                  props, tiled=True)
 
             def tile_rounds(lo, st, inbox, tel, watch, ticks, props):
                 """The call's rounds on the rows from `lo` on, handed
                 in as the tile's slices (`watch` with the whole
                 counts); and each round's lane occupancy."""
-                inbox, occ = enter(inbox)
+                with jax.named_scope("raft_carry"):
+                    inbox, occ = enter(inbox)
                 (st, inbox, _, tel, _, _, watch), occs = jax.lax.scan(
                     tile_body(lo, ticks, props),
                     (st, inbox, occ, tel, (), (), watch),
@@ -479,29 +512,33 @@ class MultiRaftEngine:
 
             def tile(i, carry):
                 st, inbox, tel, watch, seen = carry
-                lo = i * rows
-                cut = lambda x: jax.lax.dynamic_slice_in_dim(x, lo, rows)  # noqa: E731
-                # The counts run on from tile to tile: a sum, whatever
-                # the order. (None is an empty pytree, as in closed_loop.)
-                t_watch = None if watch is None else watch._replace(
-                    read_floor=cut(watch.read_floor),
-                    history=cut(watch.history))
-                t_st, t_inbox, t_tel, t_watch, occs = tile_rounds(
-                    lo, *jax.tree.map(cut, (st, inbox, tel)), t_watch,
-                    cut(ticks), cut(props))
-                # In place: the carry is the donated state, and no
-                # second copy of it exists.
-                paste = lambda x, y: jax.lax.dynamic_update_slice_in_dim(  # noqa: E731
-                    x, y, lo, 0)
-                st, inbox, tel = jax.tree.map(
-                    paste, (st, inbox, tel), (t_st, t_inbox, t_tel))
-                watch = None if watch is None else ScanWatch(
-                    t_watch.counts,
-                    paste(watch.read_floor, t_watch.read_floor),
-                    paste(watch.history, t_watch.history))
-                return st, inbox, tel, watch, seen | occs
+                with jax.named_scope("raft_tiles"):
+                    lo = i * rows
+                    cut = lambda x: jax.lax.dynamic_slice_in_dim(x, lo, rows)  # noqa: E731
+                    # The counts run on from tile to tile: a sum,
+                    # whatever the order. (None is an empty pytree, as
+                    # in closed_loop.)
+                    t_watch = None if watch is None else watch._replace(
+                        read_floor=cut(watch.read_floor),
+                        history=cut(watch.history))
+                    mine = (*jax.tree.map(cut, (st, inbox, tel)), t_watch,
+                            cut(ticks), cut(props))
+                t_st, t_inbox, t_tel, t_watch, occs = tile_rounds(lo, *mine)
+                with jax.named_scope("raft_tiles"):
+                    # In place: the carry is the donated state, and no
+                    # second copy of it exists.
+                    paste = lambda x, y: jax.lax.dynamic_update_slice_in_dim(  # noqa: E731
+                        x, y, lo, 0)
+                    st, inbox, tel = jax.tree.map(
+                        paste, (st, inbox, tel), (t_st, t_inbox, t_tel))
+                    watch = None if watch is None else ScanWatch(
+                        t_watch.counts,
+                        paste(watch.read_floor, t_watch.read_floor),
+                        paste(watch.history, t_watch.history))
+                    return st, inbox, tel, watch, seen | occs
 
-            inbox = split_lanes(inbox)
+            with jax.named_scope("raft_carry"):
+                inbox = split_lanes(inbox)
             # Tracing only. As the body of the loops the round takes
             # JAX 12.5 s to trace on the TPU's host, by itself 2.6 s
             # (PERF.md section 6, "PR 35": every warm start would pay
@@ -511,22 +548,32 @@ class MultiRaftEngine:
             # jitlint: waive(tracer-branch) -- None is an empty pytree, as in closed_loop
             t_watch = None if watch is None else ScanWatch(
                 watch.counts, like(watch.read_floor), like(watch.history))
-            jax.eval_shape(
-                lambda ticks, props, carry, row: tile_body(0, ticks, props)(
-                    carry, row),
-                like(ticks), like(props),
-                (*jax.tree.map(like, (st, inbox)),
-                 jax.ShapeDtypeStruct((NUM_KINDS,), bool),
-                 jax.tree.map(like, tel), (), (), t_watch),
-                jax.tree.map(
-                    lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
-                    (isolate, control)))
+            with self._pretrace():
+                jax.eval_shape(
+                    lambda ticks, props, carry, row: tile_body(
+                        0, ticks, props)(carry, row),
+                    like(ticks), like(props),
+                    (*jax.tree.map(like, (st, inbox)),
+                     jax.ShapeDtypeStruct((NUM_KINDS,), bool),
+                     jax.tree.map(like, tel), (), (), t_watch),
+                    jax.tree.map(
+                        lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
+                        (isolate, control)))
+            with jax.named_scope("raft_tiles"):
+                seen = jnp.zeros((rounds, NUM_KINDS), bool)
             st, inbox, tel, watch, seen = jax.lax.fori_loop(
-                0, tiles, tile,
-                (st, inbox, tel, watch, jnp.zeros((rounds, NUM_KINDS), bool)))
-            return (st, stack_lanes(inbox), tel, (),
-                    lanes + jnp.sum(seen, axis=0, dtype=I32), st.commit[0],
-                    watch)
+                0, tiles, tile, (st, inbox, tel, watch, seen))
+            # Three blocks for two names, in the order the lines had
+            # before they had names: the lowered text follows the order
+            # of the lines, and JAX's cache key the text (the names are
+            # stripped from it: tests/batched/test_scopes.py pins both
+            # tiled texts).
+            with jax.named_scope("raft_carry"):
+                inbox = stack_lanes(inbox)
+            with jax.named_scope("raft_tiles"):
+                lanes = lanes + jnp.sum(seen, axis=0, dtype=I32)
+            with jax.named_scope("raft_carry"):
+                return st, inbox, tel, (), lanes, st.commit[0], watch
 
         def closed_loop(st, inbox, ticks, props, tel, flt, lanes, isolate,
                         rounds, control=None, watch=None):
@@ -542,22 +589,25 @@ class MultiRaftEngine:
             slots = None
             # jitlint: waive(tracer-branch) -- None is an empty pytree: the branch is on the argument's structure at trace time, never on a device value
             if isolate is not None or control is not None:
-                slots = jnp.arange(n, dtype=I32) % cfg.num_replicas
+                with jax.named_scope("raft_carry"):
+                    slots = jnp.arange(n, dtype=I32) % cfg.num_replicas
             body = round_body(self._step, self._zeros_b, self._zeros_i,
                               slots, ticks, props, tiled=False)
             # Inbox and outbox ride the scan as K kind lanes, each an
             # array of its own (the round answers lanes with lanes),
             # and the inbox is stacked back once at the exit.
-            inbox, occ = enter(split_lanes(inbox))
+            with jax.named_scope("raft_carry"):
+                inbox, occ = enter(split_lanes(inbox))
             (st, inbox, _, tel, flt, lanes, watch), _ = jax.lax.scan(
                 body, (st, inbox, occ, tel, flt, lanes, watch),
                 (isolate, control), length=rounds
             )
-            inbox = stack_lanes(inbox)
             # The scalar fence is a SEPARATE output buffer: pipelined
             # callers block on it to bound queue depth without holding
             # (and thereby breaking) a donated state buffer.
-            return st, inbox, tel, flt, lanes, st.commit[0], watch
+            with jax.named_scope("raft_carry"):
+                return (st, stack_lanes(inbox), tel, flt, lanes,
+                        st.commit[0], watch)
 
         # State and inbox are donated: run_rounds/run_rounds_pipelined
         # reassign both from the return value, so XLA writes round k+1

@@ -1097,7 +1097,7 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
     on: the outbox's lanes ``k + NUM_REQ_KINDS``. No lax.scan sits
     anywhere in the round, so
     deliver→tick→control→propose→emit trace into ONE straight-line
-    fused region, and the named_scope annotations (ROUND_PHASE_SCOPES)
+    fused region, and the named_scope annotations (DEVICE_SCOPES)
     are attribution labels inside it.
 
     ``lane_any`` ([K] bool, optional) is the batch-level lane-occupancy
@@ -1702,18 +1702,31 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, conf_applied=None):
     return st, (vote, append, heartbeat)
 
 
-# Annotation registry for tools/phaseprobe.py and trace tooling: the
-# named_scope segments of one round, in execution order. Labels match
-# the jax.named_scope strings below exactly, so xprof captures, the
-# phaseprobe artifact, and the SURVEY/ROADMAP prose all name the same
-# segments.
-ROUND_PHASE_SCOPES = (
-    ("deliver", "raft_deliver"),
-    ("tick", "raft_tick"),
-    ("control", "raft_control"),
-    ("propose", "raft_propose"),
-    ("emit", "raft_emit"),
-    ("route", "raft_route"),
+# Every jax.named_scope of the device programs, (layer, segment,
+# scope): the round's in execution order (lease after emit, the two
+# planes where their configuration turns them on), then the closed-loop
+# engine's (engine.py: the tile loops' slices in and updates out with
+# what a call puts together across tiles; the ScanWatch; the rest of
+# the scan's body and of a call round the round itself). The strings
+# are those of the named_scope calls,
+# letter for letter, and of the shape benchmark/reduce/trace.py files a
+# device op by (``raft_`` and lower-case letters; the innermost wins).
+# tools/phaseprobe.py names its segments from it, and
+# tests/batched/test_scopes.py holds every equation of the closed loop
+# to it: an op a trace files under no scope is one the compiler made.
+DEVICE_SCOPES = (
+    ("round program", "deliver", "raft_deliver"),
+    ("round program", "tick", "raft_tick"),
+    ("round program", "control", "raft_control"),
+    ("round program", "propose", "raft_propose"),
+    ("round program", "emit", "raft_emit"),
+    ("round program", "lease", "raft_lease"),
+    ("round program", "telemetry", "raft_telemetry"),
+    ("round program", "fleet", "raft_fleet"),
+    ("round program", "route", "raft_route"),
+    ("closed-loop engine", "tiles", "raft_tiles"),
+    ("closed-loop engine", "watch", "raft_watch"),
+    ("closed-loop engine", "carry", "raft_carry"),
 )
 
 # -----------------------------------------------------------------------------
@@ -1776,8 +1789,12 @@ def _route_jit(r: int):
         # compiler sinks it there), where nothing gives it a layout,
         # and on TPU it then comes out instance-major and is copied
         # back (R=3 padded to a 4x128 tile: PERF.md section 6, PR 33).
-        outbox = jax.lax.optimization_barrier(outbox)
+        # (No instruction of the compiled scan comes of the barrier
+        # itself: a scope of its own read nothing in any cell, PERF.md
+        # section 6, "PR 38"; it stands under route's name with the
+        # rest of this function.)
         with jax.named_scope("raft_route"):
+            outbox = jax.lax.optimization_barrier(outbox)
             return tuple(
                 jax.lax.switch(
                     jnp.where(lane_any[k], 2, stale[k].astype(I32)),

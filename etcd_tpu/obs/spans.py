@@ -279,15 +279,28 @@ class Recorder:
         ``cpu``: the phases whose thread CPU time is read too."""
         return Phases(self, prefix, member, round, cpu)
 
-    def record(self, name: str, t0: int, t1: int, member: int, round: int,
+    def record(self, name: str, t0: int, t1: int,
+               member: Optional[int] = None, round: int = -1,
                **stats) -> None:
         """A span whose ends were read elsewhere (a wait that begins on
-        one thread and ends on another); written to the calling
-        thread's ring with no profiler event."""
+        one thread and ends on another; a duration somebody else
+        clocked); written to the calling thread's ring with no profiler
+        event. Given its ``member`` it stands under no span, with the
+        ``(member, round)`` given. With none it is a span of the
+        calling thread like any other: parent and ``(member, round)``
+        are those of the innermost span open there (-1 and (0, -1)
+        under none)."""
         ring = self._ring()
+        parent = -1
+        if member is None:
+            member, round = 0, -1
+            if ring.stack:
+                p = ring.stack[-1]
+                parent, member, round = p.seq, p.member, p.round
         seq = ring.seq
         ring.seq += 1
-        ring.put((name, seq, -1, member, round, t0, t1, -1, stats or None))
+        ring.put((name, seq, parent, member, round, t0, t1, -1,
+                  stats or None))
 
     # -- reading ---------------------------------------------------------------
 

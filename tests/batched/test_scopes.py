@@ -1,0 +1,286 @@
+"""Every op the closed loop writes has a name (ISSUE 38).
+
+``step.DEVICE_SCOPES`` is the one registry of the device programs'
+``jax.named_scope``s: the round's nine and the closed-loop engine's
+three. A profiler trace files a device op under the innermost
+``raft_*`` name of its ``tf_op`` (``benchmark/reduce/trace.py``) and
+under ``unscoped`` where there is none; these tests hold every equation
+of the traced closed loop, of each live configuration's flag set, to a
+registered scope, so that ``unscoped`` in a trace is what the compiler
+made (the carry's copies, a loop's own condition) and a line added to
+the scan without a name fails here, on the CPU, at 8 groups.
+
+Round-step programs (``conftest.py``): the five live configurations at
+the CPU tests' 8 groups, every one a key already (``test_scan_tiles``
+builds the same five); nothing here compiles, the loops are traced to
+jaxprs only.
+"""
+
+import contextlib
+import hashlib
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.extend import core as jex_core
+
+from etcd_tpu.batched import BatchedConfig, MultiRaftEngine
+from etcd_tpu.batched import engine as engine_mod
+from etcd_tpu.batched import step as step_mod
+from etcd_tpu.batched.engine import control_cols
+
+from .test_scan_tiles import CONFIGS, SPARE, sizes
+
+SCOPES = tuple(scope for _layer, _name, scope in step_mod.DEVICE_SCOPES)
+ENGINE = tuple(scope for layer, _name, scope in step_mod.DEVICE_SCOPES
+               if layer == "closed-loop engine")
+# ``benchmark/reduce/trace.py``'s SCOPE_RE, copied: the program's tests
+# import nothing of the benchmark.
+SCOPE_RE = re.compile(r"(?:^|[/(])(raft_[a-z_]+)(?=[/):]|$)")
+ROUNDS = 4
+# Tiles of each configuration's closed loop here (the tile constants
+# are patched as test_scan_tiles does): the two large cells run in
+# tiles on the chip, the three small ones in one scan.
+TILES = dict(zip(CONFIGS, (1, 1, 1, 2, 2)))
+
+
+def _bodies(eqn):
+    """The jaxprs an equation encloses: a scan's or a while's bodies,
+    a cond's branches, a jitted callee."""
+    for v in eqn.params.values():
+        for x in (v if isinstance(v, (tuple, list)) else (v,)):
+            if isinstance(x, jex_core.ClosedJaxpr):
+                yield x.jaxpr
+            elif isinstance(x, jex_core.Jaxpr):
+                yield x
+
+
+def _counts_the_loop(eqn, jaxpr) -> bool:
+    """``fori_loop``'s own ``i + 1`` on its body's counter: the loop's
+    equation itself, written by no line of the program."""
+    return (eqn.primitive.name == "add"
+            and not str(eqn.source_info.name_stack)
+            and any(isinstance(v, jex_core.Literal) and v.val == 1
+                    for v in eqn.invars)
+            and any(v in jaxpr.invars for v in eqn.invars
+                    if not isinstance(v, jex_core.Literal)))
+
+
+def scoped(jaxpr, outer: str = ""):
+    """(scope -> equations, [(primitive, name stack) under none]) of a
+    jaxpr, walked through every body: an equation's name stack is its
+    own after those of the loop, branch and call equations round it,
+    as lowering composes an op's ``tf_op``; the innermost registered
+    name wins; an equation that encloses others is read through them."""
+    by_scope, bare = {}, []
+    for eqn in jaxpr.eqns:
+        stack = f"{outer}/{eqn.source_info.name_stack}"
+        bodies = list(_bodies(eqn))
+        for body in bodies:
+            got, more = scoped(body, stack)
+            bare += more
+            for k, v in got.items():
+                by_scope[k] = by_scope.get(k, 0) + v
+        if bodies or _counts_the_loop(eqn, jaxpr):
+            continue
+        hits = [h for h in SCOPE_RE.findall(stack) if h in SCOPES]
+        if hits:
+            by_scope[hits[-1]] = by_scope.get(hits[-1], 0) + 1
+        else:
+            bare.append((eqn.primitive.name, stack))
+    return by_scope, bare
+
+
+def engine_of(name: str, monkeypatch) -> MultiRaftEngine:
+    cfg = BatchedConfig(**dict(sizes(name), num_groups=8))
+    monkeypatch.setattr(engine_mod, "TILE_ALIGN", 1)
+    monkeypatch.setattr(
+        engine_mod, "TILE_ROWS",
+        cfg.num_instances // TILES[name] if TILES[name] > 1 else 1 << 40)
+    eng = MultiRaftEngine(
+        cfg, **({"spare": SPARE} if cfg.replace_replicas else {}))
+    assert eng._tiles == TILES[name]
+    return eng
+
+
+def loop_jaxpr(eng: MultiRaftEngine):
+    """The closed loop traced with the schedules its cell hands it:
+    none (the two append cells), the fault schedule (the election
+    cell), both (the two cells with a control plane)."""
+    cfg = eng.cfg
+    sched = ctl = watch = None
+    if cfg.telemetry:
+        sched, _ = eng._schedule(
+            np.zeros((ROUNDS, cfg.num_replicas), bool), ROUNDS)
+    if cfg.conf_entries:
+        ctl, _ = eng._control_schedule(
+            np.zeros((ROUNDS, control_cols(cfg)), np.int32), ROUNDS)
+        watch = eng._watch
+    return jax.make_jaxpr(eng._closed_loop, static_argnums=(8,))(
+        eng.state, eng.inbox, eng._zeros_b, eng._zeros_i, eng._tel(),
+        eng._flt(), eng._lanes, sched, ROUNDS, ctl, watch).jaxpr
+
+
+def round_jaxpr(eng: MultiRaftEngine):
+    """The eager round (``engine.step_round``'s program)."""
+    cfg, zb, zi = eng.cfg, eng._zeros_b, eng._zeros_i
+    return jax.make_jaxpr(eng._round)(
+        eng.state, eng.inbox, zb, zb, zi, zb, zi, zb,
+        conf_req=zi if cfg.conf_entries else None,
+        wipe=zb if cfg.replace_replicas else None).jaxpr
+
+
+def expected(eng: MultiRaftEngine, loop: bool) -> set:
+    """The scopes a configuration's program holds, from its flags."""
+    cfg = eng.cfg
+    want = {"raft_deliver", "raft_tick", "raft_control", "raft_propose",
+            "raft_emit", "raft_lease", "raft_carry"}
+    if loop:
+        want.add("raft_route")
+    if cfg.telemetry:
+        want.add("raft_telemetry")
+    if eng._tiles > 1:
+        want.add("raft_tiles")
+    if loop and cfg.conf_entries:  # the cells with a control schedule
+        want.add("raft_watch")
+    return want
+
+
+# -- the registry --------------------------------------------------------------------
+
+
+def test_the_registry_is_what_the_program_names():
+    assert len(set(SCOPES)) == len(SCOPES) == 12
+    assert all(SCOPE_RE.fullmatch(s) for s in SCOPES)
+    assert {layer for layer, _n, _s in step_mod.DEVICE_SCOPES} == {
+        "round program", "closed-loop engine"}
+    assert ENGINE == ("raft_tiles", "raft_watch", "raft_carry")
+    assert all(scope == "raft_" + name
+               for _layer, name, scope in step_mod.DEVICE_SCOPES)
+    # Every named_scope the two modules open is registered, and every
+    # registered one is opened by one of them.
+    opened = set()
+    for mod in (step_mod, engine_mod):
+        with open(mod.__file__) as f:
+            opened |= set(re.findall(r'named_scope\("([^"]+)"\)', f.read()))
+    assert opened == set(SCOPES)
+    # tools/phaseprobe.py names its segments from the same tuple.
+    tool = os.path.join(os.path.dirname(__file__), "..", "..", "tools",
+                        "phaseprobe.py")
+    with open(tool) as f:
+        assert "step_mod.DEVICE_SCOPES" in f.read()
+    assert not hasattr(step_mod, "ROUND_PHASE_SCOPES")
+
+
+# -- every equation under a registered scope ---------------------------------------
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_every_equation_of_the_closed_loop_has_a_registered_scope(
+        name, monkeypatch):
+    eng = engine_of(name, monkeypatch)
+    by_scope, bare = scoped(loop_jaxpr(eng))
+    assert not bare, bare[:10]
+    assert set(by_scope) == expected(eng, loop=True)
+    # The watch, the tiles and the carry are the engine layer's own
+    # work, a fraction of the round's.
+    own = sum(by_scope.get(s, 0) for s in ENGINE)
+    assert 0 < own < sum(by_scope.values()) / 4
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_every_equation_of_the_eager_round_has_a_registered_scope(
+        name, monkeypatch):
+    eng = engine_of(name, monkeypatch)
+    by_scope, bare = scoped(round_jaxpr(eng))
+    assert not bare, bare[:10]
+    assert set(by_scope) == expected(eng, loop=False)
+
+
+# -- and the test has teeth ----------------------------------------------------------
+
+
+@pytest.mark.parametrize("scope", ENGINE)
+def test_a_scope_left_out_leaves_its_lines_bare(scope, monkeypatch):
+    """Each of the three engine scopes taken away in turn (its ``with``
+    a no-op): the lines it enclosed stand under no name, or under the
+    wrong one, and the rule above fails."""
+    real = jax.named_scope
+    monkeypatch.setattr(
+        jax, "named_scope",
+        lambda s: contextlib.nullcontext() if s == scope else real(s))
+    eng = engine_of("engine512k-r3of4", monkeypatch)
+    by_scope, bare = scoped(loop_jaxpr(eng))
+    assert bare and scope not in by_scope
+    kinds = {prim for prim, _stack in bare}
+    assert {"raft_tiles": {"dynamic_slice", "dynamic_update_slice"} <= kinds,
+            "raft_watch": "reduce_sum" in kinds,
+            "raft_carry": "add" in kinds}[scope]
+
+
+@pytest.mark.parametrize("name", (CONFIGS[0], CONFIGS[3]))
+def test_a_line_moved_out_of_its_scope_fails_the_rule(name, monkeypatch):
+    """A patched body: one more line of the scan's body, written where
+    ``route_lanes`` is called, outside every ``with``."""
+    real = engine_mod.route_lanes
+
+    def route_lanes(cfg, outbox, sent, prev):
+        return real(cfg, outbox, sent | jnp.zeros_like(sent), prev)
+
+    monkeypatch.setattr(engine_mod, "route_lanes", route_lanes)
+    eng = engine_of(name, monkeypatch)
+    by_scope, bare = scoped(loop_jaxpr(eng))
+    assert sorted(prim for prim, _stack in bare) == [
+        "broadcast_in_dim", "or"]
+    assert set(by_scope) == expected(eng, loop=True)
+
+
+# -- names are metadata: the programs are the parent's ---------------------------
+
+# sha256 of the lowered text (the closed loop as its cell calls it, with
+# both schedules; the eager round) of the two configurations that run in
+# tiles, at 8 groups in two tiles, on the parent commit (904936a, PR 36),
+# taken with tiled_text() below from `git archive` of it. The lowered
+# text holds no name of a scope, and JAX's persistent cache keys a
+# program with its names stripped: equal text here is a cache hit on the
+# chip (a run of this change against a cache the parent filled reads
+# `compile.cache_misses` 0), where a `with` that re-orders two lines is
+# a miss of the scan: 146-200 s of a large cell's cold set-up. A loop
+# that changes on purpose re-pins these (ETCD_TPU_PRINT_ROUND_DIGESTS=1
+# prints them); `test_scan_replace.py` pins the untiled texts.
+PARENT_TILED_TEXT = {
+    "engine1m-r3": (
+        "2d649dbeb6dee55162fc1216783261e0ef13c701e81eefeb468b287605f628ba",
+        "59fc8dc3d61bd1fc9152d59b5c3ea724097620481c6ae57e64aff430c73b7f95"),
+    "engine512k-r3of4": (
+        "46bf81c9bd799b5dfcec702779139cfdb654a1020bec2dae847cd09ccceda258",
+        "71d189556716128bf363bfe98bd88ca27cb2744c0945d9b725ea14fb881c27be"),
+}
+
+
+def tiled_text(eng: MultiRaftEngine):
+    cfg, zb, zi = eng.cfg, eng._zeros_b, eng._zeros_i
+    ctl, _ = eng._control_schedule(
+        np.zeros((ROUNDS, control_cols(cfg)), np.int32), ROUNDS)
+    loop = eng._closed_loop.lower(
+        eng.state, eng.inbox, zb, zi, eng._tel(), eng._flt(), eng._lanes,
+        jnp.zeros((ROUNDS, cfg.num_replicas), bool), ROUNDS, ctl, eng._watch)
+    one = eng._round.lower(
+        eng.state, eng.inbox, zb, zb, zi, zb, zi, zb, conf_req=zi,
+        wipe=zb if cfg.replace_replicas else None)
+    return loop.as_text(), one.as_text()
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_TILED_TEXT))
+def test_in_tiles_the_lowered_text_is_the_parents(name, monkeypatch):
+    texts = tiled_text(engine_of(name, monkeypatch))
+    assert not any(scope in t for t in texts for scope in SCOPES)
+    got = tuple(hashlib.sha256(t.encode()).hexdigest() for t in texts)
+    if os.environ.get("ETCD_TPU_PRINT_ROUND_DIGESTS"):
+        print(name, got)
+    assert got == PARENT_TILED_TEXT[name], (
+        "the lowered closed loop or eager round of a tiled configuration "
+        "is not the text it was at PR 36's commit")

@@ -134,7 +134,9 @@ def main() -> int:
                 lambda slot, sti: step_mod._emit(cfg, slot, sti))),
             (slots, st)),
     }
-    order = [name for name, _scope in step_mod.ROUND_PHASE_SCOPES]
+    scopes = {name: scope for layer, name, scope in step_mod.DEVICE_SCOPES
+              if layer == "round program"}
+    order = list(scopes)
     seg_s = {}
     for name in order:
         if name in phase_fns:
@@ -176,7 +178,7 @@ def main() -> int:
     segments = [
         {
             "segment": name,
-            "scope": dict(step_mod.ROUND_PHASE_SCOPES).get(name, name),
+            "scope": scopes.get(name, name),
             "ms": round(seg_s[name] * 1e3, 4),
             "pct_of_segments": round(100 * seg_s[name] / total, 1),
         }
