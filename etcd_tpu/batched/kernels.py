@@ -267,17 +267,28 @@ def ring_write_masked(
 ) -> jnp.ndarray:
     """Write terms[j] at log position start_index+j for each masked j.
 
-    Scatter-free: a [W, K] outer compare selects which ring slot each
-    masked entry lands in (positions are distinct since K <= W and the
-    indexes are consecutive), then a reduce over K folds them in."""
+    Scatter-free, with ONE reduce of ring shape: a [W, K] outer
+    compare says which ring slot each masked entry lands in, each hit
+    carries the difference terms[j] - log_term, and the old ring plus
+    the sum of the differences over K is the write. Positions are
+    distinct (K <= W, consecutive indexes), so at most one difference
+    in a slot is not zero and old + (term - old) is the term, bit for
+    bit (int32 wraps). A reduce's output ends a TPU fusion, so each
+    reduce of ring shape is a pass over the [N, W] ring: there is one,
+    and the add fuses into whatever follows. It is not none, for the
+    layout: K selects and no reduce are the same bits, but inside a
+    lane's lax.cond nothing then gives the ring a layout, the TPU
+    compiler lays the branch out ring-minor and both branches relayout
+    all of it (PERF.md section 6, PR 39;
+    tests/batched/test_ring_layout.py)."""
     w = log_term.shape[-1]
     k = terms.shape[-1]
-    # K > W would alias ring positions and SUM colliding terms; shapes
-    # are static, so this check costs nothing at runtime.
+    # K > W would alias ring positions and SUM colliding differences;
+    # shapes are static, so this check costs nothing at runtime.
     assert k <= w, f"ring write batch {k} exceeds window {w}"
     p = jnp.arange(w, dtype=I32)
     jj = jnp.arange(k, dtype=I32)
     pos_j = jnp.mod(start_index + jj, w)  # [K]
     hit = (p[:, None] == pos_j[None, :]) & mask[None, :]  # [W, K]
-    val = jnp.sum(jnp.where(hit, terms[None, :], 0), axis=-1)
-    return jnp.where(jnp.any(hit, axis=-1), val, log_term)
+    delta = jnp.where(hit, terms[None, :] - log_term[:, None], 0)
+    return log_term + jnp.sum(delta, axis=-1)

@@ -19,6 +19,8 @@ from etcd_tpu.batched.kernels import (
     joint_committed,
     joint_vote_result,
     quorum_committed,
+    ring_write,
+    ring_write_masked,
     term_at,
     vote_result,
 )
@@ -31,6 +33,8 @@ from etcd_tpu.raft.quorum import (
 )
 from etcd_tpu.raft.storage import MemoryStorage
 from etcd_tpu.raft.types import ConfState, Entry, Snapshot, SnapshotMetadata
+
+from .test_scopes import _bodies
 
 rng = random.Random(0)
 R = 8
@@ -211,3 +215,89 @@ def test_term_at_and_find_conflict_by_term_match_oracle():
         log = logs[li][0]
         expect = log.find_conflict_by_term(index, term)
         assert got_fc[k] == expect, (li, index, term, got_fc[k], expect)
+
+
+def _ring_write_cases(w, k, seed):
+    """Rows of (ring, start, terms, mask): wrapping and far starts,
+    all-false, all-true, prefix (`count`) and holed masks, terms over
+    the whole int32 range (the write copies bits, it does not add)."""
+    g = np.random.default_rng(seed)
+    n = 256
+    ring = g.integers(-5, 1 << 20, size=(n, w), dtype=np.int32)
+    start = g.integers(0, 6 * w, size=n, dtype=np.int32)
+    start[:w] = np.arange(w)                      # every wrap point
+    start[w:w + 8] = (1 << 30) + np.arange(8) * 5  # a long-lived log
+    terms = g.integers(-(1 << 31), (1 << 31) - 1, size=(n, k),
+                       dtype=np.int64).astype(np.int32)
+    terms[::7] = 0
+    mask = g.random((n, k)) < 0.5
+    mask[0::4] = True
+    mask[1::4] = False
+    count = g.integers(0, k + 1, size=n, dtype=np.int32)
+    count[0::5] = 0
+    count[1::5] = k
+    mask[2::4] = np.arange(k)[None, :] < count[2::4, None]
+    return ring, start, terms, mask, count
+
+
+def _scatter(ring, start, terms, mask):
+    out = ring.copy()
+    w = ring.shape[-1]
+    for i in range(ring.shape[0]):
+        for j in range(terms.shape[-1]):
+            if mask[i, j]:
+                out[i, (int(start[i]) + j) % w] = terms[i, j]
+    return out
+
+
+@pytest.mark.parametrize("form", ["masked", "count"])
+@pytest.mark.parametrize("k", [1, 2, 4, 8, "W"])
+@pytest.mark.parametrize("w", [16, 32])
+def test_ring_write_matches_a_scatter(w, k, form):
+    k = w if k == "W" else k
+    ring, start, terms, mask, count = _ring_write_cases(w, k, seed=w * 100 + k)
+    if form == "masked":
+        got = jax.jit(jax.vmap(ring_write_masked))(ring, start, terms, mask)
+    else:
+        got = jax.jit(jax.vmap(ring_write))(ring, start, terms, count)
+        mask = np.arange(k)[None, :] < count[:, None]
+    np.testing.assert_array_equal(np.asarray(got),
+                                  _scatter(ring, start, terms, mask))
+
+
+def _primitives(jaxpr):
+    """Every equation of a jaxpr in order, an equation that encloses
+    others (a jitted callee) read through them."""
+    for eqn in jaxpr.eqns:
+        bodies = list(_bodies(eqn))
+        if not bodies:
+            yield eqn
+        for body in bodies:
+            yield from _primitives(body)
+
+
+@pytest.mark.parametrize("fn,last", [(ring_write_masked, "mask"),
+                                     (ring_write, "count")])
+def test_ring_write_is_one_reduce_and_an_add(fn, last):
+    """A reduce's output ends a TPU fusion, so every reduce of ring
+    shape is a pass over [N, W]: the write holds ONE, a sum, and after
+    it only the add that puts the old ring back. No reduce_or (the
+    `any` over K the sum-and-select form had), no scatter or gather
+    (they serialise)."""
+    w, k, n = 32, 4, 8
+    arg = (jnp.zeros((n, k), bool) if last == "mask"
+           else jnp.zeros((n,), jnp.int32))
+    closed = jax.make_jaxpr(jax.vmap(fn))(
+        jnp.zeros((n, w), jnp.int32), jnp.zeros((n,), jnp.int32),
+        jnp.zeros((n, k), jnp.int32), arg)
+    eqns = list(_primitives(closed.jaxpr))
+    names = [e.primitive.name for e in eqns]
+    for prim in ("reduce_or", "reduce_max", "reduce_and", "argmax",
+                 "scatter", "scatter-add", "gather", "dynamic_slice",
+                 "dynamic_update_slice", "while", "cond"):
+        assert prim not in names, (prim, names)
+    assert [x for x in names if x.startswith("reduce")] == ["reduce_sum"]
+    assert names[-2:] == ["reduce_sum", "add"]
+    for eqn in eqns[-2:]:
+        (out,) = eqn.outvars
+        assert out.aval.shape == (n, w) and out.aval.dtype == jnp.int32

@@ -1,0 +1,121 @@
+"""The ring write compiled for a described TPU, no chip: what layout the
+compiler gives the [N, W] log ring when the write sits alone in a lane's
+`lax.cond`, as `_append_own` does in the HB and VOTE_RESP lanes.
+
+A branch computation made of elementwise ops alone gets the default
+layout, minor dimension last: the ring then stands ring-minor (W = 32 of
+128 lanes), the branch's fusions cost ten times theirs and the ring is
+copied at both edges of the cond, every round, taken or not (PERF.md
+section 6, PR 39: +22% on the compiled 64k round). A reduce over K with
+a ring-shaped output gets N minor and everything follows it, which is
+why `kernels.ring_write_masked` keeps one.
+
+The topology is described inside a fixture (one process at a time may
+load the TPU's library, and every xdist worker imports every test file)
+and the compiles run in this process, with JAX's persistent cache off:
+a program compiled for a device that is not attached cannot be read
+back."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from etcd_tpu.batched.kernels import ring_write, term_at
+
+I32 = jnp.int32
+N, W, P = 1024, 32, 2
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def select_chain(log_term, start_index, terms, count):
+    """The write as K static selects and no reduce: what ISSUE 39 asked
+    for, and the same bits."""
+    w, k = log_term.shape[-1], terms.shape[-1]
+    rel = jnp.mod(jnp.arange(w, dtype=I32) - start_index, w)
+    out = log_term
+    for j in range(k):
+        out = jnp.where((rel == j) & (j < count), terms[j], out)
+    return out
+
+
+def compiled_loop(write, sharding):
+    """64 rounds of: read the ring outside the cond (`term_at`, as the
+    round does), and every third round append one entry of the own term
+    inside it, instance axis minor as `lanes_minor` carries it."""
+
+    def append(ring, last, term):
+        new = write(ring, last + 1, jnp.full((P,), 1, I32) * term,
+                    jnp.asarray(1, I32))
+        return new, last + 1
+
+    def read(ring, last):
+        zero = jnp.zeros((), I32)
+        return term_at(ring, zero, zero, last, last)
+
+    def body(i, carry):
+        ring, last, term, acc = carry
+        minor = jnp.moveaxis(ring, 0, -1)
+        acc = acc + jax.vmap(read, in_axes=-1, out_axes=-1)(minor, last)
+
+        def taken(ring, last):
+            new, last = jax.vmap(append, in_axes=-1, out_axes=-1)(
+                jnp.moveaxis(ring, 0, -1), last, term)
+            return jnp.moveaxis(new, -1, 0), last
+
+        ring, last = jax.lax.cond(
+            i % 3 == 0, taken, lambda ring, last: (ring, last), ring, last)
+        return ring, last, term, acc
+
+    def loop(ring, last, term):
+        return jax.lax.fori_loop(
+            0, 64, body, (ring, last, term, jnp.zeros_like(last)))
+
+    vec = jax.ShapeDtypeStruct((N,), I32, sharding=sharding)
+    ring = jax.ShapeDtypeStruct((N, W), I32, sharding=sharding)
+    return jax.jit(loop).lower(ring, vec, vec).compile().as_text()
+
+
+def rings(text):
+    """(N minor, ring minor): how often the ring's shape stands in each
+    layout in a compiled text."""
+    return (len(re.findall(rf"\[{N},{W}\]\{{0,1", text)),
+            len(re.findall(rf"\[{N},{W}\]\{{1,0", text)))
+
+
+def test_in_a_cond_the_ring_write_keeps_the_ring_n_minor(
+        one_chip, no_persistent_cache):
+    n_minor, ring_minor = rings(compiled_loop(ring_write, one_chip))
+    assert n_minor > 0 and ring_minor == 0
+
+
+def test_in_a_cond_a_write_of_selects_alone_lays_the_ring_out_ring_minor(
+        one_chip, no_persistent_cache):
+    """The control. If this fails the compiler has stopped doing it,
+    and the reduce in `ring_write_masked` may go: compile the five
+    closed loops for a described v5e first (the `verify` skill)."""
+    n_minor, ring_minor = rings(compiled_loop(select_chain, one_chip))
+    assert ring_minor > n_minor

@@ -574,24 +574,29 @@ def test_the_oracle_raises_where_a_reused_slot_votes_twice_in_a_term():
 # -- (d) what the new fields cost a configuration that does not ask for them ------
 
 # sha256 of the lowered text (one round, the 64-round closed loop) of the
-# four live configurations at 8 groups on the parent commit (916f7bd, PR
-# 33), taken with _lowered() below from `git archive` of it. A round that
-# changes on purpose re-pins these (ETCD_TPU_PRINT_ROUND_DIGESTS=1 prints
-# them); a field of this PR that leaks into a configuration that does
-# not ask for it shows here before it shows as a cache miss on the chip.
+# four live configurations at 8 groups, taken with _lowered() below. A
+# round that changes on purpose re-pins these
+# (ETCD_TPU_PRINT_ROUND_DIGESTS=1 prints them); a field that leaks into
+# a configuration that does not ask for it shows here before it shows as
+# a cache miss on the chip. Pinned by PR 34 on the text of 916f7bd (PR
+# 33), which stood through 2cee456 (PR 38); re-pinned by PR 39 on its own
+# text, because it rewrote `kernels.ring_write_masked` (one reduce whose
+# output is the ring, for a sum, an any and a select), which every
+# append site of the round calls: the text of every configuration moved
+# on purpose, and the chip compiles each scan anew once.
 PARENT_TEXT = {
     "engine64k-r3": (
-        "1ffef2fbe15ed62e562d29805606091c17e38adf4c6648fd44395d99d2b237e0",
-        "adc86ffa05d6cc369f6643d6a29f26349ea9a38664975ce698582e377539578d"),
+        "96e5d9705155792d82931ab14ba5b811257229512a4a00a4c6516fbed69d65a9",
+        "f50f9e20ec84a986bf4a8aaee9ec123d29cd721f44fedee49de3f8610d076471"),
     "engine10k-r5": (
-        "f86a59d4ce6b015452c9b35859714e8423b8b919c6a9271dc81845445de3e9ee",
-        "ad9404fcfe959e216704d4325f6d8a8e7450667aba7b0fae8528164a2b8b8b0c"),
+        "e0605aad1b2d820457d214d94029a9c3462148b725fa570f6db3f892b493e46a",
+        "248cd628954d81f6e3b40252cc44e3d913cb08e8adcb16a540da50621ac0b585"),
     "engine100k-r3": (
-        "b24d61596578ee7297a8e3964c26fc0f6496865c4a5c14adab139a9ea3a250f5",
-        "efe787a6fa2d7dc7fc7052876f91db8b751dbccafb4c8768b7d013f6228f5047"),
+        "c35627cbdf26df2489b4d4300d7341be7b8d601a3a4d84f48738000b2a367235",
+        "9b2f790aa71022b573e867128ac2c0acc8fb004fc5a2f613db1f8d97593f02c0"),
     "engine1m-r3": (
-        "4a8d375fc11b6228b67f042097b11cc3933b663aa7a756df24396a6f5ca66901",
-        "5d61e0116917100b2866976cc32adacbe339589dfe955b01aba995088011abd2"),
+        "00213fa35022563b9c686ef68fd5024446b76e6fe057baca0cdab1006d80e432",
+        "3631a5fcc602861c48e4c265b2f739333c57c7415ed64f9d4b0e92eb0199acb2"),
 }
 
 
@@ -633,7 +638,7 @@ def test_with_the_new_fields_off_the_round_is_the_parents_text(name):
         print(name, got)
     assert got == PARENT_TEXT[name], (
         "the lowered round or closed loop of a live configuration is not "
-        "the text it was at PR 33's commit")
+        "the text it was at the commit that pinned it (PR 39)")
     assert control_cols(cfg) == 5 and watch_names(cfg) == WATCH_NAMES
 
 
