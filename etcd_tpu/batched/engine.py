@@ -38,7 +38,18 @@ counts a round for a lane that held a message in any tile, the
 skip is each tile's own, so a lane nobody of a tile wrote is zeros on
 that tile's rows. The eager round runs in the same tiles (and donates
 state and inbox there), so a configuration traces the round at one
-shape. Entry payloads never touch the device: the host keeps them in
+shape. Given the devices that are its nodes (``nodes=``) the engine
+places slot s of every group on device s: a node's rows are G (a row a
+group), the nodes walk the same tiles of groups together, and a tile's
+round ends in the exchange between them, one all-to-all a kind lane
+over the interconnect for the lanes some node wrote
+(``step.exchange_lanes``; the nodes agree on the occupancy first,
+``step.agree_lanes``, because a node cut off writes nothing where its
+peers do and a collective in a branch only some take never returns),
+counted in the carry like the lanes (``lane_exchanges()``); a node cut
+off, retired or wiped is a device's rows; the state then lives node
+after node (row ``s * G + g``) and every method takes and hands back
+the logical order. Entry payloads never touch the device: the host keeps them in
 an arena keyed by (group, index), and the commit watermarks streaming
 back from the device drive payload application — mirroring how the
 reference applies committed entries after the Ready loop (ref:
@@ -47,6 +58,7 @@ server/etcdserver/raft.go:158-315).
 
 from __future__ import annotations
 
+import functools
 import itertools
 from collections import deque
 from typing import NamedTuple, Optional
@@ -54,6 +66,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..analysis.sentinels import note_compile_key, warm_guard
 from ..obs import spans
@@ -64,9 +77,9 @@ from .compile_cache import enable_compile_cache
 _ENGINE_SERIAL = itertools.count()
 from .state import (CANDIDATE, CONF_SWAP, LEADER, PRECANDIDATE, REPLICATE,
                     BatchedConfig, BatchedState, I32, conf_decode, init_state)
-from .step import (MsgSlots, NUM_KINDS, empty_msgs, lane_occupancy,
-                   make_step_round, route, route_lanes, split_lanes,
-                   stack_lanes)
+from .step import (MsgSlots, NUM_KINDS, agree_lanes, empty_msgs,
+                   exchange_lanes, lane_occupancy, make_step_round, route,
+                   route_lanes, split_lanes, stack_lanes)
 
 
 # Columns of a scan's control schedule, int32 [rounds, CTL_COLS], one
@@ -166,7 +179,7 @@ TILE_ROWS = 196_608
 TILE_ALIGN = 1_024
 
 
-def scan_tiles(cfg: BatchedConfig) -> int:
+def scan_tiles(cfg: BatchedConfig, nodes: bool = False) -> int:
     """How many tiles `cfg`'s closed loop runs in: the fewest, so the
     largest, that are whole groups, equal, TILE_ALIGN-aligned and no
     larger than TILE_ROWS. 1 — the one scan over all rows — for a shape
@@ -174,15 +187,23 @@ def scan_tiles(cfg: BatchedConfig) -> int:
     a round and cost a quarter more warm set-up, the round traced and
     fetched at a second shape: PERF.md section 6, "PR 35"), one with no
     such divisor, and a configuration with ``fleet_summary`` (its frame
-    reduces across rows with fields that do not add)."""
-    n = cfg.num_instances
+    reduces across rows with fields that do not add). With `nodes`
+    (``MultiRaftEngine(nodes=...)``) the rule is held to one node's
+    rows, a row a group: every node walks the same tiles of groups."""
+    n, unit = ((cfg.num_groups, 1) if nodes
+               else (cfg.num_instances, cfg.num_replicas))
     if n < 2 * TILE_ROWS or cfg.fleet_summary:
         return 1
     for tiles in range(-(-n // TILE_ROWS), n // TILE_ALIGN + 1):
         rows, rest = divmod(n, tiles)
-        if not (rest or rows % cfg.num_replicas or rows % TILE_ALIGN):
+        if not (rest or rows % unit or rows % TILE_ALIGN):
             return tiles
     return 1
+
+
+# The mesh axis of an engine placed over nodes: device s of it holds
+# replica slot s of every group.
+NODE_AXIS = "node"
 
 
 class ScanWatch(NamedTuple):
@@ -196,6 +217,14 @@ class ScanWatch(NamedTuple):
     # (a commit that ran ahead through an outage has been caught up
     # with by then).
     history: jnp.ndarray  # [N] u32
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _to_placed(x, g: int, r: int, by_node):
+    """[G*R, ...] in the logical order as [R*G, ...] in placed order,
+    split by node."""
+    y = x.reshape((g, r) + x.shape[1:]).swapaxes(0, 1).reshape(x.shape)
+    return jax.lax.with_sharding_constraint(y, by_node)
 
 
 class MultiRaftEngine:
@@ -214,13 +243,29 @@ class MultiRaftEngine:
     program is enqueued: the host's share of a call, not the device's."""
 
     def __init__(self, cfg: BatchedConfig, start_index: int = 0,
-                 spare=None):
+                 spare=None, nodes=None):
         """`spare` (``cfg.replace_replicas``): the slot every group
-        leaves empty, or one a group as [G]: ``state.init_state``."""
+        leaves empty, or one a group as [G]: ``state.init_state``.
+
+        `nodes`: the devices that are the deployment's nodes, R of
+        them. Device s then holds replica slot s of every group (G
+        rows, a row a group), the round's exchange is an all-to-all
+        between the devices inside the scan (``step.exchange_lanes``;
+        ``route()`` does not run), a node cut off, retired or wiped is
+        a device's rows, and every device walks the same tiles of
+        groups together. An instance keeps its logical id
+        ``g * R + s`` (the timeout hash reads it), so a group runs bit
+        for bit as it does on one device. ``eng.state``, ``eng.inbox``
+        and the accumulators are then arrays over the node mesh in
+        placed order (row ``s * G + g``, sharded by node); every method
+        takes and hands back per-instance values in the logical order
+        (``logical`` reorders a placed array on the host). Without
+        `nodes` nothing of this exists and the programs are what they
+        were."""
         self._serial = next(_ENGINE_SERIAL)
         self._calls = 0  # spans opened: the id the next one takes
         with self._span("engine.init"):
-            self._init(cfg, start_index, spare)
+            self._init(cfg, start_index, spare, nodes)
 
     def _span(self, name: str, **stats) -> "spans.Span":
         call = self._calls
@@ -235,42 +280,80 @@ class MultiRaftEngine:
         it, whose ``(member, round)`` it takes; it numbers no call."""
         return spans.span("engine.pretrace", engine=self._serial)
 
-    def _init(self, cfg: BatchedConfig, start_index: int, spare) -> None:
+    def _init(self, cfg: BatchedConfig, start_index: int, spare,
+              nodes) -> None:
         # deliver_shape="auto" becomes "vectorized" here, so self.cfg
         # reads as the compile key does.
         self.cfg = cfg = cfg.validate().resolved()
         # Round programs are expensive to build; cache compilations
         # across processes.
         enable_compile_cache()
-        self.state = init_state(cfg, start_index, spare=spare)
-        self.inbox = empty_msgs(
-            (cfg.num_instances, cfg.num_replicas, NUM_KINDS),
-            cfg.max_ents_per_msg,
-            narrow=cfg.narrow_lanes,
-        )
-        self._step = make_step_round(cfg)
+        r = cfg.num_replicas
+        self._nodes = nodes = None if nodes is None else tuple(nodes)
+        placed = nodes is not None
+        if placed:
+            if len(nodes) != r or len(set(nodes)) != r:
+                raise ValueError(
+                    f"nodes must be num_replicas = {r} distinct devices, "
+                    f"got {len(nodes)}")
+            if cfg.fleet_summary:
+                raise ValueError(
+                    "fleet_summary reduces across all rows of one device: "
+                    "not with nodes")
+            mesh = Mesh(np.asarray(nodes), (NODE_AXIS,))
+            # Of a per-instance array in placed order, and of what
+            # every node holds whole.
+            self._by_node = NamedSharding(mesh, P(NODE_AXIS))
+            self._on_all = NamedSharding(mesh, P())
+            with spans.span("engine.place", engine=self._serial, nodes=r):
+                self._place_state(start_index, spare)
+            self._step = None  # the untiled one-device round
+        else:
+            self.state = init_state(cfg, start_index, spare=spare)
+            self.inbox = empty_msgs(
+                (cfg.num_instances, cfg.num_replicas, NUM_KINDS),
+                cfg.max_ents_per_msg,
+                narrow=cfg.narrow_lanes,
+            )
+            self._step = make_step_round(cfg)
 
-        n = cfg.num_instances
+        # The rows one device holds: all, or one node's (a row a group).
+        n = cfg.num_groups if placed else cfg.num_instances
         # One scan over all rows, or tile by tile (``scan_tiles``); the
         # eager round likewise, so that a configuration traces the
-        # round at one shape.
-        self._tiles = tiles = scan_tiles(cfg)
-        rows = n // tiles
+        # round at one shape. Placed over nodes the loops are the tile
+        # loops whatever the number of tiles.
+        self._tiles = tiles = scan_tiles(cfg, nodes=placed)
+        self.tile_rows = rows = n // tiles
+
+        def row_slots():
+            """The slot of each row of a tile: the node's own, or the
+            row's place in its group."""
+            if placed:
+                return jnp.full((rows,), jax.lax.axis_index(NODE_AXIS), I32)
+            return jnp.arange(rows, dtype=I32) % cfg.num_replicas
 
         def tile_step(lo, slots):
             """The round for the rows from `lo` on: the timeout hash
-            reads the row's own id."""
-            return make_step_round(
-                cfg, iids=lo + jnp.arange(rows, dtype=I32), slots=slots)
+            reads the row's own id, ``g * R + s`` wherever it lives."""
+            iids = lo + jnp.arange(rows, dtype=I32)
+            if placed:
+                iids = iids * r + slots
+            return make_step_round(cfg, iids=iids, slots=slots)
 
         def like(x):  # a tile's share of a per-row array, as a shape
             return jax.ShapeDtypeStruct((rows,) + x.shape[1:], x.dtype)
+
+        def over_nodes(fn, in_specs, out_specs):
+            """`fn` as every node runs it on its own rows."""
+            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False)
 
         def tiled_round(st, lanes, per_row):
             """The eager round tile by tile (`tiled_loop`'s idiom):
             `per_row` is (masks, conf_req, wipe), every leaf [N]."""
             with jax.named_scope("raft_carry"):
-                slots = jnp.arange(rows, dtype=I32) % cfg.num_replicas
+                slots = row_slots()
 
             def one(lo, st, lanes, per_row):
                 masks, conf_req, wipe = per_row
@@ -305,7 +388,12 @@ class MultiRaftEngine:
 
             st, out = jax.lax.fori_loop(0, tiles, tile, (st, whole))
             with jax.named_scope("raft_carry"):
-                return (st, stack_lanes(out[0])) + out[1:]
+                outbox = out[0]
+                if placed:
+                    # Every lane, nothing to agree on: what route()
+                    # does after the one-device eager round.
+                    outbox = exchange_lanes(outbox, NODE_AXIS)
+                return (st, stack_lanes(outbox)) + out[1:]
 
         def step_round(st, inbox, *masks, conf_req=None, wipe=None):
             # The eager round hands the round program what the scan
@@ -319,7 +407,7 @@ class MultiRaftEngine:
             # of its own here, takes them stacked.
             with jax.named_scope("raft_carry"):
                 lanes = split_lanes(inbox)
-            if tiles > 1:
+            if tiles > 1 or placed:
                 return tiled_round(st, lanes, (masks, conf_req, wipe))
             with jax.named_scope("raft_carry"):
                 out = self._step(st, lanes, *masks,
@@ -332,13 +420,32 @@ class MultiRaftEngine:
         # and inbox from what comes back); not donated it would be a
         # second copy, a gigabyte more than the one round holds. One
         # tile keeps the parent's program, which donates nothing.
+        if placed:
+            one_node_round = step_round
+
+            def step_round(st, inbox, *masks, conf_req=None, wipe=None):
+                # What comes back in the outbox's place is the next
+                # inbox: the exchange ran with the round.
+                return over_nodes(
+                    lambda st, inbox, masks, conf_req, wipe: one_node_round(
+                        st, inbox, *masks, conf_req=conf_req, wipe=wipe),
+                    P(NODE_AXIS), P(NODE_AXIS))(
+                        st, inbox, masks, conf_req, wipe)
+
         self._round = jax.jit(
-            step_round, donate_argnums=(0, 1) if tiles > 1 else ())
-        self._zeros_b = jnp.zeros((n,), bool)
-        self._zeros_i = jnp.zeros((n,), I32)
+            step_round, donate_argnums=(0, 1) if tiles > 1 or placed else ())
+        n_all = cfg.num_instances
+        zeros = self._zeros
+        self._zeros_b = zeros((n_all,), bool)
+        self._zeros_i = zeros((n_all,), I32)
         # Scan rounds in which each kind lane held a message for any
         # instance (lane_rounds()): carried through the closed loop.
+        # Placed over nodes, beside it the tile-rounds in which each
+        # lane crossed the interconnect (lane_exchanges()).
         self._lanes = jnp.zeros((NUM_KINDS,), I32)
+        if placed:
+            self._lanes = (self._on_nodes(np.zeros((NUM_KINDS,), np.int32)),
+                           self._on_nodes(np.zeros((NUM_KINDS,), np.int32)))
         # What the scans with a control schedule counted (scan_watch()):
         # made by the first of them, carried by every one after.
         self._watch: Optional[ScanWatch] = None
@@ -348,8 +455,8 @@ class MultiRaftEngine:
         if cfg.telemetry:
             from .telemetry import NUM_COUNTERS
 
-            self._tel_counters = jnp.zeros((n, NUM_COUNTERS), I32)
-            self._tel_invariants = jnp.zeros((n,), I32)
+            self._tel_counters = zeros((n_all, NUM_COUNTERS), I32)
+            self._tel_invariants = zeros((n_all,), I32)
         self.telemetry_hub = None
         # Step output positions past (state, outbox): aux is absent on
         # the engine's step (with_aux=False), then telemetry, then the
@@ -365,7 +472,7 @@ class MultiRaftEngine:
             from ..obs.fleet import FleetLayout
 
             self._fleet_layout = FleetLayout(
-                n, cfg.num_replicas, cfg.num_groups)
+                n_all, cfg.num_replicas, cfg.num_groups)
             self._fleet_vec = jnp.zeros((self._fleet_layout.size,), I32)
             self._fleet_summask = jnp.asarray(
                 self._fleet_layout.sum_mask())
@@ -449,7 +556,20 @@ class MultiRaftEngine:
                 # left as they are (step.route_lanes). The exchange
                 # permutes slots inside a lane, so the outbox's
                 # occupancy (`sent`) is the next inbox's.
-                inbox = route_lanes(cfg, outbox, sent, (inbox, occ))
+                if placed:
+                    # Between nodes: what any node wrote, agreed first
+                    # (`occ` was, a round ago), so that every node
+                    # takes the same branch round the collective; a
+                    # node's deliver then runs a lane that only a peer
+                    # holds, over no valid slot. `lanes` counts the
+                    # lanes that crossed.
+                    sent = agree_lanes(sent, NODE_AXIS)
+                    inbox = exchange_lanes(
+                        outbox, NODE_AXIS, sent, (inbox, occ))
+                    with jax.named_scope("raft_carry"):
+                        lanes = lanes + sent
+                else:
+                    inbox = route_lanes(cfg, outbox, sent, (inbox, occ))
                 # A tile cannot count the rounds a lane was occupied
                 # for ANY instance: it hands each round's own vector
                 # out, for the call to put together over its tiles.
@@ -485,9 +605,16 @@ class MultiRaftEngine:
             this tile and occupied in another comes out as zeros here,
             where one scan over all rows exchanged emit's unsent
             fields under ``valid`` false
-            (tests/batched/test_scan_tiles.py)."""
+            (tests/batched/test_scan_tiles.py).
+
+            Placed over nodes this is what one node runs on its G rows
+            (a row a group, so any block of rows is whole groups), the
+            nodes walking the same tiles together: a tile's round ends
+            in their exchange. `lanes` is then (rounds occupied, tile-
+            rounds crossed), the occupancy every node counts is the
+            agreed one, and the fence is the node's own, [1]."""
             with jax.named_scope("raft_carry"):
-                slots = jnp.arange(rows, dtype=I32) % cfg.num_replicas
+                slots = row_slots()
                 zeros_b = jnp.zeros((rows,), bool)
                 zeros_i = jnp.zeros((rows,), I32)
 
@@ -498,20 +625,25 @@ class MultiRaftEngine:
                 return round_body(step, zeros_b, zeros_i, slots, ticks,
                                   props, tiled=True)
 
-            def tile_rounds(lo, st, inbox, tel, watch, ticks, props):
+            def tile_rounds(lo, st, inbox, tel, watch, ticks, props,
+                            crossed):
                 """The call's rounds on the rows from `lo` on, handed
                 in as the tile's slices (`watch` with the whole
-                counts); and each round's lane occupancy."""
+                counts, `crossed` the whole count of lanes exchanged
+                between nodes, () on one device); and each round's lane
+                occupancy."""
                 with jax.named_scope("raft_carry"):
                     inbox, occ = enter(inbox)
-                (st, inbox, _, tel, _, _, watch), occs = jax.lax.scan(
+                if placed:
+                    occ = agree_lanes(occ, NODE_AXIS)
+                (st, inbox, _, tel, _, crossed, watch), occs = jax.lax.scan(
                     tile_body(lo, ticks, props),
-                    (st, inbox, occ, tel, (), (), watch),
+                    (st, inbox, occ, tel, (), crossed, watch),
                     (isolate, control), length=rounds)
-                return st, inbox, tel, watch, occs
+                return st, inbox, tel, watch, occs, crossed
 
             def tile(i, carry):
-                st, inbox, tel, watch, seen = carry
+                st, inbox, tel, watch, seen, crossed = carry
                 with jax.named_scope("raft_tiles"):
                     lo = i * rows
                     cut = lambda x: jax.lax.dynamic_slice_in_dim(x, lo, rows)  # noqa: E731
@@ -523,7 +655,8 @@ class MultiRaftEngine:
                         history=cut(watch.history))
                     mine = (*jax.tree.map(cut, (st, inbox, tel)), t_watch,
                             cut(ticks), cut(props))
-                t_st, t_inbox, t_tel, t_watch, occs = tile_rounds(lo, *mine)
+                t_st, t_inbox, t_tel, t_watch, occs, crossed = tile_rounds(
+                    lo, *mine, crossed)
                 with jax.named_scope("raft_tiles"):
                     # In place: the carry is the donated state, and no
                     # second copy of it exists.
@@ -535,8 +668,11 @@ class MultiRaftEngine:
                         t_watch.counts,
                         paste(watch.read_floor, t_watch.read_floor),
                         paste(watch.history, t_watch.history))
-                    return st, inbox, tel, watch, seen | occs
+                    return st, inbox, tel, watch, seen | occs, crossed
 
+            crossed = ()
+            if placed:
+                lanes, crossed = lanes
             with jax.named_scope("raft_carry"):
                 inbox = split_lanes(inbox)
             # Tracing only. As the body of the loops the round takes
@@ -555,14 +691,14 @@ class MultiRaftEngine:
                     like(ticks), like(props),
                     (*jax.tree.map(like, (st, inbox)),
                      jax.ShapeDtypeStruct((NUM_KINDS,), bool),
-                     jax.tree.map(like, tel), (), (), t_watch),
+                     jax.tree.map(like, tel), (), crossed, t_watch),
                     jax.tree.map(
                         lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
                         (isolate, control)))
             with jax.named_scope("raft_tiles"):
                 seen = jnp.zeros((rounds, NUM_KINDS), bool)
-            st, inbox, tel, watch, seen = jax.lax.fori_loop(
-                0, tiles, tile, (st, inbox, tel, watch, seen))
+            st, inbox, tel, watch, seen, crossed = jax.lax.fori_loop(
+                0, tiles, tile, (st, inbox, tel, watch, seen, crossed))
             # Three blocks for two names, in the order the lines had
             # before they had names: the lowered text follows the order
             # of the lines, and JAX's cache key the text (the names are
@@ -573,7 +709,35 @@ class MultiRaftEngine:
             with jax.named_scope("raft_tiles"):
                 lanes = lanes + jnp.sum(seen, axis=0, dtype=I32)
             with jax.named_scope("raft_carry"):
+                if placed:
+                    return (st, inbox, tel, (), (lanes, crossed),
+                            st.commit[:1], watch)
                 return st, inbox, tel, (), lanes, st.commit[0], watch
+
+        def placed_loop(st, inbox, ticks, props, tel, lanes, isolate,
+                        rounds, control, watch):
+            """`tiled_loop` as every node runs it on its own rows; a
+            node keeps its own ScanWatch counts (``scan_watch`` adds
+            them up)."""
+            def one_node(st, inbox, ticks, props, tel, watch, lanes,
+                         isolate, control):
+                if watch is not None:  # None is an empty pytree
+                    with jax.named_scope("raft_carry"):
+                        watch = watch._replace(counts=watch.counts[0])
+                st, inbox, tel, _, lanes, fence, watch = tiled_loop(
+                    st, inbox, ticks, props, tel, lanes, isolate, rounds,
+                    control, watch)
+                if watch is not None:
+                    with jax.named_scope("raft_carry"):
+                        watch = watch._replace(counts=watch.counts[None])
+                return (st, inbox, tel, fence, watch), lanes
+
+            by_node = P(NODE_AXIS)
+            (st, inbox, tel, fence, watch), lanes = over_nodes(
+                one_node, (by_node,) * 6 + (P(),) * 3, (by_node, P()))(
+                    st, inbox, ticks, props, tel, watch, lanes, isolate,
+                    control)
+            return st, inbox, tel, (), lanes, fence, watch
 
         def closed_loop(st, inbox, ticks, props, tel, flt, lanes, isolate,
                         rounds, control=None, watch=None):
@@ -583,6 +747,9 @@ class MultiRaftEngine:
             # same) or the int32 [rounds, CTL_COLS] control schedule,
             # beside it, and `watch` the ScanWatch that rides the carry
             # with it.
+            if placed:
+                return placed_loop(st, inbox, ticks, props, tel, lanes,
+                                   isolate, rounds, control, watch)
             if tiles > 1:
                 return tiled_loop(st, inbox, ticks, props, tel, lanes,
                                   isolate, rounds, control, watch)
@@ -627,7 +794,71 @@ class MultiRaftEngine:
         # per-engine closed-loop wrapper is keyed by a monotonic serial
         # (NOT id(self): CPython reuses freed addresses, and a stale
         # warm key would put a new engine's compile inside the guard).
-        self._wkey_step = f"round_step/{hash((cfg, False, n))}"
+        self._wkey_step = f"round_step/{hash((cfg, False, n_all))}"
+
+    # -- placement over nodes (``nodes=``) -------------------------------------
+
+    def _place_state(self, start_index: int, spare) -> None:
+        """State and inbox built where they live: every node makes its
+        own rows, slot s of every group, with the logical ids."""
+        cfg = self.cfg
+        g, r = cfg.num_groups, cfg.num_replicas
+
+        def build(spare):
+            row = jnp.arange(g * r, dtype=I32)  # placed: s * G + g
+            st = init_state(cfg, start_index, iids=(row % g) * r + row // g,
+                            spare=spare)
+            return st, empty_msgs((g * r, r, NUM_KINDS),
+                                  cfg.max_ents_per_msg,
+                                  narrow=cfg.narrow_lanes)
+
+        # The spare is an argument, so one program serves every seed's.
+        self.state, self.inbox = jax.jit(
+            build, out_shardings=self._by_node)(
+                None if spare is None else self._on_nodes(
+                    np.asarray(spare, np.int32)))
+
+    def _zeros(self, shape, dtype):
+        """Zeros a row an instance, where the state lives."""
+        return jnp.zeros(
+            shape, dtype,
+            device=None if self._nodes is None else self._by_node)
+
+    def _place(self, x):
+        """A per-instance array a caller hands in, logical order, as
+        the engine holds it; None stays None."""
+        if x is None or self._nodes is None:
+            return x
+        g, r = self.cfg.num_groups, self.cfg.num_replicas
+        x = jax.device_put(x, self._on_all)
+        return _to_placed(x, g, r, self._by_node)
+
+    def _on_nodes(self, x: np.ndarray):
+        """A host array every node needs whole (a schedule, a count),
+        on the device, or on each of the nodes: placed here, a call's
+        dispatch moves nothing between devices."""
+        if self._nodes is None:
+            return jnp.asarray(x)
+        return jax.device_put(x, self._on_all)
+
+    def _rows(self, instance_ids):
+        """Logical instance ids as rows of the engine's arrays."""
+        ids = jnp.asarray(instance_ids)
+        if self._nodes is None:
+            return ids
+        g, r = self.cfg.num_groups, self.cfg.num_replicas
+        return (ids % r) * g + ids // r
+
+    def logical(self, x) -> np.ndarray:
+        """A per-instance array of this engine (a field of
+        ``eng.state``, of ``eng.inbox``) on the host in the logical
+        order, row ``g * R + s``."""
+        x = np.asarray(x)
+        if self._nodes is None:
+            return x
+        g, r = self.cfg.num_groups, self.cfg.num_replicas
+        return np.ascontiguousarray(
+            x.reshape((r, g) + x.shape[1:]).swapaxes(0, 1)).reshape(x.shape)
 
     def _watch_round(self, watch: ScanWatch, pre, st, slots,
                      stall, wiped=None) -> ScanWatch:
@@ -639,6 +870,10 @@ class MultiRaftEngine:
         r = self.cfg.num_replicas
 
         def group_max(x):
+            if self._nodes is not None:
+                # A group's replicas are the same row of every node.
+                with jax.named_scope("raft_agree"):
+                    return jax.lax.pmax(x, NODE_AXIS)
             # The maximum over the R adjacent rows of each row's group,
             # as row shifts under the slot mask (route()'s idiom: N is
             # never split).
@@ -745,6 +980,15 @@ class MultiRaftEngine:
         one with ``replace_replicas`` the replica reset, `wipe`: [N]
         bool), append proposals on leaders, route the outbox. `isolate`
         cuts instances off the network for this round."""
+        self._eager_round(tick, *map(self._place, (
+            campaign_mask, propose_n, isolate, transfer_to, read_req,
+            conf_req, wipe)))
+
+    def _eager_round(self, tick=False, campaign_mask=None, propose_n=None,
+                     isolate=None, transfer_to=None, read_req=None,
+                     conf_req=None, wipe=None) -> None:
+        """``step_round`` on per-instance arrays in the engine's own
+        row order."""
         ticks = (
             jnp.ones_like(self._zeros_b) if tick else self._zeros_b
         )
@@ -782,7 +1026,9 @@ class MultiRaftEngine:
                 fv = out[self._fleet_pos]
                 self._fleet_vec = jnp.where(
                     self._fleet_summask, self._fleet_vec + fv, fv)
-            self.inbox = route(self.cfg, outbox)
+            # Between nodes the exchange ran with the round.
+            self.inbox = (outbox if self._nodes is not None
+                          else route(self.cfg, outbox))
 
     def _tel(self):
         """Telemetry carry for the closed loop (empty pytree when off)."""
@@ -813,7 +1059,7 @@ class MultiRaftEngine:
             raise ValueError(
                 f"isolate must be [rounds, R] = "
                 f"{(rounds, self.cfg.num_replicas)}, got {sched.shape}")
-        return jnp.asarray(sched), int(sched.sum())
+        return self._on_nodes(sched), int(sched.sum())
 
     def _control_schedule(self, control, rounds: int):
         """(device schedule or None, span stats) of a call's control
@@ -842,11 +1088,14 @@ class MultiRaftEngine:
             "retired": int((ctl[:, CTL_RETIRE:CTL_WIPE] != 0).sum()),
         }
         if self._watch is None:
+            counts = (len(watch_names(self.cfg)), 2)
+            if self._nodes is not None:  # a node counts its own rows
+                counts = (len(self._nodes),) + counts
             self._watch = ScanWatch(
-                jnp.zeros((len(watch_names(self.cfg)), 2), I32),
-                jnp.zeros((self.cfg.num_instances,), I32),
-                jnp.zeros((self.cfg.num_instances,), jnp.uint32))
-        return jnp.asarray(ctl, I32), stats
+                self._zeros(counts, I32),
+                self._zeros((self.cfg.num_instances,), I32),
+                self._zeros((self.cfg.num_instances,), jnp.uint32))
+        return self._on_nodes(ctl.astype(np.int32)), stats
 
     def _scan(self, rounds: int, ticks, props, isolate, control=None):
         """One closed-loop scan enqueued; returns its scalar fence."""
@@ -890,6 +1139,7 @@ class MultiRaftEngine:
         to the instances on the device as `isolate` is. With neither,
         the scan takes no per-round input."""
         ticks = jnp.ones_like(self._zeros_b) if tick else self._zeros_b
+        propose_n = self._place(propose_n)
         props = propose_n if propose_n is not None else self._zeros_i
         self._scan(rounds, ticks, props, isolate, control)
 
@@ -917,6 +1167,7 @@ class MultiRaftEngine:
             # forever (done never advances) — a silent host hang.
             raise ValueError(f"chunk must be >= 1, got {chunk}")
         ticks = jnp.ones_like(self._zeros_b) if tick else self._zeros_b
+        propose_n = self._place(propose_n)
         props = propose_n if propose_n is not None else self._zeros_i
         fences: deque = deque()
         done = 0
@@ -932,29 +1183,30 @@ class MultiRaftEngine:
                 jax.block_until_ready(fences.popleft())
 
     def campaign(self, instance_ids) -> None:
-        mask = self._zeros_b.at[jnp.asarray(instance_ids)].set(True)
-        self.step_round(campaign_mask=mask)
+        mask = self._zeros_b.at[self._rows(instance_ids)].set(True)
+        self._eager_round(campaign_mask=mask)
 
     def transfer_leader(self, leader_instance: int, target_slot: int) -> None:
         """Ask the leader instance to hand leadership to target_slot
         (ref: raft.go:1339 MsgTransferLeader on the leader)."""
-        tr = self._zeros_i.at[leader_instance].set(target_slot + 1)
-        self.step_round(transfer_to=tr)
+        tr = self._zeros_i.at[self._rows(leader_instance)].set(
+            target_slot + 1)
+        self._eager_round(transfer_to=tr)
 
     def read_index(self, instance_ids) -> None:
         """Open a ReadIndex batch on the given leader instances
         (ref: v3_server.go sendReadIndex → MsgReadIndex)."""
-        req = self._zeros_b.at[jnp.asarray(instance_ids)].set(True)
-        self.step_round(read_req=req)
+        req = self._zeros_b.at[self._rows(instance_ids)].set(True)
+        self._eager_round(read_req=req)
 
     def read_states(self) -> "tuple[np.ndarray, np.ndarray, np.ndarray]":
         """(seq, index, ready) per instance — the ReadState watermarks
         the host read loop waits on (ref: read_only.go advance →
         Ready.ReadStates)."""
         return (
-            np.asarray(self.state.read_seq),
-            np.asarray(self.state.read_index),
-            np.asarray(self.state.read_ready),
+            self.logical(self.state.read_seq),
+            self.logical(self.state.read_index),
+            self.logical(self.state.read_ready),
         )
 
     def set_membership(self, group: int, voters, voters_out=(),
@@ -968,7 +1220,7 @@ class MultiRaftEngine:
         (``step_round(conf_req=...)``, ``run_rounds(control=...)``);
         this upload is then for a test's starting point only."""
         r = self.cfg.num_replicas
-        rows = jnp.arange(group * r, (group + 1) * r)
+        rows = self._rows(jnp.arange(group * r, (group + 1) * r))
 
         def mask(slots) -> jnp.ndarray:
             slots = list(slots)  # materialize once: iterators welcome
@@ -992,8 +1244,8 @@ class MultiRaftEngine:
         (column order: telemetry.TM_NAMES). One host gather; no
         per-round sync ever happened."""
         assert self.cfg.telemetry, "engine built with telemetry=False"
-        return (np.asarray(self._tel_counters),
-                np.asarray(self._tel_invariants))
+        return (self.logical(self._tel_counters),
+                self.logical(self._tel_invariants))
 
     def drain_telemetry(self, hub=None) -> "tuple[np.ndarray, np.ndarray]":
         """Fold the accumulated totals into `hub` (or the attached
@@ -1038,7 +1290,7 @@ class MultiRaftEngine:
 
     def leaders(self) -> np.ndarray:
         """Per group: leader replica slot, or -1."""
-        role = np.asarray(self.state.role).reshape(
+        role = self.logical(self.state.role).reshape(
             self.cfg.num_groups, self.cfg.num_replicas
         )
         is_lead = role == LEADER
@@ -1055,7 +1307,20 @@ class MultiRaftEngine:
         (step.route_lanes; lanes run a round over 6 is the share of
         the exchange that ran). Accumulated in the scan's carry; one
         host gather, no per-round sync."""
-        return np.asarray(self._lanes)
+        return np.asarray(
+            self._lanes if self._nodes is None else self._lanes[0])
+
+    def lane_exchanges(self) -> np.ndarray:
+        """[NUM_KINDS] for an engine placed over nodes: how often the
+        closed loop exchanged each lane between them, counted in the
+        scan's carry like ``lane_rounds``. One count is one tile's
+        round in which the lane crossed: an all-to-all of
+        ``eng.tile_rows`` rows of R slots, of which a node keeps its
+        own and sends R - 1. Zeros on one device, where nothing
+        crosses."""
+        if self._nodes is None:
+            return np.zeros((NUM_KINDS,), np.int32)
+        return np.asarray(self._lanes[1])
 
     def scan_watch(self) -> dict:
         """What the scans with a control schedule counted, by
@@ -1066,6 +1331,8 @@ class MultiRaftEngine:
         if self._watch is None:
             return dict.fromkeys(names, 0)
         c = np.asarray(self._watch.counts).astype(np.int64)
+        if self._nodes is not None:  # a node counts its own rows
+            c = c.sum(axis=0)
         return {name: int((c[i, 0] << _LIMB) + c[i, 1])
                 for i, name in enumerate(names)}
 
@@ -1075,16 +1342,16 @@ class MultiRaftEngine:
         from 0). All zero before the first such scan."""
         if self._watch is None:
             return np.zeros((self.cfg.num_instances,), np.uint32)
-        return np.asarray(self._watch.history)
+        return self.logical(self._watch.history)
 
     def commits(self) -> np.ndarray:
         """Per-instance commit watermarks [G, R] — the host applies
         payloads from its arena up to these."""
-        return np.asarray(self.state.commit).reshape(
+        return self.logical(self.state.commit).reshape(
             self.cfg.num_groups, self.cfg.num_replicas
         )
 
     def terms(self) -> np.ndarray:
-        return np.asarray(self.state.term).reshape(
+        return self.logical(self.state.term).reshape(
             self.cfg.num_groups, self.cfg.num_replicas
         )

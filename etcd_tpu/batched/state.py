@@ -493,6 +493,7 @@ def init_state(cfg: BatchedConfig, start_index: int = 0,
     every group passes its own subset so the deterministic
     randomized-timeout hash matches the dense all-replica layout."""
     r, w = cfg.num_replicas, cfg.window
+    # jitlint: waive(tracer-branch) -- an engine placed over nodes builds its state under jit: None is the argument left out, tested at trace time, never a device value
     if iids is None:
         iids = jnp.arange(cfg.num_instances, dtype=I32)
     else:
@@ -555,6 +556,7 @@ def init_state(cfg: BatchedConfig, start_index: int = 0,
         st = ConfBatchedState(*st, conf=ConfLanes(
             index=zeros_n(), op=zeros_n(), pending=zeros_n(),
             learner_next=jnp.zeros((n, r), bool)))
+    # jitlint: waive(tracer-branch) -- as above
     if spare is not None:
         if not cfg.replace_replicas:
             raise ValueError(

@@ -1707,7 +1707,10 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, conf_applied=None):
 # planes where their configuration turns them on), then the closed-loop
 # engine's (engine.py: the tile loops' slices in and updates out with
 # what a call puts together across tiles; the ScanWatch; the rest of
-# the scan's body and of a call round the round itself). The strings
+# the scan's body and of a call round the round itself; for an engine
+# placed one slot a device, the exchange over the interconnect and what
+# the devices agree on first: ``exchange_lanes``, ``agree_lanes``, the
+# ScanWatch's reduction over a group). The strings
 # are those of the named_scope calls,
 # letter for letter, and of the shape benchmark/reduce/trace.py files a
 # device op by (``raft_`` and lower-case letters; the innermost wins).
@@ -1727,6 +1730,8 @@ DEVICE_SCOPES = (
     ("closed-loop engine", "tiles", "raft_tiles"),
     ("closed-loop engine", "watch", "raft_watch"),
     ("closed-loop engine", "carry", "raft_carry"),
+    ("closed-loop engine", "ici", "raft_ici"),
+    ("closed-loop engine", "agree", "raft_agree"),
 )
 
 # -----------------------------------------------------------------------------
@@ -1795,17 +1800,25 @@ def _route_jit(r: int):
         # rest of this function.)
         with jax.named_scope("raft_route"):
             outbox = jax.lax.optimization_barrier(outbox)
-            return tuple(
-                jax.lax.switch(
-                    jnp.where(lane_any[k], 2, stale[k].astype(I32)),
-                    (lambda lane, ob: lane,
-                     lambda lane, ob: jax.tree.map(jnp.zeros_like, lane),
-                     lambda lane, ob: jax.tree.map(
-                         lambda o: exchange_lane(o) if o.size else o, ob)),
-                    lanes[k], outbox[k])
-                for k in range(NUM_KINDS))
+            return _exchange_written(exchange_lane, outbox, lane_any, lanes,
+                                     stale)
 
     return (jax.jit(route, inline=True), jax.jit(route_lanes, inline=True))
+
+
+def _exchange_written(exchange, outbox, lane_any, lanes, stale):
+    """Lane by lane, three ways: a lane somebody wrote is exchanged
+    (`exchange` of every field that has elements), one that held last
+    round's messages (`stale`) wiped, the rest left as they are."""
+    return tuple(
+        jax.lax.switch(
+            jnp.where(lane_any[k], 2, stale[k].astype(I32)),
+            (lambda lane, ob: lane,
+             lambda lane, ob: jax.tree.map(jnp.zeros_like, lane),
+             lambda lane, ob: jax.tree.map(
+                 lambda o: exchange(o) if o.size else o, ob)),
+            lanes[k], outbox[k])
+        for k in range(NUM_KINDS))
 
 
 def route(cfg: BatchedConfig, outbox: MsgSlots, lane_any=None,
@@ -1875,6 +1888,46 @@ def route_lanes(cfg: BatchedConfig, outbox: Tuple[MsgSlots, ...], lane_any,
         prev = (jax.tree.map(jnp.zeros_like, outbox),
                 jnp.zeros((NUM_KINDS,), bool))
     return _route_jit(cfg.num_replicas)[1](outbox, lane_any, *prev)
+
+
+def agree_lanes(lane_any, axis: str) -> jnp.ndarray:
+    """``lane_occupancy`` of a batch placed one replica slot a device
+    along the mesh axis `axis` (inside ``shard_map``): a lane counts as
+    occupied where any device wrote it. One small all-reduce, and the
+    vector every device then branches on alike: a collective inside a
+    branch that only some devices take never returns, and a device cut
+    off or switched off sends nothing where its peers do."""
+    with jax.named_scope("raft_agree"):
+        return jax.lax.psum(lane_any.astype(I32), axis) > 0
+
+
+def exchange_lanes(outbox: Tuple[MsgSlots, ...], axis: str, lane_any=None,
+                   prev=None) -> Tuple[MsgSlots, ...]:
+    """``route_lanes`` between devices: slot s of every group lives on
+    device s of the mesh axis `axis` (inside ``shard_map``; the rows at
+    hand are one device's, a row a group), so ``inbox[g, s]`` on device
+    t is ``outbox[g, t]`` on device s: per lane and field one
+    all-to-all over `axis` on the slot axis, the peer streams of
+    rafthttp as the interconnect's collective. Nothing of ``route()``
+    runs, no pad, no shift, no select.
+
+    ``lane_any`` is ``agree_lanes`` of the outbox's occupancy and
+    ``prev`` is ``(lanes, stale)`` with `stale` agreed likewise, so
+    every device takes the same branch of every lane: exchanged where
+    some device wrote it, wiped where it held last round's messages,
+    left alone otherwise (``route_lanes``' three ways). With
+    ``lane_any=None`` every lane is exchanged and nothing branches (the
+    eager round). Entries travel in KIND_APP alone, as ever."""
+    def swap(o):
+        return jax.lax.all_to_all(o, axis, 1, 1)
+
+    with jax.named_scope("raft_ici"):
+        # jitlint: waive(tracer-branch) -- None is the argument left out, tested at trace time, never a device value
+        if lane_any is None:
+            return jax.tree.map(lambda o: swap(o) if o.size else o, outbox)
+        # As in route_lanes: emit stays out of the branches.
+        outbox = jax.lax.optimization_barrier(outbox)
+        return _exchange_written(swap, outbox, lane_any, *prev)
 
 
 class TelemetryFrame(NamedTuple):

@@ -167,6 +167,16 @@ os.environ.setdefault("ETCD_TPU_TRANSFER_GUARD", "disallow")
 # RC3, RP4 and `engine64k-r3` at 8 groups). A tile of the closed loop
 # calls the configuration's own `jit(step_round)` on fewer rows
 # (make_step_round with the tile's iids): another trace of one key.
+# ISSUE 40 AUDIT: still 49 of 50, raised by exactly the programs the
+# configuration brings, which is none. `engine1m-r3of4-x4` is
+# `engine512k-r3of4`'s BatchedConfig at another num_groups, and at the
+# CPU tests' 8 groups the same key (RP4: tests/benchmark/test_nodes.py's
+# tiny run, test_scopes' node-placed case). An engine placed over nodes
+# (`MultiRaftEngine(nodes=...)`) hands the configuration's own
+# `jit(step_round)` a node's rows with the logical iids, inside
+# `shard_map`: another trace of one key, as a tile is. test_scan_nodes
+# runs its node-placed engines in child processes (RP4 there too), which
+# this session's sentinel does not see.
 ROUND_STEP_SHAPE_BUDGET = 50
 
 

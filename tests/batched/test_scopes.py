@@ -2,7 +2,9 @@
 
 ``step.DEVICE_SCOPES`` is the one registry of the device programs'
 ``jax.named_scope``s: the round's nine and the closed-loop engine's
-three. A profiler trace files a device op under the innermost
+five (three of any engine, two of one placed over nodes, ISSUE 40: the
+exchange over the interconnect and what the nodes agree on first). A
+profiler trace files a device op under the innermost
 ``raft_*`` name of its ``tf_op`` (``benchmark/reduce/trace.py``) and
 under ``unscoped`` where there is none; these tests hold every equation
 of the traced closed loop, of each live configuration's flag set, to a
@@ -13,7 +15,8 @@ the scan without a name fails here, on the CPU, at 8 groups.
 Round-step programs (``conftest.py``): the five live configurations at
 the CPU tests' 8 groups, every one a key already (``test_scan_tiles``
 builds the same five); nothing here compiles, the loops are traced to
-jaxprs only.
+jaxprs only. The node-placed loop is the replacement configuration's
+over four of the forced devices: the same key, another trace of it.
 """
 
 import contextlib
@@ -94,14 +97,19 @@ def scoped(jaxpr, outer: str = ""):
     return by_scope, bare
 
 
-def engine_of(name: str, monkeypatch) -> MultiRaftEngine:
+def engine_of(name: str, monkeypatch, placed: bool = False
+              ) -> MultiRaftEngine:
+    """`placed`: over as many of the forced devices as the
+    configuration has replicas, a node's rows in the same tiles."""
     cfg = BatchedConfig(**dict(sizes(name), num_groups=8))
+    rows = cfg.num_groups if placed else cfg.num_instances
     monkeypatch.setattr(engine_mod, "TILE_ALIGN", 1)
     monkeypatch.setattr(
         engine_mod, "TILE_ROWS",
-        cfg.num_instances // TILES[name] if TILES[name] > 1 else 1 << 40)
+        rows // TILES[name] if TILES[name] > 1 else 1 << 40)
     eng = MultiRaftEngine(
-        cfg, **({"spare": SPARE} if cfg.replace_replicas else {}))
+        cfg, **({"spare": SPARE} if cfg.replace_replicas else {}),
+        **({"nodes": jax.devices()[:cfg.num_replicas]} if placed else {}))
     assert eng._tiles == TILES[name]
     return eng
 
@@ -138,7 +146,13 @@ def expected(eng: MultiRaftEngine, loop: bool) -> set:
     cfg = eng.cfg
     want = {"raft_deliver", "raft_tick", "raft_control", "raft_propose",
             "raft_emit", "raft_lease", "raft_carry"}
-    if loop:
+    if eng._nodes is not None:
+        # The exchange runs with the eager round too; only the scan
+        # has an occupancy, and a ScanWatch, to agree on. Nothing of
+        # route() runs.
+        want |= {"raft_ici", "raft_tiles"} | (
+            {"raft_agree"} if loop else set())
+    elif loop:
         want.add("raft_route")
     if cfg.telemetry:
         want.add("raft_telemetry")
@@ -153,11 +167,12 @@ def expected(eng: MultiRaftEngine, loop: bool) -> set:
 
 
 def test_the_registry_is_what_the_program_names():
-    assert len(set(SCOPES)) == len(SCOPES) == 12
+    assert len(set(SCOPES)) == len(SCOPES) == 14
     assert all(SCOPE_RE.fullmatch(s) for s in SCOPES)
     assert {layer for layer, _n, _s in step_mod.DEVICE_SCOPES} == {
         "round program", "closed-loop engine"}
-    assert ENGINE == ("raft_tiles", "raft_watch", "raft_carry")
+    assert ENGINE == ("raft_tiles", "raft_watch", "raft_carry", "raft_ici",
+                      "raft_agree")
     assert all(scope == "raft_" + name
                for _layer, name, scope in step_mod.DEVICE_SCOPES)
     # Every named_scope the two modules open is registered, and every
@@ -200,21 +215,79 @@ def test_every_equation_of_the_eager_round_has_a_registered_scope(
     assert set(by_scope) == expected(eng, loop=False)
 
 
+# -- placed over nodes (ISSUE 40) ------------------------------------------------------
+
+
+def primitives_by_scope(jaxpr, outer: str = "") -> dict:
+    """{scope: {primitive}} of the leaf equations, as `scoped` files
+    them."""
+    out: dict = {}
+    for eqn in jaxpr.eqns:
+        stack = f"{outer}/{eqn.source_info.name_stack}"
+        bodies = list(_bodies(eqn))
+        for body in bodies:
+            for k, v in primitives_by_scope(body, stack).items():
+                out.setdefault(k, set()).update(v)
+        if not bodies:
+            hits = [h for h in SCOPE_RE.findall(stack) if h in SCOPES]
+            out.setdefault(hits[-1] if hits else "", set()).add(
+                eqn.primitive.name)
+    return out
+
+
+@pytest.mark.parametrize("loop", (True, False), ids=("closed-loop", "eager"))
+def test_placed_over_nodes_every_equation_has_a_registered_scope(
+        loop, monkeypatch):
+    """The replacement configuration over four nodes, a node's rows in
+    two tiles: every equation under a registered scope, none under
+    ``raft_route`` (``route()`` does not run: a trace of this program
+    has no such scope, which is what the benchmark's readers of it
+    count on), every collective under one of the two new names."""
+    eng = engine_of(CONFIGS[4], monkeypatch, placed=True)
+    assert eng._nodes is not None and eng.cfg.replace_replicas
+    jaxpr = loop_jaxpr(eng) if loop else round_jaxpr(eng)
+    by_scope, bare = scoped(jaxpr)
+    assert not bare, bare[:10]
+    assert set(by_scope) == expected(eng, loop)
+    assert "raft_route" not in by_scope
+    prims = primitives_by_scope(jaxpr)
+    collectives = {"all_to_all", "psum", "psum_invariant", "pmax",
+                   "all_gather", "ppermute", "pmin"}
+    assert "all_to_all" in prims["raft_ici"]
+    if loop:
+        assert prims["raft_agree"] & {"psum", "psum_invariant"}
+        assert "pmax" in prims["raft_agree"]
+    for scope, got in prims.items():
+        if scope not in ("raft_ici", "raft_agree"):
+            assert not got & collectives, (scope, got & collectives)
+
+
 # -- and the test has teeth ----------------------------------------------------------
 
 
 @pytest.mark.parametrize("scope", ENGINE)
 def test_a_scope_left_out_leaves_its_lines_bare(scope, monkeypatch):
-    """Each of the three engine scopes taken away in turn (its ``with``
-    a no-op): the lines it enclosed stand under no name, or under the
-    wrong one, and the rule above fails."""
+    """Each of the engine scopes taken away in turn (its ``with`` a
+    no-op): the lines it enclosed stand under no name, or under the
+    wrong one, and the rule above fails. The two of the node-placed
+    loop on that loop: without its name the scan's exchange stands
+    under none, and the watch's reduction over a group is filed with
+    the watch, where a trace would hide the interconnect's time."""
     real = jax.named_scope
     monkeypatch.setattr(
         jax, "named_scope",
         lambda s: contextlib.nullcontext() if s == scope else real(s))
-    eng = engine_of("engine512k-r3of4", monkeypatch)
-    by_scope, bare = scoped(loop_jaxpr(eng))
-    assert bare and scope not in by_scope
+    placed = scope in ("raft_ici", "raft_agree")
+    eng = engine_of("engine512k-r3of4", monkeypatch, placed=placed)
+    jaxpr = loop_jaxpr(eng)
+    by_scope, bare = scoped(jaxpr)
+    assert scope not in by_scope
+    if placed:
+        prims = primitives_by_scope(jaxpr)
+        assert {"raft_ici": "all_to_all" in prims.get("", ()),
+                "raft_agree": "pmax" in prims["raft_watch"]}[scope]
+        return
+    assert bare
     kinds = {prim for prim, _stack in bare}
     assert {"raft_tiles": {"dynamic_slice", "dynamic_update_slice"} <= kinds,
             "raft_watch": "reduce_sum" in kinds,
