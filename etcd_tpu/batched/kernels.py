@@ -29,19 +29,38 @@ def quorum_committed(match: jnp.ndarray, voter: jnp.ndarray) -> jnp.ndarray:
     """Largest index acked by a quorum of voters.
 
     Go picks srt[n-(n/2+1)] of the ascending sort of n acked indexes
-    (missing voters count 0). Masking non-voters to 0 prepends (R-n)
-    zeros to the sort, shifting the pick to position R - n//2 - 1.
+    (missing voters count 0). With non-voters masked to 0 that is the
+    q-th largest of the R masked values, q = n//2 + 1, and the q-th
+    largest of a multiset is the largest member that at least q
+    members reach: max{m_j : #{i : m_i >= m_j} >= q}. The set is never
+    empty (the smallest member counts all R >= q) and `match` is never
+    negative, so 0 is the identity of the max.
+
+    Written over the R static slots: R*R compares, R selects and R
+    maxima on per-slot scalars, which XLA fuses into the phase that
+    calls it. A `sort` is the one op of the round the TPU compiler
+    never fuses (its operand goes out to memory, it runs as a kernel of
+    its own padded to a power of two, and a one-hot pick reads the
+    result back): PERF.md section 6, "PR 41".
+
+    The mask is applied to the whole [R] vector BEFORE the slots are
+    taken apart, and that order is load-bearing: one op of [N, R] shape
+    is what keeps the instance axis minor. With voter[i] and match[i]
+    sliced first (the same bits) the node-placed closed loop compiles
+    with the log ring ring-minor all through tick, propose and control,
+    at 3.5 times the round's cost.
     """
     r = match.shape[-1]
     n = jnp.sum(voter.astype(I32))
+    q = n // 2 + 1
     masked = jnp.where(voter, match, 0)
-    srt = jnp.sort(masked)  # ascending
-    pos = jnp.clip(r - n // 2 - 1, 0, r - 1)
-    # One-hot pick instead of srt[pos]: traced-index gathers serialize
-    # on TPU; a compare+reduce over R stays on the VPU.
-    pick = jnp.sum(jnp.where(jnp.arange(r, dtype=I32) == pos, srt, 0), -1)
+    m = [masked[i] for i in range(r)]
+    best = jnp.zeros((), I32)
+    for j in range(r):
+        reach = sum((m[i] >= m[j]).astype(I32) for i in range(r))
+        best = jnp.maximum(best, jnp.where(reach >= q, m[j], 0))
     # Empty config commits "everything" (joint-quorum convention).
-    return jnp.where(n == 0, MAX_I32, pick)
+    return jnp.where(n == 0, MAX_I32, best)
 
 
 def vote_result(votes: jnp.ndarray, voter: jnp.ndarray) -> jnp.ndarray:

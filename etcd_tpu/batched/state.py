@@ -47,7 +47,9 @@ class BatchedConfig(NamedTuple):
     """Static (compile-time) engine configuration."""
 
     num_groups: int
-    num_replicas: int  # R: replica slots per group (<= 8 keeps sorts cheap)
+    # R: replica slots per group (<= 8: the quorum index is R*R compares
+    # a call, kernels.quorum_committed, and route() R*R shifted slices)
+    num_replicas: int
     window: int  # W: log-ring capacity per instance
     max_ents_per_msg: int  # E: entries carried by one MsgApp
     max_props_per_round: int  # P: proposals appended per instance per round

@@ -79,8 +79,13 @@ def test_the_resolved_round_holds_no_loop_and_the_scan_one(cfg):
     assert one.count("stablehlo.while") == 0, (
         "a loop is back inside the round: deliver folds each lane once "
         "over the sender axis and scans nothing")
-    assert one.count("stablehlo.sort") > 0  # the text is the program's
+    assert "stablehlo.reduce" in one  # the text is the program's
     assert loop.count("stablehlo.while") == 1, "only the round scan loops"
+    for text in (one, loop):
+        assert text.count("stablehlo.sort") == 0, (
+            "a sort is back in the round: the quorum index is an "
+            "elementwise order statistic (kernels.quorum_committed), "
+            "which the TPU compiler fuses; a sort it never does")
 
 
 # -- (c) the lane counter ----------------------------------------------------------

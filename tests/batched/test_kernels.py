@@ -41,21 +41,65 @@ R = 8
 W = 64
 
 
-def test_quorum_committed_matches_oracle():
-    cases = []
-    for _ in range(500):
-        match = [rng.randint(0, 20) for _ in range(R)]
-        voter = [rng.random() < 0.7 for _ in range(R)]
-        cases.append((match, voter))
-    match = jnp.array([c[0] for c in cases], jnp.int32)
-    voter = jnp.array([c[1] for c in cases])
-    got = np.asarray(jax.jit(jax.vmap(quorum_committed))(match, voter))
-    for i, (m, v) in enumerate(cases):
-        cfg = MajorityConfig(j for j in range(R) if v[j])
-        if not cfg:
-            assert got[i] == 2**31 - 1  # device ∞ is int32 max
-        else:
-            assert got[i] == cfg.committed_index(lambda vid: m[vid]), (m, v)
+def _quorum_cases(r, n=400):
+    """[n, r] acked indexes and voter masks: small ranges (ties), rows
+    all equal, rows near the top of int32, no voter, one voter, all."""
+    rs = np.random.RandomState(100 + r)
+    top = rs.choice([3, 20, MAX_I32], size=(n, 1))
+    match = (rs.randint(0, MAX_I32, size=(n, r)) % top).astype(np.int32)
+    voter = rs.rand(n, r) < rs.rand(n, 1)
+    match[0] = 7  # all equal
+    match[1] = MAX_I32 - 1  # the largest index the device holds
+    match[2, 0] = MAX_I32 - 1
+    voter[:3] = True
+    voter[3] = False  # no voter
+    voter[4] = False
+    voter[4, r - 1] = True  # one voter, the last slot
+    voter[5] = True
+    voter[5, 0] = r == 1  # all but the first
+    return match, voter
+
+
+def _majority_committed(match_row, voter_row):
+    cfg = MajorityConfig(np.flatnonzero(voter_row).tolist())
+    want = cfg.committed_index(lambda vid: int(match_row[vid]))
+    return MAX_I32 if want == MAX_UINT64 else want  # device ∞ is int32 max
+
+
+@pytest.mark.parametrize("r", range(1, 9))
+def test_quorum_committed_matches_oracle(r):
+    match, voter = _quorum_cases(r)
+    fn = jax.jit(jax.vmap(quorum_committed))
+    got = np.asarray(fn(jnp.asarray(match), jnp.asarray(voter)))
+    assert got.dtype == np.int32
+    for i in range(len(match)):
+        assert got[i] == _majority_committed(match[i], voter[i]), (
+            match[i], voter[i])
+    # The q-th largest of R numbers is compares and selects: no sort
+    # (the one op of the round the TPU compiler never fuses) and no
+    # gather in the program.
+    text = fn.lower(jnp.asarray(match), jnp.asarray(voter)).as_text()
+    assert "stablehlo.sort" not in text and "gather" not in text
+
+
+@pytest.mark.parametrize("in_joint", (False, True), ids=("majority", "joint"))
+@pytest.mark.parametrize("r", range(1, 9))
+def test_joint_committed_matches_oracle(r, in_joint):
+    """The minimum over both halves where the configuration is joint,
+    the incoming half alone where it is not, whatever the outgoing mask
+    holds."""
+    match, voter = _quorum_cases(r)
+    voter_out = np.random.RandomState(200 + r).rand(*voter.shape) < 0.5
+    voter_out[0] = False  # an empty outgoing half beside a full incoming
+    voter_out[3] = False  # both halves empty
+    got = np.asarray(jax.jit(jax.vmap(joint_committed, (0, 0, 0, None)))(
+        jnp.asarray(match), jnp.asarray(voter), jnp.asarray(voter_out),
+        jnp.asarray(in_joint)))
+    for i in range(len(match)):
+        want = _majority_committed(match[i], voter[i])
+        if in_joint:
+            want = min(want, _majority_committed(match[i], voter_out[i]))
+        assert got[i] == want, (match[i], voter[i], voter_out[i])
 
 
 VOTE_OF = {

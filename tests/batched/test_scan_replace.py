@@ -582,21 +582,25 @@ def test_the_oracle_raises_where_a_reused_slot_votes_twice_in_a_term():
 # 33), which stood through 2cee456 (PR 38); re-pinned by PR 39 on its own
 # text, because it rewrote `kernels.ring_write_masked` (one reduce whose
 # output is the ring, for a sum, an any and a select), which every
-# append site of the round calls: the text of every configuration moved
-# on purpose, and the chip compiles each scan anew once.
+# append site of the round calls; re-pinned by PR 41 on its own text,
+# because it rewrote `kernels.quorum_committed` (the q-th largest of the
+# R acked indexes by compares and selects, for a sort and a one-hot
+# pick), which every `_maybe_commit` of the round calls: each time the
+# text of every configuration moved on purpose, and the chip compiles
+# each scan anew once.
 PARENT_TEXT = {
     "engine64k-r3": (
-        "96e5d9705155792d82931ab14ba5b811257229512a4a00a4c6516fbed69d65a9",
-        "f50f9e20ec84a986bf4a8aaee9ec123d29cd721f44fedee49de3f8610d076471"),
+        "6d8ca8016fa9b5eda1ce3dbc3708692ff213598d2594f51df23cbab12af585ae",
+        "150c7061ba15bf21620d59ac2f0c6639f05f12ffcce7fb8fb6d0e73f6f352ebb"),
     "engine10k-r5": (
-        "e0605aad1b2d820457d214d94029a9c3462148b725fa570f6db3f892b493e46a",
-        "248cd628954d81f6e3b40252cc44e3d913cb08e8adcb16a540da50621ac0b585"),
+        "8b7c9902d465931d61732d588f6fbea8bf3cefa20aea796c48327bb780d199c9",
+        "0a3b208dc6a2bcf15c1a8bb7bbb3a3ba5e4b722c1f28bb560998592d0828970d"),
     "engine100k-r3": (
-        "c35627cbdf26df2489b4d4300d7341be7b8d601a3a4d84f48738000b2a367235",
-        "9b2f790aa71022b573e867128ac2c0acc8fb004fc5a2f613db1f8d97593f02c0"),
+        "994e97925c0027eabfbd313ea236acb1f2326daa7e2feda544eac39ff08922ce",
+        "67b5148d70cb429b15499a5f1d3e47d7691aff19e44762f42cbb0d6b0b6548d3"),
     "engine1m-r3": (
-        "00213fa35022563b9c686ef68fd5024446b76e6fe057baca0cdab1006d80e432",
-        "3631a5fcc602861c48e4c265b2f739333c57c7415ed64f9d4b0e92eb0199acb2"),
+        "f780c02ed191549ce2e3626314393387da28b122067c4f17f3e09ef9b05e7e7a",
+        "1819b804b111d82a47a5320b44541cf05d9661886cde85c3c9247e75ed9d8fb3"),
 }
 
 
@@ -638,7 +642,7 @@ def test_with_the_new_fields_off_the_round_is_the_parents_text(name):
         print(name, got)
     assert got == PARENT_TEXT[name], (
         "the lowered round or closed loop of a live configuration is not "
-        "the text it was at the commit that pinned it (PR 39)")
+        "the text it was at the commit that pinned it (PR 41)")
     assert control_cols(cfg) == 5 and watch_names(cfg) == WATCH_NAMES
 
 

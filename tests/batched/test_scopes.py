@@ -114,9 +114,9 @@ def engine_of(name: str, monkeypatch, placed: bool = False
     return eng
 
 
-def loop_jaxpr(eng: MultiRaftEngine):
-    """The closed loop traced with the schedules its cell hands it:
-    none (the two append cells), the fault schedule (the election
+def loop_args(eng: MultiRaftEngine) -> tuple:
+    """The closed loop's arguments with the schedules its cell hands
+    it: none (the two append cells), the fault schedule (the election
     cell), both (the two cells with a control plane)."""
     cfg = eng.cfg
     sched = ctl = watch = None
@@ -127,18 +127,27 @@ def loop_jaxpr(eng: MultiRaftEngine):
         ctl, _ = eng._control_schedule(
             np.zeros((ROUNDS, control_cols(cfg)), np.int32), ROUNDS)
         watch = eng._watch
+    return (eng.state, eng.inbox, eng._zeros_b, eng._zeros_i, eng._tel(),
+            eng._flt(), eng._lanes, sched, ROUNDS, ctl, watch)
+
+
+def round_args(eng: MultiRaftEngine) -> tuple:
+    """(args, kwargs) of the eager round (``engine.step_round``'s
+    program)."""
+    cfg, zb, zi = eng.cfg, eng._zeros_b, eng._zeros_i
+    return ((eng.state, eng.inbox, zb, zb, zi, zb, zi, zb),
+            dict(conf_req=zi if cfg.conf_entries else None,
+                 wipe=zb if cfg.replace_replicas else None))
+
+
+def loop_jaxpr(eng: MultiRaftEngine):
     return jax.make_jaxpr(eng._closed_loop, static_argnums=(8,))(
-        eng.state, eng.inbox, eng._zeros_b, eng._zeros_i, eng._tel(),
-        eng._flt(), eng._lanes, sched, ROUNDS, ctl, watch).jaxpr
+        *loop_args(eng)).jaxpr
 
 
 def round_jaxpr(eng: MultiRaftEngine):
-    """The eager round (``engine.step_round``'s program)."""
-    cfg, zb, zi = eng.cfg, eng._zeros_b, eng._zeros_i
-    return jax.make_jaxpr(eng._round)(
-        eng.state, eng.inbox, zb, zb, zi, zb, zi, zb,
-        conf_req=zi if cfg.conf_entries else None,
-        wipe=zb if cfg.replace_replicas else None).jaxpr
+    args, kwargs = round_args(eng)
+    return jax.make_jaxpr(eng._round)(*args, **kwargs).jaxpr
 
 
 def expected(eng: MultiRaftEngine, loop: bool) -> set:
@@ -315,11 +324,14 @@ def test_a_line_moved_out_of_its_scope_fails_the_rule(name, monkeypatch):
 
 # sha256 of the lowered text (the closed loop as its cell calls it, with
 # both schedules; the eager round) of the two configurations that run in
-# tiles, at 8 groups in two tiles, taken with tiled_text() below. Pinned
+# tiles, at 8 groups in two tiles, taken with lowered_texts() below. Pinned
 # by PR 38 on the text of 904936a (PR 36), which 2cee456 (PR 38) kept;
 # re-pinned by PR 39 on its own text, because it rewrote
 # `kernels.ring_write_masked`, which every append site of the round
-# calls (the untiled pins in `test_scan_replace.py` moved with it): names
+# calls, and by PR 41 on its own, because it rewrote
+# `kernels.quorum_committed` (an elementwise order statistic for a sort
+# and a one-hot pick), which every `_maybe_commit` of the round calls
+# (the untiled pins in `test_scan_replace.py` moved with both): names
 # are still not part of either text. The lowered
 # text holds no name of a scope, and JAX's persistent cache keys a
 # program with its names stripped: equal text here is a cache hit on the
@@ -330,34 +342,48 @@ def test_a_line_moved_out_of_its_scope_fails_the_rule(name, monkeypatch):
 # prints them); `test_scan_replace.py` pins the untiled texts.
 PARENT_TILED_TEXT = {
     "engine1m-r3": (
-        "2d1e27dbcebecb1ce9bb734878a5ac244550331b7e2d933cfc0b9f8d1ffb74e3",
-        "613b03e280c228163a8f0e6e90b5fbb4f0364ff7c8b9c791b8bb7b340bd3db76"),
+        "fa77d3054781cf1fcbe72da9ca86338c6e77fe1a4b07ea8237e2a328636dbf6d",
+        "88746b8fa3720d29f6b89fff851b9701aa6cb099477c28fd35e484efd6f68a06"),
     "engine512k-r3of4": (
-        "36542571e5df2c10bc2dc33c71f50931e3d1cf01b84d0399c4f8b4f3f1307012",
-        "d45a32b5c95f42bf17411140980299932bae91fa72a0ef358c9f431ba0000971"),
+        "a335faade54c8333c8c5ea0fad7758cde068f832658d873e5bbae7a9be96149c",
+        "45c64101c8f011b5ec917c64a08c67110039976979111cd4545bf4bf7135b07b"),
 }
 
 
-def tiled_text(eng: MultiRaftEngine):
-    cfg, zb, zi = eng.cfg, eng._zeros_b, eng._zeros_i
-    ctl, _ = eng._control_schedule(
-        np.zeros((ROUNDS, control_cols(cfg)), np.int32), ROUNDS)
-    loop = eng._closed_loop.lower(
-        eng.state, eng.inbox, zb, zi, eng._tel(), eng._flt(), eng._lanes,
-        jnp.zeros((ROUNDS, cfg.num_replicas), bool), ROUNDS, ctl, eng._watch)
-    one = eng._round.lower(
-        eng.state, eng.inbox, zb, zb, zi, zb, zi, zb, conf_req=zi,
-        wipe=zb if cfg.replace_replicas else None)
-    return loop.as_text(), one.as_text()
+def lowered_texts(eng: MultiRaftEngine):
+    """(closed loop, eager round) lowered as `loop_jaxpr` and
+    `round_jaxpr` trace them: nothing compiles."""
+    args, kwargs = round_args(eng)
+    return (eng._closed_loop.lower(*loop_args(eng)).as_text(),
+            eng._round.lower(*args, **kwargs).as_text())
 
 
 @pytest.mark.parametrize("name", sorted(PARENT_TILED_TEXT))
 def test_in_tiles_the_lowered_text_is_the_parents(name, monkeypatch):
-    texts = tiled_text(engine_of(name, monkeypatch))
+    texts = lowered_texts(engine_of(name, monkeypatch))
     assert not any(scope in t for t in texts for scope in SCOPES)
     got = tuple(hashlib.sha256(t.encode()).hexdigest() for t in texts)
     if os.environ.get("ETCD_TPU_PRINT_ROUND_DIGESTS"):
         print(name, got)
     assert got == PARENT_TILED_TEXT[name], (
         "the lowered closed loop or eager round of a tiled configuration "
-        "is not the text it was at the commit that pinned it (PR 39)")
+        "is not the text it was at the commit that pinned it (PR 41)")
+
+
+# -- no sort in any live program (ISSUE 41) ----------------------------------------
+
+
+@pytest.mark.parametrize(
+    "name, placed", [(name, False) for name in CONFIGS] + [(CONFIGS[4], True)],
+    ids=list(CONFIGS) + [CONFIGS[4] + "-placed"])
+def test_no_live_program_holds_a_sort(name, placed, monkeypatch):
+    """The quorum index was the round's only `sort` (two a
+    `_maybe_commit`), the one op of the round the TPU compiler never
+    fuses. `kernels.quorum_committed` is an elementwise order statistic
+    since PR 41; a sort that comes back, there or anywhere in a round,
+    shows here in the lowered closed loop and eager round of every live
+    configuration, the node-placed one included, before it shows as
+    `*/sort` in a cell's traced line."""
+    for text in lowered_texts(engine_of(name, monkeypatch, placed=placed)):
+        assert "stablehlo.reduce" in text  # the text is the program's
+        assert text.count("stablehlo.sort") == 0
