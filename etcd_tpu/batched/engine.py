@@ -17,7 +17,11 @@ apply point (``BatchedConfig.conf_entries``) inside one program; with
 (``MultiRaftEngine(cfg, spare=...)``), a fresh replica joins there as a
 learner and is carried by a snapshot that states the configuration, a
 node is retired and its slot reset (``CTL_RETIRE``, ``CTL_WIPE``), all
-inside the scan. Inside
+inside the scan. The control schedule is a row a round for every group
+alike, or *phased* (``run_rounds(control=cycle, starts=...)``): one
+cycle's rows and the round at which each group enters it, so that a
+rebalancer's batch of groups moves while every other group runs steady
+beside it. Inside
 a scan the network moves only what
 was sent: inbox and outbox ride as six kind lanes (entries in the
 append lane alone, ``step.split_lanes``) and a round exchanges
@@ -104,11 +108,43 @@ CTL_COLS = 5
 # of a retired machine handed to a fresh process. CTL_CONF holds the
 # wide ``state.conf_code`` there, second slot included.
 CTL_RETIRE, CTL_WIPE = 5, 6
+# A *phased* schedule (``run_rounds(control=..., starts=...)``) is one
+# cycle's rows and, a group, the round at which it enters the cycle: a
+# rebalancer that moves a few ranges at a time, where the form above
+# moves every group in the same round. `control` is then int32
+# [cycle_rounds, control_cols], the columns as they are, and `starts`
+# int32 [num_groups], counted in the rounds the engine's phased scans
+# have run (``MultiRaftEngine.phase_round``, carried across calls). In
+# round t group g reads row ``t - starts[g]`` of the cycle for what is a
+# group's own (CTL_FROM, CTL_TO, CTL_CONF, CTL_RETIRE, CTL_WIPE); a
+# group whose start is in the future (NEVER: never) or whose cycle has
+# ended reads the steady row: no transfer, no change on offer, nobody
+# retired, nothing wiped. What is a round's and no group's (CTL_READS,
+# CTL_STALL) is read per round, from row ``t mod cycle_rounds``. On the
+# device the cycle is its runs of equal rows (`_cycle_runs`: an edge a
+# run) and a row of the batch finds its own by as many compares on
+# ``t - start`` (scope ``raft_phase``); the scan's per-round input is
+# three scalars, in these columns.
+PH_ROUND, PH_READS, PH_STALL = range(3)
+NEVER = np.iinfo(np.int32).max
 
 
 def control_cols(cfg: BatchedConfig) -> int:
     """The width of `cfg`'s control schedule."""
     return CTL_COLS + (2 if cfg.replace_replicas else 0)
+
+
+def _cycle_runs(cycle: np.ndarray):
+    """A phased schedule's cycle as its runs of equal rows, in what is
+    a group's own (the round's columns zeroed): (edges [E], runs
+    [E, cols]), run j from cycle round ``edges[j]`` on; the last, from
+    the cycle's end on, is the steady row, all zeros."""
+    own = cycle.astype(np.int32)
+    own[:, [CTL_READS, CTL_STALL]] = 0
+    first = np.ones(len(own), bool)
+    first[1:] = (own[1:] != own[:-1]).any(axis=1)
+    edges = np.append(np.flatnonzero(first), len(own))
+    return edges, np.concatenate([own[first], np.zeros_like(own[:1])])
 
 
 # What a scan with a control schedule counts in its carry
@@ -239,7 +275,10 @@ class MultiRaftEngine:
     (rows that offer a change, ask for a hand-over; 0 with none) and,
     for a configuration with ``replace_replicas``, ``retired`` and
     ``wipes`` (rounds x nodes switched off, reset; 0 elsewhere) as
-    stats. A span ends when the
+    stats; of a phased schedule those four count a round a batch in
+    flight, and ``batches`` (in flight in the call) and ``started``
+    (groups that have entered the cycle by its end) say which form it
+    was. A span ends when the
     program is enqueued: the host's share of a call, not the device's."""
 
     def __init__(self, cfg: BatchedConfig, start_index: int = 0,
@@ -449,6 +488,11 @@ class MultiRaftEngine:
         # What the scans with a control schedule counted (scan_watch()):
         # made by the first of them, carried by every one after.
         self._watch: Optional[ScanWatch] = None
+        # Rounds the scans with a phased schedule have run (what its
+        # `starts` count in), and that schedule as the device holds it
+        # (`_phased_schedule`).
+        self.phase_round = 0
+        self._phase: Optional[dict] = None
         # In-device telemetry accumulator (cfg.telemetry): per-instance
         # counter totals + OR-folded invariant bitmaps, accumulated
         # inside the closed-loop scan with no per-round host sync.
@@ -491,9 +535,40 @@ class MultiRaftEngine:
             self._fleet_sum_np = self._fleet_layout.sum_mask()
         self.fleet_hub = None
 
-        def round_body(step, zeros_b, zeros_i, slots, ticks, props, tiled):
+        def widen_phased(phase, t, slots, zeros_i):
+            """What round `t` of a phased schedule asks of each row:
+            (transfer, conf, retired, wipe), the last three None where
+            the configuration has no such input. `phase` is (edges [E],
+            runs [E, cols], start [rows]): a row's cycle round is
+            ``t - start``, its run the last whose edge is at or below
+            it (none before the cycle begins; the last run, from the
+            cycle's end on, is the steady row)."""
+            edges, runs, start = phase
+            k = t - start
+            past = [k >= edges[j] for j in range(edges.shape[0])]
+
+            def column(col):
+                out = zeros_i
+                for j, at_or_past in enumerate(past):
+                    out = jnp.where(at_or_past, runs[j, col], out)
+                return out
+
+            drained = slots == column(CTL_FROM) - 1
+            transfer = jnp.where(drained, column(CTL_TO), 0)
+            conf = retired = wipe = None
+            if cfg.conf_entries:
+                conf = jnp.where(drained, 0, column(CTL_CONF))
+            if cfg.replace_replicas:
+                retired = slots == column(CTL_RETIRE) - 1
+                wipe = slots == column(CTL_WIPE) - 1
+            return transfer, conf, retired, wipe
+
+        def round_body(step, zeros_b, zeros_i, slots, ticks, props, tiled,
+                       phase=None):
             """The scan's body over the rows its arguments are made
-            for: all N, or one tile's (`tiled`)."""
+            for: all N, or one tile's (`tiled`). With `phase`
+            (`widen_phased`) the control row is a phased schedule's
+            three scalars of the round."""
 
             def body(carry, row):
                 # `occ` is the inbox's lane occupancy, [K] bool: what
@@ -518,7 +593,7 @@ class MultiRaftEngine:
                     transfer, reads, conf = zeros_i, zeros_b, None
                     wipe = None
                     # jitlint: waive(tracer-branch) -- as above
-                    if ctl is not None:
+                    if ctl is not None and phase is None:
                         # The row's few scalars widened the same way.
                         drained = slots == ctl[CTL_FROM] - 1
                         transfer = jnp.where(drained, ctl[CTL_TO], 0)
@@ -530,6 +605,19 @@ class MultiRaftEngine:
                             iso = iso | (slots == ctl[CTL_RETIRE] - 1)
                             wipe = slots == ctl[CTL_WIPE] - 1
                         pre = st
+                    # jitlint: waive(tracer-branch) -- as above
+                    elif ctl is not None:
+                        # A phased schedule: each row reads the cycle
+                        # at its own group's round of it.
+                        with jax.named_scope("raft_phase"):
+                            transfer, conf, retired, wipe = widen_phased(
+                                phase, ctl[PH_ROUND], slots, zeros_i)
+                            reads = jnp.broadcast_to(
+                                ctl[PH_READS] != 0, zeros_b.shape)
+                            if cfg.replace_replicas:
+                                iso = iso | retired
+                            stall = ctl[PH_STALL] != 0
+                        pre = st
                     out = step(
                         st, inbox, ticks, zeros_b, props, iso,
                         transfer, reads, lane_any=occ, conf_req=conf,
@@ -539,8 +627,12 @@ class MultiRaftEngine:
                 # jitlint: waive(tracer-branch) -- as above
                 if ctl is not None:
                     with jax.named_scope("raft_watch"):
+                        # (The lockstep row's stall is read here, where
+                        # it always was: the lowered text follows the
+                        # order of the lines.)
                         watch = self._watch_round(
-                            watch, pre, st, slots, ctl[CTL_STALL] != 0,
+                            watch, pre, st, slots,
+                            ctl[CTL_STALL] != 0 if phase is None else stall,
                             wipe)
                 with jax.named_scope("raft_carry"):
                     if cfg.telemetry:
@@ -592,7 +684,7 @@ class MultiRaftEngine:
                 for k in range(NUM_KINDS)), occ
 
         def tiled_loop(st, inbox, ticks, props, tel, lanes, isolate,
-                       rounds, control, watch):
+                       rounds, control, watch, phase=None):
             """`closed_loop` tile by tile. Groups share nothing, so a
             call of `rounds` rounds over all rows is `tiles` calls
             over a block of whole groups each (`rows` adjacent rows:
@@ -612,21 +704,24 @@ class MultiRaftEngine:
             nodes walking the same tiles together: a tile's round ends
             in their exchange. `lanes` is then (rounds occupied, tile-
             rounds crossed), the occupancy every node counts is the
-            agreed one, and the fence is the node's own, [1]."""
+            agreed one, and the fence is the node's own, [1]. Of a
+            phased schedule (`phase`: `widen_phased`) a tile takes its
+            rows' starts, as it takes every per-row array."""
             with jax.named_scope("raft_carry"):
                 slots = row_slots()
                 zeros_b = jnp.zeros((rows,), bool)
                 zeros_i = jnp.zeros((rows,), I32)
 
-            def tile_body(lo, ticks, props):
+            def tile_body(lo, ticks, props, start=()):
                 """The scan's body for the rows from `lo` on."""
                 with jax.named_scope("raft_carry"):
                     step = tile_step(lo, slots)
-                return round_body(step, zeros_b, zeros_i, slots, ticks,
-                                  props, tiled=True)
+                return round_body(
+                    step, zeros_b, zeros_i, slots, ticks, props, tiled=True,
+                    phase=None if phase is None else phase[:2] + (start,))
 
             def tile_rounds(lo, st, inbox, tel, watch, ticks, props,
-                            crossed):
+                            crossed, start=()):
                 """The call's rounds on the rows from `lo` on, handed
                 in as the tile's slices (`watch` with the whole
                 counts, `crossed` the whole count of lanes exchanged
@@ -637,7 +732,7 @@ class MultiRaftEngine:
                 if placed:
                     occ = agree_lanes(occ, NODE_AXIS)
                 (st, inbox, _, tel, _, crossed, watch), occs = jax.lax.scan(
-                    tile_body(lo, ticks, props),
+                    tile_body(lo, ticks, props, start),
                     (st, inbox, occ, tel, (), crossed, watch),
                     (isolate, control), length=rounds)
                 return st, inbox, tel, watch, occs, crossed
@@ -655,8 +750,12 @@ class MultiRaftEngine:
                         history=cut(watch.history))
                     mine = (*jax.tree.map(cut, (st, inbox, tel)), t_watch,
                             cut(ticks), cut(props))
+                    start = ()
+                    if phase is not None:  # its structure: None or arrays
+                        with jax.named_scope("raft_phase"):
+                            start = (cut(phase[2]),)
                 t_st, t_inbox, t_tel, t_watch, occs, crossed = tile_rounds(
-                    lo, *mine, crossed)
+                    lo, *mine, crossed, *start)
                 with jax.named_scope("raft_tiles"):
                     # In place: the carry is the donated state, and no
                     # second copy of it exists.
@@ -684,17 +783,20 @@ class MultiRaftEngine:
             # jitlint: waive(tracer-branch) -- None is an empty pytree, as in closed_loop
             t_watch = None if watch is None else ScanWatch(
                 watch.counts, like(watch.read_floor), like(watch.history))
+            # jitlint: waive(tracer-branch) -- as above: None or a tuple of arrays
+            t_start = () if phase is None else (like(phase[2]),)
             with self._pretrace():
                 jax.eval_shape(
-                    lambda ticks, props, carry, row: tile_body(
-                        0, ticks, props)(carry, row),
+                    lambda ticks, props, carry, row, *start: tile_body(
+                        0, ticks, props, *start)(carry, row),
                     like(ticks), like(props),
                     (*jax.tree.map(like, (st, inbox)),
                      jax.ShapeDtypeStruct((NUM_KINDS,), bool),
                      jax.tree.map(like, tel), (), crossed, t_watch),
                     jax.tree.map(
                         lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
-                        (isolate, control)))
+                        (isolate, control)),
+                    *t_start)
             with jax.named_scope("raft_tiles"):
                 seen = jnp.zeros((rounds, NUM_KINDS), bool)
             st, inbox, tel, watch, seen, crossed = jax.lax.fori_loop(
@@ -740,26 +842,34 @@ class MultiRaftEngine:
             return st, inbox, tel, (), lanes, fence, watch
 
         def closed_loop(st, inbox, ticks, props, tel, flt, lanes, isolate,
-                        rounds, control=None, watch=None):
+                        rounds, control=None, watch=None, phase=None):
             # `isolate` is None (no fault: the scan is traced as it
             # always was) or the bool [rounds, R] node schedule, one
             # row a round as the scan's xs; `control` is None (the
             # same) or the int32 [rounds, CTL_COLS] control schedule,
             # beside it, and `watch` the ScanWatch that rides the carry
-            # with it.
+            # with it. `phase` is None (the same again) or a phased
+            # schedule's (edges [E], runs [E, cols], starts [G]), and
+            # `control` then the rounds' [rounds, 3] (PH_*).
             if placed:
                 return placed_loop(st, inbox, ticks, props, tel, lanes,
                                    isolate, rounds, control, watch)
+            # jitlint: waive(tracer-branch) -- None or a tuple of arrays: the argument's structure
+            if phase is not None:
+                # A group's start on each of its rows (N is g-major).
+                with jax.named_scope("raft_phase"):
+                    phase = phase[:2] + (
+                        jnp.repeat(phase[2], cfg.num_replicas),)
             if tiles > 1:
                 return tiled_loop(st, inbox, ticks, props, tel, lanes,
-                                  isolate, rounds, control, watch)
+                                  isolate, rounds, control, watch, phase)
             slots = None
             # jitlint: waive(tracer-branch) -- None is an empty pytree: the branch is on the argument's structure at trace time, never on a device value
             if isolate is not None or control is not None:
                 with jax.named_scope("raft_carry"):
                     slots = jnp.arange(n, dtype=I32) % cfg.num_replicas
             body = round_body(self._step, self._zeros_b, self._zeros_i,
-                              slots, ticks, props, tiled=False)
+                              slots, ticks, props, tiled=False, phase=phase)
             # Inbox and outbox ride the scan as K kind lanes, each an
             # array of its own (the round answers lanes with lanes),
             # and the inbox is stacked back once at the exit.
@@ -1067,26 +1177,28 @@ class MultiRaftEngine:
         if control is None:
             return None, {"reads": 0, "conf_ops": 0, "transfers": 0,
                           "wipes": 0, "retired": 0}
+        ctl = self._control_rows(control, rounds)
+        return self._on_nodes(ctl), self._asked(
+            ctl, int((ctl[:, CTL_READS] != 0).sum()))
+
+    def _control_rows(self, control, rounds) -> np.ndarray:
+        """`control` as int32 [rounds, CTL_COLS] (a phased schedule's
+        cycle: `rounds` None, any number of rows), or a refusal; the
+        first such call makes the ScanWatch."""
         ctl = np.asarray(control)
         cols = control_cols(self.cfg)
-        if ctl.shape != (rounds, cols) or ctl.dtype.kind not in "iu":
+        rows = "cycle_rounds" if rounds is None else "rounds"
+        if (ctl.ndim != 2 or ctl.shape[1] != cols or not len(ctl)
+                or rounds not in (None, len(ctl))
+                or ctl.dtype.kind not in "iu"):
             raise ValueError(
-                f"control must be integers [rounds, CTL_COLS] = "
-                f"{(rounds, cols)}, got {ctl.dtype} {ctl.shape}")
+                f"control must be integers [{rows}, CTL_COLS] = "
+                f"{(rows if rounds is None else rounds, cols)}, got "
+                f"{ctl.dtype} {ctl.shape}")
         if ctl[:, CTL_CONF].any() and not self.cfg.conf_entries:
             raise ValueError(
                 "the control schedule offers a configuration change: "
                 "that needs a configuration with conf_entries")
-        stats = {
-            "reads": int((ctl[:, CTL_READS] != 0).sum())
-            * self.cfg.num_instances,
-            "conf_ops": int((ctl[:, CTL_CONF] != 0).sum()),
-            "transfers": int(((ctl[:, CTL_FROM] != 0)
-                              & (ctl[:, CTL_TO] != 0)).sum()),
-            # rounds x nodes, as `isolated` counts.
-            "wipes": int((ctl[:, CTL_WIPE:] != 0).sum()),
-            "retired": int((ctl[:, CTL_RETIRE:CTL_WIPE] != 0).sum()),
-        }
         if self._watch is None:
             counts = (len(watch_names(self.cfg)), 2)
             if self._nodes is not None:  # a node counts its own rows
@@ -1095,25 +1207,99 @@ class MultiRaftEngine:
                 self._zeros(counts, I32),
                 self._zeros((self.cfg.num_instances,), I32),
                 self._zeros((self.cfg.num_instances,), jnp.uint32))
-        return self._on_nodes(ctl.astype(np.int32)), stats
+        return ctl.astype(np.int32)
 
-    def _scan(self, rounds: int, ticks, props, isolate, control=None):
+    def _asked(self, seen: np.ndarray, reads: int) -> dict:
+        """The span's stats of the rows a call's instances read (of a
+        phased schedule: a round a batch in flight) and the rounds that
+        ask for reads."""
+        return {
+            "reads": reads * self.cfg.num_instances,
+            "conf_ops": int((seen[:, CTL_CONF] != 0).sum()),
+            "transfers": int(((seen[:, CTL_FROM] != 0)
+                              & (seen[:, CTL_TO] != 0)).sum()),
+            # rounds x nodes, as `isolated` counts.
+            "wipes": int((seen[:, CTL_WIPE:] != 0).sum()),
+            "retired": int((seen[:, CTL_RETIRE:CTL_WIPE] != 0).sum()),
+        }
+
+    def _phased_schedule(self, control, starts, rounds: int):
+        """A phased schedule (the CTL_* comment at the top of this
+        module) for the `rounds` rounds from ``phase_round`` on, which
+        moves on: (the rounds' [rounds, 3] PH_* rows and the
+        schedule's (edges, runs, starts), both on the device; the
+        span's stats, with two more: ``batches`` in flight in the call
+        and ``started``, the groups that have entered the cycle by its
+        end). The schedule's arrays are kept from call to call while
+        it is the same."""
+        if self._nodes is not None:
+            raise ValueError(
+                "a phased schedule reads the row's group, which an engine "
+                "placed over nodes does not widen yet (ROADMAP R1d): not "
+                "with nodes")
+        if control is None:
+            raise ValueError(
+                "starts says when each group enters the cycle that control "
+                "holds: it needs control")
+        cycle = self._control_rows(control, None)
+        period = len(cycle)
+        starts = np.asarray(starts)
+        if (starts.shape != (self.cfg.num_groups,)
+                or starts.dtype.kind not in "iu" or (starts < 0).any()):
+            raise ValueError(
+                f"starts must be rounds >= 0, integers [num_groups] = "
+                f"{(self.cfg.num_groups,)}, got {starts.dtype} "
+                f"{starts.shape}")
+        kept = self._phase
+        if (kept is None or not np.array_equal(kept["cycle"], cycle)
+                or not np.array_equal(kept["starts"], starts)):
+            batches, sizes = np.unique(starts, return_counts=True)
+            self._phase = kept = {
+                "cycle": cycle, "starts": starts.copy(),
+                "device": tuple(jnp.asarray(x, I32) for x in (
+                    *_cycle_runs(cycle), starts)),
+                "batches": batches.astype(np.int64),
+                "started": np.cumsum(sizes)}
+        t = self.phase_round + np.arange(rounds, dtype=np.int64)
+        self.phase_round += rounds
+        per_round = np.stack(
+            [t, cycle[t % period, CTL_READS] != 0,
+             cycle[t % period, CTL_STALL] != 0], axis=1).astype(np.int32)
+        batches = kept["batches"]
+        k = t[:, None] - batches[None, :]
+        inside = (k >= 0) & (k < period)
+        done = np.searchsorted(batches, t[-1], side="right")
+        stats = dict(
+            self._asked(cycle[k[inside]], int(per_round[:, PH_READS].sum())),
+            batches=int(inside.any(axis=0).sum()),
+            started=int(kept["started"][done - 1]) if done else 0)
+        return jnp.asarray(per_round), kept["device"], stats
+
+    def _scan(self, rounds: int, ticks, props, isolate, control=None,
+              starts=None):
         """One closed-loop scan enqueued; returns its scalar fence."""
         sched, isolated = self._schedule(isolate, rounds)
-        ctl, asked = self._control_schedule(control, rounds)
+        phase = None
+        if starts is None:
+            ctl, asked = self._control_schedule(control, rounds)
+        else:
+            ctl, phase, asked = self._phased_schedule(control, starts, rounds)
         # `rounds` is a static arg: each new value compiles a new scan
         # program (and so does the first call with a schedule of either
-        # kind), so warmth (and thus the transfer guard) is per value.
+        # kind, or of either form), so warmth (and thus the transfer
+        # guard) is per value.
         key = f"closed_loop/{self._serial}/{rounds}" + (
             "" if sched is None else "/isolate") + (
-            "" if ctl is None else "/control")
+            "" if ctl is None else "/control") + (
+            "" if phase is None else f"/phased{len(phase[0])}")
         with self._span("engine.run_rounds", rounds=rounds,
                         tiles=self._tiles, isolated=isolated,
                         **asked), warm_guard(key):
             watch = None if ctl is None else self._watch
             self.state, self.inbox, tel, flt, lanes, fence, watch = self._closed_loop(
                 self.state, self.inbox, ticks, props, self._tel(),
-                self._flt(), self._lanes, sched, rounds, ctl, watch
+                self._flt(), self._lanes, sched, rounds, ctl, watch,
+                *(() if phase is None else (phase,))
             )
         self._lanes = lanes
         self._set_tel(tel)
@@ -1124,7 +1310,7 @@ class MultiRaftEngine:
 
     def run_rounds(self, rounds: int, tick: bool = True,
                    propose_n: Optional[jnp.ndarray] = None,
-                   isolate=None, control=None) -> None:
+                   isolate=None, control=None, starts=None) -> None:
         """Closed-loop simulation of `rounds` rounds, faults and the
         control plane included, without leaving the device (one fused
         lax.scan program).
@@ -1137,11 +1323,25 @@ class MultiRaftEngine:
         the top of this module): the ``transfer_to``, ``read_req`` and
         ``conf_req`` of ``step_round``, a few scalars a round widened
         to the instances on the device as `isolate` is. With neither,
-        the scan takes no per-round input."""
+        the scan takes no per-round input.
+        With `starts`, int [num_groups], the control schedule is
+        *phased*: `control` is one cycle's rows, int [cycle_rounds,
+        CTL_COLS], and ``starts[g]`` the round at which group g enters
+        the cycle, counted in the rounds this engine's phased scans
+        have run (``phase_round``; this call runs rounds
+        ``phase_round`` to ``phase_round + rounds - 1`` and moves it
+        on). A group reads the cycle at ``t - starts[g]`` for what is
+        its own (transfer, change on offer, replica retired, slot
+        reset) and the steady row (none of them) before its start
+        (``NEVER``: never) and once its cycle has ended; reads and the
+        stall mark are a round's, row ``t mod cycle_rounds``. A
+        rebalancer that starts a batch of moves every few rounds is a
+        `starts` with a batch's groups at each such round. Not with
+        ``nodes=``."""
         ticks = jnp.ones_like(self._zeros_b) if tick else self._zeros_b
         propose_n = self._place(propose_n)
         props = propose_n if propose_n is not None else self._zeros_i
-        self._scan(rounds, ticks, props, isolate, control)
+        self._scan(rounds, ticks, props, isolate, control, starts)
 
     def run_rounds_pipelined(self, rounds: int, chunk: int = 16,
                              depth: int = 2, tick: bool = True,

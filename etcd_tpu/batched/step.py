@@ -1710,7 +1710,9 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, conf_applied=None):
 # the scan's body and of a call round the round itself; for an engine
 # placed one slot a device, the exchange over the interconnect and what
 # the devices agree on first: ``exchange_lanes``, ``agree_lanes``, the
-# ScanWatch's reduction over a group). The strings
+# ScanWatch's reduction over a group; for a phased control schedule,
+# each row's own round of the cycle and what it asks there:
+# ``engine.widen_phased``). The strings
 # are those of the named_scope calls,
 # letter for letter, and of the shape benchmark/reduce/trace.py files a
 # device op by (``raft_`` and lower-case letters; the innermost wins).
@@ -1732,6 +1734,7 @@ DEVICE_SCOPES = (
     ("closed-loop engine", "carry", "raft_carry"),
     ("closed-loop engine", "ici", "raft_ici"),
     ("closed-loop engine", "agree", "raft_agree"),
+    ("closed-loop engine", "phase", "raft_phase"),
 )
 
 # -----------------------------------------------------------------------------

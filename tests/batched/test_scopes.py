@@ -2,8 +2,10 @@
 
 ``step.DEVICE_SCOPES`` is the one registry of the device programs'
 ``jax.named_scope``s: the round's nine and the closed-loop engine's
-five (three of any engine, two of one placed over nodes, ISSUE 40: the
-exchange over the interconnect and what the nodes agree on first). A
+six (three of any engine, two of one placed over nodes, ISSUE 40: the
+exchange over the interconnect and what the nodes agree on first; one
+of a scan with a phased control schedule, ISSUE 42: each row's own
+round of the cycle, held to its scope in ``test_scan_phased.py``). A
 profiler trace files a device op under the innermost
 ``raft_*`` name of its ``tf_op`` (``benchmark/reduce/trace.py``) and
 under ``unscoped`` where there is none; these tests hold every equation
@@ -176,12 +178,12 @@ def expected(eng: MultiRaftEngine, loop: bool) -> set:
 
 
 def test_the_registry_is_what_the_program_names():
-    assert len(set(SCOPES)) == len(SCOPES) == 14
+    assert len(set(SCOPES)) == len(SCOPES) == 15
     assert all(SCOPE_RE.fullmatch(s) for s in SCOPES)
     assert {layer for layer, _n, _s in step_mod.DEVICE_SCOPES} == {
         "round program", "closed-loop engine"}
     assert ENGINE == ("raft_tiles", "raft_watch", "raft_carry", "raft_ici",
-                      "raft_agree")
+                      "raft_agree", "raft_phase")
     assert all(scope == "raft_" + name
                for _layer, name, scope in step_mod.DEVICE_SCOPES)
     # Every named_scope the two modules open is registered, and every
@@ -274,11 +276,13 @@ def test_placed_over_nodes_every_equation_has_a_registered_scope(
 # -- and the test has teeth ----------------------------------------------------------
 
 
-@pytest.mark.parametrize("scope", ENGINE)
+@pytest.mark.parametrize("scope", ENGINE[:5])
 def test_a_scope_left_out_leaves_its_lines_bare(scope, monkeypatch):
     """Each of the engine scopes taken away in turn (its ``with`` a
     no-op): the lines it enclosed stand under no name, or under the
-    wrong one, and the rule above fails. The two of the node-placed
+    wrong one, and the rule above fails. (``raft_phase`` is taken away
+    where a phased schedule is traced: ``test_scan_phased.py``.) The
+    two of the node-placed
     loop on that loop: without its name the scan's exchange stands
     under none, and the watch's reduction over a group is filed with
     the watch, where a trace would hide the interconnect's time."""
