@@ -29,7 +29,9 @@ the lanes some instance of the batch wrote (``step.route_lanes``, on the
 occupancy vector deliver's lane conds skip on); a lane nobody wrote
 holds what ``empty_msgs`` holds, ``valid`` false and every field zero,
 in the scan and in ``eng.inbox`` after it (``lane_rounds()`` counts the
-rounds each lane was occupied, so exchanged). A call over more rows
+rounds each lane was occupied, so exchanged; ``rare_rounds()`` those in
+which a heartbeat lane held the rare message type that makes deliver
+run its whole handler, ``step.lane_occupancy``). A call over more rows
 than one tile holds (``scan_tiles``: TILE_ROWS, from the shape alone)
 runs tile by tile: a tile is a block of whole groups, adjacent rows of
 ``eng.state`` (row ``g * R + s`` as ever: the row order does not
@@ -81,7 +83,7 @@ from .compile_cache import enable_compile_cache
 _ENGINE_SERIAL = itertools.count()
 from .state import (CANDIDATE, CONF_SWAP, LEADER, PRECANDIDATE, REPLICATE,
                     BatchedConfig, BatchedState, I32, conf_decode, init_state)
-from .step import (MsgSlots, NUM_KINDS, agree_lanes, empty_msgs,
+from .step import (MsgSlots, NUM_KINDS, NUM_OCC, agree_lanes, empty_msgs,
                    exchange_lanes, lane_occupancy, make_step_round, route,
                    route_lanes, split_lanes, stack_lanes)
 
@@ -478,13 +480,15 @@ class MultiRaftEngine:
         self._zeros_b = zeros((n_all,), bool)
         self._zeros_i = zeros((n_all,), I32)
         # Scan rounds in which each kind lane held a message for any
-        # instance (lane_rounds()): carried through the closed loop.
+        # instance (lane_rounds()) and, after the lanes, in which each
+        # rare message type did (rare_rounds()): the occupancy vector
+        # (step.lane_occupancy) added up through the closed loop.
         # Placed over nodes, beside it the tile-rounds in which each
         # lane crossed the interconnect (lane_exchanges()).
-        self._lanes = jnp.zeros((NUM_KINDS,), I32)
+        self._lanes = jnp.zeros((NUM_OCC,), I32)
         if placed:
-            self._lanes = (self._on_nodes(np.zeros((NUM_KINDS,), np.int32)),
-                           self._on_nodes(np.zeros((NUM_KINDS,), np.int32)))
+            self._lanes = (self._on_nodes(np.zeros((NUM_OCC,), np.int32)),
+                           self._on_nodes(np.zeros((NUM_OCC,), np.int32)))
         # What the scans with a control schedule counted (scan_watch()):
         # made by the first of them, carried by every one after.
         self._watch: Optional[ScanWatch] = None
@@ -571,9 +575,10 @@ class MultiRaftEngine:
             three scalars of the round."""
 
             def body(carry, row):
-                # `occ` is the inbox's lane occupancy, [K] bool: what
-                # deliver's lane conds skip on and route_lanes' are
-                # told was there.
+                # `occ` is the inbox's occupancy (step.lane_occupancy:
+                # the K lanes, then the rare types' bits), [NUM_OCC]
+                # bool: what deliver's lane conds skip on and
+                # route_lanes' are told was there.
                 # Every line here stands under a scope of
                 # step.DEVICE_SCOPES (the round's own are innermost and
                 # win): what a trace then files under no scope, the
@@ -791,14 +796,14 @@ class MultiRaftEngine:
                         0, ticks, props, *start)(carry, row),
                     like(ticks), like(props),
                     (*jax.tree.map(like, (st, inbox)),
-                     jax.ShapeDtypeStruct((NUM_KINDS,), bool),
+                     jax.ShapeDtypeStruct((NUM_OCC,), bool),
                      jax.tree.map(like, tel), (), crossed, t_watch),
                     jax.tree.map(
                         lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
                         (isolate, control)),
                     *t_start)
             with jax.named_scope("raft_tiles"):
-                seen = jnp.zeros((rounds, NUM_KINDS), bool)
+                seen = jnp.zeros((rounds, NUM_OCC), bool)
             st, inbox, tel, watch, seen, crossed = jax.lax.fori_loop(
                 0, tiles, tile, (st, inbox, tel, watch, seen, crossed))
             # Three blocks for two names, in the order the lines had
@@ -1507,6 +1512,21 @@ class MultiRaftEngine:
         (step.route_lanes; lanes run a round over 6 is the share of
         the exchange that ran). Accumulated in the scan's carry; one
         host gather, no per-round sync."""
+        return self._occupied()[:NUM_KINDS]
+
+    def rare_rounds(self) -> np.ndarray:
+        """[2] closed-loop rounds, counted like ``lane_rounds``, in
+        which the heartbeat lane held a MsgTimeoutNow and the
+        heartbeat-response lane a MsgAppResp for any instance (of the
+        outbox, before a fault schedule cuts anybody off): the rounds
+        in which deliver took the whole handler of those lanes, campaign
+        and MsgAppResp fold included, and not the plain one
+        (step._deliver_vectorized). One less their share of
+        ``lane_rounds()[KIND_HB]`` / ``[KIND_HB_RESP]`` is how often the
+        plain branch did."""
+        return self._occupied()[NUM_KINDS:]
+
+    def _occupied(self) -> np.ndarray:
         return np.asarray(
             self._lanes if self._nodes is None else self._lanes[0])
 
@@ -1520,7 +1540,7 @@ class MultiRaftEngine:
         crosses."""
         if self._nodes is None:
             return np.zeros((NUM_KINDS,), np.int32)
-        return np.asarray(self._lanes[1])
+        return np.asarray(self._lanes[1])[:NUM_KINDS]
 
     def scan_watch(self) -> dict:
         """What the scans with a control schedule counted, by

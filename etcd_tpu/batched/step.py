@@ -232,10 +232,26 @@ def stack_lanes(lanes: Tuple[MsgSlots, ...]) -> MsgSlots:
             lanes, jnp.zeros_like(lanes[KIND_APP].ent_terms)))
 
 
+# A batch's occupancy vector (``lane_occupancy``): the K kind lanes, then
+# one bit for each message type that is rare inside a lane that is not —
+# a MsgTimeoutNow in the heartbeat lane (there only while a leadership
+# is handed over), a MsgAppResp in the heartbeat-response lane (there
+# only as the nudge a stale leader's heartbeat draws). Deliver gives the
+# handling of each a branch of its own (_deliver_vectorized).
+RARE_TIMEOUT_NOW, RARE_APP_RESP = NUM_KINDS, NUM_KINDS + 1
+NUM_OCC = NUM_KINDS + 2
+
+
 def lane_occupancy(lanes: Tuple[MsgSlots, ...]) -> jnp.ndarray:
-    """[K] bool: which kind lanes hold a message for any instance."""
+    """[NUM_OCC] bool: which kind lanes hold a message for any instance
+    and, after the K lanes, whether the heartbeat lane holds a
+    MsgTimeoutNow and the heartbeat-response lane a MsgAppResp for any
+    (RARE_TIMEOUT_NOW, RARE_APP_RESP)."""
+    hb, hb_resp = lanes[KIND_HB], lanes[KIND_HB_RESP]
     return jnp.stack(
-        [jnp.any(lanes[k].valid) for k in range(NUM_KINDS)])
+        [jnp.any(lanes[k].valid) for k in range(NUM_KINDS)]
+        + [jnp.any(hb.valid & (hb.type == T_TIMEOUT_NOW)),
+           jnp.any(hb_resp.valid & (hb_resp.type == T_APP_RESP))])
 
 
 def _sel(cond, a, b):
@@ -498,9 +514,12 @@ def _lane_app(cfg: BatchedConfig, iid, slot, st: BatchedState, m: MsgSlots,
 
 
 def _lane_hb(cfg: BatchedConfig, iid, slot, st: BatchedState, m: MsgSlots,
-             from_slot):
+             from_slot, timeout_now: bool = True):
     """Lane KIND_HB: T_HB + T_TIMEOUT_NOW (ref: raft.go:1513;
-    :1465-1472 MsgTimeoutNow → immediate transfer campaign)."""
+    :1465-1472 MsgTimeoutNow → immediate transfer campaign). Without
+    `timeout_now` (static) the campaign is not built: the handler of a
+    batch that holds no valid MsgTimeoutNow, which then neither reads
+    nor writes the log ring (_deliver_vectorized)."""
     no_resp = empty_msgs((), 0)
     st1, dead, lower = _term_gate(cfg, iid, slot, st, m, from_slot)
 
@@ -519,13 +538,14 @@ def _lane_hb(cfg: BatchedConfig, iid, slot, st: BatchedState, m: MsgSlots,
     # and never a durability-fenced one (the fence exists to keep this
     # replica out of elections until its durable log is whole again).
     is_ton = m.type == T_TIMEOUT_NOW
-    r = st1.match.shape[-1]
-    promotable = _pick_b(_vote_targets(st1), jnp.arange(r, dtype=I32) == slot)
-    st_ton = _campaign(cfg, st1, iid, slot, False, transfer=True)
+    if timeout_now:
+        r = st1.match.shape[-1]
+        promotable = _pick_b(
+            _vote_targets(st1), jnp.arange(r, dtype=I32) == slot)
+        st_ton = _campaign(cfg, st1, iid, slot, False, transfer=True)
+        st_hb = _sel(is_ton & promotable & ~st1.fenced, st_ton, st_hb)
 
-    st_live = _sel(leader_traffic_ok,
-                   _sel(is_ton & promotable & ~st1.fenced, st_ton, st_hb),
-                   st1)
+    st_live = _sel(leader_traffic_ok, st_hb, st1)
     resp_live = _sel(leader_traffic_ok & ~is_ton, hb_resp, no_resp)
 
     stale = lower & jnp.asarray(cfg.check_quorum or cfg.pre_vote) & ~is_ton
@@ -1030,13 +1050,15 @@ def _vec_lane_app_resp(cfg: BatchedConfig, iid, slot, st: BatchedState,
 
 
 def _vec_lane_hb_resp(cfg: BatchedConfig, iid, slot, st: BatchedState,
-                      m: MsgSlots):
+                      m: MsgSlots, app_resps: bool = True):
     """Lane KIND_HB_RESP, vectorized: heartbeat acks are a masked OR
     into probe_sent/inflight/recent_active plus ONE ReadIndex quorum
     recompute (acks are monotone; quorum on the full set equals the
     checks after each ack); T_APP_RESP stale-leader probes that
     route back in this lane reuse the column fold; then the depose
-    tail."""
+    tail. Without `app_resps` (static) the column fold is not built:
+    the lane of a batch that holds no valid MsgAppResp here, which then
+    neither reads nor writes the log ring (_deliver_vectorized)."""
     is_leader = st.role == LEADER
     eqterm = m.valid & (m.term == st.term) & is_leader
     prog = _repl_targets(st)
@@ -1083,8 +1105,9 @@ def _vec_lane_hb_resp(cfg: BatchedConfig, iid, slot, st: BatchedState,
         read_ready=st_h.read_ready
         | (pending & confirmed & jnp.any(okh)),
     )
-    st_a = _vec_app_resp_effects(cfg, st_h, m, apr)
-    return _vec_depose(cfg, iid, slot, st_a, m)
+    if app_resps:
+        st_h = _vec_app_resp_effects(cfg, st_h, m, apr)
+    return _vec_depose(cfg, iid, slot, st_h, m)
 
 
 def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
@@ -1108,8 +1131,32 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
     of a full masked no-op. An all-invalid lane is an exact identity,
     so the skip is bit-equivalent; None falls back to per-instance
     occupancy (the cond degrades to a select under a mapped predicate
-    — correct, just unskipped)."""
+    — correct, just unskipped).
+
+    The vector's last two bits (``lane_occupancy``: RARE_TIMEOUT_NOW,
+    RARE_APP_RESP) are the lane skip one level down, for a message type
+    that is rare inside a lane that is not. The heartbeat lane's handler
+    builds a whole campaign for every row (two resets, a tally,
+    become_leader, its append and commit) and selects it where the
+    winner is a MsgTimeoutNow; the heartbeat-response lane ends in the
+    leader's whole MsgAppResp column fold, for the stale-leader nudges
+    that come back in that lane. Each lane is therefore two conds: one
+    on ``occupied & ~rare`` that builds neither, one on ``occupied &
+    rare`` with the whole handler. Exact by construction: with no valid
+    T_TIMEOUT_NOW in the batch every row's winner has ``is_ton`` false
+    or is ``dead``, so the campaign is never selected; with no valid
+    T_APP_RESP in the heartbeat-response lane ``apr`` is all false and
+    ``_vec_app_resp_effects`` returns its input. The bits may be a
+    superset (the engine takes them from the outbox, before ``isolate``
+    masks ``valid``): the whole handler is exact for any batch. Nothing
+    on the plain branches reads or writes the log ring (the term gate,
+    become_follower and reset go by ``st.last``), so the ring goes round
+    them as it goes round the vote cond. With ``lane_any=None`` the two
+    lanes keep their one cond each: under a mapped predicate a cond is a
+    select, and a lane split in two would compute both halves."""
     no_resp = empty_msgs((cfg.num_replicas,), 0)
+    ringless = lambda stx: stx._replace(  # noqa: E731
+        log_term=jnp.zeros((0,), I32))
 
     def occupied(k, m):
         if lane_any is None:
@@ -1134,35 +1181,68 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
             lambda sty, mx: _vec_lane_vote(
                 cfg, iid, slot, sty, mx, last_term),
             lambda sty, mx: (sty, no_resp),
-            stx._replace(log_term=jnp.zeros((0,), I32)), m,
+            ringless(stx), m,
         )
         return sty._replace(log_term=stx.log_term), resp
 
-    def request(k, handler, stx):
+    def request_cond(k, handler, pred, stx):
         # The cond holds the winner's handler; the [R] response slots
         # are widened after it (see _vec_lane_request on why).
-        m = inbox[k]
-        occ = occupied(k, m)
         no_answer = (empty_msgs((), 0),
                      jnp.zeros((), I32),
                      jnp.zeros((cfg.num_replicas,), bool))
-        stx, answer = jax.lax.cond(
-            occ,
+        return jax.lax.cond(
+            pred,
             lambda sty, mx: _vec_lane_request(
                 cfg, iid, slot, sty, mx, handler, hb_lane=k == KIND_HB),
             lambda sty, mx: (sty, no_answer),
-            stx, m,
+            stx, inbox[k],
         )
+
+    def request(k, handler, stx):
+        occ = occupied(k, inbox[k])
+        stx, answer = request_cond(k, handler, occ, stx)
         return stx, _vec_request_resps(cfg, stx, answer, occ)
 
-    def state_only(k, fn, stx):
-        m = inbox[k]
+    def state_cond(k, fn, pred, stx):
         return jax.lax.cond(
-            occupied(k, m),
+            pred,
             lambda sty, mx: fn(sty, mx),
             lambda sty, mx: sty,
-            stx, m,
+            stx, inbox[k],
         )
+
+    def state_only(k, fn, stx):
+        return state_cond(k, fn, occupied(k, inbox[k]), stx)
+
+    def heartbeats(stx):
+        # The lane in its two conds (the docstring's last paragraph);
+        # the two small answers joined on the unmapped bit.
+        if lane_any is None:
+            return request(KIND_HB, _lane_hb, stx)
+        occ, ton = lane_any[KIND_HB], lane_any[RARE_TIMEOUT_NOW]
+        sty, plain = request_cond(
+            KIND_HB, functools.partial(_lane_hb, timeout_now=False),
+            occ & ~ton, ringless(stx))
+        stx, full = request_cond(
+            KIND_HB, _lane_hb, occ & ton,
+            sty._replace(log_term=stx.log_term))
+        return stx, _vec_request_resps(
+            cfg, stx, _sel(ton, full, plain), occ)
+
+    def heartbeat_resps(stx):
+        whole = lambda s, m: _vec_lane_hb_resp(cfg, iid, slot, s, m)  # noqa: E731
+        if lane_any is None:
+            return state_only(KIND_HB_RESP, whole, stx)
+        occ, apr = lane_any[KIND_HB_RESP], lane_any[RARE_APP_RESP]
+        sty = state_cond(
+            KIND_HB_RESP,
+            lambda s, m: _vec_lane_hb_resp(
+                cfg, iid, slot, s, m, app_resps=False),
+            occ & ~apr, ringless(stx))
+        return state_cond(
+            KIND_HB_RESP, whole, occ & apr,
+            sty._replace(log_term=stx.log_term))
 
     # No lane writes send_heartbeat (tick and control set it, emit
     # clears it), so it goes round the six conds like the ring round
@@ -1189,16 +1269,14 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
     if cfg.replace_replicas:
         learner_next = st.conf.learner_next
         st = without(st)
-    st, r2 = request(KIND_HB, _lane_hb, st)
+    st, r2 = heartbeats(st)
     st = state_only(
         KIND_VOTE_RESP,
         lambda s, m: _vec_lane_vote_resp(cfg, iid, slot, s, m), st)
     st = state_only(
         KIND_APP_RESP,
         lambda s, m: _vec_lane_app_resp(cfg, iid, slot, s, m), st)
-    st = state_only(
-        KIND_HB_RESP,
-        lambda s, m: _vec_lane_hb_resp(cfg, iid, slot, s, m), st)
+    st = heartbeat_resps(st)
     st = st._replace(send_heartbeat=send_heartbeat)
     if cfg.conf_entries:
         st = st._replace(conf=st.conf._replace(learner_next=learner_next))

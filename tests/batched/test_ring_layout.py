@@ -119,3 +119,76 @@ def test_in_a_cond_a_write_of_selects_alone_lays_the_ring_out_ring_minor(
     closed loops for a described v5e first (the `verify` skill)."""
     n_minor, ring_minor = rings(compiled_loop(select_chain, one_chip))
     assert ring_minor > n_minor
+
+
+# -- a lane cond that holds no ring-shaped value (ISSUE 43) -------------------------
+
+
+def compiled_lane_cond(ring_through: bool, sharding):
+    """64 rounds of: append an entry outside any cond (the ring's write,
+    every round, as propose makes it) and, every third round, a lane's
+    branch that moves a few per-row words and holds nothing of ring
+    shape: the plain heartbeat branches of `step._deliver_vectorized`.
+    The ring goes round the cond (it is no operand of it) or, with
+    `ring_through`, through it, where the branch reads one entry's term
+    from it (`term_at`) and hands the ring back as it came: the
+    MsgAppResp lane's branch with the reject hints' counts taken out,
+    `_maybe_commit`'s read all that is left."""
+
+    def handler(ring, last, commit, term):
+        zero = jnp.zeros((), I32)
+        if ring is None:
+            ok = commit < last
+        else:
+            ok = term_at(ring, zero, zero, last, commit + 1) == term
+        return jnp.where(ok, commit + 1, commit)
+
+    def body(i, carry):
+        ring, last, commit, term = carry
+        new, last = jax.vmap(
+            lambda r, l, t: (ring_write(r, l + 1, jnp.full((P,), 1, I32) * t,
+                                        jnp.asarray(1, I32)), l + 1),
+            in_axes=-1, out_axes=-1)(jnp.moveaxis(ring, 0, -1), last, term)
+        ring = jnp.moveaxis(new, -1, 0)
+        lane = i % 3 == 0
+        if ring_through:
+            def taken(ring, last, commit):
+                return ring, jax.vmap(handler, in_axes=-1, out_axes=-1)(
+                    jnp.moveaxis(ring, 0, -1), last, commit, term)
+
+            ring, commit = jax.lax.cond(
+                lane, taken, lambda ring, last, commit: (ring, commit),
+                ring, last, commit)
+        else:
+            commit = jax.lax.cond(
+                lane,
+                lambda last, commit: jax.vmap(
+                    lambda l, c, t: handler(None, l, c, t))(
+                        last, commit, term),
+                lambda last, commit: commit, last, commit)
+        return ring, last, commit, term
+
+    def loop(ring, last, term):
+        return jax.lax.fori_loop(
+            0, 64, body, (ring, last, jnp.zeros_like(last), term))
+
+    vec = jax.ShapeDtypeStruct((N,), I32, sharding=sharding)
+    ring = jax.ShapeDtypeStruct((N, W), I32, sharding=sharding)
+    return jax.jit(loop).lower(ring, vec, vec).compile().as_text()
+
+
+def test_a_lane_cond_with_the_ring_led_round_it_keeps_the_ring_n_minor(
+        one_chip, no_persistent_cache):
+    n_minor, ring_minor = rings(compiled_lane_cond(False, one_chip))
+    assert n_minor > 0 and ring_minor == 0
+
+
+def test_a_lane_cond_that_only_reads_the_ring_lays_it_out_ring_minor(
+        one_chip, no_persistent_cache):
+    """The control, and what refused the same split of the MsgAppResp
+    lane's `reject` (PERF.md section 6, "PR 43"): a branch whose only
+    ring-shaped work is one `term_at` takes the ring ring-minor, and it
+    is copied at the cond's edges taken or not. If this fails the
+    compiler has stopped doing it, and that split may be asked for."""
+    n_minor, ring_minor = rings(compiled_lane_cond(True, one_chip))
+    assert ring_minor > 0

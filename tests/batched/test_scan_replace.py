@@ -585,22 +585,27 @@ def test_the_oracle_raises_where_a_reused_slot_votes_twice_in_a_term():
 # append site of the round calls; re-pinned by PR 41 on its own text,
 # because it rewrote `kernels.quorum_committed` (the q-th largest of the
 # R acked indexes by compares and selects, for a sort and a one-hot
-# pick), which every `_maybe_commit` of the round calls: each time the
+# pick), which every `_maybe_commit` of the round calls; re-pinned by PR
+# 43 on its own text, because it split the HB and HB_RESP lane conds of
+# `step._deliver_vectorized` in two wherever the occupancy is a batch's
+# (two more `lax.cond`s a round, two more bits in `lane_occupancy`; a
+# round built with `lane_skip=False` kept the parent's text:
+# `test_rare_lanes.py` pins it): each time the
 # text of every configuration moved on purpose, and the chip compiles
 # each scan anew once.
 PARENT_TEXT = {
     "engine64k-r3": (
-        "6d8ca8016fa9b5eda1ce3dbc3708692ff213598d2594f51df23cbab12af585ae",
-        "150c7061ba15bf21620d59ac2f0c6639f05f12ffcce7fb8fb6d0e73f6f352ebb"),
+        "c49d987b3a4a50db6bd93566c068f524edc751a3741bda03c112dd042ed30ac5",
+        "4059adc13820d564f75a304e5ccde8097bb7c2dc2905fee5984cead8cd80a79f"),
     "engine10k-r5": (
-        "8b7c9902d465931d61732d588f6fbea8bf3cefa20aea796c48327bb780d199c9",
-        "0a3b208dc6a2bcf15c1a8bb7bbb3a3ba5e4b722c1f28bb560998592d0828970d"),
+        "89bd141ebfaa0fb02eba264111a71ea459090690a0b66e34c51f3dabe9c2617c",
+        "fe1af142b1ee763fd9a16dfa914484ca0725451baa807b400db38bd50935cf87"),
     "engine100k-r3": (
-        "994e97925c0027eabfbd313ea236acb1f2326daa7e2feda544eac39ff08922ce",
-        "67b5148d70cb429b15499a5f1d3e47d7691aff19e44762f42cbb0d6b0b6548d3"),
+        "dc988e61d199bdd99154b087064ef62a4dc7cd32d47bc121e05489139b59bf6b",
+        "73b45a928312c1c67512c22ac9fa86a5fa589d0a8cb600ed69a21d069ef6638c"),
     "engine1m-r3": (
-        "f780c02ed191549ce2e3626314393387da28b122067c4f17f3e09ef9b05e7e7a",
-        "1819b804b111d82a47a5320b44541cf05d9661886cde85c3c9247e75ed9d8fb3"),
+        "52106f1e90ffee6657734e2791db552f5226b05f8eb527e58cb8e64b8958ab27",
+        "edd27f8746599ee76476eaf20f45433bbd18d351efeff1bdbe5358b004d41e1b"),
 }
 
 
@@ -642,7 +647,7 @@ def test_with_the_new_fields_off_the_round_is_the_parents_text(name):
         print(name, got)
     assert got == PARENT_TEXT[name], (
         "the lowered round or closed loop of a live configuration is not "
-        "the text it was at the commit that pinned it (PR 41)")
+        "the text it was at the commit that pinned it (PR 43)")
     assert control_cols(cfg) == 5 and watch_names(cfg) == WATCH_NAMES
 
 

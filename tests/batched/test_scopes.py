@@ -335,7 +335,9 @@ def test_a_line_moved_out_of_its_scope_fails_the_rule(name, monkeypatch):
 # calls, and by PR 41 on its own, because it rewrote
 # `kernels.quorum_committed` (an elementwise order statistic for a sort
 # and a one-hot pick), which every `_maybe_commit` of the round calls
-# (the untiled pins in `test_scan_replace.py` moved with both): names
+# (the untiled pins in `test_scan_replace.py` moved with both), and by PR
+# 43 on its own, because it split deliver's HB and HB_RESP lane conds in
+# two (the untiled pins moved with it): names
 # are still not part of either text. The lowered
 # text holds no name of a scope, and JAX's persistent cache keys a
 # program with its names stripped: equal text here is a cache hit on the
@@ -346,11 +348,11 @@ def test_a_line_moved_out_of_its_scope_fails_the_rule(name, monkeypatch):
 # prints them); `test_scan_replace.py` pins the untiled texts.
 PARENT_TILED_TEXT = {
     "engine1m-r3": (
-        "fa77d3054781cf1fcbe72da9ca86338c6e77fe1a4b07ea8237e2a328636dbf6d",
-        "88746b8fa3720d29f6b89fff851b9701aa6cb099477c28fd35e484efd6f68a06"),
+        "599706195d35d8ed95591fc77fa35ba039dd5372682c5a2a08e9dd505170e16a",
+        "a505641232b5910c43d73398f7119b740240135600ef385c78094a906f3679a9"),
     "engine512k-r3of4": (
-        "a335faade54c8333c8c5ea0fad7758cde068f832658d873e5bbae7a9be96149c",
-        "45c64101c8f011b5ec917c64a08c67110039976979111cd4545bf4bf7135b07b"),
+        "23db4b797fec6cb855039d67ed777d697d85ba41918d281aadd88b30024d695c",
+        "72de6e3b4623f6c5bbe00170b2c90b3633aee163176206b8cb5f6ab03f6c0b28"),
 }
 
 
@@ -371,7 +373,7 @@ def test_in_tiles_the_lowered_text_is_the_parents(name, monkeypatch):
         print(name, got)
     assert got == PARENT_TILED_TEXT[name], (
         "the lowered closed loop or eager round of a tiled configuration "
-        "is not the text it was at the commit that pinned it (PR 41)")
+        "is not the text it was at the commit that pinned it (PR 43)")
 
 
 # -- no sort in any live program (ISSUE 41) ----------------------------------------
