@@ -31,7 +31,9 @@ holds what ``empty_msgs`` holds, ``valid`` false and every field zero,
 in the scan and in ``eng.inbox`` after it (``lane_rounds()`` counts the
 rounds each lane was occupied, so exchanged; ``rare_rounds()`` those in
 which a heartbeat lane held the rare message type that makes deliver
-run its whole handler, ``step.lane_occupancy``). A call over more rows
+run its whole handler, ``step.lane_occupancy``; ``emit_ring_rounds()``
+the tile-rounds in which emit read the log ring for the terms its
+messages state, ``step._emit``). A call over more rows
 than one tile holds (``scan_tiles``: TILE_ROWS, from the shape alone)
 runs tile by tile: a tile is a block of whole groups, adjacent rows of
 ``eng.state`` (row ``g * R + s`` as ever: the row order does not
@@ -399,9 +401,10 @@ class MultiRaftEngine:
             def one(lo, st, lanes, per_row):
                 masks, conf_req, wipe = per_row
                 with jax.named_scope("raft_carry"):
+                    # (Emit's bit, last, is the scan's to count.)
                     return tile_step(lo, slots)(
                         st, lanes, *masks, lane_any=lane_occupancy(lanes),
-                        conf_req=conf_req, wipe=wipe)
+                        conf_req=conf_req, wipe=wipe)[:-1]
 
             # The shapes of what a tile answers, for the outbox and the
             # frames the loop writes into; and the round traced once
@@ -454,7 +457,7 @@ class MultiRaftEngine:
                 out = self._step(st, lanes, *masks,
                                  lane_any=lane_occupancy(lanes),
                                  conf_req=conf_req, wipe=wipe)
-                return (out[0], stack_lanes(out[1])) + out[2:]
+                return (out[0], stack_lanes(out[1])) + out[2:-1]
 
         # In tiles the loop's carry is the state: donated, it is updated
         # in place as the scan's is (`step_round` below reassigns state
@@ -485,10 +488,15 @@ class MultiRaftEngine:
         # (step.lane_occupancy) added up through the closed loop.
         # Placed over nodes, beside it the tile-rounds in which each
         # lane crossed the interconnect (lane_exchanges()).
-        self._lanes = jnp.zeros((NUM_OCC,), I32)
+        # And, second of the pair, the tile-rounds in which emit read
+        # the ring for the terms it states (emit_ring_rounds(); step._emit):
+        # [1], or a count a node, [R].
+        self._lanes = (jnp.zeros((NUM_OCC,), I32), jnp.zeros((1,), I32))
         if placed:
-            self._lanes = (self._on_nodes(np.zeros((NUM_OCC,), np.int32)),
-                           self._on_nodes(np.zeros((NUM_OCC,), np.int32)))
+            self._lanes = (
+                (self._on_nodes(np.zeros((NUM_OCC,), np.int32)),
+                 self._on_nodes(np.zeros((NUM_OCC,), np.int32))),
+                zeros((r,), I32))
         # What the scans with a control schedule counted (scan_watch()):
         # made by the first of them, carried by every one after.
         self._watch: Optional[ScanWatch] = None
@@ -583,7 +591,7 @@ class MultiRaftEngine:
                 # step.DEVICE_SCOPES (the round's own are innermost and
                 # win): what a trace then files under no scope, the
                 # compiler made (tests/batched/test_scopes.py).
-                st, inbox, occ, tel, flt, lanes, watch = carry
+                st, inbox, occ, tel, flt, (lanes, ring), watch = carry
                 cut, ctl = row
                 with jax.named_scope("raft_carry"):
                     if not tiled:
@@ -629,6 +637,7 @@ class MultiRaftEngine:
                         wipe=wipe,
                     )
                     st, outbox = out[:2]
+                    ring = ring + out[-1]  # emit's bit, the round's last
                 # jitlint: waive(tracer-branch) -- as above
                 if ctl is not None:
                     with jax.named_scope("raft_watch"):
@@ -670,7 +679,7 @@ class MultiRaftEngine:
                 # A tile cannot count the rounds a lane was occupied
                 # for ANY instance: it hands each round's own vector
                 # out, for the call to put together over its tiles.
-                return (st, inbox, sent, tel, flt, lanes, watch), (
+                return (st, inbox, sent, tel, flt, (lanes, ring), watch), (
                     occ if tiled else None)
 
             return body
@@ -707,9 +716,10 @@ class MultiRaftEngine:
             Placed over nodes this is what one node runs on its G rows
             (a row a group, so any block of rows is whole groups), the
             nodes walking the same tiles together: a tile's round ends
-            in their exchange. `lanes` is then (rounds occupied, tile-
-            rounds crossed), the occupancy every node counts is the
-            agreed one, and the fence is the node's own, [1]. Of a
+            in their exchange. `lanes` is then ((rounds occupied, tile-
+            rounds crossed), the node's own count of emit's), the
+            occupancy every node counts is the agreed one, and the fence
+            is the node's own, [1]. Of a
             phased schedule (`phase`: `widen_phased`) a tile takes its
             rows' starts, as it takes every per-row array."""
             with jax.named_scope("raft_carry"):
@@ -726,24 +736,25 @@ class MultiRaftEngine:
                     phase=None if phase is None else phase[:2] + (start,))
 
             def tile_rounds(lo, st, inbox, tel, watch, ticks, props,
-                            crossed, start=()):
+                            counts, start=()):
                 """The call's rounds on the rows from `lo` on, handed
                 in as the tile's slices (`watch` with the whole
-                counts, `crossed` the whole count of lanes exchanged
-                between nodes, () on one device); and each round's lane
-                occupancy."""
+                counts, `counts` the whole counts the scan's carry adds
+                up: of lanes exchanged between nodes, () on one device,
+                and of tile-rounds in which emit read the ring); and
+                each round's lane occupancy."""
                 with jax.named_scope("raft_carry"):
                     inbox, occ = enter(inbox)
                 if placed:
                     occ = agree_lanes(occ, NODE_AXIS)
-                (st, inbox, _, tel, _, crossed, watch), occs = jax.lax.scan(
+                (st, inbox, _, tel, _, counts, watch), occs = jax.lax.scan(
                     tile_body(lo, ticks, props, start),
-                    (st, inbox, occ, tel, (), crossed, watch),
+                    (st, inbox, occ, tel, (), counts, watch),
                     (isolate, control), length=rounds)
-                return st, inbox, tel, watch, occs, crossed
+                return st, inbox, tel, watch, occs, counts
 
             def tile(i, carry):
-                st, inbox, tel, watch, seen, crossed = carry
+                st, inbox, tel, watch, seen, counts = carry
                 with jax.named_scope("raft_tiles"):
                     lo = i * rows
                     cut = lambda x: jax.lax.dynamic_slice_in_dim(x, lo, rows)  # noqa: E731
@@ -759,8 +770,8 @@ class MultiRaftEngine:
                     if phase is not None:  # its structure: None or arrays
                         with jax.named_scope("raft_phase"):
                             start = (cut(phase[2]),)
-                t_st, t_inbox, t_tel, t_watch, occs, crossed = tile_rounds(
-                    lo, *mine, crossed, *start)
+                t_st, t_inbox, t_tel, t_watch, occs, counts = tile_rounds(
+                    lo, *mine, counts, *start)
                 with jax.named_scope("raft_tiles"):
                     # In place: the carry is the donated state, and no
                     # second copy of it exists.
@@ -772,11 +783,13 @@ class MultiRaftEngine:
                         t_watch.counts,
                         paste(watch.read_floor, t_watch.read_floor),
                         paste(watch.history, t_watch.history))
-                    return st, inbox, tel, watch, seen | occs, crossed
+                    return st, inbox, tel, watch, seen | occs, counts
 
+            lanes, ring = lanes
             crossed = ()
             if placed:
                 lanes, crossed = lanes
+            counts = (crossed, ring)
             with jax.named_scope("raft_carry"):
                 inbox = split_lanes(inbox)
             # Tracing only. As the body of the loops the round takes
@@ -797,15 +810,15 @@ class MultiRaftEngine:
                     like(ticks), like(props),
                     (*jax.tree.map(like, (st, inbox)),
                      jax.ShapeDtypeStruct((NUM_OCC,), bool),
-                     jax.tree.map(like, tel), (), crossed, t_watch),
+                     jax.tree.map(like, tel), (), counts, t_watch),
                     jax.tree.map(
                         lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
                         (isolate, control)),
                     *t_start)
             with jax.named_scope("raft_tiles"):
                 seen = jnp.zeros((rounds, NUM_OCC), bool)
-            st, inbox, tel, watch, seen, crossed = jax.lax.fori_loop(
-                0, tiles, tile, (st, inbox, tel, watch, seen, crossed))
+            st, inbox, tel, watch, seen, (crossed, ring) = jax.lax.fori_loop(
+                0, tiles, tile, (st, inbox, tel, watch, seen, counts))
             # Three blocks for two names, in the order the lines had
             # before they had names: the lowered text follows the order
             # of the lines, and JAX's cache key the text (the names are
@@ -817,34 +830,35 @@ class MultiRaftEngine:
                 lanes = lanes + jnp.sum(seen, axis=0, dtype=I32)
             with jax.named_scope("raft_carry"):
                 if placed:
-                    return (st, inbox, tel, (), (lanes, crossed),
+                    return (st, inbox, tel, (), ((lanes, crossed), ring),
                             st.commit[:1], watch)
-                return st, inbox, tel, (), lanes, st.commit[0], watch
+                return st, inbox, tel, (), (lanes, ring), st.commit[0], watch
 
         def placed_loop(st, inbox, ticks, props, tel, lanes, isolate,
                         rounds, control, watch):
             """`tiled_loop` as every node runs it on its own rows; a
             node keeps its own ScanWatch counts (``scan_watch`` adds
             them up)."""
-            def one_node(st, inbox, ticks, props, tel, watch, lanes,
+            def one_node(st, inbox, ticks, props, tel, watch, ring, lanes,
                          isolate, control):
                 if watch is not None:  # None is an empty pytree
                     with jax.named_scope("raft_carry"):
                         watch = watch._replace(counts=watch.counts[0])
-                st, inbox, tel, _, lanes, fence, watch = tiled_loop(
-                    st, inbox, ticks, props, tel, lanes, isolate, rounds,
-                    control, watch)
+                st, inbox, tel, _, (lanes, ring), fence, watch = tiled_loop(
+                    st, inbox, ticks, props, tel, (lanes, ring), isolate,
+                    rounds, control, watch)
                 if watch is not None:
                     with jax.named_scope("raft_carry"):
                         watch = watch._replace(counts=watch.counts[None])
-                return (st, inbox, tel, fence, watch), lanes
+                return (st, inbox, tel, fence, watch, ring), lanes
 
             by_node = P(NODE_AXIS)
-            (st, inbox, tel, fence, watch), lanes = over_nodes(
-                one_node, (by_node,) * 6 + (P(),) * 3, (by_node, P()))(
-                    st, inbox, ticks, props, tel, watch, lanes, isolate,
-                    control)
-            return st, inbox, tel, (), lanes, fence, watch
+            lanes, ring = lanes  # agreed between the nodes; a node's own
+            (st, inbox, tel, fence, watch, ring), lanes = over_nodes(
+                one_node, (by_node,) * 7 + (P(),) * 3, (by_node, P()))(
+                    st, inbox, ticks, props, tel, watch, ring, lanes,
+                    isolate, control)
+            return st, inbox, tel, (), (lanes, ring), fence, watch
 
         def closed_loop(st, inbox, ticks, props, tel, flt, lanes, isolate,
                         rounds, control=None, watch=None, phase=None):
@@ -1526,9 +1540,20 @@ class MultiRaftEngine:
         plain branch did."""
         return self._occupied()[NUM_KINDS:]
 
+    def emit_ring_rounds(self) -> int:
+        """Tile-rounds of the closed loop (a node's, over nodes) in
+        which emit read the log ring for the terms its messages state,
+        because some row of the tile asked below its own-term boundary
+        (a vote request, a new leader's first append, a snapshot taken
+        below it: step._emit); in every other it stated the sender's
+        own term and read the ring for the floor's term alone. Of
+        ``rounds * tiles`` (``* R`` over nodes) a call; counted in the
+        scan's carry like ``lane_rounds``."""
+        return int(np.asarray(self._lanes[1]).sum())
+
     def _occupied(self) -> np.ndarray:
-        return np.asarray(
-            self._lanes if self._nodes is None else self._lanes[0])
+        lanes = self._lanes[0]
+        return np.asarray(lanes if self._nodes is None else lanes[0])
 
     def lane_exchanges(self) -> np.ndarray:
         """[NUM_KINDS] for an engine placed over nodes: how often the
@@ -1540,7 +1565,7 @@ class MultiRaftEngine:
         crosses."""
         if self._nodes is None:
             return np.zeros((NUM_KINDS,), np.int32)
-        return np.asarray(self._lanes[1])[:NUM_KINDS]
+        return np.asarray(self._lanes[0][1])[:NUM_KINDS]
 
     def scan_watch(self) -> dict:
         """What the scans with a control schedule counted, by

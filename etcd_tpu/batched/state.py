@@ -414,6 +414,17 @@ class BatchedState(NamedTuple):
     # every protocol branch.
     lease_ticks: jnp.ndarray  # [N] i32
 
+    # Where a leader's own term begins in its log: the index of the
+    # empty entry it appended as it won (step._become_leader), 0 on
+    # every row that is not a leader (step._reset clears it; a fresh, a
+    # wiped, a restarted or a restored replica is a follower, so it is
+    # never persisted). A leader's log is append-only from that entry,
+    # so for a leader term_at(i) == term exactly when own_from <= i <=
+    # last: what _maybe_commit, _control's committed-in-term and emit
+    # ask of an entry they answer from this field and not from the
+    # ring.
+    own_from: jnp.ndarray  # [N] i32
+
 
 # The state of a configuration with cfg.conf_entries: every field of
 # BatchedState and, last, `conf`, its ConfLanes. A type of its own, so
@@ -553,6 +564,7 @@ def init_state(cfg: BatchedConfig, start_index: int = 0,
         vote_req_transfer=jnp.zeros((n,), bool),
         send_timeout_now=jnp.zeros((n,), bool),
         lease_ticks=zeros_n(),
+        own_from=zeros_n(),
     )
     if cfg.conf_entries:
         st = ConfBatchedState(*st, conf=ConfLanes(

@@ -306,6 +306,9 @@ def _reset(cfg: BatchedConfig, st: BatchedState, iid, slot, term) -> BatchedStat
         read_acks=jnp.zeros((r,), bool),
         read_ready=jnp.zeros_like(st.read_ready),
         read_req_latch=jnp.zeros_like(st.read_req_latch),
+        # Every way out of leadership, and into it, comes through here:
+        # _become_leader sets it again after this.
+        own_from=jnp.zeros_like(st.own_from),
     )
 
 
@@ -333,9 +336,12 @@ def _maybe_commit(st: BatchedState) -> BatchedState:
     """Quorum commit-index advancement — THE replica-axis reduction
     (ref: raft.go:585-588 + quorum/majority.go:126, joint.go:49-56)."""
     mci = joint_committed(st.match, st.voter, st.voter_out, st.in_joint)
-    ok = (mci > st.commit) & (
-        term_at(st.log_term, st.snap_index, st.snap_term, st.last, mci) == st.term
-    )
+    # Only an entry of the leader's own term commits by counting
+    # (raft.go maybeCommit's term check): for a leader that is every
+    # entry from own_from on (BatchedState.own_from), so the ring is not
+    # read. On a row that is no leader own_from is 0 and nothing
+    # commits; every caller keeps the result for a leader alone.
+    ok = (mci > st.commit) & (st.own_from > 0) & (mci >= st.own_from)
     return st._replace(commit=jnp.where(ok, mci, st.commit))
 
 
@@ -360,6 +366,8 @@ def _become_leader(cfg, st, iid, slot) -> BatchedState:
         role=jnp.full_like(st.role, LEADER),
         lead=slot + 1,
         pr_state=jnp.where(peers == slot, REPLICATE, st.pr_state),
+        # The empty entry appended below: where this term begins.
+        own_from=st.last + 1,
     )
     if cfg.conf_entries:
         # The tail may hold a change nobody has applied: no new one
@@ -1572,10 +1580,7 @@ def _control(cfg: BatchedConfig, slot, st: BatchedState, transfer_to,
     # batch in flight must not be clobbered (its in-flight acks would
     # be orphaned). Unserviceable requests latch and open the next
     # batch when the blocker clears — read_only.go's pending queue.
-    committed_in_term = (
-        term_at(st.log_term, st.snap_index, st.snap_term, st.last, st.commit)
-        == st.term
-    )
+    committed_in_term = (st.own_from > 0) & (st.commit >= st.own_from)
     batch_pending = (st.read_index >= 0) & ~st.read_ready
     want = read_req | st.read_req_latch
     accept = is_leader & want & committed_in_term & ~batch_pending
@@ -1627,28 +1632,15 @@ def _propose(cfg: BatchedConfig, slot, st: BatchedState, n_new):
     return _sel(n > 0, st2, st)
 
 
-def _emit(cfg: BatchedConfig, slot, st: BatchedState, conf_applied=None):
-    """Materialize pending sends into the three request lanes of the
-    outbox (KIND_VOTE, KIND_APP, KIND_HB, each [R] slots addressed by
-    target) and clear flags; auto-apply committed entries (device
-    applies immediately; the host drains (group, index) ranges for real
-    payload apply). The lanes leave as they are computed: nothing here
-    builds [R, K], and only KIND_APP's ``ent_terms`` has columns.
-    `conf_applied` (cfg.conf_entries) is _control's word that this
-    round's apply point was taken."""
-    e = cfg.max_ents_per_msg
-    r = cfg.num_replicas
-    peers = jnp.arange(r, dtype=I32)
-    # A field of a lane is int32 [R], one slot a target: what a sender
-    # says to all alike is widened here, and a Python-int choice made
-    # int32 (the branches of route_lanes' switches must agree on it).
-    per_target = lambda x: jnp.broadcast_to(jnp.asarray(x, I32), (r,))  # noqa: E731
-
-    # Device-side apply + compaction first: committed == applied on
-    # device (payload apply is the host's job, driven from the commit
-    # watermark), and with auto_compact the snapshot floor chases the
-    # applied watermark so the ring never fills. Stale ring slots below
-    # the floor need no clearing — term_at bounds exclude them.
+def _apply_and_compact(cfg: BatchedConfig, st: BatchedState,
+                       conf_applied=None) -> BatchedState:
+    """Device-side apply + compaction, before anything is sent:
+    committed == applied on device (payload apply is the host's job,
+    driven from the commit watermark), and with auto_compact the
+    snapshot floor chases the applied watermark so the ring never
+    fills. Stale ring slots below the floor need no clearing — term_at
+    bounds exclude them. `conf_applied` (cfg.conf_entries) is
+    _control's word that this round's apply point was taken."""
     upto = st.commit
     if cfg.conf_entries:
         # `applied` stops short of a configuration change this replica
@@ -1658,20 +1650,110 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, conf_applied=None):
         upto = jnp.where(waits, jnp.minimum(upto, st.conf.index - 1), upto)
     st = st._replace(applied=jnp.maximum(st.applied, upto))
     if cfg.auto_compact:
-        ta0 = lambda i: term_at(
-            st.log_term, st.snap_index, st.snap_term, st.last, i
-        )
         keep = cfg.window // 2
         floor = jnp.minimum(st.applied, st.last - keep)
         new_snap = jnp.maximum(st.snap_index, floor)
-        st = st._replace(snap_term=ta0(new_snap), snap_index=new_snap)
+        # The floor's term: a follower's as often as a leader's, so the
+        # one read of the ring every round makes for emit.
+        st = st._replace(
+            snap_term=term_at(st.log_term, st.snap_index, st.snap_term,
+                              st.last, new_snap),
+            snap_index=new_snap)
+    return st
 
-    ta = lambda i: term_at(st.log_term, st.snap_index, st.snap_term, st.last, i)
+
+def _appends_due(cfg: BatchedConfig, slot, st: BatchedState):
+    """(app, snp, prev), each [R]: the peers an append is due to and
+    those a snapshot is (both hold ``is_leader``), and the index before
+    the first entry a peer lacks (ref: raft.go:432-492
+    maybeSendAppend)."""
+    not_self = jnp.arange(cfg.num_replicas, dtype=I32) != slot
+    want = (st.send_append & _repl_targets(st) & not_self
+            & (st.role == LEADER) & ~_paused(cfg, st))
+    prev = st.next - 1
+    snap_needed = prev < st.snap_index
+    return want & ~snap_needed, want & snap_needed, prev
+
+
+def _asks_below(cfg: BatchedConfig, slot, st: BatchedState):
+    """Whether this row's emit states a term that its own-term boundary
+    does not answer: a candidate's vote request (the term of its last
+    entry), an append whose previous entry lies below ``own_from`` (a
+    new leader's first to a peer, a probe walking back), a snapshot
+    with cfg.replace_replicas taken at an applied index below it. Of
+    the state as emit finds it (after _apply_and_compact)."""
+    app, snp, prev = _appends_due(cfg, slot, st)
+    below = st.send_vote_req | jnp.any(app & (prev < st.own_from))
+    if cfg.replace_replicas:
+        below = below | (jnp.any(snp) & (st.applied < st.own_from))
+    return below
+
+
+def _emit(cfg: BatchedConfig, slot, st: BatchedState, ring_read=None):
+    """Materialize pending sends into the three request lanes of the
+    outbox (KIND_VOTE, KIND_APP, KIND_HB, each [R] slots addressed by
+    target) and clear flags, of the state _apply_and_compact leaves. The
+    lanes leave as they are computed: nothing here builds [R, K], and
+    only KIND_APP's ``ent_terms`` has columns.
+
+    The terms a message states (of the entries an append carries and of
+    the one before them, of a vote request's last entry, of the applied
+    index a snapshot with cfg.replace_replicas stands at) are a
+    leader's questions about its own log, all but the candidate's: an
+    entry at or above ``own_from`` is of the sender's term
+    (BatchedState.own_from). `ring_read` is ONE unmapped bit, whether
+    any row of the batch asks below its boundary (_asks_below, reduced
+    by the round outside the instance vmap wherever it holds a batch's
+    lane occupancy): the ring is then read for these terms in a
+    lax.cond on it, the own term standing in on the other branch, and
+    the floor's term (_apply_and_compact) is the one read of the ring
+    both make. Exact by construction: with the bit false no row sends a
+    vote request and every append or snapshot a leader sends reaches no
+    lower than its boundary, so the terms the ring would give are the
+    sender's own in every slot that is ``valid`` (the rest may differ:
+    the vote lane's ``log_term`` of a row that asks for no vote, every
+    lane of a row the round cuts off, which the round therefore leaves
+    out of the bit). The bit may be a superset: the ring is exact for
+    any batch. None (under
+    a mapped predicate a cond is a select and would compute both) reads
+    the ring as ever."""
+    e = cfg.max_ents_per_msg
+    r = cfg.num_replicas
+    peers = jnp.arange(r, dtype=I32)
+    # A field of a lane is int32 [R], one slot a target: what a sender
+    # says to all alike is widened here, and a Python-int choice made
+    # int32 (the branches of route_lanes' switches must agree on it).
+    per_target = lambda x: jnp.broadcast_to(jnp.asarray(x, I32), (r,))  # noqa: E731
 
     not_self = peers != slot
     vote_peer = _vote_targets(st) & not_self
     repl_peer = _repl_targets(st) & not_self
     is_leader = st.role == LEADER
+
+    # What is asked of the log: the sends are decided here, above the
+    # terms they state.
+    app, snp, prev = _appends_due(cfg, slot, st)
+    n_send = jnp.clip(st.last - prev, 0, e)  # [R]
+    j = jnp.arange(e, dtype=I32)
+    ent_idx = prev[:, None] + 1 + j[None, :]  # [R, E]
+
+    def ring_terms(log_term):
+        ta = lambda i: term_at(  # noqa: E731
+            log_term, st.snap_index, st.snap_term, st.last, i)
+        applied = (ta(st.applied),) if cfg.replace_replicas else ()
+        return (ta(st.last), ta(ent_idx), ta(prev)) + applied
+
+    def own_terms(log_term):
+        own = lambda like: jnp.broadcast_to(st.term, like.shape)  # noqa: E731
+        applied = (st.term,) if cfg.replace_replicas else ()
+        return (st.term, own(ent_idx), own(prev)) + applied
+
+    # jitlint: waive(tracer-branch) -- None is the argument left out, tested at trace time, never a device value
+    if ring_read is None:
+        terms = ring_terms(st.log_term)
+    else:
+        terms = jax.lax.cond(ring_read, ring_terms, own_terms, st.log_term)
+    last_term, ent_terms, prev_term = terms[:3]
 
     # --- vote requests (ref: raft.go:822-834) ---
     vote = empty_msgs((r,), 0)._replace(
@@ -1680,7 +1762,7 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, conf_applied=None):
         term=per_target(
             jnp.where(st.vote_req_is_pre, st.term + 1, st.term)),
         index=per_target(st.last),
-        log_term=per_target(ta(st.last)),
+        log_term=per_target(last_term),
         ctx=per_target(jnp.where(st.vote_req_transfer, 1, 0)),
     )
 
@@ -1709,16 +1791,7 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, conf_applied=None):
     st = st._replace(transfer_sent=st.transfer_sent | jnp.any(ton))
 
     # --- appends / snapshots (ref: raft.go:432-492 maybeSendAppend) ---
-    want = st.send_append & repl_peer & is_leader & ~_paused(cfg, st)
-    prev = st.next - 1
-    snap_needed = prev < st.snap_index
-    n_send = jnp.clip(st.last - prev, 0, e)  # [R]
-    j = jnp.arange(e, dtype=I32)
-    ent_idx = prev[:, None] + 1 + j[None, :]  # [R, E]
-    ent_terms = ta(ent_idx)
     ent_mask = j[None, :] < n_send[:, None]
-    app = want & ~snap_needed
-    snp = want & snap_needed
     # The snapshot sent is the floor's. One that states the
     # configuration (cfg.replace_replicas) is taken where this replica
     # knows it, at its applied index, which is where etcd takes its own
@@ -1726,14 +1799,14 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, conf_applied=None):
     # behind it): the masks are as of `applied`, not as of the floor.
     snap_at, snap_t = st.snap_index, st.snap_term
     if cfg.replace_replicas:
-        snap_at, snap_t = st.applied, ta(st.applied)
+        snap_at, snap_t = st.applied, terms[3]
 
     append = empty_msgs((r,), e)._replace(
         valid=app | snp,
         type=per_target(jnp.where(snp, T_SNAP, T_APP)),
         term=per_target(st.term),
         index=jnp.where(snp, snap_at, prev),
-        log_term=jnp.where(snp, snap_t, ta(prev)),
+        log_term=jnp.where(snp, snap_t, prev_term),
         commit=per_target(st.commit),
         n_ents=jnp.where(app, n_send, 0),
         ent_terms=jnp.where(ent_mask & app[:, None], ent_terms, 0),
@@ -2250,12 +2323,21 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
         # overrules it.
         if not lane_skip:
             lane_any = None
+        # A caller that counts what its rounds skipped (it handed the
+        # occupancy in) is told, last, whether emit read the ring.
+        counted = lane_any is not None
         # jitlint: waive(tracer-branch) -- None is an empty pytree: the branch is on the argument's structure at trace time
-        elif lane_any is None:
+        if lane_skip and lane_any is None:
             lane_any = lane_occupancy(inbox)  # [K]
 
-        def per_instance(iid, slot, sti, inbox_i, do_tick, do_camp, n_new,
-                         iso, tr_to, rd_req, cf_req, wp, lane_any):
+        # The round a row, in two halves: up to emit's sends, and from
+        # them on. Between the two stands the one thing a row asks of
+        # the batch in mid-round: whether anybody's emit reaches below
+        # its own-term boundary (_asks_below), reduced OUTSIDE the vmap
+        # to an unmapped bit like `lane_any`, and wherever that is:
+        # under a mapped predicate emit's cond would be a select.
+        def upto_emit(iid, slot, sti, inbox_i, do_tick, do_camp, n_new,
+                      iso, tr_to, rd_req, cf_req, wp, lane_any):
             # Partitioned instances neither receive nor send this round
             # (fault injection; ref: tests/framework bridge & pkg/proxy).
             # Phases carry jax.named_scope annotations so xprof/JAX
@@ -2278,7 +2360,22 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
             with jax.named_scope("raft_propose"):
                 sti = _propose(cfg, slot, sti, n_new)
             with jax.named_scope("raft_emit"):
-                sti, out = _emit(cfg, slot, sti, conf_applied)
+                sti = _apply_and_compact(cfg, sti, conf_applied)
+                below = None
+                # jitlint: waive(tracer-branch) -- on the argument's structure, as above
+                if lane_any is not None:
+                    # (A row cut off sends nothing, whatever it asks:
+                    # a retired node's replicas campaign into the void
+                    # for as long as they are away.)
+                    below = _asks_below(cfg, slot, sti) & ~iso
+            return (slot, n_new, iso, pre, inbox_i, sti, req_resps,
+                    read_snap, conf_applied, last_tick), below
+
+        def from_emit(mid, ring_read):
+            (slot, n_new, iso, pre, inbox_i, sti, req_resps, read_snap,
+             conf_applied, last_tick) = mid
+            with jax.named_scope("raft_emit"):
+                sti, out = _emit(cfg, slot, sti, ring_read)
             # The response to sender s's request of kind k is slot s of
             # lane k + NUM_REQ_KINDS; it routes back by the same
             # exchange (the inbox lane-order contract, top of module).
@@ -2315,6 +2412,22 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
                         n_new, read_snap, conf_applied),)
             return ret
 
+        def whole_round(ax, *rows):
+            """Both halves over the instance axis `ax` of every array
+            of `rows`, and emit's bit (None where there is no batch)."""
+            mid, below = jax.vmap(
+                upto_emit, in_axes=(ax,) * len(rows) + (None,),
+                out_axes=ax)(*rows, lane_any)
+            ring_read = None
+            # jitlint: waive(tracer-branch) -- on the argument's structure, as above
+            if below is not None:
+                with jax.named_scope("raft_emit"):
+                    ring_read = jnp.any(below)
+            return jax.vmap(from_emit, in_axes=(ax, None), out_axes=ax)(
+                mid, ring_read), ring_read
+
+        rows = (iids, slots, st, inbox, tick_mask, campaign_mask,
+                propose_n, isolate, transfer_to, read_req, conf_req, wipe)
         if cfg.lanes_minor:
             # Instance axis minor inside the kernel: every elementwise
             # op fills the TPU vector lanes with N, not with R/K/W.
@@ -2324,25 +2437,10 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
             to_major = lambda x: (
                 jnp.moveaxis(x, -1, 0) if x.ndim > 1 else x
             )
-            args = jax.tree.map(
-                to_minor,
-                (iids, slots, st, inbox, tick_mask, campaign_mask,
-                 propose_n, isolate, transfer_to, read_req, conf_req,
-                 wipe),
-            )
-            outs = jax.vmap(
-                per_instance,
-                in_axes=(-1,) * len(args) + (None,), out_axes=-1,
-            )(*args, lane_any)
+            outs, ring_read = whole_round(-1, *jax.tree.map(to_minor, rows))
             outs = jax.tree.map(to_major, outs)
         else:
-            outs = jax.vmap(
-                per_instance, in_axes=(0,) * 12 + (None,),
-            )(
-                iids, slots, st, inbox, tick_mask, campaign_mask,
-                propose_n, isolate, transfer_to, read_req, conf_req,
-                wipe, lane_any,
-            )
+            outs, ring_read = whole_round(0, *rows)
         sti, out, aux = outs[:3]
         fleet = None
         if cfg.fleet_summary:
@@ -2359,14 +2457,18 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
         # jitlint: waive(tracer-branch) -- on the argument's pytree structure, as above
         if packed:
             out = stack_lanes(out)
-        # Output order: (state, outbox[, aux][, telemetry][, fleet]) —
-        # callers index via the cfg flags (engine/rawnode compute the
-        # positions once at build time).
+        # Output order: (state, outbox[, aux][, telemetry][, fleet]
+        # [, emit's bit]) — callers index via the cfg flags (engine/
+        # rawnode compute the positions once at build time); the bit is
+        # the last of what it is handed to (`counted`, above).
         ret = (sti, out) + ((aux,) if with_aux else ())
         if cfg.telemetry:
             ret += (outs[3],)
         if cfg.fleet_summary:
             ret += (fleet,)
+        # jitlint: waive(tracer-branch) -- on the argument's structure, as above
+        if counted:
+            ret += (ring_read,)
         return ret
 
     # NOT donated: hosting callers (BatchedRawNode) build the inbox by
@@ -2397,7 +2499,9 @@ def make_step_round(cfg: BatchedConfig, iids=None, slots=None,
     randomized-timeout hash identical across topologies). `inbox` is
     [N, R, K] slots or the K kind lanes route_lanes() returns; a caller
     that holds the inbox's lane occupancy already (route_lanes' own
-    `lane_any`) may hand it in as `lane_any` and save the reduce."""
+    `lane_any`) may hand it in as `lane_any` and save the reduce, and
+    is then handed back, last, one bool more: whether this round's emit
+    read the log ring for the terms it states (_emit)."""
     # Resolve deliver_shape="auto" BEFORE the per-config jit cache so
     # "auto" and "vectorized" share one program.
     # ``lane_skip=False`` is for mesh-sharded callers — see

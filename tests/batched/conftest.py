@@ -184,6 +184,13 @@ os.environ.setdefault("ETCD_TPU_TRANSFER_GUARD", "disallow")
 # which is `engine512k-r3of4`'s BatchedConfig to the digit, at the CPU
 # tests' 8 groups: RP4 again. A phased schedule, like the lockstep one,
 # is an input of the closed-loop program and no key of the round step.
+# ISSUE 45 AUDIT: still 49 of 50. test_own_term runs the schedules of
+# test_differential, test_scan_faults, test_scan_reconf and
+# test_scan_replace again and builds its own engines on RC3, RC5 and
+# RP4: keys all. `own_from` is a field of the state and no field of the
+# configuration; the cases that force emit's bit clear
+# step._step_round_jit's cache to trace a key's round anew (the same key
+# string, counted once, as tests/benchmark/test_replace.py does).
 ROUND_STEP_SHAPE_BUDGET = 50
 
 

@@ -305,15 +305,18 @@ def test_rare_rounds_is_zero_over_a_steady_run():
 # sha256 of the lowered round of the two configurations that are built
 # with ``lane_skip=False`` anywhere in the suite (``test_route``'s
 # BOTH_FORMS: keys already), handed [N, R, K] slots as a hosting member
-# hands them, taken on the parent's tree (eaa210e, PR 42). Under a mapped
-# predicate a cond is a select, so there each heartbeat lane keeps its
-# one cond and the text does not move: a hosting member that shards its
-# rows over a mesh compiles nothing anew.
+# hands them. Under a mapped predicate a cond is a select, so there each
+# heartbeat lane keeps its one cond (and emit its one body, the ring
+# read every round) and PR 43 left the text of eaa210e (PR 42) as it
+# was: a hosting member that shards its rows over a mesh compiled
+# nothing anew. Re-pinned by PR 45 on its own text: the state carries
+# `own_from`, and `_maybe_commit` and `_control`'s committed-in-term
+# read it and not the ring, with or without the lane skip.
 NO_SKIP_TEXT = {
     "r3-wide-noskip":
-        "8591e0ca2cdbc69b2a0ff49ece7186bc48ac267cbb56fcfc2ef8b391d151ea8d",
+        "fe72165db8726279c1ee4e925063671dac01d115611e8bdd50527c4ab2a3465e",
     "r5-narrow-noskip":
-        "e52f93633403b5eab6fb5d022b28e33b2e0a4ba72dc3f38efaad83da7aee599a",
+        "89fe37f35261d9b423d439b360ee3dfbcb10b5c1a23793a557531dccc75516f4",
 }
 
 
@@ -333,15 +336,17 @@ def test_without_the_lane_skip_the_round_is_the_parents_text(name):
     assert "stablehlo.case" not in text  # every cond a select
 
 
-def test_with_the_lane_skip_the_round_holds_eight_conds():
-    """Six lanes, the two heartbeat lanes in two conds each; the round
-    that computes the occupancy itself (a hosting member on one device,
-    ``make_step_round``'s default) splits them like the engine's."""
+def test_with_the_lane_skip_the_round_holds_nine_conds():
+    """Six lanes, the two heartbeat lanes in two conds each, and emit's
+    (ISSUE 45: the ring read for the terms a message states, or the
+    sender's own term); the round that computes the occupancy itself (a
+    hosting member on one device, ``make_step_round``'s default) splits
+    them like the engine's."""
     eng = MultiRaftEngine(reconf.RC3)
     zb, zi = eng._zeros_b, eng._zeros_i
     text = jax.jit(eng._step).lower(
         eng.state, eng.inbox, zb, zb, zi, zb).as_text()
-    assert text.count("stablehlo.case") == NUM_KINDS + 2
+    assert text.count("stablehlo.case") == NUM_KINDS + 3
 
 
 # -- (d) placed over nodes: the bits are agreed with the lanes ---------------------

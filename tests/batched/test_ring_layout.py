@@ -192,3 +192,57 @@ def test_a_lane_cond_that_only_reads_the_ring_lays_it_out_ring_minor(
     compiler has stopped doing it, and that split may be asked for."""
     n_minor, ring_minor = rings(compiled_lane_cond(True, one_chip))
     assert ring_minor > 0
+
+
+# -- a cond one branch of which only reads the ring (ISSUE 45) ----------------------
+
+
+def compiled_emit_cond(sharding):
+    """64 rounds of: append an entry outside any cond, as above, and
+    state the terms of E entries a row, read from the ring through
+    `term_at` where a bit of the batch says so (every third round) and
+    the row's own term in every other: emit's two branches
+    (`step._emit`). The ring is an operand of the cond that one branch
+    reads and neither returns; the other holds nothing of ring shape."""
+    e = 4
+
+    def from_ring(ring, last, term):
+        zero = jnp.zeros((), I32)
+        return term_at(ring, zero, zero, last, last - jnp.arange(e, dtype=I32))
+
+    def own(ring, last, term):
+        return jnp.broadcast_to(term, (e,))
+
+    def row(ring, last, term, bit):
+        return jax.lax.cond(bit, from_ring, own, ring, last, term)
+
+    def body(i, carry):
+        ring, last, term, acc = carry
+        new, last = jax.vmap(
+            lambda r, l, t: (ring_write(r, l + 1, jnp.full((P,), 1, I32) * t,
+                                        jnp.asarray(1, I32)), l + 1),
+            in_axes=-1, out_axes=-1)(jnp.moveaxis(ring, 0, -1), last, term)
+        ring = jnp.moveaxis(new, -1, 0)
+        terms = jax.vmap(row, in_axes=(-1, -1, -1, None), out_axes=-1)(
+            new, last, term, i % 3 == 0)
+        return ring, last, term, acc + jnp.sum(terms, axis=0)
+
+    def loop(ring, last, term):
+        return jax.lax.fori_loop(
+            0, 64, body, (ring, last, term, jnp.zeros_like(last)))
+
+    vec = jax.ShapeDtypeStruct((N,), I32, sharding=sharding)
+    ring = jax.ShapeDtypeStruct((N, W), I32, sharding=sharding)
+    return jax.jit(loop).lower(ring, vec, vec).compile().as_text()
+
+
+def test_a_cond_whose_one_branch_only_reads_the_ring_keeps_it_n_minor(
+        one_chip, no_persistent_cache):
+    """What lets emit read the ring in one branch of a cond (PERF.md
+    section 6, "PR 45"): handed in and not handed back, the ring stays
+    N-minor through the branch's `term_at`s, where the same read in a
+    branch that returns the ring (the case above) flips it."""
+    text = compiled_emit_cond(one_chip)
+    n_minor, ring_minor = rings(text)
+    assert " conditional(" in text
+    assert n_minor > 0 and ring_minor == 0

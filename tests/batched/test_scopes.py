@@ -337,7 +337,10 @@ def test_a_line_moved_out_of_its_scope_fails_the_rule(name, monkeypatch):
 # and a one-hot pick), which every `_maybe_commit` of the round calls
 # (the untiled pins in `test_scan_replace.py` moved with both), and by PR
 # 43 on its own, because it split deliver's HB and HB_RESP lane conds in
-# two (the untiled pins moved with it): names
+# two (the untiled pins moved with it), and by PR 45 on its own, because
+# the state gained `own_from`, `_maybe_commit` and `_control` read it and
+# no ring, and emit reads the ring in one branch of a cond on a bit the
+# round reduces between its two vmaps (the untiled pins moved with it): names
 # are still not part of either text. The lowered
 # text holds no name of a scope, and JAX's persistent cache keys a
 # program with its names stripped: equal text here is a cache hit on the
@@ -348,11 +351,11 @@ def test_a_line_moved_out_of_its_scope_fails_the_rule(name, monkeypatch):
 # prints them); `test_scan_replace.py` pins the untiled texts.
 PARENT_TILED_TEXT = {
     "engine1m-r3": (
-        "599706195d35d8ed95591fc77fa35ba039dd5372682c5a2a08e9dd505170e16a",
-        "a505641232b5910c43d73398f7119b740240135600ef385c78094a906f3679a9"),
+        "02e6daa95038bc84d782c9e1e3b6ba6ebf0b408838041da68bc23ac29172213e",
+        "a06da0e7541dd9f754e58a096e623e661b6796236b2c08471a1656dff63a398b"),
     "engine512k-r3of4": (
-        "23db4b797fec6cb855039d67ed777d697d85ba41918d281aadd88b30024d695c",
-        "72de6e3b4623f6c5bbe00170b2c90b3633aee163176206b8cb5f6ab03f6c0b28"),
+        "87a167684e8b85d82e3cd4dfc06363bcd8834df91445c4c0fd7bedd29b8520aa",
+        "2fee4c9ba7988abea82981bcf2359cdd7456ec5c364f1e1be9ca457ac6c75c33"),
 }
 
 
@@ -373,7 +376,7 @@ def test_in_tiles_the_lowered_text_is_the_parents(name, monkeypatch):
         print(name, got)
     assert got == PARENT_TILED_TEXT[name], (
         "the lowered closed loop or eager round of a tiled configuration "
-        "is not the text it was at the commit that pinned it (PR 43)")
+        "is not the text it was at the commit that pinned it (PR 45)")
 
 
 # -- no sort in any live program (ISSUE 41) ----------------------------------------
