@@ -16,9 +16,9 @@ process initializes JAX itself, and with any backend but ``tpu`` it
 exits non-zero before building anything and prints no rate. It starts
 no child process.
 
-Kernel layout ([N, R] instance-major vs [R, N] instance-minor): the
-lane-filling minor layout runs unless BENCH_LAYOUT=major|minor pins
-one. A layout that fails to build fails the bench.
+It runs the one configuration BENCHMARK.json's ``engine64k-r3`` source
+line names: the lane-filling [R, N] instance-minor layout, sequential
+``run_rounds`` calls, telemetry and fleet planes off.
 
 Persistent compile cache: every engine build routes XLA compilations
 through the on-disk cache (batched/compile_cache.py:
@@ -26,11 +26,6 @@ JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache), so the second
 bench of an identical config pays a disk hit instead of the full
 compile. Build time is logged so warm/cold is visible in the stderr
 trace.
-
-Round pipelining: BENCH_PIPELINE=1 drives the measured loop through
-`run_rounds_pipelined` (double-buffered chunks, donated state; chunk
-k+1 enqueued while chunk k runs) instead of sequential `run_rounds`
-calls — the dispatch-gap experiment knob. Default off.
 
 Prints exactly one JSON line: {"metric", "value", "unit", "vs_baseline"}
 with commit-p50 detail inside "unit".
@@ -48,35 +43,21 @@ def _note(msg: str) -> None:
     print(f"[bench {time.strftime('%H:%M:%S')}] {msg}", file=sys.stderr, flush=True)
 
 
-def _make_engine(groups: int, lanes_minor: bool,
-                 telemetry: bool = False,
-                 fleet: bool = False):
-    # Canonical config + setup shared with tools/frontier_sweep.py so
-    # the two tools' numbers stay methodologically comparable.
-    from etcd_tpu.tools.benchlib import make_bench_engine
-
-    return make_bench_engine(groups, lanes_minor,
-                             telemetry=telemetry, fleet=fleet)
-
-
-def _rate(eng, props, rounds_per_call: int, calls: int,
-          pipelined: bool = False) -> float:
-    from etcd_tpu.tools.benchlib import measure_rate
-
-    return measure_rate(eng, props, rounds_per_call, calls,
-                        pipelined=pipelined)
-
-
 def main() -> None:
     # Transfer sentinel (ISSUE 7): every warm round dispatch runs under
     # jax.transfer_guard("disallow") — an implicit transfer in the
     # measured loop is a hard error, not a silent per-round sync that
-    # ships a fake record (the r4 675M/s artifact class). Overhead is
-    # below box noise (BENCH_NOTES r7). Opt out: ETCD_TPU_TRANSFER_GUARD=.
+    # ships a fake record (the r4 675M/s artifact class).
+    # Opt out: ETCD_TPU_TRANSFER_GUARD=.
     os.environ.setdefault("ETCD_TPU_TRANSFER_GUARD", "disallow")
     import jax
 
     from etcd_tpu.batched.compile_cache import enable_compile_cache
+    from etcd_tpu.tools.benchlib import (
+        make_bench_engine,
+        measure_commit_p50,
+        measure_rate,
+    )
 
     platform = jax.devices()[0].platform
     if platform != "tpu":
@@ -85,41 +66,13 @@ def main() -> None:
             f"{platform!r} ({jax.devices()[0].device_kind}); "
             "run it on the chip")
     _note(f"compile cache: {enable_compile_cache()}")
-
-    layout_env = os.environ.get("BENCH_LAYOUT", "")
-    if layout_env and layout_env not in ("major", "minor"):
-        raise SystemExit(f"BENCH_LAYOUT must be major|minor, got {layout_env!r}")
-    pipe_env = os.environ.get("BENCH_PIPELINE", "")
-    if pipe_env and pipe_env not in ("0", "1"):
-        raise SystemExit(f"BENCH_PIPELINE must be 0|1, got {pipe_env!r}")
-    pipelined = pipe_env == "1"
-    # BENCH_TELEMETRY=1 compiles the kernel telemetry plane (ISSUE 4)
-    # into the measured round — the overhead-measurement knob backing
-    # the BENCH_NOTES telemetry-off/on row. Headline default: off.
-    tel_env = os.environ.get("BENCH_TELEMETRY", "")
-    if tel_env and tel_env not in ("0", "1"):
-        raise SystemExit(
-            f"BENCH_TELEMETRY must be 0|1, got {tel_env!r}")
-    telemetry = tel_env == "1"
-    # BENCH_FLEET=1 compiles the fleet-summary plane (ISSUE 10) into
-    # the measured round — the overhead knob backing the BENCH_NOTES
-    # fleet row (tools/fleet_overhead.py interleaves on/off runs).
-    flt_env = os.environ.get("BENCH_FLEET", "")
-    if flt_env and flt_env not in ("0", "1"):
-        raise SystemExit(f"BENCH_FLEET must be 0|1, got {flt_env!r}")
-    fleet = flt_env == "1"
-    # The lane-filling layout ([R*K, N]: the group axis fills the
-    # 128-wide vector lanes) unless pinned.
-    lanes_minor = layout_env != "major"
     t0 = time.perf_counter()
-    eng, props = _make_engine(GROUPS, lanes_minor, telemetry, fleet)
+    eng, props = make_bench_engine(GROUPS)
     _note(f"main G={GROUPS} built+compiled in {time.perf_counter()-t0:.1f}s")
-    rate = _rate(eng, props, 16, 8, pipelined=pipelined)
+    rate = measure_rate(eng, props, 16, 8)
     _note(f"main rate: {rate:.0f} group-rounds/s")
     commits = eng.commits()
     assert commits.min() > 0
-
-    from etcd_tpu.tools.benchlib import measure_commit_p50
 
     commit_p50_ms, rounds = measure_commit_p50(eng)
 
@@ -130,11 +83,8 @@ def main() -> None:
                 "value": round(rate, 1),
                 "unit": (
                     f"group-rounds/s ({platform}, G={GROUPS}, R=3, "
-                    f"layout={'minor' if lanes_minor else 'major'}, "
-                    f"deliver={eng.cfg.deliver_shape}, "
-                    f"loop={'pipelined' if pipelined else 'serial'}, "
-                    f"telemetry={'on' if telemetry else 'off'}, "
-                    f"fleet={'on' if fleet else 'off'}, "
+                    f"layout=minor, deliver={eng.cfg.deliver_shape}, "
+                    "loop=serial, telemetry=off, fleet=off, "
                     f"commit_p50={commit_p50_ms:.2f}ms/{rounds}r)"
                 ),
                 "vs_baseline": round(rate / 1e6, 4),

@@ -565,8 +565,8 @@ class ChaosHarness:
         self.trace = bool(trace)
         # fence=False disables the durability watermark + fenced-boot
         # path on every member — the pre-PR behavior, kept so the
-        # torn-acked divergence stays demonstrable
-        # (tools/repro_progress_wedge.py --torn-acked).
+        # torn-acked divergence stays demonstrable (the fenced side
+        # is tests/batched/test_torn_fence.py).
         self.fence = fence
         self.cfg = cfg or BatchedConfig(
             num_groups=num_groups, num_replicas=num_members,
@@ -1208,9 +1208,9 @@ def run_invariant_checks(harness: ChaosHarness,
     heals after the fact (root-caused with the ISSUE 4 flight
     recorder — the leader's match oscillates against the survivor's
     below-commit fast-path ack at the conflicted commit index). The
-    knob remains for fence-disabled runs
-    (tools/repro_progress_wedge.py --torn-acked keeps the failure
-    demonstrable against ChaosHarness(fence=False)).
+    knob remains for fence-disabled runs (``torn_acked_tail`` against
+    ChaosHarness(fence=False) keeps the failure demonstrable;
+    tests/batched/test_torn_fence.py drives the fenced side).
 
     When the harness flies with telemetry (the default config), the
     closer also asserts the on-device invariant sweep stayed clean —

@@ -652,7 +652,7 @@ class MultiRaftMember:
             # round/wal/apply/send are member-pipeline phases; stage/
             # extract/collect split the round's host-side Python (inbox
             # staging, post-round extraction, outbound block assembly)
-            # so the BENCH_NOTES phase breakdown is reproducible from
+            # so the hosted phase breakdown is reproducible from
             # metrics alone (dump_metrics --admin).
             self._h_phase = {
                 p: ph.labels(mid, p)

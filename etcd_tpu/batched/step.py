@@ -1867,7 +1867,6 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, ring_read=None):
 # are those of the named_scope calls,
 # letter for letter, and of the shape benchmark/reduce/trace.py files a
 # device op by (``raft_`` and lower-case letters; the innermost wins).
-# tools/phaseprobe.py names its segments from it, and
 # tests/batched/test_scopes.py holds every equation of the closed loop
 # to it: an op a trace files under no scope is one the compiler made.
 DEVICE_SCOPES = (

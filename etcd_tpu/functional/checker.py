@@ -116,7 +116,8 @@ def multiraft_hash_check(members: Sequence, timeout: float = 30.0,
     default and — since the ISSUE 5 durability fence — what every
     chaos episode class asserts; the relaxation remains for
     fence-disabled runs that deliberately re-open the torn-tail
-    divergence (tools/repro_progress_wedge.py --torn-acked)."""
+    divergence (``ChaosHarness(fence=False)``; the fenced side is
+    tests/batched/test_torn_fence.py)."""
     import numpy as np
 
     members = list(members)

@@ -43,6 +43,12 @@ class TLSInfo:
         ctx = ssl.SSLContext(ssl.PROTOCOL_TLS_SERVER)
         ctx.minimum_version = ssl.TLSVersion.TLSv1_2
         ctx.load_cert_chain(self.cert_file, self.key_file)
+        # No TLS 1.3 session tickets: nothing here resumes a session,
+        # and a dialer that reads them on its reader thread while
+        # another thread writes the first request (two threads in one
+        # OpenSSL object) loses that request, about 2% of fresh
+        # connections under load (ROADMAP D14).
+        ctx.num_tickets = 0
         if self.trusted_ca_file:
             ctx.load_verify_locations(self.trusted_ca_file)
         if self.client_cert_auth:

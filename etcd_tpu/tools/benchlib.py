@@ -1,13 +1,12 @@
 """Shared bench methodology: the canonical engine config and the
 commit-p50 measurement.
 
-``bench.py`` (the headline number) and ``tools/frontier_sweep.py``
-(the latency/throughput frontier) must stay directly comparable to
-each other and to the BENCH_r05 captures (2026-07-31) — same R/W/E
-config, same election setup, same proposal load, same quiet-point
-commit-latency loop. Both import these helpers so a methodology tweak
-lands in one place and cannot silently desynchronize the two tools'
-numbers.
+``bench.py`` (the headline number) and ``chip_smoke.py`` (the engine
+phase of the chip gate) must stay directly comparable to each other
+and to the BENCH_r05 captures (2026-07-31) — same R/W/E config, same
+election setup, same proposal load, same quiet-point commit-latency
+loop — and ``benchmark/drivers/engine.py`` copies this set-up for the
+``engine64k-r3`` configuration. A methodology tweak lands in one place.
 """
 
 from __future__ import annotations
@@ -16,19 +15,12 @@ import time
 from typing import Tuple
 
 
-def make_bench_engine(groups: int, lanes_minor: bool = True,
-                      telemetry: bool = False,
-                      fleet: bool = False):
+def make_bench_engine(groups: int):
     """Build the canonical bench engine (BENCH_r05 methodology: R=3,
     W=32, E=4, steady state with no timer elections, auto-compacting
-    ring), elect every group's slot-0 replica, and return the engine
-    plus the steady 2-entries-per-group-per-round proposal vector.
-
-    ``telemetry`` compiles the kernel telemetry plane in (ISSUE 4):
-    the headline number stays telemetry-off; BENCH_TELEMETRY=1 /
-    frontier --telemetry measure the overhead so it stays pinned in
-    BENCH_NOTES. ``fleet`` likewise compiles the fleet-summary plane
-    in (ISSUE 10; BENCH_FLEET=1 / tools/fleet_overhead.py)."""
+    ring, the lane-filling minor layout, telemetry and fleet planes
+    off), elect every group's slot-0 replica, and return the engine
+    plus the steady 2-entries-per-group-per-round proposal vector."""
     import jax.numpy as jnp
 
     from ..batched import BatchedConfig, MultiRaftEngine
@@ -42,9 +34,7 @@ def make_bench_engine(groups: int, lanes_minor: bool = True,
         election_timeout=1 << 20,  # steady state: no timer elections
         heartbeat_timeout=4,
         auto_compact=True,  # sustained load: ring chases the applied mark
-        lanes_minor=lanes_minor,
-        telemetry=telemetry,
-        fleet_summary=fleet,
+        lanes_minor=True,  # the group axis fills the 128-wide lanes
     )
     eng = MultiRaftEngine(cfg)
     eng.campaign([g * cfg.num_replicas for g in range(groups)])
@@ -55,26 +45,18 @@ def make_bench_engine(groups: int, lanes_minor: bool = True,
     return eng, props
 
 
-def measure_rate(eng, props, rounds_per_call: int, calls: int,
-                 pipelined: bool = False) -> float:
+def measure_rate(eng, props, rounds_per_call: int, calls: int) -> float:
     """Steady-state group-rounds/s. The warmup compiles the
-    chunk-sized scan program (rounds is a static arg, so the serial
-    warmup covers the pipelined timed loop too — same program); the
-    timed region then drives either sequential ``run_rounds`` calls
-    (the BENCH_r05 headline methodology) or one
-    ``run_rounds_pipelined`` pass with chunk == rounds_per_call."""
+    chunk-sized scan program (rounds is a static arg); the timed
+    region then drives sequential ``run_rounds`` calls (the BENCH_r05
+    headline methodology)."""
     import jax
 
     eng.run_rounds(rounds_per_call, tick=True, propose_n=props)  # warmup
     jax.block_until_ready(eng.state.commit)
     t0 = time.perf_counter()
-    if pipelined:
-        eng.run_rounds_pipelined(
-            rounds_per_call * calls, chunk=rounds_per_call,
-            tick=True, propose_n=props)
-    else:
-        for _ in range(calls):
-            eng.run_rounds(rounds_per_call, tick=True, propose_n=props)
+    for _ in range(calls):
+        eng.run_rounds(rounds_per_call, tick=True, propose_n=props)
     jax.block_until_ready(eng.state.commit)
     dt = time.perf_counter() - t0
     return eng.cfg.num_groups * rounds_per_call * calls / dt

@@ -1,7 +1,7 @@
 """Hosted-path benchmark, a CPU tool: 3 real OS processes over a
 selectable peer fabric (``--fabric=tcp`` sockets or ``--fabric=shm``
 mmap'd SPSC rings, ISSUE 16), G groups — the service-rate number next
-to bench.py's kernel rate (VERDICT r04 task #1: an artifact with a
+to bench.py's kernel rate (review round 4, task 1: an artifact with a
 floor). A chip belongs to one process, so three member processes
 cannot share it: every worker is pinned to ``JAX_PLATFORMS=cpu`` and
 the artifact says ``"platform": "cpu"``. Its numbers are not device
@@ -20,7 +20,7 @@ Writes HOSTED_BENCH.json at the repo root:
      "restart_catchup_s": ..., "config": "...", "captured_at": "..."}
 
 (phase_ms_per_round is the member-round budget averaged over members —
-the BENCH_NOTES phase table, reproducible from the artifact; the same
+the hosted phase table, reproducible from the artifact; the same
 split is exported as the round-phase histograms under --telemetry.)
 
 With ``--trace`` the workers run the proposal-lifecycle tracer
@@ -34,8 +34,8 @@ so ``--trace`` runs are labeled and are NOT the parity baseline.
 
 ``--wal-pipeline`` (or ``ETCD_TPU_WAL_PIPELINE=1``) flies the workers
 with the async group-commit WAL pipeline (ISSUE 13); A/B rows against
-the same-day inline baseline land in BENCH_NOTES and the
-``artifacts/hosted_walpipe_*.json`` artifacts. Pair with
+the same-day inline baseline are written with ``--out`` (e.g.
+``artifacts/hosted_walpipe_*.json``). Pair with
 ``ETCD_TPU_FSYNC_DELAY_MS`` (walog-level slow-disk emulation) on boxes
 whose local fsync is microsecond-class — the pipeline overlaps IO
 wait, so a free fsync leaves nothing to win.
@@ -144,8 +144,8 @@ def main() -> None:
                     default=env_flag("ETCD_TPU_WAL_PIPELINE"),
                     help="run the workers with the async group-commit "
                          "WAL pipeline (ISSUE 13); also honored via "
-                         "ETCD_TPU_WAL_PIPELINE=1 — A/B rows against "
-                         "the inline baseline land in BENCH_NOTES")
+                         "ETCD_TPU_WAL_PIPELINE=1 — A/B against the "
+                         "same-day inline baseline")
     ap.add_argument("--fabric", choices=("tcp", "shm"), default="tcp",
                     help="peer transport for the workers: tcp "
                          "(TCPRouter sockets, default) or shm (the "
@@ -270,7 +270,7 @@ def main() -> None:
         # Per-phase member-round budget (ms/round, averaged over the
         # members): stage/step/extract/collect from the rawnode timers
         # (rn.phase_total, summed from the round spans), wal/apply/send from
-        # the member pipeline stats — the BENCH_NOTES phase table,
+        # the member pipeline stats — the hosted phase table,
         # recorded in the artifact instead of ad-hoc profiling.
         phase_ms = {}
         for mid, c in clients.items():

@@ -593,7 +593,7 @@ class TestLogLifecycleSoak:
                         hl["ring"]["occ_high_water"],
                 }
 
-            # Evidence for BENCH_NOTES r17: the measured plateau.
+            # Evidence of the measured plateau (r17).
             os.makedirs("artifacts", exist_ok=True)
             with open("artifacts/lifecycle_soak_r17.json", "w") as f:
                 json.dump({

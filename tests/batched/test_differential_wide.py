@@ -1,4 +1,4 @@
-"""Widened differential envelope (VERDICT r1 item 4): randomized timer
+"""Widened differential envelope (review round 1, item 4): randomized timer
 elections, partial partitions, snapshot catch-up under auto-compaction,
 and a long randomized soak — every round compared field-for-field
 against the reference-semantics oracle."""
@@ -250,7 +250,7 @@ class TestRandomSoak:
 class TestWideSoakG64:
     @pytest.mark.slow
     def test_wide_random_soak_g64(self):
-        """VERDICT r04 task #7: the differential envelope at G=64 —
+        """review round 4, task 7: the differential envelope at G=64 —
         live randomized timer elections, rolling isolation windows,
         rolling PARTIAL partitions (directed link cuts), random
         proposals, auto-compaction — for >=2000 rounds with every

@@ -1,7 +1,7 @@
 """Batched-engine feature envelope: learners, joint membership,
 leader transfer, ReadIndex — on-device implementations of the paths
-VERDICT round 1 flagged as host-only (ref: raft.go:1339-1372 transfer;
-read_only.go; confchange/confchange.go; tracker learners)."""
+the review of round 1 flagged as host-only (ref: raft.go:1339-1372
+transfer; read_only.go; confchange/confchange.go; tracker learners)."""
 
 import jax.numpy as jnp
 import numpy as np

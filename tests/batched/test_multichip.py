@@ -66,8 +66,8 @@ def test_dryrun_subprocess_fallback():
 def test_hosted_put_roundtrip_on_mesh(tmp_path):
     """A 3-member hosted cluster whose members each shard their [G,...]
     device state over the virtual 8-device mesh: puts round-trip
-    through WAL + transport + apply with the sharded step (VERDICT r04
-    task #3 'sharded engine under the hosting layer')."""
+    through WAL + transport + apply with the sharded step (review round
+    4, task 3: 'sharded engine under the hosting layer')."""
     from etcd_tpu.batched.hosting import MultiRaftCluster
 
     from .test_hosting import wait_until
@@ -98,7 +98,7 @@ def test_hosted_put_roundtrip_on_mesh(tmp_path):
 def test_sharded_vs_unsharded_differential_g4096(tmp_path):
     """Sharded (8-device mesh) and unsharded members at G=4096 must
     produce identical applied KV state for the same workload, end to
-    end through WAL + transport + apply (VERDICT r04 task #3)."""
+    end through WAL + transport + apply (review round 4, task 3)."""
     from etcd_tpu.batched.hosting import MultiRaftCluster
     from etcd_tpu.batched.state import BatchedConfig
 

@@ -16,8 +16,8 @@ evidence (always safe: commit is monotone), letting the normal
 reject/backtrack/resend cycle re-heal the log.
 
 The deterministic kernel-level test runs in tier-1; the stochastic
-TCP chaos repro (the original tools/repro_progress_wedge.py scenario)
-is slow-marked.
+TCP chaos repro (the scenario the wedge was first seen in) is
+slow-marked.
 """
 
 import tempfile
@@ -97,7 +97,7 @@ def test_torn_follower_heals_deterministically():
 @pytest.mark.slow
 @pytest.mark.chaos
 def test_tcp_restart_torn_tail_no_wedge():
-    """The original stochastic repro (tools/repro_progress_wedge.py):
+    """The original stochastic repro:
     TCP transport, failpoint crash/restart + crash/torn-tail/restart.
     Pre-fix this wedged on ~10-30% of attempts with the illegal
     `next <= match` progress state pinned for the rest of the run —

@@ -193,11 +193,6 @@ def test_the_registry_is_what_the_program_names():
         with open(mod.__file__) as f:
             opened |= set(re.findall(r'named_scope\("([^"]+)"\)', f.read()))
     assert opened == set(SCOPES)
-    # tools/phaseprobe.py names its segments from the same tuple.
-    tool = os.path.join(os.path.dirname(__file__), "..", "..", "tools",
-                        "phaseprobe.py")
-    with open(tool) as f:
-        assert "step_mod.DEVICE_SCOPES" in f.read()
     assert not hasattr(step_mod, "ROUND_PHASE_SCOPES")
 
 

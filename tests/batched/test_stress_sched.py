@@ -1,4 +1,4 @@
-"""Scheduler-stress mode for the threaded host code (VERDICT r04 #8).
+"""Scheduler-stress mode for the threaded host code (review round 4, #8).
 
 Python has no ThreadSanitizer: Go gets `-race` for free on the
 reference's heavily-threaded rafthttp/etcdserver code

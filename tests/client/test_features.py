@@ -223,7 +223,7 @@ class TestSnapshotSave:
 
 
 class TestAdvisorRegressions:
-    """Round-1 advisor findings (ADVICE.md) must stay fixed."""
+    """Round-1 advisor findings must stay fixed."""
 
     def test_mirror_streams_full_batches_at_max_txns_0(self, member):
         # A txn writing two keys produces ONE watch batch with two

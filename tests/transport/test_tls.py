@@ -64,14 +64,27 @@ def test_plaintext_dial_to_tls_peer_rejected(certs):
         t2.stop()
 
 
+def wait_ready(addr, certs, timeout=60.0):
+    """The member serves: one TLS handshake and one answered request,
+    tried again until both succeed."""
+    deadline = time.monotonic() + timeout
+    while True:
+        try:
+            c = Client([addr], tls_info=certs)
+            try:
+                c.get(b"ready")
+                return
+            finally:
+                c.close()
+        except ClientError:
+            if time.monotonic() >= deadline:
+                raise
+            time.sleep(0.05)
+
+
 class TestClientChannelTLS:
     @pytest.fixture
     def tls_cluster(self, tmp_path, certs):
-        from tests.framework.integration import IntegrationCluster
-
-        class TLSMember:
-            pass
-
         # Single member with a TLS RPC listener.
         from etcd_tpu.raftexample.transport import InProcNetwork
         from etcd_tpu.server import EtcdServer, ServerConfig
@@ -81,13 +94,13 @@ class TestClientChannelTLS:
             member_id=1, peers=[1], data_dir=str(tmp_path),
             network=InProcNetwork(), tick_interval=0.01))
         rpc = V3RPCServer(srv, bind=("127.0.0.1", 0), tls_info=certs)
-        deadline = time.monotonic() + 20
-        while not srv.is_leader() and time.monotonic() < deadline:
-            time.sleep(0.02)
-        assert srv.is_leader()
-        yield srv, rpc
-        rpc.stop()
-        srv.stop()
+        try:
+            wait_ready(rpc.addr, certs)
+            assert srv.is_leader()
+            yield srv, rpc
+        finally:
+            rpc.stop()
+            srv.stop()
 
     def test_tls_client_roundtrip(self, tls_cluster, certs):
         _, rpc = tls_cluster
@@ -95,6 +108,18 @@ class TestClientChannelTLS:
         try:
             c.put(b"sk", b"sv")
             assert c.get(b"sk").kvs[0].value == b"sv"
+        finally:
+            c.close()
+
+    def test_server_sends_no_session_tickets(self, tls_cluster, certs):
+        """A ticket is read by the client's reader thread while another
+        thread writes the first request: see ``server_context``."""
+        _, rpc = tls_cluster
+        c = Client([rpc.addr], tls_info=certs)
+        try:
+            c.get(b"sk")  # an answer read: a ticket would have come first
+            assert c._sock.version() == "TLSv1.3"
+            assert not c._sock.session.has_ticket
         finally:
             c.close()
 

@@ -7,12 +7,10 @@ require it to (a) emit a schema-valid report and (b) converge the
 cluster below the skew threshold — a broken fleet signal, admin
 transfer op, or rebalance policy fails the static gate, not a live
 hosted run. Writes ``artifacts/rebalance_smoke.json`` (seeded-skew
-shape, per-pass report, convergence wall time) — the artifact the
-BENCH_NOTES rebalance-convergence row cites; lint.yml uploads it on
-failure.
+shape, per-pass report, convergence wall time); lint.yml uploads it
+on failure.
 
-``--groups N`` scales the cell (default 24; the BENCH_NOTES row runs
-1024).
+``--groups N`` scales the cell (default 24).
 """
 
 from __future__ import annotations
