@@ -21,7 +21,10 @@ inside the scan. The control schedule is a row a round for every group
 alike, or *phased* (``run_rounds(control=cycle, starts=...)``): one
 cycle's rows and the round at which each group enters it, so that a
 rebalancer's batch of groups moves while every other group runs steady
-beside it. Inside
+beside it; or the scan draws what each group is offered itself
+(``run_rounds(load=...)``: a *load plane*, two thresholds a group and a
+seed, so that a few hot groups append in every round while most only
+heartbeat). Inside
 a scan the network moves only what
 was sent: inbox and outbox ride as six kind lanes (entries in the
 append lane alone, ``step.split_lanes``) and a round exchanges
@@ -131,6 +134,68 @@ CTL_RETIRE, CTL_WIPE = 5, 6
 # three scalars, in these columns.
 PH_ROUND, PH_READS, PH_STALL = range(3)
 NEVER = np.iinfo(np.int32).max
+# A *load plane* (``run_rounds(load=(update_thr, read_thr, seed))``) is
+# the third form of a scan's input: no two groups are offered the same
+# thing. `update_thr` and `read_thr` are uint32 [num_groups], `seed` an
+# integer below 2**32. In round t, counted in the rounds the engine's
+# load scans have run (``MultiRaftEngine.load_round``, carried across
+# calls), group g draws one 32-bit word a stream,
+#
+#     base = fmix32(seed + t * LOAD_ROUND_MUL)
+#     u_k  = fmix32(base ^ (g * LOAD_GROUP_MUL + k * LOAD_STREAM_MUL))
+#
+# in uint32 arithmetic (``fmix32``: murmur3's finalizer), and is offered
+# one update for each of the streams k = 0 .. max_props_per_round - 1
+# with ``u_k < update_thr[g]``, and a read where stream
+# max_props_per_round has ``u < read_thr[g]``. A threshold of
+# LOAD_ALWAYS is one no draw can miss (a probability of exactly 1), 0
+# one none can meet. The offer goes to every replica of the group
+# (`_propose` appends on a leader only). The scan's per-round input is
+# the round's number, one scalar; the thresholds are widened to rows
+# once a call and sliced once a tile (scope ``raft_load``).
+LOAD_ROUND_MUL, LOAD_GROUP_MUL, LOAD_STREAM_MUL = (
+    0x9E3779B1, 0x85EBCA77, 0xC2B2AE3D)
+LOAD_ALWAYS = 0xFFFFFFFF
+# What a scan with a load plane counts in its carry
+# (``MultiRaftEngine.load_counts``), a group and not a row, in this
+# order: updates offered, group-rounds in which a read was asked, and
+# group-rounds in which either was.
+LOAD_COUNT_NAMES = ("offered", "reads_asked", "active")
+U32 = jnp.uint32
+
+
+def fmix32(x):
+    """murmur3's 32-bit finalizer on uint32 (wraps as the device's
+    arithmetic does)."""
+    x = (x ^ (x >> 16)) * U32(0x85EBCA6B)
+    x = (x ^ (x >> 13)) * U32(0xC2B2AE35)
+    return x ^ (x >> 16)
+
+
+def load_base(seed, t):
+    """A load plane's word of round `t` (the LOAD_* comment above):
+    uint32 `seed`, any integer `t`."""
+    return fmix32(seed + t.astype(U32) * U32(LOAD_ROUND_MUL))
+
+
+def load_word(base, group_key, k: int):
+    """Stream `k`'s 32-bit draw of the groups whose keys (``g *
+    LOAD_GROUP_MUL``, uint32) `group_key` holds, in the round whose
+    word is `base`."""
+    return fmix32(base ^ (group_key + U32(k * LOAD_STREAM_MUL & LOAD_ALWAYS)))
+
+
+def _add_limbs(counts, add):
+    """[K, 2] two-limb counts (high, low: `_LIMB`) and [K] more."""
+    low = counts[:, 1] + add
+    return jnp.stack(
+        [counts[:, 0] + (low >> _LIMB), low & ((1 << _LIMB) - 1)], axis=1)
+
+
+def _limbs_total(counts) -> np.ndarray:
+    """[..., 2] two-limb counts as int64 [...]."""
+    c = np.asarray(counts).astype(np.int64)
+    return (c[..., 0] << _LIMB) + c[..., 1]
 
 
 def control_cols(cfg: BatchedConfig) -> int:
@@ -282,7 +347,8 @@ class MultiRaftEngine:
     stats; of a phased schedule those four count a round a batch in
     flight, and ``batches`` (in flight in the call) and ``started``
     (groups that have entered the cycle by its end) say which form it
-    was. A span ends when the
+    was; a scan with a load plane says ``load_from``, its first load
+    round. A span ends when the
     program is enqueued: the host's share of a call, not the device's."""
 
     def __init__(self, cfg: BatchedConfig, start_index: int = 0,
@@ -505,6 +571,14 @@ class MultiRaftEngine:
         # (`_phased_schedule`).
         self.phase_round = 0
         self._phase: Optional[dict] = None
+        # Rounds the scans with a load plane have run (what its draws
+        # count in), that plane as the device holds it
+        # (`_load_schedule`) and what those scans counted
+        # (load_counts()): made by the first of them, so that an engine
+        # that runs none builds no program more than it did.
+        self.load_round = 0
+        self._load: Optional[dict] = None
+        self._tally = None
         # In-device telemetry accumulator (cfg.telemetry): per-instance
         # counter totals + OR-folded invariant bitmaps, accumulated
         # inside the closed-loop scan with no per-round host sync.
@@ -575,12 +649,45 @@ class MultiRaftEngine:
                 wipe = slots == column(CTL_WIPE) - 1
             return transfer, conf, retired, wipe
 
+        def load_plane(load, lo, slots):
+            """A load plane for the rows from `lo` on, made once a
+            call or a tile: `load` is (update_thr, read_thr, seed) with
+            the thresholds a row each. Hands `draw_load` the
+            thresholds, which of them no draw can miss, the rows' hash
+            keys (their group's) and the rows that count for their
+            group (its first)."""
+            upd, rd, seed = load
+            group = (lo + jnp.arange(upd.shape[0], dtype=I32)) // r
+            return (upd, rd, upd == U32(LOAD_ALWAYS), rd == U32(LOAD_ALWAYS),
+                    group.astype(U32) * U32(LOAD_GROUP_MUL), slots == 0, seed)
+
+        def draw_load(plane, t, tally):
+            """Round `t` of a load plane: each row's (updates offered
+            [rows] i32, read asked [rows] bool), a group's replicas
+            alike, and `tally` (LOAD_COUNT_NAMES, two limbs) with the
+            round's counted in, a group once."""
+            upd, rd, always_u, always_r, key, first, seed = plane
+            base = load_base(seed, t)
+            n_new = zeros = jnp.zeros(upd.shape, I32)
+            for k in range(cfg.max_props_per_round):
+                n_new = n_new + (
+                    (load_word(base, key, k) < upd) | always_u).astype(I32)
+            reads = (load_word(base, key, cfg.max_props_per_round) < rd
+                     ) | always_r
+            add = jnp.stack([
+                jnp.sum(jnp.where(first, n_new, zeros)),
+                jnp.sum((first & reads).astype(I32)),
+                jnp.sum((first & (reads | (n_new > 0))).astype(I32))])
+            return n_new, reads, _add_limbs(tally, add)
+
         def round_body(step, zeros_b, zeros_i, slots, ticks, props, tiled,
-                       phase=None):
+                       phase=None, load=None):
             """The scan's body over the rows its arguments are made
             for: all N, or one tile's (`tiled`). With `phase`
             (`widen_phased`) the control row is a phased schedule's
-            three scalars of the round."""
+            three scalars of the round; with `load` (`load_plane`) it
+            is the round's number alone, and what each row is offered
+            is drawn from it."""
 
             def body(carry, row):
                 # `occ` is the inbox's occupancy (step.lane_occupancy:
@@ -591,8 +698,10 @@ class MultiRaftEngine:
                 # step.DEVICE_SCOPES (the round's own are innermost and
                 # win): what a trace then files under no scope, the
                 # compiler made (tests/batched/test_scopes.py).
-                st, inbox, occ, tel, flt, (lanes, ring), watch = carry
+                # (`tally`: the load plane's counts, of that form alone.)
+                st, inbox, occ, tel, flt, (lanes, ring, *tally), watch = carry
                 cut, ctl = row
+                offer = props
                 with jax.named_scope("raft_carry"):
                     if not tiled:
                         lanes = lanes + occ
@@ -606,7 +715,19 @@ class MultiRaftEngine:
                     transfer, reads, conf = zeros_i, zeros_b, None
                     wipe = None
                     # jitlint: waive(tracer-branch) -- as above
-                    if ctl is not None and phase is None:
+                    if ctl is not None and load is not None:
+                        # A load plane: each row is offered what its
+                        # group draws in this round.
+                        with jax.named_scope("raft_load"):
+                            offer, reads, counted = draw_load(
+                                load, ctl, tally[0])
+                            tally = [counted]
+                            stall = jnp.zeros((), bool)
+                        if cfg.replace_replicas:
+                            wipe = zeros_b
+                        pre = st
+                    # jitlint: waive(tracer-branch) -- as above
+                    elif ctl is not None and phase is None:
                         # The row's few scalars widened the same way.
                         drained = slots == ctl[CTL_FROM] - 1
                         transfer = jnp.where(drained, ctl[CTL_TO], 0)
@@ -632,7 +753,7 @@ class MultiRaftEngine:
                             stall = ctl[PH_STALL] != 0
                         pre = st
                     out = step(
-                        st, inbox, ticks, zeros_b, props, iso,
+                        st, inbox, ticks, zeros_b, offer, iso,
                         transfer, reads, lane_any=occ, conf_req=conf,
                         wipe=wipe,
                     )
@@ -646,7 +767,8 @@ class MultiRaftEngine:
                         # order of the lines.)
                         watch = self._watch_round(
                             watch, pre, st, slots,
-                            ctl[CTL_STALL] != 0 if phase is None else stall,
+                            ctl[CTL_STALL] != 0
+                            if phase is None and load is None else stall,
                             wipe)
                 with jax.named_scope("raft_carry"):
                     if cfg.telemetry:
@@ -679,8 +801,8 @@ class MultiRaftEngine:
                 # A tile cannot count the rounds a lane was occupied
                 # for ANY instance: it hands each round's own vector
                 # out, for the call to put together over its tiles.
-                return (st, inbox, sent, tel, flt, (lanes, ring), watch), (
-                    occ if tiled else None)
+                return (st, inbox, sent, tel, flt, (lanes, ring, *tally),
+                        watch), (occ if tiled else None)
 
             return body
 
@@ -698,7 +820,7 @@ class MultiRaftEngine:
                 for k in range(NUM_KINDS)), occ
 
         def tiled_loop(st, inbox, ticks, props, tel, lanes, isolate,
-                       rounds, control, watch, phase=None):
+                       rounds, control, watch, phase=None, load=None):
             """`closed_loop` tile by tile. Groups share nothing, so a
             call of `rounds` rounds over all rows is `tiles` calls
             over a block of whole groups each (`rows` adjacent rows:
@@ -721,34 +843,45 @@ class MultiRaftEngine:
             occupancy every node counts is the agreed one, and the fence
             is the node's own, [1]. Of a
             phased schedule (`phase`: `widen_phased`) a tile takes its
-            rows' starts, as it takes every per-row array."""
+            rows' starts, as it takes every per-row array, and of a
+            load plane (`load`: (update_thr, read_thr, seed), a row
+            each) its rows' thresholds; the plane's counts run on from
+            tile to tile with the others."""
             with jax.named_scope("raft_carry"):
                 slots = row_slots()
                 zeros_b = jnp.zeros((rows,), bool)
                 zeros_i = jnp.zeros((rows,), I32)
 
-            def tile_body(lo, ticks, props, start=()):
-                """The scan's body for the rows from `lo` on."""
+            def tile_body(lo, ticks, props, *own):
+                """The scan's body for the rows from `lo` on; `own` is
+                what the schedule's form keeps a row: a phased
+                schedule's starts, a load plane's two thresholds."""
                 with jax.named_scope("raft_carry"):
                     step = tile_step(lo, slots)
+                plane = None
+                if load is not None:  # its structure: None or arrays
+                    with jax.named_scope("raft_load"):
+                        plane = load_plane(own + load[2:], lo, slots)
                 return round_body(
                     step, zeros_b, zeros_i, slots, ticks, props, tiled=True,
-                    phase=None if phase is None else phase[:2] + (start,))
+                    phase=None if phase is None else phase[:2] + own,
+                    load=plane)
 
             def tile_rounds(lo, st, inbox, tel, watch, ticks, props,
-                            counts, start=()):
+                            counts, *own):
                 """The call's rounds on the rows from `lo` on, handed
                 in as the tile's slices (`watch` with the whole
                 counts, `counts` the whole counts the scan's carry adds
                 up: of lanes exchanged between nodes, () on one device,
-                and of tile-rounds in which emit read the ring); and
-                each round's lane occupancy."""
+                of tile-rounds in which emit read the ring and, of a
+                load plane, of what it offered); and each round's lane
+                occupancy."""
                 with jax.named_scope("raft_carry"):
                     inbox, occ = enter(inbox)
                 if placed:
                     occ = agree_lanes(occ, NODE_AXIS)
                 (st, inbox, _, tel, _, counts, watch), occs = jax.lax.scan(
-                    tile_body(lo, ticks, props, start),
+                    tile_body(lo, ticks, props, *own),
                     (st, inbox, occ, tel, (), counts, watch),
                     (isolate, control), length=rounds)
                 return st, inbox, tel, watch, occs, counts
@@ -770,6 +903,9 @@ class MultiRaftEngine:
                     if phase is not None:  # its structure: None or arrays
                         with jax.named_scope("raft_phase"):
                             start = (cut(phase[2]),)
+                    if load is not None:  # likewise
+                        with jax.named_scope("raft_load"):
+                            start = (cut(load[0]), cut(load[1]))
                 t_st, t_inbox, t_tel, t_watch, occs, counts = tile_rounds(
                     lo, *mine, counts, *start)
                 with jax.named_scope("raft_tiles"):
@@ -785,11 +921,11 @@ class MultiRaftEngine:
                         paste(watch.history, t_watch.history))
                     return st, inbox, tel, watch, seen | occs, counts
 
-            lanes, ring = lanes
+            lanes, ring, *tally = lanes
             crossed = ()
             if placed:
                 lanes, crossed = lanes
-            counts = (crossed, ring)
+            counts = (crossed, ring, *tally)
             with jax.named_scope("raft_carry"):
                 inbox = split_lanes(inbox)
             # Tracing only. As the body of the loops the round takes
@@ -803,6 +939,9 @@ class MultiRaftEngine:
                 watch.counts, like(watch.read_floor), like(watch.history))
             # jitlint: waive(tracer-branch) -- as above: None or a tuple of arrays
             t_start = () if phase is None else (like(phase[2]),)
+            # jitlint: waive(tracer-branch) -- as above
+            if load is not None:
+                t_start = (like(load[0]), like(load[1]))
             with self._pretrace():
                 jax.eval_shape(
                     lambda ticks, props, carry, row, *start: tile_body(
@@ -817,8 +956,9 @@ class MultiRaftEngine:
                     *t_start)
             with jax.named_scope("raft_tiles"):
                 seen = jnp.zeros((rounds, NUM_OCC), bool)
-            st, inbox, tel, watch, seen, (crossed, ring) = jax.lax.fori_loop(
-                0, tiles, tile, (st, inbox, tel, watch, seen, counts))
+            st, inbox, tel, watch, seen, (crossed, ring, *tally) = (
+                jax.lax.fori_loop(
+                    0, tiles, tile, (st, inbox, tel, watch, seen, counts)))
             # Three blocks for two names, in the order the lines had
             # before they had names: the lowered text follows the order
             # of the lines, and JAX's cache key the text (the names are
@@ -832,7 +972,8 @@ class MultiRaftEngine:
                 if placed:
                     return (st, inbox, tel, (), ((lanes, crossed), ring),
                             st.commit[:1], watch)
-                return st, inbox, tel, (), (lanes, ring), st.commit[0], watch
+                return (st, inbox, tel, (), (lanes, ring, *tally),
+                        st.commit[0], watch)
 
         def placed_loop(st, inbox, ticks, props, tel, lanes, isolate,
                         rounds, control, watch):
@@ -861,7 +1002,8 @@ class MultiRaftEngine:
             return st, inbox, tel, (), (lanes, ring), fence, watch
 
         def closed_loop(st, inbox, ticks, props, tel, flt, lanes, isolate,
-                        rounds, control=None, watch=None, phase=None):
+                        rounds, control=None, watch=None, phase=None,
+                        load=None):
             # `isolate` is None (no fault: the scan is traced as it
             # always was) or the bool [rounds, R] node schedule, one
             # row a round as the scan's xs; `control` is None (the
@@ -869,7 +1011,10 @@ class MultiRaftEngine:
             # beside it, and `watch` the ScanWatch that rides the carry
             # with it. `phase` is None (the same again) or a phased
             # schedule's (edges [E], runs [E, cols], starts [G]), and
-            # `control` then the rounds' [rounds, 3] (PH_*).
+            # `control` then the rounds' [rounds, 3] (PH_*). `load` is
+            # None (the same once more) or a load plane's (update_thr
+            # [G], read_thr [G], seed), `control` then the rounds'
+            # numbers, [rounds], and `lanes` ends in the plane's counts.
             if placed:
                 return placed_loop(st, inbox, ticks, props, tel, lanes,
                                    isolate, rounds, control, watch)
@@ -879,16 +1024,28 @@ class MultiRaftEngine:
                 with jax.named_scope("raft_phase"):
                     phase = phase[:2] + (
                         jnp.repeat(phase[2], cfg.num_replicas),)
+            # jitlint: waive(tracer-branch) -- as above
+            if load is not None:
+                # A group's thresholds on each of its rows.
+                with jax.named_scope("raft_load"):
+                    load = (jnp.repeat(load[0], cfg.num_replicas),
+                            jnp.repeat(load[1], cfg.num_replicas), load[2])
             if tiles > 1:
                 return tiled_loop(st, inbox, ticks, props, tel, lanes,
-                                  isolate, rounds, control, watch, phase)
+                                  isolate, rounds, control, watch, phase,
+                                  load)
             slots = None
             # jitlint: waive(tracer-branch) -- None is an empty pytree: the branch is on the argument's structure at trace time, never on a device value
             if isolate is not None or control is not None:
                 with jax.named_scope("raft_carry"):
                     slots = jnp.arange(n, dtype=I32) % cfg.num_replicas
+            # jitlint: waive(tracer-branch) -- as above
+            if load is not None:
+                with jax.named_scope("raft_load"):
+                    load = load_plane(load, 0, slots)
             body = round_body(self._step, self._zeros_b, self._zeros_i,
-                              slots, ticks, props, tiled=False, phase=phase)
+                              slots, ticks, props, tiled=False, phase=phase,
+                              load=load)
             # Inbox and outbox ride the scan as K kind lanes, each an
             # array of its own (the round answers lanes with lanes),
             # and the inbox is stacked back once at the exit.
@@ -1040,10 +1197,7 @@ class MultiRaftEngine:
         if self.cfg.replace_replicas:
             add = jnp.concatenate(
                 [add, self._replace_events(pre, st, slots, wiped)])
-        low = watch.counts[:, 1] + add
-        counts = jnp.stack(
-            [watch.counts[:, 0] + (low >> _LIMB), low & ((1 << _LIMB) - 1)],
-            axis=1)
+        counts = _add_limbs(watch.counts, add)
         bits = (1 << jnp.arange(r, dtype=I32))[None, :]
         history = watch.history
         for name in HISTORY_FIELDS:
@@ -1218,6 +1372,11 @@ class MultiRaftEngine:
             raise ValueError(
                 "the control schedule offers a configuration change: "
                 "that needs a configuration with conf_entries")
+        self._ensure_watch()
+        return ctl.astype(np.int32)
+
+    def _ensure_watch(self) -> None:
+        """The ScanWatch, made by the first scan that carries one."""
         if self._watch is None:
             counts = (len(watch_names(self.cfg)), 2)
             if self._nodes is not None:  # a node counts its own rows
@@ -1226,7 +1385,6 @@ class MultiRaftEngine:
                 self._zeros(counts, I32),
                 self._zeros((self.cfg.num_instances,), I32),
                 self._zeros((self.cfg.num_instances,), jnp.uint32))
-        return ctl.astype(np.int32)
 
     def _asked(self, seen: np.ndarray, reads: int) -> dict:
         """The span's stats of the rows a call's instances read (of a
@@ -1294,12 +1452,60 @@ class MultiRaftEngine:
             started=int(kept["started"][done - 1]) if done else 0)
         return jnp.asarray(per_round), kept["device"], stats
 
+    def _load_schedule(self, load, rounds: int):
+        """A load plane (the LOAD_* comment at the top of this module)
+        for the `rounds` rounds from ``load_round`` on, which moves on:
+        (the rounds' numbers [rounds] and the plane's (update_thr,
+        read_thr, seed), both on the device; the span's stats, with
+        ``load_from``, the call's first load round). The plane's arrays
+        are kept from call to call while it is the same."""
+        if self._nodes is not None:
+            raise ValueError(
+                "a load plane reads the row's group, which an engine "
+                "placed over nodes does not widen yet (ROADMAP R1d): not "
+                "with nodes")
+        try:
+            update_thr, read_thr, seed = load
+        except (TypeError, ValueError):
+            raise ValueError(
+                "load must be (update_thr, read_thr, seed)") from None
+        thr = [np.asarray(update_thr), np.asarray(read_thr)]
+        for name, x in zip(("update_thr", "read_thr"), thr):
+            if x.shape != (self.cfg.num_groups,) or x.dtype != np.uint32:
+                raise ValueError(
+                    f"{name} must be uint32 [num_groups] = "
+                    f"{(self.cfg.num_groups,)}, got {x.dtype} {x.shape}")
+        if (not isinstance(seed, (int, np.integer))
+                or not 0 <= int(seed) <= LOAD_ALWAYS):
+            raise ValueError(
+                f"the load plane's seed must be an integer in [0, 2**32), "
+                f"got {seed!r}")
+        kept = self._load
+        if (kept is None or kept["seed"] != int(seed)
+                or not all(np.array_equal(a, b)
+                           for a, b in zip(kept["thr"], thr))):
+            self._load = kept = {
+                "thr": [x.copy() for x in thr], "seed": int(seed),
+                "device": (jnp.asarray(thr[0]), jnp.asarray(thr[1]),
+                           jnp.asarray(np.uint32(seed)))}
+        self._ensure_watch()
+        if self._tally is None:
+            self._tally = jnp.zeros((len(LOAD_COUNT_NAMES), 2), I32)
+        first = self.load_round
+        self.load_round += rounds
+        stats = dict(self._control_schedule(None, rounds)[1],
+                     load_from=first)
+        return (jnp.asarray(first + np.arange(rounds, dtype=np.int32)),
+                kept["device"], stats)
+
     def _scan(self, rounds: int, ticks, props, isolate, control=None,
-              starts=None):
+              starts=None, load=None):
         """One closed-loop scan enqueued; returns its scalar fence."""
         sched, isolated = self._schedule(isolate, rounds)
-        phase = None
-        if starts is None:
+        phase = plane = None
+        if load is not None:
+            ctl, plane, asked = self._load_schedule(load, rounds)
+        elif starts is None:
             ctl, asked = self._control_schedule(control, rounds)
         else:
             ctl, phase, asked = self._phased_schedule(control, starts, rounds)
@@ -1310,17 +1516,23 @@ class MultiRaftEngine:
         key = f"closed_loop/{self._serial}/{rounds}" + (
             "" if sched is None else "/isolate") + (
             "" if ctl is None else "/control") + (
-            "" if phase is None else f"/phased{len(phase[0])}")
+            "" if phase is None else f"/phased{len(phase[0])}") + (
+            "" if plane is None else "/load")
         with self._span("engine.run_rounds", rounds=rounds,
                         tiles=self._tiles, isolated=isolated,
                         **asked), warm_guard(key):
             watch = None if ctl is None else self._watch
             self.state, self.inbox, tel, flt, lanes, fence, watch = self._closed_loop(
                 self.state, self.inbox, ticks, props, self._tel(),
-                self._flt(), self._lanes, sched, rounds, ctl, watch,
-                *(() if phase is None else (phase,))
+                self._flt(),
+                self._lanes + (() if plane is None else (self._tally,)),
+                sched, rounds, ctl, watch,
+                *(() if phase is None else (phase,)),
+                **({} if plane is None else {"load": plane})
             )
-        self._lanes = lanes
+        if plane is not None:
+            self._tally = lanes[2]
+        self._lanes = lanes[:2]
         self._set_tel(tel)
         self._set_flt(flt)
         if ctl is not None:
@@ -1329,7 +1541,8 @@ class MultiRaftEngine:
 
     def run_rounds(self, rounds: int, tick: bool = True,
                    propose_n: Optional[jnp.ndarray] = None,
-                   isolate=None, control=None, starts=None) -> None:
+                   isolate=None, control=None, starts=None,
+                   load=None) -> None:
         """Closed-loop simulation of `rounds` rounds, faults and the
         control plane included, without leaving the device (one fused
         lax.scan program).
@@ -1356,11 +1569,30 @@ class MultiRaftEngine:
         stall mark are a round's, row ``t mod cycle_rounds``. A
         rebalancer that starts a batch of moves every few rounds is a
         `starts` with a batch's groups at each such round. Not with
-        ``nodes=``."""
+        ``nodes=``.
+        With `load`, ``(update_thr, read_thr, seed)``, every group is
+        offered its own updates and reads, drawn on the device round by
+        round (the LOAD_* comment at the top of this module): 0 to
+        ``max_props_per_round`` updates, one for each draw below
+        ``update_thr[g]``, and a read where the read's draw is below
+        ``read_thr[g]`` (uint32 [num_groups]; LOAD_ALWAYS: in every
+        round), in the rounds ``load_round`` to ``load_round + rounds -
+        1`` of this engine's load scans, which moves on. The scan
+        carries the ScanWatch as a controlled scan does and counts what
+        it offered (``load_counts``). The draws are the call's
+        proposals and its control plane: not with `propose_n`,
+        `control` or `starts`, and not with ``nodes=``."""
+        if load is not None:
+            for name, given in (("propose_n", propose_n),
+                                ("control", control), ("starts", starts)):
+                if given is not None:
+                    raise ValueError(
+                        f"a load plane draws what each group is offered "
+                        f"and asked: not with {name}")
         ticks = jnp.ones_like(self._zeros_b) if tick else self._zeros_b
         propose_n = self._place(propose_n)
         props = propose_n if propose_n is not None else self._zeros_i
-        self._scan(rounds, ticks, props, isolate, control, starts)
+        self._scan(rounds, ticks, props, isolate, control, starts, load)
 
     def run_rounds_pipelined(self, rounds: int, chunk: int = 16,
                              depth: int = 2, tick: bool = True,
@@ -1575,11 +1807,10 @@ class MultiRaftEngine:
         names = watch_names(self.cfg)
         if self._watch is None:
             return dict.fromkeys(names, 0)
-        c = np.asarray(self._watch.counts).astype(np.int64)
+        c = _limbs_total(self._watch.counts)
         if self._nodes is not None:  # a node counts its own rows
             c = c.sum(axis=0)
-        return {name: int((c[i, 0] << _LIMB) + c[i, 1])
-                for i, name in enumerate(names)}
+        return dict(zip(names, map(int, c)))
 
     def scan_history(self) -> np.ndarray:
         """[N] uint32: each instance's state after every round of every
@@ -1588,6 +1819,16 @@ class MultiRaftEngine:
         if self._watch is None:
             return np.zeros((self.cfg.num_instances,), np.uint32)
         return self.logical(self._watch.history)
+
+    def load_counts(self) -> dict:
+        """What the scans with a load plane offered since the engine
+        was built, by LOAD_COUNT_NAMES, a group and not a row: updates
+        offered, group-rounds in which a read was asked, group-rounds
+        in which either was. One host gather; no per-round sync."""
+        if self._tally is None:
+            return dict.fromkeys(LOAD_COUNT_NAMES, 0)
+        return dict(zip(LOAD_COUNT_NAMES,
+                        map(int, _limbs_total(self._tally))))
 
     def commits(self) -> np.ndarray:
         """Per-instance commit watermarks [G, R] — the host applies

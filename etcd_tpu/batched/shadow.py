@@ -154,13 +154,16 @@ def conf_change(code: int) -> ConfChangeV2:
 class _ReadView:
     """What the device's read lanes hold of one replica (read_seq,
     read_index, read_ready): one batch at a time, a request that finds
-    one in flight waits for it (step._control)."""
+    one in flight waits for it (step._control; `waiting` is the
+    device's ``read_req_latch``: a request asked in one round and not
+    in the next still opens the batch after the one in flight)."""
 
     def __init__(self) -> None:
         self.seq, self.index, self.ready = 0, -1, False
+        self.waiting = False
 
     def reset(self) -> None:
-        self.index, self.ready = -1, False
+        self.index, self.ready, self.waiting = -1, False, False
 
 
 class ShadowCluster:
@@ -579,16 +582,20 @@ class ShadowCluster:
     def _control_read(self, slot: int, asked: bool) -> None:
         """step._control's ReadIndex rule on plain RawNode.read_index:
         a leader that has committed in its term opens a batch at its
-        commit index when none is in flight; the ReadState raft yields
-        for its context confirms it."""
+        commit index when none is in flight, for a request of this
+        round or one that has waited since an earlier one; the
+        ReadState raft yields for its context confirms it."""
         node, view = self.nodes[slot], self.reads[slot]
         r = node.raft
         ctx = str(view.seq).encode()
         if any(rs.request_ctx == ctx for rs in r.read_states):
             view.ready = True
         pending = view.index >= 0 and not view.ready
+        asked = asked or view.waiting
+        view.waiting = False
         if not (asked and self._leads(slot) and not pending
                 and r.committed_entry_in_current_term()):
+            view.waiting = asked
             return
         view.seq += 1
         view.index, view.ready = r.raft_log.committed, False

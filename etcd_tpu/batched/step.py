@@ -1863,7 +1863,8 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, ring_read=None):
 # the devices agree on first: ``exchange_lanes``, ``agree_lanes``, the
 # ScanWatch's reduction over a group; for a phased control schedule,
 # each row's own round of the cycle and what it asks there:
-# ``engine.widen_phased``). The strings
+# ``engine.widen_phased``; for a load plane, each group's draws of the
+# round and their count: ``engine.draw_load``). The strings
 # are those of the named_scope calls,
 # letter for letter, and of the shape benchmark/reduce/trace.py files a
 # device op by (``raft_`` and lower-case letters; the innermost wins).
@@ -1885,6 +1886,7 @@ DEVICE_SCOPES = (
     ("closed-loop engine", "ici", "raft_ici"),
     ("closed-loop engine", "agree", "raft_agree"),
     ("closed-loop engine", "phase", "raft_phase"),
+    ("closed-loop engine", "load", "raft_load"),
 )
 
 # -----------------------------------------------------------------------------

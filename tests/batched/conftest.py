@@ -191,6 +191,13 @@ os.environ.setdefault("ETCD_TPU_TRANSFER_GUARD", "disallow")
 # configuration; the cases that force emit's bit clear
 # step._step_round_jit's cache to trace a key's round anew (the same key
 # string, counted once, as tests/benchmark/test_replace.py does).
+# ISSUE 47 AUDIT: still 49 of 50. test_scan_load builds every engine on
+# RC3 (the benchmark's `engine1m-r3` values at 8 groups; one of them
+# over three of the forced devices, for the refusal: another trace of
+# the same key), and tests/benchmark/test_load.py drives the cell
+# `engine1m-r3-zipf.ycsb-a`, whose sizes are `engine1m-r3`'s to the
+# digit: RC3 again. A load plane, like the schedules, is an input of the
+# closed-loop program and no key of the round step.
 ROUND_STEP_SHAPE_BUDGET = 50
 
 

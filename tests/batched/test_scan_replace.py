@@ -20,7 +20,6 @@ program at 8 groups) and ``RP4_MAJOR`` is this file's own (n-major,
 telemetry off).
 """
 
-import hashlib
 import json
 import os
 
@@ -43,6 +42,7 @@ from etcd_tpu.batched.state import (CONF_ADD_LEARNER, CONF_DEMOTE, CONF_LEAVE,
                                     init_state)
 from etcd_tpu.batched.telemetry import TM_INDEX
 
+from . import lowered_text
 from .test_differential import device_log, device_state
 from .test_scan_faults import COMMON, inbox_equal
 from .test_scan_reconf import (_fields_equal, _first, device_membership,
@@ -644,15 +644,21 @@ def _lowered(name):
 
 
 @pytest.mark.parametrize("name", sorted(PARENT_TEXT))
-def test_with_the_new_fields_off_the_round_is_the_parents_text(name):
+def test_with_the_new_fields_off_the_round_is_the_parents_text(name,
+                                                               tmp_path):
+    """(Where a digest differs, `lowered_text.held_to_the_pin` lowers
+    the program again in a fresh process, writes both texts under
+    `tmp_path` and says what differed: PERF.md section 7, "A digest
+    that moved".)"""
     cfg, one, loop = _lowered(name)
     assert not cfg.replace_replicas
-    got = tuple(hashlib.sha256(t.encode()).hexdigest() for t in (one, loop))
     if os.environ.get("ETCD_TPU_PRINT_ROUND_DIGESTS"):
-        print(name, got)
-    assert got == PARENT_TEXT[name], (
+        print(name, tuple(lowered_text.digest(t) for t in (one, loop)))
+    lowered_text.held_to_the_pin(
+        (one, loop), PARENT_TEXT[name], tmp_path,
+        ("tests.batched.test_scan_replace", "_lowered", name),
         "the lowered round or closed loop of a live configuration is not "
-        "the text it was at the commit that pinned it (PR 43)")
+        "the text it was at the commit that pinned it (PR 45)")
     assert control_cols(cfg) == 5 and watch_names(cfg) == WATCH_NAMES
 
 

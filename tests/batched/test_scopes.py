@@ -2,10 +2,12 @@
 
 ``step.DEVICE_SCOPES`` is the one registry of the device programs'
 ``jax.named_scope``s: the round's nine and the closed-loop engine's
-six (three of any engine, two of one placed over nodes, ISSUE 40: the
+seven (three of any engine, two of one placed over nodes, ISSUE 40: the
 exchange over the interconnect and what the nodes agree on first; one
 of a scan with a phased control schedule, ISSUE 42: each row's own
-round of the cycle, held to its scope in ``test_scan_phased.py``). A
+round of the cycle, held to its scope in ``test_scan_phased.py``; one
+of a scan with a load plane, ISSUE 47: each group's draws of the round,
+held to its scope in ``test_scan_load.py``). A
 profiler trace files a device op under the innermost
 ``raft_*`` name of its ``tf_op`` (``benchmark/reduce/trace.py``) and
 under ``unscoped`` where there is none; these tests hold every equation
@@ -178,12 +180,12 @@ def expected(eng: MultiRaftEngine, loop: bool) -> set:
 
 
 def test_the_registry_is_what_the_program_names():
-    assert len(set(SCOPES)) == len(SCOPES) == 15
+    assert len(set(SCOPES)) == len(SCOPES) == 16
     assert all(SCOPE_RE.fullmatch(s) for s in SCOPES)
     assert {layer for layer, _n, _s in step_mod.DEVICE_SCOPES} == {
         "round program", "closed-loop engine"}
     assert ENGINE == ("raft_tiles", "raft_watch", "raft_carry", "raft_ici",
-                      "raft_agree", "raft_phase")
+                      "raft_agree", "raft_phase", "raft_load")
     assert all(scope == "raft_" + name
                for _layer, name, scope in step_mod.DEVICE_SCOPES)
     # Every named_scope the two modules open is registered, and every
@@ -276,7 +278,8 @@ def test_a_scope_left_out_leaves_its_lines_bare(scope, monkeypatch):
     """Each of the engine scopes taken away in turn (its ``with`` a
     no-op): the lines it enclosed stand under no name, or under the
     wrong one, and the rule above fails. (``raft_phase`` is taken away
-    where a phased schedule is traced: ``test_scan_phased.py``.) The
+    where a phased schedule is traced, ``test_scan_phased.py``, and
+    ``raft_load`` where a load plane is, ``test_scan_load.py``.) The
     two of the node-placed
     loop on that loop: without its name the scan's exchange stands
     under none, and the watch's reduction over a group is filed with
