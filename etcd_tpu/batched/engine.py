@@ -26,8 +26,8 @@ beside it; or the scan draws what each group is offered itself
 seed, so that a few hot groups append in every round while most only
 heartbeat). Inside
 a scan the network moves only what
-was sent: inbox and outbox ride as six kind lanes (entries in the
-append lane alone, ``step.split_lanes``) and a round exchanges
+was sent: inbox and outbox ride as six kind lanes (each with the
+fields its messages use alone, ``step.LANE_FIELDS``) and a round exchanges
 the lanes some instance of the batch wrote (``step.route_lanes``, on the
 occupancy vector deliver's lane conds skip on); a lane nobody wrote
 holds what ``empty_msgs`` holds, ``valid`` false and every field zero,

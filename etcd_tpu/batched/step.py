@@ -131,7 +131,8 @@ for _t, _lane in (
 
 class MsgSlots(NamedTuple):
     """SoA message batch; every field has the same leading shape, plus
-    ent_terms with a trailing [E]."""
+    ent_terms with a trailing [E]. As a kind lane (``split_lanes``) it
+    holds None for the fields the lane does not carry (LANE_FIELDS)."""
 
     valid: jnp.ndarray  # bool
     type: jnp.ndarray  # i32
@@ -147,6 +148,33 @@ class MsgSlots(NamedTuple):
     # heartbeats/acks — ref: raft.go campaignTransfer, read_only.go ctx).
     ctx: jnp.ndarray  # i32
     ent_terms: jnp.ndarray  # i32 [..., E]
+
+
+# The fields each kind lane carries, beside NUM_REQ_KINDS' lane-order
+# contract and as static: what some message type of the lane states
+# (raftpb's MsgVote, MsgApp and MsgSnap, MsgHeartbeat and MsgTimeoutNow
+# and their responses state no field more), read off the writers, _emit
+# and the response builders of deliver. One table for every
+# configuration: KIND_APP keeps ``reject_hint`` and ``ctx`` (an
+# append's configuration mark with cfg.conf_entries, a snapshot's
+# configuration with cfg.replace_replicas) whether or not the flag is
+# on. Every other field of a lane is zero in every slot a writer makes,
+# so the lane form (``split_lanes``) holds None for it, an empty pytree
+# that no exchange, wipe, slice or carry touches, and a handler reads
+# the zero it always read (``_whole``). tests/batched/test_lane_fields.py
+# holds the table to the writers.
+LANE_FIELDS = (
+    ("valid", "type", "term", "log_term", "index", "ctx"),  # KIND_VOTE
+    ("valid", "type", "term", "log_term", "index", "commit", "reject_hint",
+     "n_ents", "ctx", "ent_terms"),  # KIND_APP
+    ("valid", "type", "term", "commit", "ctx"),  # KIND_HB
+    ("valid", "type", "term", "reject"),  # KIND_VOTE_RESP
+    ("valid", "type", "term", "log_term", "index", "reject",
+     "reject_hint"),  # KIND_APP_RESP
+    ("valid", "type", "term", "ctx"),  # KIND_HB_RESP
+)
+assert len(LANE_FIELDS) == NUM_KINDS and all(
+    set(fs) <= set(MsgSlots._fields) for fs in LANE_FIELDS)
 
 
 # Narrow storage dtype per bounded message lane (cfg.narrow_lanes),
@@ -167,9 +195,11 @@ NARROW_MSG_DTYPES = {
 
 
 def narrow_msgs(m: MsgSlots) -> MsgSlots:
-    """Cast the bounded message lanes to their narrow storage dtypes."""
+    """Cast the bounded message lanes to their narrow storage dtypes (of
+    the fields `m` carries: a lane holds None for the rest)."""
     return m._replace(**{
         f: getattr(m, f).astype(dt) for f, dt in NARROW_MSG_DTYPES.items()
+        if getattr(m, f) is not None
     })
 
 
@@ -177,6 +207,7 @@ def widen_msgs(m: MsgSlots) -> MsgSlots:
     """Cast narrow message lanes back to i32 for the round kernel."""
     return m._replace(**{
         f: getattr(m, f).astype(I32) for f in NARROW_MSG_DTYPES
+        if getattr(m, f) is not None
     })
 
 
@@ -203,33 +234,56 @@ def empty_msgs(shape: Tuple[int, ...], num_ents: int,
     return narrow_msgs(m) if narrow else m
 
 
-def _entries_in_app_alone(lanes, ents) -> Tuple[MsgSlots, ...]:
-    """`lanes` with `ents` for the ``ent_terms`` of every lane but
-    KIND_APP's."""
-    return tuple(
-        lanes[k] if k == KIND_APP else lanes[k]._replace(ent_terms=ents)
-        for k in range(NUM_KINDS))
+def _carried(k: int, m: MsgSlots) -> MsgSlots:
+    """`m` as lane `k` carries it: the fields of ``LANE_FIELDS[k]``,
+    None for the rest."""
+    return m._replace(**{f: None for f in MsgSlots._fields
+                         if f not in LANE_FIELDS[k]})
+
+
+def _whole(k: int, m: MsgSlots) -> MsgSlots:
+    """A message of lane `k` with every field: what the lane does not
+    carry is the zero a writer would have put there (no entries, as a
+    handler's own ``empty_msgs(..., 0)``). Constants, which the compiler
+    folds into whatever reads them."""
+    return empty_msgs(m.valid.shape, 0)._replace(
+        **{f: getattr(m, f) for f in LANE_FIELDS[k]})
 
 
 def split_lanes(m: MsgSlots) -> Tuple[MsgSlots, ...]:
-    """[N, R, K] slots as K kind lanes of [N, R]. Entries travel in
-    KIND_APP alone (emit writes no other lane's, no other handler reads
-    them), so only that lane keeps its ``ent_terms`` [N, R, E]; the
-    other five carry an ``ent_terms`` of no columns, [N, R, 0]: the
-    same NamedTuple, one tree.map over fields, and nothing to move."""
-    lanes = [jax.tree.map(lambda x, _k=k: x[:, :, _k], m)
-             for k in range(NUM_KINDS)]
-    return _entries_in_app_alone(lanes, lanes[KIND_APP].ent_terms[..., :0])
+    """[N, R, K] slots as K kind lanes of [N, R], each with the fields
+    of ``LANE_FIELDS`` alone (entries travel in KIND_APP, ``reject`` in
+    two response lanes, ...): no writer puts anything but zero in the
+    others, so nothing of an inbox a writer can make is lost, and what
+    a lane does not hold is not there to move."""
+    return tuple(
+        jax.tree.map(lambda x, _k=k: x[:, :, _k], _carried(k, m))
+        for k in range(NUM_KINDS))
 
 
 def stack_lanes(lanes: Tuple[MsgSlots, ...]) -> MsgSlots:
     """K kind lanes of [N, R] as [N, R, K] slots, the public form: the
-    ``ent_terms`` the five other lanes do not carry come back as the
-    zeros they always were."""
-    return jax.tree.map(
-        lambda *xs: jnp.stack(xs, axis=2),
-        *_entries_in_app_alone(
-            lanes, jnp.zeros_like(lanes[KIND_APP].ent_terms)))
+    fields a lane does not carry come back as the zeros they always
+    were (shape and dtype of a lane that carries the field)."""
+    def stacked(f):
+        xs = [getattr(lane, f) for lane in lanes]
+        zeros = jnp.zeros_like(next(x for x in xs if x is not None))
+        return jnp.stack([zeros if x is None else x for x in xs], axis=2)
+
+    return MsgSlots(*map(stacked, MsgSlots._fields))
+
+
+def lane_slot_bytes(num_ents: int) -> np.ndarray:
+    """[K] ints: the bytes of one slot of each kind lane as it is
+    carried and exchanged (``LANE_FIELDS`` and the fields' dtypes; E =
+    `num_ents` entries' terms in KIND_APP). Times the rows, the R
+    slots and the rounds a lane ran (``lane_rounds()``, between nodes
+    ``lane_exchanges()``) it is what a window's exchange moved."""
+    like = jax.eval_shape(lambda: empty_msgs((), num_ents))
+    return np.array([
+        sum(x.dtype.itemsize * x.size
+            for x in jax.tree.leaves(_carried(k, like)))
+        for k in range(NUM_KINDS)])
 
 
 # A batch's occupancy vector (``lane_occupancy``): the K kind lanes, then
@@ -495,8 +549,8 @@ def _leader_traffic_prelude(cfg, iid, slot, st1, m, from_slot):
 def _lane_app(cfg: BatchedConfig, iid, slot, st: BatchedState, m: MsgSlots,
               from_slot):
     """Lane KIND_APP: T_APP / T_SNAP (ref: raft.go:1475-1614). A
-    response carries no entries: its ``ent_terms`` has no columns, as
-    its lane's (split_lanes)."""
+    response carries no entries: its ``ent_terms`` has no columns, and
+    its lane none at all (LANE_FIELDS)."""
     no_resp = empty_msgs((), 0)
     st1, dead, lower = _term_gate(cfg, iid, slot, st, m, from_slot)
 
@@ -755,7 +809,7 @@ def _gather_msg(msgs: MsgSlots, at) -> MsgSlots:
 
 
 def _vec_lane_request(cfg: BatchedConfig, iid, slot, st: BatchedState,
-                      m: MsgSlots, handler, hb_lane: bool):
+                      m: MsgSlots, handler, k: int):
     """One request lane (KIND_APP / KIND_HB), vectorized: at most one
     in-protocol message can take effect per (instance, lane) per round
     (there is one leader per term, and only the highest term survives
@@ -765,7 +819,8 @@ def _vec_lane_request(cfg: BatchedConfig, iid, slot, st: BatchedState,
     against the post-winner state (ref: raft.go:885-905).
 
     Returns the state and the lane's answer in its SMALL form, (the
-    winner's response, the winner's sender, the losers' nudge mask):
+    winner's response with the fields its lane, `k` + NUM_REQ_KINDS,
+    carries, the winner's sender, the losers' nudge mask):
     ``_vec_request_resps`` widens it to the [R] response slots. The
     two are apart because this half runs under the lane's lax.cond:
     slots built inside a branch from per-instance scalars alone have
@@ -784,22 +839,24 @@ def _vec_lane_request(cfg: BatchedConfig, iid, slot, st: BatchedState,
         m.valid & ~at_w & (m.term < st2.term)
         & jnp.asarray(cfg.check_quorum or cfg.pre_vote)
     )
-    if hb_lane:
+    if k == KIND_HB:
         # A losing MsgTimeoutNow never draws a response
         # (ref: raft.go:885-905 applies to leader traffic only).
         nudge = nudge & (m.type != T_TIMEOUT_NOW)
-    return st2, (wresp, w, nudge)
+    return st2, (_carried(k + NUM_REQ_KINDS, wresp), w, nudge)
 
 
 def _vec_request_resps(cfg: BatchedConfig, st: BatchedState, answer,
-                       occupied) -> MsgSlots:
-    """[R] response slots of a request lane from ``_vec_lane_request``'s
-    answer and the post-lane state; an unoccupied lane answers nothing."""
+                       occupied, k: int) -> MsgSlots:
+    """[R] response slots of request lane `k` from
+    ``_vec_lane_request``'s answer and the post-lane state, as their
+    lane carries them; an unoccupied lane answers nothing."""
     wresp, w, nudge = answer
+    wresp = _whole(k + NUM_REQ_KINDS, wresp)
     r = cfg.num_replicas
     at_w = jnp.arange(r, dtype=I32) == w
     resp = empty_msgs((r,), 0)
-    return _sel(occupied, resp._replace(
+    return _carried(k + NUM_REQ_KINDS, _sel(occupied, resp._replace(
         valid=jnp.where(at_w, wresp.valid, nudge),
         type=jnp.where(at_w, wresp.type, T_APP_RESP),
         term=jnp.where(at_w, wresp.term, st.term),
@@ -810,7 +867,7 @@ def _vec_request_resps(cfg: BatchedConfig, st: BatchedState, answer,
         reject_hint=jnp.where(at_w, wresp.reject_hint, 0),
         n_ents=jnp.where(at_w, wresp.n_ents, 0),
         ctx=jnp.where(at_w, wresp.ctx, 0),
-    ), resp)
+    ), resp))
 
 
 def _vec_lane_vote(cfg: BatchedConfig, iid, slot, st: BatchedState,
@@ -885,7 +942,7 @@ def _vec_lane_vote(cfg: BatchedConfig, iid, slot, st: BatchedState,
                        jnp.broadcast_to(st2.term, (r,))),
         reject=jnp.where(is_vote, ~granted, ~grant_p),
     )
-    return st2, resp
+    return st2, _carried(KIND_VOTE_RESP, resp)
 
 
 def _vec_app_resp_effects(cfg: BatchedConfig, st: BatchedState,
@@ -1120,12 +1177,14 @@ def _vec_lane_hb_resp(cfg: BatchedConfig, iid, slot, st: BatchedState,
 
 def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
                         inbox: Tuple[MsgSlots, ...], lane_any=None):
-    """Deliver this instance's inbox, K kind lanes of [R] slots each:
-    lanes in kind order, each
+    """Deliver this instance's inbox, K kind lanes of [R] slots each
+    (as carried: a lane's LANE_FIELDS, or whole messages): lanes in
+    kind order, each
     lane one fold over the sender axis (the order contract is in the
     section comment above). Returns the state and the responses to the
     three request lanes, each a lane of [R] slots as the round hands it
-    on: the outbox's lanes ``k + NUM_REQ_KINDS``. No lax.scan sits
+    on, with the fields it carries: the outbox's lanes ``k +
+    NUM_REQ_KINDS``. No lax.scan sits
     anywhere in the round, so
     deliver→tick→control→propose→emit trace into ONE straight-line
     fused region, and the named_scope annotations (DEVICE_SCOPES)
@@ -1162,7 +1221,10 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
     them as it goes round the vote cond. With ``lane_any=None`` the two
     lanes keep their one cond each: under a mapped predicate a cond is a
     select, and a lane split in two would compute both halves."""
-    no_resp = empty_msgs((cfg.num_replicas,), 0)
+    # A lane enters its cond as it is carried and its handler sees whole
+    # messages: a field the lane does not carry is, inside the branch,
+    # the zero constant a writer would have stated (_whole).
+    no_resp = _carried(KIND_VOTE_RESP, empty_msgs((cfg.num_replicas,), 0))
     ringless = lambda stx: stx._replace(  # noqa: E731
         log_term=jnp.zeros((0,), I32))
 
@@ -1187,7 +1249,7 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
         sty, resp = jax.lax.cond(
             occupied(KIND_VOTE, m),
             lambda sty, mx: _vec_lane_vote(
-                cfg, iid, slot, sty, mx, last_term),
+                cfg, iid, slot, sty, _whole(KIND_VOTE, mx), last_term),
             lambda sty, mx: (sty, no_resp),
             ringless(stx), m,
         )
@@ -1196,13 +1258,13 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
     def request_cond(k, handler, pred, stx):
         # The cond holds the winner's handler; the [R] response slots
         # are widened after it (see _vec_lane_request on why).
-        no_answer = (empty_msgs((), 0),
+        no_answer = (_carried(k + NUM_REQ_KINDS, empty_msgs((), 0)),
                      jnp.zeros((), I32),
                      jnp.zeros((cfg.num_replicas,), bool))
         return jax.lax.cond(
             pred,
             lambda sty, mx: _vec_lane_request(
-                cfg, iid, slot, sty, mx, handler, hb_lane=k == KIND_HB),
+                cfg, iid, slot, sty, _whole(k, mx), handler, k),
             lambda sty, mx: (sty, no_answer),
             stx, inbox[k],
         )
@@ -1210,12 +1272,12 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
     def request(k, handler, stx):
         occ = occupied(k, inbox[k])
         stx, answer = request_cond(k, handler, occ, stx)
-        return stx, _vec_request_resps(cfg, stx, answer, occ)
+        return stx, _vec_request_resps(cfg, stx, answer, occ, k)
 
     def state_cond(k, fn, pred, stx):
         return jax.lax.cond(
             pred,
-            lambda sty, mx: fn(sty, mx),
+            lambda sty, mx: fn(sty, _whole(k, mx)),
             lambda sty, mx: sty,
             stx, inbox[k],
         )
@@ -1236,7 +1298,7 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
             KIND_HB, _lane_hb, occ & ton,
             sty._replace(log_term=stx.log_term))
         return stx, _vec_request_resps(
-            cfg, stx, _sel(ton, full, plain), occ)
+            cfg, stx, _sel(ton, full, plain), occ, KIND_HB)
 
     def heartbeat_resps(stx):
         whole = lambda s, m: _vec_lane_hb_resp(cfg, iid, slot, s, m)  # noqa: E731
@@ -1963,15 +2025,15 @@ def _route_jit(r: int):
 
 def _exchange_written(exchange, outbox, lane_any, lanes, stale):
     """Lane by lane, three ways: a lane somebody wrote is exchanged
-    (`exchange` of every field that has elements), one that held last
-    round's messages (`stale`) wiped, the rest left as they are."""
+    (`exchange` of every field it carries: None, an empty pytree, is no
+    operand of the switch), one that held last round's messages
+    (`stale`) wiped, the rest left as they are."""
     return tuple(
         jax.lax.switch(
             jnp.where(lane_any[k], 2, stale[k].astype(I32)),
             (lambda lane, ob: lane,
              lambda lane, ob: jax.tree.map(jnp.zeros_like, lane),
-             lambda lane, ob: jax.tree.map(
-                 lambda o: exchange(o) if o.size else o, ob)),
+             lambda lane, ob: jax.tree.map(exchange, ob)),
             lanes[k], outbox[k])
         for k in range(NUM_KINDS))
 
@@ -1999,8 +2061,8 @@ def route(cfg: BatchedConfig, outbox: MsgSlots, lane_any=None,
     ``lane_skip``). Given ``lane_any``, only the lanes somebody wrote
     are exchanged: ``stack_lanes`` of ``route_lanes`` of the outbox's
     ``split_lanes``, which see (its ``prev`` here is ``(inbox [N, R,
-    K], stale)``; like the round itself this carries ``ent_terms`` in
-    KIND_APP alone)."""
+    K], stale)``; like the round itself this carries a lane's
+    ``LANE_FIELDS`` alone and hands the rest back as zeros)."""
     # Lane indexes pass through untouched: by the inbox lane-order
     # contract (NUM_REQ_KINDS, top of module), emit writes requests
     # into lanes 0..NUM_REQ_KINDS-1 and the round
@@ -2018,7 +2080,7 @@ def route(cfg: BatchedConfig, outbox: MsgSlots, lane_any=None,
 def route_lanes(cfg: BatchedConfig, outbox: Tuple[MsgSlots, ...], lane_any,
                 prev=None) -> Tuple[MsgSlots, ...]:
     """route() by kind lane: outbox and inbox as K lanes of [N, R]
-    slots (``split_lanes``' form: entries in KIND_APP alone), what
+    slots (``split_lanes``' form: a lane's ``LANE_FIELDS`` alone), what
     ``step_round`` returns when handed lanes, what the engine's scan
     carries and what ``step_round`` takes as it is.
 
@@ -2072,14 +2134,15 @@ def exchange_lanes(outbox: Tuple[MsgSlots, ...], axis: str, lane_any=None,
     some device wrote it, wiped where it held last round's messages,
     left alone otherwise (``route_lanes``' three ways). With
     ``lane_any=None`` every lane is exchanged and nothing branches (the
-    eager round). Entries travel in KIND_APP alone, as ever."""
+    eager round). One collective a field a lane carries
+    (``LANE_FIELDS``): 36 where all six lanes run."""
     def swap(o):
         return jax.lax.all_to_all(o, axis, 1, 1)
 
     with jax.named_scope("raft_ici"):
         # jitlint: waive(tracer-branch) -- None is the argument left out, tested at trace time, never a device value
         if lane_any is None:
-            return jax.tree.map(lambda o: swap(o) if o.size else o, outbox)
+            return jax.tree.map(swap, outbox)
         # As in route_lanes: emit stays out of the branches.
         outbox = jax.lax.optimization_barrier(outbox)
         return _exchange_written(swap, outbox, lane_any, *prev)
@@ -2380,9 +2443,13 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
             # The response to sender s's request of kind k is slot s of
             # lane k + NUM_REQ_KINDS; it routes back by the same
             # exchange (the inbox lane-order contract, top of module).
+            # A lane leaves the row with the fields it carries: emit
+            # states the rest as the zeros they are (LANE_FIELDS), and
+            # dropped here, before the vmap returns, no [N, R] plane of
+            # them is ever made.
             out = out + req_resps
             out = tuple(
-                out[k]._replace(valid=out[k].valid & ~iso)
+                _carried(k, out[k]._replace(valid=out[k].valid & ~iso))
                 for k in range(NUM_KINDS))
             with jax.named_scope("raft_lease"):
                 # Quorum-evidence lease re-arm (BatchedState.lease_ticks):

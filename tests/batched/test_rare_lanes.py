@@ -312,11 +312,15 @@ def test_rare_rounds_is_zero_over_a_steady_run():
 # nothing anew. Re-pinned by PR 45 on its own text: the state carries
 # `own_from`, and `_maybe_commit` and `_control`'s committed-in-term
 # read it and not the ring, with or without the lane skip.
+# Re-pinned by PR 48 on its own text: handed slots the round splits
+# them into lanes of `step.LANE_FIELDS` alone, fills the rest with zero
+# constants for the handlers and stacks zeros back, with or without the
+# lane skip (a hosting member compiles its round anew once).
 NO_SKIP_TEXT = {
     "r3-wide-noskip":
-        "fe72165db8726279c1ee4e925063671dac01d115611e8bdd50527c4ab2a3465e",
+        "496360f53f3a72a54e79d06c7835d30f16997ec15fb9d36f8c907839a01498c7",
     "r5-narrow-noskip":
-        "89fe37f35261d9b423d439b360ee3dfbcb10b5c1a23793a557531dccc75516f4",
+        "d835c828844cba88ff803310487fc1181dacee1d37a5136f5b3a293da6ad9055",
 }
 
 

@@ -13,7 +13,10 @@ out as ``empty_msgs``, and the closed loop, which routes that way,
 equals single rounds that exchange every lane. The round hands its
 outbox on in the form it was handed its inbox (ISSUE 33): six kind
 lanes for lanes, entries in the append lane alone, and the scan's body
-never holds an [N, R, K] array; slots for slots, the same bits.
+never holds an [N, R, K] array; slots for slots, the same bits. A lane
+carries the fields its messages use and no other (ISSUE 48,
+``step.LANE_FIELDS``): the outboxes made here hold zero everywhere
+else, as every writer's do (``test_lane_fields.py`` holds that).
 """
 
 import re
@@ -32,6 +35,7 @@ from etcd_tpu.batched.step import (
     KIND_HB,
     KIND_HB_RESP,
     KIND_VOTE,
+    LANE_FIELDS,
     NARROW_MSG_DTYPES,
     NUM_KINDS,
     T_APP,
@@ -69,6 +73,13 @@ def transposing_oracle(groups: int, replicas: int, x: np.ndarray):
 
 
 def random_outbox(rng, groups: int, replicas: int, narrow: bool) -> MsgSlots:
+    """Random bits in every field of every lane that some message type
+    of the lane states, and zero in the rest, as a round makes it: no
+    writer puts anything else in a field outside `LANE_FIELDS` (entries
+    travel in the append lane alone, ISSUE 33; `reject` in two response
+    lanes, `commit` in the two leader lanes, ...: ISSUE 48), which is
+    why the lane form carries none of them and `route()` by lane hands
+    them back as zeros."""
     shape = (groups * replicas, replicas, NUM_KINDS)
 
     def field(name):
@@ -82,7 +93,13 @@ def random_outbox(rng, groups: int, replicas: int, narrow: bool) -> MsgSlots:
         return rng.integers(info.min, info.max, shape, dtype=np.int64,
                             endpoint=True).astype(dt)
 
-    return MsgSlots(**{f: jnp.asarray(field(f)) for f in MsgSlots._fields})
+    def written(name):
+        x = field(name)
+        live = np.array([name in fs for fs in LANE_FIELDS])
+        return np.where(live[:, None] if name == "ent_terms" else live,
+                        x, np.zeros_like(x))
+
+    return MsgSlots(**{f: jnp.asarray(written(f)) for f in MsgSlots._fields})
 
 
 @pytest.mark.parametrize("narrow", [False, True], ids=["wide", "narrow"])
@@ -108,16 +125,12 @@ OCCUPANCY = {
 
 
 def only_lanes(out: MsgSlots, lanes) -> MsgSlots:
-    """`out` as a round can make it, with nothing valid outside
-    `lanes`: the payload fields there stay, as emit leaves term, type
-    and commit in the request slots it does not send, and entries
-    travel in the append lane alone (every other lane's `ent_terms` is
-    zero, which is why the lane form carries none: ISSUE 33)."""
+    """`out` (`random_outbox`'s: zero in every field a lane does not
+    carry) with nothing valid outside `lanes`: the payload fields there
+    stay, as emit leaves term, type and commit in the request slots it
+    does not send."""
     keep = np.isin(np.arange(NUM_KINDS), lanes)
-    app = np.arange(NUM_KINDS) == KIND_APP
-    return out._replace(
-        valid=out.valid & jnp.asarray(keep),
-        ent_terms=jnp.where(jnp.asarray(app)[:, None], out.ent_terms, 0))
+    return out._replace(valid=out.valid & jnp.asarray(keep))
 
 
 def lane_any_of(out: MsgSlots):
@@ -389,7 +402,8 @@ def test_the_scans_body_holds_no_packed_outbox(name):
     (under `lanes_minor` the vmap's own arrays run [R, K, N]): emit
     makes lanes, route_lanes takes them, and the inbox is stacked once,
     after the scan. Exactly one [N, R, E] array is exchanged a round,
-    the append lane's `ent_terms`; the other five lanes carry none."""
+    the append lane's `ent_terms`, and of [N, R] planes the 35 that
+    `LANE_FIELDS` names; a lane carries no other field (ISSUE 48)."""
     cfg = SCANNED[name].validate().resolved()
     n, r, e = cfg.num_instances, cfg.num_replicas, cfg.max_ents_per_msg
     closed = _trace_scan(cfg).jaxpr.jaxpr
@@ -409,9 +423,10 @@ def test_the_scans_body_holds_no_packed_outbox(name):
         if routed and q.params.get("name") == "exchange":
             exchanged.append(q.invars[0].aval.shape)
     assert seen > 1000
-    fields = len(MsgSlots._fields) - 1
+    planes = sum(len(fs) for fs in LANE_FIELDS) - 1
+    assert planes == 35
     assert sorted(exchanged) == sorted(
-        [(n, r)] * (fields * NUM_KINDS) + [(n, r, e)]), exchanged
+        [(n, r)] * planes + [(n, r, e)]), exchanged
     # The stacked inbox is there all the same, outside the scan.
     outer = [v.aval.shape for q in closed.eqns for v in q.outvars
              if hasattr(v.aval, "shape")]
@@ -473,9 +488,11 @@ def test_lanes_in_lanes_out_equals_slots_in_slots_out(name):
         assert isinstance(slots_out[1], MsgSlots)
         assert isinstance(lanes_out[1], tuple) and all(
             isinstance(m, MsgSlots) for m in lanes_out[1])
-        assert [m.ent_terms.shape for m in lanes_out[1]] == [
-            (n, r, cfg.max_ents_per_msg if k == KIND_APP else 0)
-            for k in range(NUM_KINDS)]
+        for k, m in enumerate(lanes_out[1]):
+            assert [f for f, x in zip(MsgSlots._fields, m)
+                    if x is not None] == list(LANE_FIELDS[k]), k
+        assert lanes_out[1][KIND_APP].ent_terms.shape == (
+            n, r, cfg.max_ents_per_msg)
         _bits_equal(slots_out[0], lanes_out[0], ("state", t))
         _bits_equal(slots_out[2:], lanes_out[2:], ("frames", t))
         outbox = stack_lanes(lanes_out[1])

@@ -338,7 +338,10 @@ def test_a_line_moved_out_of_its_scope_fails_the_rule(name, monkeypatch):
 # two (the untiled pins moved with it), and by PR 45 on its own, because
 # the state gained `own_from`, `_maybe_commit` and `_control` read it and
 # no ring, and emit reads the ring in one branch of a cond on a bit the
-# round reduces between its two vmaps (the untiled pins moved with it): names
+# round reduces between its two vmaps (the untiled pins moved with it), and by
+# PR 48 on its own, because the lanes the tile loops slice, step and
+# paste carry the fields of `step.LANE_FIELDS` alone (the untiled pins
+# moved with it): names
 # are still not part of either text. The lowered
 # text holds no name of a scope, and JAX's persistent cache keys a
 # program with its names stripped: equal text here is a cache hit on the
@@ -349,11 +352,11 @@ def test_a_line_moved_out_of_its_scope_fails_the_rule(name, monkeypatch):
 # prints them); `test_scan_replace.py` pins the untiled texts.
 PARENT_TILED_TEXT = {
     "engine1m-r3": (
-        "02e6daa95038bc84d782c9e1e3b6ba6ebf0b408838041da68bc23ac29172213e",
-        "a06da0e7541dd9f754e58a096e623e661b6796236b2c08471a1656dff63a398b"),
+        "d04639c2f64e2fd2ea986faf3dba156ec2eba292898f84796fefeb80aed18c02",
+        "a749b97cca111be75bdc9d78a1fd111d518fbf6563870b0e16794a0b0f9ebfa0"),
     "engine512k-r3of4": (
-        "87a167684e8b85d82e3cd4dfc06363bcd8834df91445c4c0fd7bedd29b8520aa",
-        "2fee4c9ba7988abea82981bcf2359cdd7456ec5c364f1e1be9ca457ac6c75c33"),
+        "bba58cab42f3a738330e5f670f2e6963ff90b9f414eace92ab394db9cf11e73d",
+        "4ef3296553f187181c9245f561c94733b8600f2e5e2ab96cf7a26da038618e14"),
 }
 
 
@@ -374,7 +377,7 @@ def test_in_tiles_the_lowered_text_is_the_parents(name, monkeypatch):
         print(name, got)
     assert got == PARENT_TILED_TEXT[name], (
         "the lowered closed loop or eager round of a tiled configuration "
-        "is not the text it was at the commit that pinned it (PR 45)")
+        "is not the text it was at the commit that pinned it (PR 48)")
 
 
 # -- no sort in any live program (ISSUE 41) ----------------------------------------
