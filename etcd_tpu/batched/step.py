@@ -371,10 +371,13 @@ def _become_follower(cfg, st, iid, slot, term, lead) -> BatchedState:
     return st._replace(role=jnp.full_like(st.role, FOLLOWER), lead=lead)
 
 
-def _append_own(cfg: BatchedConfig, st: BatchedState, slot, n) -> BatchedState:
+def _append_own(cfg: BatchedConfig, st: BatchedState, slot, n,
+                cols: int = 0) -> BatchedState:
     """Leader appends n entries of its own term (ref: raft.go:621-642
-    appendEntry): ring write, self progress, maybe_commit."""
-    p = cfg.max_props_per_round
+    appendEntry): ring write, self progress, maybe_commit. The write is
+    `cols` term columns wide (static; n <= cols), max_props_per_round
+    where the caller names none (0): see _tick for the one that does."""
+    p = cols or cfg.max_props_per_round
     terms = jnp.full((p,), 1, I32) * st.term
     log = ring_write(st.log_term, st.last + 1, terms, n)
     last = st.last + n
@@ -411,8 +414,9 @@ def _vote_targets(st: BatchedState) -> jnp.ndarray:
     return st.voter | st.voter_out
 
 
-def _become_leader(cfg, st, iid, slot) -> BatchedState:
-    """ref: raft.go:724-758 (reset, self replicate, append empty entry)."""
+def _become_leader(cfg, st, iid, slot, cols: int = 0) -> BatchedState:
+    """ref: raft.go:724-758 (reset, self replicate, append empty entry).
+    `cols` is _append_own's."""
     st = _reset(cfg, st, iid, slot, st.term)
     r = st.match.shape[-1]
     peers = jnp.arange(r, dtype=I32)
@@ -427,7 +431,7 @@ def _become_leader(cfg, st, iid, slot) -> BatchedState:
         # The tail may hold a change nobody has applied: no new one
         # until all of it is (ref: raft.go becomeLeader).
         st = st._replace(conf=st.conf._replace(pending=st.last))
-    return _append_own(cfg, st, slot, jnp.asarray(1, I32))
+    return _append_own(cfg, st, slot, jnp.asarray(1, I32), cols)
 
 
 def _record_vote_and_tally(st: BatchedState, from_slot, granted):
@@ -443,10 +447,10 @@ def _record_vote_and_tally(st: BatchedState, from_slot, granted):
 
 
 def _campaign(cfg: BatchedConfig, st: BatchedState, iid, slot, pre: bool,
-              transfer: bool = False) -> BatchedState:
+              transfer: bool = False, cols: int = 0) -> BatchedState:
     """ref: raft.go:785-835; `pre`/`transfer` are static bools
     (config.pre_vote; campaignTransfer skips pre-vote and marks its
-    vote requests to pierce leader leases)."""
+    vote requests to pierce leader leases). `cols` is _append_own's."""
     if pre:
         # becomePreCandidate: no term bump, no vote change.
         st1 = st._replace(
@@ -463,9 +467,9 @@ def _campaign(cfg: BatchedConfig, st: BatchedState, iid, slot, pre: bool,
     won = res == VOTE_WON
     if pre:
         # Single-voter group: pre-vote win chains into the real election.
-        st_won = _campaign(cfg, st1, iid, slot, False)
+        st_won = _campaign(cfg, st1, iid, slot, False, cols=cols)
     else:
-        st_won = _become_leader(cfg, st1, iid, slot)
+        st_won = _become_leader(cfg, st1, iid, slot, cols)
     st_lost = st1._replace(
         send_vote_req=jnp.ones_like(st.send_vote_req),
         vote_req_is_pre=jnp.full_like(st.vote_req_is_pre, pre),
@@ -1419,7 +1423,16 @@ def _tick(cfg: BatchedConfig, iid, slot, st: BatchedState, do_tick,
     st1 = st1._replace(
         election_elapsed=jnp.where(fire & ~is_leader, 0, st1.election_elapsed)
     )
-    st_camp = _campaign(cfg, st1, iid, slot, cfg.pre_vote)
+    # A campaign that wins here (a single-voter group) appends ONE entry,
+    # so its ring write is one column wide: [N, W, 1] is a reshape of the
+    # ring and fuses into tick. The P-column write's [N, W, P] compare is
+    # the first ring-sized value after deliver, computed from the last
+    # lane cond's output alone, and XLA's conditional code motion may
+    # sink it into both branches of that cond (PERF.md section 6, PR 48
+    # and PR 49). Tick's alone: a campaign under a lane cond
+    # (_lane_hb's, _vec_lane_vote_resp's) needs the P-column write's
+    # reduce for the ring's layout (kernels.ring_write_masked).
+    st_camp = _campaign(cfg, st1, iid, slot, cfg.pre_vote, cols=1)
     return _sel(fire, st_camp, st1)
 
 

@@ -198,7 +198,18 @@ os.environ.setdefault("ETCD_TPU_TRANSFER_GUARD", "disallow")
 # `engine1m-r3-zipf.ycsb-a`, whose sizes are `engine1m-r3`'s to the
 # digit: RC3 again. A load plane, like the schedules, is an input of the
 # closed-loop program and no key of the round step.
-ROUND_STEP_SHAPE_BUDGET = 50
+# ISSUE 49 AUDIT: 50 used of 51. test_tick_campaign builds its engines
+# on test_scan_faults' CELL and R3_MAJOR and on test_scopes' five live
+# configurations at 8 groups: keys all (the P-column spelling it holds
+# the round against re-traces a key's round, the same key string).
+# test_ring_layout adds ONE: `engine64k-r3` at 256 groups (768 rows:
+# the smallest batch at which the TPU compiler sinks tick's ring
+# broadcast into the last deliver cond; at 8 groups it lays a 24-row
+# ring out ring-minor everywhere and sinks nothing), traced, lowered
+# and compiled for a described v5e twice (16 s) and never built for
+# the CPU. Budget 50 -> 51: raised by exactly the one, the headroom of
+# 1 kept.
+ROUND_STEP_SHAPE_BUDGET = 51
 
 
 @pytest.fixture(scope="session", autouse=True)

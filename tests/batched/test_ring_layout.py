@@ -16,6 +16,7 @@ and the compiles run in this process, with JAX's persistent cache off:
 a program compiled for a device that is not attached cannot be read
 back."""
 
+import contextlib
 import re
 
 import jax
@@ -40,15 +41,23 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture()
-def no_persistent_cache():
+@contextlib.contextmanager
+def persistent_cache_off():
     from jax.experimental.compilation_cache import compilation_cache
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture()
+def no_persistent_cache():
+    with persistent_cache_off():
+        yield
 
 
 def select_chain(log_term, start_index, terms, count):
@@ -246,3 +255,129 @@ def test_a_cond_whose_one_branch_only_reads_the_ring_keeps_it_n_minor(
     n_minor, ring_minor = rings(text)
     assert " conditional(" in text
     assert n_minor > 0 and ring_minor == 0
+
+
+# -- tick's campaign in a compiled closed loop (ISSUE 49) ---------------------------
+
+# The smallest batch at which the P-column spelling on tick's path shows
+# the sink: 768 rows (at the tests' 8 groups the compiler lays a 24-row
+# ring out ring-minor everywhere and sinks nothing). It showed at every
+# size compiled from 256 to 65,536 groups, so the control below stays.
+SINK_GROUPS = 256
+
+
+@pytest.fixture(scope="module")
+def tick_loops(one_chip):
+    """The compiled 64-round closed loop of `engine64k-r3`'s
+    configuration at SINK_GROUPS groups, as shipped ("one": tick's
+    campaign writes one ring column) and in the spelling before ISSUE
+    49 ("p"): (configuration, text, the text and the loop as
+    `tools/loop_cost.py` reads them) of each."""
+    import importlib.util
+    import os
+
+    from etcd_tpu.batched import BatchedConfig, MultiRaftEngine
+    from etcd_tpu.batched import step as step_mod
+
+    from .test_scopes import loop_args, sizes
+    from .test_tick_campaign import p_column_spelling
+
+    spec = importlib.util.spec_from_file_location(
+        "loop_cost", os.path.join(os.path.dirname(__file__), "..", "..",
+                                  "tools", "loop_cost.py"))
+    loop_cost = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(loop_cost)
+
+    shipped = step_mod._campaign
+    out = {}
+    try:
+        with persistent_cache_off():
+            for name, campaign in (("one", shipped),
+                                   ("p", p_column_spelling(shipped))):
+                step_mod._campaign = campaign
+                step_mod._step_round_jit.cache_clear()
+                eng = MultiRaftEngine(BatchedConfig(
+                    **dict(sizes("engine64k-r3"), num_groups=SINK_GROUPS)))
+                args = jax.tree.map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                                   sharding=one_chip)
+                    if hasattr(x, "shape") else x, loop_args(eng))
+                text = eng._closed_loop.lower(*args).compile().as_text()
+                out[name] = (eng.cfg, text, loop_cost._Text(text),
+                             loop_cost.read(text))
+    finally:
+        step_mod._campaign = shipped
+        step_mod._step_round_jit.cache_clear()
+    return out
+
+
+def _p_columns(cfg):
+    """The shape of a P-column ring write's outer compare, as the
+    compiled text prints it."""
+    return f"[{cfg.num_instances},{cfg.window},{cfg.max_props_per_round}]"
+
+
+def _last_deliver_cond(cfg, t, loop):
+    """(the cond, the lines of its not-taken branch, of its taken one):
+    the last conditional under `raft_deliver` that returns the ring."""
+    ring = f"s32[{cfg.num_instances},{cfg.window}]"
+    for cond in reversed(loop.conds):
+        if "raft_deliver" not in cond.op_name:
+            continue
+        line = next(x for x in t.computations[loop.body] if re.match(
+            rf"\s*(?:ROOT )?%{re.escape(cond.name)} = ", x))
+        if ring in line.split(" conditional(")[0]:
+            not_taken, taken = t._branches(line)
+            return cond, t.computations[not_taken], t.computations[taken]
+    raise AssertionError("no deliver cond returns the ring")
+
+
+def _columns(cfg, text, scope):
+    """How many values of `[N, W, P]` shape stand under a scope in the
+    whole text (a fusion's body is part of it)."""
+    return sum(_p_columns(cfg) in line.split(" = ", 1)[-1].split("(")[0]
+               and re.search(rf'op_name="[^"]*\({scope}\)', line) is not None
+               for line in text.splitlines())
+
+
+def test_the_last_deliver_cond_holds_nothing_of_ticks(tick_loops):
+    """What the one-column write is for: no ring-times-P value of
+    tick's exists, so conditional code motion has nothing to sink into
+    the cond the ring leaves deliver through; its not-taken branch
+    hands its operands on and prices 0 cycles."""
+    cfg, text, t, loop = tick_loops["one"]
+    cond, not_taken, taken = _last_deliver_cond(cfg, t, loop)
+    assert cond.branches[0] == 0, cond
+    assert not [x for x in not_taken + taken if "raft_tick" in x]
+    assert _columns(cfg, text, "raft_tick") == 0
+    assert loop.ring_n_minor > 0 and loop.ring_ring_minor == 0
+
+
+def test_a_campaign_under_a_lane_cond_still_writes_p_columns(tick_loops):
+    """The fork is tick's alone: `_become_leader` under the VOTE_RESP
+    lane's cond and the transfer campaign under the HB lane's keep the
+    P-column write, whose reduce is what lays the ring out N-minor
+    inside a branch (the first case of this file; ROADMAP S12 (a): one
+    column on every campaign path compiled all eight loops with the
+    ring ring-minor)."""
+    cfg, text, t, loop = tick_loops["one"]
+    assert _columns(cfg, text, "raft_deliver") > 0
+    assert loop.ring_ring_minor == 0
+    assert len([c for c in loop.conds if "raft_deliver" in c.op_name]) == 8
+
+
+def test_the_p_column_spelling_on_ticks_path_is_sunk_into_that_cond(
+        tick_loops):
+    """The control. With P columns on tick's path the `[N, W, P]`
+    broadcast of the write stands in the not-taken branch of the last
+    deliver cond, materialised in every round whichever branch runs.
+    If this fails the compiler has stopped sinking it (or sinks it
+    elsewhere: read every cond with `tools/loop_cost.py`), and the
+    case above no longer shows what `cols=1` buys."""
+    cfg, text, t, loop = tick_loops["p"]
+    cond, not_taken, taken = _last_deliver_cond(cfg, t, loop)
+    sunk = [x for x in not_taken
+            if "raft_tick" in x and _p_columns(cfg) in x]
+    assert sunk and cond.branches[0] > 0, cond
+    assert _columns(cfg, text, "raft_tick") > 0
+    assert loop.ring_ring_minor == 0
