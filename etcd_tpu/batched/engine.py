@@ -88,9 +88,10 @@ from .compile_cache import enable_compile_cache
 _ENGINE_SERIAL = itertools.count()
 from .state import (CANDIDATE, CONF_SWAP, LEADER, PRECANDIDATE, REPLICATE,
                     BatchedConfig, BatchedState, I32, conf_decode, init_state)
-from .step import (MsgSlots, NUM_KINDS, NUM_OCC, agree_lanes, empty_msgs,
-                   exchange_lanes, lane_occupancy, make_step_round, route,
-                   route_lanes, split_lanes, stack_lanes)
+from .step import (KIND_APP, MsgSlots, NUM_KINDS, NUM_OCC, T_APP, agree_lanes,
+                   empty_msgs, exchange_lanes, lane_occupancy,
+                   make_step_round, route, route_lanes, split_lanes,
+                   stack_lanes)
 
 
 # Columns of a scan's control schedule, int32 [rounds, CTL_COLS], one
@@ -196,6 +197,44 @@ def _limbs_total(counts) -> np.ndarray:
     """[..., 2] two-limb counts as int64 [...]."""
     c = np.asarray(counts).astype(np.int64)
     return (c[..., 0] << _LIMB) + c[..., 1]
+
+
+# What the closed loop counts of catch-up, a round, in its carry (with
+# cfg.log_runs; ``catchup_counts``): instance-rounds in which a replica
+# that is not cut off stands more than E below its group's commit;
+# appends that leave with their previous index more than E below the
+# commit they state (to a peer not yet level); the entries those carry.
+CATCHUP_NAMES = ("behind_rounds", "catchup_appends", "catchup_entries")
+
+
+def group_max(x, slots, r: int):
+    """[N]: the largest `x` among the R rows of each row's group (rows
+    ``g * R + s``, `slots` each row's s), on N whole: the R - 1 rows
+    either side, shifted and masked to the row's own group (route()'s
+    idiom; no [G, R] reshape takes N out of the lanes)."""
+    n = x.shape[0]
+    xp = jnp.pad(x, (r - 1, r - 1))
+    out = x
+    for d in range(1 - r, r):
+        if d:
+            inside = (slots + d >= 0) & (slots + d < r)
+            out = jnp.where(
+                inside, jnp.maximum(out, xp[r - 1 + d:r - 1 + d + n]), out)
+    return out
+
+
+def catchup_round(cfg: BatchedConfig, st, appends: MsgSlots, iso, slots):
+    """[3] int32, CATCHUP_NAMES of one round: the state after
+    it, the append lane of its outbox as it leaves (a row cut off sends
+    nothing) and the rows cut off in it."""
+    e = cfg.max_ents_per_msg
+    behind = ~iso & (
+        group_max(st.commit, slots, cfg.num_replicas) - st.commit > e)
+    deep = (appends.valid & (appends.type == T_APP)
+            & (appends.index + e < appends.commit))
+    return jnp.stack([
+        jnp.sum(behind.astype(I32)), jnp.sum(deep.astype(I32)),
+        jnp.sum(jnp.where(deep, appends.n_ents.astype(I32), 0))])
 
 
 def control_cols(cfg: BatchedConfig) -> int:
@@ -409,6 +448,11 @@ class MultiRaftEngine:
                 raise ValueError(
                     "fleet_summary reduces across all rows of one device: "
                     "not with nodes")
+            if cfg.log_runs:
+                raise ValueError(
+                    "log_runs counts a replica's catch-up against its "
+                    "group's commit, a reduce over a group's rows on one "
+                    "device: not with nodes")
             mesh = Mesh(np.asarray(nodes), (NODE_AXIS,))
             # Of a per-instance array in placed order, and of what
             # every node holds whole.
@@ -579,6 +623,14 @@ class MultiRaftEngine:
         self.load_round = 0
         self._load: Optional[dict] = None
         self._tally = None
+        # What the scans of a configuration with log_runs counted of
+        # catch-up (catchup_counts()): CATCHUP_NAMES in two
+        # limbs, in the carry where a load plane's counts ride (the two
+        # do not meet: `_scan`), and the last row of the last fault
+        # schedule, for the nodes a call heals in its first round.
+        self._catchup = (jnp.zeros((len(CATCHUP_NAMES), 2), I32)
+                         if cfg.log_runs else None)
+        self._cut_last = np.zeros((r,), bool)
         # In-device telemetry accumulator (cfg.telemetry): per-instance
         # counter totals + OR-folded invariant bitmaps, accumulated
         # inside the closed-loop scan with no per-round host sync.
@@ -759,6 +811,10 @@ class MultiRaftEngine:
                     )
                     st, outbox = out[:2]
                     ring = ring + out[-1]  # emit's bit, the round's last
+                if cfg.log_runs:
+                    with jax.named_scope("raft_watch"):
+                        tally = [_add_limbs(tally[0], catchup_round(
+                            cfg, st, outbox[KIND_APP], iso, slots))]
                 # jitlint: waive(tracer-branch) -- as above
                 if ctl is not None:
                     with jax.named_scope("raft_watch"):
@@ -1036,7 +1092,7 @@ class MultiRaftEngine:
                                   load)
             slots = None
             # jitlint: waive(tracer-branch) -- None is an empty pytree: the branch is on the argument's structure at trace time, never on a device value
-            if isolate is not None or control is not None:
+            if isolate is not None or control is not None or cfg.log_runs:
                 with jax.named_scope("raft_carry"):
                     slots = jnp.arange(n, dtype=I32) % cfg.num_replicas
             # jitlint: waive(tracer-branch) -- as above
@@ -1344,6 +1400,19 @@ class MultiRaftEngine:
                 f"{(rounds, self.cfg.num_replicas)}, got {sched.shape}")
         return self._on_nodes(sched), int(sched.sum())
 
+    def _heals(self, isolate, rounds: int) -> int:
+        """Nodes a call heals: cut off in one round of the scans'
+        schedules and not in the next, the last call's last round and
+        this call's first among them (a call without a schedule cuts
+        nobody)."""
+        sched = (np.zeros((rounds, self.cfg.num_replicas), bool)
+                 if isolate is None else np.asarray(isolate, bool))
+        if not len(sched):
+            return 0
+        before = np.concatenate([self._cut_last[None], sched[:-1]])
+        self._cut_last = sched[-1].copy()
+        return int((before & ~sched).sum())
+
     def _control_schedule(self, control, rounds: int):
         """(device schedule or None, span stats) of a call's control
         plane."""
@@ -1502,7 +1571,12 @@ class MultiRaftEngine:
               starts=None, load=None):
         """One closed-loop scan enqueued; returns its scalar fence."""
         sched, isolated = self._schedule(isolate, rounds)
+        healed = self._heals(isolate, rounds)
         phase = plane = None
+        if load is not None and self.cfg.log_runs:
+            raise ValueError(
+                "a load plane's counts and log_runs' ride one place of the "
+                "scan's carry: not with log_runs")
         if load is not None:
             ctl, plane, asked = self._load_schedule(load, rounds)
         elif starts is None:
@@ -1519,19 +1593,22 @@ class MultiRaftEngine:
             "" if phase is None else f"/phased{len(phase[0])}") + (
             "" if plane is None else "/load")
         with self._span("engine.run_rounds", rounds=rounds,
-                        tiles=self._tiles, isolated=isolated,
+                        tiles=self._tiles, isolated=isolated, healed=healed,
                         **asked), warm_guard(key):
             watch = None if ctl is None else self._watch
             self.state, self.inbox, tel, flt, lanes, fence, watch = self._closed_loop(
                 self.state, self.inbox, ticks, props, self._tel(),
                 self._flt(),
-                self._lanes + (() if plane is None else (self._tally,)),
+                self._lanes + (() if plane is None else (self._tally,))
+                + (() if self._catchup is None else (self._catchup,)),
                 sched, rounds, ctl, watch,
                 *(() if phase is None else (phase,)),
                 **({} if plane is None else {"load": plane})
             )
         if plane is not None:
             self._tally = lanes[2]
+        if self._catchup is not None:
+            self._catchup = lanes[2]
         self._lanes = lanes[:2]
         self._set_tel(tel)
         self._set_flt(flt)
@@ -1829,6 +1906,16 @@ class MultiRaftEngine:
             return dict.fromkeys(LOAD_COUNT_NAMES, 0)
         return dict(zip(LOAD_COUNT_NAMES,
                         map(int, _limbs_total(self._tally))))
+
+    def catchup_counts(self) -> dict:
+        """What the scans of a configuration with log_runs counted of
+        catch-up since the engine was built, by CATCHUP_NAMES
+        (all zero without the field). One host gather; no per-round
+        sync."""
+        if self._catchup is None:
+            return dict.fromkeys(CATCHUP_NAMES, 0)
+        return dict(zip(CATCHUP_NAMES,
+                        map(int, _limbs_total(self._catchup))))
 
     def commits(self) -> np.ndarray:
         """Per-instance commit watermarks [G, R] — the host applies

@@ -163,9 +163,13 @@ def find_conflict_by_term(
     return jnp.where(cnt > 0, snap_index + cnt, floor)
 
 
-def invariant_bits(st, slot) -> jnp.ndarray:
+def invariant_bits(st, slot, window=None) -> jnp.ndarray:
     """Per-instance illegal-state bitmap (bit layout:
-    telemetry.INV_NAMES), computed on end-of-round state.
+    telemetry.INV_NAMES), computed on end-of-round state. `window` is
+    the log's capacity where ``st.log_term`` is no ring of that many
+    slots (BatchedConfig.log_runs: the run table), and the map then
+    ends in one bit more: the floor above the applied index, where a
+    full run table gave away entries that had yet to be applied.
 
     Everything here is impossible under the raft model — a set bit
     means either a kernel bug or a violated environment assumption
@@ -177,6 +181,10 @@ def invariant_bits(st, slot) -> jnp.ndarray:
     # existing layering for its scalar-oracle consumers).
     leader, probe, snapshot = 2, 0, 2
     r = st.match.shape[-1]
+    capacity = window
+    # jitlint: waive(tracer-branch) -- None is the argument left out (a static int otherwise), tested at trace time, never a device value
+    if window is None:
+        capacity = st.log_term.shape[-1]
     peers = jnp.arange(r, dtype=I32)
     is_leader = st.role == leader
     tracked = (st.voter | st.voter_out | st.learner) & (peers != slot)
@@ -219,7 +227,7 @@ def invariant_bits(st, slot) -> jnp.ndarray:
         # unreachable; a trip means log-lifecycle pressure accounting
         # broke (wrap = silent log corruption, the worst failure the
         # ring representation admits).
-        (st.last - st.snap_index) > st.log_term.shape[-1],
+        (st.last - st.snap_index) > capacity,
         # leader-lease residue on a non-leader: the lease lane
         # authorizes quorum-free linearizable reads, so every
         # step-down path must zero it in the same round (step.py's
@@ -228,6 +236,9 @@ def invariant_bits(st, slot) -> jnp.ndarray:
         # fast path admits.
         (st.lease_ticks > 0) & ~is_leader,
     ]
+    # jitlint: waive(tracer-branch) -- as above
+    if window is not None:
+        bad.append(st.snap_index > st.applied)
     bits = jnp.zeros((), I32)
     for i, b in enumerate(bad):
         bits = bits | (b.astype(I32) << i)

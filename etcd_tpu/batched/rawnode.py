@@ -236,6 +236,12 @@ class BatchedRawNode:
         # closed-loop engine pick the same compiled round program for
         # one logical config.
         self.cfg = cfg = cfg.validate().resolved()
+        if cfg.log_runs:
+            raise ValueError(
+                "log_runs on the hosting path: a member restores, persists "
+                "and reads back each row's log as a ring of terms (the d2h "
+                "extract, RowRestore); the run table is the closed-loop "
+                "engine's")
         from .compile_cache import enable_compile_cache
 
         enable_compile_cache()

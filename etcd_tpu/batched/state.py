@@ -41,6 +41,12 @@ MAX_HASHED_TIMEOUT = 46340
 # A snapshot that states its configuration (cfg.replace_replicas) packs
 # two masks into one int32, 16 bits each, the sign bit left alone.
 MAX_SNAPSHOT_SLOTS = 15
+# The most terms held as rings, all replicas' together (1 GiB of
+# int32; BatchedConfig.log_runs holds any window): every ring operation
+# is a pass over all W slots of every row, an append's write a
+# [N, W, E] compare. The live cells' rings are 0.3 to 100 M terms
+# (W = 32), a hosted single-group member's 32,768 a row of a few rows.
+MAX_RING_TERMS = 1 << 28
 
 
 class BatchedConfig(NamedTuple):
@@ -153,6 +159,23 @@ class BatchedConfig(NamedTuple):
     # `conf_entries`: off, the compiled round and closed loop are the
     # programs they were.
     replace_replicas: bool = False
+    # The log as term runs (see batched/termlog.py): K > 0 holds each
+    # replica's log as K (start index, term) runs, [N, 2, K], where the
+    # ring holds a term an entry, [N, W]. A log's terms never decrease
+    # with the index, so its runs say all the ring says, every question
+    # the round asks of the log is an elementwise pass over K whatever
+    # the window, and `window` is then what the ring's size stood for:
+    # the entries a replica may hold above its floor, of which
+    # auto_compact keeps window // 2 behind the applied index (etcd's
+    # SnapshotCatchUpEntries, 5,000, at window 10,240). A run lives in
+    # slot term mod K; where a new term's slot holds a run the window
+    # still needs the floor moves up past that run (a shorter tail is
+    # always legal), and where that would pass `applied` the invariant
+    # bitmap says so (telemetry.INV_NAMES, runs_passed_applied).
+    # Static, default 0 (off), the contract of `telemetry` and
+    # `conf_entries`: off, the state, the compiled round and the closed
+    # loop are what they were.
+    log_runs: int = 0
 
     @property
     def num_instances(self) -> int:
@@ -189,6 +212,30 @@ class BatchedConfig(NamedTuple):
                 f"num_replicas={self.num_replicas} with replace_replicas: a "
                 "snapshot states its four membership masks as two 16-bit "
                 f"halves of two int32 fields (1..{MAX_SNAPSHOT_SLOTS})")
+        if self.log_runs < 0:
+            raise ValueError(f"log_runs={self.log_runs} must be >= 0")
+        if (self.num_instances * self.window > MAX_RING_TERMS
+                and not self.log_runs):
+            raise ValueError(
+                f"window={self.window} over {self.num_instances} replicas "
+                f"without log_runs: a ring of more than {MAX_RING_TERMS} "
+                "terms, and every ring operation a pass over all of it "
+                "(an append's write a [N, W, E] compare); hold a log that "
+                "deep as term runs (log_runs)")
+        if self.log_runs and self.max_ents_per_msg > self.window:
+            raise ValueError(
+                f"max_ents_per_msg={self.max_ents_per_msg} exceeds "
+                f"window={self.window}")
+        if self.log_runs and self.fleet_summary:
+            raise ValueError(
+                "log_runs with fleet_summary: the fleet frame's "
+                "ring-pressure histogram is bucketed for a ring of terms; "
+                "no frame reads the run table yet")
+        if self.log_runs and self.conf_entries:
+            raise ValueError(
+                "log_runs with conf_entries: a configuration change's mark "
+                "is held to the ring's truncation by the differential "
+                "tests, and none runs it over term runs yet")
         et = self.election_timeout
         if et < 1 or (et > MAX_HASHED_TIMEOUT and et & (et - 1)):
             raise ValueError(
@@ -316,7 +363,9 @@ class BatchedState(NamedTuple):
     lead: jnp.ndarray  # [N] i32, slot + 1; 0 = None
 
     # Log (ref: raft/log.go raftLog) — ring of terms plus watermarks.
-    log_term: jnp.ndarray  # [N, W] i32; term of entry i at ring slot i % W
+    # [N, W] i32; term of entry i at ring slot i % W. With cfg.log_runs
+    # [N, 2, K] i32: the run table (termlog.py), starts then terms.
+    log_term: jnp.ndarray
     snap_index: jnp.ndarray  # [N] i32: index covered by snapshot (= first-1)
     snap_term: jnp.ndarray  # [N] i32
     last: jnp.ndarray  # [N] i32: last log index
@@ -524,7 +573,9 @@ def init_state(cfg: BatchedConfig, start_index: int = 0,
         vote=zeros_n(),
         role=jnp.full((n,), FOLLOWER, I32),
         lead=zeros_n(),
-        log_term=jnp.zeros((n, w), I32),
+        # The ring, or with cfg.log_runs the run table (all zero: no run).
+        log_term=jnp.zeros(
+            (n, 2, cfg.log_runs) if cfg.log_runs else (n, w), I32),
         snap_index=start(),
         snap_term=jnp.where(start0 > 0, jnp.ones((n,), I32), zeros_n()),
         last=start(),

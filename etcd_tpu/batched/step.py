@@ -41,18 +41,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import termlog
 from .kernels import (
     VOTE_LOST,
     VOTE_WON,
-    find_conflict_by_term,
     invariant_bits,
     joint_committed,
     joint_vote_result,
     log_bucket_counts,
     log_bucket_counts_masked,
-    ring_write,
-    ring_write_masked,
-    term_at,
 )
 from ..analysis.sentinels import note_compile_key
 from ..obs.fleet import FLEET_BUCKETS, FleetLayout
@@ -374,18 +371,17 @@ def _become_follower(cfg, st, iid, slot, term, lead) -> BatchedState:
 def _append_own(cfg: BatchedConfig, st: BatchedState, slot, n,
                 cols: int = 0) -> BatchedState:
     """Leader appends n entries of its own term (ref: raft.go:621-642
-    appendEntry): ring write, self progress, maybe_commit. The write is
-    `cols` term columns wide (static; n <= cols), max_props_per_round
-    where the caller names none (0): see _tick for the one that does."""
-    p = cols or cfg.max_props_per_round
-    terms = jnp.full((p,), 1, I32) * st.term
-    log = ring_write(st.log_term, st.last + 1, terms, n)
+    appendEntry): the log's write, self progress, maybe_commit. The
+    ring's write is `cols` term columns wide (static; n <= cols),
+    max_props_per_round where the caller names none (0): see _tick for
+    the one that does."""
+    st = termlog.append_own(cfg, st, n, cols or cfg.max_props_per_round)
     last = st.last + n
     r = st.match.shape[-1]
     peers = jnp.arange(r, dtype=I32)
     match = jnp.where(peers == slot, jnp.maximum(st.match, last), st.match)
     nxt = jnp.where(peers == slot, jnp.maximum(st.next, last + 1), st.next)
-    st = st._replace(log_term=log, last=last, match=match, next=nxt)
+    st = st._replace(last=last, match=match, next=nxt)
     return _maybe_commit(st)
 
 
@@ -637,7 +633,7 @@ def _handle_append(cfg: BatchedConfig, st: BatchedState, m: MsgSlots):
         term=st.term,
     )
 
-    ta = lambda i: term_at(st.log_term, st.snap_index, st.snap_term, st.last, i)
+    ta = lambda i: termlog.term_at(cfg, st, i)  # noqa: E731
     match_ok = ta(prev) == m.log_term
 
     j = jnp.arange(e, dtype=I32)
@@ -649,11 +645,12 @@ def _handle_append(cfg: BatchedConfig, st: BatchedState, m: MsgSlots):
     ci = jnp.argmax(conflict)  # first conflicting offset
 
     write_mask = have & (j >= ci) & any_conflict
-    log = ring_write_masked(st.log_term, prev + 1, m.ent_terms, write_mask)
+    st_ok = termlog.append_entries(
+        cfg, st, prev, m.ent_terms, write_mask, ci, any_conflict)
     last = jnp.where(any_conflict, prev + m.n_ents, st.last)
     lastnewi = prev + m.n_ents
     commit = jnp.maximum(st.commit, jnp.minimum(m.commit, lastnewi))
-    st_ok = st._replace(log_term=log, last=last, commit=commit)
+    st_ok = st_ok._replace(last=last, commit=commit)
     if cfg.conf_entries:
         # The append says which of its entries is a configuration
         # change (emit: reject_hint its index, ctx its code). A mark at
@@ -674,9 +671,7 @@ def _handle_append(cfg: BatchedConfig, st: BatchedState, m: MsgSlots):
 
     # Reject with a term-skipping hint (ref: raft.go:1487-1509).
     hint0 = jnp.minimum(prev, st.last)
-    hint = find_conflict_by_term(
-        st.log_term, st.snap_index, st.snap_term, st.last, hint0, m.log_term
-    )
+    hint = termlog.find_conflict(cfg, st, hint0, m.log_term)
     resp_rej = no_resp._replace(
         valid=True,
         type=jnp.asarray(T_APP_RESP, I32),
@@ -721,8 +716,7 @@ def _handle_snapshot(cfg: BatchedConfig, st: BatchedState, m: MsgSlots,
     refuses a snapshot whose configuration does not hold it."""
     no_resp = empty_msgs((), 0)
     ignore = m.index <= st.commit
-    ta = lambda i: term_at(st.log_term, st.snap_index, st.snap_term, st.last, i)
-    fast_forward = ta(m.index) == m.log_term
+    fast_forward = termlog.term_at(cfg, st, m.index) == m.log_term
 
     st_ff = st._replace(commit=jnp.maximum(st.commit, m.index))
     st_restore = st._replace(
@@ -799,14 +793,26 @@ def _argfirst(mask):
     return jnp.argmax(mask).astype(I32)
 
 
-def _gather_msg(msgs: MsgSlots, at) -> MsgSlots:
+def _gather_msg(msgs: MsgSlots, at, chain: bool = False) -> MsgSlots:
     """msgs[w] for a traced winner index, as one-hot compare+reduce per
     field (at = senders == w): traced-index gathers serialize on TPU,
-    one-hot reads don't (the _pick_b discipline, tree-wide)."""
+    one-hot reads don't (the _pick_b discipline, tree-wide). With
+    `chain` (static) the entries' terms are picked by R - 1 selects and
+    no reduce: at E = 64 the reduce over the senders of a [R, E] field
+    makes the TPU compiler lay the lane's whole cond out instance-major
+    (every [N, R] plane of the state copied there and back in both
+    branches, the entries relaid E-minor: 56 M estimated cycles for 25 M
+    in the append lane's branch, PERF.md section 6, PR 50; E = 16 does
+    not flip, E = 32 does)."""
     def pick(x):
         sel = at if x.ndim == 1 else at[:, None]
         if x.dtype == jnp.bool_:
             return jnp.any(x & sel, axis=0)
+        if chain and x.ndim == 2:
+            out = x[0]
+            for s in range(1, x.shape[0]):
+                out = jnp.where(at[s], x[s], out)
+            return out
         return jnp.sum(jnp.where(sel, x, 0), axis=0)
 
     return jax.tree.map(pick, msgs)
@@ -836,7 +842,7 @@ def _vec_lane_request(cfg: BatchedConfig, iid, slot, st: BatchedState,
     t_max = jnp.max(jnp.where(m.valid, m.term, -1))
     w = _argfirst(m.valid & (m.term == t_max))
     at_w = senders == w
-    mw = _gather_msg(m, at_w)
+    mw = _gather_msg(m, at_w, chain=bool(cfg.log_runs))
     st2, wresp = handler(cfg, iid, slot, st, mw, w)
 
     nudge = (
@@ -965,8 +971,7 @@ def _vec_app_resp_effects(cfg: BatchedConfig, st: BatchedState,
 
     # --- rejected: move next back using the hint (raft.go:1130-1236) ---
     hint = jax.vmap(
-        lambda idx, t: find_conflict_by_term(
-            st.log_term, st.snap_index, st.snap_term, st.last, idx, t)
+        lambda idx, t: termlog.find_conflict(cfg, st, idx, t)
     )(m.reject_hint, m.log_term)
     hint = jnp.where(m.log_term > 0, hint, m.reject_hint)
     in_repl = st.pr_state == REPLICATE
@@ -1247,9 +1252,8 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
         # of it, every round (two 25 MB copies at G=65,536; PERF.md
         # section 6, PR 29).
         m = inbox[KIND_VOTE]
-        last_term = jax.lax.optimization_barrier(term_at(
-            stx.log_term, stx.snap_index, stx.snap_term, stx.last,
-            stx.last))
+        last_term = jax.lax.optimization_barrier(
+            termlog.term_at(cfg, stx, stx.last))
         sty, resp = jax.lax.cond(
             occupied(KIND_VOTE, m),
             lambda sty, mx: _vec_lane_vote(
@@ -1731,8 +1735,7 @@ def _apply_and_compact(cfg: BatchedConfig, st: BatchedState,
         # The floor's term: a follower's as often as a leader's, so the
         # one read of the ring every round makes for emit.
         st = st._replace(
-            snap_term=term_at(st.log_term, st.snap_index, st.snap_term,
-                              st.last, new_snap),
+            snap_term=termlog.term_at(cfg, st, new_snap),
             snap_index=new_snap)
     return st
 
@@ -1813,8 +1816,7 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, ring_read=None):
     ent_idx = prev[:, None] + 1 + j[None, :]  # [R, E]
 
     def ring_terms(log_term):
-        ta = lambda i: term_at(  # noqa: E731
-            log_term, st.snap_index, st.snap_term, st.last, i)
+        ta = lambda i: termlog.term_at(cfg, st, i, log_term)  # noqa: E731
         applied = (ta(st.applied),) if cfg.replace_replicas else ()
         return (ta(st.last), ta(ent_idx), ta(prev)) + applied
 
@@ -1962,6 +1964,9 @@ DEVICE_SCOPES = (
     ("closed-loop engine", "agree", "raft_agree"),
     ("closed-loop engine", "phase", "raft_phase"),
     ("closed-loop engine", "load", "raft_load"),
+    # Of a configuration with log_runs alone: the run table's own ops
+    # (termlog.py), wherever in the round they stand.
+    ("round program", "log", "raft_log"),
 )
 
 # -----------------------------------------------------------------------------
@@ -2232,7 +2237,8 @@ def _telemetry_frame(cfg: BatchedConfig, slot, pre: BatchedState,
     )
     counters = jnp.stack([jnp.asarray(c, I32) for c in cols])
     assert counters.shape == (NUM_COUNTERS,)
-    return TelemetryFrame(counters, invariant_bits(post, slot))
+    return TelemetryFrame(counters, invariant_bits(
+        post, slot, cfg.window if cfg.log_runs else None))
 
 
 def _fleet_frame(cfg: BatchedConfig, pre: BatchedState,

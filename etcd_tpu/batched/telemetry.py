@@ -107,6 +107,11 @@ INV_NAMES = (
     "lease_on_nonleader",   # leader-lease tick residue on a
     # non-leader: a stale quorum-free read authorization (ISSUE 19 —
     # every step-down path must zero the lane in the same round)
+    "runs_passed_applied",  # BatchedConfig.log_runs only (the bit is
+    # computed for no other configuration): the floor above the applied
+    # index, a full run table having given away entries not yet applied
+    # (termlog.py; with K runs over a window of thousands, K leader
+    # changes inside the entries a replica has yet to apply)
 )
 
 

@@ -37,6 +37,7 @@ from jax.extend import core as jex_core
 from etcd_tpu.batched import BatchedConfig, MultiRaftEngine
 from etcd_tpu.batched import engine as engine_mod
 from etcd_tpu.batched import step as step_mod
+from etcd_tpu.batched import termlog as termlog_mod
 from etcd_tpu.batched.engine import control_cols
 
 from .test_scan_tiles import CONFIGS, SPARE, sizes
@@ -180,7 +181,7 @@ def expected(eng: MultiRaftEngine, loop: bool) -> set:
 
 
 def test_the_registry_is_what_the_program_names():
-    assert len(set(SCOPES)) == len(SCOPES) == 16
+    assert len(set(SCOPES)) == len(SCOPES) == 17
     assert all(SCOPE_RE.fullmatch(s) for s in SCOPES)
     assert {layer for layer, _n, _s in step_mod.DEVICE_SCOPES} == {
         "round program", "closed-loop engine"}
@@ -191,7 +192,7 @@ def test_the_registry_is_what_the_program_names():
     # Every named_scope the two modules open is registered, and every
     # registered one is opened by one of them.
     opened = set()
-    for mod in (step_mod, engine_mod):
+    for mod in (step_mod, engine_mod, termlog_mod):
         with open(mod.__file__) as f:
             opened |= set(re.findall(r'named_scope\("([^"]+)"\)', f.read()))
     assert opened == set(SCOPES)
