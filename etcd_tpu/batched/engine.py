@@ -36,7 +36,13 @@ rounds each lane was occupied, so exchanged; ``rare_rounds()`` those in
 which a heartbeat lane held the rare message type that makes deliver
 run its whole handler, ``step.lane_occupancy``; ``emit_ring_rounds()``
 the tile-rounds in which emit read the log ring for the terms its
-messages state, ``step._emit``). A call over more rows
+messages state, ``step._emit``; ``bulk_rounds()`` the rounds in which
+an append stated more entries than the head of a split append lane
+holds, so that deliver ran the lane whole, emit built the entries' tail
+and the exchange moved it: ``step.app_head``, a configuration whose
+appends are sized for catch-up, E=64 for P=2; in every other round the
+lane is the head's few columns and the tail rides the carry untouched).
+A call over more rows
 than one tile holds (``scan_tiles``: TILE_ROWS, from the shape alone)
 runs tile by tile: a tile is a block of whole groups, adjacent rows of
 ``eng.state`` (row ``g * R + s`` as ever: the row order does not
@@ -88,10 +94,10 @@ from .compile_cache import enable_compile_cache
 _ENGINE_SERIAL = itertools.count()
 from .state import (CANDIDATE, CONF_SWAP, LEADER, PRECANDIDATE, REPLICATE,
                     BatchedConfig, BatchedState, I32, conf_decode, init_state)
-from .step import (KIND_APP, MsgSlots, NUM_KINDS, NUM_OCC, T_APP, agree_lanes,
-                   empty_msgs, exchange_lanes, lane_occupancy,
-                   make_step_round, route, route_lanes, split_lanes,
-                   stack_lanes)
+from .step import (BULK_APP, KIND_APP, MsgSlots, NUM_KINDS, NUM_OCC, T_APP,
+                   agree_lanes, app_head, empty_msgs, exchange_lanes,
+                   lane_occupancy, make_step_round, route, route_lanes,
+                   settled, split_lanes, stack_lanes)
 
 
 # Columns of a scan's control schedule, int32 [rounds, CTL_COLS], one
@@ -453,6 +459,13 @@ class MultiRaftEngine:
                     "log_runs counts a replica's catch-up against its "
                     "group's commit, a reduce over a group's rows on one "
                     "device: not with nodes")
+            if app_head(cfg):
+                raise ValueError(
+                    f"max_ents_per_msg={cfg.max_ents_per_msg} with "
+                    f"max_props_per_round={cfg.max_props_per_round} splits "
+                    "the append lane (step.app_head), and emit builds the "
+                    "tail on a bit of a node's own rows where the nodes "
+                    "exchange it on one they agree on: not with nodes")
             mesh = Mesh(np.asarray(nodes), (NODE_AXIS,))
             # Of a per-instance array in placed order, and of what
             # every node holds whole.
@@ -478,6 +491,10 @@ class MultiRaftEngine:
         # loops whatever the number of tiles.
         self._tiles = tiles = scan_tiles(cfg, nodes=placed)
         self.tile_rows = rows = n // tiles
+        # A split append lane's head (step.app_head; 0: not split), and
+        # the occupancy vector's length with its bit.
+        self._head = head = app_head(cfg)
+        n_occ = NUM_OCC + (1 if head else 0)
 
         def row_slots():
             """The slot of each row of a tile: the node's own, or the
@@ -511,10 +528,13 @@ class MultiRaftEngine:
             def one(lo, st, lanes, per_row):
                 masks, conf_req, wipe = per_row
                 with jax.named_scope("raft_carry"):
-                    # (Emit's bit, last, is the scan's to count.)
-                    return tile_step(lo, slots)(
+                    # (Emit's bit, last, is the scan's to count. A split
+                    # append lane's tail means something on the tile's
+                    # own bit: settled here, tile by tile.)
+                    out = tile_step(lo, slots)(
                         st, lanes, *masks, lane_any=lane_occupancy(lanes),
                         conf_req=conf_req, wipe=wipe)[:-1]
+                    return (out[0], settled(out[1])) + out[2:]
 
             # The shapes of what a tile answers, for the outbox and the
             # frames the loop writes into; and the round traced once
@@ -560,7 +580,7 @@ class MultiRaftEngine:
             # Handed lanes it answers in lanes; route(), a program
             # of its own here, takes them stacked.
             with jax.named_scope("raft_carry"):
-                lanes = split_lanes(inbox)
+                lanes = split_lanes(inbox, head)
             if tiles > 1 or placed:
                 return tiled_round(st, lanes, (masks, conf_req, wipe))
             with jax.named_scope("raft_carry"):
@@ -601,7 +621,7 @@ class MultiRaftEngine:
         # And, second of the pair, the tile-rounds in which emit read
         # the ring for the terms it states (emit_ring_rounds(); step._emit):
         # [1], or a count a node, [R].
-        self._lanes = (jnp.zeros((NUM_OCC,), I32), jnp.zeros((1,), I32))
+        self._lanes = (jnp.zeros((n_occ,), I32), jnp.zeros((1,), I32))
         if placed:
             self._lanes = (
                 (self._on_nodes(np.zeros((NUM_OCC,), np.int32)),
@@ -743,9 +763,10 @@ class MultiRaftEngine:
 
             def body(carry, row):
                 # `occ` is the inbox's occupancy (step.lane_occupancy:
-                # the K lanes, then the rare types' bits), [NUM_OCC]
-                # bool: what deliver's lane conds skip on and
-                # route_lanes' are told was there.
+                # the K lanes, then the rare types' bits and, of a split
+                # append lane, BULK_APP), [NUM_OCC] bool or one more:
+                # what deliver's lane conds skip on and route_lanes'
+                # are told was there.
                 # Every line here stands under a scope of
                 # step.DEVICE_SCOPES (the round's own are innermost and
                 # win): what a trace then files under no scope, the
@@ -869,6 +890,8 @@ class MultiRaftEngine:
             request fields too), so such a lane is wiped here, once
             a call: inside the scan an empty lane is all zeros."""
             occ = lane_occupancy(inbox)
+            # (A split append lane's tail is the public form's already,
+            # zeros unless an append states it: split_lanes' of slots.)
             return tuple(
                 jax.tree.map(
                     lambda x, _k=k: jnp.where(occ[_k], x, jnp.zeros_like(x)),
@@ -983,7 +1006,7 @@ class MultiRaftEngine:
                 lanes, crossed = lanes
             counts = (crossed, ring, *tally)
             with jax.named_scope("raft_carry"):
-                inbox = split_lanes(inbox)
+                inbox = split_lanes(inbox, head)
             # Tracing only. As the body of the loops the round takes
             # JAX 12.5 s to trace on the TPU's host, by itself 2.6 s
             # (PERF.md section 6, "PR 35": every warm start would pay
@@ -1004,14 +1027,14 @@ class MultiRaftEngine:
                         0, ticks, props, *start)(carry, row),
                     like(ticks), like(props),
                     (*jax.tree.map(like, (st, inbox)),
-                     jax.ShapeDtypeStruct((NUM_OCC,), bool),
+                     jax.ShapeDtypeStruct((n_occ,), bool),
                      jax.tree.map(like, tel), (), counts, t_watch),
                     jax.tree.map(
                         lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
                         (isolate, control)),
                     *t_start)
             with jax.named_scope("raft_tiles"):
-                seen = jnp.zeros((rounds, NUM_OCC), bool)
+                seen = jnp.zeros((rounds, n_occ), bool)
             st, inbox, tel, watch, seen, (crossed, ring, *tally) = (
                 jax.lax.fori_loop(
                     0, tiles, tile, (st, inbox, tel, watch, seen, counts)))
@@ -1106,7 +1129,7 @@ class MultiRaftEngine:
             # array of its own (the round answers lanes with lanes),
             # and the inbox is stacked back once at the exit.
             with jax.named_scope("raft_carry"):
-                inbox, occ = enter(split_lanes(inbox))
+                inbox, occ = enter(split_lanes(inbox, head))
             (st, inbox, _, tel, flt, lanes, watch), _ = jax.lax.scan(
                 body, (st, inbox, occ, tel, flt, lanes, watch),
                 (isolate, control), length=rounds
@@ -1847,7 +1870,18 @@ class MultiRaftEngine:
         (step._deliver_vectorized). One less their share of
         ``lane_rounds()[KIND_HB]`` / ``[KIND_HB_RESP]`` is how often the
         plain branch did."""
-        return self._occupied()[NUM_KINDS:]
+        return self._occupied()[NUM_KINDS:NUM_OCC]
+
+    def bulk_rounds(self) -> int:
+        """Closed-loop rounds, counted like ``lane_rounds``, in which
+        the append lane held a MsgApp that states more entries than a
+        split lane's head holds (step.app_head, ``lane_occupancy``'s
+        BULK_APP) for any instance of any tile: the rounds in which
+        deliver ran the lane at its whole width and, the round before,
+        emit built the entries' tail and route() moved it; in every
+        other round of ``lane_rounds()[KIND_APP]`` the lane ran at the
+        head's. 0 where the lane is not split."""
+        return int(self._occupied()[BULK_APP]) if self._head else 0
 
     def emit_ring_rounds(self) -> int:
         """Tile-rounds of the closed loop (a node's, over nodes) in

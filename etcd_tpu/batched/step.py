@@ -27,6 +27,13 @@ round is one jitted program:
     deliver (each inbox lane one fold over the sender axis, no scan)
     → tick → control → propose → emit → route
 
+Where an append's E entries outweigh the lane's scalar fields
+(``app_head``: E=64 with P=2, not the engine family's E=4) the append
+lane travels, is written and is read in two halves: a head of P + 1
+columns in every round, the rest only in the rounds some append of the
+batch states more (``lane_occupancy``'s BULK_APP; deliver's three-way
+append lane, emit's tail, route()'s switch for it).
+
 Determinism: randomized election timeouts use a per-instance hash of
 (instance id, reset count), reproducible by the host oracle for
 differential testing (ref: raft.go:1718-1720 resetRandomizedElectionTimeout).
@@ -147,6 +154,26 @@ class MsgSlots(NamedTuple):
     ent_terms: jnp.ndarray  # i32 [..., E]
 
 
+# The append lane of a configuration whose entries travel in two halves
+# (``app_head``), in the lane form alone: MsgSlots' fields with
+# ``ent_terms`` the head's columns, [..., Wn], and the columns past
+# them apart in ``ent_tail``, [..., E - Wn]. Whatever takes a lane by
+# its fields' names takes this one; the slot form never holds it.
+BulkLane = NamedTuple(
+    "BulkLane", [(f, jnp.ndarray) for f in MsgSlots._fields]
+    + [("ent_tail", jnp.ndarray)])
+
+
+def _tail(lane):
+    """A split append lane's tail; None of any other lane or message."""
+    return getattr(lane, "ent_tail", None)
+
+
+def _untailed(lane) -> MsgSlots:
+    """`lane` without a tail: a split append lane's head as MsgSlots."""
+    return MsgSlots(*lane[:len(MsgSlots._fields)])
+
+
 # The fields each kind lane carries, beside NUM_REQ_KINDS' lane-order
 # contract and as static: what some message type of the lane states
 # (raftpb's MsgVote, MsgApp and MsgSnap, MsgHeartbeat and MsgTimeoutNow
@@ -232,8 +259,8 @@ def empty_msgs(shape: Tuple[int, ...], num_ents: int,
 
 
 def _carried(k: int, m: MsgSlots) -> MsgSlots:
-    """`m` as lane `k` carries it: the fields of ``LANE_FIELDS[k]``,
-    None for the rest."""
+    """`m` as lane `k` carries it: the fields of ``LANE_FIELDS[k]``
+    (a split append lane's tail with its head), None for the rest."""
     return m._replace(**{f: None for f in MsgSlots._fields
                          if f not in LANE_FIELDS[k]})
 
@@ -242,26 +269,97 @@ def _whole(k: int, m: MsgSlots) -> MsgSlots:
     """A message of lane `k` with every field: what the lane does not
     carry is the zero a writer would have put there (no entries, as a
     handler's own ``empty_msgs(..., 0)``). Constants, which the compiler
-    folds into whatever reads them."""
-    return empty_msgs(m.valid.shape, 0)._replace(
+    folds into whatever reads them. A split append lane's tail stays
+    beside its head (``_joined`` puts the two together)."""
+    whole = empty_msgs(m.valid.shape, 0)._replace(
         **{f: getattr(m, f) for f in LANE_FIELDS[k]})
+    # jitlint: waive(tracer-branch) -- on the lane's type, at trace time
+    if _tail(m) is None:
+        return whole
+    return BulkLane(*whole, m.ent_tail)
 
 
-def split_lanes(m: MsgSlots) -> Tuple[MsgSlots, ...]:
+def app_head(cfg) -> int:
+    """Wn, the columns of an append's entries that travel, are written
+    and are read in every round, or 0 where the append lane is not
+    split. Static, of E = ``max_ents_per_msg`` and P =
+    ``max_props_per_round`` alone: a steady append carries the round's
+    P proposals, a new leader's first its empty entry beside them, so
+    the head is P + 1 columns; the E - Wn past it (the *tail*) are
+    used by the appends that carry a replica that fell behind, and
+    travel as a part of the lane of its own (``BulkLane.ent_tail``)
+    only in the rounds in which some append of the batch states more
+    than Wn entries (``lane_occupancy``'s BULK_APP). The lane is split
+    only where the tail outweighs the nine scalar fields beside it
+    (E=64, P=2: 61 words; not at E=4, P=2 nor at E=8, P=4, whose
+    programs are what they were)."""
+    head = cfg.max_props_per_round + 1
+    scalars = len(LANE_FIELDS[KIND_APP]) - 1
+    return head if cfg.max_ents_per_msg - head > scalars else 0
+
+
+def _joined(m) -> MsgSlots:
+    """A split append (lane) with its entries in one piece again."""
+    # jitlint: waive(tracer-branch) -- on the lane's type, at trace time, never on a device value
+    if _tail(m) is None:
+        return m
+    return _untailed(m)._replace(
+        ent_terms=jnp.concatenate([m.ent_terms, m.ent_tail], axis=-1))
+
+
+def _states_bulk(app: BulkLane):
+    """Per slot of a split append lane: a valid MsgApp that states more
+    entries than the head holds, so that its tail is read."""
+    return (app.valid & (app.type == T_APP)
+            & (app.n_ents > app.ent_terms.shape[-1]))
+
+
+def settled(lanes: Tuple[MsgSlots, ...]) -> Tuple[MsgSlots, ...]:
+    """Kind lanes with a split append lane's tail as the public form
+    states it. In the lane form the tail means something only while
+    some valid append of the lane states more than the head holds
+    (BULK_APP): in every other round emit hands the spent inbox's tail
+    on unread and unwritten (``_emit``), and here that is the zeros a
+    writer of slots would have stated."""
+    app = lanes[KIND_APP]
+    # jitlint: waive(tracer-branch) -- as in _joined
+    if _tail(app) is None:
+        return lanes
+    tail = jnp.where(jnp.any(_states_bulk(app)), app.ent_tail, 0)
+    return (lanes[:KIND_APP] + (app._replace(ent_tail=tail),)
+            + lanes[KIND_APP + 1:])
+
+
+def split_lanes(m: MsgSlots, head: int = 0) -> Tuple[MsgSlots, ...]:
     """[N, R, K] slots as K kind lanes of [N, R], each with the fields
     of ``LANE_FIELDS`` alone (entries travel in KIND_APP, ``reject`` in
     two response lanes, ...): no writer puts anything but zero in the
     others, so nothing of an inbox a writer can make is lost, and what
-    a lane does not hold is not there to move."""
-    return tuple(
+    a lane does not hold is not there to move. With `head`
+    (``app_head(cfg)``, static; 0: no split) the append lane's entries
+    come in two halves (``BulkLane``), ``ent_terms`` the first `head`
+    columns and ``ent_tail`` the rest."""
+    lanes = tuple(
         jax.tree.map(lambda x, _k=k: x[:, :, _k], _carried(k, m))
         for k in range(NUM_KINDS))
+    if head:
+        app = lanes[KIND_APP]
+        lanes = lanes[:KIND_APP] + (BulkLane(
+            *app._replace(ent_terms=app.ent_terms[..., :head]),
+            app.ent_terms[..., head:]),) + lanes[KIND_APP + 1:]
+    return lanes
 
 
 def stack_lanes(lanes: Tuple[MsgSlots, ...]) -> MsgSlots:
     """K kind lanes of [N, R] as [N, R, K] slots, the public form: the
     fields a lane does not carry come back as the zeros they always
-    were (shape and dtype of a lane that carries the field)."""
+    were (shape and dtype of a lane that carries the field), and a
+    split append lane's entries in one piece, ``ent_terms [.., E]``
+    (``settled``)."""
+    lanes = settled(lanes)
+    lanes = (lanes[:KIND_APP] + (_joined(lanes[KIND_APP]),)
+             + lanes[KIND_APP + 1:])
+
     def stacked(f):
         xs = [getattr(lane, f) for lane in lanes]
         zeros = jnp.zeros_like(next(x for x in xs if x is not None))
@@ -270,17 +368,23 @@ def stack_lanes(lanes: Tuple[MsgSlots, ...]) -> MsgSlots:
     return MsgSlots(*map(stacked, MsgSlots._fields))
 
 
-def lane_slot_bytes(num_ents: int) -> np.ndarray:
+def lane_slot_bytes(num_ents: int, head: int = 0) -> np.ndarray:
     """[K] ints: the bytes of one slot of each kind lane as it is
     carried and exchanged (``LANE_FIELDS`` and the fields' dtypes; E =
     `num_ents` entries' terms in KIND_APP). Times the rows, the R
     slots and the rounds a lane ran (``lane_rounds()``, between nodes
-    ``lane_exchanges()``) it is what a window's exchange moved."""
-    like = jax.eval_shape(lambda: empty_msgs((), num_ents))
-    return np.array([
+    ``lane_exchanges()``) it is what a window's exchange moved. With
+    `head` (``app_head(cfg)``; 0: no split) [K + 1]: KIND_APP's slot
+    with the head's columns alone and, last, the bytes of the tail's,
+    which moved in the rounds ``bulk_rounds()`` counts."""
+    like = jax.eval_shape(lambda: empty_msgs((), head or num_ents))
+    lanes = [
         sum(x.dtype.itemsize * x.size
             for x in jax.tree.leaves(_carried(k, like)))
-        for k in range(NUM_KINDS)])
+        for k in range(NUM_KINDS)]
+    if head:
+        lanes.append((num_ents - head) * like.ent_terms.dtype.itemsize)
+    return np.array(lanes)
 
 
 # A batch's occupancy vector (``lane_occupancy``): the K kind lanes, then
@@ -288,21 +392,32 @@ def lane_slot_bytes(num_ents: int) -> np.ndarray:
 # a MsgTimeoutNow in the heartbeat lane (there only while a leadership
 # is handed over), a MsgAppResp in the heartbeat-response lane (there
 # only as the nudge a stale leader's heartbeat draws). Deliver gives the
-# handling of each a branch of its own (_deliver_vectorized).
+# handling of each a branch of its own (_deliver_vectorized). Where the
+# append lane is split (``app_head``), and only there, one bit more:
+# BULK_APP, a valid MsgApp that states more entries than the head holds,
+# on which deliver runs the lane at its whole width, emit builds the
+# tail and route() moves it.
 RARE_TIMEOUT_NOW, RARE_APP_RESP = NUM_KINDS, NUM_KINDS + 1
 NUM_OCC = NUM_KINDS + 2
+BULK_APP = NUM_OCC
 
 
 def lane_occupancy(lanes: Tuple[MsgSlots, ...]) -> jnp.ndarray:
     """[NUM_OCC] bool: which kind lanes hold a message for any instance
     and, after the K lanes, whether the heartbeat lane holds a
     MsgTimeoutNow and the heartbeat-response lane a MsgAppResp for any
-    (RARE_TIMEOUT_NOW, RARE_APP_RESP)."""
+    (RARE_TIMEOUT_NOW, RARE_APP_RESP); of a split append lane
+    [NUM_OCC + 1], last whether any valid MsgApp states more entries
+    than the head holds (BULK_APP)."""
     hb, hb_resp = lanes[KIND_HB], lanes[KIND_HB_RESP]
+    app = lanes[KIND_APP]
+    # jitlint: waive(tracer-branch) -- on the lane's type, at trace time, never on a device value
+    bulk = [] if _tail(app) is None else [jnp.any(_states_bulk(app))]
     return jnp.stack(
         [jnp.any(lanes[k].valid) for k in range(NUM_KINDS)]
         + [jnp.any(hb.valid & (hb.type == T_TIMEOUT_NOW)),
-           jnp.any(hb_resp.valid & (hb_resp.type == T_APP_RESP))])
+           jnp.any(hb_resp.valid & (hb_resp.type == T_APP_RESP))]
+        + bulk)
 
 
 def _sel(cond, a, b):
@@ -621,8 +736,10 @@ def _lane_hb(cfg: BatchedConfig, iid, slot, st: BatchedState, m: MsgSlots,
 
 def _handle_append(cfg: BatchedConfig, st: BatchedState, m: MsgSlots):
     """Follower append handling (ref: raft.go:1475-1511 +
-    log.go maybeAppend/findConflict)."""
-    e = cfg.max_ents_per_msg
+    log.go maybeAppend/findConflict). As wide as the entries it is
+    handed: E, or a split lane's head in a batch none of whose appends
+    states more (_deliver_vectorized)."""
+    e = m.ent_terms.shape[-1]
     no_resp = empty_msgs((), 0)
     prev = m.index
 
@@ -842,7 +959,9 @@ def _vec_lane_request(cfg: BatchedConfig, iid, slot, st: BatchedState,
     t_max = jnp.max(jnp.where(m.valid, m.term, -1))
     w = _argfirst(m.valid & (m.term == t_max))
     at_w = senders == w
-    mw = _gather_msg(m, at_w, chain=bool(cfg.log_runs))
+    # (Of a split append lane's two halves the winner's are picked
+    # apart and put together after: [E] a row and not [R, E].)
+    mw = _joined(_gather_msg(m, at_w, chain=bool(cfg.log_runs)))
     st2, wresp = handler(cfg, iid, slot, st, mw, w)
 
     nudge = (
@@ -1229,7 +1348,22 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
     become_follower and reset go by ``st.last``), so the ring goes round
     them as it goes round the vote cond. With ``lane_any=None`` the two
     lanes keep their one cond each: under a mapped predicate a cond is a
-    select, and a lane split in two would compute both halves."""
+    select, and a lane split in two would compute both halves.
+
+    The append lane of a configuration that splits it (``app_head``)
+    comes as head and tail and is the same move for an append's
+    entries, on the vector's BULK_APP: a ``lax.switch`` three ways,
+    skipped / at the head's width Wn / whole. The winner's handler
+    takes E from the entries it is handed (_handle_append, the run
+    table's passes under it), so at Wn its [E, K] passes are [Wn, K].
+    Exact by construction: with the bit clear every valid append has
+    ``have[j] = j < n_ents`` false for j >= Wn, so ``conflict``,
+    ``write_mask`` and the run table's ``first`` are false there and
+    ``last``, ``commit``, the response and the log are what the whole
+    handler gives; the tail is then neither read nor, by the lane
+    form's invariant, anything but stale (``settled``). The bit may be
+    a superset. With ``lane_any=None`` the lane keeps its one whole
+    handler."""
     # A lane enters its cond as it is carried and its handler sees whole
     # messages: a field the lane does not carry is, inside the branch,
     # the zero constant a writer would have stated (_whole).
@@ -1281,6 +1415,29 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
         occ = occupied(k, inbox[k])
         stx, answer = request_cond(k, handler, occ, stx)
         return stx, _vec_request_resps(cfg, stx, answer, occ, k)
+
+    def appends(stx):
+        # A split lane three ways on (occupied, BULK_APP): skipped, at
+        # the head's width, whole (the docstring's last paragraph).
+        # Both live branches take the same operands, the log among
+        # them, so one switch does where the heartbeat lanes' ringless
+        # plain branch needed a cond of its own.
+        m = inbox[KIND_APP]
+        if _tail(m) is None or lane_any is None:
+            return request(KIND_APP, _lane_app, stx)
+        occ, bulk = lane_any[KIND_APP], lane_any[BULK_APP]
+        lane = lambda sty, mx: _vec_lane_request(  # noqa: E731
+            cfg, iid, slot, sty, _whole(KIND_APP, mx), _lane_app, KIND_APP)
+        no_answer = (_carried(KIND_APP_RESP, empty_msgs((), 0)),
+                     jnp.zeros((), I32),
+                     jnp.zeros((cfg.num_replicas,), bool))
+        stx, answer = jax.lax.switch(
+            occ.astype(I32) + (occ & bulk).astype(I32),
+            (lambda sty, mx: (sty, no_answer),
+             lambda sty, mx: lane(sty, _untailed(mx)),
+             lane),
+            stx, m)
+        return stx, _vec_request_resps(cfg, stx, answer, occ, KIND_APP)
 
     def state_cond(k, fn, pred, stx):
         return jax.lax.cond(
@@ -1343,7 +1500,7 @@ def _deliver_vectorized(cfg: BatchedConfig, iid, slot, st: BatchedState,
     st, r0 = votes(st)
     if cfg.replace_replicas:
         st = st._replace(conf=st.conf._replace(learner_next=learner_next))
-    st, r1 = request(KIND_APP, _lane_app, st)
+    st, r1 = appends(st)
     if cfg.replace_replicas:
         learner_next = st.conf.learner_next
         st = without(st)
@@ -1767,7 +1924,17 @@ def _asks_below(cfg: BatchedConfig, slot, st: BatchedState):
     return below
 
 
-def _emit(cfg: BatchedConfig, slot, st: BatchedState, ring_read=None):
+def _sends_bulk(cfg: BatchedConfig, slot, st: BatchedState, head: int):
+    """Whether this row's emit sends an append of more entries than a
+    split lane's head holds (`head`, ``app_head``): of the state as
+    emit finds it, like ``_asks_below``."""
+    app, _, prev = _appends_due(cfg, slot, st)
+    n_send = jnp.clip(st.last - prev, 0, cfg.max_ents_per_msg)
+    return jnp.any(app & (n_send > head))
+
+
+def _emit(cfg: BatchedConfig, slot, st: BatchedState, ring_read=None,
+          head: int = 0, bulk=None, spent=None):
     """Materialize pending sends into the three request lanes of the
     outbox (KIND_VOTE, KIND_APP, KIND_HB, each [R] slots addressed by
     target) and clear flags, of the state _apply_and_compact leaves. The
@@ -1794,8 +1961,24 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, ring_read=None):
     out of the bit). The bit may be a superset: the ring is exact for
     any batch. None (under
     a mapped predicate a cond is a select and would compute both) reads
-    the ring as ever."""
+    the ring as ever.
+
+    Where the append lane is split (`head` = ``app_head(cfg)``, static;
+    0: it is not, or the round was handed a lane in one piece) the
+    entries leave in two halves (``BulkLane``). The head's Wn columns
+    are built as ever. The tail's indexes, terms, mask and select stand
+    under `bulk`, ONE unmapped bit like `ring_read` and reduced beside
+    it: whether any row of the batch sends an append of more than Wn
+    entries (_sends_bulk; no superset: it is the outbox's BULK_APP to
+    the bit, which route() moves the tail on). With the bit false no
+    tail is built, zeros included: the branch hands on `spent`, the
+    tail of the inbox this round delivered, a buffer nobody reads
+    again, so that a round of steady appends neither writes, wipes nor
+    copies E - Wn columns a slot; what it holds then means nothing
+    (``settled``). None builds the tail in every round."""
     e = cfg.max_ents_per_msg
+    # The columns every round builds: all, or a split lane's head.
+    wn = head or e
     r = cfg.num_replicas
     peers = jnp.arange(r, dtype=I32)
     # A field of a lane is int32 [R], one slot a target: what a sender
@@ -1812,8 +1995,8 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, ring_read=None):
     # terms they state.
     app, snp, prev = _appends_due(cfg, slot, st)
     n_send = jnp.clip(st.last - prev, 0, e)  # [R]
-    j = jnp.arange(e, dtype=I32)
-    ent_idx = prev[:, None] + 1 + j[None, :]  # [R, E]
+    j = jnp.arange(wn, dtype=I32)
+    ent_idx = prev[:, None] + 1 + j[None, :]  # [R, E], a head's [R, Wn]
 
     def ring_terms(log_term):
         ta = lambda i: termlog.term_at(cfg, st, i, log_term)  # noqa: E731
@@ -1878,7 +2061,7 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, ring_read=None):
     if cfg.replace_replicas:
         snap_at, snap_t = st.applied, terms[3]
 
-    append = empty_msgs((r,), e)._replace(
+    append = empty_msgs((r,), wn)._replace(
         valid=app | snp,
         type=per_target(jnp.where(snp, T_SNAP, T_APP)),
         term=per_target(st.term),
@@ -1888,6 +2071,26 @@ def _emit(cfg: BatchedConfig, slot, st: BatchedState, ring_read=None):
         n_ents=jnp.where(app, n_send, 0),
         ent_terms=jnp.where(ent_mask & app[:, None], ent_terms, 0),
     )
+    if wn < e:
+        def tail(log_term, _):
+            jt = jnp.arange(wn, e, dtype=I32)
+            idx = prev[:, None] + 1 + jt[None, :]
+            own = lambda lt: jnp.broadcast_to(st.term, idx.shape)  # noqa: E731
+            ring = lambda lt: termlog.term_at(cfg, st, idx, lt)  # noqa: E731
+            if ring_read is None:
+                terms = ring(log_term)
+            else:
+                terms = jax.lax.cond(ring_read, ring, own, log_term)
+            return jnp.where(
+                (jt[None, :] < n_send[:, None]) & app[:, None], terms, 0)
+
+        # jitlint: waive(tracer-branch) -- as above
+        if bulk is None:
+            ent_tail = tail(st.log_term, None)
+        else:
+            ent_tail = jax.lax.cond(
+                bulk, tail, lambda _, old: old, st.log_term, spent)
+        append = BulkLane(*append, ent_tail)
     if cfg.conf_entries:
         # Entry types do not travel: an append that carries the entry
         # this leader's log marks as a configuration change says so in
@@ -2045,15 +2248,30 @@ def _exchange_written(exchange, outbox, lane_any, lanes, stale):
     """Lane by lane, three ways: a lane somebody wrote is exchanged
     (`exchange` of every field it carries: None, an empty pytree, is no
     operand of the switch), one that held last round's messages
-    (`stale`) wiped, the rest left as they are."""
-    return tuple(
-        jax.lax.switch(
-            jnp.where(lane_any[k], 2, stale[k].astype(I32)),
-            (lambda lane, ob: lane,
-             lambda lane, ob: jax.tree.map(jnp.zeros_like, lane),
-             lambda lane, ob: jax.tree.map(exchange, ob)),
-            lanes[k], outbox[k])
-        for k in range(NUM_KINDS))
+    (`stale`) wiped, the rest left as they are. A split append lane's
+    tail (``BulkLane.ent_tail``) goes the same three ways apart from
+    its head, on the vectors' BULK_APP: exchanged in a round in which
+    some append states more than the head holds, wiped after the last
+    such round, and in every other handed back untouched — the
+    outbox's own, which is then the spent inbox's that emit handed on
+    (``_emit``: one buffer rides the round, and `lanes` gives none)."""
+    def three_ways(written, was, *operands):
+        return jax.lax.switch(
+            jnp.where(written, 2, was.astype(I32)),
+            (lambda lane, *ob: lane,
+             lambda lane, *ob: jax.tree.map(jnp.zeros_like, lane),
+             lambda *ob: jax.tree.map(exchange, ob[-1])),
+            *operands)
+
+    out = [three_ways(lane_any[k], stale[k], _untailed(lanes[k]),
+                      _untailed(outbox[k]))
+           for k in range(NUM_KINDS)]
+    tail = _tail(outbox[KIND_APP])
+    # jitlint: waive(tracer-branch) -- on the lane's type, at trace time
+    if tail is not None:
+        out[KIND_APP] = BulkLane(*out[KIND_APP], three_ways(
+            lane_any[BULK_APP], stale[BULK_APP], tail))
+    return tuple(out)
 
 
 def route(cfg: BatchedConfig, outbox: MsgSlots, lane_any=None,
@@ -2089,10 +2307,13 @@ def route(cfg: BatchedConfig, outbox: MsgSlots, lane_any=None,
     # so the exchange alone lands everything in its inbox lane.
     if lane_any is None:
         return _route_jit(cfg.num_replicas)[0](outbox)
+    # (The append lane in two halves where the vector has the bit for
+    # it: ``lane_occupancy`` of lanes split so.)
+    head = app_head(cfg) if lane_any.shape[0] > NUM_OCC else 0
     if prev is not None:
-        prev = (split_lanes(prev[0]), prev[1])
+        prev = (split_lanes(prev[0], head), prev[1])
     return stack_lanes(
-        route_lanes(cfg, split_lanes(outbox), lane_any, prev))
+        route_lanes(cfg, split_lanes(outbox, head), lane_any, prev))
 
 
 def route_lanes(cfg: BatchedConfig, outbox: Tuple[MsgSlots, ...], lane_any,
@@ -2118,10 +2339,18 @@ def route_lanes(cfg: BatchedConfig, outbox: Tuple[MsgSlots, ...], lane_any,
     whether it may hold anything but zeros. A lane empty then and now
     is handed back untouched, which costs nothing (the vote lanes
     under steady appends); one occupied then and empty now is wiped.
-    Without ``prev`` an empty lane is fresh zeros."""
+    Without ``prev`` an empty lane is fresh zeros.
+
+    A split append lane (``BulkLane``, ``app_head``) is two such
+    lanes: its head goes with KIND_APP's bit, its tail the same three
+    ways on BULK_APP (`lane_any` and `stale` are then one longer, as
+    ``lane_occupancy`` of such lanes is), so a round of steady appends moves
+    the head's Wn columns and hands the tail back untouched
+    (``_exchange_written`` on whose buffer that is)."""
     if prev is None:
         prev = (jax.tree.map(jnp.zeros_like, outbox),
-                jnp.zeros((NUM_KINDS,), bool))
+                jnp.zeros((NUM_KINDS if _tail(outbox[KIND_APP]) is None
+                           else BULK_APP + 1,), bool))
     return _route_jit(cfg.num_replicas)[1](outbox, lane_any, *prev)
 
 
@@ -2385,9 +2614,27 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
         # lanes (route_lanes takes them as they are), [N, R, K] slots
         # for slots, stacked once, outside the vmap.
         packed = isinstance(inbox, MsgSlots)
+        # An occupancy vector handed in that has no bit for a tail.
+        short = False
+        # jitlint: waive(tracer-branch) -- None is the argument left out, tested at trace time, never a device value
+        if lane_any is not None:
+            short = lane_any.shape[0] <= BULK_APP
         # jitlint: waive(tracer-branch) -- the branch is on the argument's pytree structure at trace time, never on a device value
         if packed:
-            inbox = split_lanes(inbox)
+            # (Without the lane skip every cond is a select and a lane
+            # in two halves would compute both: in one piece, then; and
+            # so under a vector without the bit.)
+            inbox = split_lanes(
+                inbox, 0 if short or not lane_skip else app_head(cfg))
+        # The append lane's entries in two halves (BulkLane), if that
+        # is how the inbox holds them: the outbox then leaves so too.
+        # jitlint: waive(tracer-branch) -- on the lane's type, at trace time
+        head = 0 if _tail(inbox[KIND_APP]) is None else app_head(cfg)
+        if head and short and lane_skip:
+            raise ValueError(
+                "a split append lane needs lane_occupancy's vector of the "
+                f"same lanes ({BULK_APP + 1} bits, BULK_APP last), got "
+                f"{lane_any.shape[0]}")
         if cfg.narrow_lanes:
             # Narrow lanes live int8/int16 BETWEEN rounds (the donated
             # state carry AND the routed inbox); the protocol math runs
@@ -2444,21 +2691,24 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
                 sti = _propose(cfg, slot, sti, n_new)
             with jax.named_scope("raft_emit"):
                 sti = _apply_and_compact(cfg, sti, conf_applied)
-                below = None
+                below = wide = None
                 # jitlint: waive(tracer-branch) -- on the argument's structure, as above
                 if lane_any is not None:
                     # (A row cut off sends nothing, whatever it asks:
                     # a retired node's replicas campaign into the void
                     # for as long as they are away.)
                     below = _asks_below(cfg, slot, sti) & ~iso
+                    if head:
+                        wide = _sends_bulk(cfg, slot, sti, head) & ~iso
             return (slot, n_new, iso, pre, inbox_i, sti, req_resps,
-                    read_snap, conf_applied, last_tick), below
+                    read_snap, conf_applied, last_tick), (below, wide)
 
-        def from_emit(mid, ring_read):
+        def from_emit(mid, ring_read, bulk):
             (slot, n_new, iso, pre, inbox_i, sti, req_resps, read_snap,
              conf_applied, last_tick) = mid
             with jax.named_scope("raft_emit"):
-                sti, out = _emit(cfg, slot, sti, ring_read)
+                sti, out = _emit(cfg, slot, sti, ring_read, head, bulk,
+                                 _tail(inbox_i[KIND_APP]))
             # The response to sender s's request of kind k is slot s of
             # lane k + NUM_REQ_KINDS; it routes back by the same
             # exchange (the inbox lane-order contract, top of module).
@@ -2502,16 +2752,21 @@ def _step_round_jit(cfg: BatchedConfig, with_aux: bool,
         def whole_round(ax, *rows):
             """Both halves over the instance axis `ax` of every array
             of `rows`, and emit's bit (None where there is no batch)."""
-            mid, below = jax.vmap(
+            mid, (below, wide) = jax.vmap(
                 upto_emit, in_axes=(ax,) * len(rows) + (None,),
                 out_axes=ax)(*rows, lane_any)
-            ring_read = None
+            ring_read = bulk = None
             # jitlint: waive(tracer-branch) -- on the argument's structure, as above
             if below is not None:
                 with jax.named_scope("raft_emit"):
                     ring_read = jnp.any(below)
-            return jax.vmap(from_emit, in_axes=(ax, None), out_axes=ax)(
-                mid, ring_read), ring_read
+                    # (Of a split append lane: emit's other bit.)
+                    # jitlint: waive(tracer-branch) -- on the structure, as above
+                    if wide is not None:
+                        bulk = jnp.any(wide)
+            return jax.vmap(
+                from_emit, in_axes=(ax, None, None), out_axes=ax)(
+                    mid, ring_read, bulk), ring_read
 
         rows = (iids, slots, st, inbox, tick_mask, campaign_mask,
                 propose_n, isolate, transfer_to, read_req, conf_req, wipe)

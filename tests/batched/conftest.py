@@ -219,7 +219,18 @@ os.environ.setdefault("ETCD_TPU_TRANSFER_GUARD", "disallow")
 # 8 groups: the cell's own sizes, as every tests/benchmark cell runs
 # its own). Budget 51 -> 53: raised by exactly the two, the headroom of
 # 1 kept.
-ROUND_STEP_SHAPE_BUDGET = 53
+# ISSUE 51 AUDIT: 53 used of 54. test_bulk_lane builds its engines on
+# two keys that are there (the cell `engine100k-r3-deeplog`'s sizes at 8
+# groups, tests/benchmark/test_catchup.py's; the values of
+# test_differential_wide.make_pair(2, 10, auto_compact=True)), and the
+# append lane in one piece beside the split one is another trace of the
+# same key's round (the round answers in the form it is handed: no
+# field, no flag, no key). test_ring_layout adds ONE: that cell's sizes
+# at 256 groups (768 rows, as ISSUE 49's case and for its reason),
+# traced, lowered and compiled for a described v5e once (11 s) and
+# never built for the CPU. Budget 53 -> 54: raised by exactly the one,
+# the headroom of 1 kept.
+ROUND_STEP_SHAPE_BUDGET = 54
 
 
 @pytest.fixture(scope="session", autouse=True)

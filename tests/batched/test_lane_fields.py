@@ -28,10 +28,12 @@ import pytest
 from etcd_tpu.batched import MultiRaftEngine
 from etcd_tpu.batched import step as step_mod
 from etcd_tpu.batched.engine import CTL_FROM, CTL_READS, CTL_TO
-from etcd_tpu.batched.step import (KIND_APP, KIND_HB_RESP, LANE_FIELDS,
-                                   NUM_KINDS, T_APP_RESP, MsgSlots,
-                                   lane_slot_bytes, make_step_round, route,
-                                   split_lanes, stack_lanes)
+from etcd_tpu.batched.step import (BULK_APP, KIND_APP, KIND_HB_RESP,
+                                   LANE_FIELDS, NUM_KINDS, T_APP, T_APP_RESP,
+                                   BulkLane, MsgSlots, app_head,
+                                   lane_occupancy, lane_slot_bytes,
+                                   make_step_round, route, split_lanes,
+                                   stack_lanes)
 
 from . import test_differential as differential
 from . import test_rare_lanes as rare
@@ -156,6 +158,50 @@ def test_split_then_stack_is_the_identity_on_what_the_writers_write(written):
     for k in range(NUM_KINDS):
         assert [f for f, x in zip(MsgSlots._fields, lanes[k])
                 if x is not None] == list(LANE_FIELDS[k])
+
+
+@pytest.mark.parametrize("scenario", ["steady appends", "a cut-off and heal"])
+def test_the_writers_state_no_entry_past_those_a_message_carries(
+        written, scenario):
+    """The head/tail table (ISSUE 51) against the writers: the
+    differential configuration (E=16, P=4) splits its append lane at
+    ``app_head`` = 5, and in every inbox its scenarios saw (the round
+    ran split: the oracle stood beside it) a valid append's
+    ``ent_terms`` are zero from column ``n_ents`` on, so the tail holds
+    something only where some MsgApp states more than the head holds,
+    which is the occupancy vector's BULK_APP; split so and stacked, the
+    slots are the slots."""
+    cfg = differential.make_pair()[0]
+    head = app_head(cfg)
+    assert head == 5 and cfg.max_ents_per_msg == 16
+    tails = 0
+    for t, m in enumerate(written[scenario]):
+        app = m.valid[:, :, KIND_APP]
+        n_ents = np.where(app & (m.type[:, :, KIND_APP] == T_APP),
+                          m.n_ents[:, :, KIND_APP], 0)
+        past = np.arange(cfg.max_ents_per_msg) >= n_ents[..., None]
+        ents = np.where(app[..., None], m.ent_terms[:, :, KIND_APP], 0)
+        assert not np.where(past, ents, 0).any(), (scenario, t)
+        m = MsgSlots(*map(jnp.asarray, m))
+        lanes = split_lanes(m, head)
+        lane = lanes[KIND_APP]
+        assert isinstance(lane, BulkLane)
+        assert lane.ent_terms.shape == app.shape + (head,)
+        assert lane.ent_tail.shape == app.shape + (16 - head,)
+        assert all(isinstance(lanes[k], MsgSlots)
+                   for k in range(NUM_KINDS) if k != KIND_APP)
+        bulk = bool(lane_occupancy(lanes)[BULK_APP])
+        assert bulk == bool((n_ents > head).any())
+        stated = np.where(app[..., None], np.asarray(lane.ent_tail), 0).any()
+        assert stated == bulk, (scenario, t)
+        tails += bulk
+        for f, x, y in zip(MsgSlots._fields, stack_lanes(lanes), m):
+            assert x.dtype == y.dtype and x.shape == y.shape, f
+            at = m.valid if f != "ent_terms" else m.valid[..., None]
+            assert (np.where(at, x, 0) == np.where(at, y, 0)).all(), (t, f)
+    # (Both scenarios' appends stay within the head, the heal's short
+    # catch-up included: tests/batched/test_bulk_lane.py has the tails.)
+    assert tails == 0
 
 
 # -- (b): nothing rides a field outside the table --------------------------------------
@@ -313,3 +359,15 @@ def test_lane_slot_bytes():
     # and the heartbeat pairs run, 101 of 152.
     assert lane_slot_bytes(4).sum() == 132
     assert lane_slot_bytes(4)[[1, 2, 4, 5]].sum() == 101
+
+
+def test_lane_slot_bytes_of_a_split_lane_are_head_and_tail():
+    """The deep-log cell's append slot (E=64, head 3): 45 bytes in every
+    round the lane runs and 244 more in the rounds ``bulk_rounds()``
+    counts; together the 289 of the lane in one piece."""
+    whole, split = lane_slot_bytes(64), lane_slot_bytes(64, 3)
+    assert whole.tolist() == [21, 289, 17, 10, 22, 13]
+    assert split.tolist() == [21, 45, 17, 10, 22, 13, 244]
+    assert split[KIND_APP] + split[-1] == whole[KIND_APP]
+    assert lane_slot_bytes(4, 0).tolist() == lane_slot_bytes(4).tolist()
+    assert lane_slot_bytes(16, 5).tolist() == [21, 53, 17, 10, 22, 13, 44]

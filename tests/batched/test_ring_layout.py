@@ -381,3 +381,169 @@ def test_the_p_column_spelling_on_ticks_path_is_sunk_into_that_cond(
     assert sunk and cond.branches[0] > 0, cond
     assert _columns(cfg, text, "raft_tick") > 0
     assert loop.ring_ring_minor == 0
+
+
+# -- the append lane in two halves, in a compiled closed loop (ISSUE 51) --------------
+
+# The deep-log cell's configuration (E = 64, K = 32 runs, a head of 3
+# columns) at SINK_GROUPS groups: the shapes a steady round must not
+# hold are then [768, 64, 32] (the run table's passes over E entries),
+# [768, 3, 64] (an append's entries a sender in one piece) and, but for
+# the hand-through, [768, 3, 61] (their tail).
+
+
+@pytest.fixture(scope="module")
+def deep_loop(one_chip):
+    """(configuration, text, the text and the loop as
+    `tools/loop_cost.py` reads them) of the compiled 64-round closed
+    loop of `engine100k-r3-deeplog`'s configuration at SINK_GROUPS
+    groups, under a fault schedule as its cell hands it one."""
+    import importlib.util
+    import os
+
+    import numpy as np
+
+    from etcd_tpu.batched import BatchedConfig, MultiRaftEngine
+
+    from .test_scopes import ROUNDS, sizes
+
+    spec = importlib.util.spec_from_file_location(
+        "loop_cost", os.path.join(os.path.dirname(__file__), "..", "..",
+                                  "tools", "loop_cost.py"))
+    loop_cost = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(loop_cost)
+    with persistent_cache_off():
+        eng = MultiRaftEngine(BatchedConfig(**dict(
+            sizes("engine100k-r3-deeplog"), num_groups=SINK_GROUPS)))
+        cfg = eng.cfg
+        sched, _ = eng._schedule(
+            np.zeros((ROUNDS, cfg.num_replicas), bool), ROUNDS)
+        args = (eng.state, eng.inbox, eng._zeros_b, eng._zeros_i, eng._tel(),
+                eng._flt(), eng._lanes + (eng._catchup,), sched, ROUNDS,
+                None, None)
+        args = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip)
+            if hasattr(x, "shape") else x, args)
+        text = eng._closed_loop.lower(*args).compile().as_text()
+    return cfg, text, loop_cost._Text(text), loop_cost.read(text)
+
+
+def _reached(t, name, through_conds=True):
+    """The lines of computation `name` and of everything it calls
+    (fusions, calls, and with `through_conds` every branch of its
+    conditionals)."""
+    out, todo, seen = [], [name], set()
+    while todo:
+        at = todo.pop()
+        if at in seen or at not in t.computations:
+            continue
+        seen.add(at)
+        for line in t.computations[at]:
+            out.append(line)
+            branches = t._branches(line)
+            if branches is not None:
+                if through_conds:
+                    todo.extend(branches)
+                continue
+            todo.extend(re.findall(r"(?:calls|to_apply)=%([^ ,)]+)", line))
+    return out
+
+
+def _cond_line(t, loop, cond):
+    return next(x for x in t.computations[loop.body] if re.match(
+        rf"\s*(?:ROOT )?%{re.escape(cond.name)} = ", x))
+
+
+def _wide(cfg):
+    """The shapes of an append at its whole width, as the text prints
+    them: the run table's [N, E, K] passes, the entries [N, R, E] and
+    [N, E], and the tail's [N, R, E - Wn] and [N, E - Wn]."""
+    from etcd_tpu.batched.step import app_head
+
+    n, r, e = cfg.num_instances, cfg.num_replicas, cfg.max_ents_per_msg
+    tail = e - app_head(cfg)
+    assert tail == 61
+    return {"passes": f"[{n},{e},{cfg.log_runs}]",
+            "entries": (f"[{n},{r},{e}]", f"[{n},{e}]"),
+            "tail": (f"[{n},{r},{tail}]", f"[{n},{tail}]")}
+
+
+def _split_conds(cfg, t, loop):
+    """(deliver's append switch, emit's tail cond, route's tail
+    switch) of the compiled loop."""
+    tail = _wide(cfg)["tail"][0]
+    result = lambda c: _cond_line(t, loop, c).split(" conditional(")[0]  # noqa: E731
+    deliver = [c for c in loop.conds
+               if "raft_deliver" in c.op_name and len(c.branches) == 3]
+    emit = [c for c in loop.conds if "raft_emit" in c.op_name
+            and len(c.branches) == 2 and tail in result(c)]
+    route = [c for c in loop.conds if "raft_route" in c.op_name
+             and len(c.branches) == 3 and tail in result(c)]
+    assert len(deliver) == len(emit) == len(route) == 1
+    return deliver[0], emit[0], route[0]
+
+
+def test_the_split_loop_holds_no_instance_major_plane(deep_loop):
+    """Rule 6 of ROADMAP's queue S: at E >= 32 a reduce over the
+    senders of the [R, E] entries laid the append lane's whole cond out
+    instance-major (`_gather_msg(chain=)` picks by selects); the
+    three-way switch and the two halves must not bring it back. And the
+    run table stands N-minor wherever it stands, through the switch
+    included."""
+    cfg, text, t, loop = deep_loop
+    n = cfg.num_instances
+    assert text.count(f"s32[{n},{cfg.num_replicas}]{{1,0") == 0
+    table = f"[{n},2,{cfg.log_runs}]"
+    layouts = set(re.findall(re.escape(table) + r"\{([0-9,]+)", text))
+    assert layouts and all(x.startswith("0,") for x in layouts), layouts
+    assert len(loop.conds) == 17
+    switch, _, _ = _split_conds(cfg, t, loop)
+    line = _cond_line(t, loop, switch)
+    assert table + "{0," in line.split(" conditional(")[0]
+    for branch in t._branches(line)[1:]:
+        here = "\n".join(_reached(t, branch))
+        assert table + "{0," in here
+        assert not re.search(re.escape(table) + r"\{[12]", here)
+
+
+def test_a_steady_round_holds_nothing_of_an_appends_whole_width(deep_loop):
+    """What a round of steady appends runs (the loop's body outside its
+    conditionals, the append switch's head arm, emit's not-bulk branch,
+    route's untouched branch of the tail) holds no value of the whole
+    width: no [N, 64, 32] pass of the run table, no [N, 3, 64] or
+    [N, 64] entries, and of the tail's shape only the hand-through: a
+    parameter in, the same out, priced 0 cycles, no copy."""
+    cfg, text, t, loop = deep_loop
+    wide = _wide(cfg)
+    switch, emit, route = _split_conds(cfg, t, loop)
+    skipped, head, whole = t._branches(_cond_line(t, loop, switch))
+    not_bulk, bulk = t._branches(_cond_line(t, loop, emit))
+    untouched, wiped, exchanged = t._branches(_cond_line(t, loop, route))
+    steady = {
+        "the body": _reached(t, loop.body, through_conds=False),
+        "the head arm": _reached(t, head),
+        "emit's not-bulk branch": _reached(t, not_bulk),
+        "route's untouched branch": _reached(t, untouched)}
+    shape_of = lambda line: line.split(" = ", 1)[-1].split("(")[0]  # noqa: E731
+    for where, lines in steady.items():
+        for line in lines:
+            made = shape_of(line)
+            assert wide["passes"] not in made, (where, line[:200])
+            assert not any(x in made for x in wide["entries"]), (
+                where, line[:200])
+            if any(x in made for x in wide["tail"]):
+                op = re.match(r"^\s*(?:ROOT )?%[^ ]+ = .*?\s([a-z][a-z0-9-]*)\(",
+                              line).group(1)
+                assert op in ("parameter", "get-tuple-element", "tuple",
+                              "conditional", "bitcast"), (where, line[:200])
+    assert emit.branches[0] == 0 and emit.branches[1] > 0, emit
+    assert route.branches[0] == 0 and min(route.branches[1:]) > 0, route
+    # The control: the whole arm and emit's bulk branch are where the
+    # width lives.
+    assert any(wide["passes"] in shape_of(x) for x in _reached(t, whole))
+    assert any(wide["tail"][0] in shape_of(x) for x in _reached(t, bulk))
+    # And the head arm is the cheaper one (by a fifth at the cell's
+    # size, 4.6 M estimated cycles for 25.6 M; at 768 rows the fixed
+    # part of a fusion leads).
+    assert switch.branches[0] < switch.branches[1] < switch.branches[2] / 1.3
