@@ -7,10 +7,18 @@ a cell compiles one scan program. After the window the whole state is
 read back once; a seeded sample of groups is compared with
 ``reference.shadow.ShadowCluster`` stepped through the same schedule.
 
-The scan's lane counter (``MultiRaftEngine.lane_rounds``) is read where
-set-up ends and as the window closes, never between two calls of the
-window; ``window_counters`` hands both readings to the harness's
-``raw`` (``lanes``), for ``readers/lanes.py``.
+The scan's occupancy counters (``occupancy``: ``lane_rounds``,
+``rare_rounds``, ``bulk_rounds``, ``emit_ring_rounds`` of
+``MultiRaftEngine``) are read where set-up ends, as the window closes
+and, when the generator says the trace has stopped (``traced_closes``),
+once more after the traced calls, never between two calls that are
+timed or traced; ``window_counters`` hands the readings to the
+harness's ``raw`` (``occupancy``), for ``readers/lanes.py`` and
+``readers/trace.route_roofline_pct``.
+
+What every engine driver shares stands here as functions: ``fence``
+(the wait for a scan, under a span of its own), ``occupancy``,
+``exchange_shape``, ``traced_closes`` and ``window_occupancy``.
 """
 
 from __future__ import annotations
@@ -24,6 +32,79 @@ from ..compare import Check, engine_checks
 from ..harness import say
 
 
+def fence(eng) -> None:
+    """Wait until the engine's last scan has run: every
+    ``block_until_ready`` of the engine drivers, under the span
+    ``engine.fence`` of the recorder the engine's own spans use
+    (``etcd_tpu.obs.spans``: a ``TraceAnnotation`` on the device
+    trace's clock), so that the device's idle time while the host sits
+    here is put down to a name (``reduce/gaps.py``)."""
+    import jax
+
+    from etcd_tpu.obs import spans
+
+    with spans.span("engine.fence", engine=getattr(eng, "_serial", 0)):
+        jax.block_until_ready(eng.state.commit)
+
+
+def occupancy(driver) -> dict:
+    """One reading of the counters the closed loop keeps in its carry
+    of what a round ran (a few integers, a host gather each; never read
+    between two timed calls), with the driver's calls so far. Three of
+    them count a round in which ANY tile took the branch (``lanes``,
+    ``rare``, ``bulk``); ``ring`` counts tile-rounds."""
+    eng = driver.eng
+    return {"lanes": eng.lane_rounds().tolist(),
+            "rare": eng.rare_rounds().tolist(),
+            "bulk": eng.bulk_rounds(),
+            "ring": eng.emit_ring_rounds(),
+            "calls": driver.calls}
+
+
+def exchange_shape(eng) -> dict:
+    """What one round's exchange is made of, for ``reduce/roofline
+    .lane_bytes``: the rows, the R slots a row addresses, and the bytes
+    of one slot of each kind lane as the program carries it
+    (``step.lane_slot_bytes`` at the configuration's E and the append
+    lane's head, ``step.app_head``; with a head a seventh number, the
+    tail's). ``ring_tiles``: what ``emit_ring_rounds`` counts a round
+    at the most (the scan's tiles; over nodes, a node's)."""
+    from etcd_tpu.batched import step
+
+    cfg = eng.cfg
+    head = step.app_head(cfg)
+    placed = getattr(eng, "_nodes", None) is not None
+    return {"rows": int(cfg.num_instances),
+            "replicas": int(cfg.num_replicas),
+            "app_head": int(head),
+            "slot_bytes": step.lane_slot_bytes(
+                cfg.max_ents_per_msg, head).tolist(),
+            "ring_tiles": int(eng._tiles) * (
+                int(cfg.num_replicas) if placed else 1)}
+
+
+def traced_closes(driver) -> None:
+    """The reading after the traced calls: the generators call it right
+    after the trace is stopped, outside every timed and traced loop, so
+    that the traced calls' own lanes are this reading less the closing
+    mark's (``readers/trace.traced_runs``)."""
+    driver.marks["traced"] = {"occupancy": occupancy(driver)}
+
+
+def window_occupancy(driver, marks: dict) -> dict:
+    """``raw["occupancy"]``, what the occupancy's readers read: every
+    counter at the window's two marks and, where ``traced_closes`` took
+    it, after the traced calls (else ``None``); each reading says the
+    calls the driver had made, and a reader holds the difference to the
+    calls that were traced; with it the exchange's shape. Reads
+    nothing: asked twice, it says the same."""
+    return {"occupancy": {
+        "before": marks["open"]["occupancy"],
+        "after": marks["close"]["occupancy"],
+        "traced": marks.get("traced", {}).get("occupancy"),
+        **exchange_shape(driver.eng)}}
+
+
 class Driver:
     def __init__(self, config: dict, traffic: dict, seed: int,
                  workdir: str) -> None:
@@ -34,10 +115,9 @@ class Driver:
         self.eng = None
         self.calls = 0
         self.settle_rounds = 0
-        self.lanes: dict = {}  # lane_rounds() before and after the window
+        self.marks: dict = {}  # occupancy() round the window
 
     def setup(self, load, gen) -> None:
-        import jax
         import jax.numpy as jnp
 
         from etcd_tpu.batched import BatchedConfig, MultiRaftEngine
@@ -77,32 +157,36 @@ class Driver:
         self.props = props.at[jnp.asarray(leaders)].set(
             load["proposals_per_round"])
         self.call()  # warm-up: the window's own program and arguments
-        jax.block_until_ready(eng.state.commit)
+        fence(eng)
         # Nothing runs between here and the window's first call, and
         # ``generators/engine_rounds.run`` starts its clock before it
         # calls ``window_opens``: read here, the window pays nothing.
-        self.lanes = {"before": eng.lane_rounds().tolist()}
+        self.marks = {"open": {"occupancy": occupancy(self)}}
         say("engine", build_elect_warm_s=time.perf_counter() - t0,
             deliver=cfg.deliver_shape, lanes_minor=cfg.lanes_minor,
             leaders_per_slot=np.bincount(slots, minlength=r).tolist())
 
     def call(self) -> None:
         """One scan of ``rounds_per_call`` rounds, fenced."""
-        import jax
-
         self.eng.run_rounds(self.rpc, tick=self.tick, propose_n=self.props)
-        jax.block_until_ready(self.eng.state.commit)
+        fence(self.eng)
         self.calls += 1
 
     def window_opens(self) -> None:
         pass
 
     def window_closes(self) -> None:
-        self.lanes["after"] = self.eng.lane_rounds().tolist()
+        self.marks["close"] = {"occupancy": occupancy(self)}
+
+    def traced_closes(self) -> None:
+        traced_closes(self)
 
     def window_counters(self) -> dict:
-        """For the harness's ``raw``: what ``readers/lanes.py`` reads."""
-        return {"lanes": self.lanes}
+        """For the harness's ``raw``: what ``readers/lanes.py`` and the
+        roofline read. A caller that closed no window gets nothing."""
+        if "close" not in self.marks:
+            return {}
+        return window_occupancy(self, self.marks)
 
     # -- the comparison, outside the window -------------------------------------------
 

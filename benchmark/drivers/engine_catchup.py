@@ -34,9 +34,7 @@ end of a period, 1,024 rounds after a heal:
 ``window_counters`` hands the readers (``readers/catchup.py``) the
 engine's catch-up counts at the window's two marks, the replicas that
 returned inside it (the schedule's heals x groups) and the depth of the
-leaders' logs as the window closed. The cell's own per-layer entries
-wait in ``parked/catchup.json`` (its note says for what), so ``check``
-says their values on a ``[bench:catchup_layers]`` line instead.
+leaders' logs as the window closed.
 """
 
 from __future__ import annotations
@@ -52,6 +50,7 @@ from ..compare import Check
 from ..fault_checks import quiet_checks, window_checks
 from ..harness import say
 from . import engine_faults
+from .engine import fence
 
 LEADER = 2  # BatchedState.role
 # Controls (``check(control=...)``; ``benchmark/control_faults.py`` runs
@@ -72,7 +71,6 @@ class Driver(engine_faults.Driver):
         self.reference_window: Optional[int] = None
 
     def setup(self, load, gen) -> None:
-        import jax
         import jax.numpy as jnp
 
         from etcd_tpu.batched import BatchedConfig, MultiRaftEngine
@@ -109,7 +107,7 @@ class Driver(engine_faults.Driver):
         self.props = jnp.full((cfg.num_instances,),
                               load["proposals_per_round"], jnp.int32)
         self.call()  # warm-up: the window's own program and arguments
-        jax.block_until_ready(eng.state.commit)
+        fence(eng)
         self._mark("open")
         say("engine", build_elect_warm_s=time.perf_counter() - t0,
             deliver=cfg.deliver_shape, lanes_minor=cfg.lanes_minor,
@@ -168,29 +166,6 @@ class Driver(engine_faults.Driver):
                 a["round"], b["round"] - a["round"]),
             log_depth_entries=b.get("depth"))
 
-    def say_layers(self, raw: dict) -> None:
-        """The cell's own per-layer metrics on a line of the run's
-        output: ``BENCHMARK.json`` lacks their entries
-        (``parked/catchup.json`` says why), so no result line holds
-        them. Each through its file's reader, as the harness would."""
-        import importlib
-        import json
-        import os
-
-        base = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        with open(os.path.join(base, "parked", "catchup.json")) as f:
-            names = [m["name"] for m in json.load(f)["per_layer"]]
-        ctx, out = {"raw": raw}, {}
-        for name in names:
-            with open(os.path.join(base, "layer_metrics",
-                                   name + ".json")) as f:
-                spec = json.load(f)
-            mod, _, fn = spec["reader"].partition(".")
-            read = getattr(importlib.import_module(
-                f"benchmark.readers.{mod}"), fn)
-            out[name] = read(ctx, **spec["params"])
-        say("catchup_layers", **out)
-
     # -- the comparison, outside the window ---------------------------------------------
 
     def finish(self) -> dict:
@@ -231,7 +206,6 @@ class Driver(engine_faults.Driver):
             control = CONTROLS[1]
         if self.final is None:
             self.final = self.finish()
-            self.say_layers(dict(raw, **self.window_counters()))
         state, cfg = self.final["state"], self.cfg
         t0 = time.perf_counter()
         sample = self.sample(load)

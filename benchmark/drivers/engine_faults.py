@@ -47,8 +47,8 @@ one call more)
 
 The cell's per-layer entries that read the telemetry plane
 (``layer_metrics/election.*.json``) and the lane counter
-(``round.lanes_run``) read what ``window_counters`` hands the
-generator's ``raw``.
+(``round.lanes_run``) and the other occupancy counters read what
+``window_counters`` hands the generator's ``raw``.
 """
 
 from __future__ import annotations
@@ -63,6 +63,7 @@ from ..compare import Check, engine_checks
 from ..fault_checks import (group_checks, quiet_checks, schedule_classes,
                             window_checks)
 from ..harness import say
+from .engine import fence, occupancy, traced_closes, window_occupancy
 
 # Controls (``check(control=...)``): each breaks, in the reference, one
 # guarantee the configuration states; the comparison then has to fail.
@@ -101,7 +102,6 @@ class Driver:
         self.derailed: List[int] = []  # of the last reference's groups
 
     def setup(self, load, gen) -> None:
-        import jax
         import jax.numpy as jnp
 
         from etcd_tpu.batched import BatchedConfig, MultiRaftEngine
@@ -151,7 +151,7 @@ class Driver:
         self.props = jnp.full((cfg.num_instances,),
                               load["proposals_per_round"], jnp.int32)
         self.call()  # warm-up: the window's own program and arguments
-        jax.block_until_ready(eng.state.commit)
+        fence(eng)
         # For a caller that opens no window (``benchmark/control.py``):
         # everything after the warm-up is then the window.
         self._mark("open")
@@ -163,13 +163,11 @@ class Driver:
     def call(self) -> None:
         """One scan of ``rounds_per_call`` rounds of the schedule,
         fenced."""
-        import jax
-
         self.eng.run_rounds(
             self.rpc, tick=self.tick, propose_n=self.props,
             isolate=self.gen.schedule(self.load, self.rounds_done,
                                       self.rpc))
-        jax.block_until_ready(self.eng.state.commit)
+        fence(self.eng)
         self.calls += 1
         self.rounds_done += self.rpc
 
@@ -183,7 +181,7 @@ class Driver:
         self.marks[name] = {
             "counters": {n: int(v) for n, v in zip(TM_NAMES, totals)},
             "commit": self.eng.commits().max(axis=1),
-            "lanes": self.eng.lane_rounds().tolist(),
+            "occupancy": occupancy(self),
         }
 
     def window_opens(self) -> None:
@@ -193,13 +191,16 @@ class Driver:
     def window_closes(self) -> None:
         self._mark("close")
 
+    def traced_closes(self) -> None:
+        traced_closes(self)
+
     def window_counters(self) -> dict:
-        """For the generator's ``raw``: what ``readers/telemetry.py``
-        and ``readers/lanes.py`` read."""
+        """For the generator's ``raw``: what ``readers/telemetry.py``,
+        ``readers/lanes.py`` and the roofline read."""
         a, b = self.marks["open"], self.marks["close"]
         return {
             "telemetry": {"before": a["counters"], "after": b["counters"]},
-            "lanes": {"before": a["lanes"], "after": b["lanes"]},
+            **window_occupancy(self, self.marks),
             "entries_committed": int((b["commit"] - a["commit"]).sum()),
         }
 

@@ -61,6 +61,7 @@ from ..load_checks import (conservation_checks, count_checks, run_checks,
                            sampled_engine_checks, window_checks)
 from ..reconf_checks import sample_checks
 from . import engine_reconf
+from .engine import fence
 
 # Controls (``check(control=...)``): each steps the reference on other
 # offers than the program drew; the comparison then has to fail. The
@@ -90,8 +91,6 @@ class Driver(engine_reconf.Driver):
         self.quiet_rounds = 0  # after the timed rounds, nothing offered
 
     def setup(self, load, gen) -> None:
-        import jax
-
         from etcd_tpu.batched import BatchedConfig, MultiRaftEngine
         from etcd_tpu.batched.telemetry import TM_INDEX
         from etcd_tpu.obs import spans
@@ -155,7 +154,7 @@ class Driver(engine_reconf.Driver):
                 axis=1, dtype=np.int64)
                for name in ("proposals_dropped", "elections_won")}}
         self.call()  # warm-up: the window's own program and arguments
-        jax.block_until_ready(eng.state.commit)
+        fence(eng)
         self._mark("open")
         say("engine", build_elect_warm_s=time.perf_counter() - t0,
             deliver=cfg.deliver_shape, lanes_minor=cfg.lanes_minor,
@@ -165,10 +164,8 @@ class Driver(engine_reconf.Driver):
     def call(self) -> None:
         """One scan of ``rounds_per_call`` rounds of the load plane,
         fenced."""
-        import jax
-
         self.eng.run_rounds(self.rpc, tick=self.tick, load=self.plane)
-        jax.block_until_ready(self.eng.state.commit)
+        fence(self.eng)
         self.calls += 1
         self.rounds_done += self.rpc
 
@@ -202,10 +199,8 @@ class Driver(engine_reconf.Driver):
         """One closing call with nothing offered: what is in flight
         lands, and every replica ends level and committed."""
         if not self.quiet_rounds:
-            import jax
-
             self.eng.run_rounds(self.rpc, tick=self.tick, load=self.nothing)
-            jax.block_until_ready(self.eng.state.commit)
+            fence(self.eng)
             self.quiet_rounds = self.rpc
 
     def finish(self) -> dict:

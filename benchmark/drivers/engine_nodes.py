@@ -15,7 +15,8 @@ they read from one chip; the reference is
 ``reference.shadow_replace_nodes.NodesCluster``, which knows nothing of
 chips; and after every call the engine's count of lanes that crossed
 the interconnect (``eng.lane_exchanges()``, a few integers) is noted,
-for ``readers/nodes.py``.
+for ``readers/nodes.py``, with the bytes of a slot of each lane as the
+program carries it (``drivers/engine.exchange_shape``).
 
 ``correct`` is ``engine_replace``'s, every limit 0, on what the timed
 scans left on the four chips.
@@ -30,6 +31,7 @@ import numpy as np
 
 from ..harness import say
 from . import engine_replace
+from .engine import exchange_shape, fence
 
 CONTROLS = engine_replace.CONTROLS
 
@@ -99,7 +101,7 @@ class Driver(engine_replace.Driver):
         # first.
         self.crossed = [eng.lane_exchanges().tolist()]
         self.call()  # warm-up: the window's own program and arguments
-        jax.block_until_ready(eng.state.commit)
+        fence(eng)
         self._mark("open")
         say("engine", build_elect_warm_s=time.perf_counter() - t0,
             deliver=cfg.deliver_shape, lanes_minor=cfg.lanes_minor,
@@ -124,15 +126,15 @@ class Driver(engine_replace.Driver):
         that had crossed the interconnect as each call ended, with the
         calls at which the window opened and closed and the shapes one
         exchange has."""
-        cfg = self.cfg
+        shape = exchange_shape(self.eng)
         return dict(super().window_counters(), ici={
             "after_call": [list(c) for c in self.crossed],
             "open": self.marks["open"]["call"],
             "close": self.marks["close"]["call"],
             "tile_rows": int(self.eng.tile_rows),
             "tiles": int(self.eng._tiles),
-            "replicas": cfg.num_replicas,
-            "ents": cfg.max_ents_per_msg,
+            "replicas": shape["replicas"],
+            "slot_bytes": shape["slot_bytes"],
         })
 
     def read_state(self) -> dict:

@@ -71,6 +71,7 @@ from ..fault_checks import group_checks, schedule_classes
 from ..harness import say
 from ..reconf_checks import (MASKS, membership_checks, run_checks,
                              sample_checks, window_checks)
+from .engine import fence, occupancy, traced_closes, window_occupancy
 
 # Controls (``check(control=...)``): each breaks, in the reference, one
 # guarantee the configuration states; the comparison then has to fail.
@@ -117,7 +118,6 @@ class Driver:
         self.derailed: List[int] = []  # of the last reference's groups
 
     def setup(self, load, gen) -> None:
-        import jax
         import jax.numpy as jnp
 
         from etcd_tpu.batched import BatchedConfig, MultiRaftEngine
@@ -172,7 +172,7 @@ class Driver:
         self.props = jnp.full((cfg.num_instances,),
                               load["proposals_per_round"], jnp.int32)
         self.call()  # warm-up: the window's own program and arguments
-        jax.block_until_ready(eng.state.commit)
+        fence(eng)
         # For a caller that opens no window (``benchmark/control.py``):
         # everything after the warm-up is then the window.
         self._mark("open")
@@ -212,13 +212,11 @@ class Driver:
     def call(self) -> None:
         """One scan of ``rounds_per_call`` rounds of the schedule,
         fenced."""
-        import jax
-
         isolate, control = self._arrays(
             self.gen.rows(self.load, self.rounds_done, self.rpc))
         self.eng.run_rounds(self.rpc, tick=self.tick, propose_n=self.props,
                             isolate=isolate, control=control)
-        jax.block_until_ready(self.eng.state.commit)
+        fence(self.eng)
         self.calls += 1
         self.rounds_done += self.rpc
 
@@ -237,7 +235,7 @@ class Driver:
             "reads": counters[:, TM_INDEX["reads_confirmed"]].reshape(
                 g_n, r).sum(axis=1, dtype=np.int64),
             "applied": counters[:, TM_INDEX["conf_changes_applied"]].copy(),
-            "lanes": self.eng.lane_rounds().tolist(),
+            "occupancy": occupancy(self),
             "rounds_done": self.rounds_done,
         }
 
@@ -248,14 +246,18 @@ class Driver:
     def window_closes(self) -> None:
         self._mark("close")
 
+    def traced_closes(self) -> None:
+        traced_closes(self)
+
     def window_counters(self) -> dict:
         """For the generator's ``raw``: what ``readers/telemetry.py``,
-        ``readers/reconf.py`` and ``readers/lanes.py`` read."""
+        ``readers/reconf.py``, ``readers/lanes.py`` and the roofline
+        read."""
         a, b = self.marks["open"], self.marks["close"]
         return {
             "telemetry": {"before": a["counters"], "after": b["counters"]},
             "watch": {"before": a["watch"], "after": b["watch"]},
-            "lanes": {"before": a["lanes"], "after": b["lanes"]},
+            **window_occupancy(self, self.marks),
             "entries_committed": int((b["commit"] - a["commit"]).sum()),
         }
 

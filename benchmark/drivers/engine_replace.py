@@ -72,6 +72,7 @@ from ..reconf_checks import sample_checks
 from ..replace_checks import (empty_slot_checks, live_view,
                               membership_checks, run_checks, window_checks)
 from . import engine_reconf
+from .engine import fence
 from .engine_reconf import _Derailed
 
 # Controls (``check(control=...)``): each breaks, in the reference, one
@@ -82,7 +83,6 @@ CONTROLS = ("snapshot_restored_without_its_confstate",
 
 class Driver(engine_reconf.Driver):
     def setup(self, load, gen) -> None:
-        import jax
         import jax.numpy as jnp
 
         from etcd_tpu.batched import BatchedConfig, MultiRaftEngine
@@ -138,7 +138,7 @@ class Driver(engine_reconf.Driver):
         self.props = jnp.full((cfg.num_instances,),
                               load["proposals_per_round"], jnp.int32)
         self.call()  # warm-up: the window's own program and arguments
-        jax.block_until_ready(eng.state.commit)
+        fence(eng)
         self._mark("open")
         say("engine", build_elect_warm_s=time.perf_counter() - t0,
             deliver=cfg.deliver_shape, lanes_minor=cfg.lanes_minor,

@@ -49,26 +49,14 @@ after the run has gone on to the end of the current cycle length:
   of each class the batch holds (``sample``).
 
 The per-layer entries ``trickle.*`` read what ``window_counters``
-hands the generator's ``raw``. The accepted entries that list other
-cells and whose layers this cell runs too (``LISTED_ELSEWHERE``: their
-lists are held to those cells by their tests, so this cell's name is
-not appended and the harness does not hand them this run) are said on
-the run's own ``[bench:trickle_layers]`` line as the driver closes
-(``close``), each by its own reader, as far as the host's counters and
-spans give them; those that read the device trace are on the harness's
-``[bench:trace]`` line (``scope_s``: a share is a scope over their
-sum), and ``route.roofline_pct`` is the line's
-``route_bytes_a_round`` times the traced rounds over ``raft_route``'s
-seconds and the chip's peak.
+hands the generator's ``raw``; the accepted entries whose layers this
+cell runs too list it and read the same ``raw`` and the trace.
 """
 
 from __future__ import annotations
 
 import inspect
-import json
-import os
 import time
-from importlib import import_module
 from typing import List, Optional
 
 import numpy as np
@@ -81,6 +69,7 @@ from ..trickle_checks import (fresh_slot_checks, membership_checks,
                               move_checks, resting_checks, run_checks,
                               window_checks)
 from . import engine_replace
+from .engine import fence
 from .engine_reconf import _Derailed
 
 # Controls (``check(control=...)``): each steps the reference on
@@ -98,26 +87,10 @@ CONTROLS = ("starts_shifted_by_one_round",
 SLACK = 7
 # Batches the sample follows beside the groups never started.
 SAMPLE_BATCHES = 6
-# The accepted entries whose lists a `benchmark` PR should extend with
-# this cell (CHANGES.md, PR 42).
-LISTED_ELSEWHERE = (
-    "round.route_pct", "route.roofline_pct", "round.tick_pct",
-    "round.telemetry_pct", "round.control_pct", "round.propose_pct",
-    "round.emit_pct", "round.unscoped_pct", "round.lanes_run",
-    "scan.tiles_pct", "scan.watch_pct", "scan.carry_pct",
-    "setup.jax_trace_s", "setup.jax_compile_s", "setup.pretrace_s",
-    "setup.unspanned_s", "read.confirmed_per_kgr", "read.rounds_to_confirm")
 
 
 class Driver(engine_replace.Driver):
-    def __init__(self, config: dict, traffic: dict, seed: int,
-                 workdir: str) -> None:
-        super().__init__(config, traffic, seed, workdir)
-        self.traffic = traffic
-        self.raw: Optional[dict] = None  # of the run `check` last saw
-
     def setup(self, load, gen) -> None:
-        import jax
         import jax.numpy as jnp
 
         from etcd_tpu.batched import BatchedConfig, MultiRaftEngine
@@ -192,7 +165,7 @@ class Driver(engine_replace.Driver):
         # batches in flight, each at another point of the cycle.
         for _ in range(load["cycle_rounds"] // self.rpc):
             self.call()
-        jax.block_until_ready(eng.state.commit)
+        fence(eng)
         self._mark("open")
         e, d, n, m = gen.nodes(load)
         say("engine", build_elect_warm_s=time.perf_counter() - t0,
@@ -206,11 +179,9 @@ class Driver(engine_replace.Driver):
     def call(self) -> None:
         """One scan of ``rounds_per_call`` rounds: the cycle and the
         starts, as every call hands them; fenced."""
-        import jax
-
         self.eng.run_rounds(self.rpc, tick=self.tick, propose_n=self.props,
                             control=self.cycle, starts=self.starts)
-        jax.block_until_ready(self.eng.state.commit)
+        fence(self.eng)
         self.calls += 1
         self.rounds_done += self.rpc
 
@@ -366,7 +337,6 @@ class Driver(engine_replace.Driver):
             control = CONTROLS[0]
         if self.final is None:
             self.final = self.finish()
-        self.raw = raw  # for `close`: the harness adds `setup_s` to it
         final = self.final
         state = final["state"]
         cfg = self.cfg
@@ -437,37 +407,3 @@ class Driver(engine_replace.Driver):
         assert checks[0].name == name
         third = np.sort(state["commit"].reshape(g_n, r), axis=1)[:, r - 3]
         return [Check(name, int((third <= 0).sum()), 0)] + checks[1:]
-
-    # -- the accepted entries that list other cells -----------------------------------
-
-    def layers_elsewhere(self, raw: dict) -> dict:
-        """What each of ``LISTED_ELSEWHERE`` reads in this run, by the
-        entry's own file and reader, from the host's counters and
-        spans; an entry that reads the device trace is left out."""
-        base = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        ctx = {"raw": raw, "config": self.config, "traffic": self.traffic,
-               "trace": None, "gaps": None}
-        out = {}
-        for name in LISTED_ELSEWHERE:
-            with open(os.path.join(base, "layer_metrics",
-                                   name + ".json")) as f:
-                spec = json.load(f)
-            mod, _, fn = spec["reader"].partition(".")
-            reader = getattr(import_module("..readers." + mod, __package__),
-                             fn)
-            value = reader(ctx, **spec.get("params", {}))
-            if value is not None:
-                out[name] = value
-        return out
-
-    def close(self) -> None:
-        raw = self.raw
-        if raw is not None and "setup_s" in raw:  # a run that was checked
-            from ..reduce import roofline
-
-            s = self.sizes
-            say("trickle_layers", route_bytes_a_round=roofline.route_bytes(
-                int(s["num_groups"]), int(s["num_replicas"]),
-                int(s["max_ents_per_msg"])), **self.layers_elsewhere(raw))
-            self.raw = None
-        super().close()

@@ -90,8 +90,13 @@ def run(target, load: dict, traffic: dict, seconds: float, probe) -> dict:
         call_s.append(time.perf_counter() - t_call)
     window_s = time.perf_counter() - t0
     target.window_closes()
-    # The trace is taken after the window, over one whole period of the
-    # same program.
+    # The trace is taken after the window, over ``trace_calls`` whole
+    # calls of the same program, from the round the window ended at
+    # (whole calls, not whole periods: which rounds of the period those
+    # are differs from run to run). A whole period only where the
+    # traffic file makes ``trace_calls`` x ``rounds_per_call`` its
+    # ``period_rounds``; else the trace holds those calls' rounds and
+    # the window the rest (PERF.md section 4 says it for each cell).
     traced = 0
     if probe.want:
         probe.start()
@@ -99,6 +104,7 @@ def run(target, load: dict, traffic: dict, seconds: float, probe) -> dict:
             target.call()
             traced += 1
         probe.stop()
+        target.traced_closes()
     groups = target.groups
     rounds = rpc * len(call_s)
     med = statistics.median(call_s)

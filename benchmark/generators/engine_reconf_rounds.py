@@ -152,8 +152,14 @@ def run(target, load: dict, traffic: dict, seconds: float, probe) -> dict:
         call_s.append(time.perf_counter() - t_call)
     window_s = time.perf_counter() - t0
     target.window_closes()
-    # The trace is taken after the window, over one whole period of the
-    # same program.
+    # The trace is taken after the window, over ``trace_calls`` whole
+    # calls of the same program, from the round the window ended at (a
+    # period's first, since the window is whole periods): a whole
+    # period only where the traffic file makes ``trace_calls`` x
+    # ``rounds_per_call`` its ``period_rounds``; else the period's
+    # first calls, and what the schedule does later in the period is
+    # in the window and not in the trace (PERF.md section 4 names it
+    # for each cell).
     traced = 0
     if probe.want:
         probe.start()
@@ -161,6 +167,7 @@ def run(target, load: dict, traffic: dict, seconds: float, probe) -> dict:
             target.call()
             traced += 1
         probe.stop()
+        target.traced_closes()
     groups = target.groups
     rounds = rpc * len(call_s)
     med = statistics.median(call_s)

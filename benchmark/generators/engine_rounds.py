@@ -59,6 +59,7 @@ def run(target, load: dict, traffic: dict, seconds: float, probe) -> dict:
             target.call()
             traced += 1
         probe.stop()
+        target.traced_closes()
     groups = target.groups
     rounds = rpc * len(call_s)
     med = statistics.median(call_s)

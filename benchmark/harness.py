@@ -221,6 +221,10 @@ class Probe:
 
         self.traced_s = time.perf_counter() - self.t_on
         jax.profiler.stop_trace()
+        # Where a traced run's wall goes, beside ``[bench:reduced]``:
+        # the profiler's own collecting and writing of the trace.
+        say("trace_stopped",
+            seconds=time.perf_counter() - self.t_on - self.traced_s)
 
 
 # -- one run --------------------------------------------------------------------
@@ -297,8 +301,12 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool,
             from .reduce.gaps import reduce_gaps
             from .reduce.trace import reduce_trace
 
+            t0 = time.perf_counter()
             ctx["trace"] = reduce_trace(probe.dir, window_s=probe.traced_s)
-            ctx["gaps"] = reduce_gaps(ctx["trace"]["xplane"])
+            ctx["gaps"] = reduce_gaps(ctx["trace"]["xplane"],
+                                      reduced=ctx["trace"])
+            say("reduced", seconds=time.perf_counter() - t0,
+                ops=ctx["trace"]["ops"])
         return ctx, checks
     finally:
         probe.stop()

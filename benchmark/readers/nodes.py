@@ -72,7 +72,7 @@ def mb_per_round(ctx) -> Optional[float]:
         return None
     ici = ctx["raw"]["ici"]
     return roofline_ici.sent_bytes(
-        runs, ici["tile_rows"], ici["replicas"], ici["ents"]
+        runs, ici["tile_rows"], ici["replicas"], ici["slot_bytes"]
     ) / rounds / 1e6
 
 
@@ -90,11 +90,13 @@ def roofline_pct(ctx) -> Optional[float]:
     if runs is None:
         return None
     sent = roofline_ici.sent_bytes(
-        runs, ici["tile_rows"], ici["replicas"], ici["ents"])
+        runs, ici["tile_rows"], ici["replicas"], ici["slot_bytes"])
     secs = red["scope_s"][ICI]
     kind = ctx["device"]["kind"]
     say("roofline", kernel="ici exchange",
         bound_by="interconnect bytes (no arithmetic)", bytes_sent=sent,
-        seconds=secs, lane_runs=runs, achieved_GBps=sent / secs / 1e9,
+        seconds=secs, lane_runs=runs, slot_bytes=ici["slot_bytes"],
+        tile_rows=ici["tile_rows"], replicas=ici["replicas"],
+        achieved_GBps=sent / secs / 1e9,
         peak_GBps=roofline_ici.ici_peak(kind) / 1e9)
     return roofline_ici.roofline_pct(sent, secs, kind)

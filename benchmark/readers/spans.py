@@ -50,7 +50,10 @@ def _engine(ctx) -> Optional[dict]:
     snap = _snapshot()
     calls = ctx["raw"].get("calls")
     if snap and calls:
-        mine = [s for s in snap if s.name.startswith("engine.")]
+        # (A span of the prefix that names no engine is not one of an
+        # engine's own: a driver's, or a later PR's.)
+        mine = [s for s in snap if s.name.startswith("engine.")
+                and s.stats and "engine" in s.stats]
         if mine:
             serial = max(s.stats["engine"] for s in mine)
             mine = sorted((s for s in mine if s.stats["engine"] == serial),

@@ -39,21 +39,43 @@ def scope_pct(ctx, scope: str) -> Optional[float]:
     return scope_share_pct(red, scope) if red else None
 
 
+def traced_runs(ctx):
+    """(how often each of the six lanes ran in the traced calls, how
+    often the append lane's tail did, the driver's ``occupancy``), from
+    the reading the driver took after the traced calls less the one at
+    the window's close; ``None`` where no driver read them, or where
+    the calls between the two readings are not the calls traced."""
+    occ = ctx["raw"].get("occupancy")
+    traced = int(ctx["raw"].get("traced_calls", 0))
+    if not occ or not occ.get("traced") or traced <= 0:
+        return None
+    a, b = occ["after"], occ["traced"]
+    if b["calls"] - a["calls"] != traced:
+        return None
+    runs = [y - x for x, y in zip(a["lanes"], b["lanes"])]
+    return runs, b["bulk"] - a["bulk"], occ
+
+
 def route_roofline_pct(ctx, module: str, rounds_key: str
                        ) -> Optional[float]:
+    """The bytes the lanes that ran in the traced calls must move
+    (``reduce/roofline.lane_bytes``) over the HBM peak, over the
+    seconds the trace holds under ``raft_route``."""
     red = ctx.get("trace")
     m = _module(ctx, module)
-    if not red or m is None or "raft_route" not in red["scope_s"]:
+    got = traced_runs(ctx)
+    if (not red or m is None or got is None
+            or "raft_route" not in red["scope_s"]):
         return None
-    s = ctx["config"]["sizes"]
+    runs, bulk, occ = got
     rounds = m["count"] * int(ctx["traffic"][rounds_key])
-    need = rounds * roofline.route_bytes(
-        int(s["num_groups"]), int(s["num_replicas"]),
-        int(s["max_ents_per_msg"]))
+    need = roofline.lane_bytes(occ["rows"], occ["replicas"], runs,
+                               occ["slot_bytes"], bulk)
     secs = red["scope_s"]["raft_route"]
     say("roofline", kernel="route", bound_by="HBM bytes (no arithmetic)",
-        bytes_needed=need, seconds=secs, rounds=rounds,
-        achieved_GBps=need / secs / 1e9,
+        bytes_needed=need, seconds=secs, rounds=rounds, lane_runs=runs,
+        bulk_runs=bulk, slot_bytes=occ["slot_bytes"], rows=occ["rows"],
+        replicas=occ["replicas"], achieved_GBps=need / secs / 1e9,
         peak_GBps=roofline.peaks(ctx["device"]["kind"])[
             "hbm_bytes_per_s"] / 1e9)
     return roofline.roofline_pct(need, secs, ctx["device"]["kind"])

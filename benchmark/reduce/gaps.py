@@ -124,10 +124,12 @@ def host_clock_lead(module_starts: Sequence[float],
                                      sorted(enqueue_starts)))
 
 
-def read_xplane(path: str):
+def read_xplane(path: str, device_ops: bool = True):
     """(per device plane its ops, per host thread its span events
     (start, end, name) on the device's clock, the host clock's lead in
-    ns or ``None``) of one trace file."""
+    ns or ``None``) of one trace file. Without ``device_ops`` the ops
+    are not walked and the first is empty: for a caller that has
+    ``reduce_trace``'s gaps already."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
@@ -138,7 +140,7 @@ def read_xplane(path: str):
     for plane in data.planes:
         if plane.name.startswith("/device:TPU:"):
             for line in plane.lines:
-                if line.name == OPS_LINE:
+                if line.name == OPS_LINE and device_ops:
                     devices.append([(e.start_ns, e.duration_ns, e.name)
                                     for e in line.events])
                 elif line.name == MODULES_LINE:
@@ -161,27 +163,34 @@ def read_xplane(path: str):
     return devices, threads, lead
 
 
-def reduce_gaps(trace_dir_or_file: str, top: int = 10) -> dict:
+def reduce_gaps(trace_dir_or_file: str, top: int = 10,
+                reduced: Optional[dict] = None) -> dict:
     """Device idle seconds by host span, and the ``top`` longest gaps
     with what covered each. Seconds are averaged over the device planes
-    (as ``reduce_trace`` averages its own)."""
+    (as ``reduce_trace`` averages its own). ``reduced``:
+    ``reduce_trace``'s result of the same file, whose gaps are then
+    taken as they stand and the device's ops not read again."""
     path = (trace_dir_or_file if trace_dir_or_file.endswith(".pb")
             else find_xplane(trace_dir_or_file))
-    devices, threads, lead = read_xplane(path)
-    if not devices:
+    devices, threads, lead = read_xplane(path, device_ops=reduced is None)
+    if reduced is not None:
+        device_gaps, t_first = reduced["idle_gaps"], reduced["first_op_ns"]
+    else:
+        device_gaps = [idle_gaps(ops) for ops in devices]
+        t_first = min((s for ops in devices for s, _d, _n in ops),
+                      default=None)
+    if not device_gaps or t_first is None:
         raise ValueError(f"{path}: no /device:TPU:* plane with ops")
-    k = len(devices)
+    k = len(device_gaps)
     by_span: Dict[str, float] = {}
     rows = []
-    for ops in devices:
-        gaps = idle_gaps(ops)
+    for gaps in device_gaps:
         total, per_gap = share_out([(a, b) for a, b, _n in gaps], threads)
         for n, v in total.items():
             by_span[n] = by_span.get(n, 0.0) + v / 1e9 / k
         rows.extend((b - a, a, after, cover)
                     for (a, b, after), cover in zip(gaps, per_gap))
     gap_s = sum(by_span.values())
-    t_first = min(s for ops in devices for s, _d, _n in ops)
     rows.sort(key=lambda r: -r[0])
     return {
         "xplane": path,

@@ -1,5 +1,5 @@
-"""Bytes a node sends over the chips' interconnect, from shapes, the
-interconnect's peak, and the exchange's share of it.
+"""Bytes a node sends over the chips' interconnect, the interconnect's
+peak, and the exchange's share of it.
 
 Placed a node a chip (``MultiRaftEngine(nodes=...)``), the round's
 exchange is one all-to-all a kind lane over the node axis: chip s holds
@@ -7,13 +7,13 @@ exchange is one all-to-all a kind lane over the node axis: chip s holds
 keeps its own slot and sends each of the other R - 1 chips theirs. One
 *lane run* (one lane exchanged in one tile's round: what
 ``eng.lane_exchanges()`` counts) therefore sends, a chip,
-``tile_rows * (R - 1)`` slots, a slot ``SLOT_BOOL_FIELDS`` bytes and
-``SLOT_WORD_FIELDS`` words, and in the append lane ``E`` words more
-(``ent_terms``; entries travel in that lane alone). As many bytes
-arrive. The count is the algorithm's, from shapes: not the padded
-tiles a layout touches, not the pass that packs a boolean field for the
-wire. It does no arithmetic, so the interconnect's bandwidth bounds
-it.
+``tile_rows * (R - 1)`` slots of that lane's bytes, once; as many
+arrive. The count is ``reduce/roofline.lane_bytes``, the one the
+one-chip ``route()`` is held to, with the other nodes as the peers and
+one pass: the algorithm's, from the lanes that crossed and the bytes of
+a slot as the program carries it, not the padded tiles a layout
+touches, not the pass that packs a boolean field for the wire. It does
+no arithmetic, so the interconnect's bandwidth bounds it.
 
 The peak is the chip's published interconnect figure, every link
 together. One round's three peers sit behind different links, two of
@@ -25,9 +25,7 @@ from __future__ import annotations
 
 from typing import Optional, Sequence
 
-from .roofline import SLOT_BOOL_FIELDS, SLOT_WORD_FIELDS
-
-KIND_APP = 1  # ``etcd_tpu.batched.step.KIND_APP``: the lane of entries
+from .roofline import lane_bytes
 
 # Bytes a second a chip can send over its interconnect, all links.
 # Read on 2026-09-30 from Google Cloud's documentation, "TPU v5e"
@@ -53,24 +51,16 @@ def ici_peak(device_kind: str) -> float:
     return ICI_PEAKS[device_kind]["ici_bytes_per_s"]
 
 
-def slot_bytes(lane: int, max_ents_per_msg: int) -> int:
-    return (SLOT_BOOL_FIELDS * 1 + SLOT_WORD_FIELDS * 4
-            + (max_ents_per_msg * 4 if lane == KIND_APP else 0))
-
-
-def lane_run_bytes(tile_rows: int, num_replicas: int, max_ents_per_msg: int,
-                   lane: int) -> int:
-    """Bytes one chip sends when `lane` is exchanged for one tile."""
-    return tile_rows * (num_replicas - 1) * slot_bytes(
-        lane, max_ents_per_msg)
-
-
 def sent_bytes(lane_runs: Sequence[int], tile_rows: int, num_replicas: int,
-               max_ents_per_msg: int) -> int:
-    """Bytes one chip sent over `lane_runs` (lane runs by kind lane)."""
-    return sum(int(n) * lane_run_bytes(tile_rows, num_replicas,
-                                       max_ents_per_msg, k)
-               for k, n in enumerate(lane_runs))
+               slot_bytes: Sequence[int],
+               bulk_runs: Optional[int] = None) -> int:
+    """Bytes one chip sent over `lane_runs` (tile-rounds in which each
+    lane crossed). A split append lane (seven slot sizes) needs
+    `bulk_runs`, the tile-rounds its tail crossed in: the placed engine
+    counts none today (no live placed configuration splits the lane),
+    so such a run raises in ``lane_bytes`` and does not read low."""
+    return lane_bytes(tile_rows, num_replicas - 1, lane_runs, slot_bytes,
+                      bulk_runs, passes=1)
 
 
 def roofline_pct(sent: float, seconds: float,

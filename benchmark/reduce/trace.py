@@ -14,8 +14,17 @@ What this gives, for the device planes of one trace:
 Times come from ``jax.profiler.ProfileData`` (the events). ProfileData
 does not expose the per-op *metadata* stats, and the ``named_scope`` of
 an op lives exactly there (``tf_op``), so a few dozen lines of protobuf
-wire decoding read that one map out of the same file; nothing but JAX
-and the standard library is imported.
+wire decoding read that one map out of the same file; nothing but JAX,
+numpy (which JAX brings) and the standard library is imported.
+
+A traced call of a large cell is millions of events. ``reduce_trace``
+walks them once: the sorts, the leaf test and the merge of the busy
+intervals run in numpy (``_plane``), every sum still runs in the order
+and the precision it always did, so the numbers are the same to the
+last bit as the plain functions beside it give (``_leaf_seconds``,
+``_union``, ``idle_gaps``: kept, for small inputs and as the statement
+of what ``_plane`` computes; ``tests/benchmark/test_reduce.py`` holds
+the two equal on the recorded traces).
 """
 
 from __future__ import annotations
@@ -25,6 +34,8 @@ import glob
 import os
 import re
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
@@ -190,21 +201,77 @@ def _union(intervals: List[Tuple[float, float]]) -> Tuple[float, List]:
     return sum(e - s for s, e in merged), merged
 
 
-def idle_gaps(ops: Sequence[Tuple[float, float, str]]
+def idle_gaps(ops: Sequence[Tuple[float, float, str]],
+              merged: Optional[List] = None
               ) -> List[Tuple[float, float, str]]:
     """(start, end, kind of the op that ended before it) of every idle
     gap of one device's ops (start, duration, name): the pauses of at
-    least ``MIN_GAP_NS`` between the merged op intervals."""
-    _busy, merged = _union([(s, s + d) for s, d, _n in ops])
+    least ``MIN_GAP_NS`` between the merged op intervals (``merged``:
+    ``_union``'s of these ops, where the caller has it already)."""
+    if merged is None:
+        _busy, merged = _union([(s, s + d) for s, d, _n in ops])
+    gaps = [(e0, s1) for (_s0, e0), (s1, _e1) in zip(merged, merged[1:])
+            if s1 - e0 >= MIN_GAP_NS]
+    if not gaps:
+        return []
     ends = sorted((s + d, n) for s, d, n in ops)
     end_times = [e for e, _n in ends]
     out = []
-    for (_s0, e0), (s1, _e1) in zip(merged, merged[1:]):
-        if s1 - e0 < MIN_GAP_NS:
-            continue
+    for e0, s1 in gaps:
         i = bisect.bisect_right(end_times, e0 + 1e-3) - 1
         out.append((e0, s1, op_kind(ends[max(i, 0)][1])))
     return out
+
+
+def _plane(ops: Sequence[Tuple[float, float, str]]):
+    """One device's ops (start, duration, name) -> (the leaves in
+    ``_leaf_seconds``'s order, ``_union``'s total, the first merged
+    interval's start, the last one's end, ``idle_gaps``' list), by
+    sorting and merging in numpy. What makes it the same as the plain
+    functions: sorted by (start, longest first) an event has a child
+    exactly when the next one starts inside it, and a leaf is popped
+    before anything is pushed over it, so the leaves come in sorted
+    order; sorted by (start, end) a new busy interval begins where a
+    start passes the running maximum of the ends."""
+    n = len(ops)
+    start = np.fromiter((o[0] for o in ops), np.float64, n)
+    dur = np.fromiter((o[1] for o in ops), np.float64, n)
+    end = start + dur
+    order = np.lexsort((-dur, start))
+    s1, e1 = start[order], end[order]
+    leaf = np.ones(n, bool)
+    leaf[:-1] = e1[:-1] <= s1[1:] + 1e-3
+    leaves = [ops[i] for i in order[leaf].tolist()]
+
+    by_start = np.lexsort((end, start))
+    s2, e2 = start[by_start], end[by_start]
+    reach = np.maximum.accumulate(e2)
+    first = np.ones(n, bool)
+    first[1:] = s2[1:] > reach[:-1]
+    at = np.flatnonzero(first)
+    m_start = s2[at]
+    m_end = reach[np.append(at[1:] - 1, n - 1)]
+    total = sum((m_end - m_start).tolist())
+
+    gaps: List[Tuple[float, float, str]] = []
+    wide = np.flatnonzero(m_start[1:] - m_end[:-1] >= MIN_GAP_NS)
+    if len(wide):
+        by_end = np.argsort(end, kind="stable")
+        ends = end[by_end]
+        for g in wide.tolist():
+            e0 = float(m_end[g])
+            i = int(np.searchsorted(ends, e0 + 1e-3, "right")) - 1
+            # Of the ops that ended together, the last by name: where
+            # ``idle_gaps``' sort of (end, name) puts the one it takes
+            # (the first of all, if none ended by then).
+            if i < 0:
+                hi = int(np.searchsorted(ends, ends[0], "right"))
+                name = min(ops[j][2] for j in by_end[:hi].tolist())
+            else:
+                lo = int(np.searchsorted(ends, ends[i], "left"))
+                name = max(ops[j][2] for j in by_end[lo:i + 1].tolist())
+            gaps.append((e0, float(m_start[g + 1]), op_kind(name)))
+    return leaves, total, float(m_start[0]), float(m_end[-1]), gaps
 
 
 def reduce_trace(trace_dir_or_file: str, window_s: Optional[float] = None,
@@ -229,6 +296,8 @@ def reduce_trace(trace_dir_or_file: str, window_s: Optional[float] = None,
     modules: Dict[str, List[float]] = {}
     busy_ns = span_ns = gap_ns = 0.0
     n_ops = 0
+    device_gaps: List[List[Tuple[float, float, str]]] = []
+    first_op_ns: Optional[float] = None
     for plane in planes:
         ops: List[Tuple[float, float, str]] = []
         for line in plane.lines:
@@ -242,15 +311,25 @@ def reduce_trace(trace_dir_or_file: str, window_s: Optional[float] = None,
         if not ops:
             continue
         n_ops += len(ops)
-        for _s, dur, name in _leaf_seconds(ops):
-            scope = scope_of(scopes.get(name))
+        # A traced call of a large cell is millions of events of a few
+        # thousand distinct ops: the two regular expressions run once
+        # an op, not once an event; the sums run in the events' order
+        # as they always did.
+        keys: Dict[str, Tuple[str, str]] = {}
+        leaves, total, first, last, gaps = _plane(ops)
+        for _s, dur, name in leaves:
+            if name not in keys:
+                scope = scope_of(scopes.get(name))
+                keys[name] = scope, f"{scope}/{op_kind(name)}"
+            scope, key = keys[name]
             by_scope[scope] = by_scope.get(scope, 0.0) + dur / 1e9
-            key = f"{scope}/{op_kind(name)}"
             by_op[key] = by_op.get(key, 0.0) + dur / 1e9
-        total, merged = _union([(s, s + d) for s, d, _n in ops])
         busy_ns += total
-        span_ns += merged[-1][1] - merged[0][0]
-        gap_ns += sum(b - a for a, b, _after in idle_gaps(ops))
+        span_ns += last - first
+        device_gaps.append(gaps)
+        gap_ns += sum(b - a for a, b, _after in gaps)
+        first_op_ns = first if first_op_ns is None else min(first_op_ns,
+                                                            first)
 
     k = len(planes)
     busy_s = busy_ns / 1e9 / k
@@ -275,6 +354,11 @@ def reduce_trace(trace_dir_or_file: str, window_s: Optional[float] = None,
         "device_ops": [[n, v / k] for n, v in sorted(
             by_op.items(), key=lambda r: -r[1])[:top]],
         "gap_s": gap_ns / 1e9 / k,
+        # For ``reduce/gaps.reduce_gaps``, which then reads the host's
+        # plane alone: the ops, millions in a large cell, are walked
+        # once a traced run.
+        "idle_gaps": device_gaps,
+        "first_op_ns": first_op_ns,
     }
 
 

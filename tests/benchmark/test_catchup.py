@@ -23,11 +23,13 @@ from benchmark.drivers import engine_catchup
 from benchmark.generators import engine_faults_rounds as gen
 from benchmark.readers import catchup as reader
 
-from .util import REPO, _edit, bench, tiny_root
+from .util import (REPO, SHARED_AT_52, TINY_DIR, UNLISTED, bench, cell_root,
+                   own_entries, reaches, shared_with)
 
 CONFIG = "engine100k-r3-deeplog"
 CELL = CONFIG + ".reboot-catchup"
-TINY = {"period_rounds": 512, "cut_from_round": 64, "cut_rounds": 192}
+with open(os.path.join(TINY_DIR, CELL + ".json")) as _f:
+    TINY = json.load(_f)["traffic"]  # a period of 512 rounds
 NEW = ["round.log_pct", "catchup.rounds_to_level",
        "catchup.rejects_per_return", "catchup.snapshots_per_return",
        "catchup.ents_per_app", "log.depth_entries"]
@@ -83,10 +85,9 @@ def test_the_traffic_is_the_issues():
     assert t["cut_rounds"] * t["proposals_per_round"] == 4096 < 5120
 
 
-def test_the_cell_follows_what_was_there():
+def follows_rule(b: dict) -> None:
     """Appended, by rule: everything PR 47's file had stands first and
     in its order; one four-chip cell still."""
-    b = bench()
     before = ["engine64k-r3", "engine10k-r5", "engine100k-r3", "engine1m-r3",
               "engine512k-r3of4", "engine1m-r3of4-x4",
               "engine768k-r3of4-rebalance", "engine1m-r3-zipf"]
@@ -102,64 +103,78 @@ def test_the_cell_follows_what_was_there():
     assert 1 <= len(cell["why"]) <= 200
     rate = b["end_to_end"][0]
     assert (rate["name"], rate["bound"]) == ("group_rounds_per_s", 0.01)
-    assert rate["workloads"] == cells[:9]
+    assert rate["workloads"][:9] == cells[:9]
     assert [w["name"] for w in b["workloads"] if w["chips"] == 4] == [
         "engine1m-r3of4-x4.replace-readindex-x4"]
     assert b["run_seconds"] == 30
+
+
+def test_the_cell_follows_what_was_there():
+    follows_rule(bench())
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         assert len(f.read()) < 64 << 10
 
 
-def parked_entries():
-    return load_json("parked", "catchup")["per_layer"]
-
-
-def add_catchup(b: dict) -> None:
-    """The cell's own entries appended, as the benchmark PR that takes
-    them up will (``parked/catchup.json``'s note says what keeps them
-    out of ``BENCHMARK.json``)."""
-    b["per_layer"].extend(parked_entries())
-
-
-def test_the_new_entries_list_this_cell_alone_and_wait_parked():
-    rows = parked_entries()
-    assert [m["name"] for m in rows] == NEW
-    live = bench()["per_layer"]
-    layers = {m["layer"] for m in live}
-    for m in rows:
-        assert m["workloads"] == [CELL]
+def entries_rule(b: dict) -> None:
+    """The cell's six stand right after PR 47's six (PR 50 wrote them
+    and had to park them behind a pin; PR 52 pasted them here), in
+    their order, for this cell alone; what follows them is a later
+    PR's."""
+    for m in own_entries(b, NEW, 60, CELL):
         assert m["moves"] == "group_rounds_per_s"
-        assert set(m) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert m["layer"] in layers
         spec = load_json("layer_metrics", m["name"])
-        assert (spec["name"], spec["unit"], spec["layer"], spec["moves"]) \
-            == (m["name"], m["unit"], m["layer"], m["moves"])
         mod = spec["reader"].partition(".")[0]
         assert os.path.exists(os.path.join(
             REPO, "benchmark", "readers", mod + ".py"))
-    # No accepted entry gained or lost a cell, and none is this PR's.
-    assert len(live) == 60 and not set(NEW) & {m["name"] for m in live}
-    for m in live:
-        assert CELL not in m.get("workloads", [])
+
+
+def test_the_new_entries_list_this_cell_alone_and_are_live():
+    b = bench()
+    entries_rule(b)
+    assert not os.path.exists(os.path.join(
+        REPO, "benchmark", "parked", "catchup.json"))
+    assert not hasattr(engine_catchup.Driver, "say_layers")
     log = load_json("layer_metrics", "round.log_pct")
     assert (log["reader"], log["params"]) == (
         "trace.scope_pct", {"scope": "raft_log"})
-    assert "test_load.py" in load_json("parked", "catchup")["note"]
+    got = {m["name"]: (m["unit"], m["better"], m["source"], m["layer"])
+           for m in b["per_layer"][60:66]}
+    assert got == {
+        "round.log_pct": ("%", "lower", "device_trace", "round program"),
+        "catchup.rounds_to_level": ("rounds", "lower", "program_counter",
+                                    "closed-loop engine"),
+        "catchup.rejects_per_return": ("per_return", "lower",
+                                       "program_counter", "telemetry plane"),
+        "catchup.snapshots_per_return": ("per_return", "lower",
+                                         "program_counter",
+                                         "telemetry plane"),
+        "catchup.ents_per_app": ("entries", "higher", "program_counter",
+                                 "closed-loop engine"),
+        "log.depth_entries": ("entries", "higher", "program_counter",
+                              "closed-loop engine")}
 
 
 def test_the_cell_resolves_to_its_files():
+    b = bench()
     cell = harness.Cell(REPO, CELL)
     assert cell.config["name"] == CONFIG and cell.chips == 1
     assert [m["name"] for m in cell.end_to_end] == [
         "group_rounds_per_s", "setup_s"]
-    mine = [m["name"] for m in cell.per_layer]
-    # The accepted entries that name no cell reach this one.
-    assert set(mine) == {
-        "round.device_ms", "round.deliver_pct", "engine.call_gap_ms",
-        "device.hbm_peak_gb", "compile.in_window", "compile.cache_misses",
-        "engine.dispatch_ms", "engine.late_ms", "setup.engine_init_s",
-        "setup.elect_s", "setup.first_scan_s"}
+    mine = {m["name"] for m in cell.per_layer}
+    # The accepted entries that name no cell reach this one, its own
+    # six, and the shared ones that list it: of the round's and the
+    # scan's layers all but the tiles' (one scan over all rows), no
+    # read's (none is asked), and alone of all cells the bulk half's.
+    assert UNLISTED <= mine == reaches(b, CELL)
+    # (At least: a later PR may bring one more view of this cell.)
+    assert mine >= {m["name"] for m in b["per_layer"]
+                    if "workloads" not in m} | set(NEW) | shared_with(
+                        b, CELL)
+    assert set(SHARED_AT_52) - mine == {
+        "scan.tiles_pct", "setup.pretrace_s", "read.confirmed_per_kgr",
+        "read.rounds_to_confirm"}
+    assert [m["workloads"][0] for m in b["per_layer"]
+            if m["name"] == "round.bulk_pct"] == [CELL]
     assert cell.module("drivers", "engine_catchup") is engine_catchup
 
 
@@ -370,13 +385,9 @@ def test_engine_checks_hold_the_run_table_to_the_references_log():
 
 
 def with_entries(dst) -> str:
-    """A tiny root whose ``BENCHMARK.json`` holds the cell's own
-    entries, and whose period is 512 rounds."""
-    dst = tiny_root(str(dst))
-    _edit(os.path.join(dst, "BENCHMARK.json"), add_catchup)
-    _edit(os.path.join(dst, "benchmark", "traffic", "reboot-catchup.json"),
-          lambda t: t.update(TINY))
-    return dst
+    """A tiny root (its ``BENCHMARK.json`` holds the cell's own
+    entries since PR 52) whose period is 512 rounds."""
+    return cell_root(str(dst), CELL)
 
 
 def reader_ctx():
@@ -453,13 +464,10 @@ def driven(root):
     driver.close()
 
 
-def test_the_cell_is_correct_and_says_its_layers(root, capsys):
+def test_the_cell_is_correct_and_its_layers_are_on_the_line(root, capsys):
     cell = harness.Cell(root, CELL)
     ctx, checks = harness.measure(cell, 2**31 + 51, 0.3, False,
                                   time.perf_counter(), require_tpu=False)
-    said = [json.loads(ln.split("] ", 1)[1])
-            for ln in capsys.readouterr().out.splitlines()
-            if ln.startswith("[bench:catchup_layers]")]
     assert verdict(checks), [c for c in checks if not c.ok]
     assert all(c.limit == 0 for c in checks) and len(checks) == 19
     raw = ctx["raw"]
@@ -477,10 +485,21 @@ def test_the_cell_is_correct_and_says_its_layers(root, capsys):
     assert got["catchup.ents_per_app"]["value"] > 48
     assert got["log.depth_entries"]["value"] > 1000
     assert "round.log_pct" not in got  # no trace, nothing to read
-    # The run's own line says what the result line would.
-    assert len(said) == 1 and said[0]["round.log_pct"] is None
-    assert {n: v for n, v in said[0].items() if v is not None} == {
-        n: m["value"] for n, m in got.items() if n in NEW}
+    # The cell's own five counters through the harness, from
+    # ``BENCHMARK.json`` itself; the driver says them on no line of
+    # its own any more (a run's lines: the harness's tags alone).
+    assert set(NEW[1:]) <= set(got)
+    tags = {ln.split("]")[0] for ln in capsys.readouterr().out.splitlines()
+            if ln.startswith("[bench:")}
+    assert not [t for t in tags if t.endswith("_layers")]
+    # The bulk half ran in the rounds a returned replica was carried: a
+    # few of the window's, and the lane is split (E 64, head 3).
+    occ = raw["occupancy"]
+    assert (occ["app_head"], occ["slot_bytes"][6]) == (3, 4 * 61)
+    assert 0 < got["round.bulk_pct"]["value"] < 25
+    assert got["round.bulk_pct"]["value"] == (
+        100.0 * (occ["after"]["bulk"] - occ["before"]["bulk"])
+        / raw["rounds"])
     assert harness.end_to_end_metrics(cell, ctx)[
         "group_rounds_per_s"]["value"] > 0
 

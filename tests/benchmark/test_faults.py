@@ -25,7 +25,8 @@ from benchmark.fault_checks import (LEADER, REPLICATE, group_checks,
 from benchmark.generators import engine_faults_rounds as gen
 from benchmark.readers import telemetry as reader
 
-from .util import CELLS_AT_36, REPO, bench, listed_cells, tiny_root
+from .util import (CELLS_AT_36, REPO, bench, in_workloads_order, own_entries,
+                   tiny_root)
 
 CELL = "engine100k-r3.elections"
 SIZES = {"num_groups": 16, "num_replicas": 3}
@@ -448,24 +449,34 @@ SEVEN = ["round.tick_pct", "round.telemetry_pct",
          "election.committed_pct"]
 
 
-def test_the_seven_are_live_with_exactly_these_workloads():
-    """The two shares of the round follow the work among the cells PR 36
-    found (``raft_tick`` runs in every one, ``raft_telemetry`` where the
-    configuration turns the plane on); the five counter metrics keep
-    the cell they were written for. A cell appended since is not
-    theirs to list."""
-    b = bench()
+def entries_rule(b: dict) -> None:
+    """The seven stand right after the 13 entries PR 24's file had, in
+    their order. The two shares of the round follow the work: they
+    list the cells PR 36 found first (``raft_tick`` runs in every one,
+    ``raft_telemetry`` where the configuration turns the plane on) and
+    after them whatever cell's program runs the scope too
+    (``test_lists.py`` holds that, cell by cell); the five counter
+    metrics keep the cell they were written for."""
+    rows = b["per_layer"]
+    assert [m["name"] for m in rows[13:20]] == SEVEN
+    assert not set(SEVEN) & {m["name"] for m in rows[:13] + rows[20:]}
     files = {c["name"]: c["file"] for c in b["configs"]}
     plane_on = []
     for w in b["workloads"]:
-        with open(os.path.join(REPO, files[w["config"]])) as f:
-            if (w["name"] in CELLS_AT_36
-                    and json.load(f)["sizes"].get("telemetry")):
-                plane_on.append(w["name"])
+        if w["name"] in CELLS_AT_36:
+            with open(os.path.join(REPO, files[w["config"]])) as f:
+                if json.load(f)["sizes"].get("telemetry"):
+                    plane_on.append(w["name"])
     assert CELL in plane_on and len(plane_on) == 3
-    assert listed_cells(SEVEN) == {
-        "round.tick_pct": CELLS_AT_36, "round.telemetry_pct": plane_on,
-        **{name: [CELL] for name in SEVEN[2:]}}
+    assert rows[13]["workloads"][:5] == CELLS_AT_36
+    assert rows[14]["workloads"][:3] == plane_on
+    for m in rows[13:15]:
+        assert in_workloads_order(b, m)
+    own_entries(b, SEVEN[2:], 15, CELL, new_layers={"telemetry plane"})
+
+
+def test_the_seven_are_live_with_these_workloads():
+    entries_rule(bench())
     assert not os.path.exists(os.path.join(
         REPO, "benchmark", "parked", "engine100k-r3_layers.json"))
 

@@ -25,7 +25,7 @@ from benchmark.compare import verdict
 
 from . import test_faults, test_reconf, test_replace
 from .test_spans import ONE_MORE, live_entries_rule
-from .util import CELLS_AT_36, REPO, _edit, tiny_root
+from .util import CELLS_AT_36, REPO, _edit, in_workloads_order, tiny_root
 
 CELLS = CELLS_AT_36
 NEW = [*test_faults.SEVEN, *test_reconf.SEVEN, *test_replace.FIVE,
@@ -50,31 +50,32 @@ def bench_of(root: str) -> dict:
 # -- the rules, of a root ----------------------------------------------------------
 
 
-def the_23_rule(root: str) -> None:
+def the_23_rule(b: dict) -> None:
     """The 23 stand right after the 13 that were there, in R0b's order;
-    the four this PR wrote list the five cells. What follows them is a
+    the four this PR wrote list the five cells first, and after them
+    the cells whose program runs the layer too, in the order of the
+    cells (``test_lists.py`` holds which). What follows the 23 is a
     later PR's."""
-    rows = bench_of(root)["per_layer"]
+    rows = b["per_layer"]
     assert len(NEW) == 23 and len(set(NEW)) == 23
     assert [m["name"] for m in rows[13:36]] == NEW
     assert not set(NEW) & {m["name"] for m in rows[:13] + rows[36:]}
     for m in rows[32:36]:
-        assert m["workloads"] == CELLS
-    parked = os.listdir(os.path.join(root, "benchmark", "parked"))
-    assert not [f for f in parked if f.endswith("_layers.json")]
+        assert m["workloads"][:5] == CELLS and in_workloads_order(b, m)
 
 
 def entry_rule(root: str, name: str) -> None:
-    """Beyond ``test_metric_entry``: the entry names its cells, each one
-    of the five and one that reports the metric it moves; its file's
+    """Beyond ``test_metric_entry``: the entry names its cells, one of
+    the five among them, each a cell that reports the metric it moves
+    and each once, in the order of the cells; its file's
     reader resolves; the harness hands it to the cells it lists and to
     no other cell of the root; ``PERF.md`` names it."""
     b = bench_of(root)
     m = [x for x in b["per_layer"] if x["name"] == name][0]
     assert set(m) == {"name", "unit", "better", "source", "layer", "moves",
                       "workloads"}
-    assert m["workloads"] and set(m["workloads"]) <= set(CELLS)
-    assert len(set(m["workloads"])) == len(m["workloads"])
+    assert m["workloads"] and set(m["workloads"]) & set(CELLS)
+    assert in_workloads_order(b, m)
     moved = [e for e in b["end_to_end"] if e["name"] == m["moves"]][0]
     assert set(m["workloads"]) <= set(moved["workloads"])
     with open(os.path.join(root, "benchmark", "layer_metrics",
@@ -133,7 +134,9 @@ def partition_rule(root: str, name: str) -> None:
 
 
 def test_the_23_follow_the_13_and_each_names_its_cells():
-    the_23_rule(REPO)
+    the_23_rule(bench_of(REPO))
+    parked = os.listdir(os.path.join(REPO, "benchmark", "parked"))
+    assert not [f for f in parked if f.endswith("_layers.json")]
     assert [w["name"] for w in bench_of(REPO)["workloads"]][:5] == CELLS
 
 
@@ -225,7 +228,7 @@ def test_the_later_root_is_what_it_says(later_root):
 
 
 def test_the_rules_of_position_admit_what_was_appended(later_root):
-    the_23_rule(later_root)
+    the_23_rule(bench_of(later_root))
     live_entries_rule(bench_of(later_root))
     test_reconf.gained_rule(bench_of(later_root))
 
@@ -267,6 +270,8 @@ TIGHT = {
         b["per_layer"].pop(35)),
     "the lane counter in four cells": lambda b: b["per_layer"][35].update(
         workloads=CELLS[:4]),
+    "the lane counter's cells out of order": lambda b: b["per_layer"][
+        35].update(workloads=b["per_layer"][35]["workloads"][::-1]),
 }
 
 
@@ -277,10 +282,10 @@ def test_the_rule_holds_the_23_where_they_are(later_root, tmp_path, edit):
                os.path.join(root, "benchmark"))
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench_of(later_root), f)
-    the_23_rule(root)
+    the_23_rule(bench_of(root))
     _edit(os.path.join(root, "BENCHMARK.json"), edit)
     with pytest.raises(AssertionError):
-        the_23_rule(root)
+        the_23_rule(bench_of(root))
 
 
 # -- the lane counter, on a tiny run of each engine driver -----------------------
@@ -338,8 +343,8 @@ def test_a_driver_that_reads_no_lanes_gives_no_metric():
     from benchmark.readers import lanes
 
     assert lanes.run_a_round({"raw": {"rounds": 64}}) is None
-    assert lanes.run_a_round({"raw": {"rounds": 64, "lanes": {
-        "before": [0] * 6}}}) is None
-    assert lanes.run_a_round({"raw": {"rounds": 64, "lanes": {
-        "before": [0, 64, 16, 0, 64, 16],
-        "after": [0, 128, 32, 0, 128, 32]}}}) == 2.5
+    assert lanes.run_a_round({"raw": {"rounds": 64, "occupancy": {
+        "before": {"lanes": [0] * 6}}}}) is None
+    assert lanes.run_a_round({"raw": {"rounds": 64, "occupancy": {
+        "before": {"lanes": [0, 64, 16, 0, 64, 16]},
+        "after": {"lanes": [0, 128, 32, 0, 128, 32]}}}}) == 2.5

@@ -35,7 +35,8 @@ from benchmark.readers import load as reader
 from benchmark.reference import shadow_load
 
 from .test_contract import NAME, SOURCES, UNIT
-from .util import REPO, bench, listed_cells, tiny_root
+from .util import (REPO, UNLISTED, bench, listed_cells, own_entries, reaches,
+                   shared_with, tiny_root)
 
 CONFIG = "engine1m-r3-zipf"
 CELL = CONFIG + ".ycsb-a"
@@ -411,24 +412,22 @@ def test_readers():
 # -- the cell's entries ----------------------------------------------------------------
 
 
-def test_the_six_are_appended_for_this_cell_alone():
-    assert listed_cells(SIX) == {name: [CELL] for name in SIX}
-    b = bench()
-    rows = [m["name"] for m in b["per_layer"]]
-    assert rows.index(SIX[0]) == 54 and rows[54:] == SIX
-    layers = {m["layer"] for m in b["per_layer"][:54]}
-    for m in b["per_layer"][54:]:
-        assert set(m) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
+def entries_rule(b: dict) -> None:
+    """The cell's six stand right after the 54 entries PR 46's file
+    had, in their order, for this cell alone; what follows them is a
+    later PR's."""
+    for m in own_entries(b, SIX, 54, CELL):
         assert NAME.match(m["name"]) and UNIT.match(m["unit"])
-        assert m["source"] in SOURCES and m["layer"] in layers
+        assert m["source"] in SOURCES
         assert m["moves"] == "group_rounds_per_s"
-        spec = load_json("layer_metrics", m["name"])
-        assert (spec["name"], spec["unit"], spec["layer"], spec["moves"]) \
-            == (m["name"], m["unit"], m["layer"], m["moves"])
-        assert "workloads" not in spec
+
+
+def test_the_six_are_appended_for_this_cell_alone():
+    b = bench()
+    entries_rule(b)
+    assert listed_cells(SIX) == {name: [CELL] for name in SIX}
     got = {m["name"]: (m["unit"], m["better"], m["source"], m["layer"])
-           for m in b["per_layer"][54:]}
+           for m in b["per_layer"][54:60]}
     assert got == {
         "scan.load_pct": ("%", "lower", "device_trace", "closed-loop engine"),
         "load.active_pct": ("%", "lower", "program_counter",
@@ -445,21 +444,25 @@ def test_the_six_are_appended_for_this_cell_alone():
         "reconf.rounds_to_confirm")
 
 
-def test_the_cell_reports_the_eleven_that_name_no_cells_and_its_six():
+def test_the_cell_reports_what_names_no_cells_its_six_and_the_shared():
+    """At least the eleven that name no cells, by name; its own six;
+    and of the other entries with a list only shared ones, which the
+    rule of ``test_lists.py`` holds to what this cell's run gives."""
     b = bench()
     mine = {s["name"] for s in harness.Cell(REPO, CELL).per_layer}
     unlisted = {m["name"] for m in b["per_layer"] if "workloads" not in m}
-    assert len(unlisted) == 11 and mine == unlisted | set(SIX)
-    # The pinned lists of the older entries are not extended.
-    for m in b["per_layer"][:54]:
-        assert CELL not in m.get("workloads", [])
+    assert UNLISTED <= unlisted <= mine == reaches(b, CELL)
+    assert mine >= unlisted | set(SIX) | shared_with(b, CELL)
+    assert {"round.route_pct", "route.roofline_pct", "round.lanes_run",
+            "scan.tiles_pct", "read.rounds_to_confirm",
+            "round.rare_pct"} <= shared_with(b, CELL)
+    assert "round.bulk_pct" not in mine  # the append lane is not split
 
 
-def test_the_cell_follows_what_was_there():
+def follows_rule(b: dict) -> None:
     """By rule, not by position from the end: the configuration, the
     cell and its name under the rate come after everything PR 46's
     file had, in its order."""
-    b = bench()
     before = ["engine64k-r3", "engine10k-r5", "engine100k-r3", "engine1m-r3",
               "engine512k-r3of4", "engine1m-r3of4-x4",
               "engine768k-r3of4-rebalance"]
@@ -474,6 +477,10 @@ def test_the_cell_follows_what_was_there():
     assert [w["name"] for w in b["workloads"] if w["chips"] == 4] == [
         "engine1m-r3of4-x4.replace-readindex-x4"]
     assert b["run_seconds"] == 30
+
+
+def test_the_cell_follows_what_was_there():
+    follows_rule(bench())
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         assert len(f.read()) < 64 << 10
 

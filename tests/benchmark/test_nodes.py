@@ -23,7 +23,9 @@ from benchmark.reference.shadow_replace_nodes import NodesCluster
 
 from .test_contract import NAME, SOURCES, UNIT
 from .test_replace import CELL as ONE_CHIP_CELL
-from .util import CELLS_AT_36, REPO, bench, edited_copy, listed_cells, tiny_root
+from .util import (CELLS_AT_36, REPO, UNLISTED, bench, edited_copy,
+                   listed_cells, own_entries, reaches, shared_with,
+                   tiny_root)
 
 CONFIG = "engine1m-r3of4-x4"
 TRAFFIC = "replace-readindex-x4"
@@ -165,6 +167,10 @@ def test_the_entries_are_the_issues():
 
 
 def test_the_traffic_is_the_one_chip_cells_but_for_the_traced_calls():
+    """To the key, the name and the traced calls apart: the one-chip
+    cell traces a whole period (two calls), this cell one call, the
+    period's first half (PERF.md section 4 says which of the schedule's
+    events that leaves to the window)."""
     mine, one = load("traffic", TRAFFIC), load("traffic", "replace-readindex")
     assert list(mine) == list(one)
     assert {k: v for k, v in mine.items() if k not in ("name", "trace_calls")
@@ -176,11 +182,20 @@ def test_the_traffic_is_the_one_chip_cells_but_for_the_traced_calls():
     # calls of 16, in tile-rounds.
     assert 1 * 8 * 4 * mine["rounds_per_call"] == 2 * 16 * one[
         "rounds_per_call"]
+    assert one["trace_calls"] * one["rounds_per_call"] == one[
+        "period_rounds"]
+
+
+def entries_rule(b: dict) -> None:
+    """The five stand right after the 43 entries PR 38's file had, in
+    their order, for this cell alone."""
+    own_entries(b, FIVE, 43, CELL)
 
 
 def test_the_five_are_live_for_this_cell_alone():
     assert listed_cells(FIVE) == {name: [CELL] for name in FIVE}
     b = bench()
+    entries_rule(b)
     layers = {m["layer"] for m in b["per_layer"][:43]}
     for m in b["per_layer"]:
         if m["name"] not in FIVE:
@@ -207,19 +222,28 @@ def test_the_five_are_live_for_this_cell_alone():
 def test_route_entries_list_the_cells_whose_program_routes():
     """``route()`` does not run between nodes, so a traced run of this
     cell has no ``raft_route`` scope and the two entries that read it
-    find nothing there: each lists the five cells that report it, which
-    the contract reads as no change to them. Every other entry without
-    a list reaches this cell by itself."""
+    find nothing there: each lists every one-chip cell, the five PR 36
+    found first, and not this one. The shares of the round its placed
+    program does run list it (``test_lists.py`` holds each to what the
+    cell's run gives), and every entry without a list reaches it by
+    itself: at least the eleven, by name."""
     b = bench()
     rows = {m["name"]: m for m in b["per_layer"]}
+    one_chip = [w["name"] for w in b["workloads"] if w["chips"] == 1]
     for name in ("round.route_pct", "route.roofline_pct"):
-        assert rows[name]["workloads"] == CELLS_AT_36
+        assert rows[name]["workloads"][:5] == CELLS_AT_36
+        assert rows[name]["workloads"] == one_chip
     cell = harness.Cell(REPO, CELL)
     mine = {s["name"] for s in cell.per_layer}
     assert set(FIVE) <= mine
     assert not {"round.route_pct", "route.roofline_pct"} & mine
     unlisted = {m["name"] for m in b["per_layer"] if "workloads" not in m}
-    assert len(unlisted) == 11 and unlisted <= mine
+    assert UNLISTED <= unlisted <= mine == reaches(b, CELL)
+    assert mine >= unlisted | set(FIVE) | shared_with(b, CELL)
+    assert {"round.tick_pct", "round.control_pct", "round.propose_pct",
+            "round.emit_pct", "round.unscoped_pct", "round.telemetry_pct",
+            "round.lanes_run", "scan.tiles_pct", "scan.watch_pct",
+            "scan.carry_pct", "setup.pretrace_s"} <= shared_with(b, CELL)
     one = {s["name"] for s in harness.Cell(REPO, ONE_CHIP_CELL).per_layer}
     assert {"round.route_pct", "route.roofline_pct"} <= one
     assert not set(FIVE) & one
@@ -227,33 +251,34 @@ def test_route_entries_list_the_cells_whose_program_routes():
 
 # -- bytes from shapes ------------------------------------------------------------------
 
+E4 = [21, 49, 17, 10, 22, 13]  # step.lane_slot_bytes(4), by kind lane
+
 
 def test_bytes_a_chip_sends_by_hand():
-    """At the cell's size: a tile of 131,072 groups, R=4, E=4. A slot is
-    2 booleans and 8 words, 34 bytes, and 4 words of entries more in
-    the append lane; a chip sends three peers their slot of every
-    group."""
-    assert roofline_ici.slot_bytes(0, 4) == 2 + 8 * 4 == 34
-    assert roofline_ici.slot_bytes(roofline_ici.KIND_APP, 4) == 34 + 16
-    assert roofline_ici.lane_run_bytes(131_072, 4, 4, 0) == (
-        131_072 * 3 * 34) == 13_369_344
-    assert roofline_ici.lane_run_bytes(131_072, 4, 4, 1) == (
-        131_072 * 3 * 50) == 19_660_800
+    """At the cell's size: a tile of 131,072 groups, R=4, E=4. A slot of
+    a lane is the fields that lane carries (PR 48: 10 to 49 bytes, the
+    append lane's with 4 words of entries); a chip sends three peers
+    their slot of every group, once."""
+    assert roofline_ici.sent_bytes([1, 0, 0, 0, 0, 0], 131_072, 4, E4) == (
+        131_072 * 3 * 21) == 8_257_536
+    assert roofline_ici.sent_bytes([0, 1, 0, 0, 0, 0], 131_072, 4, E4) == (
+        131_072 * 3 * 49) == 19_267_584
     # A round in which the append, heartbeat and their response lanes
-    # cross in all 8 tiles: 107 MB a lane and 157 MB the append lane,
-    # the issue's figures at 1,048,576 groups.
+    # cross in all 8 tiles: 101 bytes a slot where the count until PR
+    # 52 had 50 + 3 x 34 = 152.
     runs = [0, 8, 8, 0, 8, 8]
-    assert roofline_ici.sent_bytes(runs, 131_072, 4, 4) == (
-        1_048_576 * 3 * (50 + 3 * 34)) == 478_150_656
-    assert 8 * roofline_ici.lane_run_bytes(131_072, 4, 4, 0) == 106_954_752
+    assert roofline_ici.sent_bytes(runs, 131_072, 4, E4) == (
+        1_048_576 * 3 * (49 + 17 + 22 + 13)) == 317_718_528
     # The peak is the published 1,600 Gbit/s of the chip, and an
     # unknown device has none.
     assert roofline_ici.ici_peak("TPU v5 lite") == 200e9
     with pytest.raises(KeyError):
         roofline_ici.ici_peak("cpu")
-    assert roofline_ici.roofline_pct(478_150_656, 0.01, "TPU v5 lite") == (
-        pytest.approx(100 * 478_150_656 / 200e9 / 0.01))
+    assert roofline_ici.roofline_pct(317_718_528, 0.01, "TPU v5 lite") == (
+        pytest.approx(100 * 317_718_528 / 200e9 / 0.01))
     assert roofline_ici.roofline_pct(1.0, 0.0, "TPU v5 lite") is None
+    for gone in ("slot_bytes", "lane_run_bytes", "KIND_APP"):
+        assert not hasattr(roofline_ici, gone)
 
 
 # -- the readers --------------------------------------------------------------------------
@@ -267,7 +292,7 @@ def ctx_of(traced=1, scope_s=None):
     after = [[n * k for k in per_call] for n in range(1, 7 + traced)]
     ctx = {"raw": {"rounds_per_call": 64, "traced_calls": traced, "ici": {
         "after_call": after, "open": 1, "close": 5, "tile_rows": 131_072,
-        "tiles": 8, "replicas": 4, "ents": 4}},
+        "tiles": 8, "replicas": 4, "slot_bytes": E4}},
         "device": {"kind": "TPU v5 lite"}}
     if scope_s is not None:
         ctx["trace"] = {"scope_s": scope_s, "leaf_s": sum(scope_s.values()),
@@ -275,20 +300,31 @@ def ctx_of(traced=1, scope_s=None):
     return ctx
 
 
-def test_readers_on_counts_made_by_hand():
+def test_readers_on_counts_made_by_hand(capsys):
     ctx = ctx_of(scope_s={"raft_ici": 0.5, "raft_agree": 0.02,
                           "raft_deliver": 1.48})
     lanes = (4 * 512 + 2 * 8) / 512
     assert reader.lanes_run(ctx) == pytest.approx(lanes)
-    sent = 64 * 8 * 131_072 * 3 * (50 + 3 * 34) + 2 * 8 * 131_072 * 3 * 34
+    sent = (64 * 8 * 131_072 * 3 * (49 + 17 + 22 + 13)
+            + 8 * 131_072 * 3 * (21 + 10))
     assert reader.mb_per_round(ctx) == pytest.approx(sent / 64 / 1e6)
     assert reader.exchange_pct(ctx) == pytest.approx(25.0)
     assert reader.agree_pct(ctx) == pytest.approx(1.0)
     # The traced call's own bytes over the chip's seconds under the
-    # scope: 30.6 GB in half a second against 200 GB/s.
+    # scope: 20.4 GB in half a second against 200 GB/s.
     assert reader.roofline_pct(ctx) == pytest.approx(
         100 * (sent / 200e9) / 0.5)
     assert 0 < reader.roofline_pct(ctx) < 100
+    # The line says what the share was counted with: the bytes, the
+    # lanes that crossed in the traced call and a slot's bytes by lane.
+    said = [json.loads(ln.split("] ", 1)[1])
+            for ln in capsys.readouterr().out.splitlines()
+            if ln.startswith("[bench:roofline] ")]
+    assert said and all(line == said[0] for line in said)
+    assert said[0]["bytes_sent"] == sent
+    assert said[0]["lane_runs"] == [8, 512, 512, 8, 512, 512]
+    assert said[0]["slot_bytes"] == E4
+    assert (said[0]["tile_rows"], said[0]["replicas"]) == (131_072, 4)
     two = ctx_of(traced=2, scope_s={"raft_ici": 1.0})
     assert reader.roofline_pct(two) == pytest.approx(
         100 * (2 * sent / 200e9) / 1.0)
@@ -298,7 +334,8 @@ def test_readers_find_nothing_where_nothing_crossed():
     """Another driver's run has no ``ici`` counts and its trace no such
     scope; a run without a trace has neither share; a window of no call
     divides by nothing. The metric is left out and nothing raises."""
-    bare = {"raw": {"rounds_per_call": 64, "lanes": {"before": [0] * 6}},
+    bare = {"raw": {"rounds_per_call": 64,
+                    "occupancy": {"before": {"lanes": [0] * 6}}},
             "device": {"kind": "TPU v5 lite"}}
     routed = dict(bare, trace={"scope_s": {"raft_route": 1.0}, "leaf_s": 1.0,
                                "modules": {}})
@@ -382,15 +419,15 @@ def test_each_reader_on_a_tiny_run(tiny_run):
     assert {n for n in layer if n.startswith("ici.")} == {
         "ici.lanes_run", "ici.mb_per_round"}
     ici = ctx["raw"]["ici"]
-    assert (ici["tiles"], ici["tile_rows"], ici["replicas"], ici["ents"]
-            ) == (1, 8, 4, 4)
+    assert (ici["tiles"], ici["tile_rows"], ici["replicas"]) == (1, 8, 4)
+    assert ici["slot_bytes"] == E4
     assert len(ici["after_call"]) == ici["close"] + 1 + ctx["raw"][
         "traced_calls"]
     assert ici["close"] - ici["open"] == ctx["raw"]["calls"]
     # What crossed is what the window's lane counter saw occupied, a
     # round a lane a call apart (the call's last outbox waits).
-    lanes = ctx["raw"]["lanes"]
-    occupied = sum(b - a for a, b in zip(lanes["before"], lanes["after"]))
+    occ = ctx["raw"]["occupancy"]
+    occupied = sum(occ["after"]["lanes"]) - sum(occ["before"]["lanes"])
     rounds = ctx["raw"]["rounds"]
     assert layer["ici.lanes_run"]["value"] == pytest.approx(
         occupied / rounds, abs=6 * ctx["raw"]["calls"] / rounds)
@@ -399,7 +436,7 @@ def test_each_reader_on_a_tiny_run(tiny_run):
     runs = [b - a for a, b in zip(ici["after_call"][ici["open"]],
                                   ici["after_call"][ici["close"]])]
     assert layer["ici.mb_per_round"]["value"] == pytest.approx(
-        (sum(runs) * 8 * 3 * 34 + runs[1] * 8 * 3 * 16) / rounds / 1e6)
+        sum(n * 8 * 3 * E4[k] for k, n in enumerate(runs)) / rounds / 1e6)
     with_trace = dict(ctx, trace={
         "scope_s": {"raft_ici": 1.0, "raft_agree": 1.0}, "leaf_s": 4.0,
         "modules": {}}, device={"kind": "TPU v5 lite"})

@@ -30,8 +30,8 @@ from benchmark.readers import telemetry as telemetry_reader
 from benchmark.reconf_checks import (LEADER, membership_checks, run_checks,
                                      sample_checks, window_checks)
 
-from .util import (CELLS_AT_36, REPO, bench, edited_copy, listed_cells,
-                   swap, tiny_root)
+from .util import (CELLS_AT_36, REPO, bench, edited_copy, in_workloads_order,
+                   own_entries, swap, tiny_root)
 
 CELL = "engine1m-r3.joint-readindex"
 SIZES = {"num_groups": 16, "num_replicas": 3}
@@ -540,14 +540,28 @@ WERE = ["engine64k-r3", "engine10k-r5", "engine100k-r3"]
 CELLS_WERE = CELLS_AT_36[:3]
 
 
-def test_the_seven_are_live_with_exactly_these_workloads():
-    """``raft_control`` runs in every cell (transfers and ReadIndex
-    batches are the round's, asked or not), so its share lists the five
-    PR 36 found; the six counter metrics keep the cell they were
-    written for. A cell appended since is not theirs to list."""
-    assert listed_cells(SEVEN) == {
-        "round.control_pct": CELLS_AT_36,
-        **{name: [CELL] for name in SEVEN[1:]}}
+def entries_rule(b: dict) -> None:
+    """The seven stand right after the 20 entries PR 28's file had, in
+    their order. ``raft_control`` runs in every cell (transfers and
+    ReadIndex batches are the round's, asked or not), so its share
+    lists the five PR 36 found and after them every later cell; the
+    two reads' entries list this cell first and after it the cells
+    whose traffic asks reads (``test_lists.py`` holds each, cell by
+    cell); the four ``reconf.*`` keep the cell they were written
+    for."""
+    rows = b["per_layer"]
+    assert [m["name"] for m in rows[20:27]] == SEVEN
+    assert not set(SEVEN) & {m["name"] for m in rows[:20] + rows[27:]}
+    assert rows[20]["workloads"][:5] == CELLS_AT_36
+    for m in rows[20:23]:
+        assert CELL in m["workloads"] and in_workloads_order(b, m)
+    for m in rows[21:23]:
+        assert m["workloads"][0] == CELL
+    own_entries(b, SEVEN[3:], 23, CELL)
+
+
+def test_the_seven_are_live_with_these_workloads():
+    entries_rule(bench())
     assert not os.path.exists(os.path.join(
         REPO, "benchmark", "parked", "engine1m-r3_layers.json"))
 

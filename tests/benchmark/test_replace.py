@@ -32,7 +32,7 @@ from benchmark.replace_checks import (FRESH, empty_slot_checks, live_view,
                                       window_checks)
 
 from .test_contract import NAME
-from .util import CELLS_AT_36, REPO, bench, listed_cells, tiny_root
+from .util import CELLS_AT_36, REPO, bench, own_entries, tiny_root
 
 CELL = "engine512k-r3of4.replace-readindex"
 SIZES = {"num_groups": 20, "num_replicas": 4}
@@ -473,13 +473,19 @@ FIVE = ["replace.snapshots_per_swap", "replace.catchup_rounds",
         "replace.swapped_per_kgr"]
 
 
+def entries_rule(b: dict) -> None:
+    """The five stand right after the 27 entries PR 32's file had, in
+    their order, for this cell alone."""
+    own_entries(b, FIVE, 27, CELL)
+
+
 def test_the_five_are_live_with_exactly_these_workloads():
-    assert listed_cells(FIVE) == {name: [CELL] for name in FIVE}
+    entries_rule(bench())
     assert not os.path.exists(os.path.join(
         REPO, "benchmark", "parked", "engine512k-r3of4_layers.json"))
 
 
-def test_the_cell_follows_the_cells_that_were_there():
+def follows_rule(b: dict) -> None:
     """The three additions ISSUE 34 names, each at the end of its list
     as PR 34 found it: the configuration, the cell, the cell's name
     under its end-to-end metric. Held in the order-relative form (this
@@ -487,7 +493,6 @@ def test_the_cell_follows_the_cells_that_were_there():
     the three before them), so that the next cell appended after this
     one does not fail it (``test_reconf.py`` holds ``engine1m-r3``'s
     in the same form since PR 36, and shows it open and tight)."""
-    b = bench()
     was = ["engine64k-r3", "engine10k-r5", "engine100k-r3", "engine1m-r3"]
     cells = CELLS_AT_36[:4]
     assert [c["name"] for c in b["configs"]][:5] == was + [
@@ -500,6 +505,10 @@ def test_the_cell_follows_the_cells_that_were_there():
                      "bound": 0.25, "source": "host_clock"}
     assert set(FIVE) <= {m["name"] for m in b["per_layer"]}
     assert b["run_seconds"] == 30
+
+
+def test_the_cell_follows_the_cells_that_were_there():
+    follows_rule(bench())
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         assert len(f.read()) < 64 << 10
     assert not os.path.exists(os.path.join(

@@ -21,8 +21,12 @@ from etcd_tpu.obs.spans import SpanRec
 from . import test_layers
 from .test_layers import SCOPE_S, SHARES, bench_of, entry_rule, shares
 from .util import CELLS_AT_36 as CELLS
-from .util import REPO, tiny_root
+from .util import REPO, in_workloads_order, tiny_root
 
+# The cells each of the seven listed when PR 38 wrote it: it lists them
+# still, first; what cell came after is listed by the rule
+# ``test_lists.py`` holds (the cell's program runs the scope, its
+# set-up has the span).
 LARGE = CELLS[3:]
 SCAN = {"scan.tiles_pct": ("raft_tiles", LARGE),
         "scan.watch_pct": ("raft_watch", LARGE),
@@ -36,17 +40,22 @@ NEW = [*SCAN, *SETUP]
 
 
 def test_the_seven_are_appended_after_the_36_and_nothing_else_moved():
-    rows = bench_of(REPO)["per_layer"]
+    b = bench_of(REPO)
+    rows = b["per_layer"]
     assert [m["name"] for m in rows[36:36 + len(NEW)]] == NEW
     for m in rows[36:36 + len(NEW)]:
         scan = m["name"] in SCAN
+        were = SCAN[m["name"]][1] if scan else SETUP[m["name"]]
         assert m == {
             "name": m["name"], "unit": "%" if scan else "s",
             "better": "lower",
             "source": "device_trace" if scan else "program_span",
             "layer": "closed-loop engine" if scan else "compile",
             "moves": "group_rounds_per_s" if scan else "setup_s",
-            "workloads": SCAN[m["name"]][1] if scan else SETUP[m["name"]]}
+            "workloads": m["workloads"]}
+        assert [c for c in m["workloads"] if c in CELLS] == were
+        assert m["workloads"][:len(were)] == were
+        assert in_workloads_order(b, m)
 
 
 @pytest.mark.parametrize("name", NEW)

@@ -219,6 +219,21 @@ def test_rows_of_a_trace_without_spans_are_all_unspanned():
     assert rows[0][1] == pytest.approx(red["gap_s"], rel=1e-9)
 
 
+def test_the_gaps_of_a_reduced_trace_are_those_read_anew(chip):
+    """A traced run walks the device's ops once: ``reduce_gaps`` handed
+    ``reduce_trace``'s result takes its gaps as they stand and reads
+    the host's plane alone, and says what it says reading all anew."""
+    gaps, red = chip
+    again = reduce_gaps(red["xplane"], top=5, reduced=red)
+    assert again == gaps
+    assert sum(b - a for dev in red["idle_gaps"] for a, b, _n in dev) \
+        == pytest.approx(red["gap_s"] * 1e9 * red["devices"])
+    assert red["first_op_ns"] is not None
+    if os.path.isdir(XPROF):
+        old = reduce_trace(XPROF)
+        assert reduce_gaps(XPROF, reduced=old) == reduce_gaps(XPROF)
+
+
 def test_result_line_of_a_traced_run(chip, root):
     """``run_cell``'s second half on a tiny engine run, with the served
     chip trace put where a traced run's reduction would be (a CPU trace

@@ -34,7 +34,9 @@ from benchmark.trickle_checks import (fresh_slot_checks, membership_checks,
                                       run_checks, window_checks)
 
 from .test_contract import NAME, SOURCES, UNIT
-from .util import REPO, _edit, bench, listed_cells, tiny_root
+from .util import (REPO, SHARED_AT_52, UNLISTED, apply_tiny, bench,
+                   cell_root, listed_cells, own_entries, reaches,
+                   shared_with, tiny_root)
 
 CONFIG = "engine768k-r3of4-rebalance"
 CELL = CONFIG + ".trickle-readindex"
@@ -471,22 +473,20 @@ def test_the_phase_share_is_a_share_of_the_traced_rounds(capsys):
 # -- the cell's entries ----------------------------------------------------------------
 
 
-def test_the_six_are_appended_for_this_cell_alone():
-    assert listed_cells(SIX) == {name: [CELL] for name in SIX}
-    b = bench()
-    rows = [m["name"] for m in b["per_layer"]]
-    assert rows.index(SIX[0]) == 48 and rows[48:54] == SIX
-    layers = {m["layer"] for m in b["per_layer"][:48]}
-    for m in b["per_layer"][48:54]:
-        assert set(m) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
+def entries_rule(b: dict) -> None:
+    """The cell's six stand right after the 48 entries PR 40's file
+    had, in their order, for this cell alone; what follows them is a
+    later PR's."""
+    for m in own_entries(b, SIX, 48, CELL):
         assert NAME.match(m["name"]) and UNIT.match(m["unit"])
-        assert m["source"] in SOURCES and m["layer"] in layers
+        assert m["source"] in SOURCES
         assert m["moves"] == "group_rounds_per_s"
-        spec = load_json("layer_metrics", m["name"])
-        assert (spec["name"], spec["unit"], spec["layer"], spec["moves"]) \
-            == (m["name"], m["unit"], m["layer"], m["moves"])
-        assert "workloads" not in spec
+
+
+def test_the_six_are_appended_for_this_cell_alone():
+    b = bench()
+    entries_rule(b)
+    assert listed_cells(SIX) == {name: [CELL] for name in SIX}
     got = {m["name"]: (m["unit"], m["better"], m["source"])
            for m in b["per_layer"][48:54]}
     assert got == {
@@ -499,34 +499,47 @@ def test_the_six_are_appended_for_this_cell_alone():
         "scan.phase_pct": ("%", "lower", "device_trace")}
 
 
-def test_the_entries_that_list_the_five_cells_are_left_as_they_were():
-    """Their tests hold them to ``CELLS_AT_36`` (and the two reads' to
-    the reconfiguration cell): this cell's driver says what they read
-    here on its own ``[bench:trickle_layers]`` line, and every entry
-    without a list reaches it by itself."""
+def test_the_entries_of_shared_layers_list_this_cell():
+    """Until PR 52 eighteen accepted entries listed the five cells of
+    PR 36 and this cell's driver said what they read here on a line of
+    its own; now each lists the cell (``test_lists.py`` holds every one
+    to what the cell's run gives), the line and the driver's list are
+    gone, and every entry without a list reaches the cell by itself."""
     b = bench()
-    rows = {m["name"]: m for m in b["per_layer"]}
-    assert len(engine_trickle.LISTED_ELSEWHERE) == 18
-    for name in engine_trickle.LISTED_ELSEWHERE:
-        assert CELL not in rows[name]["workloads"], name
+    assert not hasattr(engine_trickle, "LISTED_ELSEWHERE")
+    assert not hasattr(engine_trickle.Driver, "layers_elsewhere")
     mine = {s["name"] for s in harness.Cell(REPO, CELL).per_layer}
     unlisted = {m["name"] for m in b["per_layer"] if "workloads" not in m}
-    assert len(unlisted) == 11 and unlisted <= mine
-    assert mine == unlisted | set(SIX)
+    assert UNLISTED <= unlisted <= mine == reaches(b, CELL)
+    # (At least: a later PR may bring one more view of this cell.)
+    assert mine >= unlisted | set(SIX) | shared_with(b, CELL)
+    assert set(SHARED_AT_52[:18]) | {
+        "round.rare_pct", "emit.ring_pct"} <= shared_with(b, CELL)
+    assert "round.bulk_pct" not in mine  # the append lane is not split
 
 
-def test_the_cell_follows_what_was_there():
-    b = bench()
-    assert [c["name"] for c in b["configs"]][-1] == CONFIG
-    assert [c["name"] for c in b["configs"]].count(CONFIG) == 1
-    assert [w["name"] for w in b["workloads"]][-1] == CELL
+def follows_rule(b: dict) -> None:
+    """By rule, not by position from the end (as ``test_load.py``'s):
+    the configuration, the cell and its name under the rate come after
+    everything PR 40's file had, in its order."""
+    before = ["engine64k-r3", "engine10k-r5", "engine100k-r3", "engine1m-r3",
+              "engine512k-r3of4", "engine1m-r3of4-x4"]
+    names = [c["name"] for c in b["configs"]]
+    assert names[:6] == before and names.index(CONFIG) == 6
+    assert names.count(CONFIG) == 1
+    cells = [w["name"] for w in b["workloads"]]
+    assert [w["config"] for w in b["workloads"]][:6] == before
+    assert cells.index(CELL) == 6 and cells.count(CELL) == 1
     rate = b["end_to_end"][0]
     assert (rate["name"], rate["bound"]) == ("group_rounds_per_s", 0.01)
-    assert rate["workloads"][-1] == CELL and len(rate["workloads"]) == 7
-    assert len(b["workloads"]) == 7
+    assert rate["workloads"][:7] == cells[:7]
     assert [w["name"] for w in b["workloads"] if w["chips"] == 4] == [
         "engine1m-r3of4-x4.replace-readindex-x4"]
     assert b["run_seconds"] == 30
+
+
+def test_the_cell_follows_what_was_there():
+    follows_rule(bench())
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         assert len(f.read()) < 64 << 10
 
@@ -579,15 +592,9 @@ def test_the_entries_are_the_issues():
 def root(tmp_path_factory):
     """The benchmark cut to 8 groups, and the trickle to a group a
     batch (four batches, 16 rounds apart; four groups never started)
-    under a budget of one snapshot."""
-    dst = tiny_root(str(tmp_path_factory.mktemp("trickle")))
-    _edit(os.path.join(dst, "benchmark", "traffic",
-                       "trickle-readindex.json"),
-          lambda c: c.update(batch_groups=1))
-    _edit(os.path.join(dst, "benchmark", "configs", CONFIG + ".json"),
-          lambda c: c.update(shadow_groups=8, rebalance={
-              "snapshots_in_flight_per_member": 1, "sending_members": 1}))
-    return dst
+    under a budget of one snapshot: the cell's own cuts, from its file
+    under ``tiny/``."""
+    return cell_root(str(tmp_path_factory.mktemp("trickle")), CELL)
 
 
 def test_the_cell_resolves_to_files_that_exist(root):
@@ -607,12 +614,7 @@ def root24(tmp_path_factory):
     """``root`` at 24 groups: twelve batches of a group, so that moves
     begin, swap and end inside a window that opens a whole cycle in."""
     dst = tiny_root(str(tmp_path_factory.mktemp("trickle24")), groups=24)
-    _edit(os.path.join(dst, "benchmark", "traffic",
-                       "trickle-readindex.json"),
-          lambda c: c.update(batch_groups=1))
-    _edit(os.path.join(dst, "benchmark", "configs", CONFIG + ".json"),
-          lambda c: c.update(shadow_groups=8, rebalance={
-              "snapshots_in_flight_per_member": 1, "sending_members": 1}))
+    apply_tiny(dst, CELL)
     return dst
 
 
@@ -732,29 +734,29 @@ def test_committed_of_offered_is_what_the_reference_logs_hold(driven24):
         r.closed[1] - r.opened[1] == raw["rounds"] * 2 for r in never)
 
 
-def test_the_driver_says_the_entries_listed_elsewhere_as_it_closes(
-        root24, capsys):
-    """On the run's own ``[bench:trickle_layers]`` line, each by its own
-    reader: what the host's counters and spans give; the device
-    trace's are the harness's ``[bench:trace]`` line's to give."""
-    cell = harness.Cell(root24, CELL)
-    harness.measure(cell, 7, 0.3, False, time.perf_counter(),
-                    require_tpu=False)
-    line = [ln for ln in capsys.readouterr().out.splitlines()
-            if ln.startswith("[bench:trickle_layers] ")]
-    assert len(line) == 1
-    said = json.loads(line[0].split(" ", 1)[1])
-    assert said.pop("route_bytes_a_round") > 0
-    assert set(said) == {
+def test_the_shared_entries_read_this_cells_run_through_the_harness(
+        layer_run, capsys):
+    """What a line of the driver's own said until PR 52 is on the
+    result line: the shared entries the host's counters and spans
+    give, each by its own reader through the harness (the device
+    trace's need the chip)."""
+    cell, ctx = layer_run
+    layer = harness.per_layer_metrics(cell, ctx)
+    tags = {ln.split("]")[0] for ln in capsys.readouterr().out.splitlines()
+            if ln.startswith("[bench:")}
+    assert not [t for t in tags if t.endswith("_layers")]
+    got = {n: m["value"] for n, m in layer.items() if n in SHARED_AT_52}
+    assert set(got) == {
         "round.lanes_run", "read.confirmed_per_kgr",
         "read.rounds_to_confirm", "setup.jax_trace_s",
-        "setup.jax_compile_s", "setup.unspanned_s"} | (
-            {"setup.pretrace_s"} & set(said))
-    assert set(said) <= set(engine_trickle.LISTED_ELSEWHERE)
-    assert 0 < said["round.lanes_run"] <= 6
+        "setup.jax_compile_s", "setup.unspanned_s", "round.rare_pct",
+        "emit.ring_pct"} | ({"setup.pretrace_s"} & set(got))
+    assert 0 < got["round.lanes_run"] <= 6
     # A ReadIndex batch a leader a round or two.
-    assert 300 < said["read.confirmed_per_kgr"] <= 1000
-    assert 1.0 <= said["read.rounds_to_confirm"] < 4.0
+    assert 300 < got["read.confirmed_per_kgr"] <= 1000
+    assert 1.0 <= got["read.rounds_to_confirm"] < 4.0
+    # A leadership handed over in a few of the window's rounds.
+    assert 0 < got["round.rare_pct"] < 50 and 0 < got["emit.ring_pct"] < 100
 
 
 def test_a_program_without_a_phased_schedule_fails_at_once(root, monkeypatch):
